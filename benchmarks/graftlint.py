@@ -1078,8 +1078,9 @@ def _ir_sharding(asm, label, expect_sharding, seq_axis=False) -> list[Finding]:
     counts: dict[str, int] = {}
     total = 0
     seq_total = 0
+    # Shardy form: sdy.sharding_constraint %x <@mesh, [{"data"}, {}]> : tensor<..> loc(#locN)
     for m in re.finditer(
-        r"stablehlo\.custom_call\s+@Sharding.*?loc\(#loc(\d+)\)", asm
+        r"sdy\.sharding_constraint[^\n]*?loc\(#loc(\d+)\)", asm
     ):
         total += 1
         scope_s = locs.get(m.group(1), "")
@@ -1089,13 +1090,15 @@ def _ir_sharding(asm, label, expect_sharding, seq_axis=False) -> list[Finding]:
         # Sequence-axis census (r22): a constraint splitting dim 1 of a
         # rank>=3 operand is anchoring the [B, S, ...] sequence dim — on a
         # context>1 mesh that's the seq/context axis (plus "model" when the
-        # Megatron-SP fold is on). devices=[a,b,...] lists the per-dim tile
-        # factors in dim order, so dim 1's factor is the second entry.
-        dev = re.search(r'mhlo\.sharding = "[^"]*devices=\[(\d+),(\d+)',
-                        m.group(0))
+        # Megatron-SP fold is on). The [{..}, {..}, ...] list names the mesh
+        # axes per dim in dim order, so dim 1 is anchored when its entry
+        # names an axis.
+        dims = re.search(r"<@\w+, \[(.*?)\]>", m.group(0))
         rank = re.search(r"tensor<(?:\d+x){3,}", m.group(0))
-        if dev and rank and int(dev.group(2)) > 1:
-            seq_total += 1
+        if dims and rank:
+            per_dim = re.findall(r"\{([^}]*)\}", dims.group(1))
+            if len(per_dim) > 1 and '"' in per_dim[1]:
+                seq_total += 1
     out: list[Finding] = []
     if seq_axis and total and seq_total == 0:
         out.append(

@@ -658,7 +658,7 @@ def _assemble_sharded(arrays_dir: str, meta: dict, sharding) -> jax.Array:
     pieces = [placed[device] for device in index_map]
     arr = jax.make_array_from_single_device_arrays(shape, sharding, pieces)
     # ``device_put(host_block, device)`` zero-copies aligned numpy memory on
-    # the CPU PJRT client (jax 0.4.x), and the train loop DONATES the state:
+    # the CPU PJRT client, and the train loop DONATES the state:
     # donating a buffer XLA merely borrows frees host memory it does not own
     # — a hard segfault on the first post-resume step (reproduced by
     # tests/test_distributed.py::test_mid_epoch_kill_resume_is_sample_exact).

@@ -63,18 +63,13 @@ def init_process_group(
         _initialized = True
         return
 
-    if (os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
-            or os.environ.get("JAX_PLATFORMS_OVERRIDE") == "cpu"):
-        # Local CPU pods (launch.py --cpu-devices): XLA:CPU refuses any
-        # computation spanning processes ("Multiprocess computations aren't
-        # implemented on the CPU backend") unless a CPU collectives backend
-        # is selected before the backend initializes. Gloo ships in jaxlib;
-        # older jaxlibs without the flag fall through to the old behavior.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - jaxlib without gloo
-            log.warning("no CPU collectives backend available — "
-                        "multi-process CPU computations will fail")
+    if (jax.config.jax_platforms or "").startswith("cpu"):
+        # Local CPU pods (launch.py --cpu-devices; JAX_PLATFORMS=cpu or
+        # main.py --platform cpu): XLA:CPU refuses any computation spanning
+        # processes ("Multiprocess computations aren't implemented on the
+        # CPU backend") unless a CPU collectives backend is selected before
+        # the backend initializes. Gloo ships in jaxlib.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

@@ -207,11 +207,15 @@ def build_mesh(
         dev_array = mesh_utils.create_hybrid_device_mesh(
             ici, dcn, devices=devices)
     else:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
+        try:
             dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
         except Exception:
+            # Fake host devices have no torus to lay out along; on a real
+            # chip a failed layout is a fault, not something to paper over.
+            if devices[0].platform != "cpu":
+                raise
             dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXES)
 
@@ -241,14 +245,7 @@ def use_mesh(mesh: Mesh):
     prev = current_mesh()
     _local.mesh = mesh
     try:
-        # jax's own set_mesh/use_mesh contextmanager (when present) lets bare
-        # PartitionSpecs be used inside jit bodies.
-        ctx = getattr(jax.sharding, "use_mesh", None)
-        if ctx is not None:
-            with ctx(mesh):
-                yield mesh
-        else:
-            yield mesh
+        yield mesh
     finally:
         _local.mesh = prev
 
@@ -280,6 +277,27 @@ def constrain(x, spec: P):
         return x
     spec = _prune_spec(spec, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def manual_call(fn, *args, in_specs, out_specs, mesh: Mesh | None = None):
+    """Run ``fn`` per device of ``mesh`` — the ambient mesh by default —
+    through a ``shard_map`` over ALL its axes.
+
+    Mosaic kernels cannot be partitioned by GSPMD: lowered bare under a
+    multi-device mesh the chip's compiler refuses them ("wrap the call in a
+    shard_map"), which no CPU run ever meets. Every Pallas call site on a
+    GSPMD path goes through here: mesh axes the specs leave unmentioned
+    replicate, so ``P()`` specs mean "every device runs the whole kernel".
+    Without a multi-device mesh, or when already inside a manual
+    region (ring attention, pipeline stages, the EP shard_map), ``fn`` is
+    called directly.
+    """
+    mesh = mesh or current_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def _prune_spec(spec: P, mesh: Mesh) -> P:

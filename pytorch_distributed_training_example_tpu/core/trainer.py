@@ -793,9 +793,10 @@ class Trainer:
                     mfu = metrics_lib.mfu(per_chip, self.bundle.fwd_flops_per_example)
                     log.info(
                         "epoch %d step %d/%d loss %.4f lr %.2e %s/s %.1f "
-                        "(%.1f/chip) mfu %.1f%% %s",
+                        "(%.1f/chip) mfu %s %s",
                         epoch, i + 1, self.steps_per_epoch, m["loss"], lr,
-                        self.bundle.examples_unit, rate, per_chip, 100 * mfu,
+                        self.bundle.examples_unit, rate, per_chip,
+                        "not measured" if mfu is None else f"{100 * mfu:.1f}%",
                         " ".join(f"{k} {v:.4f}" for k, v in m.items()
                                  if k not in ("loss",)),
                     )

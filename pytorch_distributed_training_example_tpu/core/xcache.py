@@ -24,9 +24,10 @@ Entry layout (one directory per fingerprint)::
 Corruption handling mirrors ``core/checkpoint.py``: a CRC or unpickle
 failure quarantines the entry (rename to ``<key>.corrupt``) and recompiles.
 Serialization is backend-dependent; where ``serialize`` is unsupported the
-cache degrades to the jax persistent compilation cache (``main.py`` points
-``jax_compilation_cache_dir`` into ``<ckpt-dir>/xcache/jaxcache`` when
-``--xcache`` is on), which ``Lowered.compile()`` consults transparently.
+cache degrades to the jax persistent compilation cache
+(:func:`place_compile_cache`; with ``--xcache`` and no
+``JAX_COMPILATION_CACHE_DIR`` it lives in ``<ckpt-dir>/xcache/jaxcache``),
+which ``Lowered.compile()`` consults transparently.
 """
 
 from __future__ import annotations
@@ -89,6 +90,10 @@ def fingerprint(*, mesh, config=None, example_args=(), extra=None) -> dict:
         "device_count": jax.device_count(),
         "device_kind": jax.devices()[0].device_kind,
         "mesh_shape": {str(k): int(v) for k, v in dict(mesh.shape).items()},
+        # The devices the executable runs on, in mesh order: load() hands
+        # them to deserialize_and_load, which otherwise assumes every
+        # device of the backend.
+        "mesh_devices": [int(d.id) for d in mesh.devices.flat],
         "abstract": [s for a in example_args for s in _abstract_sig(a)],
     }
     if config is not None:
@@ -220,7 +225,10 @@ def load(root: str, fields: dict, example=None):
         from jax.experimental.serialize_executable import (
             deserialize_and_load)
 
-        compiled = deserialize_and_load(payload, in_tree, out_tree)
+        by_id = {d.id: d for d in jax.devices()}
+        compiled = deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in fields["mesh_devices"]])
     except Exception as e:  # noqa: BLE001 — any failure means cold compile
         log.warning("xcache: deserialize failed for %s (%s: %s) — "
                     "quarantining, cold compile", entry,
@@ -321,6 +329,31 @@ def save(root: str, fields: dict, compiled, *, example=None,
     log.info("xcache: saved compiled executable -> %s (%d bytes)",
              entry, len(blob))
     return True
+
+
+#: Where the jax persistent compilation cache goes when the environment
+#: does not place it: one fixed directory inside the checkout (the path is
+#: part of what a later run must find again, so it never moves).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_compile_cache(fallback_dir: str = DEFAULT_COMPILE_CACHE_DIR,
+                        min_compile_secs: float = 1.0) -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    The ONE place this repo sets ``jax_compilation_cache_dir`` (main.py,
+    bench.py, chip_smoke.py and tests/conftest.py all call it). Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it, and no
+    directory is set in code — the cache can be placed from outside.
+    """
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    jax.config.update("jax_compilation_cache_dir", fallback_dir)
+    return fallback_dir
 
 
 def compile_cached(lowered, root: str | None, fields: dict):

@@ -204,12 +204,21 @@ class DataLoader:
         # writing into a per-batch slot so batch order is deterministic.
         index_batches = list(self._batches_of_indices(start))
         out_q: list[queue.Queue] = [queue.Queue(maxsize=1) for _ in index_batches]
-        budget = threading.Semaphore(max(self.prefetch_batches, self.num_workers))
+        # Look-ahead window, granted IN BATCH ORDER: a worker may build batch
+        # b only while b < consumed + window. An unordered permit pool (a
+        # semaphore) deadlocks: workers of later batches can take every
+        # permit while the worker of the batch the consumer is blocked on
+        # starves. window >= num_workers keeps that batch always admitted.
+        window = max(self.prefetch_batches, self.num_workers)
+        consumed = 0
+        cond = threading.Condition()
         stop = threading.Event()
 
         def worker(wid: int):
             for b in range(wid, len(index_batches), self.num_workers):
-                budget.acquire()
+                with cond:
+                    cond.wait_for(
+                        lambda: stop.is_set() or b < consumed + window)
                 if stop.is_set():
                     return
                 try:
@@ -232,9 +241,10 @@ class DataLoader:
                         f"DataLoader worker failed on batch {b}") from item.exc
                 _log_indices(self.sampler.epoch, start + b, index_batches[b])
                 yield _apply_batch_hook(self.sampler.epoch, start + b, item)
-                budget.release()
+                with cond:
+                    consumed = b + 1
+                    cond.notify_all()
         finally:
             stop.set()
-            # Unblock any workers parked on the budget semaphore.
-            for _ in threads:
-                budget.release()
+            with cond:  # unblock any workers parked on the window
+                cond.notify_all()

@@ -7,13 +7,14 @@ released, double-buffered ahead of the train loop. Python keeps orchestration
 identical to the pure-Python loader — tested against it bit-for-bit in
 gather mode (augmentation RNG differs by design).
 
-Falls back silently (``available() == False``) when no compiler is present;
-the pure-Python loader is always the reference implementation.
+``available()`` is False, with a loud log line, when ``make`` cannot build
+the library; the pure-Python loader is always the reference implementation.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -24,6 +25,8 @@ from pytorch_distributed_training_example_tpu.data import loader as loader_lib
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _LIB_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libbatch_engine.so"))
+
+log = logging.getLogger(__name__)
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -37,9 +40,11 @@ def _load() -> ctypes.CDLL | None:
         # ALWAYS invoke make (incremental: a no-op when the .so is newer than
         # batch_engine.cc). The library is untracked, so a checkout can leave
         # a stale binary with an old C ABI next to newer sources — loading it
-        # would mis-stride gathers instead of erroring. An flock serializes
-        # concurrent ranks (launch.py spawns N processes that would otherwise
-        # race the compiler on the same output file).
+        # would mis-stride gathers instead of erroring. So only a library
+        # this process just built, or make found up to date, is ever loaded:
+        # a failed build means the Python loader, whatever .so lies there.
+        # An flock serializes concurrent ranks (launch.py spawns N processes
+        # that would otherwise race the compiler on the same output file).
         try:
             import fcntl
 
@@ -47,9 +52,14 @@ def _load() -> ctypes.CDLL | None:
                 fcntl.flock(lk, fcntl.LOCK_EX)
                 subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR)],
                                check=True, capture_output=True, timeout=120)
-        except Exception:
-            if not os.path.exists(_LIB_PATH):
-                return None  # no toolchain and no prebuilt library
+        except Exception as e:
+            detail = getattr(e, "stderr", b"") or b""
+            log.error(
+                "native batch engine NOT built (%s: %s) — the input pipeline "
+                "runs on the Python loader%s", type(e).__name__, e,
+                (": " + detail.decode(errors="replace")[-500:]) if detail
+                else "")
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:

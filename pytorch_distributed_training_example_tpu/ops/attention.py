@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_distributed_training_example_tpu.ops import pallas_compat  # noqa: F401
+from pytorch_distributed_training_example_tpu.ops import backend
 
 NEG_INF = -1e30
 
@@ -555,7 +555,9 @@ def attention(
         elif _flash_eligible(q, k):
             impl = "flash"
         elif _padded_flash_eligible(q, k, explicit=False):
-            return padded_flash_attention(q, k, v, causal=causal)
+            return _per_device_flash(padded_flash_attention, q, k, v,
+                                     causal=causal, mesh=mesh,
+                                     batch_axes=batch_axes)
         else:
             impl = "xla"
     elif impl in ("ring", "ring_zigzag", "ring_allgather",
@@ -582,21 +584,56 @@ def attention(
     if impl == "flash":
         if not _flash_eligible(q, k, explicit=True):
             if _padded_flash_eligible(q, k):
-                return padded_flash_attention(q, k, v, causal=causal)
+                return _per_device_flash(padded_flash_attention, q, k, v,
+                                         causal=causal, mesh=mesh,
+                                         batch_axes=batch_axes)
+            msg = (f"attn_impl='flash' not eligible for shape q={q.shape} "
+                   f"k={k.shape} (needs seq % 512 == 0 or a VMEM-fitting "
+                   "padded one-shot plan, head_dim in {64,128,256}, TPU)")
+            if backend.on_tpu():
+                # On the chip an explicit 'flash' that silently ran XLA
+                # attention would be measured under the kernel's name.
+                raise ValueError(msg)
             import logging
 
             logging.getLogger(__name__).warning(
-                "attn_impl='flash' not eligible for shape q=%s k=%s on %s "
-                "(needs seq %% 512 == 0 or a VMEM-fitting padded one-shot "
-                "plan, head_dim in {64,128,256}, TPU); falling back to XLA "
-                "attention", q.shape, k.shape, jax.default_backend())
+                "%s; running XLA attention on cpu", msg)
             return dot_product_attention(q, k, v, causal=causal,
                                          lowp_residual=_lowp(q))
         from pytorch_distributed_training_example_tpu.ops import flash_attention
 
-        return flash_attention.flash_attention(q, k, v, causal=causal)
+        return _per_device_flash(flash_attention.flash_attention, q, k, v,
+                                 causal=causal, mesh=mesh,
+                                 batch_axes=batch_axes)
     return dot_product_attention(q, k, v, causal=causal,
                                  lowp_residual=_lowp(q))
+
+
+def _per_device_flash(fn, q, k, v, *, causal, mesh, batch_axes):
+    """Call a Pallas flash entry point per device of ``mesh``.
+
+    GSPMD cannot partition a Mosaic kernel (``mesh_lib.manual_call``), so
+    under a multi-device mesh the call is ``shard_map``-ped the way the
+    ring/Ulysses paths already are: batch over the data-parallel axes,
+    heads over ``model``. An axis whose size does not divide its dimension
+    (the batch-2 init template under fsdp=4, GQA KV heads under TP)
+    replicates that dimension instead; the sequence is always whole.
+    """
+    from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
+
+    b_ax = h_ax = None
+    if mesh is not None:
+        axes = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
+        dp = math.prod(mesh.shape[a] for a in axes)
+        if axes and q.shape[0] % dp == 0:
+            b_ax = axes
+        tp = mesh.shape.get("model", 1)
+        if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0:
+            h_ax = "model"
+    spec = P(b_ax, None, h_ax, None)
+    return mesh_lib.manual_call(
+        functools.partial(fn, causal=causal), q, k, v, mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec)
 
 
 def _lowp(q) -> bool:
@@ -657,7 +694,7 @@ def _padded_flash_eligible(q, k, multiple: int = PAD_MULTIPLE,
                            explicit: bool = True) -> bool:
     from pytorch_distributed_training_example_tpu.ops import flash_attention
 
-    if jax.default_backend() in ("cpu",) or q.shape[-1] not in (64, 128, 256):
+    if not backend.on_tpu() or q.shape[-1] not in (64, 128, 256):
         return False
     if q.shape[1] != k.shape[1]:  # cross-shard ring chunks: keep simple
         return False
@@ -681,7 +718,7 @@ def _flash_eligible(q, k, explicit: bool = False) -> bool:
     is already fast and kernel launch overhead dominates; an explicit
     ``impl='flash'`` only needs the kernel's hard shape constraints.
     """
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = backend.on_tpu()
     seq_ok = q.shape[1] % 512 == 0 and k.shape[1] % 512 == 0
     if not explicit:
         seq_ok = seq_ok and q.shape[1] >= 1024

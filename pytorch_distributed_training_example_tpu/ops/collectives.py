@@ -16,15 +16,8 @@ import jax.numpy as jnp
 
 
 def axis_size(axis: str) -> int:
-    """Static mesh-axis size inside ``shard_map``, portable across jax
-    versions (``jax.lax.axis_size`` only exists on newer jax; 0.4.x exposes
-    the size through ``jax.core.axis_frame``)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    from jax import core
-
-    frame = core.axis_frame(axis)
-    return int(getattr(frame, "size", frame))
+    """Static mesh-axis size inside ``shard_map``."""
+    return int(jax.lax.axis_size(axis))
 
 
 def all_reduce(x, axis: str | Sequence[str]):

@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_training_example_tpu.ops import pallas_compat  # noqa: F401
 from pytorch_distributed_training_example_tpu.ops import attention as attn_lib
+from pytorch_distributed_training_example_tpu.ops import backend
 
 NEG_INF = -1e30
 # Online-kernel defaults (the one-shot kernels self-plan their tiling):
@@ -766,6 +766,17 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
 
 STREAM_BWD = os.environ.get("PDTX_STREAM_BWD", "1")
 STREAM_BWD_BUDGET = 13 * 1024 * 1024  # same general-admission cap as one-shot
+# The byte model below undercounts what Mosaic keeps live across the
+# unrolled q-subtile loop, and the gap grows with the rows a program pins.
+# v5e compiler (16 MB scoped VMEM), kernel compiled alone at D=128:
+# g*Sq = 4096 fits (Sq=4096 g=1, Sq=2048 g=2, either bsub); g*Sq = 6144 is
+# counted at 16.58 MB (bsub=512) / 21.29 MB (256); g*Sq = 8192 at 20.79-
+# 28.79 MB (16.75 MB inside the fwd+bwd program) — all refused. So admission
+# is also bounded by resident rows (of 128 lanes: a narrower head pads to
+# them, a wider one counts double); refused shapes take the online
+# two-kernel backward. Outside D=128 (PDTX_STREAM_BWD=all) every plan the
+# compiler refused had 256-row subtiles, so only 512-row ones are admitted.
+STREAM_BWD_MAX_ROWS = 4096
 
 
 def _stream_bwd_plan(H, Sq, Skv, D, *, mode=None):
@@ -775,7 +786,9 @@ def _stream_bwd_plan(H, Sq, Skv, D, *, mode=None):
     accumulator + lse/delta rows, plus the double-buffered k/v chunk pair,
     per-chunk dk/dv output blocks and fp32 accumulators, plus the transient
     s/p/dp/ds tiles (14 B per (g, bsub, ck) cell, as in the one-shot bwd
-    model). None -> caller falls back to the online two-kernel backward.
+    model), bounded by ``STREAM_BWD_MAX_ROWS`` resident rows — the bound the
+    v5e compiler actually enforces. None -> caller falls back to the online
+    two-kernel backward.
     """
     mode = STREAM_BWD if mode is None else mode
     if mode in ("0", "off"):
@@ -783,10 +796,11 @@ def _stream_bwd_plan(H, Sq, Skv, D, *, mode=None):
     if D != 128 and mode != "all":
         return None
     best = None
+    D = max(D, 128)  # VMEM rows are lane-padded
     for g in range(min(H, 8), 0, -1):
-        if H % g:
+        if H % g or g * Sq * D > STREAM_BWD_MAX_ROWS * 128:
             continue
-        for bsub in (512, 256):
+        for bsub in (512, 256) if D == 128 else (512,):
             if bsub > Sq or Sq % bsub:
                 continue
             ck = 512  # keeps per-chunk dots MXU-sized (see _oneshot_num_chunks)
@@ -1125,9 +1139,9 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        # Non-TPU backends run the identical kernel body interpreted — the
-        # parity tests exercise this exact code path on CPU.
-        interpret=jax.default_backend() != "tpu",
+        # On cpu the identical kernel body runs interpreted — the parity
+        # tests exercise this exact code path.
+        interpret=not backend.on_tpu(),
     )(page_table, positions, q, k_pages, v_pages)
 
 
@@ -1169,7 +1183,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions,
     GQA is served natively: KV heads stay folded (H % Hkv == 0), queries
     are grouped per KV head. ``impl``: "auto" picks the Pallas page-table
     kernel on TPU and the gather-based XLA path elsewhere; "pallas"/"xla"
-    force (the Pallas kernel runs interpreted off-TPU — that is the
+    force (the Pallas kernel runs interpreted on cpu — that is the
     parity-test configuration).
     """
     B, H, D = q.shape
@@ -1181,8 +1195,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions,
     sm_scale = 1.0 / math.sqrt(D)
     page_table = page_table.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
-    if impl == "pallas" or (impl == "auto"
-                            and jax.default_backend() == "tpu"):
+    if impl == "pallas" or (impl == "auto" and backend.on_tpu()):
         return _paged_decode_pallas(q, k_pages, v_pages, page_table,
                                     positions, sm_scale)
     return _paged_decode_xla(q, k_pages, v_pages, page_table, positions,

@@ -24,9 +24,9 @@ cotangent the reference chain's AD produces (equivalence-tested in
 tests/test_moe_router.py). A Pallas backward is a chip-A/B follow-up; the
 [T, E] recompute is tiny next to the expert FFNs.
 
-On non-TPU backends the kernel runs in interpret mode (numerically the same
-program), so CPU tests/dryruns validate the real kernel body — the same
-``pallas_compat`` route ``_stream_bwd`` took. Output layouts are kept at
+On the ``cpu`` platform the kernel runs in interpret mode (numerically the
+same program), so CPU tests/dryruns validate the real kernel body
+(``ops/backend.py``). Output layouts are kept at
 their logical shapes (``[T, k]``, ``[T, 1]``); lane-padding them for Mosaic
 is part of the chip A/B, not correctness.
 """
@@ -40,7 +40,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_training_example_tpu.ops import pallas_compat  # noqa: F401
+from jax.sharding import PartitionSpec as P
+
+from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
+from pytorch_distributed_training_example_tpu.ops import backend
 
 
 def _block_tokens(n_tokens: int) -> int:
@@ -99,7 +102,7 @@ def _fused_router_call(logits, top_k: int):
         (Tp, E), logits.dtype).at[:T].set(logits)
     kernel = functools.partial(_router_kernel, top_k=top_k, n_tokens=T,
                                block_tokens=bt, num_experts=E)
-    gate, idx, lse, pm = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(Tp // bt,),
         in_specs=[pl.BlockSpec((bt, E), lambda i: (i, 0))],
@@ -118,10 +121,12 @@ def _fused_router_call(logits, top_k: int):
         # The pm accumulator needs the grid walked in order.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        # Non-TPU backends run the identical kernel body interpreted — the
-        # CPU-validation route (pallas_compat) the flash kernels use.
-        interpret=jax.default_backend() != "tpu",
-    )(logits_p)
+        interpret=not backend.on_tpu(),
+    )
+    # probs_mean accumulates over ALL tokens, so under a mesh every device
+    # runs the kernel over the whole (small) [T, E] logits: P() specs.
+    gate, idx, lse, pm = mesh_lib.manual_call(
+        call, logits_p, in_specs=P(), out_specs=P())
     return gate[:T], idx[:T], lse[:T, 0], pm[0]
 
 

@@ -34,10 +34,9 @@ Backward is a ``custom_vjp``:
   accumulates into it before the block index moves on — segment-wise
   accumulation with no atomics and no ``[E, Tk]`` masks.
 
-On non-TPU backends both kernels run in interpret mode (numerically the
-same program), so CPU tests and dryruns validate the real kernel bodies —
-the same ``pallas_compat`` route the flash and fused-router kernels take.
-fp32 accumulation everywhere (``preferred_element_type``); outputs are
+On the ``cpu`` platform both kernels run in interpret mode (numerically the
+same program), so CPU tests and dryruns validate the real kernel bodies
+(``ops/backend.py``). fp32 accumulation everywhere (``preferred_element_type``); outputs are
 cast to the input dtype, gradients to the primal dtypes. Tile sizes are
 powers of two down to 8 rows — Mosaic-friendly at bench shapes; lane-dim
 (128) padding of small test shapes is interpret-mode territory and part
@@ -54,7 +53,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_training_example_tpu.ops import pallas_compat  # noqa: F401
+from pytorch_distributed_training_example_tpu.ops import backend
 
 
 def _block_rows(n_rows: int, num_experts: int) -> int:
@@ -153,9 +152,7 @@ def _gmm_call(x_pad, w, tile_expert, bt: int, out_dtype):
         # block resident instead of re-fetching it.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        # Non-TPU backends run the identical kernel body interpreted — the
-        # CPU-validation route (pallas_compat) the flash kernels use.
-        interpret=jax.default_backend() != "tpu",
+        interpret=not backend.on_tpu(),
     )(tile_expert, x_pad, w)
 
 
@@ -200,7 +197,7 @@ def _gmm_dw_call(x_pad, g_pad, tile_expert, tile_first, num_experts: int,
         out_shape=jax.ShapeDtypeStruct((num_experts, d, f), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=jax.default_backend() != "tpu",
+        interpret=not backend.on_tpu(),
     )(tile_expert, tile_first, x_pad, g_pad)
 
 

@@ -392,16 +392,18 @@ class GroupedExpertFFN(nn.Module):
                             (self.num_experts, self.ffn_dim, d), self.param_dtype)
         ep = _ep_degree(self.ep_dispatch, self.num_experts, x_sorted.shape[0])
         if ep == 1:
+            # Replicated execution: every device runs all experts over the
+            # whole sorted array (P() specs — GSPMD cannot partition the
+            # Mosaic kernel, see mesh_lib.manual_call).
             with jax.named_scope("moe_experts_gmm"):
-                return gmm_lib.grouped_ffn(x_sorted, w_up.astype(self.dtype),
-                                           w_down.astype(self.dtype), starts,
-                                           counts)
+                return mesh_lib.manual_call(
+                    gmm_lib.grouped_ffn, x_sorted, w_up.astype(self.dtype),
+                    w_down.astype(self.dtype), starts, counts,
+                    in_specs=P(), out_specs=P())
         # Sharded EP execution: manual over 'expert' only; the other mesh
         # axes are unmentioned (the sorted array is replicated over the
         # batch axes exactly like the r14 path — shard_map's transpose
         # handles the unmentioned-axis cotangents, grads oracle-tested).
-        from pytorch_distributed_training_example_tpu.ops import (
-            pallas_compat as _compat)  # noqa: F401  jax.shard_map shim
         mesh = mesh_lib.current_mesh()
         a2a_impl = os.environ.get(EP_A2A_IMPL_ENV, "native")
         R = x_sorted.shape[0] // ep
@@ -506,7 +508,7 @@ def routing_stats(expert_idx, num_experts: int, capacity: int) -> RoutingStats:
     # Routing index vectors are O(E) and O(k·T) ints — pin them replicated
     # so sharding propagation (backward from the expert-sharded dispatch)
     # can never turn `starts[sorted_e]` into a sharded-operand gather
-    # (miscompiled by the jax 0.4.x SPMD partitioner; see MoEBlock._combine).
+    # (an SPMD-partitioner miscompile guard; see MoEBlock._combine).
     counts = mesh_lib.constrain(counts, P(None))
     starts = mesh_lib.constrain(starts, P(None))
     pos_sorted = (jnp.arange(k * T, dtype=jnp.int32) - starts[sorted_e])
@@ -733,7 +735,7 @@ class MoEBlock(nn.Module):
             # Replicate the slot table before the combine gather. Every
             # token needs rows from every expert, so GSPMD must all-gather
             # the [E·C, d] outputs over 'expert' here regardless; making it
-            # explicit also sidesteps a jax 0.4.x SPMD partitioner
+            # explicit also sidesteps an SPMD partitioner
             # miscompile for gathers with sharded operands (wrong values,
             # reproduced in tests/test_moe_sort_dispatch.py's EP suite).
             out_pad = mesh_lib.constrain(out_pad, P(None, None))
@@ -762,7 +764,7 @@ class MoEBlock(nn.Module):
             x_sorted = tokens[tok_flat].astype(self.dtype)       # [kT, d]
             # Pin the sorted layout: replicated for the single-program
             # kernel (pallas_call does not partition under GSPMD, and the
-            # pin also sidesteps the jax 0.4.x sharded-operand gather
+            # pin also sidesteps the sharded-operand gather
             # miscompile — see _combine); expert-sliced for the sharded EP
             # paths, matching the shard_map in_specs so GSPMD feeds the
             # manual region without a reshard.

@@ -49,8 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_distributed_training_example_tpu.ops import pallas_compat  # noqa: F401
-
 from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 
 

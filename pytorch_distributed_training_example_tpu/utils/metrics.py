@@ -80,13 +80,13 @@ def topk_correct(logits, labels, ks=(1, 5), mask=None):
 # ---------------------------------------------------------------------------
 
 #: Peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
+#: A TPU kind that is not in the tables is an error, never a default.
 PEAK_FLOPS = {
     "tpu v4": 275e12,
     "tpu v5 lite": 197e12,  # v5e
     "tpu v5": 459e12,       # v5p
     "tpu v5p": 459e12,
     "tpu v6 lite": 918e12,  # trillium
-    "cpu": 1e12,            # nominal; CPU MFU is not meaningful
 }
 
 #: HBM bandwidth per chip (GB/s) — the other roofline axis.
@@ -96,7 +96,6 @@ PEAK_HBM_GBPS = {
     "tpu v5": 2765.0,       # v5p
     "tpu v5p": 2765.0,
     "tpu v6 lite": 1640.0,  # trillium
-    "cpu": 100.0,
 }
 
 
@@ -112,24 +111,29 @@ def finalize_eval_sums(sums: dict) -> dict:
     return {k.removesuffix("_sum"): v / count for k, v in sums.items()}
 
 
-def peak_hbm_gbps(device=None) -> float:
+def _peak(table: dict, device) -> float | None:
+    """``table``'s entry for ``device``; None on platform ``cpu`` (device
+    utilization is not measured there); an unknown kind raises."""
     if device is None:
         device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
     kind = device.device_kind.lower()
-    for key, val in PEAK_HBM_GBPS.items():
+    for key, val in table.items():
         if key in kind:
             return val
-    return PEAK_HBM_GBPS["cpu"]
+    raise ValueError(
+        f"no published peak for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to utils/metrics.py with "
+        "its source instead of measuring against a default")
 
 
-def peak_flops_per_chip(device=None) -> float:
-    if device is None:
-        device = jax.devices()[0]
-    kind = device.device_kind.lower()
-    for key, val in PEAK_FLOPS.items():
-        if key in kind:
-            return val
-    return PEAK_FLOPS["cpu"]
+def peak_hbm_gbps(device=None) -> float | None:
+    return _peak(PEAK_HBM_GBPS, device)
+
+
+def peak_flops_per_chip(device=None) -> float | None:
+    return _peak(PEAK_FLOPS, device)
 
 
 def training_flops_per_example(fwd_flops: float) -> float:
@@ -138,9 +142,13 @@ def training_flops_per_example(fwd_flops: float) -> float:
 
 
 def mfu(examples_per_sec_per_chip: float, fwd_flops_per_example: float,
-        device=None) -> float:
+        device=None) -> float | None:
+    """Model FLOP/s utilization; None ("not measured") on platform cpu."""
+    peak = peak_flops_per_chip(device)
+    if peak is None:
+        return None
     achieved = examples_per_sec_per_chip * training_flops_per_example(fwd_flops_per_example)
-    return achieved / peak_flops_per_chip(device)
+    return achieved / peak
 
 
 def transformer_flops_per_token(n_params: int, seq_len: int, n_layers: int,

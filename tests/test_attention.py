@@ -483,7 +483,8 @@ def test_auto_dispatch_is_per_direction(monkeypatch):
     forwards stream (online), non-causal auto forwards take one-shot when
     a plan exists, and auto backwards take one-shot whenever the bwd plan
     fits. Long-context backwards fall back to the streaming one-pass
-    backward at D=128 (r6) and to the online kernel pair elsewhere.
+    backward at D=128 where the v5e compiler admits it (S<=4096) and to
+    the online kernel pair elsewhere (S=8192: refused on the chip).
     Kernels are stubbed so this asserts the routing, not the math
     (covered elsewhere)."""
     calls = []
@@ -505,27 +506,36 @@ def test_auto_dispatch_is_per_direction(monkeypatch):
     q4 = jnp.zeros((1, 4096, 16, 64), jnp.bfloat16)  # bwd plan infeasible, D=64
     F._vjp_bwd(True, 1024, 1024, "auto", None, (q4, q4, q4, "o", "l"),
                jnp.zeros_like(q4))
-    q8 = jnp.zeros((1, 8192, 16, 128), jnp.bfloat16)  # D=128 long context
+    q4k = jnp.zeros((1, 4096, 16, 128), jnp.bfloat16)  # D=128 long context
+    F._vjp_bwd(True, 1024, 1024, "auto", None, (q4k, q4k, q4k, "o", "l"),
+               jnp.zeros_like(q4k))
+    # forced online must never take the streaming path
+    F._vjp_bwd(True, 1024, 1024, "online", None, (q4k, q4k, q4k, "o", "l"),
+               jnp.zeros_like(q4k))
+    # S=8192/D=128: the streaming plan is over the chip's scoped VMEM
+    q8 = jnp.zeros((1, 8192, 16, 128), jnp.bfloat16)
     F._vjp_bwd(True, 1024, 1024, "auto", None, (q8, q8, q8, "o", "l"),
                jnp.zeros_like(q8))
-    # forced online must never take the streaming path
-    F._vjp_bwd(True, 1024, 1024, "online", None, (q8, q8, q8, "o", "l"),
-               jnp.zeros_like(q8))
     assert calls == ["online_fwd", "oneshot_fwd", "oneshot_bwd",
-                     "online_bwd", "stream_bwd", "online_bwd"], calls
+                     "online_bwd", "stream_bwd", "online_bwd",
+                     "online_bwd"], calls
 
 
 def test_stream_bwd_plan_thresholds():
     """Lock the streaming-backward admission map (r6): engages only where
     the dense one-shot bwd plan is infeasible AND D=128 (the dedicated
     long-context round; PDTX_STREAM_BWD="all" widens, "0" kills)."""
-    # the S=8192 contract shape: full-Sq residency fits at (G=1, bsub=256)
-    assert F._stream_bwd_plan(16, 8192, 8192, 128) == (1, 256, 512)
-    # S=4096/D=128 (bwd one-shot infeasible there too): fatter subtiles fit
+    # S=8192: the byte model admitted (1, 256, 512), which the v5e compiler
+    # counts at 16.75 MB and more against 16 MB of scoped VMEM — refused on
+    # the chip, so not admitted (tests/test_chip_compile.py compiles it).
+    assert F._stream_bwd_plan(16, 8192, 8192, 128) is None
+    # S=4096/D=128 (bwd one-shot infeasible there too): the largest admitted
     assert F._stream_bwd_plan(16, 4096, 4096, 128) == (1, 512, 512)
+    assert F._stream_bwd_plan(8, 2048, 2048, 128) == (2, 256, 512)
     # D=64 keeps the measured online fallback unless widened explicitly
-    assert F._stream_bwd_plan(16, 8192, 8192, 64) is None
-    assert F._stream_bwd_plan(16, 8192, 8192, 64, mode="all") == (1, 512, 512)
+    assert F._stream_bwd_plan(16, 4096, 4096, 64) is None
+    assert F._stream_bwd_plan(16, 4096, 4096, 64, mode="all") == (1, 512, 512)
+    assert F._stream_bwd_plan(16, 8192, 8192, 64, mode="all") is None
     # kill switch
     assert F._stream_bwd_plan(16, 8192, 8192, 128, mode="0") is None
     # sub-chunk sequences have nothing to stream
@@ -556,13 +566,14 @@ def test_stream_bwd_parity_d128_interpret(causal):
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.slow  # interpret-mode S=8192: minutes on the CPU CI host
+@pytest.mark.slow  # interpret-mode S=4096: minutes on the CPU CI host
 @pytest.mark.parametrize("causal", [False, True])
-def test_stream_bwd_parity_s8192_interpret(causal):
-    """The exact contract shape's (S=8192, D=128) plan, end to end."""
-    q, k, v = _qkv(B=1, S=8192, H=1, D=128)
-    plan = F._stream_bwd_plan(1, 8192, 8192, 128)
-    assert plan == (1, 256, 512)
+def test_stream_bwd_parity_s4096_interpret(causal):
+    """The largest shape the chip admits (S=4096, D=128), end to end
+    (S=8192's plan is over the v5e's scoped VMEM and no longer admitted)."""
+    q, k, v = _qkv(B=1, S=4096, H=1, D=128)
+    plan = F._stream_bwd_plan(1, 4096, 4096, 128)
+    assert plan == (1, 512, 512)
     g = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
     ref, vjp = jax.vjp(
         lambda *a: A.dot_product_attention(*a, causal=causal), q, k, v)

@@ -1,0 +1,139 @@
+"""The main path's kernels compile for the chip — checked without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a DESCRIBED
+v5e:2x2 topology (nothing runs; these are not chip results). That catches
+what interpret mode cannot: a kernel over its scoped-VMEM limit, a slice off
+the tiling, a Mosaic call GSPMD cannot partition. Real widths, a second or
+two each (the grouped FFN ~10 s); whole-step compiles stay out of the suite.
+
+Rules this file keeps (xdist runs several workers, each imports every test
+file, and only one process may own libtpu): the topology is described inside
+a module-scoped fixture that skips when it cannot be, never at import;
+shardings and meshes are built in fixtures/tests; compiles run in this
+process with the persistent cache off; code that asks
+``jax.default_backend()`` is steered by monkeypatch, not by a program option.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
+from pytorch_distributed_training_example_tpu.ops import (
+    attention as attn, flash_attention as fa, fused_router, grouped_matmul)
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip, with the persistent compile
+    cache off for the module: an entry compiled for a described device can
+    be written but not read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The program's "are we on the chip" (ops/backend.py) answers tpu."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compiled_text(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _sds(shape, sharding, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _grads(fn):
+    def total(*args):
+        return fn(*args).astype(jnp.float32).sum()
+    return jax.grad(total, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("B,S,H,Hkv,D", [
+    (24, 1024, 12, 12, 64),    # GPT-2 124M at the smoke's batch
+    (4, 2048, 8, 2, 128),      # D=128 GQA: streaming one-pass backward
+    (1, 4096, 8, 2, 128),      # largest S the streaming backward admits
+    (1, 8192, 8, 2, 128),      # llama3_8b's S: refused there -> online bwd
+])
+def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D):
+    q = _sds((B, S, H, D), one_chip)
+    kv = _sds((B, S, Hkv, D), one_chip)
+    _compiled_text(_grads(lambda q, k, v: fa.flash_attention(q, k, v, True)),
+                   q, kv, kv)
+    if S == 8192:
+        # The v5e compiler counts the streaming backward over its 16 MB of
+        # scoped VMEM here; the planner must not admit it.
+        assert fa._stream_bwd_plan(H, S, S, D) is None
+
+
+def test_padded_flash_vit_compiles(one_chip):
+    x = _sds((64, 197, 12, 64), one_chip)  # ViT-B/16
+    _compiled_text(_grads(attn.padded_flash_attention), x, x, x)
+
+
+@pytest.mark.parametrize("H,Hkv,D", [(32, 8, 128), (12, 12, 64)])
+def test_paged_decode_compiles(one_chip, as_tpu, H, Hkv, D):
+    B, page, pages, max_pages = 8, 16, 2048, 64
+    pool = _sds((pages, page, Hkv, D), one_chip)
+    _compiled_text(
+        fa.paged_decode_attention, _sds((B, H, D), one_chip), pool, pool,
+        _sds((B, max_pages), one_chip, jnp.int32),
+        _sds((B,), one_chip, jnp.int32))
+
+
+def test_grouped_ffn_fwd_bwd_compiles(one_chip, as_tpu):
+    T, E, d, ffn = 16384, 8, 1024, 2048
+    seg = _sds((E,), one_chip, jnp.int32)
+
+    def grads(x, w_up, w_down, starts, counts):
+        return _grads(lambda x, wu, wd: grouped_matmul.grouped_ffn(
+            x, wu, wd, starts, counts))(x, w_up, w_down)
+
+    _compiled_text(grads, _sds((T, d), one_chip), _sds((E, d, ffn), one_chip),
+                   _sds((E, ffn, d), one_chip), seg, seg)
+
+
+@pytest.mark.parametrize("E,k", [(8, 2), (64, 8)])
+def test_fused_router_compiles(one_chip, as_tpu, E, k):
+    _compiled_text(lambda logits: fused_router.fused_router(logits, k),
+                   _sds((8192, E), one_chip, jnp.float32))
+
+
+def test_flash_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
+    """Batch sharded over four chips: GSPMD cannot partition a Mosaic
+    kernel, so a bare call raises "wrap the call in a shard_map" — the
+    dispatcher must go through mesh_lib.manual_call."""
+    mesh = mesh_lib.build_mesh({"fsdp": 4}, devices=topo.devices)
+    x = _sds((24, 1024, 12, 64),
+             NamedSharding(mesh, P(("data", "fsdp"), None, None, None)))
+    with mesh_lib.use_mesh(mesh):
+        _compiled_text(
+            _grads(lambda q, k, v: attn.attention(q, k, v, causal=True)),
+            x, x, x)

@@ -122,3 +122,30 @@ def test_dp_shard_coordinate_mapping():
     import pytest
     with pytest.raises(ValueError, match="multiple of"):
         loader_lib.dp_shard(3, 2, 0)
+
+
+def test_threaded_loader_cannot_starve_the_awaited_batch():
+    """Look-ahead is granted in batch order: with an unordered permit pool,
+    workers of later batches could take every permit while the worker of
+    the batch the consumer waits for starved — the trainer hung at its
+    first batches (found rehearsing chip_smoke.py). Many fast batches make
+    the old race near-certain; the run must finish, in order."""
+    import sys
+    import threading
+
+    ds = SyntheticTokenDataset(num_examples=4096, seq_len=8, vocab_size=64)
+    ldr = DataLoader(ds, 2, num_workers=16)  # more workers than cores
+    got = []
+    t = threading.Thread(target=lambda: got.extend(b["tokens"] for b in ldr),
+                         daemon=True)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # switch threads often: provoke the race
+    try:
+        t.start()
+        t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not t.is_alive(), "threaded DataLoader deadlocked"
+    assert len(got) == 2048
+    serial = [b["tokens"] for b in DataLoader(ds, 2, num_workers=0)]
+    assert all(np.array_equal(a, b) for a, b in zip(got, serial))

@@ -13,7 +13,7 @@ Three layers of guarantee, mirroring how the sort-dispatch suite is built:
    telemetry must be the exact constant 0.0.
 3. Wiring: a full train step on the GQA llama_moe_tiny trunk under an
    fsdp x ep mesh matches the einsum oracle loss/params, an EP-mesh leg
-   guards the jax 0.4.x sharded-operand gather miscompile workaround,
+   guards the sharded-operand gather miscompile workaround,
    and the capacity-clamp warning fires (once) for the non-dropless
    paths it protects.
 """
@@ -357,8 +357,6 @@ def test_capacity_clamp_warns_once():
 
 def _a2a_blocks_run(mesh, x, impl):
     from pytorch_distributed_training_example_tpu.ops import collectives
-    from pytorch_distributed_training_example_tpu.ops import (
-        pallas_compat as _compat)  # noqa: F401  jax.shard_map shim
     from jax.sharding import PartitionSpec as P
 
     def body(xl):

@@ -5,7 +5,7 @@ MegaBlocks-style) replaces the one-hot/scatter formulations on perf grounds
 only, so it must reproduce them EXACTLY: same routing decisions, same
 capacity-overflow drops (priority: k=0 choices before k=1, earlier tokens
 first), same outputs and gradients. The EP suite at the bottom also guards
-the jax 0.4.x SPMD gather miscompile worked around in parallel/moe.py
+the SPMD gather miscompile worked around in parallel/moe.py
 (_combine/_sort_route pin gather operands replicated — without that, the
 partitioner silently produces wrong VALUES for gathers with sharded
 operands).
@@ -94,7 +94,7 @@ def test_bf16_combine_parity():
 
 def test_sort_expert_parallel_matches_replicated(devices):
     """Sort dispatch under an expert×data mesh == unsharded oracle, forward
-    AND grads. This is the regression guard for the jax 0.4.x sharded-
+    AND grads. This is the regression guard for the sharded-
     operand gather miscompile (see module docstring)."""
     block = moe_lib.MoEBlock(num_experts=4, ffn_dim=32, top_k=2,
                              capacity_factor=2.0, dispatch_impl="sort")

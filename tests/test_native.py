@@ -173,3 +173,23 @@ def test_native_dataloader_rejects_drop_last_false():
     with pytest.raises(ValueError, match="drop_last"):
         nl.NativeDataLoader(imgs, np.zeros(8), ShardedSampler(8), 4,
                             [0.5] * 3, [0.25] * 3, False, drop_last=False)
+
+
+def test_failed_build_never_loads_a_stale_library(monkeypatch, caplog):
+    """A library this process did not just build (or make did not find up
+    to date) is never loaded: the .so is untracked, so what lies there may
+    have an old C ABI. A failed make means the Python loader, loudly."""
+    import logging
+    import subprocess
+
+    assert os.path.exists(nl._LIB_PATH)  # available() above built it
+
+    def no_make(*a, **k):
+        raise subprocess.CalledProcessError(2, "make", stderr=b"g++: not found")
+
+    monkeypatch.setattr(nl, "_lib", None)
+    monkeypatch.setattr(nl.subprocess, "run", no_make)
+    with caplog.at_level(logging.ERROR, logger=nl.log.name):
+        assert nl._load() is None
+        assert not nl.available()
+    assert "NOT built" in caplog.text and "g++: not found" in caplog.text

@@ -5,6 +5,7 @@ import json
 import logging
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -106,11 +107,31 @@ def test_average_meter_and_throughput():
 def test_mfu_accounting():
     # 1000 img/s at 4.09 GFLOP fwd => 3x fwd+bwd = 12.27 TF/s achieved.
     class FakeDev:
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
     mfu = metrics_lib.mfu(1000.0, 4.09e9, device=FakeDev())
     assert mfu == pytest.approx(3 * 4.09e12 / 197e12)
     assert metrics_lib.peak_hbm_gbps(FakeDev()) == 819.0
+
+
+def test_peaks_unknown_tpu_raises_and_cpu_is_not_measured():
+    """No nominal default: an unknown TPU kind is an error, and on platform
+    cpu MFU / roofline peaks are "not measured" (None), never 1e12."""
+    class Unknown:
+        platform = "tpu"
+        device_kind = "TPU v99 mega"
+
+    for fn in (metrics_lib.peak_flops_per_chip, metrics_lib.peak_hbm_gbps):
+        with pytest.raises(ValueError, match="v99 mega"):
+            fn(Unknown())
+    with pytest.raises(ValueError, match="v99 mega"):
+        metrics_lib.mfu(1000.0, 4.09e9, device=Unknown())
+    cpu = jax.devices()[0]
+    assert cpu.platform == "cpu"
+    assert metrics_lib.peak_flops_per_chip(cpu) is None
+    assert metrics_lib.peak_hbm_gbps(cpu) is None
+    assert metrics_lib.mfu(1000.0, 4.09e9) is None
 
 
 def test_metric_logger_tensorboard_export(tmp_path):
@@ -208,3 +229,49 @@ def test_lr_schedules_reference_recipes():
 
     with pytest.raises(ValueError, match="lr_schedule"):
         optim.build_schedule(Config(lr_schedule="nope"), spe)
+
+
+# ---- no fallback that hides the device (entry points, off-chip) ----------
+
+import os  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_off_chip(*argv):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    return subprocess.run([sys.executable, *argv], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=300)
+
+
+def test_chip_smoke_fails_off_chip():
+    """chip_smoke.py has no CPU mode: platform != tpu -> non-zero exit and
+    ``"ok": false`` in the contract's last line, before any other work."""
+    res = _run_off_chip("chip_smoke.py")
+    assert res.returncode != 0
+    last = json.loads(res.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert "gpt2_train" not in res.stdout  # no phase ran
+
+
+def test_bench_fails_off_chip():
+    """bench.py is a measurement path: no TPU, no number."""
+    res = _run_off_chip("bench.py", "--steps", "1")
+    assert res.returncode != 0
+    assert "found platform 'cpu'" in res.stderr
+    assert "metric" not in res.stdout
+
+
+def test_launcher_import_initialises_no_backend():
+    """launch.py imports the package (whose __init__ imports jax), but must
+    never initialise a backend: on a TPU host that would take the chip from
+    the one child that needs it."""
+    res = _run_off_chip(
+        "-c", "import launch; from jax._src import xla_bridge as xb; "
+        "assert not xb.backends_are_initialized(), 'backend initialised'; "
+        "print('clean')")
+    assert res.returncode == 0, res.stderr
+    assert "clean" in res.stdout

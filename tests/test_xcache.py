@@ -210,3 +210,66 @@ def test_compile_cached_modes(tmp_path):
     compiled, mode = xcache.compile_cached(lowered, str(tmp_path), fields)
     assert mode == "warm"
     np.testing.assert_allclose(np.asarray(compiled(x)), np.asarray(x) * 3.0)
+
+
+# ---- compile-cache placement (the one helper every entry point uses) -----
+
+
+@pytest.fixture
+def _restore_cache_config():
+    keep = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    yield
+    jax.config.update("jax_compilation_cache_dir", keep[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", keep[1])
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_place_compile_cache_env_wins(monkeypatch, tmp_path,
+                                      _restore_cache_config, env_set):
+    """JAX_COMPILATION_CACHE_DIR set: no directory is set in code (the cache
+    can be placed from outside). Unset: ONE fixed directory inside the
+    checkout — never a temp name, a pid or the time."""
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert xcache.place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = xcache.place_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert got == xcache.DEFAULT_COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == got
+
+
+def test_only_the_helper_sets_the_cache_dir():
+    """grep: ``jax_compilation_cache_dir`` is updated in core/xcache.py only."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    hits = []
+    for root, dirs, files in os.walk(repo):
+        dirs[:] = [d for d in dirs if not d.startswith((".", "_"))
+                   and d not in ("chiprun_out", "tests")]
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path) as fh:
+                    if 'update("jax_compilation_cache_dir"' in fh.read():
+                        hits.append(os.path.relpath(path, repo))
+    assert hits == [os.path.join(
+        "pytorch_distributed_training_example_tpu", "core", "xcache.py")]
+
+
+def test_threefry_partitionable_is_the_default():
+    """Sharding-invariant RNG (same init bits on any mesh) is the installed
+    jax's default — nothing in main.py or conftest.py sets it any more."""
+    assert jax.config.jax_threefry_partitionable is True
+    key = jax.random.PRNGKey(0)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("x",))
+    sharded = jax.jit(lambda k: jax.random.normal(k, (8, 16)),
+                      out_shardings=NamedSharding(mesh, P("x")))(key)
+    np.testing.assert_array_equal(
+        np.asarray(sharded), np.asarray(jax.random.normal(key, (8, 16))))
