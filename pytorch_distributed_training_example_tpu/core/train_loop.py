@@ -77,7 +77,10 @@ class LanguageModelingTask:
     inputs = ("tokens",)
 
     def loss(self, logits, batch):
-        return metrics_lib.cross_entropy(logits, batch["targets"])
+        # the same region name as the model's head: the head's matmul and the
+        # loss over its logits fuse, and are read as one (``head_loss_ms``)
+        with jax.named_scope("head_loss"):
+            return metrics_lib.cross_entropy(logits, batch["targets"])
 
     def metrics(self, logits, batch):
         return {}
@@ -283,7 +286,9 @@ def make_train_step(task, grad_accum: int = 1, health: bool = False) -> Callable
             grads = state.scaler.unscale(grads)
             finite = precision_lib.all_finite(grads)
             new_scaler = state.scaler.update(finite)
-            candidate = state.apply_gradients(grads, scaler=new_scaler, **bn_update)
+            with jax.named_scope("optimizer"):
+                candidate = state.apply_gradients(
+                    grads, scaler=new_scaler, **bn_update)
             # GradScaler.step parity: on overflow skip the optimizer update
             # entirely (params AND optimizer state hold) but still advance
             # step/scaler so the schedule and backoff progress.
@@ -293,11 +298,14 @@ def make_train_step(task, grad_accum: int = 1, health: bool = False) -> Callable
                 opt_state=jax.tree.map(pick, candidate.opt_state, state.opt_state),
             )
         else:
-            new_state = state.apply_gradients(grads, **bn_update)
+            # clipping lives in the optax chain, so it is inside this region
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads, **bn_update)
+        with jax.named_scope("optimizer"):
+            grad_norm = global_norm(grads)
 
         metrics = {"loss": loss, **task_metrics,
-                   **task.metrics_from_loss(loss),
-                   "grad_norm": global_norm(grads)}
+                   **task.metrics_from_loss(loss), "grad_norm": grad_norm}
         if health:
             metrics.update(tele)
             metrics.update(telemetry_lib.health_pack(

@@ -9,7 +9,6 @@ are fetched every ``log_every`` steps.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import tempfile
@@ -57,10 +56,12 @@ class Trainer:
             if cfg.checkpoint_dir else None,
             tensorboard_dir=cfg.tensorboard_dir)
 
-        # Telemetry layer (utils/telemetry.py): span recorder + anomaly
-        # guard; also flips the compiled step's on-device health pack on.
-        # Created FIRST so the init/compile/restore phases are on the
-        # timeline too.
+        # The process's span recorder (utils/telemetry.py): always there,
+        # host-side only. The telemetry layer below (health pack in the
+        # compiled step, anomaly guard, goodput/timeline files, flight
+        # recorder) adopts it when cfg.telemetry is on. Both come FIRST so
+        # the init/compile/restore phases are on the timeline too.
+        self.recorder = telemetry_lib.recorder()
         self.telemetry = None
         self._watchdog: watchdog_lib.Watchdog | None = None
         self._compiled = False
@@ -115,9 +116,16 @@ class Trainer:
         finally:
             init_span.__exit__(None, None, None)
 
-    def _span(self, name: str):
-        return (self.telemetry.span(name) if self.telemetry is not None
-                else contextlib.nullcontext())
+    def _span(self, name: str, **kw):
+        return self.recorder.span(name, **kw)
+
+    def _context(self) -> dict:
+        """The watchdog's dump: where the loop was (the recorder's newest
+        spans, and which step compiled what), plus the telemetry layer's
+        snapshot (last health row, goodput) when that is on."""
+        snap = self.telemetry.snapshot() if self.telemetry is not None else {}
+        return {**snap, "last_spans": self.recorder.tail(16, kind="span"),
+                "last_compiles": self.recorder.tail(8, kind="compile")}
 
     def _init_workload(self, cfg: Config, mesh=None):
         self.mesh = mesh if mesh is not None else mesh_lib.build_mesh(
@@ -443,12 +451,14 @@ class Trainer:
         self.resumed = True
 
     def _save(self, epoch: int, step_offset: int | None = None,
-              block: bool = False):
+              block: bool = False) -> float:
+        """Returns the seconds its ``checkpoint_save`` span took (0.0 when
+        there was nothing to write)."""
         if self.checkpointer is None:
-            return
+            return 0.0
         step = int(jax.device_get(self.state.step))
         if step == self._last_saved_step:
-            return  # the step cadence already wrote this exact state
+            return 0.0  # the step cadence already wrote this exact state
         # Batch geometry travels with the checkpoint: a mid-epoch resume
         # fast-forwards the sampler by step_offset * global_batch samples,
         # which is only sample-exact if the restore run slices the epoch
@@ -472,7 +482,7 @@ class Trainer:
         # once more, then let a persistent failure propagate.
         for attempt in (1, 2):
             try:
-                with self._span("checkpoint_save"):
+                with self._span("checkpoint_save") as save_span:
                     if self._chaos is not None:
                         self._chaos.before_save()
                     self.checkpointer.save(self.state, step, extra=extra,
@@ -492,6 +502,7 @@ class Trainer:
             # shutdown summary, so the restart-tax merge in the next attempt
             # measures its gap from the last flush here.
             self.telemetry.write_artifacts()
+        return save_span.seconds
 
     # -- resilience --------------------------------------------------------
 
@@ -605,11 +616,11 @@ class Trainer:
         resilience.install()
         # One run-level watchdog spanning train AND eval (both loops beat it,
         # so a long eval never false-triggers); its timeout dump carries the
-        # telemetry snapshot — last step, last health row, goodput — when on.
+        # recorder's last spans, and the telemetry snapshot — last step,
+        # last health row, goodput — when that is on.
         self._watchdog = watchdog_lib.Watchdog(
             timeout_s=cfg.watchdog_timeout,
-            context_fn=(self.telemetry.snapshot
-                        if self.telemetry is not None else None)).start()
+            context_fn=self._context).start()
         try:
             for epoch in range(self.start_epoch, cfg.epochs):
                 self.train_epoch(epoch)
@@ -669,8 +680,7 @@ class Trainer:
         if own_watchdog:
             watchdog = watchdog_lib.Watchdog(
                 timeout_s=cfg.watchdog_timeout,
-                context_fn=(self.telemetry.snapshot
-                            if self.telemetry is not None else None)).start()
+                context_fn=self._context).start()
         try:
             self._train_epoch_inner(epoch, loss_m, tput, t_step, watchdog)
         finally:
@@ -699,119 +709,126 @@ class Trainer:
         it = self._make_step_iter(epoch, self.train_loader.start_batch)
         with mesh_lib.use_mesh(self.mesh):
             i = self.train_loader.start_batch
-            # Per-step host timings for the fleet layer (straggler detection,
-            # flight recorder): pure perf_counter deltas around phases the
-            # loop already runs — no extra device syncs at any cadence.
-            t_iter = time.perf_counter()
             while i < self.steps_per_epoch:
-                t_wait = time.perf_counter()
-                # Host wait on the input pipeline is its own badput bucket —
-                # with the prefetcher keeping up this span is ~0.
-                with self._span("input_wait"):
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        break
-                input_wait_s = time.perf_counter() - t_wait
-                watchdog.beat()
                 gstep = epoch * self.steps_per_epoch + i
-                if (self.fault_inject
-                        and jax.process_index() == self.fault_inject[0]
-                        and gstep == self.fault_inject[1]):
-                    # Simulated host failure: no cleanup, no flushes — the
-                    # hardest crash shape recovery must handle.
-                    log.error("fault injection: killing process %d at step %d",
-                              *self.fault_inject)
-                    os._exit(57)
                 if self.profile_range and gstep == self.profile_range[0]:
                     jax.profiler.start_trace(cfg.profile_dir)
-                if not self._compiled:
-                    # First dispatch ever traces + compiles; block so the
-                    # "compile" span covers it (dispatch is async — without
-                    # the block the cost would leak into later step spans).
-                    with self._span("compile"):
-                        metrics = self._first_dispatch(batch)
-                        jax.tree.map(lambda x: x.block_until_ready(), metrics)
-                    self._compiled = True
-                    if tele is not None:
-                        # Time-to-first-step: wall from process start to the
-                        # first completed optimizer step, cold vs warm.
-                        tele.mark_first_step(self._xcache_mode)
-                else:
-                    with self._span("step"):
-                        self.state, metrics = self.train_step(self.state, batch)
-                if self.profile_range and gstep + 1 == self.profile_range[1]:
-                    jax.tree.map(lambda x: x.block_until_ready(), metrics)
-                    jax.profiler.stop_trace()
-                    log.info("profile written to %s", cfg.profile_dir)
-                tput.update(cfg.global_batch_size)
-                is_log = ((i + 1) % cfg.log_every == 0
-                          or i + 1 == self.steps_per_epoch)
-                is_health = (tele is not None and cfg.health_every > 0
-                             and (i + 1) % cfg.health_every == 0)
-                if is_log or is_health:
-                    # The fetch drains the async step queue: that wait IS
-                    # device step time, so it stays in the "step" bucket.
-                    with self._span("step"):
-                        m = {k: float(v)
-                             for k, v in jax.device_get(metrics).items()}
-                    if tele is not None:
-                        # May raise AnomalyError (anomaly_action="abort")
-                        # after writing the diagnostic bundle.
-                        tripped = tele.observe(gstep, {"epoch": epoch, **m})
-                        if tripped and cfg.anomaly_action == "rollback":
-                            it.close()
-                            i = self._anomaly_rollback(epoch, i)
-                            it = self._make_step_iter(epoch, i)
-                            t_step = t_iter = time.perf_counter()
-                            continue
-                    if not is_log:
-                        self.metric_logger.write(kind="health", epoch=epoch,
-                                                 step=gstep, **m)
-                checkpoint_s = 0.0
-                if (cfg.checkpoint_every_steps
-                        and (gstep + 1) % cfg.checkpoint_every_steps == 0):
-                    # Step-cadence save: records (epoch, steps applied) so
-                    # resume fast-forwards to the exact next sample. Runs
-                    # even at the epoch boundary — eval may take a long
-                    # time, and the boundary state must be durable before
-                    # it; the per-epoch save then dedupes on step id.
-                    # AFTER the health fetch above: a state the anomaly
-                    # guard just flagged (rollback `continue`d, abort
-                    # raised) must never be the checkpoint a restart
-                    # resumes into.
-                    t_save = time.perf_counter()
-                    self._save(epoch, step_offset=i + 1)
-                    checkpoint_s = time.perf_counter() - t_save
-                if is_log:
-                    loss_m.update(m["loss"])
-                    lr = float(self.schedule(gstep))
-                    dt = (time.perf_counter() - t_step) / cfg.log_every
-                    t_step = time.perf_counter()
-                    rate = tput.rate
-                    per_chip = rate / max(jax.device_count(), 1)
-                    mfu = metrics_lib.mfu(per_chip, self.bundle.fwd_flops_per_example)
-                    log.info(
-                        "epoch %d step %d/%d loss %.4f lr %.2e %s/s %.1f "
-                        "(%.1f/chip) mfu %s %s",
-                        epoch, i + 1, self.steps_per_epoch, m["loss"], lr,
-                        self.bundle.examples_unit, rate, per_chip,
-                        "not measured" if mfu is None else f"{100 * mfu:.1f}%",
-                        " ".join(f"{k} {v:.4f}" for k, v in m.items()
-                                 if k not in ("loss",)),
-                    )
-                    self.metric_logger.write(kind="train", epoch=epoch, step=gstep,
-                                             lr=lr, rate=rate, mfu=mfu, **m)
-                    self._publish(gstep, epoch, m, dt)
-                now = time.perf_counter()
+                profiled = None
+                # Every span of this iteration carries gstep; the step
+                # marker groups them (and the device's work) in xprof.
+                self.recorder.step = gstep
+                with jax.profiler.StepTraceAnnotation("train", step_num=gstep), \
+                        self._span("iteration", bucket=None) as iteration:
+                    # Host wait on the input pipeline is its own badput
+                    # bucket — with the prefetcher keeping up this span is
+                    # ~0. data/prefetch.py splits it: loader_wait, device_put.
+                    with self._span("input_wait") as input_wait:
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            break
+                    watchdog.beat()
+                    if (self.fault_inject
+                            and jax.process_index() == self.fault_inject[0]
+                            and gstep == self.fault_inject[1]):
+                        # Simulated host failure: no cleanup, no flushes — the
+                        # hardest crash shape recovery must handle.
+                        log.error("fault injection: killing process %d at "
+                                  "step %d", *self.fault_inject)
+                        os._exit(57)
+                    if not self._compiled:
+                        # First dispatch ever traces + compiles; block so the
+                        # "compile" span covers it (dispatch is async — without
+                        # the block the cost would leak into later step spans).
+                        with self._span("compile"):
+                            metrics = self._first_dispatch(batch)
+                            jax.tree.map(lambda x: x.block_until_ready(),
+                                         metrics)
+                        self._compiled = True
+                        if tele is not None:
+                            # Time-to-first-step: wall from process start to
+                            # the first completed optimizer step, cold vs warm.
+                            tele.mark_first_step(self._xcache_mode)
+                    else:
+                        # The enqueue. Productive time: goodput's "step".
+                        with self._span("dispatch", bucket="step"):
+                            self.state, metrics = self.train_step(self.state,
+                                                                  batch)
+                    if self.profile_range and gstep + 1 == self.profile_range[1]:
+                        profiled = metrics
+                    tput.update(cfg.global_batch_size)
+                    is_log = ((i + 1) % cfg.log_every == 0
+                              or i + 1 == self.steps_per_epoch)
+                    is_health = (tele is not None and cfg.health_every > 0
+                                 and (i + 1) % cfg.health_every == 0)
+                    if is_log or is_health:
+                        # The fetch drains the async step queue: that wait IS
+                        # device step time, so it stays in the "step" bucket.
+                        with self._span("metrics_fetch", bucket="step"):
+                            m = {k: float(v)
+                                 for k, v in jax.device_get(metrics).items()}
+                        if tele is not None:
+                            # May raise AnomalyError (anomaly_action="abort")
+                            # after writing the diagnostic bundle.
+                            tripped = tele.observe(gstep, {"epoch": epoch, **m})
+                            if tripped and cfg.anomaly_action == "rollback":
+                                if profiled is not None:
+                                    self._stop_profile(profiled)
+                                it.close()
+                                i = self._anomaly_rollback(epoch, i)
+                                it = self._make_step_iter(epoch, i)
+                                t_step = time.perf_counter()
+                                continue
+                        if not is_log:
+                            self.metric_logger.write(kind="health", epoch=epoch,
+                                                     step=gstep, **m)
+                    checkpoint_s = 0.0
+                    if (cfg.checkpoint_every_steps
+                            and (gstep + 1) % cfg.checkpoint_every_steps == 0):
+                        # Step-cadence save: records (epoch, steps applied) so
+                        # resume fast-forwards to the exact next sample. Runs
+                        # even at the epoch boundary — eval may take a long
+                        # time, and the boundary state must be durable before
+                        # it; the per-epoch save then dedupes on step id.
+                        # AFTER the health fetch above: a state the anomaly
+                        # guard just flagged (rollback `continue`d, abort
+                        # raised) must never be the checkpoint a restart
+                        # resumes into.
+                        checkpoint_s = self._save(epoch, step_offset=i + 1)
+                    if is_log:
+                        loss_m.update(m["loss"])
+                        lr = float(self.schedule(gstep))
+                        dt = (time.perf_counter() - t_step) / cfg.log_every
+                        t_step = time.perf_counter()
+                        rate = tput.rate
+                        per_chip = rate / max(jax.device_count(), 1)
+                        mfu = metrics_lib.mfu(per_chip,
+                                              self.bundle.fwd_flops_per_example)
+                        log.info(
+                            "epoch %d step %d/%d loss %.4f lr %.2e %s/s %.1f "
+                            "(%.1f/chip) mfu %s %s",
+                            epoch, i + 1, self.steps_per_epoch, m["loss"], lr,
+                            self.bundle.examples_unit, rate, per_chip,
+                            "not measured" if mfu is None
+                            else f"{100 * mfu:.1f}%",
+                            " ".join(f"{k} {v:.4f}" for k, v in m.items()
+                                     if k not in ("loss",)),
+                        )
+                        self.metric_logger.write(kind="train", epoch=epoch,
+                                                 step=gstep, lr=lr, rate=rate,
+                                                 mfu=mfu, **m)
+                        self._publish(gstep, epoch, m, dt)
+                if profiled is not None:
+                    # after the step marker has closed, so the trace holds it
+                    self._stop_profile(profiled)
                 if tele is not None:
-                    # Feed the fleet layer every step: flight-recorder ring,
-                    # buffered step rows, live straggler monitor (warn-only).
-                    tele.observe_timing(gstep, total_s=now - t_iter,
-                                        input_wait_s=input_wait_s,
+                    # Feed the fleet layer every step from the spans just
+                    # closed: flight-recorder ring, buffered step rows, live
+                    # straggler monitor (warn-only). No extra clock reads.
+                    tele.observe_timing(gstep, total_s=iteration.seconds,
+                                        input_wait_s=input_wait.seconds,
                                         checkpoint_s=checkpoint_s,
                                         epoch=epoch)
-                t_iter = now
                 if self._chaos is not None:
                     self._chaos.step_boundary(gstep)
                 # Preemption poll — the ONLY place the SIGTERM flag is acted
@@ -820,6 +837,12 @@ class Trainer:
                 if resilience.preempted():
                     self._graceful_shutdown(epoch, i + 1)
                 i += 1
+
+    def _stop_profile(self, metrics):
+        """End the ``--profile-steps`` trace once its last step has run."""
+        jax.tree.map(lambda x: x.block_until_ready(), metrics)
+        jax.profiler.stop_trace()
+        log.info("profile written to %s", self.cfg.profile_dir)
 
     def _first_dispatch(self, batch):
         """Run the first step, consulting the persistent executable cache.

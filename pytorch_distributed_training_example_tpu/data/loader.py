@@ -19,6 +19,7 @@ from typing import Iterator
 import numpy as np
 
 from pytorch_distributed_training_example_tpu.data.sampler import ShardedSampler
+from pytorch_distributed_training_example_tpu.utils import telemetry
 
 # Debug/verification hook: when this env var names a file, every loader
 # appends one JSON line per YIELDED batch ({"epoch", "batch", "indices"}).
@@ -192,10 +193,12 @@ class DataLoader:
     def __iter__(self) -> Iterator[dict[str, np.ndarray]]:
         start = self.start_batch
         if self.num_workers <= 0:
+            rec = telemetry.recorder()
             for b, indices in enumerate(self._batches_of_indices(start), start):
                 _log_indices(self.sampler.epoch, b, indices)
-                yield _apply_batch_hook(self.sampler.epoch, b,
-                                        self._make_batch(indices))
+                with rec.span("make_batch", step=b, bucket=None):
+                    batch = self._make_batch(indices)
+                yield _apply_batch_hook(self.sampler.epoch, b, batch)
             return
         yield from self._threaded_iter(start)
 
@@ -211,6 +214,7 @@ class DataLoader:
         # starves. window >= num_workers keeps that batch always admitted.
         window = max(self.prefetch_batches, self.num_workers)
         consumed = 0
+        rec = telemetry.recorder()
         cond = threading.Condition()
         stop = threading.Event()
 
@@ -222,7 +226,10 @@ class DataLoader:
                 if stop.is_set():
                     return
                 try:
-                    out_q[b].put(self._make_batch(index_batches[b]))
+                    # on the worker's own thread; the batch index is its id
+                    with rec.span("make_batch", step=start + b, bucket=None):
+                        batch = self._make_batch(index_batches[b])
+                    out_q[b].put(batch)
                 except BaseException as e:  # re-raised in the consumer
                     out_q[b].put(_WorkerError(e))
                     return
@@ -235,6 +242,11 @@ class DataLoader:
             t.start()
         try:
             for b in range(len(index_batches)):
+                # finished batches waiting when the consumer asks for batch
+                # b: 0 means it is about to block on the workers
+                rec.count("loader.ready_depth",
+                          sum(not q.empty() for q in out_q[b:b + window]),
+                          step=start + b)
                 item = out_q[b].get()
                 if isinstance(item, _WorkerError):
                     raise RuntimeError(

@@ -17,6 +17,8 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding
 
+from pytorch_distributed_training_example_tpu.utils import telemetry
+
 
 def pad_batch(batch: dict, target: int) -> dict:
     """Pad a short final batch up to ``target`` rows and attach a 0/1 ``mask``.
@@ -61,18 +63,31 @@ def shard_batch(batch: dict, sharding: NamedSharding) -> dict:
 def device_prefetch(
     it: Iterable[dict], sharding: NamedSharding, lookahead: int = 2
 ) -> Iterator[dict]:
-    """Yield sharded device batches, keeping ``lookahead`` in flight."""
+    """Yield sharded device batches, keeping ``lookahead`` in flight.
+
+    Where the caller's ``next()`` waits is recorded on the process's span
+    recorder: ``loader_wait`` (blocked on the host loader) and ``device_put``
+    (the host-to-device put). Both run inside the trainer's ``input_wait``
+    span, which is their parent; neither counts toward goodput by itself.
+    """
+    rec = telemetry.recorder()
     it = iter(it)
     buf: collections.deque = collections.deque()
-    try:
-        for _ in range(lookahead):
-            buf.append(shard_batch(next(it), sharding))
-    except StopIteration:
-        pass
+
+    def fetch() -> bool:
+        with rec.span("loader_wait", bucket=None):
+            try:
+                batch = next(it)
+            except StopIteration:
+                return False
+        with rec.span("device_put", bucket=None):
+            buf.append(shard_batch(batch, sharding))
+        return True
+
+    more = True
+    for _ in range(lookahead):
+        more = more and fetch()
     while buf:
         out = buf.popleft()
-        try:
-            buf.append(shard_batch(next(it), sharding))
-        except StopIteration:
-            pass
+        more = more and fetch()
         yield out

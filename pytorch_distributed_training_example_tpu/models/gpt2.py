@@ -77,20 +77,27 @@ class Block(nn.Module):
     def __call__(self, x, train: bool):
         ln = lambda name: nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
                                        param_dtype=self.param_dtype, name=name)
+        # Region names for the profiler (jax.named_scope touches neither the
+        # parameter paths nor the numerics): norm | attn (the module's own
+        # name) | mlp; embed, head_loss and optimizer are named where they run.
+        with jax.named_scope("norm"):
+            h = ln("ln_1")(x)
         x = x + SelfAttention(self.num_heads, self.dtype, self.param_dtype,
                               self.dropout, self.attn_impl,
-                              name="attn")(ln("ln_1")(x), train)
+                              name="attn")(h, train)
         x = mesh_lib.constrain(x, _seq_rule("residual", self.sp))
-        h = ln("ln_2")(x)
+        with jax.named_scope("norm"):
+            h = ln("ln_2")(x)
         d = x.shape[-1]
-        h = nn.Dense(self.mlp_ratio * d, dtype=self.dtype,
-                     param_dtype=self.param_dtype, name="mlp_up")(h)
-        h = mesh_lib.constrain(h, _seq_rule("ffn_hidden"))
-        h = nn.gelu(h, approximate=True)
-        h = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
-                     name="mlp_down")(h)
-        if self.dropout > 0:
-            h = nn.Dropout(self.dropout, deterministic=not train)(h)
+        with jax.named_scope("mlp"):
+            h = nn.Dense(self.mlp_ratio * d, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="mlp_up")(h)
+            h = mesh_lib.constrain(h, _seq_rule("ffn_hidden"))
+            h = nn.gelu(h, approximate=True)
+            h = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
+                         name="mlp_down")(h)
+            if self.dropout > 0:
+                h = nn.Dropout(self.dropout, deterministic=not train)(h)
         x = x + h
         return mesh_lib.constrain(x, _seq_rule("residual", self.sp))
 
@@ -117,7 +124,8 @@ class GPT2(nn.Module):
                        param_dtype=self.param_dtype, name="wte")
         pos_emb = self.param("wpe", nn.initializers.normal(0.01),
                              (self.max_seq_len, self.d_model), self.param_dtype)
-        x = emb(tokens) + pos_emb[None, :S].astype(self.dtype)
+        with jax.named_scope("embed"):
+            x = emb(tokens) + pos_emb[None, :S].astype(self.dtype)
         x = mesh_lib.constrain(x, _seq_rule("residual", self.sp))
         if self.dropout > 0:
             x = nn.Dropout(self.dropout, deterministic=not train)(x)
@@ -132,15 +140,17 @@ class GPT2(nn.Module):
             x = block_cls(self.num_heads, self.mlp_ratio, self.dtype,
                           self.param_dtype, self.dropout, self.attn_impl,
                           self.sp, name=f"block_{i}")(x, train)
-        x = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ln_f")(x)
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="ln_f")(x)
         # Weight-tied LM head (GPT-2 convention). flax's attend promotes both
         # operands to the module dtype (bf16 under the bf16 policy), so the
         # matmul output is already bf16-rounded; logits_dtype only decides
         # what lands in HBM (metrics.cross_entropy upcasts fp32 per-element).
-        logits = emb.attend(x.astype(self.param_dtype))
-        logits = mesh_lib.constrain(logits, _seq_rule("logits", self.sp))
-        return logits.astype(self.logits_dtype)
+        with jax.named_scope("head_loss"):
+            logits = emb.attend(x.astype(self.param_dtype))
+            logits = mesh_lib.constrain(logits, _seq_rule("logits", self.sp))
+            return logits.astype(self.logits_dtype)
 
 
 #: Tensor-parallel rule table (path regex -> PartitionSpec). AUTO_FSDP

@@ -385,10 +385,12 @@ class Llama(nn.Module):
         x = RMSNorm(dtype=self.dtype, param_dtype=self.param_dtype,
                     name="final_norm")(x)
         x = mesh_lib.constrain(x, _seq_rule("residual", self.sp))
-        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                          param_dtype=self.param_dtype, name="lm_head")(x)
-        logits = mesh_lib.constrain(logits, _seq_rule("logits", self.sp))
-        return logits.astype(self.logits_dtype)
+        with jax.named_scope("head_loss"):
+            logits = nn.Dense(self.vocab_size, use_bias=False,
+                              dtype=self.dtype, param_dtype=self.param_dtype,
+                              name="lm_head")(x)
+            logits = mesh_lib.constrain(logits, _seq_rule("logits", self.sp))
+            return logits.astype(self.logits_dtype)
 
 
 TP_RULES = (

@@ -164,6 +164,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
                           causal=causal, block_q=block_q, block_kv=block_kv),
+        name="flash_fwd_online",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -313,6 +314,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_kv=block_kv),
+        name="flash_bwd_dq",
         grid=(B, H, Sq // block_q, Skv // block_kv),
         in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
         out_specs=qspec,
@@ -331,6 +333,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_kv=block_kv),
+        name="flash_bwd_dkv",
         grid=(B, H, Skv // block_kv, Sq // block_q),
         in_specs=[qspec2, kspec2, kspec2, qspec2, lspec2, lspec2],
         out_specs=(kspec2, kspec2),
@@ -576,6 +579,7 @@ def _oneshot_fwd(q, k, v, *, causal, plan, kv_len=None):
         scratch = []
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd_oneshot",
         scratch_shapes=scratch,
         grid=grid,
         in_specs=[
@@ -724,6 +728,7 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
                    pltpu.VMEM((G, Skv, D), jnp.float32)]
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_oneshot",
         grid=(B, H // G, Sq // bq),
         in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
         out_specs=(qspec, kspec, kspec),
@@ -898,6 +903,7 @@ def _stream_bwd(q, k, v, o, lse, g, *, causal, plan):
     dq, dk, dv = pl.pallas_call(
         functools.partial(_stream_bwd_kernel, sm_scale=sm_scale,
                           causal=causal, bsub=bsub, num_sub=Sq // bsub),
+        name="flash_bwd_stream",
         grid=(B, H // G, Skv // ck),
         in_specs=[qspec, cspec, cspec, qspec, lspec, lspec],
         out_specs=(qspec, cspec, cspec),
@@ -1134,6 +1140,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, positions,
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
                           page_size=page_size, num_kv_heads=num_kv_heads),
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

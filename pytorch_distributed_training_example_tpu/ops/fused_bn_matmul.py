@@ -93,6 +93,7 @@ def fused_stats_matmul(x, w, scale=None, bias=None, *, relu: bool = True,
     grid = (N // block_n,)
     y, stats = pl.pallas_call(
         functools.partial(_kernel, relu=relu, affine=affine),
+        name="fused_bn_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, K), lambda i: (i, 0)),

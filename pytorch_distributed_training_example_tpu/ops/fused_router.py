@@ -104,6 +104,7 @@ def _fused_router_call(logits, top_k: int):
                                block_tokens=bt, num_experts=E)
     call = pl.pallas_call(
         kernel,
+        name="fused_router",
         grid=(Tp // bt,),
         in_specs=[pl.BlockSpec((bt, E), lambda i: (i, 0))],
         out_specs=[

@@ -138,6 +138,7 @@ def _gmm_call(x_pad, w, tile_expert, bt: int, out_dtype):
     bf = _block_cols(f)
     return pl.pallas_call(
         _gmm_kernel,
+        name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(f // bf, Tp // bt),
@@ -180,6 +181,7 @@ def _gmm_dw_call(x_pad, g_pad, tile_expert, tile_first, num_experts: int,
     bf = _block_cols(f)
     return pl.pallas_call(
         _gmm_dw_kernel,
+        name="grouped_matmul_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             # Token tiles are the INNER grid dim: for each column block the

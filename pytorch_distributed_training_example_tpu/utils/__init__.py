@@ -1,1 +1,1 @@
-"""Config, logging/metrics, profiling, and guard-rail utilities."""
+"""Config, logging/metrics, telemetry (spans, goodput), and guard-rail utilities."""

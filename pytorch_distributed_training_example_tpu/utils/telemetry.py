@@ -12,30 +12,36 @@ interpretable):
 
 2. **Span recorder** (host side): ``SpanRecorder.span("input_wait")`` times
    named phases, mirrors them onto the device timeline via
-   ``jax.profiler.TraceAnnotation`` (so they line up with xplane traces), and
-   emits a Perfetto-loadable ``trace_events.json`` plus a goodput summary —
-   fraction of wall-clock in productive steps vs. each badput category
-   (PaLM-style goodput accounting, PAPERS.md).
+   ``jax.profiler.TraceAnnotation("pdtx.<name>")`` (so they line up with
+   xplane traces), keeps them in a bounded ring with their step, parent and
+   thread, and renders a Perfetto-loadable ``trace_events.json`` plus a
+   goodput summary — fraction of wall-clock in productive steps vs. each
+   badput category (PaLM-style goodput accounting, PAPERS.md). The process
+   has ONE recorder (``recorder()``), on whether or not ``cfg.telemetry``
+   is: it costs microseconds a step and never touches the compiled step.
+   ``cfg.telemetry`` switches on everything else here, and the files.
 
 3. **Anomaly guard**: on a non-finite health scalar, dump a diagnostic
    bundle (step, config, last-K metric rows, trigger row, goodput snapshot)
    and either raise :class:`AnomalyError` or skip-and-continue, per the
    ``--anomaly-action`` knob.
 
-The :class:`Telemetry` facade bundles all three for ``core/trainer.py``.
+The :class:`Telemetry` facade bundles all three for ``core/trainer.py``
+(adopting the process's recorder).
 """
 
 from __future__ import annotations
 
 import atexit
 import collections
-import contextlib
 import dataclasses
+import itertools
 import json
 import logging
 import os
+import threading
 import time
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +63,10 @@ PRODUCTIVE_SPANS = ("step", "prefill")
 #: start (the restart tax of an elastic/preemption relaunch).
 BADPUT_SPANS = ("init", "compile", "input_wait", "checkpoint_save",
                 "checkpoint_restore", "eval", "anomaly_dump", "restart")
+
+
+#: Every span is mirrored as ``jax.profiler.TraceAnnotation(PREFIX + name)``.
+ANNOTATION_PREFIX = "pdtx."
 
 
 class AnomalyError(RuntimeError):
@@ -132,36 +142,136 @@ def collect_sowed(tele_vars) -> dict[str, jax.Array]:
 # ---------------------------------------------------------------------------
 
 
+class Span(NamedTuple):
+    """One record of the recorder's ring. ``t0``/``t1`` are
+    ``time.perf_counter_ns()``; ``step`` is the global step the loop was in
+    (a loader-side record carries its batch index); ``parent`` is the ``id``
+    of the span that was open on the same thread when this one started."""
+
+    kind: str            # "span" | "counter" | "compile"
+    name: str
+    t0: int
+    t1: int
+    step: int | None
+    id: int
+    parent: int | None
+    thread: str
+    value: Any = None    # a counter's reading; a compile event's function
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1 - self.t0) / 1e9
+
+
+#: Ring size: a step leaves about a dozen records, so this holds the last
+#: few thousand steps (about 10 MB when full) however long the run.
+RING_RECORDS = 1 << 16
+
+_SAME = object()
+
+
+class _OpenSpan:
+    """The context manager ``SpanRecorder.span`` returns. After ``__exit__``
+    its ``seconds`` is the span's duration (the loop feeds the fleet layer's
+    per-step timings from the spans it has just closed)."""
+
+    __slots__ = ("rec", "name", "step", "bucket", "id", "parent", "t0",
+                 "seconds", "_ann", "_accrues")
+
+    def __init__(self, rec, name, step, bucket):
+        self.rec, self.name, self.step, self.bucket = rec, name, step, bucket
+        self.seconds = 0.0
+
+    def __enter__(self):
+        rec = self.rec
+        local = rec._local
+        stack = getattr(local, "stack", None)
+        if stack is None:
+            stack = local.stack = []
+            local.accruing = 0
+        if self.step is None:
+            self.step = rec.step
+        self.id = next(rec._ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        # Only the OUTERMOST span with a bucket accrues to goodput: a span
+        # without one (``iteration``, loader detail) is transparent.
+        self._accrues = self.bucket is not None and not local.accruing
+        if self._accrues:
+            local.accruing += 1
+        self._ann = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name,
+            **({} if self.step is None else {"step": self.step}))
+        self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        rec = self.rec
+        local = rec._local
+        local.stack.pop()
+        self.seconds = (t1 - self.t0) / 1e9
+        rec._events.append(Span(
+            "span", self.name, self.t0, t1, self.step, self.id, self.parent,
+            threading.current_thread().name))
+        if self._accrues:
+            local.accruing -= 1
+            rec._totals[self.bucket] += self.seconds
+            rec._counts[self.bucket] += 1
+        return False
+
+
 class SpanRecorder:
     """Times named host-side phases and renders them two ways.
 
     ``trace_events()`` is Chrome/Perfetto trace-event JSON (complete "X"
     events, microsecond timestamps); ``goodput()`` is the wall-clock
-    decomposition. Only OUTERMOST spans accrue to the goodput totals —
+    decomposition. Records (spans, counter readings, compile events) live in
+    a bounded ring, so the recorder can stay on for a run of any length;
+    the goodput totals are running sums and do not depend on the ring. Only
+    the OUTERMOST span that names a goodput bucket accrues to the totals —
     nested spans (e.g. a checkpoint restore inside init) still appear on
     the timeline but never double-count wall time. Each span also enters a
-    ``jax.profiler.TraceAnnotation`` so the phase shows up on xplane traces
-    captured by ``--profile-steps``.
+    ``jax.profiler.TraceAnnotation("pdtx.<name>")`` so the phase shows up,
+    beside the device, on xplane traces captured by ``--profile-steps``.
+
+    Appends come from the loop's thread and from loader workers
+    (``deque.append`` is atomic); the open-span stack is per thread.
     """
 
     def __init__(self, run_id: str = "", carry: dict | None = None,
-                 meta: dict | None = None):
+                 meta: dict | None = None, capacity: int = RING_RECORDS):
+        self._events: collections.deque = collections.deque(maxlen=capacity)
+        self._local = threading.local()
+        self._ids = itertools.count(1)
+        #: the global step the training loop is in: the default ``step`` of
+        #: every span and event opened until the loop sets the next one
+        self.step: int | None = None
+        self.adopt(run_id, carry=carry, meta=meta)
+
+    def adopt(self, run_id: str = "", carry: dict | None = None,
+              meta: dict | None = None) -> None:
+        """(Re)start the accounting: a new origin, empty ring and totals,
+        and a previous attempt's goodput carried in. ``Telemetry`` calls it
+        on the process's recorder, so that ``goodput.json`` decomposes the
+        wall-clock from the telemetry layer's start as it always has."""
         self.run_id = run_id
         # Monotonic<->wall anchor, captured at the same instant: ``ts``
         # values in the trace are microseconds after ``_start`` on THIS
         # host's monotonic clock; ``_wall_origin`` places that origin on the
         # shared wall clock so the merge CLI can align ranks whose monotonic
         # clocks have arbitrary offsets.
-        self._start = time.perf_counter()
+        self._start_ns = time.perf_counter_ns()
+        self._start = self._start_ns / 1e9
         self._wall_origin = time.time()
         self.meta = dict(meta or {})
         self._run_ids: list[str] = []
         self._attempt_ids: list[str] = []
-        self._events: list[dict] = []
+        self._events.clear()
         self._totals: collections.defaultdict = collections.defaultdict(float)
         self._counts: collections.defaultdict = collections.defaultdict(int)
-        self._depth = 0
-        self._pid = jax.process_index()
         # Cross-attempt carryover (elastic/preemption relaunch): ``carry`` is
         # a previous attempt's goodput.json dict. Its categories/counts/wall
         # seed the cumulative totals, and the gap between its ``ended_at``
@@ -208,10 +318,10 @@ class SpanRecorder:
                     self._base_counts.get("restart", 0) + 1)
                 self._base_wall += gap
                 # Timeline marker: the gap sits BEFORE this attempt's origin.
-                self._events.append({
-                    "name": "restart", "ph": "X", "cat": "telemetry",
-                    "ts": -int(gap * 1e6), "dur": int(gap * 1e6),
-                    "pid": self._pid, "tid": 0})
+                self._events.append(Span(
+                    "span", "restart", self._start_ns - int(gap * 1e9),
+                    self._start_ns, None, next(self._ids), None,
+                    threading.current_thread().name))
         if run_id and run_id not in self._run_ids:
             self._run_ids.append(run_id)
         aid = self.meta.get("attempt_id")
@@ -219,27 +329,47 @@ class SpanRecorder:
             self._attempt_ids.append(aid)
         self.meta.setdefault("attempt", self.attempts)
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        ann = jax.profiler.TraceAnnotation(f"telemetry/{name}")
-        ann.__enter__()
-        t0 = time.perf_counter()
-        self._depth += 1
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self._depth -= 1
-            ann.__exit__(None, None, None)
-            self._events.append({
-                "name": name, "ph": "X", "cat": "telemetry",
-                "ts": int((t0 - self._start) * 1e6),
-                "dur": int(dt * 1e6),
-                "pid": self._pid, "tid": self._depth,
-            })
-            if self._depth == 0:
-                self._totals[name] += dt
-                self._counts[name] += 1
+    def span(self, name: str, step: int | None = None, bucket=_SAME):
+        """A context manager timing ``name``. ``step`` defaults to the
+        loop's current step. ``bucket`` is the goodput category the span
+        accrues to (its own name unless given); ``None`` keeps it out of
+        goodput and lets the spans inside it accrue."""
+        return _OpenSpan(self, name, step,
+                         name if bucket is _SAME else bucket)
+
+    def count(self, name: str, value, step: int | None = None) -> None:
+        """One reading of the counter ``name``, taken where the work is."""
+        now = time.perf_counter_ns()
+        self._events.append(Span(
+            "counter", name, now, now, self.step if step is None else step,
+            next(self._ids), None, threading.current_thread().name, value))
+
+    def compile_event(self, name: str, seconds: float = 0.0,
+                      fun_name: str | None = None) -> None:
+        """A compilation (or a compilation-cache hit or miss) that just
+        ended, stamped with the step the loop is in."""
+        now = time.perf_counter_ns()
+        self._events.append(Span(
+            "compile", name, now - int(seconds * 1e9), now, self.step,
+            next(self._ids), None, threading.current_thread().name,
+            fun_name))
+
+    def records(self) -> list[Span]:
+        """A snapshot of the ring, oldest first."""
+        return list(self._events)
+
+    def tail(self, n: int, kind: str | None = None) -> list[dict]:
+        """The newest ``n`` records (of ``kind``) as plain dicts, for the
+        watchdog's dump."""
+        picked = [r for r in self.records() if kind is None or r.kind == kind]
+        return [{"kind": r.kind, "name": r.name, "step": r.step,
+                 "ms": round(r.seconds * 1e3, 3), "thread": r.thread,
+                 **({} if r.value is None else {"value": r.value})}
+                for r in picked[-n:]]
+
+    def clear(self) -> None:
+        """Empty the ring (tests; the totals stay)."""
+        self._events.clear()
 
     @property
     def wall_s(self) -> float:
@@ -259,9 +389,23 @@ class SpanRecorder:
         # ``fleetobs.trace_doc`` puts otherData FIRST (torn-write salvage
         # contract) and is shared with the serving-side RequestTrace so both
         # kinds of file merge under one clock-alignment rule.
+        pid = jax.process_index()
+        tids: dict[str, int] = {}
+        events = []
+        for r in self.records():
+            args = {k: v for k, v in (("step", r.step), ("id", r.id),
+                                      ("parent", r.parent),
+                                      ("value", r.value)) if v is not None}
+            events.append({
+                "name": r.name, "ph": "C" if r.kind == "counter" else "X",
+                "cat": "telemetry" if r.kind == "span" else r.kind,
+                "ts": (r.t0 - self._start_ns) // 1000,
+                "dur": (r.t1 - r.t0) // 1000,
+                "pid": pid, "tid": tids.setdefault(r.thread, len(tids)),
+                "args": args})
         return fleetobs.trace_doc(
             run_id=self.run_id, anchor_wall=self._wall_origin,
-            anchor_mono=self._start, events=self._events, meta=self.meta)
+            anchor_mono=self._start, events=events, meta=self.meta)
 
     def goodput(self) -> dict:
         """Wall-clock decomposition since construction (plus carried attempts).
@@ -339,6 +483,42 @@ class SpanRecorder:
             json.dump(self.trace_events(), fh)
         fleetobs.write_json_atomic(
             os.path.join(directory, f"goodput.{suffix}.json"), self.goodput())
+
+
+# The process's recorder: one, like a logger, whatever ``cfg.telemetry`` says.
+# The trainer, the input pipeline and the benchmark's readers all reach it
+# through ``recorder()``; ``Telemetry`` adopts it and builds no other.
+_process_recorder: SpanRecorder | None = None
+_process_lock = threading.Lock()
+
+
+def recorder() -> SpanRecorder:
+    """The process-wide :class:`SpanRecorder`, made on first use. From then
+    on it also hears jax's compile events (``jax.monitoring``), so the
+    timeline says which step a recompile fell into."""
+    global _process_recorder
+    if _process_recorder is None:
+        with _process_lock:
+            if _process_recorder is None:
+                rec = SpanRecorder()
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile_duration)
+                jax.monitoring.register_event_listener(_on_cache_event)
+                _process_recorder = rec
+    return _process_recorder
+
+
+def _on_compile_duration(event: str, seconds: float, **kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _process_recorder.compile_event("compile", seconds,
+                                        kw.get("fun_name"))
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _process_recorder.compile_event("cache_hit")
+    elif event == "/jax/compilation_cache/cache_misses":
+        _process_recorder.compile_event("cache_miss")
 
 
 def load_goodput(directory: str, rank: int = 0) -> dict | None:
@@ -536,8 +716,8 @@ class Telemetry:
             carry = None
         meta = {"host": self.host, "rank": self.rank,
                 "attempt_id": self.attempt_id}
-        self.recorder = SpanRecorder(run_id=self.run_id, carry=carry,
-                                     meta=meta)
+        self.recorder = recorder()
+        self.recorder.adopt(self.run_id, carry=carry, meta=meta)
         if carry:
             log.info(
                 "telemetry: merging goodput across supervisor attempts — "

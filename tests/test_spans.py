@@ -1,0 +1,324 @@
+"""The program's own instrumentation (ISSUE 26): one process-wide span
+recorder that is on whatever ``cfg.telemetry`` says, a name on every Pallas
+kernel, and the region names of the compiled step."""
+
+import ast
+import os
+import sys
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from pytorch_distributed_training_example_tpu.core import train_loop
+from pytorch_distributed_training_example_tpu.core.trainer import Trainer
+from pytorch_distributed_training_example_tpu.data import loader as loader_lib
+from pytorch_distributed_training_example_tpu.data import prefetch
+from pytorch_distributed_training_example_tpu.utils import telemetry
+from pytorch_distributed_training_example_tpu.utils.config import Config
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OPS = os.path.join(REPO, "pytorch_distributed_training_example_tpu", "ops")
+
+
+@pytest.fixture
+def ring():
+    """The process's recorder with an empty ring."""
+    rec = telemetry.recorder()
+    rec.clear()
+    rec.step = None
+    return rec
+
+
+def _tiny_lm(**kw):
+    return Config(**{**dict(
+        model="gpt2_tiny", dataset="lm", seq_len=32, epochs=1,
+        global_batch_size=8, steps_per_epoch=4, log_every=2, workers=2,
+        precision="fp32", warmup_epochs=0.0, telemetry=False,
+        eval_every_epochs=100, checkpoint_every_epochs=100), **kw})
+
+
+# -- the recorder -----------------------------------------------------------------
+
+
+def test_ring_is_bounded_and_goodput_does_not_depend_on_it():
+    rec = telemetry.SpanRecorder(capacity=8)
+    for i in range(20):
+        rec.step = i
+        with rec.span("step"):
+            pass
+        rec.count("depth", i)
+    records = rec.records()
+    assert len(records) == 8
+    assert records[-1].kind == "counter" and records[-1].value == 19
+    assert records[-2].name == "step" and records[-2].step == 19
+    assert rec.goodput()["counts"] == {"step": 20}   # running sums, no ring
+
+
+def test_a_span_without_a_bucket_is_transparent_to_goodput():
+    rec = telemetry.SpanRecorder()
+    with rec.span("iteration", bucket=None) as iteration:
+        with rec.span("input_wait") as wait:
+            with rec.span("loader_wait", bucket=None):
+                pass
+        with rec.span("dispatch", bucket="step"):
+            pass
+        with rec.span("metrics_fetch", bucket="step"):
+            with rec.span("init"):   # nested under a bucket: timeline only
+                pass
+    assert rec.goodput()["counts"] == {"input_wait": 1, "step": 2}
+    assert iteration.seconds >= wait.seconds > 0.0
+    by_name = {r.name: r for r in rec.records()}
+    assert by_name["iteration"].parent is None
+    assert by_name["input_wait"].parent == by_name["iteration"].id
+    assert by_name["loader_wait"].parent == by_name["input_wait"].id
+    assert by_name["init"].parent == by_name["metrics_fetch"].id
+
+
+def test_four_loader_workers_append_to_one_ring(ring):
+    """More workers than this test has cores to spare, a short switch
+    interval: every batch leaves exactly one ``make_batch`` span, stamped
+    with its batch index on its worker's thread, and one depth reading."""
+
+    class Rows:
+        def __len__(self):
+            return 64 * 4
+
+        def __getitem__(self, i):
+            return {"x": np.full(8, i, np.int32)}
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ldr = loader_lib.DataLoader(Rows(), 4, num_workers=4)
+        ldr.start_batch = 3
+        batches = list(ldr)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(batches) == 61
+    made = [r for r in ring.records() if r.name == "make_batch"]
+    assert sorted(r.step for r in made) == list(range(3, 64))
+    assert {r.thread for r in made}.isdisjoint({threading.current_thread().name})
+    assert len({r.thread for r in made}) == 4
+    assert all(r.parent is None and r.t1 >= r.t0 for r in made)
+    depth = [r for r in ring.records() if r.name == "loader.ready_depth"]
+    assert [r.step for r in depth] == list(range(3, 64))
+    assert all(0 <= r.value <= 4 for r in depth)
+    assert len({r.id for r in ring.records()}) == len(ring.records())
+
+
+def test_inline_loader_and_prefetch_record_their_spans(ring, devices):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    class Rows:
+        def __len__(self):
+            return 24
+
+        def __getitem__(self, i):
+            return {"x": np.full(8, i, np.float32)}
+
+    sharding = NamedSharding(Mesh(np.array(devices), ("data",)), P("data"))
+    ring.step = 7
+    got = list(prefetch.device_prefetch(
+        loader_lib.DataLoader(Rows(), 8, num_workers=0), sharding))
+    assert len(got) == 3
+    names = [r.name for r in ring.records()]
+    assert names.count("make_batch") == 3       # inline, on this thread
+    assert names.count("device_put") == 3
+    assert names.count("loader_wait") == 4      # the last finds the end
+    assert all(r.step == 7 for r in ring.records()
+               if r.name in ("loader_wait", "device_put"))
+    assert ring.goodput()["counts"] == {}       # detail, not goodput
+
+
+def test_compile_events_name_the_function_and_the_step(ring):
+    ring.step = 41
+
+    @jax.jit
+    def only_compiled_in_test_spans(x):
+        return x * 3 + 1
+
+    only_compiled_in_test_spans(np.arange(5.0))
+    events = [r for r in ring.records() if r.kind == "compile"
+              and r.name == "compile"]
+    mine = [r for r in events if "only_compiled_in_test_spans" in (r.value or "")]
+    assert len(mine) == 1 and mine[0].step == 41 and mine[0].t1 >= mine[0].t0
+    assert ring.tail(4, kind="compile")[-1]["step"] == 41
+
+
+def test_trace_events_keep_their_keys_with_counters_and_threads():
+    rec = telemetry.SpanRecorder(run_id="r")
+    with rec.span("step"):
+        rec.count("loader.ready_depth", 3, step=5)
+    events = rec.trace_events()["traceEvents"]
+    assert [e["ph"] for e in events] == ["C", "X"]
+    for e in events:
+        assert {"name", "ph", "cat", "ts", "dur", "pid", "tid", "args"} <= set(e)
+        assert isinstance(e["ts"], int) and isinstance(e["dur"], int)
+    assert events[0]["args"] == {"step": 5, "id": events[0]["args"]["id"],
+                                 "value": 3}
+
+
+# -- the trainer, telemetry off ------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """One epoch of the tiny LM with ``telemetry=False``, run from an empty
+    directory: ``(trainer, records, files the run left there)``."""
+    work = tmp_path_factory.mktemp("spans_cwd")
+    rec = telemetry.recorder()
+    rec.clear()
+    old = os.getcwd()
+    os.chdir(work)
+    try:
+        trainer = Trainer(_tiny_lm())
+        trainer.train_epoch(0)
+    finally:
+        os.chdir(old)
+    return trainer, rec.records(), sorted(os.listdir(work))
+
+
+def test_telemetry_off_still_records_spans_and_writes_nothing(trained):
+    trainer, records, files = trained
+    assert trainer.telemetry is None
+    assert trainer.recorder is telemetry.recorder()
+    names = {r.name for r in records if r.kind == "span"}
+    assert {"init", "iteration", "input_wait", "loader_wait", "device_put",
+            "make_batch", "compile", "dispatch", "metrics_fetch"} <= names
+    assert files == []
+
+
+def test_spans_of_one_iteration_share_its_step_and_hang_together(trained):
+    _, records, _ = trained
+    spans = [r for r in records if r.kind == "span"]
+    iterations = [r for r in spans if r.name == "iteration"]
+    assert [r.step for r in iterations][:4] == [0, 1, 2, 3]
+    loop_thread = iterations[0].thread
+    for iteration in iterations[1:4]:        # 0 compiles, the rest dispatch
+        inside = {r.name: r for r in spans if r.thread == loop_thread
+                  and iteration.t0 <= r.t0 and r.t1 <= iteration.t1
+                  and r is not iteration}
+        assert iteration.parent is None
+        assert {"input_wait", "loader_wait", "device_put",
+                "dispatch"} <= set(inside)
+        assert all(r.step == iteration.step for r in inside.values())
+        assert inside["input_wait"].parent == iteration.id
+        assert inside["dispatch"].parent == iteration.id
+        assert inside["loader_wait"].parent == inside["input_wait"].id
+        assert inside["device_put"].parent == inside["input_wait"].id
+    fetches = [r for r in spans if r.name == "metrics_fetch"]
+    assert [r.step for r in fetches] == [1, 3]   # log_every=2
+    workers = {r.thread for r in spans if r.name == "make_batch"}
+    assert workers and loop_thread not in workers
+
+
+def test_telemetry_off_step_is_the_program_it_was(trained):
+    """No health pack in the compiled step: the metrics are the three the LM
+    step has always returned, and the state comes back in its own avals."""
+    trainer, _, _ = trained
+    batch = next(iter(trainer._make_step_iter(0, 0)))
+    task = train_loop.get_task("lm")
+    state, metrics = jax.eval_shape(
+        train_loop.make_train_step(task, health=False), trainer.state, batch)
+    assert set(metrics) == {"loss", "perplexity", "grad_norm"}
+    assert all(v.shape == () and v.dtype == np.float32
+               for v in metrics.values())
+    avals = lambda tree: [(x.shape, x.dtype) for x in jax.tree.leaves(tree)]
+    assert avals(state) == avals(trainer.state)
+    with_pack = jax.eval_shape(
+        train_loop.make_train_step(task, health=True), trainer.state, batch)[1]
+    assert {"update_norm", "param_norm"} <= set(with_pack) - set(metrics)
+
+
+def test_watchdog_context_has_the_last_spans_without_telemetry(trained):
+    trainer, _, _ = trained
+    context = trainer._context()
+    assert "goodput" not in context              # the telemetry layer is off
+    assert context["last_spans"] and len(context["last_spans"]) <= 16
+    assert all({"name", "step", "ms", "thread"} <= set(s)
+               for s in context["last_spans"])
+    assert isinstance(context["last_compiles"], list)
+
+
+def test_dispatch_and_fetch_are_goodputs_step_bucket(tmp_path):
+    cfg = _tiny_lm(telemetry=True, checkpoint_dir=str(tmp_path), workers=0,
+                   health_every=0)
+    trainer = Trainer(cfg)
+    trainer.train_epoch(0)
+    assert trainer.telemetry.recorder is telemetry.recorder()
+    g = trainer.telemetry.emit("test")
+    # 1 compile, 3 dispatches, 2 fetches (log_every=2), 5 input waits (the
+    # last finds the end of the epoch)
+    assert g["counts"] == {"init": 1, "compile": 1, "step": 5,
+                           "input_wait": 4}
+    assert set(g["categories_s"]) == {"init", "compile", "step", "input_wait"}
+    assert (tmp_path / "goodput.json").exists()
+    assert (tmp_path / "trace_events.json").exists()
+
+
+# -- names inside the compiled step -----------------------------------------------------
+
+
+def _pallas_sites():
+    sites = []
+    for name in sorted(os.listdir(OPS)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(OPS, name)) as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                sites.append(pytest.param(name, node, id=f"{name}:{node.lineno}"))
+    return sites
+
+
+@pytest.mark.parametrize("file,call", _pallas_sites())
+def test_every_pallas_call_has_a_name(file, call):
+    named = [k.value for k in call.keywords if k.arg == "name"]
+    assert len(named) == 1, f"{file}:{call.lineno} pl.pallas_call has no name="
+    assert isinstance(named[0], ast.Constant) and isinstance(
+        named[0].value, str) and named[0].value.isidentifier()
+
+
+def test_pallas_names_are_one_per_kernel():
+    names = [k.value.value for p in _pallas_sites() for k in p.values[1].keywords
+             if k.arg == "name"]
+    assert len(names) == 11 and len(set(names)) == 11
+    assert {n for n in names if n.startswith("flash_fwd")} == {
+        "flash_fwd_online", "flash_fwd_oneshot"}
+    assert {n for n in names if n.startswith("flash_bwd")} == {
+        "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_oneshot",
+        "flash_bwd_stream"}
+
+
+def test_region_vocabulary_is_in_the_compiled_tiny_gpt2_step(trained):
+    """``lower().compile().as_text()`` keeps the scope path as ``op_name``.
+    The persistent cache is off around the compile: programs that differ only
+    in that metadata share a cache key, and an entry compiled before the
+    names existed would be served without them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    trainer, _, _ = trained
+    batch = next(iter(trainer._make_step_iter(0, 0)))
+    task = train_loop.get_task("lm")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(train_loop.make_train_step(task)).lower(
+            trainer.state, batch).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    import re
+
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    components = {part.rsplit("(", 1)[-1].split(")", 1)[0]
+                  for p in paths for part in p.split("/")}
+    assert {"embed", "attn", "mlp", "norm", "head_loss",
+            "optimizer"} <= components
+    assert any("block_0/norm/ln_1" in p for p in paths)
+    assert any("block_3/mlp/mlp_up" in p for p in paths)
