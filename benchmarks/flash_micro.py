@@ -9,6 +9,10 @@ Timing is SLOPE-BASED: chained iterations inside one ``lax.scan`` under
 jit, synced by a host transfer, measured at two trip counts; the
 per-iteration time is the slope, which cancels the host's fixed dispatch
 cost per executable call.
+
+``--kernels`` times each direction alone, by kernel name in a device trace
+(no transposes, no glue): what ``auto`` dispatches to, the forced impls, and
+the causal kernels at the planner's plan or at each ``--plans`` entry.
 """
 
 from __future__ import annotations
@@ -30,6 +34,83 @@ def attn_flops(B, H, S, D, causal=True, bwd=False):
     return f * (3.5 if bwd else 1.0)
 
 
+def flash_kernel_ms(fn, args, reps):
+    """{kernel name: device ms per call} of the ``flash_*`` kernels in
+    ``jit(fn)(*args)``, from a profiler trace of ``reps`` calls."""
+    import tempfile
+
+    import jax
+
+    from profile_step import collect_ops
+
+    run = jax.jit(fn)
+    jax.block_until_ready(run(*args))  # compile + warm
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for _ in range(reps):
+                out = run(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        ops, _, _ = collect_ops(trace_dir)
+    took = {}
+    for event, (ns, _) in ops.items():
+        # "%flash_fwd_online.3 = (...) custom-call(...)" -> flash_fwd_online
+        name = event.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
+        if name.startswith("flash_"):
+            took[name] = took.get(name, 0.0) + ns / reps / 1e6
+    if not took:
+        raise RuntimeError("no flash_* kernel in the device trace; it holds "
+                           + ", ".join(sorted(e[:40] for e in ops)[:8]))
+    return took
+
+
+def kernel_rows(fa, shapes, plans, reps):
+    """One row per (shape, impl, direction): the flash kernels that ran and
+    their device time. ``plans``: (G, T) pairs for the causal kernels; empty
+    means the planner's own choice per direction."""
+    import jax
+    import jax.numpy as jnp
+
+    blocks = (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_KV)
+    rows = []
+    for (B, H, S, D) in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, g = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
+                      for key in ks)
+        o, lse = jax.jit(lambda q, k, v: fa._fwd_dispatch(
+            q, k, v, True, *blocks, "online", None))(q, k, v)
+        fwd_args, bwd_args = (q, k, v), (q, k, v, o, lse, g)
+        cases = []
+        for impl in ("auto", "online", "oneshot"):
+            cases.append((impl, "fwd", fwd_args,
+                          lambda q, k, v, impl=impl: fa._fwd_dispatch(
+                              q, k, v, True, *blocks, impl, None)))
+            cases.append((impl, "bwd", bwd_args,
+                          lambda q, k, v, o, lse, g, impl=impl: fa._vjp_bwd(
+                              True, *blocks, impl, None, (q, k, v, o, lse), g)))
+        for bwd in (False, True):
+            own = fa._causal_plan(H, S, D, bwd=bwd)
+            for plan in plans or ([own] if own else []):
+                cases.append((
+                    "causal:%d:%d" % plan, "bwd" if bwd else "fwd",
+                    bwd_args if bwd else fwd_args,
+                    functools.partial(fa._causal_bwd if bwd else fa._causal_fwd,
+                                      plan=plan)))
+        for impl, tag, args, fn in cases:
+            row = {"impl": impl, "pass": tag, "B": B, "H": H, "S": S, "D": D}
+            try:
+                row["kernels"] = {n: round(ms, 4) for n, ms in
+                                  flash_kernel_ms(fn, args, reps).items()}
+                row["ms"] = round(sum(row["kernels"].values()), 4)
+            except Exception as e:  # a plan the compiler refuses
+                row["error"] = str(e).strip().splitlines()[-1][-300:]
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--peak-tflops", type=float, default=197.0)
@@ -49,6 +130,12 @@ def main():
     p.add_argument("--blocks", default="256,512,1024",
                    help="comma-separated candidate block sizes for "
                         "--block-sweep (applied to both axes)")
+    p.add_argument("--kernels", action="store_true",
+                   help="device time of each flash kernel by its name, "
+                        "forward and backward apart (see the docstring)")
+    p.add_argument("--plans", default="",
+                   help="with --kernels: comma-separated G:T plans for the "
+                        "causal kernels (default: the planner's choice)")
     args = p.parse_args()
 
     import jax
@@ -102,6 +189,14 @@ def main():
     if args.shapes:
         shapes = tuple(tuple(int(x) for x in s.split("x"))
                        for s in args.shapes.split(","))
+    if args.kernels:
+        plans = [tuple(int(x) for x in p.split(":"))
+                 for p in args.plans.split(",") if p]
+        rows = kernel_rows(fa, shapes, plans, args.iters)
+        if args.out:
+            with open(args.out, "w") as f:
+                json.dump({"rows": rows}, f, indent=1)
+        return
     rows = []
     for (B, H, S, D) in shapes:
         ks = jax.random.split(jax.random.PRNGKey(0), 3)

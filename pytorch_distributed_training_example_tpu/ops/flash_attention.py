@@ -288,6 +288,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _delta_rows(g, o):
+    """delta_i = rowsum(dO * O) as [B,H,S,LSE_LANES] float32: a cheap
+    elementwise+reduce that XLA fuses, broadcast over LSE_LANES to match the
+    kernels' tile layout."""
+    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
+                       o.astype(jnp.float32))
+    return jnp.broadcast_to(delta[..., None], (*delta.shape, LSE_LANES))
+
+
 def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
     """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse: [B,H,Sq]."""
     B, Sq, H, D = q.shape
@@ -296,11 +305,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
     block_kv = _fit_block(Skv, block_kv)
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, Skv, block_q, block_kv)
     sm_scale = 1.0 / math.sqrt(D)
-    # delta_i = rowsum(dO * O): cheap elementwise+reduce, fused by XLA;
-    # broadcast over LSE_LANES to match the kernel's tile layout.
-    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
-                       o.astype(jnp.float32))
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LSE_LANES))
+    delta = _delta_rows(g, o)
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
@@ -364,12 +369,10 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
 # optionally batch G heads per program to amortize DMA latency. Backward
 # computes dq/dk/dv in ONE pass (dk/dv accumulated across q blocks in VMEM).
 #
-# Tried and rejected (measured, same slope-timing as BENCH_FLASH_MICRO):
-# splitting causal work into a low-kv half + full-kv half (two kernel
-# variants, q_base mask offset) to skip the ~37% masked tile area — fwd
-# improved 6% but fwd+bwd REGRESSED 6% (2.76 vs 2.61 ms at GPT-2 shapes):
-# the dk/dv pad+add stitch, duplicate k/v reads, and extra launches cost
-# more than the skipped FLOPs. Dense causal tiles are the keeper here.
+# Skipping the masked causal work by two kernel VARIANTS (low-kv half +
+# full-kv half) lost to its dk/dv stitch in r3 (PERF.md section 6, PR 27);
+# skipping it INSIDE one program is what the chunked backward below and the
+# causal kernels further down do.
 # ---------------------------------------------------------------------------
 
 # Live-bytes budgets for one-shot plans. r3 ran 10 MB ("16 MB VMEM minus
@@ -394,14 +397,16 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
 ONESHOT_BUDGET = 13 * 1024 * 1024
 ONESHOT_FORCED_BUDGET = 17 * 1024 * 1024
 # (bwd, g, bq, Skv, D) plans above ONESHOT_BUDGET measured to compile and
-# win on v5e (PROFILE_GPT2.md r4 plan sweep: fastest GPT-2 backward,
-# 16.8 MB modeled).
+# win on v5e in bf16 (PROFILE_GPT2.md r4 plan sweep: fastest GPT-2
+# backward, 16.8 MB modeled; in float32 the compiler counts 17.5 MB
+# against its 16).
 ONESHOT_MEASURED_PLANS = {
     (True, 2, 512, 1024, 64),
 }
 
 
-def _oneshot_plan(H, Sq, Skv, D, *, bwd=False, forced=False):
+def _oneshot_plan(H, Sq, Skv, D, *, bwd=False, forced=False,
+                  dtype=jnp.bfloat16):
     """Pick (heads_per_program G, q_rows_per_program bq), or None.
 
     Cost model (bytes live per program): fwd keeps s/p f32 + p bf16 tiles
@@ -428,7 +433,8 @@ def _oneshot_plan(H, Sq, Skv, D, *, bwd=False, forced=False):
             if bq > Sq or Sq % bq or bq < min_bq:
                 continue
             if (cell * g * bq * Skv + g * kvbytes <= budget
-                    or (bwd, g, bq, Skv, D) in ONESHOT_MEASURED_PLANS):
+                    or (dtype == jnp.bfloat16
+                        and (bwd, g, bq, Skv, D) in ONESHOT_MEASURED_PLANS)):
                 # Maximize work per program; on ties prefer MORE HEADS over
                 # fatter q tiles — measured at B16·H12·S1024·D64 (r4 plan
                 # sweep): (2,512) runs fwd+bwd 1.87 ms vs 2.49 ms for
@@ -460,29 +466,18 @@ def _causal_mask_chunk(s, qi, block_q, k_base):
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
 
 
-# Per-direction switches for the chunked causal-skip path, set from e2e
-# GPT-2 A/B (3 reps each, PROFILE_GPT2.md r4 addendum): chunked BACKWARD
-# wins 117.2 -> 114.6 ms/step (exact lse-based chunks, ~25-37% of dot/exp
-# work skipped); chunked FORWARD loses ~5 ms (the online rescale chain +
-# scratch round-trips cost more than the skipped work at these shapes), so
-# the forward keeps the single dense-score formulation.
-CHUNK_FWD = False
-CHUNK_BWD = True
-
-
-def _oneshot_num_chunks(causal, kv_len, Skv, bq, *, enabled=True) -> int:
-    """kv chunks per program for the causal-skip path (1 = dense).
+def _oneshot_num_chunks(causal, kv_len, Skv) -> int:
+    """kv chunks per program of the causal one-shot backward (1 = dense).
 
     Causal one-shot programs waste ~(nq-1)/(2nq) of their dot/exp work on
-    fully-masked keys. r3 tried splitting into two kernel VARIANTS and the
-    dk/dv stitch + duplicate K/V reads lost more than the skipped FLOPs
-    (see "Tried and rejected" above). This splits WITHIN the program
-    instead: a python-unrolled chunk loop whose invisible chunks are
-    skipped via pl.when on the q-block index — no extra launches, no
-    stitch, K/V DMA unchanged. Chunks of 512 keys keep the per-chunk dots
-    MXU-sized; shapes that don't tile fall back to dense.
+    fully-masked keys. The backward splits the keys WITHIN the program: a
+    python-unrolled chunk loop whose invisible chunks are skipped via
+    pl.when on the q-block index (exact: probabilities come from the saved
+    lse). Chunks of 512 keys keep the per-chunk dots MXU-sized; shapes that
+    don't tile fall back to dense. The same scheme with an online softmax
+    lost as a forward in r4 (PERF.md section 6, PR 27).
     """
-    if not enabled or not causal or kv_len is not None:
+    if not causal or kv_len is not None:
         return 1
     for ck in (512, 256):
         if Skv % ck == 0 and Skv // ck > 1:
@@ -512,50 +507,6 @@ def _oneshot_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse, (*lse.shape[:2], LSE_LANES))
 
 
-def _oneshot_fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                                m_s, l_s, acc_s, *,
-                                sm_scale, block_q, num_chunks):
-    """Causal one-shot forward with in-program kv-chunk skipping: online
-    softmax over unrolled chunks (state in VMEM scratch so it crosses
-    pl.when region boundaries); chunks entirely above the diagonal are
-    never computed."""
-    qi = pl.program_id(2)
-    G, Skv, D = k_ref.shape[1], k_ref.shape[2], k_ref.shape[3]
-    ck = Skv // num_chunks
-    q = _mxu(q_ref[0])                            # [G, bq, D]
-
-    m_s[:] = jnp.full_like(m_s, NEG_INF)
-    l_s[:] = jnp.zeros_like(l_s)
-    acc_s[:] = jnp.zeros_like(acc_s)
-
-    for c in range(num_chunks):
-        @pl.when(c * ck < (qi + 1) * block_q)
-        def _chunk(c=c):
-            k_c = _mxu(k_ref[0, :, c * ck:(c + 1) * ck, :])
-            v_c = _mxu(v_ref[0, :, c * ck:(c + 1) * ck, :])
-            s = jax.lax.dot_general(q, k_c, (((2,), (2,)), ((0,), (0,))),
-                                    preferred_element_type=jnp.float32)
-            s = _causal_mask_chunk(s * sm_scale, qi, block_q, c * ck)
-            m_prev = m_s[:, :, :1]                # [G, bq, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_s[:, :, :1] = l_s[:, :, :1] * corr + jnp.sum(p, axis=2,
-                                                           keepdims=True)
-            pv = jax.lax.dot_general(p.astype(v_c.dtype), v_c,
-                                     (((2,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
-            acc_s[:] = acc_s[:] * corr + pv
-            m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-
-    l = jnp.maximum(l_s[:, :, :1], 1e-30)
-    o_ref[0] = (acc_s[:] / l).astype(o_ref.dtype)
-    # Only lane 0 of l_s carries the denominator — broadcast the lane-0
-    # lse over LSE_LANES rather than reading uninitialized lanes.
-    lse = m_s[:, :, :1] + jnp.log(l)
-    lse_ref[0] = jnp.broadcast_to(lse, (*lse.shape[:2], LSE_LANES))
-
-
 def _oneshot_fwd(q, k, v, *, causal, plan, kv_len=None):
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
@@ -563,25 +514,11 @@ def _oneshot_fwd(q, k, v, *, causal, plan, kv_len=None):
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    grid = (B, H // G, Sq // bq)
-    nc = _oneshot_num_chunks(causal, kv_len, Skv, bq, enabled=CHUNK_FWD)
-    if nc > 1:
-        kernel = functools.partial(
-            _oneshot_fwd_kernel_chunked, sm_scale=1.0 / math.sqrt(D),
-            block_q=bq, num_chunks=nc)
-        scratch = [pltpu.VMEM((G, bq, 128), jnp.float32),   # m
-                   pltpu.VMEM((G, bq, 128), jnp.float32),   # l
-                   pltpu.VMEM((G, bq, D), jnp.float32)]     # acc
-    else:
-        kernel = functools.partial(
-            _oneshot_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
-            causal=causal, block_q=bq, kv_len=kv_len)
-        scratch = []
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_oneshot_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
+                          causal=causal, block_q=bq, kv_len=kv_len),
         name="flash_fwd_oneshot",
-        scratch_shapes=scratch,
-        grid=grid,
+        grid=(B, H // G, Sq // bq),
         in_specs=[
             pl.BlockSpec((1, G, bq, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, G, Skv, D), lambda b, h, i: (b, h, 0, 0)),
@@ -702,9 +639,7 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     G, bq = plan
-    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
-                       o.astype(jnp.float32))
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LSE_LANES))
+    delta = _delta_rows(g, o)
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
@@ -712,7 +647,7 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
     qspec = pl.BlockSpec((1, G, bq, D), lambda b, h, i: (b, h, i, 0))
     kspec = pl.BlockSpec((1, G, Skv, D), lambda b, h, i: (b, h, 0, 0))
     lspec = pl.BlockSpec((1, G, bq, LSE_LANES), lambda b, h, i: (b, h, i, 0))
-    nc = _oneshot_num_chunks(causal, kv_len, Skv, bq, enabled=CHUNK_BWD)
+    nc = _oneshot_num_chunks(causal, kv_len, Skv)
     if nc > 1:
         kernel = functools.partial(
             _oneshot_bwd_kernel_chunked, sm_scale=1.0 / math.sqrt(D),
@@ -739,6 +674,202 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+    )(qt, kt, vt, dot, lse, delta)
+    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    return tr(dq), tr(dk), tr(dv)
+
+
+# ---------------------------------------------------------------------------
+# Causal kernels: causal self-attention whose whole head fits VMEM (GPT-2's
+# S=1024, D=64). A program holds G heads' whole q, k, v and walks q sub-tiles
+# of T rows in a Python loop, so sub-tile i's visible keys are the STATIC
+# prefix k[:(i+1)*T]: fully visible keys left of the diagonal tile are never
+# masked, keys right of it are never computed. Plain one-shot softmax per
+# sub-tile (no running max, no scratch); the backward walks the sub-tiles
+# from the last (whole prefix) to the first, so the first visit assigns the
+# float32 dk/dv accumulators and the later ones add to their static prefixes.
+# Work done at nt = S/T sub-tiles: (nt+1)/(2*nt) of the dense S^2 tile.
+# ---------------------------------------------------------------------------
+
+
+def _nt_dot(a, b):
+    """a [M, K] x b [N, K]^T -> [M, N] float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn_dot(a, b):
+    """a [K, M]^T x b [K, N] -> [M, N] float32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# (S, D) at which benchmarks/flash_micro.py --kernels on a v5e read both
+# causal kernels ahead of auto's earlier choice (online forward, chunked
+# one-shot backward); PERF.md section 6 has the table. Every reading is of
+# bf16. Other shapes, and other dtypes at any shape, keep the earlier kernels
+# until they are measured.
+CAUSAL_MEASURED = {(1024, 64), (2048, 64), (1024, 128), (2048, 128)}
+
+
+def _causal_plan(H, S, D, *, bwd=False):
+    """Pick (heads per program G, q sub-tile rows T) for the causal kernels,
+    or None.
+
+    T is the measured choice at GPT-2's shape (B24 H12 S1024 D64, ms a
+    layer): forward 0.73 at 256 against 0.82 at 128 (a 128-row sub-tile
+    streams too few rows past each MXU weight tile), backward 1.30 at 128
+    against 1.41 at 256 (its five matmuls gain more from the skipped work).
+    Bytes live per program, held to the one-shot planner's budget: the
+    double-buffered whole-head blocks of bf16 operands (an lse or delta
+    row pads to 128 lanes), the s/p (and dp/ds) tiles of one sub-tile, the
+    dk/dv accumulators. The v5e compiler admits every plan this model admits at
+    the shapes tried (S 1024/2048, D 64/128) and no more heads than it; the
+    model over-counts the S=2048/D=128 backward (16.5 MB; the compiler
+    12.3), which therefore keeps the chunked one-shot kernel.
+    """
+    tile = 128 if bwd else 256
+    if S % tile or S == tile:
+        return None
+    blocks = 2 * ((7 if bwd else 4) * S * D * 2
+                  + (2 if bwd else 1) * S * 128 * 4)
+    tiles = (14 if bwd else 10) * tile * S
+    acc = 2 * S * max(D, 128) * 4 if bwd else 0
+    for g in range(min(H, 8), 0, -1):
+        if H % g == 0 and g * (blocks + tiles) + acc <= ONESHOT_BUDGET:
+            return g, tile
+    return None
+
+
+def _auto_causal_plan(impl, causal, kv_len, Sq, Skv, H, D, dtype, *,
+                      bwd=False):
+    """The causal kernels' plan where auto dispatch takes them, else None:
+    causal self-attention over the whole sequence at a measured shape, in
+    the dtype that was measured and whose bytes ``_causal_plan`` counts
+    (float32 blocks are twice the bytes, as are fp16's operands once
+    ``_mxu`` has widened them: the v5e compiler refuses float32 at S=2048,
+    and at GPT-2's shape in the backward)."""
+    if (impl != "auto" or not causal or kv_len is not None or Sq != Skv
+            or (Sq, D) not in CAUSAL_MEASURED or dtype != jnp.bfloat16):
+        return None
+    return _causal_plan(H, Sq, D, bwd=bwd)
+
+
+def _mask_diagonal(s, lo):
+    """Causal mask for q rows [lo, lo+T) against the keys [0, lo+T): only
+    the last T columns (the diagonal tile) hold anything to mask."""
+    tile = s.shape[0]
+    visible = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+               >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+    diag = jnp.where(visible, s[:, lo:], NEG_INF)
+    return jnp.concatenate([s[:, :lo], diag], axis=1) if lo else diag
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, tile):
+    G, S = q_ref.shape[1], q_ref.shape[2]
+    for g in range(G):
+        for lo in range(0, S, tile):
+            n = lo + tile
+            q = _mxu(q_ref[0, g, lo:n, :])                    # [T, D]
+            k = _mxu(k_ref[0, g, :n, :])                      # [n, D]
+            v = _mxu(v_ref[0, g, :n, :])
+            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo)  # [T, n]
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            o = jnp.dot(p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+            o_ref[0, g, lo:n, :] = (o / l).astype(o_ref.dtype)
+            lse_ref[0, g, lo:n, :] = jnp.broadcast_to(
+                m + jnp.log(l), (tile, LSE_LANES))
+
+
+def _causal_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                       sm_scale, tile):
+    G, S = q_ref.shape[1], q_ref.shape[2]
+    for g in range(G):
+        for lo in reversed(range(0, S, tile)):
+            n = lo + tile
+            q = _mxu(q_ref[0, g, lo:n, :])                    # [T, D]
+            do = _mxu(do_ref[0, g, lo:n, :])
+            k = _mxu(k_ref[0, g, :n, :])                      # [n, D]
+            v = _mxu(v_ref[0, g, :n, :])
+            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo)  # [T, n]
+            p = jnp.exp(s - lse_ref[0, g, lo:n, :1])
+            dp = _nt_dot(do, v)
+            ds = (p * (dp - delta_ref[0, g, lo:n, :1]) * sm_scale
+                  ).astype(k.dtype)
+            dq_ref[0, g, lo:n, :] = jnp.dot(
+                ds, k, preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+            dv = _tn_dot(p.astype(do.dtype), do)              # [n, D]
+            dk = _tn_dot(ds, q)
+            if n == S:  # the whole prefix comes first: assigns every key row
+                dv_acc[:] = dv
+                dk_acc[:] = dk
+            else:
+                dv_acc[:n, :] += dv
+                dk_acc[:n, :] += dk
+        dk_ref[0, g] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, g] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="plan")
+def _causal_fwd(q, k, v, *, plan):
+    """Returns (out [B,S,H,D], lse [B,H,S,LSE_LANES]); K/V GQA-expanded.
+
+    Under ``jit`` so that a model's layers, and its later traces, share one
+    trace and one lowering of the unrolled kernel (it costs a few hundred
+    jnp calls; retraced per layer it added 7 s to the GPT-2 cell's set-up).
+    """
+    B, S, H, D = q.shape
+    G, tile = plan
+    qt = jnp.transpose(q, (0, 2, 1, 3))
+    kt = jnp.transpose(k, (0, 2, 1, 3))
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    spec = pl.BlockSpec((1, G, S, D), lambda b, h: (b, h, 0, 0))
+    lspec = pl.BlockSpec((1, G, S, LSE_LANES), lambda b, h: (b, h, 0, 0))
+    out, lse = pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
+                          tile=tile),
+        name="flash_fwd_causal",
+        grid=(B, H // G),
+        in_specs=[spec, spec, spec],
+        out_specs=(spec, lspec),
+        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, LSE_LANES), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+    )(qt, kt, vt)
+    return jnp.transpose(out, (0, 2, 1, 3)), lse
+
+
+@functools.partial(jax.jit, static_argnames="plan")
+def _causal_bwd(q, k, v, o, lse, g, *, plan):
+    """q,k,v,o,g: [B,S,H,D] (kv GQA-expanded); lse: [B,H,S,LSE_LANES]."""
+    B, S, H, D = q.shape
+    G, tile = plan
+    delta = _delta_rows(g, o)
+    qt = jnp.transpose(q, (0, 2, 1, 3))
+    kt = jnp.transpose(k, (0, 2, 1, 3))
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    dot = jnp.transpose(g, (0, 2, 1, 3))
+    spec = pl.BlockSpec((1, G, S, D), lambda b, h: (b, h, 0, 0))
+    lspec = pl.BlockSpec((1, G, S, LSE_LANES), lambda b, h: (b, h, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_causal_bwd_kernel, sm_scale=1.0 / math.sqrt(D),
+                          tile=tile),
+        name="flash_bwd_causal",
+        grid=(B, H // G),
+        in_specs=[spec, spec, spec, spec, lspec, lspec],
+        out_specs=(spec, spec, spec),
+        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, D), v.dtype)),
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32),   # dk, one head
+                        pltpu.VMEM((S, D), jnp.float32)],  # dv
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(qt, kt, vt, dot, lse, delta)
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     return tr(dq), tr(dk), tr(dv)
@@ -890,9 +1021,7 @@ def _stream_bwd(q, k, v, o, lse, g, *, causal, plan):
     Skv = k.shape[1]
     G, bsub, ck = plan
     sm_scale = 1.0 / math.sqrt(D)
-    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
-                       o.astype(jnp.float32))
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LSE_LANES))
+    delta = _delta_rows(g, o)
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
@@ -948,34 +1077,39 @@ def flash_attention(q, k, v, causal: bool = False,
 
 
 def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
-    """Auto dispatch is per-direction, from the r4 measured shape map
-    (BENCH_FLASH_MICRO.json):
+    """Auto dispatch is per direction, each from measurements on the chip:
 
-    - CAUSAL forward: the streaming online kernel wins at every measured
-      shape (0.54 vs 0.79 ms at B16·H12·S1024·D64; 0.72 vs 0.86 at
-      S2048; 1.37 vs 1.99 at S4096/D128) — its grid skips fully-masked
-      kv blocks and at default 1024-blocks the grid overhead that
-      motivated the one-shot kernels has collapsed to one program per
-      (batch, head, q-block).
-    - Backward: the one-shot chunked kernel wins whenever its plan fits
-      VMEM (2.37 vs 3.05 ms fwd+bwd at GPT-2 shapes); otherwise online.
+    - Causal self-attention (Sq == Skv, no kv_len) at a shape in
+      ``CAUSAL_MEASURED``: the causal kernels, both directions (PERF.md
+      section 6, PR 27: 0.73 vs 1.05 ms forward and 1.30 vs 1.94 backward
+      a layer at B24·H12·S1024·D64).
+    - Other causal forwards: the streaming online kernel (r4,
+      BENCH_FLASH_MICRO.json: 0.72 vs 0.86 ms one-shot at S2048; 1.37 vs
+      1.99 at S4096/D128). Its grid skips fully-masked kv blocks only where
+      S spans more than one 1024-block.
+    - Other backwards: the one-shot chunked kernel whenever its plan fits
+      VMEM; otherwise streaming (D=128) or online.
     - Non-causal forward: one-shot when a plan exists (no masked blocks
       for the online grid to skip, so fewer/fatter programs win).
 
-    The two kernels share the residual format (q,k,v,o + lse
-    [B,H,S,LSE_LANES]), so mixing directions is free. The r3/r4-early
-    all-or-nothing rule is superseded by these per-direction
-    measurements; forced impl="oneshot"/"online" still pin both sides.
+    All kernels share the residual format (q,k,v,o + lse
+    [B,H,S,LSE_LANES]), so mixing directions is free; forced
+    impl="oneshot"/"online" still pin both sides.
     """
     B, Sq, H, D = q.shape
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
+    cplan = _auto_causal_plan(impl, causal, kv_len, Sq, k.shape[1], H, D,
+                              q.dtype)
+    if cplan is not None:
+        return _causal_fwd(q, k, v, plan=cplan)
     plan = None
     if impl == "oneshot" or kv_len is not None:
-        plan = _oneshot_plan(H, Sq, k.shape[1], D, forced=impl == "oneshot")
+        plan = _oneshot_plan(H, Sq, k.shape[1], D, forced=impl == "oneshot",
+                             dtype=q.dtype)
     elif impl == "auto" and not causal:
-        plan = _oneshot_plan(H, Sq, k.shape[1], D)
+        plan = _oneshot_plan(H, Sq, k.shape[1], D, dtype=q.dtype)
     if plan is None and (impl == "oneshot" or kv_len is not None):
         raise ValueError(f"oneshot flash attention cannot tile "
                          f"Sq={Sq}, Skv={k.shape[1]}, D={D} within VMEM"
@@ -1005,11 +1139,14 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, res, g):
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
     plan = None
-    if impl in ("oneshot", "auto") or kv_len is not None:
+    cplan = _auto_causal_plan(impl, causal, kv_len, q.shape[1], ke.shape[1],
+                              H, q.shape[3], q.dtype, bwd=True)
+    if cplan is None and (impl in ("oneshot", "auto") or kv_len is not None):
         # auto: one-shot backward whenever its plan fits (see
         # _fwd_dispatch's dispatch-map docstring).
         plan = _oneshot_plan(H, q.shape[1], ke.shape[1], q.shape[3], bwd=True,
-                             forced=impl == "oneshot")
+                             forced=impl == "oneshot",
+                             dtype=q.dtype)
     if plan is None and (impl == "oneshot" or kv_len is not None):
         raise ValueError(
             f"oneshot flash attention backward cannot tile Sq={q.shape[1]}, "
@@ -1018,7 +1155,9 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, res, g):
             + ("; kv_len masking requires the one-shot kernels)"
                if kv_len is not None else "); use impl='auto' to fall back "
                "to the online kernels for such shapes"))
-    if plan is not None:
+    if cplan is not None:
+        dq, dk, dv = _causal_bwd(q, ke, ve, o, lse, g, plan=cplan)
+    elif plan is not None:
         dq, dk, dv = _oneshot_bwd(q, ke, ve, o, lse, g, causal=causal,
                                   plan=plan, kv_len=kv_len)
     else:

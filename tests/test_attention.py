@@ -318,11 +318,11 @@ def test_flash_eligible_shapes_trace(S, D):
 
 
 def test_oneshot_chunked_bwd_grads_interpret():
-    """The chunked causal-skip backward (engages at Skv >= 1024 when
-    CHUNK_BWD) must match the oracle exactly — invisible chunks skipped,
-    visible diagonal chunks masked per-chunk (r4 kernel)."""
-    assert F.CHUNK_BWD and not F.CHUNK_FWD  # measured defaults, r4
-    assert F._oneshot_num_chunks(True, None, 1024, 256) == 2
+    """The chunked causal-skip backward (forced one-shot, and auto outside
+    the causal kernels' measured shapes) must match the oracle exactly —
+    invisible chunks skipped, visible diagonal chunks masked per-chunk."""
+    assert F._oneshot_num_chunks(True, None, 1024) == 2
+    assert F._oneshot_num_chunks(True, 197, 1024) == 1
     q, k, v = _qkv(B=1, S=1024, H=2, D=16)
     g_ref = jax.grad(lambda *a: A.dot_product_attention(*a, causal=True).sum(),
                      argnums=(0, 1, 2))(q, k, v)
@@ -335,22 +335,101 @@ def test_oneshot_chunked_bwd_grads_interpret():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_oneshot_chunked_fwd_parity_interpret(monkeypatch):
-    """The chunked forward ships gated OFF (measured ~5 ms slower e2e,
-    PROFILE_GPT2.md r4) but must stay correct — including the lse output
-    all LSE_LANES wide — so flipping CHUNK_FWD is safe to re-measure."""
-    monkeypatch.setattr(F, "CHUNK_FWD", True)
-    q, k, v = _qkv(B=1, S=1024, H=2, D=16)
-    ref = A.dot_product_attention(q, k, v, causal=True)
+@pytest.mark.parametrize("Hkv", [4, 2])
+@pytest.mark.parametrize("tile", [128, 256])
+def test_causal_kernels_parity_interpret(monkeypatch, tile, Hkv):
+    """The causal kernels (static triangular sub-tiles) through
+    flash_attention's own dispatch, at each sub-tile size the planner
+    chooses (256 forward, 128 backward; both directions at both here),
+    with and without GQA: output, lse (every LSE_LANES lane equal and
+    finite: the other backwards read any of them) and all three grads."""
+    # float32 operands pin the comparison; auto plans only bf16 ones
+    monkeypatch.setattr(F, "_auto_causal_plan", lambda *a, **k: (2, tile))
+    ran = []
+    for name in ("_causal_fwd", "_causal_bwd"):
+        monkeypatch.setattr(F, name, lambda *a, _f=getattr(F, name), _n=name,
+                            **k: (ran.append(_n), _f(*a, **k))[1])
+    q, k, v = _qkv(B=1, S=1024, H=4, Hkv=Hkv, D=16)
+    g = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+    ref, vjp = jax.vjp(
+        lambda *a: A.dot_product_attention(*a, causal=True), q, k, v)
     with pltpu.force_tpu_interpret_mode():
-        out, lse = F._fwd_dispatch(q, k, v, True, 1024, 1024, "oneshot", None)
+        out, vjp_flash = jax.vjp(
+            lambda *a: F.flash_attention(*a, True), q, k, v)
+        grads = vjp_flash(g)
+        _, lse = F._fwd_dispatch(q, A._repeat_kv(k, 4), A._repeat_kv(v, 4),
+                                 True, 1024, 1024, "auto", None)
+    assert ran == ["_causal_fwd", "_causal_bwd", "_causal_fwd"]
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
-    # every lse lane must carry the same (real) value
+    for a, b in zip(vjp(g), grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
     lse = np.asarray(lse)
-    np.testing.assert_allclose(lse, lse[..., :1].repeat(lse.shape[-1], -1),
-                               rtol=0, atol=0)
-    assert np.isfinite(lse).all()
+    assert lse.shape == (1, 4, 1024, F.LSE_LANES) and np.isfinite(lse).all()
+    np.testing.assert_array_equal(lse, lse[..., :1].repeat(F.LSE_LANES, -1))
+
+
+def _stub_flash_kernels(monkeypatch):
+    """Every kernel entry point replaced by a recorder: routing, not math."""
+    calls = []
+    for name, out in (("_flash_fwd", ("o", "l")), ("_oneshot_fwd", ("o", "l")),
+                      ("_causal_fwd", ("o", "l")),
+                      ("_flash_bwd", ("q", "k", "v")),
+                      ("_oneshot_bwd", ("q", "k", "v")),
+                      ("_stream_bwd", ("q", "k", "v")),
+                      ("_causal_bwd", ("q", "k", "v"))):
+        monkeypatch.setattr(
+            F, name, lambda *a, _n=name, _o=out, **k: (calls.append(_n), _o)[1])
+    return calls
+
+
+def test_causal_kernels_dispatch(monkeypatch):
+    """GPT-2's shape (H=12, S=1024, D=64) takes the causal kernels in both
+    directions under auto; non-causal, kv_len, Sq != Skv, forced impls and
+    shapes nobody measured never do."""
+    assert F._causal_plan(12, 1024, 64) == (2, 256)
+    assert F._causal_plan(12, 1024, 64, bwd=True) == (2, 128)
+    calls = _stub_flash_kernels(monkeypatch)
+    q = jnp.zeros((1, 1024, 12, 64), jnp.bfloat16)
+    res = (q, q, q, "o", "l")
+    F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, res, q)
+    assert calls == ["_causal_fwd", "_causal_bwd"]
+    del calls[:]
+    half = q[:, :512]
+    p = jnp.zeros((1, 256, 12, 64), jnp.bfloat16)  # ViT's padded 197
+    for fwd_args, bwd_args in (
+            ((q, q, q, False, 1024, 1024, "auto", None),
+             (False, 1024, 1024, "auto", None, res, q)),
+            ((p, p, p, False, 1024, 1024, "auto", 197),
+             (False, 1024, 1024, "auto", 197, (p, p, p, "o", "l"), p)),
+            ((p, p, p, True, 1024, 1024, "auto", 197),
+             (True, 1024, 1024, "auto", 197, (p, p, p, "o", "l"), p)),
+            ((half, q, q, True, 1024, 1024, "auto", None),
+             (True, 1024, 1024, "auto", None, (half, q, q, "o", "l"), half)),
+            ((q, q, q, True, 1024, 1024, "online", None),
+             (True, 1024, 1024, "online", None, res, q)),
+            ((q, q, q, True, 1024, 1024, "oneshot", None),
+             (True, 1024, 1024, "oneshot", None, res, q))):
+        F._fwd_dispatch(*fwd_args)
+        F._vjp_bwd(*bwd_args)
+    odd = jnp.zeros((1, 1536, 12, 64), jnp.bfloat16)  # not in CAUSAL_MEASURED
+    # measured, and its bytes counted, in bf16 (_mxu widens fp16 in VMEM)
+    for x in (odd, q.astype(jnp.float32), q.astype(jnp.float16)):
+        F._fwd_dispatch(x, x, x, True, 1024, 1024, "auto", None)
+        F._vjp_bwd(True, 1024, 1024, "auto", None, (x, x, x, "o", "l"), x)
+    assert len(calls) == 18 and not any("causal" in c for c in calls), calls
+    # S=2048/D=128: measured ahead in both directions, but the backward's
+    # whole-head blocks are over the planner's budget -> chunked one-shot
+    del calls[:]
+    big = jnp.zeros((1, 2048, 16, 128), jnp.bfloat16)
+    assert F._causal_plan(16, 2048, 128) == (1, 256)
+    assert F._causal_plan(16, 2048, 128, bwd=True) is None
+    assert F._causal_plan(16, 4096, 128) is None
+    F._fwd_dispatch(big, big, big, True, 1024, 1024, "auto", None)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, (big, big, big, "o", "l"), big)
+    assert calls == ["_causal_fwd", "_oneshot_bwd"]
 
 
 def test_gqa_repeat():
@@ -462,6 +541,9 @@ def test_oneshot_plan_dispatch_thresholds():
     # r5 budget policy (ADVICE r4): the 16.8 MB GPT-2 backward plan is
     # admitted via the measured allowlist, not a >VMEM global cap...
     assert F._oneshot_plan(12, 1024, 1024, 64, bwd=True) == (2, 512)
+    # (measured in bf16: float32 operands keep to the budget)
+    assert F._oneshot_plan(12, 1024, 1024, 64, bwd=True,
+                           dtype=jnp.float32) == (2, 256)
     # ...so an unmeasured same-band plan (S=2048/D=64 (1,512) = 16.7 MB)
     # is no longer auto-admitted; the under-budget (1,256) is picked.
     assert F._oneshot_plan(16, 2048, 2048, 64, bwd=True) == (1, 256)
@@ -479,7 +561,8 @@ def test_oneshot_plan_dispatch_thresholds():
 
 
 def test_auto_dispatch_is_per_direction(monkeypatch):
-    """The measured r4 dispatch map must hold structurally: causal auto
+    """The measured r4 dispatch map must hold structurally outside the
+    causal kernels' shapes (test_causal_kernels_dispatch): causal auto
     forwards stream (online), non-causal auto forwards take one-shot when
     a plan exists, and auto backwards take one-shot whenever the bwd plan
     fits. Long-context backwards fall back to the streaming one-pass
@@ -487,18 +570,8 @@ def test_auto_dispatch_is_per_direction(monkeypatch):
     the online kernel pair elsewhere (S=8192: refused on the chip).
     Kernels are stubbed so this asserts the routing, not the math
     (covered elsewhere)."""
-    calls = []
-    monkeypatch.setattr(F, "_flash_fwd",
-                        lambda *a, **k: (calls.append("online_fwd"), ("o", "l"))[1])
-    monkeypatch.setattr(F, "_oneshot_fwd",
-                        lambda *a, **k: (calls.append("oneshot_fwd"), ("o", "l"))[1])
-    monkeypatch.setattr(F, "_flash_bwd",
-                        lambda *a, **k: (calls.append("online_bwd"), ("q", "k", "v"))[1])
-    monkeypatch.setattr(F, "_oneshot_bwd",
-                        lambda *a, **k: (calls.append("oneshot_bwd"), ("q", "k", "v"))[1])
-    monkeypatch.setattr(F, "_stream_bwd",
-                        lambda *a, **k: (calls.append("stream_bwd"), ("q", "k", "v"))[1])
-    q = jnp.zeros((1, 1024, 12, 64), jnp.bfloat16)
+    calls = _stub_flash_kernels(monkeypatch)
+    q = jnp.zeros((1, 1536, 12, 64), jnp.bfloat16)  # not in CAUSAL_MEASURED
     F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None)
     F._fwd_dispatch(q, q, q, False, 1024, 1024, "auto", None)
     res = (q, q, q, "o", "l")
@@ -516,9 +589,9 @@ def test_auto_dispatch_is_per_direction(monkeypatch):
     q8 = jnp.zeros((1, 8192, 16, 128), jnp.bfloat16)
     F._vjp_bwd(True, 1024, 1024, "auto", None, (q8, q8, q8, "o", "l"),
                jnp.zeros_like(q8))
-    assert calls == ["online_fwd", "oneshot_fwd", "oneshot_bwd",
-                     "online_bwd", "stream_bwd", "online_bwd",
-                     "online_bwd"], calls
+    assert calls == ["_flash_fwd", "_oneshot_fwd", "_oneshot_bwd",
+                     "_flash_bwd", "_stream_bwd", "_flash_bwd",
+                     "_flash_bwd"], calls
 
 
 def test_stream_bwd_plan_thresholds():

@@ -76,17 +76,35 @@ def _grads(fn):
     return jax.grad(total, argnums=(0, 1, 2))
 
 
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
+                         ids=["bf16", "fp32"])  # --precision bf16 / fp32
 @pytest.mark.parametrize("B,S,H,Hkv,D", [
     (24, 1024, 12, 12, 64),    # GPT-2 124M at the smoke's batch
-    (4, 2048, 8, 2, 128),      # D=128 GQA: streaming one-pass backward
+    (4, 2048, 12, 12, 64),     # causal kernels, one head a program
+    (8, 1024, 16, 4, 128),     # causal kernels at D=128, GQA
+    (4, 2048, 16, 16, 128),    # D=128: causal forward, chunked backward
+    (4, 2048, 8, 2, 128),      # the same under GQA
     (1, 4096, 8, 2, 128),      # largest S the streaming backward admits
     (1, 8192, 8, 2, 128),      # llama3_8b's S: refused there -> online bwd
 ])
-def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D):
-    q = _sds((B, S, H, D), one_chip)
-    kv = _sds((B, S, Hkv, D), one_chip)
-    _compiled_text(_grads(lambda q, k, v: fa.flash_attention(q, k, v, True)),
-                   q, kv, kv)
+def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
+    q = _sds((B, S, H, D), one_chip, dtype)
+    kv = _sds((B, S, Hkv, D), one_chip, dtype)
+    text = _compiled_text(
+        _grads(lambda q, k, v: fa.flash_attention(q, k, v, True)), q, kv, kv)
+    # The causal kernels are in the text exactly where dispatch plans them
+    # for this dtype: the v5e compiler has accepted their whole-head blocks
+    # (float32 blocks are twice the bytes, and keep the earlier kernels).
+    plans = [fa._auto_causal_plan("auto", True, None, S, S, H, D, dtype,
+                                  bwd=bwd) for bwd in (False, True)]
+    assert [name in text for name in ("flash_fwd_causal", "flash_bwd_causal")
+            ] == [plan is not None for plan in plans]
+    if dtype == jnp.float32:
+        assert plans == [None, None]
+    elif (S, D) == (1024, 64):  # GPT-2's shape: both, and nothing else
+        assert plans == [(2, 256), (2, 128)]
+        assert "flash_fwd_online" not in text
+        assert "flash_bwd_oneshot" not in text
     if S == 8192:
         # The v5e compiler counts the streaming backward over its 16 MB of
         # scoped VMEM here; the planner must not admit it.
@@ -95,7 +113,10 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D):
 
 def test_padded_flash_vit_compiles(one_chip):
     x = _sds((64, 197, 12, 64), one_chip)  # ViT-B/16
-    _compiled_text(_grads(attn.padded_flash_attention), x, x, x)
+    text = _compiled_text(_grads(attn.padded_flash_attention), x, x, x)
+    # non-causal with kv_len: the one-shot kernels, as before the causal ones
+    assert "flash_fwd_oneshot" in text and "flash_bwd_oneshot" in text
+    assert "_causal" not in text
 
 
 @pytest.mark.parametrize("H,Hkv,D", [(32, 8, 128), (12, 12, 64)])
