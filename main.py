@@ -23,9 +23,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--distributed", action="store_true",
                    help="multi-host mode: rendezvous via jax.distributed.initialize "
                         "(the init_process_group('nccl') equivalent)")
-    p.add_argument("--config", default=None,
+    p.add_argument("--config", "--preset", default=None, dest="config",
                    help="preset name (resnet18_cifar10, resnet50_imagenet, "
-                        "vit_b16_imagenet, gpt2_124m, llama3_8b)")
+                        "vit_b16_imagenet, gpt2_124m, llama3_8b, "
+                        "granite4_h_micro_share)")
     p.add_argument("--model", default=None)
     p.add_argument("--dataset", default=None)
     p.add_argument("--data-path", default=None)

@@ -41,6 +41,15 @@ def build_schedule(cfg: Config, steps_per_epoch: int) -> optax.Schedule:
     return main
 
 
+def adam_b2(cfg: Config) -> float:
+    """AdamW's second-moment decay, chosen by the model's task and not by a
+    substring of its name: 0.95 for every language model (the GPT-3 / Llama
+    recipe), optax's 0.999 for the rest (ViT)."""
+    from pytorch_distributed_training_example_tpu.models import registry
+
+    return 0.95 if registry.create_model(cfg.model).task == "lm" else 0.999
+
+
 def build_optimizer(cfg: Config, steps_per_epoch: int):
     """Returns ``(tx, schedule)``; schedule is also used for logging lr."""
     schedule = build_schedule(cfg, steps_per_epoch)
@@ -57,7 +66,7 @@ def build_optimizer(cfg: Config, steps_per_epoch: int):
                 cfg.weight_decay, mask=_wd_mask))
     elif cfg.optimizer == "adamw":
         parts.append(optax.adamw(
-            schedule, b1=0.9, b2=0.95 if "llama" in cfg.model or "gpt" in cfg.model else 0.999,
+            schedule, b1=0.9, b2=adam_b2(cfg),
             weight_decay=cfg.weight_decay, mask=_wd_mask,
         ))
     else:

@@ -152,6 +152,20 @@ class LlamaAttention(nn.Module):
                                name="out")(out)
 
 
+def swiglu_mlp(h, ffn_dim: int, dtype, param_dtype):
+    """SwiGLU MLP, ``down(silu(gate(h)) * up(h))``, without biases. Called
+    inside a block's ``nn.compact`` method: the three ``Dense`` layers become
+    that block's own ``gate``/``up``/``down`` (shared by the Llama and the
+    Granite hybrid blocks)."""
+    dense = lambda feat, name: nn.Dense(
+        feat, use_bias=False, dtype=dtype, param_dtype=param_dtype, name=name)
+    gate = dense(ffn_dim, "gate")(h)
+    up = dense(ffn_dim, "up")(h)
+    gate = mesh_lib.constrain(gate, _seq_rule("ffn_hidden"))
+    up = mesh_lib.constrain(up, _seq_rule("ffn_hidden"))
+    return dense(h.shape[-1], "down")(nn.silu(gate) * up)
+
+
 class LlamaBlock(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -182,7 +196,6 @@ class LlamaBlock(nn.Module):
                                                             decode_ctx)
         x = mesh_lib.constrain(x, _seq_rule("residual", self.sp))
         h = rn("mlp_norm")(x)
-        d = x.shape[-1]
         if self.num_experts > 0:
             from pytorch_distributed_training_example_tpu.parallel.moe import MoEBlock
 
@@ -212,15 +225,8 @@ class LlamaBlock(nn.Module):
         else:
             scope = (jax.named_scope("serve_mlp") if decode_ctx is not None
                      else contextlib.nullcontext())
-            dense = lambda feat, name: nn.Dense(
-                feat, use_bias=False, dtype=self.dtype,
-                param_dtype=self.param_dtype, name=name)
             with scope:
-                gate = dense(self.ffn_dim, "gate")(h)
-                up = dense(self.ffn_dim, "up")(h)
-                gate = mesh_lib.constrain(gate, _seq_rule("ffn_hidden"))
-                up = mesh_lib.constrain(up, _seq_rule("ffn_hidden"))
-                h = dense(d, "down")(nn.silu(gate) * up)
+                h = swiglu_mlp(h, self.ffn_dim, self.dtype, self.param_dtype)
         x = x + h
         return mesh_lib.constrain(x, _seq_rule("residual", self.sp))
 

@@ -5,6 +5,12 @@ The torchvision-factory equivalent (reference builds models via
 the framework needs: which task head to use, an input template for sharded
 init, a forward-FLOPs estimate for MFU accounting, and per-family tensor-
 parallel rule tables.
+
+Families: ResNet (torchvision depths), ViT, GPT-2, Llama (dense and MoE) and
+the Granite 4.0-H hybrid (Mamba-2 state-space mixers among GQA attention:
+``granite4_h_micro`` at its published sizes, ``granite4_h_micro_share`` one
+chip's share of it, ``granite_hybrid_tiny`` for tests; training only,
+``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -306,6 +312,44 @@ def _llama_moe(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
                                                 moe_ep_overlap_chunks))
     return _lm_bundle(module, llama.TP_RULES, seq_len,
                       llama.num_params_active)
+
+
+def _granite_hybrid(make):
+    """Registry builder for the Granite hybrid family (Mamba-2 mixers among
+    GQA attention): ``make(granite_hybrid, **kw)`` returns the module. The
+    family has ``dp``/``fsdp`` only: the mixer has no tensor-parallel rule
+    table, so ``tp``/``fsdp_tp`` fail in ``strategy_rules`` and ``*_sp``
+    fails here, rather than replicating silently."""
+    def build(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
+              sp=False, attn_impl="auto", logits_dtype, **_):
+        from pytorch_distributed_training_example_tpu.models import (
+            granite_hybrid)
+
+        if sp:
+            raise ValueError(
+                "the Granite hybrid family has no tensor- or sequence-"
+                "parallel rules for its Mamba mixer; use strategy dp or fsdp")
+        module = make(granite_hybrid, dtype=dtype, param_dtype=param_dtype,
+                      remat=remat, remat_policy=remat_policy,
+                      attn_impl=attn_impl, logits_dtype=logits_dtype)
+        return ModelBundle(
+            module=module, task="lm",
+            input_template=(jnp.zeros((2, seq_len), jnp.int32),),
+            fwd_flops_per_example=seq_len
+            * granite_hybrid.forward_flops_per_token(module, seq_len),
+            rules={}, examples_unit="sequences")
+    return build
+
+
+# The published granite-4.0-h-micro; one chip's share of it (its first
+# period of ten layers, an eighth of the tied vocabulary: what the one-chip
+# benchmark cell trains), made from the first; and a toy for the tests.
+_REGISTRY["granite4_h_micro"] = _granite_hybrid(
+    lambda g, **kw: g.granite4_h_micro(**kw))
+_REGISTRY["granite4_h_micro_share"] = _granite_hybrid(
+    lambda g, **kw: g.chip_share(g.granite4_h_micro(**kw)))
+_REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
+    lambda g, **kw: g.granite_hybrid_tiny(**kw))
 
 
 @register("resnet_micro")

@@ -231,6 +231,15 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # Granite-4.0-H-Micro, one chip's share (models/granite_hybrid.py: the
+    # first period of ten layers, an eighth of the vocabulary): Mamba-2
+    # mixers among GQA attention, one 4k sequence a chip per micro-step
+    "granite4_h_micro_share": dict(
+        model="granite4_h_micro_share", dataset="lm", seq_len=4096, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -86,6 +86,7 @@ def _grads(fn):
     (4, 2048, 8, 2, 128),      # the same under GQA
     (1, 4096, 8, 2, 128),      # largest S the streaming backward admits
     (1, 8192, 8, 2, 128),      # llama3_8b's S: refused there -> online bwd
+    (1, 4096, 32, 8, 64),      # granite-4.0-h-micro's attention layer
 ])
 def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
     q = _sds((B, S, H, D), one_chip, dtype)
@@ -105,10 +106,33 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
         assert plans == [(2, 256), (2, 128)]
         assert "flash_fwd_online" not in text
         assert "flash_bwd_oneshot" not in text
+    if (S, H, D) == (4096, 32, 64):
+        # no causal or one-shot plan at S=4096, no streaming backward at
+        # D=64: the online forward and the two-kernel online backward
+        assert all(name in text for name in (
+            "flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"))
     if S == 8192:
         # The v5e compiler counts the streaming backward over its 16 MB of
         # scoped VMEM here; the planner must not admit it.
         assert fa._stream_bwd_plan(H, S, S, D) is None
+
+
+def test_ssd_scan_compiles_at_published_widths(one_chip):
+    """granite-4.0-h-micro's mixer at one sequence of 4096: 64 heads of 64,
+    state 128, chunk 256, bf16 operands and float32 decays. Plain XLA (no
+    Mosaic call yet); the compiler counts its temporaries."""
+    from pytorch_distributed_training_example_tpu.ops import ssd
+
+    b, S, H, Pd, N = 1, 4096, 64, 64, 128
+    f32 = jnp.float32
+    args = (_sds((b, S, H, Pd), one_chip), _sds((b, S, H), one_chip, f32),
+            _sds((H,), one_chip, f32), _sds((b, S, N), one_chip),
+            _sds((b, S, N), one_chip), _sds((H,), one_chip, f32))
+    total = lambda *a: ssd.ssd(*a, chunk=256).astype(f32).sum()
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *args).compile()
+    # a handful of [16, 64, 256, 256] tiles (268 MB in float32) and no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
 
 
 def test_padded_flash_vit_compiles(one_chip):
