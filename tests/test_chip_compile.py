@@ -117,22 +117,51 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
         assert fa._stream_bwd_plan(H, S, S, D) is None
 
 
-def test_ssd_scan_compiles_at_published_widths(one_chip):
-    """granite-4.0-h-micro's mixer at one sequence of 4096: 64 heads of 64,
-    state 128, chunk 256, bf16 operands and float32 decays. Plain XLA (no
-    Mosaic call yet); the compiler counts its temporaries."""
+def _granite_scan_args(sharding, b=1, dtype=BF16):
+    """granite-4.0-h-micro's mixer at sequences of 4096: 64 heads of 64,
+    state 128 (x, dt, A, B, C, D)."""
+    S, H, Pd, N = 4096, 64, 64, 128
+    f32 = jnp.float32
+    rep = sharding if b == 1 else NamedSharding(sharding.mesh, P())
+    return (_sds((b, S, H, Pd), sharding, dtype), _sds((b, S, H), sharding, f32),
+            _sds((H,), rep, f32), _sds((b, S, N), sharding, dtype),
+            _sds((b, S, N), sharding, dtype), _sds((H,), rep, f32))
+
+
+def _scan_grads():
     from pytorch_distributed_training_example_tpu.ops import ssd
 
-    b, S, H, Pd, N = 1, 4096, 64, 64, 128
-    f32 = jnp.float32
-    args = (_sds((b, S, H, Pd), one_chip), _sds((b, S, H), one_chip, f32),
-            _sds((H,), one_chip, f32), _sds((b, S, N), one_chip),
-            _sds((b, S, N), one_chip), _sds((H,), one_chip, f32))
-    total = lambda *a: ssd.ssd(*a, chunk=256).astype(f32).sum()
-    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4, 5))).lower(
-        *args).compile()
+    total = lambda *a: ssd.ssd(*a, chunk=256).astype(jnp.float32).sum()
+    return ssd, jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4, 5)))
+
+
+def test_ssd_scan_compiles_at_published_widths(one_chip, monkeypatch):
+    """The ``jax.numpy`` scan, which shapes the plan refuses still take, at
+    chunk 256, bf16 operands and float32 decays. Plain XLA (no Mosaic call);
+    the compiler counts its temporaries."""
+    ssd, grads = _scan_grads()
+    monkeypatch.setattr(ssd, "_kernel_plan", lambda *a: None)
+    compiled = grads.lower(*_granite_scan_args(one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
     # a handful of [16, 64, 256, 256] tiles (268 MB in float32) and no more
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32], ids=["bf16", "fp32"])
+def test_ssd_kernels_compile_at_published_widths(one_chip, as_tpu, dtype):
+    """The kernel pair at the same shape, forward and all six cotangents:
+    inside the 16 MB of scoped VMEM at the plan's group, both kernels in the
+    program by name, and no [16, 64, 256, 256] float32 tile left in HBM (the
+    chunk states, y and the cotangents are what ``temp`` holds)."""
+    ssd, grads = _scan_grads()
+    args = _granite_scan_args(one_chip, dtype=dtype)
+    forward = jax.jit(lambda *a: ssd.ssd(*a, chunk=256)).lower(*args).compile()
+    assert "ssd_fwd" in forward.as_text()
+    compiled = grads.lower(*args).compile()
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert "64,256,256]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 def test_padded_flash_vit_compiles(one_chip):
@@ -182,3 +211,15 @@ def test_flash_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
         _compiled_text(
             _grads(lambda q, k, v: attn.attention(q, k, v, causal=True)),
             x, x, x)
+
+
+def test_ssd_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
+    """Four sequences over four chips, the batch the only sharded axis: the
+    kernels go through mesh_lib.manual_call, and A's and D's cotangents are
+    summed across the chips outside them."""
+    mesh = mesh_lib.build_mesh({"fsdp": 4}, devices=topo.devices)
+    batch = NamedSharding(mesh, P(("data", "fsdp")))
+    _, grads = _scan_grads()
+    with mesh_lib.use_mesh(mesh):
+        text = grads.lower(*_granite_scan_args(batch, b=4)).compile().as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text and "all-reduce" in text
