@@ -1,22 +1,26 @@
 #!/usr/bin/env python
 """Golden-metric regression gate (SURVEY.md §4.5).
 
-Compare a bench.py JSON result (stdin or file) against benchmarks/golden.json
-for the device it ran on; exit 1 if any matched metric regressed more than
-``--tolerance`` (default 10%). Metrics or devices without a golden entry are
-reported but never fail — new hardware/new benchmarks need a first recording.
+Compare a result line (stdin or file: ``{"metric", "value", "extra":
+{"device": ...}}``, the shape ``serve_bench.py`` prints) against
+benchmarks/golden.json for the device it ran on; exit 1 if any matched metric
+regressed more than ``--tolerance`` (default 10%). Metrics or devices without
+a golden entry are reported but never fail. The training rows of golden.json
+come from a machine that is gone and no program prints them any more: the
+chip's numbers are ``chipbench/run.py``'s, judged by the driver against
+``PERF_LEDGER.jsonl``, not here.
 
-``--aot-bytes`` gates a ``profile_step.py --aot`` report instead: per-region
-modeled HBM bytes versus the ``aot_regions`` section of golden.json. Bytes
+``--aot-bytes`` gates a per-region byte report (the ``aot_regions`` section
+of golden.json; ``serve_bench.py --aot`` writes the serving ones). Bytes
 regress UPWARD (more traffic = worse), it needs no chip (the numbers are
 facts of the lowered program), and ``--record`` writes the first golden.
+``--lint``, ``--ttfs``, ``--slo``, ``--goodput`` and ``--metrics-jsonl`` gate
+the other record files (see each flag's help).
 
 Usage:
-    python bench.py | python benchmarks/check_regression.py
-    python benchmarks/check_regression.py BENCH_r02.json
-    python benchmarks/profile_step.py --model llama_moe --aot \
-        --moe-dispatch gather | python benchmarks/check_regression.py \
-        --aot-bytes
+    python benchmarks/serve_bench.py | python benchmarks/check_regression.py
+    python benchmarks/check_regression.py RESULT.json
+    python benchmarks/check_regression.py --lint
 """
 
 from __future__ import annotations
@@ -302,7 +306,7 @@ def check_slo(path: str):
 
 
 def aot_key(result: dict) -> str:
-    """Golden key for an aot_report: model + shape + dispatch formulation.
+    """Golden key for a per-region byte report: model + shape + dispatch formulation.
     EP rows (lowered at an expert mesh) extend the key with the degree and
     transport so replicated/a2a/a2a_overlap goldens coexist per shape;
     composed-topology rows (r22) append dp/pp/seq tokens when those axes
@@ -534,7 +538,7 @@ def main(argv=None):
                         "distinct per-job run_ids a multi-tenant aggregate "
                         "carries by construction")
     p.add_argument("--aot-bytes", action="store_true",
-                   help="input is a profile_step.py --aot report: gate "
+                   help="input is a per-region byte report: gate "
                         "per-region modeled bytes (UP is the regression "
                         "direction) against golden.json aot_regions; runs "
                         "without a chip")
@@ -597,7 +601,7 @@ def main(argv=None):
                            or args.ttfs):
         raw = open(args.result).read() if args.result else sys.stdin.read()
         # Accept a driver BENCH_r{N}.json wrapper (pretty-printed, result
-        # under "parsed") or piped bench.py output (last stdout line is the
+        # under "parsed") or piped benchmark output (last stdout line is the
         # JSON).
         try:
             data = json.loads(raw)

@@ -15,17 +15,15 @@ hazard rules distilled from this repo's postmortems:
          ``block_until_ready``, ``float()``/``int()`` of traced values)
          inside step-scope modules (train_loop / parallel / ops).
   GL004  knob-threading consistency: every ``utils/config.py`` field must
-         be reachable from the ``main.py`` CLI, every CLI dest must map to
-         a real Config field (``config_from_args`` silently drops
-         strangers), and every perf knob threaded through
-         ``bench.setup_step`` must be reachable from both ``bench.py`` and
-         ``benchmarks/profile_step.py`` CLIs.
+         be reachable from the ``main.py`` CLI, and every CLI dest must map
+         to a real Config field (``config_from_args`` silently drops
+         strangers).
   GL005  wall-clock / unseeded randomness in seeded chaos & sampler paths
          (breaks same-seed ``chaos.jsonl`` diffing).
 
-Layer 2 (IR) reuses the chipless abstract lowering behind
-``profile_step.py --aot`` and inspects the optimized HLO / StableHLO of a
-real bench program:
+Layer 2 (IR) lowers the ``Trainer``'s own step program on shapes, with no
+chip and no parameter in memory (``core/trainer.build_step_program``), and
+inspects its optimized HLO / StableHLO:
 
   GL101  donation coverage: state inputs not aliased to outputs
          (double-HBM residency).
@@ -602,7 +600,7 @@ def _gl003(mod: Module) -> list[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# GL004: knob-threading consistency across config/main/bench/profile_step
+# GL004: knob-threading consistency between utils/config.py and main.py
 # ---------------------------------------------------------------------------
 
 # CLI dests in main.py that intentionally do not map to Config fields
@@ -646,26 +644,6 @@ def _parser_dests(tree: ast.Module) -> dict[str, int]:
     return dests
 
 
-def _kwarg_threads(tree: ast.Module) -> set[str]:
-    """Keyword names passed anywhere as `name=args.<something>`."""
-    out: set[str] = set()
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Call):
-            for kw in node.keywords:
-                if kw.arg is None:
-                    continue
-                v = kw.value
-                if (
-                    isinstance(v, ast.Attribute)
-                    and isinstance(v.value, ast.Name)
-                    and v.value.id == "args"
-                ):
-                    out.add(kw.arg)
-                elif isinstance(v, ast.Name) and v.id.startswith("args"):
-                    out.add(kw.arg)
-    return out
-
-
 def _config_fields(tree: ast.Module) -> list[str]:
     for node in ast.walk(tree):
         if isinstance(node, ast.ClassDef) and node.name == "Config":
@@ -679,21 +657,8 @@ def _config_fields(tree: ast.Module) -> list[str]:
     return []
 
 
-def _func_params(tree: ast.Module, name: str) -> list[str]:
-    for node in ast.walk(tree):
-        if isinstance(node, _FUNC_NODES) and node.name == name:
-            a = node.args
-            return [p.arg for p in list(a.posonlyargs) + list(a.args) + list(a.kwonlyargs)]
-    return []
-
-
 def _gl004(root: str) -> list[Finding]:
-    paths = {
-        "config": f"{PKG}/utils/config.py",
-        "main": "main.py",
-        "bench": "bench.py",
-        "profile": "benchmarks/profile_step.py",
-    }
+    paths = {"config": f"{PKG}/utils/config.py", "main": "main.py"}
     mods: dict[str, Module] = {}
     for key, rel in paths.items():
         full = os.path.join(root, rel)
@@ -748,34 +713,6 @@ def _gl004(root: str) -> list[Finding]:
                     snippet=field,
                 )
             )
-
-    # Direction 3: perf knobs threaded through bench.setup_step must be
-    # reachable from bench.py and profile_step.py CLIs too.
-    if "bench" in mods:
-        knobs = [p for p in _func_params(mods["bench"].tree, "setup_step") if p in fields]
-        for key in ("bench", "profile"):
-            if key not in mods:
-                continue
-            m = mods[key]
-            dests = _parser_dests(m.tree)
-            threaded = _kwarg_threads(m.tree)
-            for knob in knobs:
-                if knob in dests or knob in threaded:
-                    continue
-                out.append(
-                    Finding(
-                        rule="GL004",
-                        path=m.relpath,
-                        line=1,
-                        scope="<cli>",
-                        message=(
-                            f"perf knob '{knob}' (bench.setup_step param and "
-                            f"Config field) is not reachable from the "
-                            f"{os.path.basename(m.relpath)} CLI"
-                        ),
-                        snippet=knob,
-                    )
-                )
     return out
 
 
@@ -877,7 +814,7 @@ def run_ast(root: str = REPO_ROOT, files: list[str] | None = None) -> list[Findi
 
 
 # ---------------------------------------------------------------------------
-# IR layer (lazy jax import; reuses profile_step's abstract lowering)
+# IR layer (lazy jax import; lowers the Trainer's step program on shapes)
 # ---------------------------------------------------------------------------
 
 def _entry_block(hlo: str) -> str:
@@ -1251,32 +1188,47 @@ def run_ir(
     precision: str = "bf16",
     upcast_bytes: int = 1 << 20,
     donation_slack: float = 0.01,
-    **knobs,
+    **overrides,
 ) -> list[Finding]:
-    """Lower a real bench program chiplessly and run the IR rules on it."""
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import profile_step
+    """Lower the Trainer's own step program for ``model`` chiplessly and run
+    the IR rules on it.
 
-    built = profile_step.build_abstract_step(
-        model,
-        per_chip_batch=per_chip_batch,
-        precision=precision,
-        seq_len=seq_len,
-        **knobs,
-    )
-    import pytorch_distributed_training_example_tpu.core.mesh as mesh_lib
+    The program is ``main.py --preset gpt2_124m --model <model>`` (the LM
+    recipe: AdamW, ``fsdp`` over every device) at a short sequence, built by
+    the functions the ``Trainer`` calls (``core/trainer.build_model``,
+    ``build_step_program``) with shapes in the state's place. ``overrides``
+    are ``Config`` fields.
+    """
+    sys.path.insert(0, REPO_ROOT)
+    import jax
+    import jax.numpy as jnp
 
-    with mesh_lib.use_mesh(built["mesh"]):
-        lowered = built["step"].lower(built["abstract_state"], built["abstract_batch"])
+    from pytorch_distributed_training_example_tpu.core import (
+        mesh as mesh_lib, trainer as trainer_lib)
+    from pytorch_distributed_training_example_tpu.utils.config import (
+        from_preset)
+
+    cfg = from_preset(
+        "gpt2_124m", model=model, seq_len=seq_len, precision=precision,
+        global_batch_size=per_chip_batch * jax.device_count(), **overrides)
+    mesh = mesh_lib.build_mesh(cfg.mesh_config())
+    program = trainer_lib.build_step_program(
+        cfg, mesh, 1000, trainer_lib.build_model(cfg))
+    state = program.abstract_state()
+    tokens = jax.ShapeDtypeStruct((cfg.global_batch_size, seq_len), jnp.int32,
+                                  sharding=program.batch_sharding)
+    with mesh_lib.use_mesh(mesh):
+        lowered = program.train_step.lower(
+            state, {"tokens": tokens, "targets": tokens})
         return lint_lowered(
             model,
             lowered,
-            abstract_state=built["abstract_state"],
+            abstract_state=state,
             bf16_regions=precision in ("bf16", "mixed"),
             upcast_bytes=upcast_bytes,
             donation_slack=donation_slack,
-            expect_sharding=built["mesh"].size > 1,
-            seq_axis=built["mesh"].shape.get("context", 1) > 1,
+            expect_sharding=mesh.size > 1,
+            seq_axis=mesh.shape.get("context", 1) > 1,
         )
 
 

@@ -1,33 +1,23 @@
-#!/usr/bin/env python
-"""Per-HLO step profile from an xplane trace (VERDICT r3 #1/#3 tooling).
+"""Readers of a compiled step's HLO text and of an xplane trace.
 
-Profiles the SAME compiled train step bench.py times (shared setup via
-``bench.setup_step``), then parses the ``jax.profiler`` xplane dump into a
-per-op table and category rollup — the methodology behind PROFILE_GPT2.md /
-PROFILE_RN50.md, now a reusable script instead of a throwaway:
-
-    python benchmarks/profile_step.py --model vit_b16 --per-chip-batch 64 \
-        --out PROFILE_VIT.json
-
-Classification is NOT name-guessing: the compiled module's HLO text is
-parsed so every fusion is categorized by what its called computation
-actually contains (convolution > dot > scatter > reduce > elementwise,
-first match wins), and trace events are joined to that map by op name.
-Durations are measured device time — no cost model in the loop.
+What other programs import: ``build_op_categories`` (every fusion classified
+by what its called computation actually contains: convolution > dot >
+scatter > reduce > elementwise, first match wins; no name-guessing),
+``build_op_moe_tags`` / ``build_op_moe_weights`` / ``build_pallas_interior``
+(the ``moe_*`` named scopes), ``build_op_bytes`` (unique operand + result
+buffer bytes per executed op), ``collective_byte_census`` and ``collect_ops``
+(device time per XLA op out of a ``jax.profiler`` dump). Used by
+``serve_bench.py``, ``flash_micro.py``, ``ssd_micro.py``, ``graftlint.py``
+and ``tests/test_bench_regression.py``. The cells' per-region and
+per-kernel split is ``chipbench/run.py --trace 1``, not this file.
 """
 
 from __future__ import annotations
 
-import argparse
 import collections
 import glob
-import json
-import os
 import re
 import sys
-import tempfile
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # Category priority (first present wins) for a fused computation's body.
 _PRIORITY = ["attention_kernel", "conv", "matmul", "scatter", "gather",
@@ -264,8 +254,8 @@ _PALLAS_INTERIOR_RE = re.compile(r"\bmoe_experts_gmm/while/")
 
 def build_pallas_interior(hlo_text: str):
     """Instruction names interior to an interpret-mode Pallas grid loop
-    (``_PALLAS_INTERIOR_RE`` on op_name). ``aot_report`` drops them from
-    the byte/op tabulation entirely — they do not exist on the target."""
+    (``_PALLAS_INTERIOR_RE`` on op_name). A byte report drops them from
+    its tabulation entirely — they do not exist on the target."""
     interior = set()
     for line in hlo_text.splitlines():
         m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = ", line)
@@ -478,562 +468,3 @@ def collect_ops(trace_dir: str):
                     rec[0] += ev.duration_ns
                     rec[1] += 1
     return ops, module_ns, module_runs
-
-
-def profile(model_name: str, *, image_size=224, per_chip_batch=64,
-            precision="bf16", seq_len=1024, strategy=None, remat=False,
-            remat_policy="nothing",
-            attn_impl="auto", moe_capacity_factor=1.25, moe_top_k=2,
-            moe_dispatch_impl="gather", moe_combine_dtype="fp32",
-            moe_router_dtype="fp32", moe_router_impl="reference",
-            moe_ep_dispatch="replicated", moe_ep_overlap_chunks=2,
-            steps=3, trace_dir=None, top=25, telemetry=False):
-    import jax
-
-    from bench import setup_step
-    from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
-    from pytorch_distributed_training_example_tpu.utils import (
-        metrics as metrics_lib)
-
-    su = setup_step(model_name, image_size, per_chip_batch, precision,
-                    seq_len, strategy=strategy, remat=remat,
-                    remat_policy=remat_policy,
-                    attn_impl=attn_impl,
-                    moe_capacity_factor=moe_capacity_factor,
-                    moe_top_k=moe_top_k,
-                    moe_dispatch_impl=moe_dispatch_impl,
-                    moe_combine_dtype=moe_combine_dtype,
-                    moe_router_dtype=moe_router_dtype,
-                    moe_router_impl=moe_router_impl,
-                    moe_ep_dispatch=moe_ep_dispatch,
-                    moe_ep_overlap_chunks=moe_ep_overlap_chunks,
-                    telemetry=telemetry)
-    mesh, state, step, batch = su["mesh"], su["state"], su["step"], su["batch"]
-    bundle = su["bundle"]
-    trace_dir = trace_dir or tempfile.mkdtemp(prefix="xprof_")
-    with mesh_lib.use_mesh(mesh):
-        compiled = jax.jit(step).lower(state, batch).compile()
-        hlo_text = compiled.as_text()
-        op_cat, op_src = build_op_categories(hlo_text)
-        op_bytes = build_op_bytes(hlo_text)
-        op_moe = build_op_moe_tags(hlo_text)
-        state, m = compiled(state, batch)  # warm
-        jax.tree.map(lambda x: x.block_until_ready(), m)
-        jax.profiler.start_trace(trace_dir)
-        for _ in range(steps):
-            state, m = compiled(state, batch)
-        jax.tree.map(lambda x: x.block_until_ready(), m)
-        jax.profiler.stop_trace()
-
-    ops, module_ns, module_runs = collect_ops(trace_dir)
-    n_steps = module_runs or steps
-    cats = collections.defaultdict(lambda: [0.0, 0, 0])  # ns, count, bytes
-    moe_cats = collections.defaultdict(lambda: [0.0, 0, 0])
-    rows = []
-    total_ns = 0.0
-    unmatched_ns = 0.0
-    traffic_bytes = 0
-    for name, (ns, count) in ops.items():
-        nm = re.match(r"%?([\w.\-]+) =", name)
-        op = nm.group(1) if nm else name
-        cat = op_cat.get(op)
-        if cat is None:
-            cat = "unmatched"
-            unmatched_ns += ns
-        b = op_bytes.get(op, 0) * (count // max(n_steps, 1))
-        cats[cat][0] += ns
-        cats[cat][1] += count
-        cats[cat][2] += b
-        moe = op_moe.get(op, "non_moe")
-        moe_cats[moe][0] += ns
-        moe_cats[moe][1] += count
-        moe_cats[moe][2] += b
-        total_ns += ns
-        traffic_bytes += b
-        op_ms = ns / n_steps / 1e6
-        rows.append({"ms_per_step": op_ms,
-                     "count": count // n_steps, "category": cat,
-                     "moe_region": op_moe.get(op),
-                     "gbytes": round(b / 1e9, 3),
-                     "gbps": round(b / (op_ms * 1e6), 1) if op_ms else 0.0,
-                     "src": op_src.get(op), "hlo": name[:300]})
-    rows.sort(key=lambda r: -r["ms_per_step"])
-    # Per-category achieved bandwidth: category bytes over category device
-    # time. For memory-bound categories (reduce, elementwise, copy_layout)
-    # this is the sustained HBM rate; for MXU categories (conv, matmul) low
-    # GB/s just means the time went to math, so read those rows together
-    # with their share of step time, not as a bandwidth deficit.
-    cat_rows = sorted(
-        ({"category": c, "ms_per_step": ns / n_steps / 1e6,
-          "pct": 100 * ns / total_ns, "ops_per_step": n // n_steps,
-          "gbytes_per_step": round(b / 1e9, 3),
-          "achieved_gbps": round(b * n_steps / ns, 1) if ns else 0.0}
-         for c, (ns, n, b) in cats.items()),
-        key=lambda r: -r["ms_per_step"])
-
-    # MoE region rollup (router / dispatch / experts / combine / aux, fwd +
-    # bwd): present only when the lowered module carries moe named-scope
-    # tags — the per-category table behind PROFILE_MOE.md.
-    moe_rows = None
-    if len(moe_cats) > 1 or "non_moe" not in moe_cats:
-        moe_rows = sorted(
-            ({"region": c, "ms_per_step": round(ns / n_steps / 1e6, 3),
-              "pct": round(100 * ns / total_ns, 2),
-              "ops_per_step": n // n_steps,
-              "gbytes_per_step": round(b / 1e9, 3),
-              "achieved_gbps": round(b * n_steps / ns, 1) if ns else 0.0}
-             for c, (ns, n, b) in moe_cats.items()),
-            key=lambda r: -r["ms_per_step"])
-
-    step_ms = total_ns / n_steps / 1e6
-    flops = bundle.fwd_flops_per_example * 3 * per_chip_batch
-    peak = metrics_lib.peak_flops_per_chip()
-    module_ms = module_ns / max(module_runs, 1) / 1e6
-    peak_bw = metrics_lib.peak_hbm_gbps()
-    gbps = traffic_bytes / (module_ms / 1e3) / 1e9 if module_ms else 0.0
-    roofline = {
-        "hbm_bytes_per_step": round(traffic_bytes / 1e9, 3),
-        "bytes_source": "measured_xplane_hlo_buffers",
-        "measured_hbm_gbps": round(gbps, 1),
-        "bw_fraction_of_peak": round(gbps / peak_bw, 3),
-        "peak_hbm_gbps": peak_bw,
-        "note": ("bytes = per-executed-op unique operand+result buffer "
-                 "sizes from the scheduled HLO, joined to xplane events; "
-                 "time = measured module duration"),
-    }
-    out = {
-        "model": model_name,
-        "device": jax.devices()[0].device_kind,
-        "per_chip_batch": per_chip_batch,
-        "precision": precision,
-        "attn_impl": attn_impl,
-        "steps_traced": n_steps,
-        "xla_ops_ms_per_step": round(step_ms, 2),
-        "module_ms_per_step": round(module_ms, 2),
-        "mfu_from_op_time": round(flops / (step_ms / 1e3) / peak, 4),
-        "unmatched_pct": round(100 * unmatched_ns / max(total_ns, 1), 2),
-        "roofline_measured": roofline,
-        "categories": [{**r, "ms_per_step": round(r["ms_per_step"], 2),
-                        "pct": round(r["pct"], 1)} for r in cat_rows],
-        **({"moe_regions": moe_rows,
-            "moe_dispatch_impl": moe_dispatch_impl,
-            "moe_top_k": moe_top_k,
-            "moe_combine_dtype": moe_combine_dtype,
-            "moe_capacity_factor": moe_capacity_factor,
-            "moe_ep_dispatch": moe_ep_dispatch,
-            "moe_ep_overlap_chunks": moe_ep_overlap_chunks}
-           if moe_rows else {}),
-        "top_ops": [{**r, "ms_per_step": round(r["ms_per_step"], 3)}
-                    for r in rows[:top]],
-        "trace_dir": trace_dir,
-    }
-    return out
-
-
-def build_abstract_step(model_name: str, *, per_chip_batch=4,
-                        precision="bf16", seq_len=2048, strategy=None,
-                        remat=False, remat_policy="nothing",
-                        attn_impl="auto", moe_capacity_factor=1.0,
-                        moe_top_k=2, moe_dispatch_impl="gather",
-                        moe_combine_dtype="fp32", moe_router_dtype="fp32",
-                        moe_router_impl="reference",
-                        moe_ep_dispatch="replicated",
-                        moe_ep_overlap_chunks=2,
-                        mesh_spec: dict | None = None,
-                        pp_microbatches=4):
-    """Chipless abstract train step: the shared lowering front-end.
-
-    Builds the SAME program ``bench.setup_step`` times — same registry
-    model, optimizer, strategy resolution — but with ABSTRACT inputs
-    (``jax.eval_shape``; no params materialized), so callers can
-    ``step.lower(abstract_state, abstract_batch)`` under ``mesh`` without a
-    chip. Consumers: ``aot_report`` (per-region byte model, the
-    ``--aot-bytes`` gate) and ``graftlint`` IR rules (donation / precision /
-    host-transfer / sharding checks on the identical program).
-
-    ``mesh_spec`` overrides the default data-only mesh (e.g.
-    ``{"expert": 2, "data": -1}`` for the EP comms model); the lowering
-    needs that many addressable devices — chipless CLI runs force fake CPU
-    devices via XLA_FLAGS before jax initializes (see ``main``).
-
-    Returns a dict with ``step`` (jitted, ``donate_argnums=0``),
-    ``abstract_state``, ``abstract_batch``, ``mesh``, ``strategy``, and the
-    resolved precision ``policy``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from pytorch_distributed_training_example_tpu.core import (
-        mesh as mesh_lib, optim, precision as precision_lib, train_loop)
-    from pytorch_distributed_training_example_tpu.core.train_state import (
-        TrainState)
-    from pytorch_distributed_training_example_tpu.models import registry
-    from pytorch_distributed_training_example_tpu.parallel import (
-        sharding as sharding_lib)
-    from pytorch_distributed_training_example_tpu.utils.config import (
-        from_preset)
-
-    mesh = mesh_lib.build_mesh(mesh_spec or {"data": -1})
-    global_batch = per_chip_batch * mesh_lib.dp_size(mesh)
-    cfg = from_preset("resnet50_imagenet", global_batch_size=global_batch,
-                      precision=precision)
-    strategy = strategy or ("fsdp" if "llama" in model_name
-                            or "gpt" in model_name else cfg.strategy)
-    policy = precision_lib.get_policy(cfg.precision)
-    bundle = registry.create_model(model_name, seq_len=seq_len,
-                                   dtype=policy.compute_dtype,
-                                   param_dtype=policy.param_dtype,
-                                   remat=remat, remat_policy=remat_policy,
-                                   attn_impl=attn_impl,
-                                   moe_capacity_factor=moe_capacity_factor,
-                                   moe_top_k=moe_top_k,
-                                   moe_dispatch_impl=moe_dispatch_impl,
-                                   moe_combine_dtype=moe_combine_dtype,
-                                   moe_router_dtype=moe_router_dtype,
-                                   moe_router_impl=moe_router_impl,
-                                   moe_ep_dispatch=moe_ep_dispatch,
-                                   moe_ep_overlap_chunks=moe_ep_overlap_chunks,
-                                   logits_dtype=policy.logits_dtype)
-    tx, _ = optim.build_optimizer(cfg, steps_per_epoch=1000)
-    if strategy == "pp":
-        # Pipeline rows reuse the trainer's wiring: scan-stacked Llama
-        # blocks sharded over 'stage', GPipe microbatch schedule
-        # (parallel/pp_lm.py). The wrapper quacks like a flax module, so
-        # the abstract lowering below is unchanged.
-        from pytorch_distributed_training_example_tpu.parallel import pp_lm
-
-        module = pp_lm.PipelinedLlama(bundle.module, mesh,
-                                      num_microbatches=pp_microbatches)
-        rules = pp_lm.PP_RULES
-    else:
-        rules = sharding_lib.strategy_rules(strategy, bundle.rules)
-        module = bundle.module
-
-    def init_fn(rng):
-        variables = module.init({"params": rng}, *jax.tree.map(
-            lambda t: t[:1], bundle.input_template), train=False)
-        return TrainState.create(apply_fn=module.apply,
-                                 params=variables["params"], tx=tx,
-                                 rng=jax.random.PRNGKey(0))
-
-    state_shape = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
-    shardings = train_loop.state_shardings(state_shape, mesh, rules)
-    abstract_state = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        state_shape, shardings)
-    batch_sh = mesh_lib.batch_sharding(mesh)
-    abstract_batch = {
-        "tokens": jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32,
-                                       sharding=batch_sh),
-        "targets": jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32,
-                                        sharding=batch_sh),
-    }
-    step = jax.jit(train_loop.make_train_step(
-        train_loop.get_task(bundle.task)), donate_argnums=0)
-    return {
-        "step": step,
-        "abstract_state": abstract_state,
-        "abstract_batch": abstract_batch,
-        "mesh": mesh,
-        "strategy": strategy,
-        "policy": policy,
-    }
-
-
-def aot_report(model_name: str, *, per_chip_batch=4, precision="bf16",
-               seq_len=2048, strategy=None, remat=False,
-               remat_policy="nothing", attn_impl="auto",
-               moe_capacity_factor=1.0, moe_top_k=2,
-               moe_dispatch_impl="gather", moe_combine_dtype="fp32",
-               moe_router_dtype="fp32", moe_router_impl="reference",
-               moe_ep_dispatch="replicated", moe_ep_overlap_chunks=2,
-               ep_degree=1, seq_degree=1, pp_degree=1, dp_degree=0,
-               pp_microbatches=4):
-    """Chipless per-region program report (the derived leg of PROFILE_MOE.md).
-
-    AOT-lowers the SAME train step bench.py times — same registry model,
-    optimizer, strategy resolution as ``bench.setup_step`` — but with
-    ABSTRACT inputs (``jax.eval_shape``; no params materialized), then
-    classifies every instruction of the compiled module by its moe
-    named-scope tag and tabulates static program facts per region: op
-    counts, modeled HBM bytes (``build_op_bytes``), and the HLO category
-    mix. No timing. The fusion/schedule is THIS process' XLA backend (on a
-    CPU host: XLA:CPU) — op counts and logical bytes are facts of the
-    lowered program, but TPU fusion differs, so downstream consumers must
-    label these numbers derived, not measured.
-
-    Region BYTES use proportional attribution (``build_op_moe_weights``):
-    a mixed fusion's traffic is split across regions by interior-line
-    result bytes instead of winner-take-all line majority, which on
-    XLA:CPU charged whole-block backward mega-fusions to whichever MoE
-    region tagged a few cotangent lines (see the r8 PROFILE_MOE.md
-    addendum). Integer op counts and the category mix still use the
-    majority map — an instruction is one op in one region. The output
-    carries ``"attribution": "proportional_bytes"`` so byte goldens
-    recorded under one model never compare against the other.
-
-    Pallas-kernel interior ops from the off-TPU interpret lowering are
-    excluded wholesale (``build_pallas_interior``): the grid while-loop
-    that emulates the kernel on CPU is not part of the target program,
-    and the kernel's real HBM charge — operands + results, as for any
-    custom call — is carried by the while instruction's boundary tuple.
-
-    ``ep_degree > 1`` lowers at an ``{"expert": ep, "data": rest}`` mesh
-    (strategy defaults to the model's ``fsdp_tp`` table — the one that
-    pins ``moe/experts/w_*`` to the expert axis) and the ``collectives``
-    census becomes the EP comms model: per-opcode/per-region bytes that
-    the a2a-vs-replicated golden rows gate (``check_regression.py
-    --aot-bytes``).
-
-    ``seq_degree`` / ``pp_degree`` / ``dp_degree`` compose the full
-    topology tuple (dp x ep x pp x seq): the mesh gains a ``context`` /
-    ``stage`` axis and the report becomes the per-topology memory+comms
-    census — ring-attention ppermute bytes land in the collectives
-    census, and ``memory`` carries the abstract lowering's HBM high-water
-    (``compiled.memory_analysis()``: resident = arguments + temps under
-    donation). ``pp_degree > 1`` forces strategy "pp" (the GPipe schedule
-    over scan-stacked Llama blocks). ``dp_degree == 0`` lets the data
-    axis absorb the remaining devices (the historical single-axis
-    behavior); setting it pins the data axis so one report is one
-    (dp, ep, pp, seq) tuple."""
-    mesh_spec = None
-    if ep_degree > 1 or seq_degree > 1 or pp_degree > 1 or dp_degree:
-        mesh_spec = {a: d for a, d in (("expert", ep_degree),
-                                       ("context", seq_degree),
-                                       ("stage", pp_degree)) if d > 1}
-        mesh_spec["data"] = dp_degree if dp_degree else -1
-        if pp_degree > 1:
-            strategy = "pp"
-        elif ep_degree > 1:
-            strategy = strategy or "fsdp_tp"
-    built = build_abstract_step(
-        model_name, per_chip_batch=per_chip_batch, precision=precision,
-        seq_len=seq_len, strategy=strategy, remat=remat,
-        remat_policy=remat_policy, attn_impl=attn_impl,
-        moe_capacity_factor=moe_capacity_factor, moe_top_k=moe_top_k,
-        moe_dispatch_impl=moe_dispatch_impl,
-        moe_combine_dtype=moe_combine_dtype,
-        moe_router_dtype=moe_router_dtype,
-        moe_router_impl=moe_router_impl,
-        moe_ep_dispatch=moe_ep_dispatch,
-        moe_ep_overlap_chunks=moe_ep_overlap_chunks,
-        mesh_spec=mesh_spec, pp_microbatches=pp_microbatches)
-    import jax
-
-    from pytorch_distributed_training_example_tpu.core import (
-        mesh as mesh_lib)
-
-    strategy = built["strategy"]
-    with mesh_lib.use_mesh(built["mesh"]):
-        compiled = built["step"].lower(
-            built["abstract_state"], built["abstract_batch"]).compile()
-    hlo_text = compiled.as_text()
-    op_cat, _ = build_op_categories(hlo_text)
-    op_bytes = build_op_bytes(hlo_text)
-    op_moe = build_op_moe_tags(hlo_text)
-    op_w = build_op_moe_weights(hlo_text)
-    # Off-TPU lowering emulates Pallas kernels as grid while-loops; their
-    # interior ops are not target-program ops and would charge phantom
-    # full-array traffic per grid step (see _PALLAS_INTERIOR_RE).
-    op_interior = build_pallas_interior(hlo_text)
-
-    regions: dict[str, dict] = {}
-
-    def row(tag):
-        return regions.setdefault(tag, {"ops": 0, "gbytes_modeled": 0.0,
-                                        "by_category": collections.Counter()})
-
-    for op, b in op_bytes.items():
-        if op in op_interior:
-            continue
-        assigned = 0.0
-        for tag, frac in op_w.get(op, {}).items():
-            row(tag)["gbytes_modeled"] += b * frac / 1e9
-            assigned += frac
-        if assigned < 1.0:
-            row("non_moe")["gbytes_modeled"] += b * (1.0 - assigned) / 1e9
-        r = row(op_moe.get(op, "non_moe"))
-        r["ops"] += 1
-        if b or op_cat.get(op) not in (None, "copy_layout"):
-            r["by_category"][op_cat.get(op, "?")] += 1
-    for row in regions.values():
-        row["gbytes_modeled"] = round(row["gbytes_modeled"], 3)
-        row["by_category"] = dict(row["by_category"].most_common(6))
-    try:
-        ca = compiled.cost_analysis() or {}
-    except Exception:
-        ca = {}
-    if isinstance(ca, list):  # older jax: one dict per program
-        ca = ca[0] if ca else {}
-    # Per-device HBM high-water of the abstract lowering. Under donation the
-    # resident set is arguments + temps (outputs alias donated inputs), which
-    # is what the v5p 95 GB budget gates in FEASIBILITY_8B.json. This is the
-    # host backend's buffer assignment — CPU temps run ~2x the TPU assignment
-    # at 8B scale (no fusion of the attention softmax), so consumers compare
-    # rows against rows, never against the raw chip budget.
-    memory = None
-    try:
-        ma = compiled.memory_analysis()
-        memory = {
-            "argument_bytes": int(ma.argument_size_in_bytes),
-            "output_bytes": int(ma.output_size_in_bytes),
-            "temp_bytes": int(ma.temp_size_in_bytes),
-            "alias_bytes": int(ma.alias_size_in_bytes),
-            "resident_bytes": int(ma.argument_size_in_bytes
-                                  + ma.temp_size_in_bytes),
-        }
-    except Exception:
-        pass
-    return {
-        "mode": "aot_hlo_model",
-        "attribution": "proportional_bytes",
-        "backend_lowering": jax.default_backend(),
-        "model": model_name,
-        "per_chip_batch": per_chip_batch,
-        "seq_len": seq_len,
-        "precision": precision,
-        "strategy": strategy,
-        "moe_dispatch_impl": moe_dispatch_impl,
-        "moe_top_k": moe_top_k,
-        "moe_combine_dtype": moe_combine_dtype,
-        "moe_router_dtype": moe_router_dtype,
-        "moe_router_impl": moe_router_impl,
-        "moe_capacity_factor": moe_capacity_factor,
-        "moe_ep_dispatch": moe_ep_dispatch,
-        "moe_ep_overlap_chunks": moe_ep_overlap_chunks,
-        "ep_degree": ep_degree,
-        "seq_degree": seq_degree,
-        "pp_degree": pp_degree,
-        "dp_degree": dp_degree,
-        "attn_impl": attn_impl,
-        "xla_flops_per_step": ca.get("flops"),
-        "xla_bytes_accessed": ca.get("bytes accessed"),
-        "memory": memory,
-        "collectives": collective_byte_census(hlo_text),
-        "regions": dict(sorted(regions.items(),
-                               key=lambda kv: -kv[1]["gbytes_modeled"])),
-    }
-
-
-def main(argv=None):
-    p = argparse.ArgumentParser()
-    p.add_argument("--model", default="vit_b16")
-    p.add_argument("--image-size", type=int, default=224)
-    p.add_argument("--per-chip-batch", type=int, default=64)
-    p.add_argument("--precision", default="bf16")
-    p.add_argument("--seq-len", type=int, default=1024)
-    p.add_argument("--strategy", default=None)
-    p.add_argument("--remat", action="store_true")
-    p.add_argument("--remat-policy", default="nothing",
-                   choices=["nothing", "dots", "dots_no_batch", "attn_out"])
-    p.add_argument("--attn-impl", default="auto")
-    p.add_argument("--moe-top-k", type=int, default=2)
-    p.add_argument("--moe-dispatch", default="gather",
-                   choices=["sort", "gather", "einsum", "dropless"])
-    p.add_argument("--moe-combine", default="fp32", choices=["fp32", "bf16"])
-    p.add_argument("--moe-router-dtype", default="fp32",
-                   choices=["fp32", "bf16"])
-    p.add_argument("--moe-router-impl", default="reference",
-                   choices=["reference", "fused"])
-    p.add_argument("--moe-capacity-factor", type=float, default=1.25)
-    p.add_argument("--moe-ep-dispatch", default="replicated",
-                   choices=["replicated", "a2a", "a2a_overlap"],
-                   dest="moe_ep_dispatch",
-                   help="dropless EP transport (parallel/moe.py); with "
-                        "--aot --ep N the collectives census becomes the "
-                        "chipless EP comms model")
-    p.add_argument("--moe-ep-overlap-chunks", type=int, default=2,
-                   dest="moe_ep_overlap_chunks",
-                   help="a2a_overlap double-buffer windows over the token "
-                        "dim (chunk count reaches the lowered program)")
-    p.add_argument("--ep", type=int, default=1,
-                   help="expert-parallel degree for --aot: lower at an "
-                        "{expert: N, data: rest} mesh (forces N fake CPU "
-                        "host devices when run chipless)")
-    p.add_argument("--seq-par", type=int, default=1, dest="seq_par",
-                   help="sequence/context-parallel degree for --aot: the "
-                        "mesh gains a context axis; pair with "
-                        "--attn-impl ring for the sharded-KV lowering")
-    p.add_argument("--pp", type=int, default=1,
-                   help="pipeline-parallel degree for --aot: wraps the "
-                        "model in the GPipe schedule over a stage axis "
-                        "(llama family, layers %% stages == 0)")
-    p.add_argument("--dp", type=int, default=0,
-                   help="pin the data axis for --aot (0 = absorb the "
-                        "remaining devices); with --ep/--pp/--seq-par one "
-                        "report is one (dp, ep, pp, seq) topology tuple")
-    p.add_argument("--pp-microbatches", type=int, default=4,
-                   dest="pp_microbatches",
-                   help="GPipe microbatch count when --pp > 1")
-    p.add_argument("--steps", type=int, default=3)
-    p.add_argument("--top", type=int, default=25)
-    p.add_argument("--telemetry", action="store_true",
-                   help="profile the step WITH the on-device health pack "
-                        "compiled in (utils/telemetry.py) — its reductions "
-                        "show up under the telemetry_health named scope")
-    p.add_argument("--aot", action="store_true",
-                   help="no-chip mode: AOT-lower with abstract inputs and "
-                        "report static per-moe-region program facts "
-                        "(modeled bytes/op counts) instead of traced times")
-    p.add_argument("--out", default=None, help="write full JSON here")
-    args = p.parse_args(argv)
-    if args.aot:
-        ndev = max(args.dp, 1) * args.ep * args.seq_par * args.pp
-        if ndev > 1 and "jax" not in sys.modules:
-            # Chipless composed-mesh lowering needs dp*ep*pp*seq addressable
-            # devices; must land before the first jax import in this process.
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count={ndev}")
-        res = aot_report(args.model, per_chip_batch=args.per_chip_batch,
-                         precision=args.precision, seq_len=args.seq_len,
-                         strategy=args.strategy, remat=args.remat,
-                         remat_policy=args.remat_policy,
-                         attn_impl=args.attn_impl,
-                         moe_capacity_factor=args.moe_capacity_factor,
-                         moe_top_k=args.moe_top_k,
-                         moe_dispatch_impl=args.moe_dispatch,
-                         moe_combine_dtype=args.moe_combine,
-                         moe_router_dtype=args.moe_router_dtype,
-                         moe_router_impl=args.moe_router_impl,
-                         moe_ep_dispatch=args.moe_ep_dispatch,
-                         moe_ep_overlap_chunks=args.moe_ep_overlap_chunks,
-                         ep_degree=args.ep, seq_degree=args.seq_par,
-                         pp_degree=args.pp, dp_degree=args.dp,
-                         pp_microbatches=args.pp_microbatches)
-        if args.out:
-            with open(args.out, "w") as f:
-                json.dump(res, f, indent=1)
-        print(json.dumps(res))
-        return 0
-    res = profile(args.model, image_size=args.image_size,
-                  per_chip_batch=args.per_chip_batch, precision=args.precision,
-                  seq_len=args.seq_len, strategy=args.strategy,
-                  remat=args.remat, remat_policy=args.remat_policy,
-                  attn_impl=args.attn_impl,
-                  moe_capacity_factor=args.moe_capacity_factor,
-                  moe_top_k=args.moe_top_k,
-                  moe_dispatch_impl=args.moe_dispatch,
-                  moe_combine_dtype=args.moe_combine,
-                  moe_router_dtype=args.moe_router_dtype,
-                  moe_router_impl=args.moe_router_impl,
-                  moe_ep_dispatch=args.moe_ep_dispatch,
-                  moe_ep_overlap_chunks=args.moe_ep_overlap_chunks,
-                  steps=args.steps, top=args.top, telemetry=args.telemetry)
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(res, f, indent=1)
-    slim = {k: res[k] for k in ("model", "device", "xla_ops_ms_per_step",
-                                "module_ms_per_step", "mfu_from_op_time",
-                                "unmatched_pct")}
-    slim["roofline_measured"] = res["roofline_measured"]
-    for c in res["categories"]:
-        print(json.dumps(c), file=sys.stderr)
-    for c in res.get("moe_regions") or []:
-        print(json.dumps(c), file=sys.stderr)
-    print(json.dumps(slim))
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

@@ -13,8 +13,8 @@ TPU pod, so this doubles as the end-to-end CI leg. Two measured phases:
   because an open loop keeps arriving while the engine is saturated.
 
 ``--aot`` emits the chipless byte/FLOP model of the decode step instead:
-the same ``jit(...).lower(abstract).compile()`` front-end as
-profile_step.py, with per-region HBM bytes attributed by the serve_*
+``jit(...).lower(abstract).compile()`` read by profile_step.py's HLO
+readers, with per-region HBM bytes attributed by the serve_*
 named-scope tags (serve_cache / serve_attn / serve_mlp / serve_moe /
 serve_head) and gated in CI by ``check_regression.py --aot-bytes``
 against the ``aot_regions`` golden (key
@@ -28,7 +28,7 @@ tokens emitted per verify step times the decode/verify byte ratio from
 the AOT census (verify golden key ``<model>_verify b<bucket> s<K+1> -``).
 
 Human-readable progress goes to stderr; the result JSON to stdout
-(pipeable into check_regression.py, like bench.py).
+(pipeable into check_regression.py).
 """
 
 from __future__ import annotations
@@ -268,8 +268,7 @@ def aot_decode_report(model_name: str, *, batch: int, page_size: int,
                       precision: str = "fp32") -> dict:
     """Chipless AOT byte/FLOP model of ONE decode step at one batch bucket.
 
-    Same scheme as profile_step.aot_report: lower the exact engine decode
-    program with abstract inputs, tabulate modeled HBM bytes per serve_*
+    Lower the exact engine decode program with abstract inputs, tabulate modeled HBM bytes per serve_*
     named-scope region with proportional fusion attribution, and stamp the
     lowering backend so goldens never compare across backends."""
     import collections

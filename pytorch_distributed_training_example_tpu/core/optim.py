@@ -41,17 +41,19 @@ def build_schedule(cfg: Config, steps_per_epoch: int) -> optax.Schedule:
     return main
 
 
-def adam_b2(cfg: Config) -> float:
+def adam_b2(task: str) -> float:
     """AdamW's second-moment decay, chosen by the model's task and not by a
     substring of its name: 0.95 for every language model (the GPT-3 / Llama
     recipe), optax's 0.999 for the rest (ViT)."""
-    from pytorch_distributed_training_example_tpu.models import registry
-
-    return 0.95 if registry.create_model(cfg.model).task == "lm" else 0.999
+    return 0.95 if task == "lm" else 0.999
 
 
-def build_optimizer(cfg: Config, steps_per_epoch: int):
-    """Returns ``(tx, schedule)``; schedule is also used for logging lr."""
+def build_optimizer(cfg: Config, steps_per_epoch: int, task: str | None = None):
+    """Returns ``(tx, schedule)``; schedule is also used for logging lr.
+
+    ``task`` is the model bundle's (``core/trainer.build_step_program`` has
+    it in hand); a caller with a ``Config`` alone leaves it out and AdamW
+    asks the registry."""
     schedule = build_schedule(cfg, steps_per_epoch)
     parts = []
     if cfg.grad_clip and cfg.grad_clip > 0:
@@ -65,8 +67,12 @@ def build_optimizer(cfg: Config, steps_per_epoch: int):
             parts.insert(-1, optax.add_decayed_weights(
                 cfg.weight_decay, mask=_wd_mask))
     elif cfg.optimizer == "adamw":
+        if task is None:
+            from pytorch_distributed_training_example_tpu.models import registry
+
+            task = registry.create_model(cfg.model).task
         parts.append(optax.adamw(
-            schedule, b1=0.9, b2=adam_b2(cfg),
+            schedule, b1=0.9, b2=adam_b2(task),
             weight_decay=cfg.weight_decay, mask=_wd_mask,
         ))
     else:

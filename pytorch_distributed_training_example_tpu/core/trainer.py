@@ -9,10 +9,12 @@ are fetched every ``log_every`` steps.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import tempfile
 import time
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +48,86 @@ from pytorch_distributed_training_example_tpu.utils.config import Config
 from pytorch_distributed_training_example_tpu.utils.logging import (
     AverageMeter, MetricLogger, Throughput, log, setup_logging,
 )
+
+
+@dataclasses.dataclass(frozen=True)
+class StepProgram:
+    """What a ``Config`` on a mesh compiles to, before any data or array."""
+    mesh: Any
+    tx: Any
+    schedule: Callable
+    rules: tuple
+    init_state: Callable          # () -> the sharded TrainState (traced init)
+    train_step: Callable          # jitted, the state donated
+    eval_step: Callable           # jitted
+    batch_sharding: Any
+
+    def abstract_state(self):
+        """The state's shapes with their shardings and no array behind them:
+        what a tool lowers ``train_step`` on."""
+        shape = jax.eval_shape(self.init_state)
+        shardings = train_loop.state_shardings(shape, self.mesh, self.rules)
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shape, shardings)
+
+
+def build_model(cfg: Config) -> registry.ModelBundle:
+    """The registry's bundle for ``cfg``: the model under the precision
+    policy, with every model-shaping field of ``Config``. The first half of
+    the one path from a ``Config`` to the step program: the datasets want
+    the model's vocabulary before ``build_step_program`` can be given the
+    loader's steps per epoch."""
+    policy = precision_lib.get_policy(cfg.precision)
+    return registry.create_model(
+        cfg.model, num_classes=cfg.num_classes, image_size=cfg.image_size,
+        seq_len=cfg.seq_len, dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype, logits_dtype=policy.logits_dtype,
+        **cfg.model_options())
+
+
+def build_step_program(cfg: Config, mesh, steps_per_epoch: int,
+                       bundle: registry.ModelBundle) -> StepProgram:
+    """Optimizer, sharding rules, state constructor and the jitted steps for
+    ``build_model(cfg)``'s bundle on ``mesh``. The ``Trainer`` runs what
+    this returns; ``benchmarks/graftlint.py`` lowers it."""
+    tx, schedule = optim.build_optimizer(cfg, steps_per_epoch,
+                                         task=bundle.task)
+    # Warm the schedule's op-by-op dispatch here, inside the init span:
+    # the first eager evaluation costs ~0.2s of tracing that would
+    # otherwise land UNATTRIBUTED between the first step's spans and
+    # drag goodput coverage below its gate.
+    float(schedule(0))
+    policy = precision_lib.get_policy(cfg.precision)
+    scaler = (precision_lib.ScalerState.create()
+              if precision_lib.needs_loss_scaling(policy) else None)
+    model = bundle.module
+    if cfg.strategy == "pp":
+        from pytorch_distributed_training_example_tpu.parallel import pp_lm
+
+        if not hasattr(model, "scan_layers"):
+            raise ValueError("strategy 'pp' currently supports the Llama "
+                             "family (scan-stacked blocks)")
+        model = pp_lm.PipelinedLlama(model, mesh, cfg.pp_microbatches)
+        rules = pp_lm.PP_RULES
+    else:
+        rules = sharding_lib.strategy_rules(cfg.strategy, bundle.rules)
+
+    def init_state():
+        return train_loop.create_train_state(
+            model, tx, bundle.input_template, mesh, rules, seed=cfg.seed,
+            scaler=scaler)
+
+    task = train_loop.get_task(bundle.task, cfg.label_smoothing)
+    return StepProgram(
+        mesh=mesh, tx=tx, schedule=schedule, rules=rules,
+        init_state=init_state,
+        train_step=jax.jit(
+            train_loop.make_train_step(task, cfg.grad_accum_steps,
+                                       health=cfg.telemetry),
+            donate_argnums=0),
+        eval_step=jax.jit(train_loop.make_eval_step(task)),
+        batch_sharding=mesh_lib.batch_sharding(mesh))
 
 
 class Trainer:
@@ -139,24 +221,7 @@ class Trainer:
         if cfg.elastic and cfg.resume:
             cfg = self._plan_elastic(cfg)
             self.cfg = cfg
-        self.policy = precision_lib.get_policy(cfg.precision)
-
-        self.bundle = registry.create_model(
-            cfg.model, num_classes=cfg.num_classes, image_size=cfg.image_size,
-            seq_len=cfg.seq_len, dtype=self.policy.compute_dtype,
-            param_dtype=self.policy.param_dtype, remat=cfg.remat,
-            remat_policy=cfg.remat_policy,
-            sp=cfg.strategy.endswith("_sp"), attn_impl=cfg.attn_impl,
-            dropout=cfg.dropout,
-            moe_capacity_factor=cfg.moe_capacity_factor,
-            moe_top_k=cfg.moe_top_k,
-            moe_dispatch_impl=cfg.moe_dispatch_impl,
-            moe_combine_dtype=cfg.moe_combine_dtype,
-            moe_router_dtype=cfg.moe_router_dtype,
-            moe_router_impl=cfg.moe_router_impl,
-            moe_ep_dispatch=cfg.moe_ep_dispatch,
-            moe_ep_overlap_chunks=cfg.moe_ep_overlap_chunks,
-            logits_dtype=self.policy.logits_dtype)
+        self.bundle = build_model(cfg)
 
         # data ------------------------------------------------------------
         vocab = getattr(self.bundle.module, "vocab_size", 50257)
@@ -220,38 +285,14 @@ class Trainer:
             # step-site ones: epoch * steps_per_epoch + batch.
             self._chaos.steps_per_epoch = self.steps_per_epoch
 
-        # optimizer / state ------------------------------------------------
-        self.tx, self.schedule = optim.build_optimizer(cfg, self.steps_per_epoch)
-        # Warm the schedule's op-by-op dispatch here, inside the init span:
-        # the first eager evaluation costs ~0.2s of tracing that would
-        # otherwise land UNATTRIBUTED between the first step's spans and
-        # drag goodput coverage below its gate.
-        float(self.schedule(0))
-        scaler = (precision_lib.ScalerState.create()
-                  if precision_lib.needs_loss_scaling(self.policy) else None)
-        model = self.bundle.module
-        if cfg.strategy == "pp":
-            from pytorch_distributed_training_example_tpu.parallel import pp_lm
-
-            if not hasattr(model, "scan_layers"):
-                raise ValueError("strategy 'pp' currently supports the Llama "
-                                 "family (scan-stacked blocks)")
-            model = pp_lm.PipelinedLlama(model, self.mesh,
-                                         cfg.pp_microbatches)
-            rules = pp_lm.PP_RULES
-        else:
-            rules = sharding_lib.strategy_rules(cfg.strategy, self.bundle.rules)
-        self.state = train_loop.create_train_state(
-            model, self.tx, self.bundle.input_template,
-            self.mesh, rules, seed=cfg.seed, scaler=scaler)
-
-        task = train_loop.get_task(self.bundle.task, cfg.label_smoothing)
-        self.train_step = jax.jit(
-            train_loop.make_train_step(task, cfg.grad_accum_steps,
-                                       health=cfg.telemetry),
-            donate_argnums=0)
-        self.eval_step = jax.jit(train_loop.make_eval_step(task))
-        self.batch_sharding = mesh_lib.batch_sharding(self.mesh)
+        # optimizer / state / steps -----------------------------------------
+        program = build_step_program(cfg, self.mesh, self.steps_per_epoch,
+                                     self.bundle)
+        self.schedule = program.schedule
+        self.state = program.init_state()
+        self.train_step = program.train_step
+        self.eval_step = program.eval_step
+        self.batch_sharding = program.batch_sharding
 
         # checkpointing ----------------------------------------------------
         self.checkpointer = (checkpoint_lib.Checkpointer(cfg.checkpoint_dir)

@@ -4,8 +4,8 @@ The restart tax of an elastic relaunch is dominated by two costs the
 checkpoint machinery never touched: re-tracing + re-compiling the train
 step, and re-assembling the checkpoint layout (core/reshard.py owns the
 second). This module removes the first: the exact
-``jit(...).lower(...).compile()`` front-end the trainer, ``profile_step.py
---aot`` and the serve warmup all share is keyed on a **fingerprint** of
+``jit(...).lower(...).compile()`` front-end the trainer and the serve
+warmup share is keyed on a **fingerprint** of
 everything that can change the lowered program — jax version, backend,
 topology (process/device counts), mesh shape, the config knobs that reach
 tracing, and the abstract avals+shardings of every input — and the
@@ -344,8 +344,8 @@ def place_compile_cache(fallback_dir: str = DEFAULT_COMPILE_CACHE_DIR,
     """Turn on jax's persistent compilation cache; returns its directory.
 
     The ONE place this repo sets ``jax_compilation_cache_dir`` (main.py,
-    bench.py, chip_smoke.py and tests/conftest.py all call it). Where
-    ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it, and no
+    chip_smoke.py, the benchmark's driver and tests/conftest.py all call
+    it). Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it, and no
     directory is set in code — the cache can be placed from outside.
     """
     jax.config.update("jax_persistent_cache_min_compile_time_secs",

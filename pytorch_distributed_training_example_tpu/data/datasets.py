@@ -8,9 +8,8 @@ or ``tokens``). Real data:
 - ImageNet-style class-per-directory trees via :class:`FolderDataset`
   (JPEG decode through PIL/libjpeg-turbo, or the native C++ engine's libjpeg
   path — data/native_loader.py); the synthetic variants below stand in when
-  no dataset is on disk (benchmarking uses them — input pipeline excluded
-  from the MFU measurement the same way the reference's synthetic-data mode
-  would; ``bench.py --include-input`` measures the full pipeline).
+  no dataset is on disk (``chip_smoke.py`` trains on them; the benchmark's
+  cells put their own seeded rows in the loader, ``chipbench/rows/``).
 """
 
 from __future__ import annotations

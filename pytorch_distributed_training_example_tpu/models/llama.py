@@ -232,11 +232,11 @@ class LlamaBlock(nn.Module):
 
 
 #: Remat policies for the grad-checkpoint config (selected by name so the
-#: flag threads through Config/argparse). "nothing" is the measured default
-#: (BENCH_LLAMA.json: rate-neutral at S=8192 b=1 vs no-remat, and the only
-#: policy that admits b=2). The alternatives trade activation memory for
-#: recompute FLOPs — A/B them with bench.py --remat-policy (see
-#: PROFILE_LLAMA.md lever 4):
+#: flag threads through Config/argparse). "nothing" is the default (r4, on
+#: a machine that is gone: rate-neutral at S=8192 b=1 vs no-remat, and the
+#: only policy that admitted b=2). The alternatives trade activation memory
+#: for recompute FLOPs; none has a reading on this machine
+#: (``main.py --remat-policy``):
 #:   nothing       recompute the whole block (minimum memory)
 #:   dots          save every matmul output (maximum saveable under remat)
 #:   dots_no_batch save matmul outputs with no batch dims (XLA's classic
@@ -418,9 +418,9 @@ def llama3_8b(**kw) -> Llama:
 
 def llama_400m(**kw) -> Llama:
     """One-chip bench scale: full Llama architecture (GQA 4:1, RoPE,
-    SwiGLU, RMSNorm) at ~400M params so the family has a measured
-    single-v5e perf row (BENCH_LLAMA.json) alongside the 8B feasibility
-    artifact. Llama-2-sized vocab keeps embeddings from dominating."""
+    SwiGLU, RMSNorm) at ~400M params, sized for a single v5e. Llama-2-sized
+    vocab keeps embeddings from dominating. A test and dry-run preset: the
+    family is not a benchmark configuration (ROADMAP queue B)."""
     kw.setdefault("vocab_size", 32000)
     kw.setdefault("num_layers", 16)
     kw.setdefault("num_heads", 16)
@@ -450,10 +450,9 @@ def llama_moe_tiny(**kw) -> Llama:
 
 
 def llama_moe_520m(**kw) -> Llama:
-    """Bench-scale MoE Llama for the measured e2e EP row (BENCH_MOE.json):
-    the llama_400m trunk (d=1024, GQA 4:1, RoPE) at 12 layers with
-    8-expert top-2 MoE FFNs of ffn_dim 2048 — ~520M total / ~220M active
-    params. Sized so AdamW optimizer state (12 B/param f32) + bf16
+    """One-chip MoE Llama: the llama_400m trunk (d=1024, GQA 4:1, RoPE) at
+    12 layers with 8-expert top-2 MoE FFNs of ffn_dim 2048 — ~520M total /
+    ~220M active params. Sized so AdamW optimizer state (12 B/param f32) + bf16
     compute copies + activations fit ONE v5e's 16 GB HBM: the 400m
     backbone with 8 experts (1.18 B total) measured RESOURCE_EXHAUSTED
     at any batch, with or without remat — expert stacks multiply FFN

@@ -16,6 +16,7 @@ chip's share of it, ``granite_hybrid_tiny`` for tests; training only,
 from __future__ import annotations
 
 import dataclasses
+import inspect
 from typing import Any, Callable
 
 import jax.numpy as jnp
@@ -45,104 +46,78 @@ def list_models() -> list[str]:
     return sorted(_REGISTRY)
 
 
+#: What a caller may say about a model beyond its sizes and dtypes, with the
+#: value that asks for nothing. A family's builder names the ones it takes
+#: and lets the rest pass (the Trainer hands every family the whole set),
+#: except the two in ``_NEVER_DROPPED``: those are refused, at any other
+#: value, by a family that has no such knob.
+_OPTIONS = dict(remat=False, remat_policy="nothing", sp=False,
+                attn_impl="auto", dropout=0.0)
+_NEVER_DROPPED = {
+    "dropout": "the Llama and ResNet families have no dropout knob, matching "
+               "the reference factories",
+    "remat_policy": "only the Llama family exposes checkpoint-policy tuning",
+}
+
+
 def create_model(name: str, *, num_classes: int = 1000, image_size: int = 224,
-                 seq_len: int = 1024, dtype=jnp.bfloat16, param_dtype=jnp.float32,
-                 remat: bool = False, remat_policy: str = "nothing",
-                 sp: bool = False,
-                 attn_impl: str = "auto", dropout: float = 0.0,
-                 moe_capacity_factor: float = 1.25,
-                 moe_top_k: int = 2,
-                 moe_dispatch_impl: str = "gather",
-                 moe_combine_dtype: str = "fp32",
-                 moe_router_dtype: str = "fp32",
-                 moe_router_impl: str = "reference",
-                 moe_ep_dispatch: str = "replicated",
-                 moe_ep_overlap_chunks: int = 2,
-                 logits_dtype=jnp.float32) -> ModelBundle:
+                 seq_len: int = 1024, dtype=jnp.bfloat16,
+                 param_dtype=jnp.float32, logits_dtype=jnp.float32,
+                 **options) -> ModelBundle:
+    """``options`` (``_OPTIONS`` and the expert models' ``_MOE_OPTIONS``) go
+    whole to the family's builder, which names the ones it takes."""
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; have {list_models()}")
     builder = _REGISTRY[name]
-    if dropout != 0.0:
-        import inspect
-
-        if "dropout" not in inspect.signature(builder).parameters:
+    named = inspect.signature(builder).parameters
+    for opt, value in options.items():
+        if opt not in _OPTIONS and opt not in _MOE_OPTIONS:
+            raise TypeError(
+                f"create_model() got an unexpected option {opt!r}; have "
+                f"{sorted({**_OPTIONS, **_MOE_OPTIONS})}")
+        if (opt in _NEVER_DROPPED and opt not in named
+                and value != _OPTIONS[opt]):
             raise ValueError(
-                f"model {name!r} does not implement dropout; --dropout "
-                f"{dropout} would be silently ignored (the Llama and ResNet "
-                "families have no dropout knob, matching the reference "
-                "factories)")
-    if remat_policy != "nothing":
-        import inspect
-
-        if "remat_policy" not in inspect.signature(builder).parameters:
-            raise ValueError(
-                f"model {name!r} does not implement remat_policy; "
-                f"--remat-policy {remat_policy} would be silently ignored "
-                "(only the Llama family exposes checkpoint-policy tuning)")
-    return builder(
-        num_classes=num_classes, image_size=image_size, seq_len=seq_len,
-        dtype=dtype, param_dtype=param_dtype, remat=remat,
-        remat_policy=remat_policy, sp=sp,
-        attn_impl=attn_impl, dropout=dropout,
-        moe_capacity_factor=moe_capacity_factor,
-        moe_top_k=moe_top_k, moe_dispatch_impl=moe_dispatch_impl,
-        moe_combine_dtype=moe_combine_dtype,
-        moe_router_dtype=moe_router_dtype, moe_router_impl=moe_router_impl,
-        moe_ep_dispatch=moe_ep_dispatch,
-        moe_ep_overlap_chunks=moe_ep_overlap_chunks,
-        logits_dtype=logits_dtype,
-    )
+                f"model {name!r} does not implement {opt}; {opt}={value!r} "
+                f"would be silently ignored ({_NEVER_DROPPED[opt]})")
+    return builder(num_classes=num_classes, image_size=image_size,
+                   seq_len=seq_len, dtype=dtype, param_dtype=param_dtype,
+                   logits_dtype=logits_dtype, **{**_OPTIONS, **options})
 
 
-# --moe-combine flag values -> MoEBlock.combine_dtype (None = fp32, exact);
-# --moe-router-dtype uses the same spelling for MoEBlock.router_dtype.
-_MOE_COMBINE_DTYPES = {"fp32": None, "bf16": jnp.bfloat16}
-_MOE_ROUTER_IMPLS = ("reference", "fused")
-_MOE_DISPATCH_IMPLS = ("sort", "gather", "einsum", "dropless")
-_MOE_EP_DISPATCH = ("replicated", "a2a", "a2a_overlap")
+_MOE_DTYPES = {"fp32": None, "bf16": jnp.bfloat16}
+#: The expert layers' options (``parallel/moe.MoEBlock``): the value that
+#: asks for nothing, and the values a spelt-out one may take.
+_MOE_OPTIONS = {
+    "moe_capacity_factor": (1.25, None),
+    "moe_top_k": (2, None),
+    "moe_dispatch_impl": ("gather", ("sort", "gather", "einsum", "dropless")),
+    "moe_combine_dtype": ("fp32", tuple(sorted(_MOE_DTYPES))),
+    "moe_router_dtype": ("fp32", tuple(sorted(_MOE_DTYPES))),
+    "moe_router_impl": ("reference", ("reference", "fused")),
+    "moe_ep_dispatch": ("replicated", ("replicated", "a2a", "a2a_overlap")),
+    "moe_ep_overlap_chunks": (2, None),
+}
 
 
-def _moe_kwargs(moe_capacity_factor, moe_top_k, moe_dispatch_impl,
-                moe_combine_dtype, moe_router_dtype="fp32",
-                moe_router_impl="reference", moe_ep_dispatch="replicated",
-                moe_ep_overlap_chunks=2):
-    if moe_dispatch_impl not in _MOE_DISPATCH_IMPLS:
+def _moe_kwargs(**moe):
+    """An expert builder's options, checked, as ``Llama``'s fields: the two
+    dtype spellings become dtypes (None = fp32, exact)."""
+    moe = {k: moe.get(k, default) for k, (default, _) in _MOE_OPTIONS.items()}
+    for key, (_, have) in _MOE_OPTIONS.items():
+        if have is not None and moe[key] not in have:
+            raise ValueError(f"unknown {key} {moe[key]!r}; have {list(have)}")
+    ep, chunks = moe["moe_ep_dispatch"], int(moe["moe_ep_overlap_chunks"])
+    if ep != "replicated" and moe["moe_dispatch_impl"] != "dropless":
         raise ValueError(
-            f"unknown moe_dispatch_impl {moe_dispatch_impl!r}; "
-            f"have {list(_MOE_DISPATCH_IMPLS)}")
-    if moe_ep_dispatch not in _MOE_EP_DISPATCH:
-        raise ValueError(
-            f"unknown moe_ep_dispatch {moe_ep_dispatch!r}; "
-            f"have {list(_MOE_EP_DISPATCH)}")
-    if moe_ep_dispatch != "replicated" and moe_dispatch_impl != "dropless":
-        raise ValueError(
-            f"moe_ep_dispatch={moe_ep_dispatch!r} requires "
-            f"moe_dispatch_impl='dropless' (got {moe_dispatch_impl!r}); the "
-            "capacity-dropped impls shard through GSPMD alone")
-    if int(moe_ep_overlap_chunks) < 1:
-        raise ValueError(
-            f"moe_ep_overlap_chunks must be >= 1 "
-            f"(got {moe_ep_overlap_chunks})")
-    if moe_combine_dtype not in _MOE_COMBINE_DTYPES:
-        raise ValueError(
-            f"unknown moe_combine_dtype {moe_combine_dtype!r}; "
-            f"have {sorted(_MOE_COMBINE_DTYPES)}")
-    if moe_router_dtype not in _MOE_COMBINE_DTYPES:
-        raise ValueError(
-            f"unknown moe_router_dtype {moe_router_dtype!r}; "
-            f"have {sorted(_MOE_COMBINE_DTYPES)}")
-    if moe_router_impl not in _MOE_ROUTER_IMPLS:
-        raise ValueError(
-            f"unknown moe_router_impl {moe_router_impl!r}; "
-            f"have {list(_MOE_ROUTER_IMPLS)}")
-    return dict(moe_capacity_factor=moe_capacity_factor,
-                moe_top_k=moe_top_k,
-                moe_dispatch_impl=moe_dispatch_impl,
-                moe_combine_dtype=_MOE_COMBINE_DTYPES[moe_combine_dtype],
-                moe_router_dtype=_MOE_COMBINE_DTYPES[moe_router_dtype],
-                moe_router_impl=moe_router_impl,
-                moe_ep_dispatch=moe_ep_dispatch,
-                moe_ep_overlap_chunks=int(moe_ep_overlap_chunks))
+            f"moe_ep_dispatch={ep!r} requires moe_dispatch_impl='dropless' "
+            f"(got {moe['moe_dispatch_impl']!r}); the capacity-dropped impls "
+            "shard through GSPMD alone")
+    if chunks < 1:
+        raise ValueError(f"moe_ep_overlap_chunks must be >= 1 (got {chunks})")
+    return {**moe, "moe_ep_overlap_chunks": chunks,
+            "moe_combine_dtype": _MOE_DTYPES[moe["moe_combine_dtype"]],
+            "moe_router_dtype": _MOE_DTYPES[moe["moe_router_dtype"]]}
 
 
 @register("vit_b16")
@@ -261,12 +236,8 @@ def _llama_tiny(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
 
 @register("llama_moe_tiny")
 def _llama_moe_tiny(*, seq_len, dtype, param_dtype, remat,
-                    remat_policy="nothing", sp=False,
-                    attn_impl="auto", moe_capacity_factor=1.25, moe_top_k=2,
-                    moe_dispatch_impl="gather", moe_combine_dtype="fp32",
-                    moe_router_dtype="fp32", moe_router_impl="reference",
-                    moe_ep_dispatch="replicated", moe_ep_overlap_chunks=2,
-                    logits_dtype, **_):
+                    remat_policy="nothing", sp=False, attn_impl="auto",
+                    logits_dtype, **options):
     from pytorch_distributed_training_example_tpu.models import llama
 
     module = llama.llama_moe_tiny(dtype=dtype, param_dtype=param_dtype,
@@ -274,13 +245,7 @@ def _llama_moe_tiny(*, seq_len, dtype, param_dtype, remat,
                                   max_seq_len=max(seq_len, 256),
                                   sp=sp, attn_impl=attn_impl,
                                   logits_dtype=logits_dtype,
-                                  **_moe_kwargs(moe_capacity_factor, moe_top_k,
-                                                moe_dispatch_impl,
-                                                moe_combine_dtype,
-                                                moe_router_dtype,
-                                                moe_router_impl,
-                                                moe_ep_dispatch,
-                                                moe_ep_overlap_chunks))
+                                  **_moe_kwargs(**options))
     # MFU basis = ACTIVE params (top-2 experts), not the full expert stack
     return _lm_bundle(module, llama.TP_RULES, seq_len,
                       llama.num_params_active)
@@ -288,14 +253,9 @@ def _llama_moe_tiny(*, seq_len, dtype, param_dtype, remat,
 
 @register("llama_moe")
 def _llama_moe(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
-               sp=False,
-               attn_impl="auto", moe_capacity_factor=1.25, moe_top_k=2,
-               moe_dispatch_impl="gather", moe_combine_dtype="fp32",
-               moe_router_dtype="fp32", moe_router_impl="reference",
-               moe_ep_dispatch="replicated", moe_ep_overlap_chunks=2,
-               logits_dtype, **_):
-    """Bench-scale MoE (llama trunk, 8 experts top-2, ~520M total): the
-    e2e EP perf row on the real chip (BENCH_MOE.json e2e, BASELINE.md)."""
+               sp=False, attn_impl="auto", logits_dtype, **options):
+    """Llama trunk with 8 experts, top-2, ~520M parameters in all: sized so
+    that AdamW's state fits one v5e (``llama.llama_moe_520m``)."""
     from pytorch_distributed_training_example_tpu.models import llama
 
     module = llama.llama_moe_520m(dtype=dtype, param_dtype=param_dtype,
@@ -303,13 +263,7 @@ def _llama_moe(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
                                   max_seq_len=max(seq_len, 2048),
                                   sp=sp, attn_impl=attn_impl,
                                   logits_dtype=logits_dtype,
-                                  **_moe_kwargs(moe_capacity_factor, moe_top_k,
-                                                moe_dispatch_impl,
-                                                moe_combine_dtype,
-                                                moe_router_dtype,
-                                                moe_router_impl,
-                                                moe_ep_dispatch,
-                                                moe_ep_overlap_chunks))
+                                  **_moe_kwargs(**options))
     return _lm_bundle(module, llama.TP_RULES, seq_len,
                       llama.num_params_active)
 

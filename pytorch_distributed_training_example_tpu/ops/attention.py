@@ -50,43 +50,6 @@ def _causal_masked(logits, q_offset):
     return jnp.where(q_pos >= k_pos, logits, NEG_INF)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _softmax_lowp_residual(logits, out_dtype, causal, q_offset):
-    """(mask +) f32 softmax whose ONLY autodiff residual is the
-    low-precision probs.
-
-    Plain ``softmax(logits).astype(bf16)`` saves the f32 probs for the
-    softmax VJP *and* the bf16 copy for the downstream PV matmul VJP —
-    at ViT-B/16 shapes that f32 residual is 119 MB/layer of pure HBM
-    traffic (PROFILE_VIT.md). Backward here recomputes the softmax VJP
-    from the bf16 probs instead: dlogits = p * (g - <g, p>). The causal
-    mask lives INSIDE this op (static ``q_offset`` only) because masked
-    rows have p = 0, so the backward needs no mask residual either; a
-    ``jnp.where`` outside would pin an extra [B,H,S,S] f32 + bool pair.
-    Precision cost is one bf16 rounding of p inside an expression that is
-    already evaluated in the model's bf16 compute dtype;
-    exactness-sensitive callers keep the default exact path.
-    """
-    if causal:
-        logits = _causal_masked(logits, q_offset)
-    return jax.nn.softmax(logits, axis=-1).astype(out_dtype)
-
-
-def _softmax_lowp_fwd(logits, out_dtype, causal, q_offset):
-    p = _softmax_lowp_residual(logits, out_dtype, causal, q_offset)
-    return p, p
-
-
-def _softmax_lowp_bwd(out_dtype, causal, q_offset, p_lowp, g):
-    p = p_lowp.astype(jnp.float32)
-    g = g.astype(jnp.float32)
-    d = p * (g - jnp.sum(g * p, axis=-1, keepdims=True))
-    return (d,)
-
-
-_softmax_lowp_residual.defvjp(_softmax_lowp_fwd, _softmax_lowp_bwd)
-
-
 def dot_product_attention(
     q: jax.Array,           # [B, Sq, H, D]
     k: jax.Array,           # [B, Skv, Hkv, D]
@@ -95,19 +58,12 @@ def dot_product_attention(
     causal: bool = False,
     bias: jax.Array | None = None,
     q_offset: int | jax.Array = 0,
-    lowp_residual: bool = False,
 ) -> jax.Array:
     """Reference attention in pure XLA; fp32 softmax, inputs' dtype out.
 
     ``q_offset`` positions the query block within the global sequence for
     causal masking (used by the ring schedule where K/V blocks come from
     other context shards).
-
-    ``lowp_residual=True`` stores the attention probabilities for backward
-    in the compute dtype instead of f32 (see
-    :func:`_softmax_lowp_residual`) — the dispatcher enables it for
-    low-precision training, where it removes half the dominant residual
-    traffic at short-sequence shapes the flash kernels don't serve.
     """
     orig_dtype = q.dtype
     depth = q.shape[-1]
@@ -118,16 +74,9 @@ def dot_product_attention(
     logits = logits * (1.0 / math.sqrt(depth))
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
-    # The low-precision-residual path also wants the causal mask inside
-    # its custom VJP (see _softmax_lowp_residual); it needs a STATIC
-    # q_offset — ring schedules pass traced offsets and use the exact path.
-    if (lowp_residual and v.dtype != jnp.float32
-            and isinstance(q_offset, int)):
-        probs = _softmax_lowp_residual(logits, v.dtype, causal, q_offset)
-    else:
-        if causal:
-            logits = _causal_masked(logits, q_offset)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    if causal:
+        logits = _causal_masked(logits, q_offset)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                      preferred_element_type=jnp.float32)
     return out.astype(orig_dtype)
@@ -598,15 +547,13 @@ def attention(
 
             logging.getLogger(__name__).warning(
                 "%s; running XLA attention on cpu", msg)
-            return dot_product_attention(q, k, v, causal=causal,
-                                         lowp_residual=_lowp(q))
+            return dot_product_attention(q, k, v, causal=causal)
         from pytorch_distributed_training_example_tpu.ops import flash_attention
 
         return _per_device_flash(flash_attention.flash_attention, q, k, v,
                                  causal=causal, mesh=mesh,
                                  batch_axes=batch_axes)
-    return dot_product_attention(q, k, v, causal=causal,
-                                 lowp_residual=_lowp(q))
+    return dot_product_attention(q, k, v, causal=causal)
 
 
 def _per_device_flash(fn, q, k, v, *, causal, mesh, batch_axes):
@@ -634,23 +581,6 @@ def _per_device_flash(fn, q, k, v, *, causal, mesh, batch_axes):
     return mesh_lib.manual_call(
         functools.partial(fn, causal=causal), q, k, v, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec)
-
-
-def _lowp(q) -> bool:
-    """Model-path policy for the low-precision probs residual: OFF by
-    default — a measured NEGATIVE result on v5e (r5, paired A/B at
-    ViT-B/16: 70.4 ms/step vs 67.6 exact; PROFILE_VIT.md r5 addendum).
-    Halving the f32 probs residual's bytes loses to what XLA gives up
-    around the opaque custom-vjp boundary (the softmax-VJP chain no
-    longer fuses into the PV-matmul backward). PDTX_LOWP_RESIDUAL=1
-    enables it for low-precision dtypes — kept because the balance may
-    flip on bandwidth-poorer chips or bigger S where the residual
-    dominates harder."""
-    import os
-
-    if not os.environ.get("PDTX_LOWP_RESIDUAL"):
-        return False
-    return q.dtype in (jnp.bfloat16, jnp.float16)
 
 
 PAD_MULTIPLE = 64  # tile granularity shared by pad + eligibility below

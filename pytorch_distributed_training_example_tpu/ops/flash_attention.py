@@ -48,7 +48,8 @@ LSE_LANES = 8  # lse stored [B,H,S,8]: minor dims satisfy Mosaic tiling
 # D=128 long-S shapes get their own rows here as they are measured.
 ONLINE_BLOCK_TABLE: dict[tuple[bool, int, int], tuple[int, int]] = {
     # D=128, S=4096 fwd: default 1024x1024 measured 1.371 ms = 0.509 of MXU
-    # peak (BENCH_FLASH_MICRO.json r4) — the default IS the tuned choice.
+    # peak (r4, a machine that is gone; records in git at 6739a2e) — the
+    # default IS the tuned choice.
     (False, 4096, 128): (1024, 1024),
 }
 
@@ -388,8 +389,8 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
 #     physical VMEM (the model is known to over-count), chosen as the
 #     smallest cap that preserves every plan choice the r4 benches
 #     measured on-chip (Llama-400M bwd (G=1, bq=256) at S=2048/D=128 =
-#     11.3 MB, BENCH_LLAMA.json r4_update; S=4096/D=128 non-causal fwd
-#     (G=1, bq=256) = 12.5 MB, BENCH_FLASH_MICRO.json);
+#     11.3 MB; S=4096/D=128 non-causal fwd (G=1, bq=256) = 12.5 MB; both
+#     r4 readings of a machine that is gone, records in git at 6739a2e);
 #   - plans above 13 MB are admitted under auto only via the explicit
 #     measured allowlist below;
 #   - forced impl="oneshot" keeps the 17 MB cap (an opt-in: the caller
@@ -1083,9 +1084,9 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
       ``CAUSAL_MEASURED``: the causal kernels, both directions (PERF.md
       section 6, PR 27: 0.73 vs 1.05 ms forward and 1.30 vs 1.94 backward
       a layer at B24·H12·S1024·D64).
-    - Other causal forwards: the streaming online kernel (r4,
-      BENCH_FLASH_MICRO.json: 0.72 vs 0.86 ms one-shot at S2048; 1.37 vs
-      1.99 at S4096/D128). Its grid skips fully-masked kv blocks only where
+    - Other causal forwards: the streaming online kernel (r4, on a machine
+      that is gone: 0.72 vs 0.86 ms one-shot at S2048; 1.37 vs 1.99 at
+      S4096/D128). Its grid skips fully-masked kv blocks only where
       S spans more than one 1024-block.
     - Other backwards: the one-shot chunked kernel whenever its plan fits
       VMEM; otherwise streaming (D=128) or online.

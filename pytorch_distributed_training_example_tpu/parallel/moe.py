@@ -31,9 +31,9 @@ tokens, capacity factor irrelevant; it is equivalence-tested against the
 einsum path at a capacity factor high enough to never drop. The step
 regions are tagged with ``jax.named_scope`` (``moe_router`` /
 ``moe_dispatch`` / ``moe_experts`` / ``moe_combine`` / ``moe_aux``, plus
-``moe_experts_gmm`` inside the dropless kernel) so
-``benchmarks/profile_step.py`` can attribute device time per region from
-an xplane trace (PROFILE_MOE.md).
+``moe_experts_gmm`` inside the dropless kernel) so a trace's device time
+can be attributed per region (``benchmarks/profile_step.build_op_moe_tags``;
+graftlint's GL102/GL104/GL105 key on the same tags).
 
 The dropless path additionally supports **expert-parallel sharded
 execution** (``ep_dispatch``, r17): instead of replicated-pinning the sorted

@@ -192,6 +192,17 @@ class Config:
                     expert=self.mesh_expert, context=self.mesh_context,
                     model=self.mesh_model)
 
+    def model_options(self) -> dict[str, Any]:
+        """The fields that shape the model, as ``registry.create_model``'s
+        options: the one place that says which they are. A family's builder
+        names the ones it takes; every ``moe_*`` field goes along by its
+        prefix, so a new one needs a field and a flag here and a name there."""
+        moe = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+               if f.name.startswith("moe_")}
+        return dict(remat=self.remat, remat_policy=self.remat_policy,
+                    sp=self.strategy.endswith("_sp"),
+                    attn_impl=self.attn_impl, dropout=self.dropout, **moe)
+
     def replace(self, **kw) -> "Config":
         return dataclasses.replace(self, **kw)
 

@@ -496,39 +496,6 @@ def test_padded_flash_grads(causal):
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_lowp_probs_residual_softmax():
-    """lowp_residual: forward is BIT-identical to the exact path (same f32
-    softmax, same cast); backward recomputes the softmax VJP from the bf16
-    probs — grads must match the exact path to bf16 rounding, and the
-    custom-vjp path must not save an f32 probs residual (its only residual
-    is the bf16 tensor)."""
-    key = jax.random.PRNGKey(0)
-    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (2, 64, 4, 16),
-                                 jnp.bfloat16) for i in range(3))
-    exact = A.dot_product_attention(q, k, v, causal=True)
-    lowp = A.dot_product_attention(q, k, v, causal=True, lowp_residual=True)
-    np.testing.assert_array_equal(np.asarray(exact, np.float32),
-                                  np.asarray(lowp, np.float32))
-    ge = jax.grad(lambda *a: A.dot_product_attention(
-        *a, causal=True).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
-    gl = jax.grad(lambda *a: A.dot_product_attention(
-        *a, causal=True, lowp_residual=True).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(ge, gl):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=0.05, atol=0.02)
-    # the saved residual really is low-precision: no f32 tensor of the
-    # probs shape [B,H,S,S] survives to the backward closure
-    _, vjp = jax.vjp(lambda *a: A.dot_product_attention(
-        *a, causal=True, lowp_residual=True), q, k, v)
-    f32_probs_residuals = [
-        x for x in jax.tree.leaves(vjp)
-        if hasattr(x, "shape") and x.shape == (2, 4, 64, 64)
-        and x.dtype == jnp.float32]
-    assert not f32_probs_residuals
-
-
 def test_oneshot_plan_dispatch_thresholds():
     """Lock in the measured auto-dispatch map (BENCH_FLASH_MICRO.json r4):
     causal forwards stream (online), backwards go one-shot whenever the

@@ -209,65 +209,6 @@ def test_gl004_complete_threading_is_clean(tmp_path):
     assert rules(found) == []
 
 
-def test_gl004_perf_knob_must_reach_bench_cli(tmp_path):
-    found = lint_tree(tmp_path, {
-        f"{PKG}/utils/config.py": GL004_CONFIG,
-        "main.py": """
-            import argparse
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--lr", type=float, default=None)
-                p.add_argument("--momentum", type=float, default=None)
-                return p
-        """,
-        "bench.py": """
-            import argparse
-
-            def setup_step(model, momentum=0.9):
-                pass
-
-            def main():
-                p = argparse.ArgumentParser()
-                p.add_argument("--model", default="resnet18")
-                args = p.parse_args()
-                setup_step(args.model)
-        """,
-    })
-    assert rules(found) == ["GL004"]
-    assert "perf knob 'momentum'" in found[0].message
-
-
-def test_gl004_renamed_dest_traced_through_kwarg(tmp_path):
-    """bench.py threads --mom via setup_step(momentum=args.mom): reachable."""
-    found = lint_tree(tmp_path, {
-        f"{PKG}/utils/config.py": GL004_CONFIG,
-        "main.py": """
-            import argparse
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--lr", type=float, default=None)
-                p.add_argument("--momentum", type=float, default=None)
-                return p
-        """,
-        "bench.py": """
-            import argparse
-
-            def setup_step(model, momentum=0.9):
-                pass
-
-            def main():
-                p = argparse.ArgumentParser()
-                p.add_argument("--model", default="resnet18")
-                p.add_argument("--mom", type=float, default=0.9)
-                args = p.parse_args()
-                setup_step(args.model, momentum=args.mom)
-        """,
-    })
-    assert rules(found) == []
-
-
 # -- GL005: wall-clock / unseeded randomness --------------------------------
 
 def test_gl005_unseeded_randomness_flagged(tmp_path):
@@ -525,6 +466,15 @@ def test_ir_a2a_scope_rule(tiny, scope):
 
 
 # -- whole-tree gate + baseline workflow ------------------------------------
+
+def test_lint_ir_lowers_the_trainers_program():
+    """``run_ir`` lowers what the ``Trainer`` would run for this model (the
+    one builder, shapes in the state's place): the state is donated whole,
+    nothing crosses to the host, and on 8 devices ``fsdp`` anchors layouts."""
+    found = graftlint.run_ir("llama_tiny", seq_len=32)
+    assert not [f for f in found if f.severity == graftlint.ERROR], found
+    assert [f.rule for f in found if f.scope == "sharding"] == ["GL104"]
+
 
 def test_whole_tree_zero_unbaselined_errors():
     findings = graftlint.run_ast(REPO)

@@ -47,6 +47,53 @@ def test_dropout_rejected_for_families_without_it():
     assert on.module.dropout == 0.1
 
 
+@pytest.mark.parametrize("option,given,on_module", [
+    ("moe_top_k", 1, 1),
+    ("moe_capacity_factor", 2.0, 2.0),
+    ("moe_dispatch_impl", "sort", "sort"),
+    ("moe_combine_dtype", "bf16", jnp.bfloat16),
+    ("moe_router_dtype", "bf16", jnp.bfloat16),
+    ("moe_router_impl", "fused", "fused"),
+    ("moe_ep_dispatch", "a2a", "a2a"),
+    ("moe_ep_overlap_chunks", 3, 3),
+])
+def test_create_model_forwards_each_option(option, given, on_module):
+    """``create_model`` names no family's option: what a caller gives the
+    expert model is what its module holds (the dtype spellings as dtypes)."""
+    more = ({"moe_dispatch_impl": "dropless"}  # the sharded transports need it
+            if option == "moe_ep_dispatch" else {})
+    default = registry.create_model("llama_moe_tiny", seq_len=32).module
+    module = registry.create_model("llama_moe_tiny", seq_len=32,
+                                   **{option: given}, **more).module
+    assert getattr(module, option) == on_module
+    assert getattr(default, option) != on_module
+    # a dense model of the family is handed the same option and ignores it
+    registry.create_model("llama_tiny", seq_len=32, **{option: given}, **more)
+
+
+@pytest.mark.parametrize("name,options,error,match", [
+    ("llama_moe_tiny", {"moe_dispach_impl": "sort"}, TypeError, "moe_dispach"),
+    ("llama_tiny", {"dropout": 0.1}, ValueError, "does not implement dropout"),
+    ("gpt2_tiny", {"remat_policy": "dots"}, ValueError,
+     "does not implement remat_policy"),
+    ("llama_moe_tiny", {"moe_dispatch_impl": "nope"}, ValueError,
+     "unknown moe_dispatch_impl 'nope'"),
+    ("resnet18", {"dropout": 0.0, "remat_policy": "nothing", "sp": True,
+                  "attn_impl": "flash", "moe_top_k": 1}, None, None),
+])
+def test_create_model_refuses_what_it_refused(name, options, error, match):
+    """The registry's edge is what it was when ``create_model`` spelt every
+    option out: a misspelt keyword, a dropout or remat policy given to a
+    family without one and an unknown ``moe_*`` value fail loudly; the
+    value that asks for nothing, and the options another family takes, pass
+    (the Trainer hands every model the whole of ``Config.model_options``)."""
+    if error is None:
+        registry.create_model(name, seq_len=32, **options)
+        return
+    with pytest.raises(error, match=match):
+        registry.create_model(name, seq_len=32, **options)
+
+
 @pytest.mark.parametrize("name,expected_m", [
     ("resnet34", 21.80), ("resnet101", 44.55), ("resnet152", 60.19),
     ("vit_l16", 304.33),

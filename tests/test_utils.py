@@ -257,14 +257,6 @@ def test_chip_smoke_fails_off_chip():
     assert "gpt2_train" not in res.stdout  # no phase ran
 
 
-def test_bench_fails_off_chip():
-    """bench.py is a measurement path: no TPU, no number."""
-    res = _run_off_chip("bench.py", "--steps", "1")
-    assert res.returncode != 0
-    assert "found platform 'cpu'" in res.stderr
-    assert "metric" not in res.stdout
-
-
 def test_launcher_import_initialises_no_backend():
     """launch.py imports the package (whose __init__ imports jax), but must
     never initialise a backend: on a TPU host that would take the chip from
