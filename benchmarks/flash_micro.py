@@ -13,6 +13,10 @@ cost per executable call.
 ``--kernels`` times each direction alone, by kernel name in a device trace
 (no transposes, no glue): what ``auto`` dispatches to, the forced impls, and
 the causal kernels at the planner's plan or at each ``--plans`` entry.
+``--layer`` times one GPT-2 attention layer, the q, k, v and out projections
+around the kernels, forward and backward in one program: the layout between
+the two is what it reads. Run from another checkout's root (``cd _parent &&
+python <this file>``) both modes import that checkout's package.
 """
 
 from __future__ import annotations
@@ -34,9 +38,10 @@ def attn_flops(B, H, S, D, causal=True, bwd=False):
     return f * (3.5 if bwd else 1.0)
 
 
-def flash_kernel_ms(fn, args, reps):
-    """{kernel name: device ms per call} of the ``flash_*`` kernels in
-    ``jit(fn)(*args)``, from a profiler trace of ``reps`` calls."""
+def device_ms(fn, args, reps):
+    """(device ms per call of the whole of ``jit(fn)(*args)``, {kernel name:
+    device ms per call} of its ``flash_*`` kernels), from a profiler trace of
+    ``reps`` calls."""
     import tempfile
 
     import jax
@@ -53,7 +58,7 @@ def flash_kernel_ms(fn, args, reps):
             jax.block_until_ready(out)
         finally:
             jax.profiler.stop_trace()
-        ops, _, _ = collect_ops(trace_dir)
+        ops, module_ns, _ = collect_ops(trace_dir)
     took = {}
     for event, (ns, _) in ops.items():
         # "%flash_fwd_online.3 = (...) custom-call(...)" -> flash_fwd_online
@@ -63,7 +68,7 @@ def flash_kernel_ms(fn, args, reps):
     if not took:
         raise RuntimeError("no flash_* kernel in the device trace; it holds "
                            + ", ".join(sorted(e[:40] for e in ops)[:8]))
-    return took
+    return module_ns / reps / 1e6, took
 
 
 def kernel_rows(fa, shapes, plans, reps):
@@ -90,24 +95,61 @@ def kernel_rows(fa, shapes, plans, reps):
             cases.append((impl, "bwd", bwd_args,
                           lambda q, k, v, o, lse, g, impl=impl: fa._vjp_bwd(
                               True, *blocks, impl, None, (q, k, v, o, lse), g)))
+        fwd_plan = fa._causal_plan(H, S, D)
         for bwd in (False, True):
             own = fa._causal_plan(H, S, D, bwd=bwd)
             for plan in plans or ([own] if own else []):
+                if bwd:  # the causal backward reads the causal forward's lse
+                    args = (q, k, v, *fa._causal_fwd(
+                        q, k, v, plan=fwd_plan or plan), g)
                 cases.append((
                     "causal:%d:%d" % plan, "bwd" if bwd else "fwd",
-                    bwd_args if bwd else fwd_args,
+                    args if bwd else fwd_args,
                     functools.partial(fa._causal_bwd if bwd else fa._causal_fwd,
                                       plan=plan)))
         for impl, tag, args, fn in cases:
             row = {"impl": impl, "pass": tag, "B": B, "H": H, "S": S, "D": D}
             try:
                 row["kernels"] = {n: round(ms, 4) for n, ms in
-                                  flash_kernel_ms(fn, args, reps).items()}
+                                  device_ms(fn, args, reps)[1].items()}
                 row["ms"] = round(sum(row["kernels"].values()), 4)
             except Exception as e:  # a plan the compiler refuses
                 row["error"] = str(e).strip().splitlines()[-1][-300:]
             rows.append(row)
             print(json.dumps(row), flush=True)
+    return rows
+
+
+def layer_rows(shapes, reps):
+    """One row per shape: GPT-2's ``SelfAttention`` at width H*D under the
+    bf16 policy, forward and backward in one program. ``ms`` is the device
+    time of the whole program, ``kernels`` the flash kernels' part of it,
+    ``around_ms`` the rest: the four projections, their weight gradients and
+    whatever XLA puts between them and the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_training_example_tpu.models import gpt2
+
+    rows = []
+    for (B, H, S, D) in shapes:
+        module = gpt2.SelfAttention(H, jnp.bfloat16, jnp.float32,
+                                    attn_impl="flash")
+        kx, kg, kp = jax.random.split(jax.random.PRNGKey(0), 3)
+        x, g = (jax.random.normal(key, (B, S, H * D), jnp.bfloat16)
+                for key in (kx, kg))
+        params = module.init(kp, x[:1], False)
+
+        def fwd_bwd(params, x, g):
+            _, vjp = jax.vjp(lambda p, x: module.apply(p, x, False), params, x)
+            return vjp(g)
+
+        ms, kernels = device_ms(fwd_bwd, (params, x, g), reps)
+        rows.append({"layer": "gpt2.SelfAttention", "pass": "fwd+bwd", "B": B,
+                     "H": H, "S": S, "D": D, "ms": round(ms, 4),
+                     "kernels": {n: round(t, 4) for n, t in kernels.items()},
+                     "around_ms": round(ms - sum(kernels.values()), 4)})
+        print(json.dumps(rows[-1]), flush=True)
     return rows
 
 
@@ -133,6 +175,9 @@ def main():
     p.add_argument("--kernels", action="store_true",
                    help="device time of each flash kernel by its name, "
                         "forward and backward apart (see the docstring)")
+    p.add_argument("--layer", action="store_true",
+                   help="device time of one GPT-2 attention layer, the "
+                        "projections around the kernels, forward + backward")
     p.add_argument("--plans", default="",
                    help="with --kernels: comma-separated G:T plans for the "
                         "causal kernels (default: the planner's choice)")
@@ -189,10 +234,12 @@ def main():
     if args.shapes:
         shapes = tuple(tuple(int(x) for x in s.split("x"))
                        for s in args.shapes.split(","))
-    if args.kernels:
+    if args.kernels or args.layer:
         plans = [tuple(int(x) for x in p.split(":"))
                  for p in args.plans.split(",") if p]
-        rows = kernel_rows(fa, shapes, plans, args.iters)
+        rows = kernel_rows(fa, shapes, plans, args.iters) if args.kernels else []
+        if args.layer:
+            rows += layer_rows(shapes, args.iters)
         if args.out:
             with open(args.out, "w") as f:
                 json.dump({"rows": rows}, f, indent=1)

@@ -5,9 +5,10 @@ Architecture (standard GPT-2): learned token+position embeddings, pre-LN
 blocks, GELU MLP at 4x width, biased projections, weight-tied LM head.
 
 TPU-first details:
-- QKV projections are ``DenseGeneral`` with kernels shaped [d_model, heads,
-  head_dim] so tensor-parallel rules shard the *head* dimension (Megatron
-  column-split) purely via PartitionSpec — no parallel linear classes.
+- QKV projection kernels are shaped [d_model, heads, head_dim] (DenseGeneral's
+  parameters) so tensor-parallel rules shard the *head* dimension (Megatron
+  column-split) purely via PartitionSpec — no parallel linear classes. They
+  are applied as flat [d_model, heads*head_dim] matmuls (``_HeadsDense``).
 - Activations carry sharding constraints (batch over data axes, sequence
   over 'context') so CP/ring-attention engages by mesh shape alone.
 - ``remat`` wraps each block in ``jax.checkpoint`` (the reference matrix's
@@ -38,6 +39,48 @@ def _seq_rule(name: str, sp: bool = False):
     return sharding.seq_rules(sp)[name]
 
 
+class _HeadsDense(nn.Module):
+    """``nn.DenseGeneral`` between d_model and (heads, head_dim), computed as
+    one flat matmul.
+
+    The parameters are DenseGeneral's, name for name, shape for shape and
+    initial value for initial value (``kernel`` [d, H, D] and ``bias`` [H, D]
+    when splitting, [H, D, d] and [d] when merging, drawn flat and reshaped),
+    so checkpoints, ``TP_RULES`` and the benchmark's weights see no change.
+    The activation stays [..., H*D]: that row-major layout is what the flash
+    kernels block, so XLA puts no transpose or copy between the two.
+    """
+    heads: int
+    head_dim: int
+    merge: bool
+    dtype: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        width = self.heads * self.head_dim
+        d = width if self.merge else x.shape[-1]
+        flat = (width, d) if self.merge else (d, width)
+        shape = ((self.heads, self.head_dim, d) if self.merge
+                 else (d, self.heads, self.head_dim))
+
+        def kernel_init(rng, shape, dtype):
+            return nn.linear.default_kernel_init(rng, flat, dtype).reshape(shape)
+
+        kernel = self.param("kernel", kernel_init, shape, self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          shape[-1:] if self.merge else shape[1:],
+                          self.param_dtype)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        # The barrier keeps the relayout between [d, H, D] and [d, H*D] (the
+        # 64-wide minor dimension pads to 128 lanes) on the weight and on its
+        # gradient. Without it XLA moves it onto the [B, S, H*D] activations
+        # of the backward matmuls: nine 38 MB copies a layer at GPT-2's size.
+        kernel = jax.lax.optimization_barrier(kernel.reshape(flat))
+        return x @ kernel + bias.reshape(flat[1])
+
+
 class SelfAttention(nn.Module):
     num_heads: int
     dtype: Any
@@ -49,16 +92,15 @@ class SelfAttention(nn.Module):
     def __call__(self, x, train: bool):
         d = x.shape[-1]
         head_dim = d // self.num_heads
-        dg = lambda name: nn.DenseGeneral(
-            (self.num_heads, head_dim), axis=-1, dtype=self.dtype,
-            param_dtype=self.param_dtype, name=name)
-        q, k, v = dg("query")(x), dg("key")(x), dg("value")(x)
-        q = mesh_lib.constrain(q, _seq_rule("qkv"))
-        k = mesh_lib.constrain(k, _seq_rule("qkv"))
-        v = mesh_lib.constrain(v, _seq_rule("qkv"))
+        dense = lambda name, merge=False: _HeadsDense(
+            self.num_heads, head_dim, merge, self.dtype, self.param_dtype,
+            name=name)
+        heads = lambda t: mesh_lib.constrain(
+            t.reshape(*t.shape[:-1], self.num_heads, head_dim),
+            _seq_rule("qkv"))
+        q, k, v = (heads(dense(name)(x)) for name in ("query", "key", "value"))
         out = attn_lib.attention(q, k, v, causal=True, impl=self.attn_impl)
-        out = nn.DenseGeneral(d, axis=(-2, -1), dtype=self.dtype,
-                              param_dtype=self.param_dtype, name="out")(out)
+        out = dense("out", merge=True)(out.reshape(*x.shape))
         if self.dropout > 0:
             out = nn.Dropout(self.dropout, deterministic=not train)(out)
         return out

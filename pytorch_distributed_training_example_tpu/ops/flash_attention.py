@@ -12,9 +12,10 @@ and applies an elementwise mask only on the diagonal block.
 
 Backward is a Pallas dq/dkv kernel pair under ``custom_vjp`` (see
 ``_dq_kernel``/``_dkv_kernel`` below): recompute-based, using the
-saved forward LSE, with the same blockwise masking. Layout: [B, S, H, D] in,
-transposed to [B, H, S, D] internally (head-major keeps the MXU's 128-lane
-dim on head_dim).
+saved forward LSE, with the same blockwise masking. Layout: [B, S, H, D] in;
+the online, one-shot and streaming kernels transpose to [B, H, S, D]
+internally, the causal pair blocks the [B, S, H*D] rows as they lie (see
+"Causal kernels" below).
 """
 
 from __future__ import annotations
@@ -690,6 +691,21 @@ def _oneshot_bwd(q, k, v, o, lse, g, *, causal, plan, kv_len=None):
 # from the last (whole prefix) to the first, so the first visit assigns the
 # float32 dk/dv accumulators and the later ones add to their static prefixes.
 # Work done at nt = S/T sub-tiles: (nt+1)/(2*nt) of the dense S^2 tile.
+#
+# Layout: operands and results are the projections' own [B, S, H*D], blocked
+# (1, S, G*D), so no transpose sits on either side and every HBM row is whole
+# 128-lane tiles. Inside a block the kernels work on chunks of
+# ``_causal_lanes`` lanes: one head at D=128, two at D=64. Heads that share a
+# chunk are told apart without lane shuffles: each head's copy of the q (and
+# do) sub-tile, the other heads' lanes zeroed, is stacked along the rows, so
+# one K=128 contraction against the chunk's k or v gives every head's exact
+# scores, and the rows' sums over the stack give dk and dv (a head's rows are
+# zero outside its lanes); each head's lanes of the N=128 results (o, dq) are
+# taken from its rows with a select. On a 128 x 128 MXU that is as many passes
+# as K=64 / N=64, with each k and v weight tile loaded once for the stack. The
+# backward computes delta = rowsum(dO*O) from the sub-tiles it holds. lse
+# leaves the forward as [B, H*D/lanes, S, lanes] float32, each head's value
+# across its own D lanes.
 # ---------------------------------------------------------------------------
 
 
@@ -707,37 +723,58 @@ def _tn_dot(a, b):
 
 # (S, D) at which benchmarks/flash_micro.py --kernels on a v5e read both
 # causal kernels ahead of auto's earlier choice (online forward, chunked
-# one-shot backward); PERF.md section 6 has the table. Every reading is of
-# bf16. Other shapes, and other dtypes at any shape, keep the earlier kernels
-# until they are measured.
-CAUSAL_MEASURED = {(1024, 64), (2048, 64), (1024, 128), (2048, 128)}
+# one-shot backward); PERF.md section 6 has the tables (PR 27, PR 31). Every
+# reading is of bf16. Other shapes, and other dtypes at any shape, keep the
+# earlier kernels until they are measured. (2048, 64) was one of them while
+# a program could hold one 64-wide head; ``_causal_plan`` says why not now.
+CAUSAL_MEASURED = {(1024, 64), (1024, 128), (2048, 128)}
+
+
+def _causal_lanes(H, D):
+    """Lanes of one chunk of the causal kernels' blocks: a 128-lane tile row
+    of D-wide heads, one wider head, or all of a narrower H*D."""
+    return max(D, min(H * D, 128))
 
 
 def _causal_plan(H, S, D, *, bwd=False):
     """Pick (heads per program G, q sub-tile rows T) for the causal kernels,
     or None.
 
-    T is the measured choice at GPT-2's shape (B24 H12 S1024 D64, ms a
-    layer): forward 0.73 at 256 against 0.82 at 128 (a 128-row sub-tile
-    streams too few rows past each MXU weight tile), backward 1.30 at 128
-    against 1.41 at 256 (its five matmuls gain more from the skipped work).
-    Bytes live per program, held to the one-shot planner's budget: the
-    double-buffered whole-head blocks of bf16 operands (an lse or delta
-    row pads to 128 lanes), the s/p (and dp/ds) tiles of one sub-tile, the
-    dk/dv accumulators. The v5e compiler admits every plan this model admits at
-    the shapes tried (S 1024/2048, D 64/128) and no more heads than it; the
-    model over-counts the S=2048/D=128 backward (16.5 MB; the compiler
-    12.3), which therefore keeps the chunked one-shot kernel.
+    T is the measured choice (benchmarks/flash_micro.py, ms a layer; PERF.md
+    section 6, PR 31). The forward stacks 256 rows, a chunk's heads by T:
+    at GPT-2's shape (B24 H12 S1024 D64, two heads a chunk) 0.71 at 128
+    against 0.72 at 256 and 1.07 at 64; at B8 H16 S1024 D128 0.35 at 256
+    against 0.39 at 128 (fewer rows stream too little past each MXU weight
+    tile, more skip too little). The backward takes 128 at either width:
+    1.20 against 1.34 at 64, 0.56 against 0.60 at 256 (its five matmuls gain
+    more from the skipped work).
+
+    G heads are G*D lanes of the [B, S, H*D] operands: whole chunks of
+    ``_causal_lanes``, or None (a device's H/tp heads of 64 that do not
+    pair up; the earlier kernels serve those). Bytes live per program, held
+    to the one-shot planner's budget: the double-buffered [S, G*D] blocks of
+    bf16 operands and of float32 lse; forward, a head's score tiles, which
+    the compiler keeps for every sub-tile at once (S*S/2 floats: this
+    counts 10 bytes for 256 rows of S, right at S=1024 and 4 MB short at
+    2048); backward, the s/p/dp/ds tiles of one sub-tile and a chunk's dk/dv
+    accumulators. The v5e compiler's own counts of what this admits, MB
+    (this model's in brackets): S1024/D64 G=2 forward 8.2 [8.4], G=4
+    backward 13.2 [13.4]; S1024/D128 G=2 11.6 [11.5] and 12.0 [13.4];
+    S2048/D128 G=1 forward 14.8 [11.5] of its 16, the backward (14.3 [16.3])
+    stays with the chunked one-shot kernel. S2048/D64 fits neither way: two
+    heads' forward needs 22.8 MB, one head's 64 lanes are no block.
     """
-    tile = 128 if bwd else 256
-    if S % tile or S == tile:
+    lanes = _causal_lanes(H, D)
+    if S % 256 or S == 256 or (H * D) % lanes:
         return None
-    blocks = 2 * ((7 if bwd else 4) * S * D * 2
-                  + (2 if bwd else 1) * S * 128 * 4)
-    tiles = (14 if bwd else 10) * tile * S
-    acc = 2 * S * max(D, 128) * 4 if bwd else 0
+    tile = 128 if bwd else 256 * D // lanes
+    blocks = 2 * ((8 if bwd else 4) * S * D * 2 + S * D * 4)
     for g in range(min(H, 8), 0, -1):
-        if H % g == 0 and g * (blocks + tiles) + acc <= ONESHOT_BUDGET:
+        if H % g or (g * D) % lanes:
+            continue
+        live = (g * blocks + 14 * tile * S + 2 * S * lanes * 4 if bwd
+                else g * (blocks + 10 * 256 * S))
+        if live <= ONESHOT_BUDGET:
             return g, tile
     return None
 
@@ -756,54 +793,89 @@ def _auto_causal_plan(impl, causal, kv_len, Sq, Skv, H, D, dtype, *,
     return _causal_plan(H, Sq, D, bwd=bwd)
 
 
-def _mask_diagonal(s, lo):
-    """Causal mask for q rows [lo, lo+T) against the keys [0, lo+T): only
-    the last T columns (the diagonal tile) hold anything to mask."""
-    tile = s.shape[0]
-    visible = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-               >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+def _mask_diagonal(s, lo, tile):
+    """Causal mask for stacked q rows [lo, lo+T) against the keys [0, lo+T):
+    only the last T columns (the diagonal tile) hold anything to mask."""
+    rows = s.shape[0]
+    visible = (jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) % tile
+               >= jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1))
     diag = jnp.where(visible, s[:, lo:], NEG_INF)
     return jnp.concatenate([s[:, :lo], diag], axis=1) if lo else diag
 
 
-def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, tile):
-    G, S = q_ref.shape[1], q_ref.shape[2]
-    for g in range(G):
+def _head_lanes(tile, lanes, head_dim):
+    """One [tile, lanes] mask per head of a chunk: that head's own lanes."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (tile, lanes), 1) // head_dim
+    return [head == h for h in range(lanes // head_dim)]
+
+
+def _stack_heads(x, own):
+    """[T, lanes] -> [heads*T, lanes]: a copy of x per head of the chunk,
+    zero outside that head's lanes."""
+    if len(own) == 1:
+        return x
+    return jnp.concatenate([jnp.where(mine, x, 0) for mine in own], axis=0)
+
+
+def _own_lanes(stacked, own):
+    """[heads*T, lanes] -> [T, lanes]: each head's lanes from its own rows."""
+    tile = stacked.shape[0] // len(own)
+    out = stacked[:tile]
+    for h, mine in enumerate(own[1:], 1):
+        out = jnp.where(mine, stacked[h * tile:(h + 1) * tile], out)
+    return out
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, tile,
+                       head_dim):
+    S, lanes = q_ref.shape[1], lse_ref.shape[3]
+    own = _head_lanes(tile, lanes, head_dim)
+    for c in range(lse_ref.shape[1]):
+        at = slice(c * lanes, (c + 1) * lanes)
         for lo in range(0, S, tile):
             n = lo + tile
-            q = _mxu(q_ref[0, g, lo:n, :])                    # [T, D]
-            k = _mxu(k_ref[0, g, :n, :])                      # [n, D]
-            v = _mxu(v_ref[0, g, :n, :])
-            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo)  # [T, n]
+            q = _stack_heads(_mxu(q_ref[0, lo:n, at]), own)   # [hT, lanes]
+            k = _mxu(k_ref[0, :n, at])                        # [n, lanes]
+            v = _mxu(v_ref[0, :n, at])
+            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo, tile)  # [hT, n]
             m = jnp.max(s, axis=1, keepdims=True)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=1, keepdims=True)
             o = jnp.dot(p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-            o_ref[0, g, lo:n, :] = (o / l).astype(o_ref.dtype)
-            lse_ref[0, g, lo:n, :] = jnp.broadcast_to(
-                m + jnp.log(l), (tile, LSE_LANES))
+                        preferred_element_type=jnp.float32) / l
+            lse = jnp.broadcast_to(m + jnp.log(l), o.shape)
+            o_ref[0, lo:n, at] = _own_lanes(o, own).astype(o_ref.dtype)
+            lse_ref[0, c, lo:n, :] = _own_lanes(lse, own)
 
 
-def _causal_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _causal_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                        dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       sm_scale, tile):
-    G, S = q_ref.shape[1], q_ref.shape[2]
-    for g in range(G):
+                       sm_scale, tile, head_dim):
+    S, lanes = q_ref.shape[1], lse_ref.shape[3]
+    own = _head_lanes(tile, lanes, head_dim)
+    for c in range(lse_ref.shape[1]):
+        at = slice(c * lanes, (c + 1) * lanes)
         for lo in reversed(range(0, S, tile)):
             n = lo + tile
-            q = _mxu(q_ref[0, g, lo:n, :])                    # [T, D]
-            do = _mxu(do_ref[0, g, lo:n, :])
-            k = _mxu(k_ref[0, g, :n, :])                      # [n, D]
-            v = _mxu(v_ref[0, g, :n, :])
-            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo)  # [T, n]
-            p = jnp.exp(s - lse_ref[0, g, lo:n, :1])
+            q = _stack_heads(_mxu(q_ref[0, lo:n, at]), own)   # [hT, lanes]
+            do = _stack_heads(_mxu(do_ref[0, lo:n, at]), own)
+            k = _mxu(k_ref[0, :n, at])                        # [n, lanes]
+            v = _mxu(v_ref[0, :n, at])
+            # delta = rowsum(dO * O): a head's rows of do hold its lanes only
+            o = o_ref[0, lo:n, at].astype(jnp.float32)
+            delta = jnp.sum(do.astype(jnp.float32)
+                            * jnp.concatenate([o] * len(own), axis=0),
+                            axis=1, keepdims=True)
+            lse = jnp.concatenate(
+                [lse_ref[0, c, lo:n, h * head_dim:h * head_dim + 1]
+                 for h in range(len(own))], axis=0)           # [hT, 1]
+            s = _mask_diagonal(_nt_dot(q, k) * sm_scale, lo, tile)  # [hT, n]
+            p = jnp.exp(s - lse)
             dp = _nt_dot(do, v)
-            ds = (p * (dp - delta_ref[0, g, lo:n, :1]) * sm_scale
-                  ).astype(k.dtype)
-            dq_ref[0, g, lo:n, :] = jnp.dot(
-                ds, k, preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-            dv = _tn_dot(p.astype(do.dtype), do)              # [n, D]
+            ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
+            dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+            dq_ref[0, lo:n, at] = _own_lanes(dq, own).astype(dq_ref.dtype)
+            dv = _tn_dot(p.astype(do.dtype), do)              # [n, lanes]
             dk = _tn_dot(ds, q)
             if n == S:  # the whole prefix comes first: assigns every key row
                 dv_acc[:] = dv
@@ -811,13 +883,23 @@ def _causal_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             else:
                 dv_acc[:n, :] += dv
                 dk_acc[:n, :] += dk
-        dk_ref[0, g] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, g] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, :, at] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, :, at] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _causal_specs(B, S, H, D, G):
+    """Block specs of the [B, S, H*D] operands and of lse, and lse's shape."""
+    lanes = _causal_lanes(H, D)
+    spec = pl.BlockSpec((1, S, G * D), lambda b, h: (b, 0, h))
+    lspec = pl.BlockSpec((1, G * D // lanes, S, lanes),
+                         lambda b, h: (b, h, 0, 0))
+    return spec, lspec, (B, H * D // lanes, S, lanes)
 
 
 @functools.partial(jax.jit, static_argnames="plan")
 def _causal_fwd(q, k, v, *, plan):
-    """Returns (out [B,S,H,D], lse [B,H,S,LSE_LANES]); K/V GQA-expanded.
+    """Returns (out [B,S,H,D], lse [B, H*D/lanes, S, lanes], each head's value
+    across its own D lanes); K/V GQA-expanded.
 
     Under ``jit`` so that a model's layers, and its later traces, share one
     trace and one lowering of the unrolled kernel (it costs a few hundred
@@ -825,55 +907,54 @@ def _causal_fwd(q, k, v, *, plan):
     """
     B, S, H, D = q.shape
     G, tile = plan
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    spec = pl.BlockSpec((1, G, S, D), lambda b, h: (b, h, 0, 0))
-    lspec = pl.BlockSpec((1, G, S, LSE_LANES), lambda b, h: (b, h, 0, 0))
+    spec, lspec, lse_shape = _causal_specs(B, S, H, D, G)
     out, lse = pl.pallas_call(
         functools.partial(_causal_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
-                          tile=tile),
+                          tile=tile, head_dim=D),
         name="flash_fwd_causal",
         grid=(B, H // G),
         in_specs=[spec, spec, spec],
         out_specs=(spec, lspec),
-        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, LSE_LANES), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+                   jax.ShapeDtypeStruct(lse_shape, jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(qt, kt, vt)
-    return jnp.transpose(out, (0, 2, 1, 3)), lse
+    )(*(x.reshape(B, S, H * D) for x in (q, k, v)))
+    return out.reshape(B, S, H, D), lse
+
+
+def _causal_lse_rows(lse, H):
+    """The causal forward's lse as the [B,H,S,LSE_LANES] rows that every
+    other backward reads."""
+    B, chunks, S, lanes = lse.shape
+    D = chunks * lanes // H
+    rows = lse.reshape(B, chunks, S, lanes // D, D)[..., 0]
+    rows = jnp.moveaxis(rows, 3, 2).reshape(B, H, S, 1)
+    return jnp.broadcast_to(rows, (B, H, S, LSE_LANES))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
 def _causal_bwd(q, k, v, o, lse, g, *, plan):
-    """q,k,v,o,g: [B,S,H,D] (kv GQA-expanded); lse: [B,H,S,LSE_LANES]."""
+    """q,k,v,o,g: [B,S,H,D] (kv GQA-expanded); lse: as ``_causal_fwd`` gives."""
     B, S, H, D = q.shape
     G, tile = plan
-    delta = _delta_rows(g, o)
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    dot = jnp.transpose(g, (0, 2, 1, 3))
-    spec = pl.BlockSpec((1, G, S, D), lambda b, h: (b, h, 0, 0))
-    lspec = pl.BlockSpec((1, G, S, LSE_LANES), lambda b, h: (b, h, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    spec, lspec, _ = _causal_specs(B, S, H, D, G)
+    lanes = _causal_lanes(H, D)
+    shape = jax.ShapeDtypeStruct((B, S, H * D), q.dtype)
+    grads = pl.pallas_call(
         functools.partial(_causal_bwd_kernel, sm_scale=1.0 / math.sqrt(D),
-                          tile=tile),
+                          tile=tile, head_dim=D),
         name="flash_bwd_causal",
         grid=(B, H // G),
-        in_specs=[spec, spec, spec, spec, lspec, lspec],
+        in_specs=[spec, spec, spec, spec, spec, lspec],
         out_specs=(spec, spec, spec),
-        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, D), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32),   # dk, one head
-                        pltpu.VMEM((S, D), jnp.float32)],  # dv
+        out_shape=(shape, shape, shape),
+        scratch_shapes=[pltpu.VMEM((S, lanes), jnp.float32),   # dk, a chunk
+                        pltpu.VMEM((S, lanes), jnp.float32)],  # dv
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(qt, kt, vt, dot, lse, delta)
-    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-    return tr(dq), tr(dk), tr(dv)
+    )(*(x.reshape(B, S, H * D) for x in (q, k, v, o, g)), lse)
+    return tuple(x.reshape(B, S, H, D) for x in grads)
 
 
 # ---------------------------------------------------------------------------
@@ -1081,9 +1162,9 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
     """Auto dispatch is per direction, each from measurements on the chip:
 
     - Causal self-attention (Sq == Skv, no kv_len) at a shape in
-      ``CAUSAL_MEASURED``: the causal kernels, both directions (PERF.md
-      section 6, PR 27: 0.73 vs 1.05 ms forward and 1.30 vs 1.94 backward
-      a layer at B24·H12·S1024·D64).
+      ``CAUSAL_MEASURED``: the causal kernels, both directions where
+      ``_causal_plan`` has one (PERF.md section 6, PR 31: 0.71 vs 1.05 ms
+      forward and 1.20 vs 1.94 backward a layer at B24·H12·S1024·D64).
     - Other causal forwards: the streaming online kernel (r4, on a machine
       that is gone: 0.72 vs 0.86 ms one-shot at S2048; 1.37 vs 1.99 at
       S4096/D128). Its grid skips fully-masked kv blocks only where
@@ -1093,8 +1174,11 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
     - Non-causal forward: one-shot when a plan exists (no masked blocks
       for the online grid to skip, so fewer/fatter programs win).
 
-    All kernels share the residual format (q,k,v,o + lse
-    [B,H,S,LSE_LANES]), so mixing directions is free; forced
+    The residuals are q,k,v,o + lse. Every kernel but the causal pair
+    reads and writes lse as [B,H,S,LSE_LANES], so mixing those directions
+    is free; the causal forward's denser lse (``_causal_fwd``) goes to the
+    causal backward as it is, and ``_vjp_fwd`` spreads it into
+    [B,H,S,LSE_LANES] rows where another backward will read it. Forced
     impl="oneshot"/"online" still pin both sides.
     """
     B, Sq, H, D = q.shape
@@ -1128,6 +1212,13 @@ def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len):
     ve = attn_lib._repeat_kv(v, q.shape[2])
     out, lse = _fwd_dispatch(q, ke, ve, causal, block_q, block_kv, impl,
                              kv_len)
+    fwd_plan, bwd_plan = (
+        _auto_causal_plan(impl, causal, kv_len, q.shape[1], k.shape[1],
+                          q.shape[2], q.shape[3], q.dtype, bwd=bwd)
+        for bwd in (False, True))
+    if fwd_plan is not None and bwd_plan is None:
+        # causal forward, one-shot backward (S=2048/D=128): its format
+        lse = _causal_lse_rows(lse, q.shape[2])
     return out, (q, k, v, out, lse)
 
 

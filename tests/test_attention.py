@@ -335,21 +335,43 @@ def test_oneshot_chunked_bwd_grads_interpret():
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("Hkv", [4, 2])
-@pytest.mark.parametrize("tile", [128, 256])
-def test_causal_kernels_parity_interpret(monkeypatch, tile, Hkv):
-    """The causal kernels (static triangular sub-tiles) through
-    flash_attention's own dispatch, at each sub-tile size the planner
-    chooses (256 forward, 128 backward; both directions at both here),
-    with and without GQA: output, lse (every LSE_LANES lane equal and
-    finite: the other backwards read any of them) and all three grads."""
+def _lse_oracle(q, k):
+    """logsumexp of the causal scores, [B, H, S], as the kernels define it."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, A._repeat_kv(k, q.shape[2]))
+    s = s / np.sqrt(q.shape[-1])
+    visible = np.tril(np.ones((q.shape[1], q.shape[1]), bool))
+    return jax.nn.logsumexp(jnp.where(visible, s, -np.inf), axis=-1)
+
+
+def _check_causal_lse(lse, q, k):
+    """The causal forward's lse: [B, H*D/lanes, S, lanes] float32, each
+    head's value across its own D lanes (the backward reads any of them)."""
+    B, S, H, D = q.shape
+    lanes = F._causal_lanes(H, D)
+    lse = np.asarray(lse)
+    assert lse.shape == (B, H * D // lanes, S, lanes) and lse.dtype == np.float32
+    assert lse.size * 2 <= B * H * S * 128 or D >= 128  # half the padded rows
+    per_head = lse.reshape(B, -1, S, lanes // D, D)
+    np.testing.assert_array_equal(per_head, per_head[..., :1].repeat(D, -1))
+    rows = np.asarray(F._causal_lse_rows(jnp.asarray(lse), H))
+    assert rows.shape == (B, H, S, F.LSE_LANES)
+    np.testing.assert_array_equal(rows, rows[..., :1].repeat(F.LSE_LANES, -1))
+    np.testing.assert_allclose(rows[..., 0], np.asarray(_lse_oracle(q, k)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _causal_parity(monkeypatch, q, k, v, plans, kernels):
+    """Output, lse and all three grads of flash_attention against the XLA
+    oracle with auto dispatch pinned to ``plans`` = (forward, backward),
+    and the kernel entry points that ran against ``kernels``."""
     # float32 operands pin the comparison; auto plans only bf16 ones
-    monkeypatch.setattr(F, "_auto_causal_plan", lambda *a, **k: (2, tile))
+    monkeypatch.setattr(F, "_auto_causal_plan",
+                        lambda *a, bwd=False, **k: plans[bwd])
     ran = []
-    for name in ("_causal_fwd", "_causal_bwd"):
+    for name in ("_causal_fwd", "_causal_bwd", "_oneshot_bwd"):
         monkeypatch.setattr(F, name, lambda *a, _f=getattr(F, name), _n=name,
                             **k: (ran.append(_n), _f(*a, **k))[1])
-    q, k, v = _qkv(B=1, S=1024, H=4, Hkv=Hkv, D=16)
+    H = q.shape[2]
     g = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
     ref, vjp = jax.vjp(
         lambda *a: A.dot_product_attention(*a, causal=True), q, k, v)
@@ -357,17 +379,105 @@ def test_causal_kernels_parity_interpret(monkeypatch, tile, Hkv):
         out, vjp_flash = jax.vjp(
             lambda *a: F.flash_attention(*a, True), q, k, v)
         grads = vjp_flash(g)
-        _, lse = F._fwd_dispatch(q, A._repeat_kv(k, 4), A._repeat_kv(v, 4),
+        _, lse = F._fwd_dispatch(q, A._repeat_kv(k, H), A._repeat_kv(v, H),
                                  True, 1024, 1024, "auto", None)
-    assert ran == ["_causal_fwd", "_causal_bwd", "_causal_fwd"]
+    assert ran == [*kernels, "_causal_fwd"]
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
     for a, b in zip(vjp(g), grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
-    lse = np.asarray(lse)
-    assert lse.shape == (1, 4, 1024, F.LSE_LANES) and np.isfinite(lse).all()
-    np.testing.assert_array_equal(lse, lse[..., :1].repeat(F.LSE_LANES, -1))
+    _check_causal_lse(lse, q, k)
+
+
+@pytest.mark.parametrize("Hkv", [4, 2])
+@pytest.mark.parametrize("tile", [128, 256])
+def test_causal_kernels_parity_interpret(monkeypatch, tile, Hkv):
+    """The causal kernels (static triangular sub-tiles) through
+    flash_attention's own dispatch, at each sub-tile size the planner
+    chooses at some width (both directions at both here), with and without
+    GQA, all four 16-wide heads stacked in one 64-lane block: output, lse and
+    all three grads."""
+    q, k, v = _qkv(B=1, S=1024, H=4, Hkv=Hkv, D=16)
+    _causal_parity(monkeypatch, q, k, v, [(4, tile)] * 2,
+                   ["_causal_fwd", "_causal_bwd"])
+
+
+@pytest.mark.parametrize("H,Hkv,D,G,bwd", [
+    (4, 4, 64, 2, "causal"),    # GPT-2's blocks: two 64-wide heads, 128 lanes
+    (4, 2, 64, 4, "causal"),    # four heads a block (two chunks), GQA
+    (2, 1, 128, 1, "causal"),   # one 128-wide head a block: no lane mask; GQA
+    (2, 2, 128, 2, "causal"),   # two of them a block
+    (2, 2, 128, 1, "oneshot"),  # S=2048/D=128's pairing: the one-shot backward
+    (2, 1, 64, 2, "oneshot"),   # the same from a two-head chunk, GQA
+])
+def test_causal_kernels_lane_dense_layout_interpret(monkeypatch, H, Hkv, D, G,
+                                                    bwd):
+    """The [B, S, H*D] blocks at the widths the chip runs: heads that share
+    a 128-lane chunk are stacked along the rows and told apart by lane masks,
+    delta is computed in the backward kernel, and where the backward is
+    another family's the forward's lse reaches it as [B,H,S,LSE_LANES]
+    rows."""
+    q, k, v = _qkv(B=1, S=512, H=H, Hkv=Hkv, D=D)
+    tile = F._causal_plan(H, 1024, D)[1]  # 256 rows: a chunk's heads by T
+    plans = [(G, tile), (G, 128) if bwd == "causal" else None]
+    _causal_parity(monkeypatch, q, k, v, plans,
+                   ["_causal_fwd", f"_{bwd}_bwd"])
+
+
+def test_gpt2_attention_is_dense_generals(devices):
+    """GPT-2's SelfAttention computes its projections as flat matmuls: the
+    parameter tree (paths, shapes, initial values), the output and every
+    gradient are those of the DenseGeneral form, on one device and with the
+    heads sharded over a tp=2 mesh."""
+    import flax.linen as nn
+
+    from pytorch_distributed_training_example_tpu.models import gpt2
+    from pytorch_distributed_training_example_tpu.parallel import sharding
+
+    class DenseGenerals(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            dg = lambda name: nn.DenseGeneral((4, 16), axis=-1, name=name)
+            out = A.attention(dg("query")(x), dg("key")(x), dg("value")(x),
+                              causal=True, impl="xla")
+            return nn.DenseGeneral(64, axis=(-2, -1), name="out")(out)
+
+    rs = np.random.RandomState(0)
+    x, g = (jnp.asarray(rs.randn(4, 32, 64), jnp.float32) for _ in range(2))
+    new = gpt2.SelfAttention(4, jnp.float32, jnp.float32, attn_impl="xla")
+    old = DenseGenerals()
+    params = new.init(jax.random.PRNGKey(1), x, False)
+    ref_params = old.init(jax.random.PRNGKey(1), x)
+    assert (jax.tree.structure(params) == jax.tree.structure(ref_params))
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params)):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the benchmark's weights: no zero bias, so that every leaf's gradient counts
+    params = jax.tree.map(
+        lambda a: jnp.asarray(rs.randn(*a.shape) * 0.1, jnp.float32), params)
+    ref_out, ref_vjp = jax.vjp(old.apply, params, x)
+    ref_grads = ref_vjp(g)
+
+    def check(out, grads):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=1e-5, atol=1e-5)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)
+
+    fwd_bwd = lambda p, x, g: (lambda out, vjp: (out, vjp(g)))(
+        *jax.vjp(lambda p, x: new.apply(p, x, False), p, x))
+    check(*jax.jit(fwd_bwd)(params, x, g))
+    mesh = mesh_lib.build_mesh({"data": 4, "model": 2})
+    with mesh_lib.use_mesh(mesh):
+        # the module stands alone here: its paths lack the block's "attn/"
+        rules = [(pattern.replace("attn/", "params/"), spec)
+                 for pattern, spec in gpt2.TP_RULES]
+        sharded = sharding.shard_params(params, mesh, rules)
+        kernel = sharded["params"]["query"]["kernel"]
+        assert kernel.sharding.shard_shape(kernel.shape) == (64, 2, 16)
+        check(*jax.jit(fwd_bwd)(sharded, x, g))
 
 
 def _stub_flash_kernels(monkeypatch):
@@ -388,8 +498,18 @@ def test_causal_kernels_dispatch(monkeypatch):
     """GPT-2's shape (H=12, S=1024, D=64) takes the causal kernels in both
     directions under auto; non-causal, kv_len, Sq != Skv, forced impls and
     shapes nobody measured never do."""
-    assert F._causal_plan(12, 1024, 64) == (2, 256)
-    assert F._causal_plan(12, 1024, 64, bwd=True) == (2, 128)
+    assert F._causal_plan(12, 1024, 64) == (2, 128)
+    assert F._causal_plan(12, 1024, 64, bwd=True) == (4, 128)
+    # a program's heads are whole 128-lane chunks of [B, S, H*D]: tp=2 and 4
+    # leave 6 and 3 heads a device, and three 64-wide heads do not pair up
+    assert F._causal_plan(6, 1024, 64, bwd=True) == (2, 128)
+    assert F._causal_plan(3, 1024, 64) is None
+    assert F._causal_plan(16, 1024, 128) == (2, 256)
+    assert F._causal_plan(16, 1024, 128, bwd=True) == (2, 128)
+    # S=2048/D=64: two heads' forward is over the budget (and the v5e
+    # compiler's 16 MB), one head's 64 lanes are no block
+    assert F._causal_plan(12, 2048, 64) is None
+    assert F._causal_plan(12, 2048, 64, bwd=True) is None
     calls = _stub_flash_kernels(monkeypatch)
     q = jnp.zeros((1, 1024, 12, 64), jnp.bfloat16)
     res = (q, q, q, "o", "l")

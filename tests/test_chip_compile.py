@@ -80,7 +80,7 @@ def _grads(fn):
                          ids=["bf16", "fp32"])  # --precision bf16 / fp32
 @pytest.mark.parametrize("B,S,H,Hkv,D", [
     (24, 1024, 12, 12, 64),    # GPT-2 124M at the smoke's batch
-    (4, 2048, 12, 12, 64),     # causal kernels, one head a program
+    (4, 2048, 12, 12, 64),     # two heads' [S, 128] blocks are over VMEM
     (8, 1024, 16, 4, 128),     # causal kernels at D=128, GQA
     (4, 2048, 16, 16, 128),    # D=128: causal forward, chunked backward
     (4, 2048, 8, 2, 128),      # the same under GQA
@@ -103,7 +103,7 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
     if dtype == jnp.float32:
         assert plans == [None, None]
     elif (S, D) == (1024, 64):  # GPT-2's shape: both, and nothing else
-        assert plans == [(2, 256), (2, 128)]
+        assert plans == [(2, 128), (4, 128)]
         assert "flash_fwd_online" not in text
         assert "flash_bwd_oneshot" not in text
     if (S, H, D) == (4096, 32, 64):
@@ -115,6 +115,76 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
         # The v5e compiler counts the streaming backward over its 16 MB of
         # scoped VMEM here; the planner must not admit it.
         assert fa._stream_bwd_plan(H, S, S, D) is None
+
+
+def _entry_instructions(text):
+    """{name: (shape, opcode, operand names)} of the entry computation."""
+    import re
+
+    entry = text[text.index("ENTRY "):]
+    found = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (\(.*?\)|\S+) ([\w-]+)"
+                     r"\((.*?)\)(?:,|$)", line)
+        if m:
+            found[m.group(1)] = (re.sub(r"\{[^}]*\}", "", m.group(2)),
+                                 m.group(3), re.findall(r"%[\w.-]+", m.group(4)))
+    return found
+
+
+def test_gpt2_attention_operands_are_lane_dense(one_chip, as_tpu):
+    """GPT-2's attention layer at the cell's shape (B24 S1024 H12 D64),
+    forward and backward: the projections are flat matmuls whose fusions
+    write [24, 1024, 768] straight into the causal kernels and read their
+    results, with no copy or transpose between, no operand whose minor
+    dimension is a head's 64 or LSE_LANES' 8 (both pad to 128 lanes in
+    HBM), and lse at half the bytes of a padded row a head."""
+    from pytorch_distributed_training_example_tpu.models import gpt2
+
+    B, S, H, D = 24, 1024, 12, 64
+    module = gpt2.SelfAttention(H, BF16, jnp.float32, attn_impl="flash")
+    x = _sds((B, S, H * D), one_chip)
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, one_chip, a.dtype),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, S, H * D), BF16), False)))
+
+    def grads(params, x, g):
+        _, vjp = jax.vjp(lambda p, x: module.apply(p, x, False), params, x)
+        return vjp(g)
+
+    ins = _entry_instructions(_compiled_text(grads, params, x, x))
+    calls = {name: ins[name] for name in ins
+             if ins[name][1] == "custom-call" and name.startswith("%flash_")}
+    assert sorted(n.split(".")[0] for n in calls) == [
+        "%flash_bwd_causal", "%flash_fwd_causal"]
+    dense, lse = f"bf16[{B},{S},{H * D}]", f"f32[{B},{H // 2},{S},128]"
+    moves = {"get-tuple-element", "bitcast", "copy-start", "copy-done"}
+    users = {}
+    for name, (_, _, operands) in ins.items():
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+
+    def producer(name):  # through views and XLA's own prefetches
+        while ins[name][1] in moves:
+            name = ins[name][2][0]
+        return name
+
+    def consumers(name):
+        for user in users.get(name, []):
+            if ins[user][1] in moves:
+                yield from consumers(user)
+            else:
+                yield user
+
+    for name, (shape, _, operands) in calls.items():
+        shapes = [ins[o][0] for o in operands] + shape.strip("()").split(", ")
+        assert set(shapes) <= {dense, lse}, (name, shapes)
+        assert shapes.count(lse) == 1
+        around = [producer(o) for o in operands] + list(consumers(name))
+        assert around and all(
+            ins[n][1] in ("fusion", "custom-call", "reduce", "slice-start")
+            for n in around), [(n, ins[n][1]) for n in around]
 
 
 def _granite_scan_args(sharding, b=1, dtype=BF16):
