@@ -10,7 +10,10 @@ Families: ResNet (torchvision depths), ViT, GPT-2, Llama (dense and MoE) and
 the Granite 4.0-H hybrid (Mamba-2 state-space mixers among GQA attention:
 ``granite4_h_micro`` at its published sizes, ``granite4_h_micro_share`` one
 chip's share of it, ``granite_hybrid_tiny`` for tests; training only,
-``dp``/``fsdp`` only).
+``dp``/``fsdp`` only) and the ``afmoe`` family (gated window and full
+attention over a sigmoid-routed mixture with a shared expert:
+``trinity_mini`` at its published sizes, ``trinity_mini_share`` one chip's
+share of it, ``afmoe_tiny`` for tests; training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -304,6 +307,41 @@ _REGISTRY["granite4_h_micro_share"] = _granite_hybrid(
     lambda g, **kw: g.chip_share(g.granite4_h_micro(**kw)))
 _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
     lambda g, **kw: g.granite_hybrid_tiny(**kw))
+
+
+def _afmoe(make):
+    """Registry builder for the ``afmoe`` family (models/afmoe.py):
+    ``make(afmoe, **kw)`` returns the module. ``dp``/``fsdp`` only, as the
+    Granite hybrid: the expert layer is told which experts it holds and has
+    no exchange, and there is no tensor-parallel rule table."""
+    def build(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
+              sp=False, attn_impl="auto", logits_dtype, **_):
+        from pytorch_distributed_training_example_tpu.models import afmoe
+
+        if sp:
+            raise ValueError(
+                "the afmoe family has no tensor- or sequence-parallel "
+                "rules; use strategy dp or fsdp")
+        module = make(afmoe, dtype=dtype, param_dtype=param_dtype,
+                      remat=remat, remat_policy=remat_policy,
+                      attn_impl=attn_impl, logits_dtype=logits_dtype)
+        return ModelBundle(
+            module=module, task="lm",
+            input_template=(jnp.zeros((2, seq_len), jnp.int32),),
+            fwd_flops_per_example=seq_len
+            * afmoe.forward_flops_per_token(module, seq_len),
+            rules={}, examples_unit="sequences")
+    return build
+
+
+# The published Trinity-Mini; one chip's share of it (an eighth of every
+# layer's routed experts and of the vocabulary, a leading dense layer and the
+# first period of expert layers: what the one-chip benchmark cell trains),
+# made from the first; and a toy for the tests.
+_REGISTRY["trinity_mini"] = _afmoe(lambda a, **kw: a.trinity_mini(**kw))
+_REGISTRY["trinity_mini_share"] = _afmoe(
+    lambda a, **kw: a.chip_share(a.trinity_mini(**kw)))
+_REGISTRY["afmoe_tiny"] = _afmoe(lambda a, **kw: a.afmoe_tiny(**kw))
 
 
 @register("resnet_micro")

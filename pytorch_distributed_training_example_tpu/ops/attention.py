@@ -44,10 +44,13 @@ def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
     return jnp.repeat(k, num_q_heads // num_kv, axis=2)
 
 
-def _causal_masked(logits, q_offset):
+def _causal_masked(logits, q_offset, window=None):
     q_pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) + q_offset
     k_pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 3)
-    return jnp.where(q_pos >= k_pos, logits, NEG_INF)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, logits, NEG_INF)
 
 
 def dot_product_attention(
@@ -58,13 +61,17 @@ def dot_product_attention(
     causal: bool = False,
     bias: jax.Array | None = None,
     q_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Reference attention in pure XLA; fp32 softmax, inputs' dtype out.
 
     ``q_offset`` positions the query block within the global sequence for
     causal masking (used by the ring schedule where K/V blocks come from
-    other context shards).
+    other context shards). ``window`` (causal only): row i sees the keys
+    [i - window + 1, i].
     """
+    if window is not None and not causal:
+        raise ValueError("window attention is causal")
     orig_dtype = q.dtype
     depth = q.shape[-1]
     k = _repeat_kv(k, q.shape[2])
@@ -75,7 +82,7 @@ def dot_product_attention(
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     if causal:
-        logits = _causal_masked(logits, q_offset)
+        logits = _causal_masked(logits, q_offset, window)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                      preferred_element_type=jnp.float32)
@@ -481,7 +488,7 @@ def ulysses_attention(
 def attention(
     q, k, v, *, causal=False, impl: str = "auto",
     mesh: Mesh | None = None, context_axis: str = "context",
-    batch_axes=("data", "fsdp"),
+    batch_axes=("data", "fsdp"), window: int | None = None,
 ):
     """Dispatcher used by the models.
 
@@ -493,11 +500,19 @@ def attention(
     :func:`zigzag_ring_attention`). 'ring_allgather' is the all-gather-KV
     fallback for backends where the ppermute ring doesn't lower or overlap
     (see :func:`ring_attention` ``ring_impl``).
+
+    ``window`` (causal only): row i sees the keys [i - window + 1, i]. The
+    flash path has window kernels and the XLA path a mask; the context-
+    parallel schedules and the padded one-shot path have neither.
     """
     from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 
     mesh = mesh or mesh_lib.current_mesh()
     ctx = mesh.shape.get(context_axis, 1) if mesh is not None else 1
+    if window is not None:
+        return _window_attention(q, k, v, causal=causal, impl=impl, ctx=ctx,
+                                 mesh=mesh, batch_axes=batch_axes,
+                                 window=window)
     if impl == "auto":
         if ctx > 1:
             impl = "ring_zigzag" if causal else "ring"
@@ -554,6 +569,26 @@ def attention(
                                  causal=causal, mesh=mesh,
                                  batch_axes=batch_axes)
     return dot_product_attention(q, k, v, causal=causal)
+
+
+def _window_attention(q, k, v, *, causal, impl, ctx, mesh, batch_axes,
+                      window):
+    """``attention`` with a window: the window kernels where the flash path
+    is eligible (or asked for), else the masked XLA reference."""
+    if impl not in ("auto", "flash", "xla") or ctx > 1:
+        raise ValueError(
+            f"window attention has no context-parallel schedule (impl="
+            f"{impl!r}, context axis {ctx}); use impl auto, flash or xla")
+    if impl != "xla" and _flash_eligible(q, k, explicit=impl == "flash"):
+        from pytorch_distributed_training_example_tpu.ops import flash_attention
+
+        return _per_device_flash(
+            functools.partial(flash_attention.flash_attention, window=window),
+            q, k, v, causal=causal, mesh=mesh, batch_axes=batch_axes)
+    if impl == "flash" and backend.on_tpu():
+        raise ValueError(f"attn_impl='flash' not eligible for shape "
+                         f"q={q.shape} k={k.shape} with a window")
+    return dot_product_attention(q, k, v, causal=causal, window=window)
 
 
 def _per_device_flash(fn, q, k, v, *, causal, mesh, batch_axes):

@@ -358,6 +358,245 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
 
 
 # ---------------------------------------------------------------------------
+# Window kernels: causal attention in which row i sees the keys
+# [i - window + 1, i] (``sliding_attention`` layers). The online kernels'
+# scheme with one difference that carries the rest: the innermost grid
+# dimension walks only the ``nb + 1`` kv blocks (q blocks, in the dk/dv pass)
+# that a block's window can reach, through index maps offset by the outer
+# block's own index, so a block outside the window costs neither a grid step
+# nor a DMA. Whether a visited block needs the elementwise mask depends on
+# its distance from the diagonal alone, which is the inner index: the blocks
+# strictly inside the window take the unmasked body.
+# ---------------------------------------------------------------------------
+
+# q and kv block of the window kernels: at window 2048 a q block visits five
+# kv blocks (2,560 keys for the 2,048 a row sees); 1024 would visit 3,072.
+WINDOW_BLOCK = 512
+
+
+def _window_blocks(S: int, window: int, block_q: int, block_kv: int):
+    """``(block, nb)``: one block size for q and kv, and how many kv blocks
+    before the diagonal one a q block's window can reach."""
+    block = _fit_block(S, min(block_q, block_kv, WINDOW_BLOCK))
+    return block, min(-(-(window - 1) // block), S // block - 1)
+
+
+def _window_mask(s, dist, block, window):
+    """Mask the score tile of a (q block, kv block) pair ``dist`` blocks
+    apart: row r sees column c iff 0 <= dist*block + r - c < window."""
+    gap = dist * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((gap >= 0) & (gap < window), s, NEG_INF)
+
+
+def _window_inside(dist, block, window):
+    """True where every pair of the tile is visible: below the diagonal block
+    and with its farthest pair (last row, first column) inside the window."""
+    return (dist > 0) & ((dist + 1) * block - 1 < window)
+
+
+def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                       acc_ref, *, sm_scale, window, block, nb):
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    dist = nb - j                       # q block index less kv block index
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def step(masked):
+        q, k, v = _mxu(q_ref[0, 0]), _mxu(k_ref[0, 0]), _mxu(v_ref[0, 0])
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            logits = _window_mask(logits, dist, block, window)
+        # A row may see no key of the window's first block: its running max
+        # stays NEG_INF and p reads 1 there; the first real max that follows
+        # (the diagonal block holds the row's own key) multiplies that by
+        # exp(NEG_INF - m) = 0.
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * correction + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    inside = _window_inside(dist, block, window)
+    seen = qi - dist >= 0               # the kv block exists
+    pl.when(seen & inside)(lambda: step(False))
+    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(j == nb)
+    def _finish():
+        denom = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_ref[:, :LSE_LANES]
+                         + jnp.log(jnp.maximum(l_ref[:, :LSE_LANES], 1e-30)))
+
+
+def _window_fwd(q, k, v, *, window, block_q, block_kv):
+    """(out [B,S,H,D], lse [B,H,S,LSE_LANES]); K/V already GQA-expanded."""
+    B, S, H, D = q.shape
+    block, nb = _window_blocks(S, window, block_q, block_kv)
+    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    qspec = pl.BlockSpec((1, 1, block, D), lambda b, h, i, j: (b, h, i, 0))
+    kspec = pl.BlockSpec(
+        (1, 1, block, D),
+        lambda b, h, i, j: (b, h, jnp.maximum(i - nb + j, 0), 0))
+    out, lse = pl.pallas_call(
+        functools.partial(_window_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
+                          window=window, block=block, nb=nb),
+        name="flash_fwd_window",
+        grid=(B, H, S // block, nb + 1),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=(qspec, pl.BlockSpec((1, 1, block, LSE_LANES),
+                                       lambda b, h, i, j: (b, h, i, 0))),
+        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, LSE_LANES), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((block, 128), jnp.float32),   # m
+                        pltpu.VMEM((block, 128), jnp.float32),   # l
+                        pltpu.VMEM((block, D), jnp.float32)],    # acc
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+    )(tr(q), tr(k), tr(v))
+    return tr(out), lse
+
+
+def _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dist, *,
+                  masked, sm_scale, window, block):
+    """``(p, ds)`` of one tile, as the online backward kernels compute them."""
+    q, k = _mxu(q_ref[0, 0]), _mxu(k_ref[0, 0])
+    v, do = _mxu(v_ref[0, 0]), _mxu(do_ref[0, 0])
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    if masked:
+        s = _window_mask(s, dist, block, window)
+    p = jnp.exp(s - lse_ref[0, 0, :, :1])
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta_ref[0, 0, :, :1]) * sm_scale).astype(q.dtype)
+    return p, ds
+
+
+def _window_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, acc_ref, *, nb, **tile):
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    dist = nb - j
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def step(masked):
+        _, ds = _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, dist, masked=masked, **tile)
+        acc_ref[:] += jax.lax.dot_general(
+            ds, _mxu(k_ref[0, 0]), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    inside = _window_inside(dist, tile["block"], tile["window"])
+    seen = qi - dist >= 0
+    pl.when(seen & inside)(lambda: step(False))
+    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(j == nb)
+    def _finish():
+        dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
+
+
+def _window_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dk_acc, dv_acc, *, nb, n_q, **tile):
+    kvi = pl.program_id(2)
+    dist = pl.program_id(3)             # q block index less kv block index
+
+    @pl.when(dist == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def step(masked):
+        p, ds = _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, dist, masked=masked, **tile)
+        do = _mxu(do_ref[0, 0])
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[:] += jax.lax.dot_general(
+            ds, _mxu(q_ref[0, 0]), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    inside = _window_inside(dist, tile["block"], tile["window"])
+    seen = kvi + dist < n_q             # the q block exists
+    pl.when(seen & inside)(lambda: step(False))
+    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(dist == nb)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _window_bwd(q, k, v, o, lse, g, *, window, block_q, block_kv):
+    """q,k,v,o,g: [B,S,H,D] (kv GQA-expanded); lse: [B,H,S,LSE_LANES]."""
+    B, S, H, D = q.shape
+    block, nb = _window_blocks(S, window, block_q, block_kv)
+    n_q = S // block
+    tile = dict(sm_scale=1.0 / math.sqrt(D), window=window, block=block)
+    delta = _delta_rows(g, o)
+    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    operands = (tr(q), tr(k), tr(v), tr(g), lse, delta)
+    semantics = pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+    def specs(q_block, kv_block):
+        rows = lambda width, at: pl.BlockSpec(
+            (1, 1, block, width), lambda b, h, i, j: (b, h, at(i, j), 0))
+        return (rows(D, q_block), rows(D, kv_block),
+                rows(LSE_LANES, q_block))
+
+    qspec, kspec, lspec = specs(lambda i, j: i,
+                                lambda i, j: jnp.maximum(i - nb + j, 0))
+    dq = pl.pallas_call(
+        functools.partial(_window_dq_kernel, nb=nb, **tile),
+        name="flash_bwd_window_dq",
+        grid=(B, H, n_q, nb + 1),
+        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
+        compiler_params=semantics,
+    )(*operands)
+
+    # dk/dv pass: kv blocks outer, the nb + 1 q blocks that see each inner
+    qspec, kspec, lspec = specs(lambda i, j: jnp.minimum(i + j, n_q - 1),
+                                lambda i, j: i)
+    dk, dv = pl.pallas_call(
+        functools.partial(_window_dkv_kernel, nb=nb, n_q=n_q, **tile),
+        name="flash_bwd_window_dkv",
+        grid=(B, H, n_q, nb + 1),
+        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
+        out_specs=(kspec, kspec),
+        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, D), v.dtype)),
+        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
+                        pltpu.VMEM((block, D), jnp.float32)],
+        compiler_params=semantics,
+    )(*operands)
+    return tr(dq), tr(dk), tr(dv)
+
+
+# ---------------------------------------------------------------------------
 # One-shot kernels: short/medium sequences (the LM bench shapes).
 #
 # The online-softmax kernels above are grid-step bound at small head_dim:
@@ -1132,12 +1371,13 @@ def _stream_bwd(q, k, v, o, lse, g, *, causal, plan):
     return tr(dq), tr(dk), tr(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_kv: int = DEFAULT_BLOCK_KV,
                     impl: str = "auto",
-                    kv_len: int | None = None):
+                    kv_len: int | None = None,
+                    window: int | None = None):
     """Flash attention with the XLA oracle's exact semantics.
 
     [B, S, H, D] layout; fp32 softmax; GQA via fewer KV heads. Forward and
@@ -1151,14 +1391,31 @@ def flash_attention(q, k, v, causal: bool = False,
     tile-padding path in :func:`attention.padded_flash_attention` that
     serves non-tile-aligned sequences (e.g. ViT's 197 tokens padded to
     256); one-shot kernels only.
+
+    ``window`` (static): row i sees the keys [i - window + 1, i] only
+    (causal self-attention; the window kernels, whatever ``impl``). A window
+    that covers the sequence is plain causal attention and dispatches as such.
     """
     k = attn_lib._repeat_kv(k, q.shape[2])
     v = attn_lib._repeat_kv(v, q.shape[2])
-    out, _ = _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len)
+    out, _ = _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
+                           window)
     return out
 
 
-def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
+def _window_of(window, causal, kv_len, Sq, Skv):
+    """The window the kernels mask by, or None where it masks nothing."""
+    if window is None:
+        return None
+    if not causal or kv_len is not None or Sq != Skv or window < 1:
+        raise ValueError(
+            f"window={window} needs causal self-attention without kv_len "
+            f"(causal={causal}, kv_len={kv_len}, Sq={Sq}, Skv={Skv})")
+    return window if window < Skv else None
+
+
+def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
+                  window=None):
     """Auto dispatch is per direction, each from measurements on the chip:
 
     - Causal self-attention (Sq == Skv, no kv_len) at a shape in
@@ -1182,6 +1439,10 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
     impl="oneshot"/"online" still pin both sides.
     """
     B, Sq, H, D = q.shape
+    window = _window_of(window, causal, kv_len, Sq, k.shape[1])
+    if window is not None:
+        return _window_fwd(q, k, v, window=window, block_q=block_q,
+                           block_kv=block_kv)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
@@ -1207,11 +1468,13 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len):
                       block_kv=block_kv)
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len):
+def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len, window):
     ke = attn_lib._repeat_kv(k, q.shape[2])
     ve = attn_lib._repeat_kv(v, q.shape[2])
     out, lse = _fwd_dispatch(q, ke, ve, causal, block_q, block_kv, impl,
-                             kv_len)
+                             kv_len, window)
+    if _window_of(window, causal, kv_len, q.shape[1], k.shape[1]) is not None:
+        return out, (q, k, v, out, lse)
     fwd_plan, bwd_plan = (
         _auto_causal_plan(impl, causal, kv_len, q.shape[1], k.shape[1],
                           q.shape[2], q.shape[3], q.dtype, bwd=bwd)
@@ -1222,11 +1485,16 @@ def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len):
     return out, (q, k, v, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, res, g):
+def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
     q, k, v, o, lse = res
     H, Hkv = q.shape[2], k.shape[2]
     ke = attn_lib._repeat_kv(k, H)
     ve = attn_lib._repeat_kv(v, H)
+    window = _window_of(window, causal, kv_len, q.shape[1], k.shape[1])
+    if window is not None:
+        dq, dk, dv = _window_bwd(q, ke, ve, o, lse, g, window=window,
+                                 block_q=block_q, block_kv=block_kv)
+        return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
@@ -1267,12 +1535,16 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, res, g):
                                                block_q, block_kv)
             dq, dk, dv = _flash_bwd(q, ke, ve, o, lse, g, causal=causal,
                                     block_q=block_q, block_kv=block_kv)
+    return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)
+
+
+def _fold_kv_heads(dk, dv, H, Hkv):
+    """GQA: fold the repeated-head grads back onto the shared KV heads."""
     if Hkv != H:
-        # GQA: fold the repeated-head grads back onto the shared KV heads.
         B, S, _, D = dk.shape
         dk = dk.reshape(B, S, Hkv, H // Hkv, D).sum(3)
         dv = dv.reshape(B, S, Hkv, H // Hkv, D).sum(3)
-    return dq, dk, dv
+    return dk, dv
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

@@ -75,29 +75,53 @@ def _block_rows(n_rows: int, num_experts: int) -> int:
     return bt
 
 
-def _block_cols(n: int) -> int:
-    """Largest nice power-of-two column block; odd widths get one block."""
-    for bc in (512, 256, 128, 64, 32, 16, 8):
-        if n % bc == 0:
+#: Largest weight (or weight-gradient) block a kernel keeps in VMEM; Pallas
+#: double-buffers it, and the row tiles ride beside it under the 16 MB scope.
+_BLOCK_BYTES = 4 * 1024 * 1024
+
+
+def _block_cols(n: int, depth: int = 1, itemsize: int = 4) -> int:
+    """Largest nice power-of-two column block whose ``[depth, block]`` tile
+    of ``itemsize`` bytes stays within ``_BLOCK_BYTES`` (a wider block reads
+    each row tile fewer times); odd widths get one block."""
+    blocks = [bc for bc in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
+              if n % bc == 0]
+    for bc in blocks:
+        if depth * bc * itemsize <= _BLOCK_BYTES:
             return bc
-    return n
+    return blocks[-1] if blocks else n
+
+
+def num_tiles(group_counts, bt: int):
+    """Tiles the padded layout uses: whole ones for every segment, and one
+    for an empty segment."""
+    return jnp.sum(jnp.maximum(-(-group_counts // bt), 1)).astype(jnp.int32)
 
 
 def _padded_layout(group_starts, group_counts, n_rows: int,
-                   num_experts: int, bt: int):
+                   num_experts: int, bt: int, max_tiles: int | None = None):
     """Tile-aligned relayout of the ragged segments, static shapes.
 
-    Returns ``(tile_expert [G], tile_first [G], src [G*bt], dst [n_rows])``
-    (all int32): padded row ``r`` reads input row ``src[r]`` (``n_rows`` =
-    the appended zero row), tile ``g`` multiplies expert ``tile_expert[g]``'s
-    weights (``tile_first[g]`` marks the expert's first tile — the backward
-    accumulator init), and logical output row ``j`` reads padded row
-    ``dst[j]``. ``G = ceil(n_rows/bt) + num_experts`` is a static bound on
-    ``sum(max(ceil(counts/bt), 1))`` — every expert rounds up at most one
-    partial tile and empty experts keep one tile each.
+    Returns ``(tiles, src [G*bt], dst [n_rows])`` (all int32) with ``tiles
+    = (tile_expert [G], tile_first [G], num_tiles [1])``: padded row ``r``
+    reads input row ``src[r]`` (``n_rows`` = no row: zeros), tile ``g``
+    multiplies expert ``tile_expert[g]``'s weights (``tile_first[g]`` marks
+    the expert's first tile — the backward accumulator init), and logical
+    output row ``j`` reads padded row ``dst[j]``. ``G = ceil(n_rows/bt) +
+    num_experts`` is a static bound on ``num_tiles = sum(max(ceil(counts/bt),
+    1))`` — every expert rounds up at most one partial tile and empty experts
+    keep one tile each; the kernels run the first ``num_tiles`` tiles and
+    pass over the rest, whose output rows nobody reads. A caller that knows
+    a tighter bound on ``num_tiles`` gives it as ``max_tiles``.
+
+    The segments may end before ``n_rows`` (an expert layer that holds a part
+    of the experts sorts the rows of the others behind its own): such rows
+    belong to no tile and read ``dst = G*bt``, past the padded array.
     """
     E = num_experts
     G = -(-n_rows // bt) + E
+    if max_tiles is not None:
+        G = min(G, max_tiles)
     tiles_per_e = jnp.maximum(-(-group_counts // bt), 1)          # [E]
     tile_starts = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32),
@@ -106,6 +130,7 @@ def _padded_layout(group_starts, group_counts, n_rows: int,
     tile_expert = (jnp.searchsorted(tile_starts, tile_ids, side="right")
                    .astype(jnp.int32) - 1)                        # [G]
     tile_first = (tile_ids == tile_starts[tile_expert]).astype(jnp.int32)
+    used = num_tiles(group_counts, bt).reshape(1)
 
     padded_starts = tile_starts * bt                              # [E]
     r = jnp.arange(G * bt, dtype=jnp.int32)
@@ -121,32 +146,56 @@ def _padded_layout(group_starts, group_counts, n_rows: int,
     e_j = (jnp.searchsorted(group_starts, j, side="right")
            .astype(jnp.int32) - 1)
     dst = (padded_starts[e_j] + (j - group_starts[e_j])).astype(jnp.int32)
-    return tile_expert, tile_first, src, dst
+    dst = jnp.where(j < group_starts[-1] + group_counts[-1], dst, G * bt)
+    return (tile_expert, tile_first, used), src, dst
 
 
-def _gmm_kernel(te_ref, x_ref, w_ref, out_ref):
-    del te_ref  # consumed by the index_maps
-    out_ref[...] = jax.lax.dot_general(
-        x_ref[...], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+def _last_tile(g, nt):
+    """Block index of tile ``g``: a tile past the last one in use repeats
+    that one's, so the pipeline moves nothing for it."""
+    return jnp.minimum(g, nt[0] - 1)
 
 
-def _gmm_call(x_pad, w, tile_expert, bt: int, out_dtype):
+def _gmm_kernel(te_ref, tf_ref, nt_ref, x_ref, w_ref, out_ref, *,
+                transposed: bool):
+    del te_ref, tf_ref  # consumed by the index_maps / the dw kernel
+
+    # A tile past the last one in use repeats that one's block indices (no
+    # DMA) and leaves the output block as the last tile wrote it.
+    @pl.when(pl.program_id(1) < nt_ref[0])
+    def _tile():
+        out_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0],
+            (((1,), (1 if transposed else 0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _gmm_call(x_pad, w, tiles, bt: int, out_dtype, transposed: bool = False):
+    """``x_pad [Tp, d] @ w[e] [d, f]`` a tile, or with ``transposed`` ``x_pad
+    [Tp, f] @ w[e]^T``: the backward's dx reads the weight as it lies and
+    contracts its last dimension, so no transposed copy is made."""
     Tp, d = x_pad.shape
-    E, _, f = w.shape
-    bf = _block_cols(f)
+    f = w.shape[1] if transposed else w.shape[2]
+    bf = _block_cols(f, d, w.dtype.itemsize)
+    if transposed:
+        w_spec = pl.BlockSpec((1, bf, d), lambda jc, g, te, tf, nt: (
+            te[_last_tile(g, nt)], jc, 0))
+    else:
+        w_spec = pl.BlockSpec((1, d, bf), lambda jc, g, te, tf, nt: (
+            te[_last_tile(g, nt)], 0, jc))
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, transposed=transposed),
         name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(f // bf, Tp // bt),
             in_specs=[
-                pl.BlockSpec((bt, d), lambda jc, g, te: (g, 0)),
-                pl.BlockSpec((1, d, bf), lambda jc, g, te: (te[g], 0, jc)),
+                pl.BlockSpec((bt, d),
+                             lambda jc, g, te, tf, nt: (_last_tile(g, nt), 0)),
+                w_spec,
             ],
-            out_specs=pl.BlockSpec((bt, bf), lambda jc, g, te: (g, jc)),
+            out_specs=pl.BlockSpec(
+                (bt, bf), lambda jc, g, te, tf, nt: (_last_tile(g, nt), jc)),
         ),
         out_shape=jax.ShapeDtypeStruct((Tp, f), out_dtype),
         # Sequential grid: consecutive same-expert tiles keep the weight
@@ -154,88 +203,95 @@ def _gmm_call(x_pad, w, tile_expert, bt: int, out_dtype):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=not backend.on_tpu(),
-    )(tile_expert, x_pad, w)
+    )(*tiles, x_pad, w)
 
 
-def _gmm_dw_kernel(te_ref, tf_ref, x_ref, g_ref, dw_ref):
+def _gmm_dw_kernel(te_ref, tf_ref, nt_ref, x_ref, g_ref, dw_ref):
     del te_ref
     g_idx = pl.program_id(1)
+    live = g_idx < nt_ref[0]
 
     # First tile of this expert's segment (per column block): the [1, d, bf]
     # output block is revisited by every later tile of the segment, so
     # zero it exactly once before accumulating.
-    @pl.when(tf_ref[g_idx] == 1)
+    @pl.when(live & (tf_ref[g_idx] == 1))
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    dw_ref[...] += jax.lax.dot_general(
-        x_ref[...], g_ref[...],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[None].astype(dw_ref.dtype)
+    @pl.when(live)
+    def _tile():
+        dw_ref[...] += jax.lax.dot_general(
+            x_ref[...], g_ref[...],
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)[None].astype(dw_ref.dtype)
 
 
-def _gmm_dw_call(x_pad, g_pad, tile_expert, tile_first, num_experts: int,
-                 bt: int):
+def _gmm_dw_call(x_pad, g_pad, tiles, num_experts: int, bt: int):
     Tp, d = x_pad.shape
     f = g_pad.shape[1]
-    bf = _block_cols(f)
+    bf = _block_cols(f, d, 4)
     return pl.pallas_call(
         _gmm_dw_kernel,
         name="grouped_matmul_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             # Token tiles are the INNER grid dim: for each column block the
             # tiles of one expert are visited consecutively (the padded
             # layout is segment-sorted), which is what makes the revisited
             # dw block a valid accumulator under sequential semantics.
             grid=(f // bf, Tp // bt),
             in_specs=[
-                pl.BlockSpec((bt, d), lambda jc, g, te, tf: (g, 0)),
-                pl.BlockSpec((bt, bf), lambda jc, g, te, tf: (g, jc)),
+                pl.BlockSpec((bt, d),
+                             lambda jc, g, te, tf, nt: (_last_tile(g, nt), 0)),
+                pl.BlockSpec((bt, bf), lambda jc, g, te, tf, nt: (
+                    _last_tile(g, nt), jc)),
             ],
             out_specs=pl.BlockSpec(
-                (1, d, bf), lambda jc, g, te, tf: (te[g], 0, jc)),
+                (1, d, bf),
+                lambda jc, g, te, tf, nt: (te[_last_tile(g, nt)], 0, jc)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_experts, d, f), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=not backend.on_tpu(),
-    )(tile_expert, tile_first, x_pad, g_pad)
+    )(*tiles, x_pad, g_pad)
 
 
 def _pad_rows(x, src):
     """Gather rows into the tile-aligned layout; index n_rows reads zeros."""
-    return jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[src]
+    return jnp.take(x, src, axis=0, mode="fill", fill_value=0)
+
+
+def _float0(tiles):
+    return tuple(np.zeros(t.shape, jax.dtypes.float0) for t in tiles)
 
 
 @jax.custom_vjp
-def _gmm_padded(x_pad, w, tile_expert, tile_first):
+def _gmm_padded(x_pad, w, tiles):
     """Kernel entry over the PADDED layout: [Tp, d] -> [Tp, f] (no relayout).
 
-    The tile height is implied by the shapes (``bt = Tp // G``). Padded rows
-    are zero on the way in and garbage-free on the way out (zero rows times
-    weights are zero), so callers can chain padded-space ops — the grouped
-    FFN runs up-proj -> gelu -> down-proj entirely in this layout and pays
-    for ONE relayout round trip instead of one per matmul.
+    ``tiles`` is ``_padded_layout``'s triple; the tile height is implied by
+    the shapes (``bt = Tp // G``). Padded rows of the tiles in use are zero
+    on the way in and on the way out (zero rows times weights are zero); the
+    rows of the tiles past ``num_tiles`` are never written, here or in the
+    backward, and nothing may read them. Callers chain padded-space ops —
+    the grouped FFNs run up-proj -> activation -> down-proj entirely in this
+    layout and pay for ONE relayout round trip instead of one per matmul.
     """
-    bt = x_pad.shape[0] // tile_expert.shape[0]
-    return _gmm_call(x_pad, w, tile_expert, bt, x_pad.dtype)
+    bt = x_pad.shape[0] // tiles[0].shape[0]
+    return _gmm_call(x_pad, w, tiles, bt, x_pad.dtype)
 
 
-def _gmm_padded_fwd(x_pad, w, tile_expert, tile_first):
-    return _gmm_padded(x_pad, w, tile_expert, tile_first), (
-        x_pad, w, tile_expert, tile_first)
+def _gmm_padded_fwd(x_pad, w, tiles):
+    return _gmm_padded(x_pad, w, tiles), (x_pad, w, tiles)
 
 
 def _gmm_padded_bwd(res, dout_pad):
-    x_pad, w, tile_expert, tile_first = res
-    bt = x_pad.shape[0] // tile_expert.shape[0]
-    dx_pad = _gmm_call(dout_pad, jnp.swapaxes(w, 1, 2), tile_expert, bt,
-                       x_pad.dtype)
-    dw = _gmm_dw_call(x_pad, dout_pad, tile_expert, tile_first,
-                      w.shape[0], bt).astype(w.dtype)
-    zeros = functools.partial(np.zeros, dtype=jax.dtypes.float0)
-    return dx_pad, dw, zeros(tile_expert.shape), zeros(tile_first.shape)
+    x_pad, w, tiles = res
+    bt = x_pad.shape[0] // tiles[0].shape[0]
+    dx_pad = _gmm_call(dout_pad, w, tiles, bt, x_pad.dtype, transposed=True)
+    dw = _gmm_dw_call(x_pad, dout_pad, tiles, w.shape[0], bt).astype(w.dtype)
+    return dx_pad, dw, _float0(tiles)
 
 
 _gmm_padded.defvjp(_gmm_padded_fwd, _gmm_padded_bwd)
@@ -255,23 +311,35 @@ def grouped_ffn(x, w_up, w_down, group_starts, group_counts):
     Tk = x.shape[0]
     E = w_up.shape[0]
     bt = _block_rows(Tk, E)
-    tile_expert, tile_first, src, dst = _padded_layout(
-        group_starts, group_counts, Tk, E, bt)
+    tiles, src, dst = _padded_layout(group_starts, group_counts, Tk, E, bt)
     x_pad = _pad_rows(x, src)
-    h_pad = _gmm_padded(x_pad, w_up, tile_expert, tile_first)
+    h_pad = _gmm_padded(x_pad, w_up, tiles)
     h_pad = jax.nn.gelu(h_pad)
-    out_pad = _gmm_padded(h_pad, w_down, tile_expert, tile_first)
+    out_pad = _gmm_padded(h_pad, w_down, tiles)
     return out_pad[dst]
+
+
+def gated_ffn_padded(x_pad, w_gate, w_up, w_down, tiles):
+    """Gated grouped expert MLP over rows ALREADY in the padded layout:
+    ``(silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` a tile (SwiGLU
+    experts). The caller owns the relayout (``_padded_layout``'s ``src`` and
+    ``dst``): an expert layer that holds a part of the experts gathers token
+    rows straight into this layout and combines straight out of it. The gate
+    is multiplied in float32 and rounded once."""
+    gate = _gmm_padded(x_pad, w_gate, tiles)
+    up = _gmm_padded(x_pad, w_up, tiles)
+    h_pad = (jax.nn.silu(gate.astype(jnp.float32))
+             * up.astype(jnp.float32)).astype(x_pad.dtype)
+    return _gmm_padded(h_pad, w_down, tiles)
 
 
 def _gmm_impl(x, w, group_starts, group_counts):
     Tk, d = x.shape
     E = w.shape[0]
     bt = _block_rows(Tk, E)  # static (shape-derived) — recomputed in bwd
-    tile_expert, tile_first, src, dst = _padded_layout(
-        group_starts, group_counts, Tk, E, bt)
-    out_pad = _gmm_call(_pad_rows(x, src), w, tile_expert, bt, x.dtype)
-    return out_pad[dst], (tile_expert, tile_first, src, dst)
+    tiles, src, dst = _padded_layout(group_starts, group_counts, Tk, E, bt)
+    out_pad = _gmm_call(_pad_rows(x, src), w, tiles, bt, x.dtype)
+    return out_pad[dst], (tiles, src, dst)
 
 
 @jax.custom_vjp
@@ -296,21 +364,19 @@ def _gmm_fwd(x, w, group_starts, group_counts):
 
 def _gmm_bwd(res, dout):
     x, w, group_starts, group_counts, layout = res
-    tile_expert, tile_first, src, dst = layout
+    tiles, src, dst = layout
     bt = _block_rows(x.shape[0], w.shape[0])
     dout_pad = _pad_rows(dout, src)
-    # dx: the same grouped matmul against the transposed weight blocks,
+    # dx: the same grouped matmul against the weight blocks read transposed,
     # reusing the tile layout (dout rows live in the same segments as x).
-    dx_pad = _gmm_call(dout_pad, jnp.swapaxes(w, 1, 2), tile_expert, bt,
-                       x.dtype)
+    dx_pad = _gmm_call(dout_pad, w, tiles, bt, x.dtype, transposed=True)
     dx = dx_pad[dst]
     # dw: segment-wise accumulation — padded rows are zero on both sides,
     # so they contribute nothing; empty experts' single all-padding tile
     # zero-initializes their block.
-    dw = _gmm_dw_call(_pad_rows(x, src), dout_pad, tile_expert, tile_first,
-                      w.shape[0], bt).astype(w.dtype)
-    zeros = functools.partial(np.zeros, dtype=jax.dtypes.float0)
-    return dx, dw, zeros(group_starts.shape), zeros(group_counts.shape)
+    dw = _gmm_dw_call(_pad_rows(x, src), dout_pad, tiles, w.shape[0],
+                      bt).astype(w.dtype)
+    return (dx, dw) + _float0((group_starts, group_counts))
 
 
 gmm.defvjp(_gmm_fwd, _gmm_bwd)

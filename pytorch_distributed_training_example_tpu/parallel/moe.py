@@ -62,6 +62,7 @@ from typing import Any, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
@@ -857,6 +858,318 @@ class MoEBlock(nn.Module):
         with jax.named_scope("moe_combine"):
             return jnp.einsum("tec,ecd->td", combine,
                               expert_out.astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# The expert layer of sigmoid-routed models with a shared expert (afmoe,
+# models/afmoe.py): gated experts, a bias that only chooses, and a layer that
+# is told which experts it holds.
+# ---------------------------------------------------------------------------
+
+
+#: Row tile of the held experts' grouped matmuls: an expert sees T*k/E rows
+#: on average (512 at 8,192 tokens and 8 of 128), and a taller tile pads
+#: more of them.
+EXPERT_TILE_ROWS = 128
+
+
+def _rows(x, index):
+    """``x[index]`` with zeros where ``index`` is past the last row."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _dispatch_rows(tokens, row_token, pair_row):
+    """``tokens [T, d]`` into the experts' padded layout ``[P, d]``: padded
+    row p holds token ``row_token[p]`` (T = none: zeros). ``pair_row [T, k]``
+    is the inverse (the padded row of a token's c-th choice, P = none), so
+    the transpose is a gather too and no scatter-add is ever lowered."""
+    return _rows(tokens, row_token)
+
+
+def _dispatch_fwd(tokens, row_token, pair_row):
+    return _rows(tokens, row_token), (row_token, pair_row)
+
+
+def _dispatch_bwd(res, d_pad):
+    row_token, pair_row = res
+    d_tokens = jnp.sum(_rows(d_pad, pair_row), axis=1, dtype=jnp.float32)
+    return (d_tokens.astype(d_pad.dtype), _int_zeros(row_token),
+            _int_zeros(pair_row))
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _weighted(y_pad, weights, pair_row):
+    """``[T, k, d]`` float32: each choice's row of ``y_pad`` times its weight
+    (one fusion: the gathered rows are read in their own dtype)."""
+    return _rows(y_pad, pair_row).astype(jnp.float32) * weights[..., None]
+
+
+@jax.custom_vjp
+def _combine_rows(y_pad, weights, pair_row, row_pair):
+    """``out[t] = sum_c weights[t, c] * y_pad[pair_row[t, c]]`` in float32
+    (a choice with no padded row adds nothing); ``row_pair [P]`` is the
+    inverse (the flat (token, choice) of a padded row, T*k = none)."""
+    return jnp.sum(_weighted(y_pad, weights, pair_row), axis=1)
+
+
+def _combine_fwd(y_pad, weights, pair_row, row_pair):
+    return (_combine_rows(y_pad, weights, pair_row, row_pair),
+            (y_pad, weights, pair_row, row_pair))
+
+
+def _combine_bwd(res, d_out):
+    y_pad, weights, pair_row, row_pair = res
+    k = weights.shape[1]
+    d_weights = jnp.sum(_rows(y_pad, pair_row).astype(jnp.float32)
+                        * d_out[:, None, :], axis=-1)
+    d_pad = (_rows(d_out.astype(y_pad.dtype), row_pair // k)
+             * _rows(weights.reshape(-1, 1), row_pair))
+    return (d_pad.astype(y_pad.dtype), d_weights.astype(weights.dtype),
+            _int_zeros(pair_row), _int_zeros(row_pair))
+
+
+_combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _int_zeros(index):
+    return np.zeros(index.shape, jax.dtypes.float0)
+
+
+def _held_keys(chosen, first, held):
+    """``[n*k]``: the held expert (0..held-1) a (token, choice) pair chose,
+    or ``held`` where it chose another chip's."""
+    local = chosen.reshape(-1) - first
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
+def _held_counts(key, held):
+    return jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+
+
+def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None):
+    """The held experts' part of the layer's sum for these tokens, ``[n, d]``
+    float32: ``experts = (w_gate, w_up, w_down)`` are the experts ``first``
+    onwards, ``chosen [n, k]`` indexes all the router's experts, and the
+    padded layout takes at most ``max_tiles`` tiles of ``bt`` rows."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    (n, k), held = chosen.shape, experts[0].shape[0]
+    with jax.named_scope("moe_dispatch"):
+        # Sort the (token, choice) pairs by held expert, the pairs that chose
+        # another chip's expert behind them all; integers only. Both
+        # directions of every move of rows are gathers.
+        key = _held_keys(chosen, first, held)                       # [n*k]
+        pairs = jnp.arange(n * k, dtype=jnp.int32)
+        _, order = jax.lax.sort((key, pairs), num_keys=1)
+        _, rank = jax.lax.sort((order, pairs), num_keys=1)
+        counts = _held_counts(key, held)
+        starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+        tiles, src, dst = gmm_lib._padded_layout(
+            starts, counts, n * k, held, bt, max_tiles)
+        row_pair = _rows(order, src) + (src >= n * k) * (n * k)     # [P]
+        pair_row = dst[rank].reshape(n, k)
+        x_pad = _dispatch_rows(tokens, row_pair // k, pair_row)
+    with jax.named_scope("moe_experts"):
+        y_pad = mesh_lib.manual_call(
+            gmm_lib.gated_ffn_padded, x_pad, *experts, tiles,
+            in_specs=P(), out_specs=P())
+    with jax.named_scope("moe_combine"):
+        return _combine_rows(y_pad, weights, pair_row, row_pair)
+
+
+def _whole_or_parts(whole, parts, chosen, first, held, bt, cap):
+    """``whole()`` where the held experts' rows of all the tokens fit ``cap``
+    tiles, by the router's own counts, else ``parts()``."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    counts = _held_counts(_held_keys(chosen, first, held), held)
+    return jax.lax.cond(gmm_lib.num_tiles(counts, bt) <= cap, whole, parts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _routed_bounded(tokens, chosen, weights, experts, first, bt, chunks):
+    """``_routed`` in a layout of bounded size, whatever the router does.
+
+    The layout's static size is its worst case: every choice of every token
+    held here, E/held times the rows a balanced router sends. So the tokens
+    are taken whole in a layout of the ``chunks``-th part of that where the
+    router's counts say they fit (the rule, unless routing collapses), and
+    else in ``chunks`` parts one after another, each of whose worst case is
+    that same layout: the same routine either way, nothing dropped, and
+    buffers of the smaller size alone. Differentiated as a whole (the
+    backward computes the taken branch again and differentiates that):
+    residuals that cross a ``cond`` are materialised, the float32
+    intermediates of the gate and copies of the experts' weights among them.
+    """
+    return _bounded(chosen, first, bt, chunks)(tokens, weights, experts)
+
+
+def _bounded(chosen, first, bt, chunks):
+    """The bounded routine as a function of what it is differentiated in,
+    ``f(tokens, weights, experts)``."""
+    (n, k) = chosen.shape
+    part = n // chunks
+
+    def f(tokens, weights, experts):
+        cap = -(-part * k // bt) + experts[0].shape[0]
+        split = lambda a: a.reshape(chunks, part, *a.shape[1:])
+        one = lambda a: _routed(*a, experts, first, bt, cap)
+        return _whole_or_parts(
+            lambda: one((tokens, chosen, weights)),
+            lambda: jax.lax.map(jax.checkpoint(one), (
+                split(tokens), split(chosen), split(weights))).reshape(
+                    n, tokens.shape[1]),
+            chosen, first, experts[0].shape[0], bt, cap)
+    return f
+
+
+def _routed_bounded_fwd(tokens, chosen, weights, experts, first, bt, chunks):
+    return (_routed_bounded(tokens, chosen, weights, experts, first, bt,
+                            chunks), (tokens, chosen, weights, experts))
+
+
+def _routed_bounded_bwd(first, bt, chunks, res, d_out):
+    tokens, chosen, weights, experts = res
+    _, vjp = jax.vjp(_bounded(chosen, first, bt, chunks), tokens, weights,
+                     experts)
+    d_tokens, d_weights, d_experts = vjp(d_out)
+    return d_tokens, _int_zeros(chosen), d_weights, d_experts
+
+
+_routed_bounded.defvjp(_routed_bounded_fwd, _routed_bounded_bwd)
+
+
+class SharedExpertMoE(nn.Module):
+    """Sigmoid-routed experts beside a shared expert, on the chip that holds
+    ``held_experts`` of them (torchtitan's MoE as the ``afmoe`` models
+    configure it; the equations are in ``models/afmoe.py``).
+
+    ``s = sigmoid(x W_r)`` over all ``num_experts``, in float32; the ``top_k``
+    largest of ``s + b`` are chosen (``b``, ``expert_bias``, is a buffer in
+    the ``batch_stats`` collection: no gradient, no optimizer state); the
+    weights are ``route_scale * s_i / (sum of the chosen s + 1e-20)``: the
+    bias chooses and nothing more. ``y = Shared(x) + sum_i w_i Expert_i(x)``
+    with SwiGLU experts. After a training step ``b += d - mean(d)``, ``d =
+    balance_coeff * sign(mean(c) - c)``, ``c`` the tokens of this call that
+    chose each expert (all ``num_experts``, this chip's tokens).
+
+    ``held_experts = (how many, starting where)``: the layer routes over all
+    the experts and computes the part of the sum that its own give, for the
+    tokens that chose them; what the others would add is left out, and is the
+    business of the chips that hold them (their results would be summed over
+    the ``expert`` axis; on one chip the layer runs without that exchange).
+    Dropless: every (token, choice) that lands here is computed, whatever the
+    imbalance. The rows are gathered straight into the grouped matmul's tile
+    layout (``ops/grouped_matmul.py``), whose kernels run only the tiles in
+    use: a row that chose no held expert costs no matmul tile.
+
+    Sows into ``telemetry`` (fetched at the log cadence): ``moe_held_rows``
+    (the rows that landed on held experts), ``moe_held_peak`` (the fullest
+    held expert's rows over their mean) and ``moe_bias_peak`` (largest |b|),
+    each with the enclosing block's name behind a dot.
+    """
+
+    num_experts: int
+    ffn_dim: int
+    top_k: int
+    held_experts: tuple | None = None   # (how many, starting where)
+    shared_ffn_dim: int = 0
+    route_scale: float = 1.0
+    balance_coeff: float = 0.0
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = True):
+        """``x [B, S, d]`` in any float dtype: the router reads it as it
+        comes (float32 from a caller that keeps it so), the experts in the
+        compute dtype."""
+        from pytorch_distributed_training_example_tpu.ops import (
+            grouped_matmul as gmm_lib)
+
+        B, S, d = x.shape
+        E, k, T = self.num_experts, self.top_k, B * S
+        held, first = self.held_experts or (E, 0)
+        if not (0 < held and 0 <= first and first + held <= E):
+            raise ValueError(f"held_experts={self.held_experts} of {E}")
+        tokens = x.reshape(T, d)
+        bias = self.variable("batch_stats", "expert_bias",
+                             lambda: jnp.zeros((E,), jnp.float32))
+
+        with jax.named_scope("moe_router"):
+            # a true float32 product: two scores that nearly tie must come
+            # out in the order the published float32 router gives them
+            kernel = self.param("router", nn.initializers.lecun_normal(),
+                                (d, E), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(jnp.float32), kernel,
+                precision=jax.lax.Precision.HIGHEST))             # [T, E]
+            _, chosen = jax.lax.top_k(scores + bias.value, k)       # [T, k]
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = self.route_scale * picked / (
+                jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+            load = jnp.bincount(chosen.reshape(-1), length=E)       # [E]
+            load = mesh_lib.constrain(load, P(None))
+            if train and not self.is_initializing() \
+                    and self.is_mutable_collection("batch_stats"):
+                mean = jnp.mean(load.astype(jnp.float32))
+                delta = self.balance_coeff * jnp.sign(mean - load)
+                bias.value = bias.value + delta - jnp.mean(delta)
+
+        stacked = lambda name, shape: self.param(
+            name, nn.initializers.lecun_normal(), (held, *shape),
+            self.param_dtype).astype(self.dtype)
+        experts = (stacked("w_gate", (d, self.ffn_dim)),
+                   stacked("w_up", (d, self.ffn_dim)),
+                   stacked("w_down", (self.ffn_dim, d)))
+        bt = min(EXPERT_TILE_ROWS, gmm_lib._block_rows(T * k, held))
+        held_load = load[first:first + held].astype(jnp.int32)
+
+        # rows in a layout of bounded size: see ``_routed_bounded``
+        tokens = tokens.astype(self.dtype)
+        chunks = max(1, E // (2 * held))
+        if chunks == 1 or T % chunks:
+            out = _routed(tokens, chosen, weights, experts, first, bt)
+        else:
+            out = _routed_bounded(tokens, chosen, weights, experts, first, bt,
+                                  chunks)
+        if self.shared_ffn_dim:
+            with jax.named_scope("moe_shared"):
+                out = out + SwiGLU(self.shared_ffn_dim, self.dtype,
+                                   self.param_dtype, name="shared")(
+                    tokens).astype(jnp.float32)
+
+        layer = "." + self.path[-2] if len(self.path) > 1 else ""
+        rows = held_load.astype(jnp.float32)
+        self.sow("telemetry", "moe_held_rows" + layer, jnp.sum(rows))
+        self.sow("telemetry", "moe_held_peak" + layer,
+                 jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0))
+        self.sow("telemetry", "moe_bias_peak" + layer,
+                 jnp.max(jnp.abs(bias.value)))
+        return out.reshape(B, S, d).astype(self.dtype)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(h)) * up(h))`` without biases, as a module of its
+    own (``models.llama.swiglu_mlp`` builds the same three layers into the
+    calling block)."""
+    ffn_dim: int
+    dtype: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        dense = lambda feat, name: nn.Dense(
+            feat, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name)
+        return dense(h.shape[-1], "down")(
+            nn.silu(dense(self.ffn_dim, "gate")(h))
+            * dense(self.ffn_dim, "up")(h))
 
 
 #: Expert-parallel rules: stacked expert weights shard on the 'expert' axis

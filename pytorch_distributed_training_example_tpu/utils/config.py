@@ -251,6 +251,22 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # Trinity-Mini (models/afmoe.py) whole, and one chip's share of it (an
+    # eighth of each layer's 128 experts and of the vocabulary, a dense
+    # layer and one period of expert layers): gated window / full attention
+    # over sigmoid-routed experts, one 8k sequence a chip per micro-step
+    "trinity_mini": dict(
+        model="trinity_mini", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=8, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "trinity_mini_share": dict(
+        model="trinity_mini_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -514,31 +514,32 @@ def test_causal_kernels_dispatch(monkeypatch):
     q = jnp.zeros((1, 1024, 12, 64), jnp.bfloat16)
     res = (q, q, q, "o", "l")
     F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None)
-    F._vjp_bwd(True, 1024, 1024, "auto", None, res, q)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, res, q)
     assert calls == ["_causal_fwd", "_causal_bwd"]
     del calls[:]
     half = q[:, :512]
     p = jnp.zeros((1, 256, 12, 64), jnp.bfloat16)  # ViT's padded 197
     for fwd_args, bwd_args in (
             ((q, q, q, False, 1024, 1024, "auto", None),
-             (False, 1024, 1024, "auto", None, res, q)),
+             (False, 1024, 1024, "auto", None, None, res, q)),
             ((p, p, p, False, 1024, 1024, "auto", 197),
-             (False, 1024, 1024, "auto", 197, (p, p, p, "o", "l"), p)),
+             (False, 1024, 1024, "auto", 197, None, (p, p, p, "o", "l"), p)),
             ((p, p, p, True, 1024, 1024, "auto", 197),
-             (True, 1024, 1024, "auto", 197, (p, p, p, "o", "l"), p)),
+             (True, 1024, 1024, "auto", 197, None, (p, p, p, "o", "l"), p)),
             ((half, q, q, True, 1024, 1024, "auto", None),
-             (True, 1024, 1024, "auto", None, (half, q, q, "o", "l"), half)),
+             (True, 1024, 1024, "auto", None, None, (half, q, q, "o", "l"),
+              half)),
             ((q, q, q, True, 1024, 1024, "online", None),
-             (True, 1024, 1024, "online", None, res, q)),
+             (True, 1024, 1024, "online", None, None, res, q)),
             ((q, q, q, True, 1024, 1024, "oneshot", None),
-             (True, 1024, 1024, "oneshot", None, res, q))):
+             (True, 1024, 1024, "oneshot", None, None, res, q))):
         F._fwd_dispatch(*fwd_args)
         F._vjp_bwd(*bwd_args)
     odd = jnp.zeros((1, 1536, 12, 64), jnp.bfloat16)  # not in CAUSAL_MEASURED
     # measured, and its bytes counted, in bf16 (_mxu widens fp16 in VMEM)
     for x in (odd, q.astype(jnp.float32), q.astype(jnp.float16)):
         F._fwd_dispatch(x, x, x, True, 1024, 1024, "auto", None)
-        F._vjp_bwd(True, 1024, 1024, "auto", None, (x, x, x, "o", "l"), x)
+        F._vjp_bwd(True, 1024, 1024, "auto", None, None, (x, x, x, "o", "l"), x)
     assert len(calls) == 18 and not any("causal" in c for c in calls), calls
     # S=2048/D=128: measured ahead in both directions, but the backward's
     # whole-head blocks are over the planner's budget -> chunked one-shot
@@ -548,7 +549,7 @@ def test_causal_kernels_dispatch(monkeypatch):
     assert F._causal_plan(16, 2048, 128, bwd=True) is None
     assert F._causal_plan(16, 4096, 128) is None
     F._fwd_dispatch(big, big, big, True, 1024, 1024, "auto", None)
-    F._vjp_bwd(True, 1024, 1024, "auto", None, (big, big, big, "o", "l"), big)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (big, big, big, "o", "l"), big)
     assert calls == ["_causal_fwd", "_oneshot_bwd"]
 
 
@@ -662,19 +663,19 @@ def test_auto_dispatch_is_per_direction(monkeypatch):
     F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None)
     F._fwd_dispatch(q, q, q, False, 1024, 1024, "auto", None)
     res = (q, q, q, "o", "l")
-    F._vjp_bwd(True, 1024, 1024, "auto", None, res, jnp.zeros_like(q))
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, res, jnp.zeros_like(q))
     q4 = jnp.zeros((1, 4096, 16, 64), jnp.bfloat16)  # bwd plan infeasible, D=64
-    F._vjp_bwd(True, 1024, 1024, "auto", None, (q4, q4, q4, "o", "l"),
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (q4, q4, q4, "o", "l"),
                jnp.zeros_like(q4))
     q4k = jnp.zeros((1, 4096, 16, 128), jnp.bfloat16)  # D=128 long context
-    F._vjp_bwd(True, 1024, 1024, "auto", None, (q4k, q4k, q4k, "o", "l"),
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (q4k, q4k, q4k, "o", "l"),
                jnp.zeros_like(q4k))
     # forced online must never take the streaming path
-    F._vjp_bwd(True, 1024, 1024, "online", None, (q4k, q4k, q4k, "o", "l"),
+    F._vjp_bwd(True, 1024, 1024, "online", None, None, (q4k, q4k, q4k, "o", "l"),
                jnp.zeros_like(q4k))
     # S=8192/D=128: the streaming plan is over the chip's scoped VMEM
     q8 = jnp.zeros((1, 8192, 16, 128), jnp.bfloat16)
-    F._vjp_bwd(True, 1024, 1024, "auto", None, (q8, q8, q8, "o", "l"),
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (q8, q8, q8, "o", "l"),
                jnp.zeros_like(q8))
     assert calls == ["_flash_fwd", "_oneshot_fwd", "_oneshot_bwd",
                      "_flash_bwd", "_stream_bwd", "_flash_bwd",

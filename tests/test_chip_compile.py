@@ -293,3 +293,103 @@ def test_ssd_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
     with mesh_lib.use_mesh(mesh):
         text = grads.lower(*_granite_scan_args(batch, b=4)).compile().as_text()
     assert "ssd_fwd" in text and "ssd_bwd" in text and "all-reduce" in text
+
+
+# -- Trinity-Mini's share (models/afmoe.py): the window kernels, the held
+# -- experts' grouped matmuls, and the whole step at the benchmark's size
+
+
+def test_window_flash_compiles_at_published_widths(one_chip):
+    """B1 H32/4 S8192 D128 with the published window of 2048: the three
+    window kernels and none of the others; the same call without a window
+    keeps the kernels it had."""
+    q = _sds((1, 8192, 32, 128), one_chip)
+    kv = _sds((1, 8192, 4, 128), one_chip)
+    windowed = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True, window=2048)), q, kv, kv)
+    for name in ("flash_fwd_window", "flash_bwd_window_dq",
+                 "flash_bwd_window_dkv"):
+        assert name in windowed, name
+    assert "flash_fwd_online" not in windowed
+    full = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True)), q, kv, kv)
+    assert "flash_fwd_online" in full and "flash_fwd_window" not in full
+
+
+def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
+    """16 held experts of 128, 8 a token, 8,192 tokens of 2048, experts of
+    1024: the gated grouped FFN forward and backward (the transposed read of
+    the weights in dx, the 4 MB accumulator of dw under the scoped VMEM)."""
+    from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
+
+    layer = moe_lib.SharedExpertMoE(
+        num_experts=128, ffn_dim=1024, top_k=8, held_experts=(16, 0),
+        shared_ffn_dim=1024, route_scale=2.826, balance_coeff=0.001,
+        dtype=BF16, param_dtype=jnp.float32)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((1, 8192, 2048), BF16), train=False))
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _sds(s.shape, one_chip, s.dtype), tree)
+
+    def grads(params, stats, x):
+        return jax.grad(lambda p, x: layer.apply(
+            {"params": p, "batch_stats": stats}, x, train=False).astype(
+                jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = _compiled_text(grads, on_chip(shapes["params"]),
+                          on_chip(shapes["batch_stats"]),
+                          _sds((1, 8192, 2048), one_chip))
+    assert "grouped_matmul_dw" in text and "conditional" in text
+    # the bounded layout: 144 tiles of 128 rows, not the worst case's 528
+    assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
+
+
+@pytest.mark.slow  # 80 s of the TPU compiler on every core: whole-step
+# compiles stay out of the tier-1 suite (this file's docstring); run it by name
+def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``trinity_mini_share`` at 1 x 8192, bf16,
+    per-block remat, AdamW) compiles for a described v5e with its arguments,
+    temporaries and unaliased outputs under the chip's memory: 15.79 GB of
+    the 16.9 the allocator offers (PERF.md has the chip's own reading)."""
+    from pytorch_distributed_training_example_tpu.core import (
+        train_loop, trainer as trainer_lib)
+    from pytorch_distributed_training_example_tpu.core.train_state import (
+        TrainState)
+    from pytorch_distributed_training_example_tpu.utils.config import (
+        from_preset)
+
+    cfg = from_preset("trinity_mini_share", global_batch_size=1,
+                      seq_len=8192, lr_schedule="constant", warmup_epochs=0.0,
+                      attn_impl="flash")
+    mesh = mesh_lib.build_mesh(dict(data=1, fsdp=1),
+                               devices=[one_chip._device])
+    bundle = trainer_lib.build_model(cfg)
+    program = trainer_lib.build_step_program(cfg, mesh, 100, bundle)
+    model = bundle.module
+
+    def init(rng):
+        variables = model.init({"params": rng, "dropout": rng},
+                               *bundle.input_template, train=False)
+        return TrainState.create(
+            apply_fn=model.apply, params=variables["params"], tx=program.tx,
+            rng=rng, batch_stats=variables.get("batch_stats"), scaler=None)
+
+    shape = jax.eval_shape(init, jax.random.PRNGKey(0))
+    shardings = train_loop.state_shardings(shape, mesh, program.rules)
+    state = jax.tree.map(lambda s, sh: _sds(s.shape, sh, s.dtype), shape,
+                         shardings)
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+    batch = {k: _sds((1, 8192), rows, jnp.int32)
+             for k in ("tokens", "targets")}
+    with mesh_lib.use_mesh(mesh):
+        compiled = program.train_step.lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes == pytest.approx(705_473_792 * 12,
+                                                       rel=1e-3)
+    assert held < 16.0e9, held
+    text = compiled.as_text()
+    for name in ("flash_fwd_window", "flash_bwd_window_dq", "flash_fwd_online",
+                 "grouped_matmul", "grouped_matmul_dw"):
+        assert name in text, name

@@ -287,13 +287,15 @@ def test_every_pallas_call_has_a_name(file, call):
 def test_pallas_names_are_one_per_kernel():
     names = [k.value.value for p in _pallas_sites() for k in p.values[1].keywords
              if k.arg == "name"]
-    assert len(names) == 14 and len(set(names)) == 14
+    assert len(names) == 17 and len(set(names)) == 17
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
     assert {n for n in names if n.startswith("flash_fwd")} == {
-        "flash_fwd_online", "flash_fwd_oneshot", "flash_fwd_causal"}
+        "flash_fwd_online", "flash_fwd_oneshot", "flash_fwd_causal",
+        "flash_fwd_window"}
     assert {n for n in names if n.startswith("flash_bwd")} == {
         "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_oneshot",
-        "flash_bwd_stream", "flash_bwd_causal"}
+        "flash_bwd_stream", "flash_bwd_causal", "flash_bwd_window_dq",
+        "flash_bwd_window_dkv"}
 
 
 def test_region_vocabulary_is_in_the_compiled_tiny_gpt2_step(trained):
