@@ -949,11 +949,20 @@ def _held_counts(key, held):
     return jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
 
 
-def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None):
+def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
+                 counts=None):
     """The held experts' part of the layer's sum for these tokens, ``[n, d]``
-    float32: ``experts = (w_gate, w_up, w_down)`` are the experts ``first``
-    onwards, ``chosen [n, k]`` indexes all the router's experts, and the
-    padded layout takes at most ``max_tiles`` tiles of ``bt`` rows."""
+    float32, and what a backward reads of this forward beside its inputs:
+    ``experts = (w_gate, w_up, w_down)`` are the experts ``first`` onwards,
+    ``chosen [n, k]`` indexes all the router's experts, the padded layout
+    takes at most ``max_tiles`` tiles of ``bt`` rows, and ``counts`` are the
+    held experts' rows where the caller has them (the router counts all the
+    tokens). Kept: the integer plan ``(tiles, pair_row, row_pair)`` and the
+    two projections ``(gate, up)`` of the padded rows in the compute dtype;
+    ``x_pad`` is one gather of rows from the plan and ``y_pad`` one grouped
+    matmul from ``gate`` and ``up``, and whatever is kept here lives from the
+    forward to the backward in the step XLA schedules (151 MB more a layer
+    at the published widths, were both kept)."""
     from pytorch_distributed_training_example_tpu.ops import (
         grouped_matmul as gmm_lib)
 
@@ -966,7 +975,8 @@ def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None):
         pairs = jnp.arange(n * k, dtype=jnp.int32)
         _, order = jax.lax.sort((key, pairs), num_keys=1)
         _, rank = jax.lax.sort((order, pairs), num_keys=1)
-        counts = _held_counts(key, held)
+        if counts is None:
+            counts = _held_counts(key, held)
         starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
         tiles, src, dst = gmm_lib._padded_layout(
             starts, counts, n * k, held, bt, max_tiles)
@@ -974,71 +984,138 @@ def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None):
         pair_row = dst[rank].reshape(n, k)
         x_pad = _dispatch_rows(tokens, row_pair // k, pair_row)
     with jax.named_scope("moe_experts"):
-        y_pad = mesh_lib.manual_call(
-            gmm_lib.gated_ffn_padded, x_pad, *experts, tiles,
+        y_pad, gate, up = mesh_lib.manual_call(
+            gmm_lib.gated_ffn_padded_kept, x_pad, *experts, tiles,
             in_specs=P(), out_specs=P())
     with jax.named_scope("moe_combine"):
-        return _combine_rows(y_pad, weights, pair_row, row_pair)
+        out = _combine_rows(y_pad, weights, pair_row, row_pair)
+    return out, ((tiles, pair_row, row_pair), (gate, up))
 
 
-def _whole_or_parts(whole, parts, chosen, first, held, bt, cap):
-    """``whole()`` where the held experts' rows of all the tokens fit ``cap``
-    tiles, by the router's own counts, else ``parts()``."""
+def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None,
+            counts=None):
+    """``_routed_kept``'s sum alone: under plain AD where every expert is
+    held (no ``cond``), and the routine that the bounded layout repeats."""
+    return _routed_kept(tokens, chosen, weights, experts, first, bt,
+                        max_tiles, counts)[0]
+
+
+def _routed_kept_bwd(kept, tokens, weights, experts, d_out):
+    """``(d_tokens, d_weights, d_experts)`` of ``_routed_kept``'s sum from
+    what it kept: the transposes that plain AD of ``_routed`` strings
+    together, in its order, on the forward's own ``gate`` and ``up``; of the
+    forward only the gather of rows and the down projection run again."""
     from pytorch_distributed_training_example_tpu.ops import (
         grouped_matmul as gmm_lib)
 
-    counts = _held_counts(_held_keys(chosen, first, held), held)
-    return jax.lax.cond(gmm_lib.num_tiles(counts, bt) <= cap, whole, parts)
+    (tiles, pair_row, row_pair), (gate, up) = kept
+    with jax.named_scope("moe_dispatch"):
+        row_token = row_pair // weights.shape[1]
+        x_pad = _rows(tokens, row_token)
+    with jax.named_scope("moe_experts"):
+        y_pad = mesh_lib.manual_call(
+            gmm_lib.gated_down_padded, gate, up, experts[2], tiles,
+            in_specs=P(), out_specs=P())
+    with jax.named_scope("moe_combine"):
+        dy_pad, d_weights = _combine_bwd(
+            (y_pad, weights, pair_row, row_pair), d_out)[:2]
+    with jax.named_scope("moe_experts"):
+        dx_pad, *d_experts = mesh_lib.manual_call(
+            gmm_lib.gated_ffn_padded_bwd, x_pad, gate, up, *experts, tiles,
+            dy_pad, in_specs=P(), out_specs=P())
+    with jax.named_scope("moe_dispatch"):
+        d_tokens = _dispatch_bwd((row_token, pair_row), dx_pad)[0]
+    return d_tokens, d_weights, tuple(d_experts)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _routed_bounded(tokens, chosen, weights, experts, first, bt, chunks):
+def _bounded_tiles(chosen, experts, bt, chunks):
+    """Tiles of the bounded layout: the worst case of a ``chunks``-th part
+    of the tokens, every choice of each held here."""
+    (n, k), held = chosen.shape, experts[0].shape[0]
+    return -(-(n // chunks) * k // bt) + held
+
+
+def _fits(counts, bt, cap):
+    """Do the held experts' ``counts`` rows fit ``cap`` tiles of ``bt``?"""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    return gmm_lib.num_tiles(counts, bt) <= cap
+
+
+def _in_parts(tokens, chosen, weights, experts, first, bt, chunks, cap):
+    """``_routed`` over ``chunks`` parts of the tokens one after another,
+    each counting its own rows; a part's residuals are its inputs."""
+    n = chosen.shape[0]
+    split = lambda a: a.reshape(chunks, n // chunks, *a.shape[1:])
+    one = lambda a: _routed(*a, experts, first, bt, cap)
+    return jax.lax.map(jax.checkpoint(one), (
+        split(tokens), split(chosen), split(weights))).reshape(
+            n, tokens.shape[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _routed_bounded(tokens, chosen, weights, experts, counts, first, bt,
+                    chunks):
     """``_routed`` in a layout of bounded size, whatever the router does.
 
     The layout's static size is its worst case: every choice of every token
     held here, E/held times the rows a balanced router sends. So the tokens
     are taken whole in a layout of the ``chunks``-th part of that where the
-    router's counts say they fit (the rule, unless routing collapses), and
-    else in ``chunks`` parts one after another, each of whose worst case is
-    that same layout: the same routine either way, nothing dropped, and
-    buffers of the smaller size alone. Differentiated as a whole (the
-    backward computes the taken branch again and differentiates that):
-    residuals that cross a ``cond`` are materialised, the float32
-    intermediates of the gate and copies of the experts' weights among them.
+    router's ``counts`` of the held experts' rows say they fit (the rule,
+    unless routing collapses), and else in ``chunks`` parts one after
+    another, each of whose worst case is that same layout: the same routine
+    either way, nothing dropped, and buffers of the smaller size alone.
+
+    Differentiated by hand, because residuals that cross a ``cond`` are
+    materialised and plain AD hands out the float32 intermediates of the
+    gate and copies of the experts' weights among them (+1.5 GB). The forward
+    rule's ``cond`` hands out, beside the sum, what ``_routed_kept`` keeps:
+    the integer plan and ``gate`` and ``up`` in the compute dtype (zeros of
+    those shapes from the parts, which nobody reads). The backward rule
+    branches on the same counts: the whole layout's side strings the
+    transposes together from what was kept (``_routed_kept_bwd``), the
+    parts' side computes its forward again part by part, as ``lax.map`` over
+    a checkpointed part does. This function itself computes the sum alone.
     """
-    return _bounded(chosen, first, bt, chunks)(tokens, weights, experts)
+    cap = _bounded_tiles(chosen, experts, bt, chunks)
+    return jax.lax.cond(
+        _fits(counts, bt, cap),
+        lambda: _routed(tokens, chosen, weights, experts, first, bt, cap,
+                        counts),
+        lambda: _in_parts(tokens, chosen, weights, experts, first, bt, chunks,
+                          cap))
 
 
-def _bounded(chosen, first, bt, chunks):
-    """The bounded routine as a function of what it is differentiated in,
-    ``f(tokens, weights, experts)``."""
-    (n, k) = chosen.shape
-    part = n // chunks
-
-    def f(tokens, weights, experts):
-        cap = -(-part * k // bt) + experts[0].shape[0]
-        split = lambda a: a.reshape(chunks, part, *a.shape[1:])
-        one = lambda a: _routed(*a, experts, first, bt, cap)
-        return _whole_or_parts(
-            lambda: one((tokens, chosen, weights)),
-            lambda: jax.lax.map(jax.checkpoint(one), (
-                split(tokens), split(chosen), split(weights))).reshape(
-                    n, tokens.shape[1]),
-            chosen, first, experts[0].shape[0], bt, cap)
-    return f
-
-
-def _routed_bounded_fwd(tokens, chosen, weights, experts, first, bt, chunks):
-    return (_routed_bounded(tokens, chosen, weights, experts, first, bt,
-                            chunks), (tokens, chosen, weights, experts))
+def _routed_bounded_fwd(tokens, chosen, weights, experts, counts, first, bt,
+                        chunks):
+    cap = _bounded_tiles(chosen, experts, bt, chunks)
+    whole = lambda: _routed_kept(tokens, chosen, weights, experts, first, bt,
+                                 cap, counts)
+    kept = jax.eval_shape(whole)[1]
+    out, kept = jax.lax.cond(
+        _fits(counts, bt, cap), whole,
+        lambda: (_in_parts(tokens, chosen, weights, experts, first, bt,
+                           chunks, cap),
+                 jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), kept)))
+    return out, (tokens, chosen, weights, experts, counts, kept)
 
 
 def _routed_bounded_bwd(first, bt, chunks, res, d_out):
-    tokens, chosen, weights, experts = res
-    _, vjp = jax.vjp(_bounded(chosen, first, bt, chunks), tokens, weights,
-                     experts)
-    d_tokens, d_weights, d_experts = vjp(d_out)
-    return d_tokens, _int_zeros(chosen), d_weights, d_experts
+    tokens, chosen, weights, experts, counts, kept = res
+    cap = _bounded_tiles(chosen, experts, bt, chunks)
+
+    def parts():
+        _, vjp = jax.vjp(
+            lambda *a: _in_parts(a[0], chosen, *a[1:], first, bt, chunks,
+                                 cap), tokens, weights, experts)
+        return vjp(d_out)
+
+    d_tokens, d_weights, d_experts = jax.lax.cond(
+        _fits(counts, bt, cap),
+        lambda: _routed_kept_bwd(kept, tokens, weights, experts, d_out), parts)
+    return (d_tokens, _int_zeros(chosen), d_weights, d_experts,
+            _int_zeros(counts))
 
 
 _routed_bounded.defvjp(_routed_bounded_fwd, _routed_bounded_bwd)
@@ -1068,10 +1145,19 @@ class SharedExpertMoE(nn.Module):
     layout (``ops/grouped_matmul.py``), whose kernels run only the tiles in
     use: a row that chose no held expert costs no matmul tile.
 
+    Where it holds under half of the experts the rows go through
+    ``_routed_bounded``: one ``cond`` on the router's own counts of the held
+    experts' rows, which in the forward that a backward follows hands out
+    the integer plan and the experts' ``gate`` and ``up`` projections in the
+    compute dtype, so that the backward runs no routed forward again; no
+    float32 intermediate and no copy of a weight crosses it.
+
     Sows into ``telemetry`` (fetched at the log cadence): ``moe_held_rows``
     (the rows that landed on held experts), ``moe_held_peak`` (the fullest
-    held expert's rows over their mean) and ``moe_bias_peak`` (largest |b|),
-    each with the enclosing block's name behind a dot.
+    held expert's rows over their mean), ``moe_bias_peak`` (largest |b|) and
+    ``moe_whole`` (1.0 where the held rows fit the whole layout and the kept
+    residuals serve the backward, 0.0 where the tokens went in parts), each
+    with the enclosing block's name behind a dot.
     """
 
     num_experts: int
@@ -1135,9 +1221,12 @@ class SharedExpertMoE(nn.Module):
         chunks = max(1, E // (2 * held))
         if chunks == 1 or T % chunks:
             out = _routed(tokens, chosen, weights, experts, first, bt)
+            whole = jnp.ones((), jnp.float32)
         else:
-            out = _routed_bounded(tokens, chosen, weights, experts, first, bt,
-                                  chunks)
+            out = _routed_bounded(tokens, chosen, weights, experts, held_load,
+                                  first, bt, chunks)
+            whole = _fits(held_load, bt, _bounded_tiles(
+                chosen, experts, bt, chunks)).astype(jnp.float32)
         if self.shared_ffn_dim:
             with jax.named_scope("moe_shared"):
                 out = out + SwiGLU(self.shared_ffn_dim, self.dtype,
@@ -1151,6 +1240,7 @@ class SharedExpertMoE(nn.Module):
                  jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0))
         self.sow("telemetry", "moe_bias_peak" + layer,
                  jnp.max(jnp.abs(bias.value)))
+        self.sow("telemetry", "moe_whole" + layer, whole)
         return out.reshape(B, S, d).astype(self.dtype)
 
 

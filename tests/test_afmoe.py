@@ -380,6 +380,59 @@ def test_collapsed_routing_takes_the_parts_and_drops_nothing():
     text = str(jax.make_jaxpr(lambda p: layer.apply(
         {"params": p, "batch_stats": spread}, x, train=False))(params))
     assert "cond" in text     # whole where it fits, in parts where not
+    for bias, whole in ((collapsed, 0.0), (spread, 1.0)):
+        _, sown = layer.apply({"params": params, "batch_stats": bias}, x,
+                              train=False, mutable=["telemetry"])
+        assert float(sown["telemetry"]["moe_whole"][0]) == whole
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("routing", ["level", "collapsed"])
+def test_bounded_backward_is_plain_ad_of_the_routine(routing, remat):
+    """The hand-written backward of ``_routed_bounded`` against plain AD of
+    ``_routed`` on the same inputs, in tokens, weights and the three stacked
+    weights: the whole layout's side strings the same products together from
+    what the forward kept (bit for bit), the parts' side sums the weights'
+    gradients part by part (to 1e-6 of the leaf's scale); with the block's
+    remat around it the forward rule is what runs again."""
+    T, d, f, E, k, held, first = 64, 32, 16, 16, 2, 2, 6
+    keys = jax.random.split(jax.random.key(3), 6)
+    tokens = jax.random.normal(keys[0], (T, d))
+    scores = jax.random.uniform(keys[1], (T, E))
+    if routing == "collapsed":
+        scores = scores.at[:, first:first + held].add(5.0)
+    _, chosen = jax.lax.top_k(scores, k)
+    weights_ = jax.random.uniform(keys[2], (T, k), minval=0.2)
+    experts = tuple(0.3 * jax.random.normal(key, shape) for key, shape in zip(
+        keys[3:], [(held, d, f), (held, d, f), (held, f, d)]))
+    counts = jnp.bincount(chosen.reshape(-1), length=E)[
+        first:first + held].astype(jnp.int32)
+    bt, chunks = 8, E // (2 * held)
+    whole = bool(moe_lib._fits(counts, bt, moe_lib._bounded_tiles(
+        chosen, experts, bt, chunks)))
+    assert whole == (routing == "level")
+
+    def bounded(tokens, weights_, experts):
+        return moe_lib._routed_bounded(tokens, chosen, weights_, experts,
+                                       counts, first, bt, chunks)
+
+    def plain(tokens, weights_, experts):
+        return moe_lib._routed(tokens, chosen, weights_, experts, first, bt)
+
+    grads = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2)))(
+            tokens, weights_, experts)
+    with HIGHEST:
+        want_out, want = grads(plain)
+        got_out, got = grads(jax.checkpoint(bounded) if remat else bounded)
+    assert float(jnp.max(jnp.abs(want[0]))) > 1e-3
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if whole:
+            np.testing.assert_array_equal(g, w)
+        else:
+            np.testing.assert_allclose(
+                g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
 
 
 def test_bias_moves_as_the_rule_says_and_no_optimizer_sees_it():
@@ -399,6 +452,7 @@ def test_bias_moves_as_the_rule_says_and_no_optimizer_sees_it():
         pytest.approx(0.0, abs=1e-6)
     sown = {k: float(v[0]) for k, v in new["telemetry"].items()}
     assert sown["moe_held_rows"] == 64 * 2
+    assert sown["moe_whole"] == 1.0      # every expert held: no bound to pass
     assert sown["moe_held_peak"] == pytest.approx(
         float(counts.max()) / float(counts.mean()))
     # evaluation leaves the bias alone

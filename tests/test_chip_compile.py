@@ -316,10 +316,9 @@ def test_window_flash_compiles_at_published_widths(one_chip):
     assert "flash_fwd_online" in full and "flash_fwd_window" not in full
 
 
-def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
-    """16 held experts of 128, 8 a token, 8,192 tokens of 2048, experts of
-    1024: the gated grouped FFN forward and backward (the transposed read of
-    the weights in dx, the 4 MB accumulator of dw under the scoped VMEM)."""
+def _held_experts_layer(one_chip):
+    """``(layer, params, batch_stats, x)``: 16 held experts of 128, 8 a
+    token, 8,192 tokens of 2048, experts of 1024, as shapes on the chip."""
     from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
 
     layer = moe_lib.SharedExpertMoE(
@@ -330,17 +329,65 @@ def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
         jax.random.key(0), jnp.zeros((1, 8192, 2048), BF16), train=False))
     on_chip = lambda tree: jax.tree.map(
         lambda s: _sds(s.shape, one_chip, s.dtype), tree)
+    return (layer, on_chip(shapes["params"]), on_chip(shapes["batch_stats"]),
+            _sds((1, 8192, 2048), one_chip))
+
+
+def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
+    """The held experts' layer at the published widths: the gated grouped
+    FFN forward and backward (the transposed read of the weights in dx, the
+    4 MB accumulator of dw under the scoped VMEM)."""
+    layer, *args = _held_experts_layer(one_chip)
 
     def grads(params, stats, x):
         return jax.grad(lambda p, x: layer.apply(
             {"params": p, "batch_stats": stats}, x, train=False).astype(
                 jnp.float32).sum(), argnums=(0, 1))(params, x)
 
-    text = _compiled_text(grads, on_chip(shapes["params"]),
-                          on_chip(shapes["batch_stats"]),
-                          _sds((1, 8192, 2048), one_chip))
+    text = _compiled_text(grads, *args)
     assert "grouped_matmul_dw" in text and "conditional" in text
     # the bounded layout: 144 tiles of 128 rows, not the worst case's 528
+    assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
+
+
+def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
+    """The same layer under the block's remat (``nothing_saveable``), with a
+    consumer inside it that needs the layer's output as ``post_ffn_norm``
+    does: on the whole layout's side of the ``cond``s (the parts' side lies
+    under a ``while``) the step holds the forward's three grouped matmuls,
+    the recomputation's three, the backward's three ``dx`` and the down
+    projection again for the combine's weights, and three ``dw``; what
+    crosses a ``cond`` is in the compute dtype."""
+    import re
+    from collections import Counter
+
+    layer, *args = _held_experts_layer(one_chip)
+
+    def grads(params, stats, x):
+        block = jax.checkpoint(
+            lambda p, x: jnp.sin(layer.apply(
+                {"params": p, "batch_stats": stats}, x, train=False).astype(
+                    jnp.float32)),
+            prevent_cse=False,
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return jax.grad(lambda p, x: block(p, x).sum(), argnums=(0, 1))(
+            params, x)
+
+    text = _compiled_text(grads, *args)
+    found = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"%(grouped_matmul(?:_dw)?)[.\d]* = [^\n]*tpu_custom_call"
+        r"[^\n]*op_name=\"([^\"]*)\"", text) if "/while/" not in m.group(2)]
+    calls = Counter(name for name, _ in found)
+    assert 0 < calls["grouped_matmul"] <= 10, calls
+    assert calls["grouped_matmul_dw"] == 3, calls
+    # the backward's own: the down projection again and three dx (six where
+    # it differentiated the branch as a whole, the forward inside it)
+    backward = Counter(name for name, scope in found if "transpose(" in scope
+                       and "rematted_computation" not in scope)
+    assert backward == {"grouped_matmul": 4, "grouped_matmul_dw": 3}, backward
+    crossing = re.findall(r" = (\(.*?\)) conditional\(", text)
+    assert crossing and not any("f32[18432,1024]" in c for c in crossing)
+    assert any("bf16[18432,1024]" in c for c in crossing)   # gate and up
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
 
 
@@ -349,7 +396,7 @@ def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
 def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
     """The benchmark cell's step (``trinity_mini_share`` at 1 x 8192, bf16,
     per-block remat, AdamW) compiles for a described v5e with its arguments,
-    temporaries and unaliased outputs under the chip's memory: 15.79 GB of
+    temporaries and unaliased outputs under the chip's memory: 15.58 GB of
     the 16.9 the allocator offers (PERF.md has the chip's own reading)."""
     from pytorch_distributed_training_example_tpu.core import (
         train_loop, trainer as trainer_lib)
