@@ -1,0 +1,9 @@
+"""Per step on device 0: the device time of the operations under
+``moe_experts``: the held experts' grouped matmuls (the kernels
+``grouped_matmul`` and ``grouped_matmul_dw``) and the gate between them,
+forward, recomputation and backward."""
+from chipbench.layer_metrics import moe_experts_ms
+
+
+def read(trace, host, ctx):
+    return moe_experts_ms.read(trace, host, ctx)

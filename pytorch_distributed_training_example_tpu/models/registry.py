@@ -13,7 +13,13 @@ chip's share of it, ``granite_hybrid_tiny`` for tests; training only,
 ``dp``/``fsdp`` only) and the ``afmoe`` family (gated window and full
 attention over a sigmoid-routed mixture with a shared expert:
 ``trinity_mini`` at its published sizes, ``trinity_mini_share`` one chip's
-share of it, ``afmoe_tiny`` for tests; training only, ``dp``/``fsdp`` only).
+share of it, ``afmoe_tiny`` for tests; training only, ``dp``/``fsdp`` only)
+and the ``smallthinker`` family (every layer a mixture of ReLU-gated experts
+routed ahead of attention by a softmax over the chosen, under GQA that
+alternates a position-free full layer with window layers:
+``smallthinker_21b`` at its published sizes, ``smallthinker_21b_share`` one
+chip's share of it, ``smallthinker_tiny`` for tests; training only,
+``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -309,27 +315,30 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
     lambda g, **kw: g.granite_hybrid_tiny(**kw))
 
 
-def _afmoe(make):
-    """Registry builder for the ``afmoe`` family (models/afmoe.py):
-    ``make(afmoe, **kw)`` returns the module. ``dp``/``fsdp`` only, as the
-    Granite hybrid: the expert layer is told which experts it holds and has
-    no exchange, and there is no tensor-parallel rule table."""
+def _held_experts_family(name, make):
+    """Registry builder for a family whose expert layers are told which
+    experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``):
+    ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
+    Granite hybrid: the expert layer has no exchange, and there is no
+    tensor-parallel rule table."""
     def build(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
               sp=False, attn_impl="auto", logits_dtype, **_):
-        from pytorch_distributed_training_example_tpu.models import afmoe
+        import importlib
 
+        family = importlib.import_module(
+            f"pytorch_distributed_training_example_tpu.models.{name}")
         if sp:
             raise ValueError(
-                "the afmoe family has no tensor- or sequence-parallel "
+                f"the {name} family has no tensor- or sequence-parallel "
                 "rules; use strategy dp or fsdp")
-        module = make(afmoe, dtype=dtype, param_dtype=param_dtype,
+        module = make(family, dtype=dtype, param_dtype=param_dtype,
                       remat=remat, remat_policy=remat_policy,
                       attn_impl=attn_impl, logits_dtype=logits_dtype)
         return ModelBundle(
             module=module, task="lm",
             input_template=(jnp.zeros((2, seq_len), jnp.int32),),
             fwd_flops_per_example=seq_len
-            * afmoe.forward_flops_per_token(module, seq_len),
+            * family.forward_flops_per_token(module, seq_len),
             rules={}, examples_unit="sequences")
     return build
 
@@ -338,10 +347,22 @@ def _afmoe(make):
 # layer's routed experts and of the vocabulary, a leading dense layer and the
 # first period of expert layers: what the one-chip benchmark cell trains),
 # made from the first; and a toy for the tests.
-_REGISTRY["trinity_mini"] = _afmoe(lambda a, **kw: a.trinity_mini(**kw))
-_REGISTRY["trinity_mini_share"] = _afmoe(
-    lambda a, **kw: a.chip_share(a.trinity_mini(**kw)))
-_REGISTRY["afmoe_tiny"] = _afmoe(lambda a, **kw: a.afmoe_tiny(**kw))
+_REGISTRY["trinity_mini"] = _held_experts_family(
+    "afmoe", lambda a, **kw: a.trinity_mini(**kw))
+_REGISTRY["trinity_mini_share"] = _held_experts_family(
+    "afmoe", lambda a, **kw: a.chip_share(a.trinity_mini(**kw)))
+_REGISTRY["afmoe_tiny"] = _held_experts_family(
+    "afmoe", lambda a, **kw: a.afmoe_tiny(**kw))
+
+# The published SmallThinker-21BA3B; one chip's share of it (a quarter of
+# every layer's experts and of the vocabulary, the first period of four
+# layers: what the one-chip benchmark cell trains); and a toy for the tests.
+_REGISTRY["smallthinker_21b"] = _held_experts_family(
+    "smallthinker", lambda m, **kw: m.smallthinker_21b(**kw))
+_REGISTRY["smallthinker_21b_share"] = _held_experts_family(
+    "smallthinker", lambda m, **kw: m.chip_share(m.smallthinker_21b(**kw)))
+_REGISTRY["smallthinker_tiny"] = _held_experts_family(
+    "smallthinker", lambda m, **kw: m.smallthinker_tiny(**kw))
 
 
 @register("resnet_micro")

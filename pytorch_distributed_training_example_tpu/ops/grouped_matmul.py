@@ -319,43 +319,51 @@ def grouped_ffn(x, w_up, w_down, group_starts, group_counts):
     return out_pad[dst]
 
 
-def _gated(gate, up):
-    """``silu(gate) * up`` in float32, rounded once."""
-    return (jax.nn.silu(gate.astype(jnp.float32))
+#: The gate's function, by the name a caller gives as ``act``: what the two
+#: expert families state (SwiGLU and ReGLU experts), nothing more.
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(gate, up, act="silu"):
+    """``act(gate) * up`` in float32, rounded once."""
+    return (GATES[act](gate.astype(jnp.float32))
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
-def gated_down_padded(gate, up, w_down, tiles):
+def gated_down_padded(gate, up, w_down, tiles, act="silu"):
     """The gated product of the two projections through the down
     projection: ``gated_ffn_padded``'s result from its own ``gate`` and
     ``up``."""
-    return _gmm_padded(_gated(gate, up), w_down, tiles)
+    return _gmm_padded(_gated(gate, up, act), w_down, tiles)
 
 
-def gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down, tiles):
+def gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down, tiles, act="silu"):
     """``gated_ffn_padded`` with the two projections it gated beside it:
     ``(y_pad, gate, up)``, all in the compute dtype. With ``x_pad`` they are
     all that ``gated_ffn_padded_bwd`` reads of the forward."""
     gate = _gmm_padded(x_pad, w_gate, tiles)
     up = _gmm_padded(x_pad, w_up, tiles)
-    return gated_down_padded(gate, up, w_down, tiles), gate, up
+    return gated_down_padded(gate, up, w_down, tiles, act), gate, up
 
 
-def gated_ffn_padded(x_pad, w_gate, w_up, w_down, tiles):
+def gated_ffn_padded(x_pad, w_gate, w_up, w_down, tiles, act="silu"):
     """Gated grouped expert MLP over rows ALREADY in the padded layout:
-    ``(silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` a tile (SwiGLU
-    experts). The caller owns the relayout (``_padded_layout``'s ``src`` and
-    ``dst``): an expert layer that holds a part of the experts gathers token
-    rows straight into this layout and combines straight out of it. The gate
-    is multiplied in float32 and rounded once."""
-    return gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down, tiles)[0]
+    ``(act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` a tile, ``act`` a
+    key of ``GATES`` and static (``"silu"``: SwiGLU experts; ``"relu"``:
+    ReGLU experts). The caller owns the relayout (``_padded_layout``'s
+    ``src`` and ``dst``): an expert layer that holds a part of the experts
+    gathers token rows straight into this layout and combines straight out
+    of it. The gate is multiplied in float32 and rounded once."""
+    return gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down, tiles, act)[0]
 
 
-def gated_ffn_padded_bwd(x_pad, gate, up, w_gate, w_up, w_down, tiles, dy_pad):
+def gated_ffn_padded_bwd(x_pad, gate, up, w_gate, w_up, w_down, tiles, dy_pad,
+                         act="silu"):
     """What differentiating ``gated_ffn_padded`` gives for ``dy_pad``, from
     the forward's own ``gate`` and ``up``: ``(dx_pad, dw_gate, dw_up,
-    dw_down)``, each product as ``_gmm_padded``'s rule makes it."""
-    h_pad, gated_vjp = jax.vjp(_gated, gate, up)
+    dw_down)``, each product as ``_gmm_padded``'s rule makes it and the
+    gate's derivative as AD makes it for ``act`` (ReLU's is 0 at 0)."""
+    h_pad, gated_vjp = jax.vjp(functools.partial(_gated, act=act), gate, up)
     dh_pad, dw_down, _ = _gmm_padded_bwd((h_pad, w_down, tiles), dy_pad)
     dgate, dup = gated_vjp(dh_pad)
     dx_gate, dw_gate, _ = _gmm_padded_bwd((x_pad, w_gate, tiles), dgate)

@@ -49,6 +49,17 @@ replicated path (same rows, same weights, same single-dot full-``d``
 contraction per row; tested in tests/test_moe_dropless.py). This is what
 makes E ≫ devices representable: per-device expert memory is ``E/ep``
 weight blocks instead of all ``E``.
+
+Beside ``MoEBlock``, the expert layers of models that are told which experts
+this chip holds (the second half of this file) are two halves that a block
+calls where its model says: *a router* (scores to the chosen experts, their
+weights and every expert's count: ``route_sigmoid_bias`` for the ``afmoe``
+family, :class:`TopKSoftmaxRouter` for ``smallthinker``) and *the held
+experts' routine* (``_held_sum`` over ``(tokens, chosen, weights, counts)``:
+``_routed`` / ``_routed_bounded`` through ``ops/grouped_matmul.py``'s gated
+FFN, with the telemetry). :class:`SharedExpertMoE` is both in one module with
+a shared expert beside them; :class:`HeldExperts` is the routine alone, for a
+block that routes on another tensor than the experts read.
 """
 
 from __future__ import annotations
@@ -861,15 +872,17 @@ class MoEBlock(nn.Module):
 
 
 # ---------------------------------------------------------------------------
-# The expert layer of sigmoid-routed models with a shared expert (afmoe,
-# models/afmoe.py): gated experts, a bias that only chooses, and a layer that
-# is told which experts it holds.
+# Expert layers that are told which experts they hold: a router, and the
+# held experts' routine over its plan. Together with a shared expert and a
+# bias that only chooses (afmoe, models/afmoe.py: SharedExpertMoE), or apart
+# (smallthinker, models/smallthinker.py: TopKSoftmaxRouter ahead of
+# attention, HeldExperts after it).
 # ---------------------------------------------------------------------------
 
 
 #: Row tile of the held experts' grouped matmuls: an expert sees T*k/E rows
-#: on average (512 at 8,192 tokens and 8 of 128), and a taller tile pads
-#: more of them.
+#: on average (512 at 8,192 tokens and 8 of 128, 768 at 6 of 64), and a
+#: taller tile pads more of them.
 EXPERT_TILE_ROWS = 128
 
 
@@ -949,11 +962,35 @@ def _held_counts(key, held):
     return jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
 
 
+def _plan(chosen, first, held, bt, max_tiles, counts):
+    """The integer plan ``(tiles, pair_row, row_pair)`` of the held experts'
+    padded layout for ``chosen [n, k]``: the (token, choice) pairs sorted by
+    held expert, the pairs that chose another chip's expert behind them all;
+    integers only. Both directions of every move of rows are gathers."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    n, k = chosen.shape
+    key = _held_keys(chosen, first, held)                           # [n*k]
+    pairs = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = jax.lax.sort((key, pairs), num_keys=1)
+    _, rank = jax.lax.sort((order, pairs), num_keys=1)
+    if counts is None:
+        counts = _held_counts(key, held)
+    starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+    tiles, src, dst = gmm_lib._padded_layout(
+        starts, counts, n * k, held, bt, max_tiles)
+    row_pair = _rows(order, src) + (src >= n * k) * (n * k)         # [P]
+    pair_row = dst[rank].reshape(n, k)
+    return tiles, pair_row, row_pair
+
+
 def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
-                 counts=None):
+                 counts=None, act="silu"):
     """The held experts' part of the layer's sum for these tokens, ``[n, d]``
     float32, and what a backward reads of this forward beside its inputs:
-    ``experts = (w_gate, w_up, w_down)`` are the experts ``first`` onwards,
+    ``experts = (w_gate, w_up, w_down)`` are the experts ``first`` onwards
+    (``act`` their gate's function, a key of ``grouped_matmul.GATES``),
     ``chosen [n, k]`` indexes all the router's experts, the padded layout
     takes at most ``max_tiles`` tiles of ``bt`` rows, and ``counts`` are the
     held experts' rows where the caller has them (the router counts all the
@@ -962,45 +999,33 @@ def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
     ``x_pad`` is one gather of rows from the plan and ``y_pad`` one grouped
     matmul from ``gate`` and ``up``, and whatever is kept here lives from the
     forward to the backward in the step XLA schedules (151 MB more a layer
-    at the published widths, were both kept)."""
+    at Trinity's published widths, were both kept)."""
     from pytorch_distributed_training_example_tpu.ops import (
         grouped_matmul as gmm_lib)
 
-    (n, k), held = chosen.shape, experts[0].shape[0]
+    k, held = chosen.shape[1], experts[0].shape[0]
     with jax.named_scope("moe_dispatch"):
-        # Sort the (token, choice) pairs by held expert, the pairs that chose
-        # another chip's expert behind them all; integers only. Both
-        # directions of every move of rows are gathers.
-        key = _held_keys(chosen, first, held)                       # [n*k]
-        pairs = jnp.arange(n * k, dtype=jnp.int32)
-        _, order = jax.lax.sort((key, pairs), num_keys=1)
-        _, rank = jax.lax.sort((order, pairs), num_keys=1)
-        if counts is None:
-            counts = _held_counts(key, held)
-        starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-        tiles, src, dst = gmm_lib._padded_layout(
-            starts, counts, n * k, held, bt, max_tiles)
-        row_pair = _rows(order, src) + (src >= n * k) * (n * k)     # [P]
-        pair_row = dst[rank].reshape(n, k)
+        tiles, pair_row, row_pair = _plan(chosen, first, held, bt, max_tiles,
+                                          counts)
         x_pad = _dispatch_rows(tokens, row_pair // k, pair_row)
     with jax.named_scope("moe_experts"):
         y_pad, gate, up = mesh_lib.manual_call(
-            gmm_lib.gated_ffn_padded_kept, x_pad, *experts, tiles,
-            in_specs=P(), out_specs=P())
+            functools.partial(gmm_lib.gated_ffn_padded_kept, act=act),
+            x_pad, *experts, tiles, in_specs=P(), out_specs=P())
     with jax.named_scope("moe_combine"):
         out = _combine_rows(y_pad, weights, pair_row, row_pair)
     return out, ((tiles, pair_row, row_pair), (gate, up))
 
 
 def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None,
-            counts=None):
+            counts=None, act="silu"):
     """``_routed_kept``'s sum alone: under plain AD where every expert is
     held (no ``cond``), and the routine that the bounded layout repeats."""
     return _routed_kept(tokens, chosen, weights, experts, first, bt,
-                        max_tiles, counts)[0]
+                        max_tiles, counts, act)[0]
 
 
-def _routed_kept_bwd(kept, tokens, weights, experts, d_out):
+def _routed_kept_bwd(kept, tokens, weights, experts, d_out, act="silu"):
     """``(d_tokens, d_weights, d_experts)`` of ``_routed_kept``'s sum from
     what it kept: the transposes that plain AD of ``_routed`` strings
     together, in its order, on the forward's own ``gate`` and ``up``; of the
@@ -1014,15 +1039,15 @@ def _routed_kept_bwd(kept, tokens, weights, experts, d_out):
         x_pad = _rows(tokens, row_token)
     with jax.named_scope("moe_experts"):
         y_pad = mesh_lib.manual_call(
-            gmm_lib.gated_down_padded, gate, up, experts[2], tiles,
-            in_specs=P(), out_specs=P())
+            functools.partial(gmm_lib.gated_down_padded, act=act), gate, up,
+            experts[2], tiles, in_specs=P(), out_specs=P())
     with jax.named_scope("moe_combine"):
         dy_pad, d_weights = _combine_bwd(
             (y_pad, weights, pair_row, row_pair), d_out)[:2]
     with jax.named_scope("moe_experts"):
         dx_pad, *d_experts = mesh_lib.manual_call(
-            gmm_lib.gated_ffn_padded_bwd, x_pad, gate, up, *experts, tiles,
-            dy_pad, in_specs=P(), out_specs=P())
+            functools.partial(gmm_lib.gated_ffn_padded_bwd, act=act), x_pad,
+            gate, up, *experts, tiles, dy_pad, in_specs=P(), out_specs=P())
     with jax.named_scope("moe_dispatch"):
         d_tokens = _dispatch_bwd((row_token, pair_row), dx_pad)[0]
     return d_tokens, d_weights, tuple(d_experts)
@@ -1043,20 +1068,20 @@ def _fits(counts, bt, cap):
     return gmm_lib.num_tiles(counts, bt) <= cap
 
 
-def _in_parts(tokens, chosen, weights, experts, first, bt, chunks, cap):
+def _in_parts(tokens, chosen, weights, experts, first, bt, chunks, cap, act):
     """``_routed`` over ``chunks`` parts of the tokens one after another,
     each counting its own rows; a part's residuals are its inputs."""
     n = chosen.shape[0]
     split = lambda a: a.reshape(chunks, n // chunks, *a.shape[1:])
-    one = lambda a: _routed(*a, experts, first, bt, cap)
+    one = lambda a: _routed(*a, experts, first, bt, cap, act=act)
     return jax.lax.map(jax.checkpoint(one), (
         split(tokens), split(chosen), split(weights))).reshape(
             n, tokens.shape[1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _routed_bounded(tokens, chosen, weights, experts, counts, first, bt,
-                    chunks):
+                    chunks, act="silu"):
     """``_routed`` in a layout of bounded size, whatever the router does.
 
     The layout's static size is its worst case: every choice of every token
@@ -1082,38 +1107,39 @@ def _routed_bounded(tokens, chosen, weights, experts, counts, first, bt,
     return jax.lax.cond(
         _fits(counts, bt, cap),
         lambda: _routed(tokens, chosen, weights, experts, first, bt, cap,
-                        counts),
+                        counts, act),
         lambda: _in_parts(tokens, chosen, weights, experts, first, bt, chunks,
-                          cap))
+                          cap, act))
 
 
 def _routed_bounded_fwd(tokens, chosen, weights, experts, counts, first, bt,
-                        chunks):
+                        chunks, act):
     cap = _bounded_tiles(chosen, experts, bt, chunks)
     whole = lambda: _routed_kept(tokens, chosen, weights, experts, first, bt,
-                                 cap, counts)
+                                 cap, counts, act)
     kept = jax.eval_shape(whole)[1]
     out, kept = jax.lax.cond(
         _fits(counts, bt, cap), whole,
         lambda: (_in_parts(tokens, chosen, weights, experts, first, bt,
-                           chunks, cap),
+                           chunks, cap, act),
                  jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), kept)))
     return out, (tokens, chosen, weights, experts, counts, kept)
 
 
-def _routed_bounded_bwd(first, bt, chunks, res, d_out):
+def _routed_bounded_bwd(first, bt, chunks, act, res, d_out):
     tokens, chosen, weights, experts, counts, kept = res
     cap = _bounded_tiles(chosen, experts, bt, chunks)
 
     def parts():
         _, vjp = jax.vjp(
             lambda *a: _in_parts(a[0], chosen, *a[1:], first, bt, chunks,
-                                 cap), tokens, weights, experts)
+                                 cap, act), tokens, weights, experts)
         return vjp(d_out)
 
     d_tokens, d_weights, d_experts = jax.lax.cond(
         _fits(counts, bt, cap),
-        lambda: _routed_kept_bwd(kept, tokens, weights, experts, d_out), parts)
+        lambda: _routed_kept_bwd(kept, tokens, weights, experts, d_out, act),
+        parts)
     return (d_tokens, _int_zeros(chosen), d_weights, d_experts,
             _int_zeros(counts))
 
@@ -1121,36 +1147,146 @@ def _routed_bounded_bwd(first, bt, chunks, res, d_out):
 _routed_bounded.defvjp(_routed_bounded_fwd, _routed_bounded_bwd)
 
 
+class Route(NamedTuple):
+    """A router's plan for ``T`` tokens: what the held experts' routine
+    reads."""
+    chosen: jax.Array    # [T, k] int32, over all the router's experts
+    weights: jax.Array   # [T, k] float32
+    load: jax.Array      # [E] the (token, choice) pairs that chose each
+
+
+def _scores(tokens, kernel):
+    """``tokens @ kernel`` as a true float32 product: two scores that nearly
+    tie must come out in the order the published float32 router gives."""
+    return jnp.dot(tokens.astype(jnp.float32), kernel,
+                   precision=jax.lax.Precision.HIGHEST)            # [T, E]
+
+
+def _load(chosen, num_experts):
+    load = jnp.bincount(chosen.reshape(-1), length=num_experts)    # [E]
+    return mesh_lib.constrain(load, P(None))
+
+
+def route_sigmoid_bias(tokens, kernel, bias, k, route_scale) -> Route:
+    """``s = sigmoid(tokens W_r)``; the ``k`` largest of ``s + bias`` are
+    chosen; weights ``route_scale * s_i / (sum of the chosen s + 1e-20)``:
+    the bias chooses and nothing more (torchtitan's router as the ``afmoe``
+    models configure it)."""
+    scores = jax.nn.sigmoid(_scores(tokens, kernel))
+    _, chosen = jax.lax.top_k(scores + bias, k)                     # [T, k]
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = route_scale * picked / (
+        jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return Route(chosen, weights, _load(chosen, kernel.shape[1]))
+
+
+def route_softmax_chosen(tokens, kernel, k) -> Route:
+    """The ``k`` largest logits of ``tokens W_r`` are chosen; weights: the
+    softmax over the chosen logits alone, then divided by their sum (the
+    identity but for rounding; the published ``norm_topk_prob``). All
+    float32 (the ``smallthinker`` models' primary router)."""
+    top, chosen = jax.lax.top_k(_scores(tokens, kernel), k)         # [T, k]
+    weights = jax.nn.softmax(top, axis=-1)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return Route(chosen, weights, _load(chosen, kernel.shape[1]))
+
+
+class Held(NamedTuple):
+    """What ``_held_sum`` made on its way, for its callers' telemetry."""
+    experts: tuple       # (w_gate, w_up, w_down) in the compute dtype
+    tokens: jax.Array    # [T, d] in the compute dtype
+    first: int           # the first held expert
+    load: jax.Array      # [held] int32: the held experts' rows
+    whole: jax.Array     # 1.0 where the rows went through the layout whole
+    bt: int              # the layout's tile rows
+    cap: int | None      # the bounded layout's tiles (None: unbounded)
+
+
+def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act):
+    """The held experts' routine inside ``module`` (which gets the stacked
+    ``w_gate``, ``w_up``, ``w_down``): the part of ``sum_c weights[t, c] *
+    Expert_chosen[t, c](tokens[t])`` that the experts ``held_experts = (how
+    many, starting where)`` give, ``[T, d]`` float32, dropless whatever the
+    imbalance; and a :class:`Held`.
+
+    Where under half of the experts are held the rows go through
+    ``_routed_bounded``: one ``cond`` on the router's own counts of the held
+    experts' rows, which in the forward that a backward follows hands out
+    the integer plan and the experts' ``gate`` and ``up`` projections in the
+    compute dtype, so that the backward runs no routed forward again; no
+    float32 intermediate and no copy of a weight crosses it. Where all are
+    held (``chunks == 1``) ``_routed`` runs under plain AD."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    (T, d), (_, k), E = tokens.shape, route.chosen.shape, route.load.shape[0]
+    held, first = held_experts or (E, 0)
+    if not (0 < held and 0 <= first and first + held <= E):
+        raise ValueError(f"held_experts={held_experts} of {E}")
+    stacked = lambda name, shape: module.param(
+        name, nn.initializers.lecun_normal(), (held, *shape),
+        module.param_dtype).astype(module.dtype)
+    experts = (stacked("w_gate", (d, ffn_dim)),
+               stacked("w_up", (d, ffn_dim)),
+               stacked("w_down", (ffn_dim, d)))
+    bt = min(EXPERT_TILE_ROWS, gmm_lib._block_rows(T * k, held))
+    held_load = route.load[first:first + held].astype(jnp.int32)
+
+    # rows in a layout of bounded size: see ``_routed_bounded``
+    tokens = tokens.astype(module.dtype)
+    chunks = max(1, E // (2 * held))
+    if chunks == 1 or T % chunks:
+        cap = None
+        out = _routed(tokens, route.chosen, route.weights, experts, first, bt,
+                      act=act)
+        whole = jnp.ones((), jnp.float32)
+    else:
+        cap = _bounded_tiles(route.chosen, experts, bt, chunks)
+        out = _routed_bounded(tokens, route.chosen, route.weights, experts,
+                              held_load, first, bt, chunks, act)
+        whole = _fits(held_load, bt, cap).astype(jnp.float32)
+    return out, Held(experts, tokens, first, held_load, whole, bt, cap)
+
+
+def _sow_telemetry(module, **values):
+    """Sow each value into ``telemetry`` (fetched at the log cadence) under
+    its name with the enclosing block's name behind a dot."""
+    layer = "." + module.path[-2] if len(module.path) > 1 else ""
+    for name, value in values.items():
+        module.sow("telemetry", name + layer, value)
+
+
+def _held_peak(rows):
+    """The fullest held expert's rows over their mean."""
+    return jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0)
+
+
 class SharedExpertMoE(nn.Module):
-    """Sigmoid-routed experts beside a shared expert, on the chip that holds
-    ``held_experts`` of them (torchtitan's MoE as the ``afmoe`` models
-    configure it; the equations are in ``models/afmoe.py``).
+    """A sigmoid-and-bias router and the held experts' routine beside a
+    shared expert, on the chip that holds ``held_experts`` of the experts
+    (torchtitan's MoE as the ``afmoe`` models configure it; the equations
+    are in ``models/afmoe.py``).
 
     ``s = sigmoid(x W_r)`` over all ``num_experts``, in float32; the ``top_k``
     largest of ``s + b`` are chosen (``b``, ``expert_bias``, is a buffer in
     the ``batch_stats`` collection: no gradient, no optimizer state); the
     weights are ``route_scale * s_i / (sum of the chosen s + 1e-20)``: the
-    bias chooses and nothing more. ``y = Shared(x) + sum_i w_i Expert_i(x)``
-    with SwiGLU experts. After a training step ``b += d - mean(d)``, ``d =
-    balance_coeff * sign(mean(c) - c)``, ``c`` the tokens of this call that
-    chose each expert (all ``num_experts``, this chip's tokens).
+    bias chooses and nothing more (``route_sigmoid_bias``). ``y = Shared(x)
+    + sum_i w_i Expert_i(x)`` with SwiGLU experts. After a training step ``b
+    += d - mean(d)``, ``d = balance_coeff * sign(mean(c) - c)``, ``c`` the
+    tokens of this call that chose each expert (all ``num_experts``, this
+    chip's tokens).
 
     ``held_experts = (how many, starting where)``: the layer routes over all
     the experts and computes the part of the sum that its own give, for the
-    tokens that chose them; what the others would add is left out, and is the
-    business of the chips that hold them (their results would be summed over
-    the ``expert`` axis; on one chip the layer runs without that exchange).
-    Dropless: every (token, choice) that lands here is computed, whatever the
-    imbalance. The rows are gathered straight into the grouped matmul's tile
-    layout (``ops/grouped_matmul.py``), whose kernels run only the tiles in
-    use: a row that chose no held expert costs no matmul tile.
-
-    Where it holds under half of the experts the rows go through
-    ``_routed_bounded``: one ``cond`` on the router's own counts of the held
-    experts' rows, which in the forward that a backward follows hands out
-    the integer plan and the experts' ``gate`` and ``up`` projections in the
-    compute dtype, so that the backward runs no routed forward again; no
-    float32 intermediate and no copy of a weight crosses it.
+    tokens that chose them (``_held_sum``); what the others would add is left
+    out, and is the business of the chips that hold them (their results
+    would be summed over the ``expert`` axis; on one chip the layer runs
+    without that exchange). Dropless: every (token, choice) that lands here
+    is computed, whatever the imbalance. The rows are gathered straight into
+    the grouped matmul's tile layout (``ops/grouped_matmul.py``), whose
+    kernels run only the tiles in use: a row that chose no held expert costs
+    no matmul tile.
 
     Sows into ``telemetry`` (fetched at the log cadence): ``moe_held_rows``
     (the rows that landed on held experts), ``moe_held_peak`` (the fullest
@@ -1175,73 +1311,112 @@ class SharedExpertMoE(nn.Module):
         """``x [B, S, d]`` in any float dtype: the router reads it as it
         comes (float32 from a caller that keeps it so), the experts in the
         compute dtype."""
-        from pytorch_distributed_training_example_tpu.ops import (
-            grouped_matmul as gmm_lib)
-
         B, S, d = x.shape
-        E, k, T = self.num_experts, self.top_k, B * S
-        held, first = self.held_experts or (E, 0)
-        if not (0 < held and 0 <= first and first + held <= E):
-            raise ValueError(f"held_experts={self.held_experts} of {E}")
-        tokens = x.reshape(T, d)
+        E = self.num_experts
+        tokens = x.reshape(B * S, d)
         bias = self.variable("batch_stats", "expert_bias",
                              lambda: jnp.zeros((E,), jnp.float32))
 
         with jax.named_scope("moe_router"):
-            # a true float32 product: two scores that nearly tie must come
-            # out in the order the published float32 router gives them
             kernel = self.param("router", nn.initializers.lecun_normal(),
                                 (d, E), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.dot(
-                tokens.astype(jnp.float32), kernel,
-                precision=jax.lax.Precision.HIGHEST))             # [T, E]
-            _, chosen = jax.lax.top_k(scores + bias.value, k)       # [T, k]
-            picked = jnp.take_along_axis(scores, chosen, axis=-1)
-            weights = self.route_scale * picked / (
-                jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
-            load = jnp.bincount(chosen.reshape(-1), length=E)       # [E]
-            load = mesh_lib.constrain(load, P(None))
+            route = route_sigmoid_bias(tokens, kernel, bias.value, self.top_k,
+                                       self.route_scale)
             if train and not self.is_initializing() \
                     and self.is_mutable_collection("batch_stats"):
-                mean = jnp.mean(load.astype(jnp.float32))
-                delta = self.balance_coeff * jnp.sign(mean - load)
+                mean = jnp.mean(route.load.astype(jnp.float32))
+                delta = self.balance_coeff * jnp.sign(mean - route.load)
                 bias.value = bias.value + delta - jnp.mean(delta)
 
-        stacked = lambda name, shape: self.param(
-            name, nn.initializers.lecun_normal(), (held, *shape),
-            self.param_dtype).astype(self.dtype)
-        experts = (stacked("w_gate", (d, self.ffn_dim)),
-                   stacked("w_up", (d, self.ffn_dim)),
-                   stacked("w_down", (self.ffn_dim, d)))
-        bt = min(EXPERT_TILE_ROWS, gmm_lib._block_rows(T * k, held))
-        held_load = load[first:first + held].astype(jnp.int32)
-
-        # rows in a layout of bounded size: see ``_routed_bounded``
-        tokens = tokens.astype(self.dtype)
-        chunks = max(1, E // (2 * held))
-        if chunks == 1 or T % chunks:
-            out = _routed(tokens, chosen, weights, experts, first, bt)
-            whole = jnp.ones((), jnp.float32)
-        else:
-            out = _routed_bounded(tokens, chosen, weights, experts, held_load,
-                                  first, bt, chunks)
-            whole = _fits(held_load, bt, _bounded_tiles(
-                chosen, experts, bt, chunks)).astype(jnp.float32)
+        out, held = _held_sum(self, tokens, route, self.ffn_dim,
+                              self.held_experts, "silu")
         if self.shared_ffn_dim:
             with jax.named_scope("moe_shared"):
                 out = out + SwiGLU(self.shared_ffn_dim, self.dtype,
                                    self.param_dtype, name="shared")(
-                    tokens).astype(jnp.float32)
+                    held.tokens).astype(jnp.float32)
 
-        layer = "." + self.path[-2] if len(self.path) > 1 else ""
-        rows = held_load.astype(jnp.float32)
-        self.sow("telemetry", "moe_held_rows" + layer, jnp.sum(rows))
-        self.sow("telemetry", "moe_held_peak" + layer,
-                 jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0))
-        self.sow("telemetry", "moe_bias_peak" + layer,
-                 jnp.max(jnp.abs(bias.value)))
-        self.sow("telemetry", "moe_whole" + layer, whole)
+        rows = held.load.astype(jnp.float32)
+        _sow_telemetry(self, moe_held_rows=jnp.sum(rows),
+                       moe_held_peak=_held_peak(rows),
+                       moe_bias_peak=jnp.max(jnp.abs(bias.value)),
+                       moe_whole=held.whole)
         return out.reshape(B, S, d).astype(self.dtype)
+
+
+class TopKSoftmaxRouter(nn.Module):
+    """The router alone, for a block that routes on another tensor than its
+    experts read (the ``smallthinker`` models route on the attention's input,
+    ahead of attention): ``route_softmax_chosen`` over ``x [B, S, d]``
+    flattened to tokens, float32 throughout on a float32 input. One
+    parameter, ``kernel [d, num_experts]`` float32. Give it the name
+    ``moe_router``: the module's name is its scope in the step program."""
+    num_experts: int
+    top_k: int
+
+    @nn.compact
+    def __call__(self, x) -> Route:
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.num_experts), jnp.float32)
+        return route_softmax_chosen(x.reshape(-1, x.shape[-1]), kernel,
+                                    self.top_k)
+
+
+class HeldExperts(nn.Module):
+    """The held experts' routine alone (``_held_sum``), over a plan that a
+    router made elsewhere: ``y[t] = sum_c weights[t, c] * Expert_chosen[t, c]
+    (x[t])`` over the choices that fell on the experts held here, ``Expert(x)
+    = (act(x W_gate) * x W_up) W_down``. No shared expert, no bias, no
+    buffer: a token none of whose choices is held gets exactly zero.
+
+    Sows into ``telemetry`` as :class:`SharedExpertMoE` does:
+    ``moe_held_rows``, ``moe_held_peak``, ``moe_whole``, and
+    ``moe_gate_zero`` (the share of the held rows' gate activations that
+    ``relu`` zeroes: what a kernel that skipped them would have to gain
+    from; NaN where the rows did not fit the layout whole). The last costs
+    the plan and the gate projection once more, and is computed only in a
+    run that collects ``telemetry``."""
+    ffn_dim: int
+    held_experts: tuple | None = None   # (how many, starting where)
+    act: str = "relu"                   # a key of ops.grouped_matmul.GATES
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, route: Route):
+        out, held = _held_sum(self, x.reshape(-1, x.shape[-1]), route,
+                              self.ffn_dim, self.held_experts, self.act)
+        rows = held.load.astype(jnp.float32)
+        sown = dict(moe_held_rows=jnp.sum(rows),
+                    moe_held_peak=_held_peak(rows), moe_whole=held.whole)
+        if self.is_mutable_collection("telemetry"):
+            sown["moe_gate_zero"] = _gate_zero_share(route.chosen, held)
+        _sow_telemetry(self, **sown)
+        return out.reshape(x.shape).astype(self.dtype)
+
+
+def _gate_zero_share(chosen, held: Held):
+    """The share of the held rows' gate pre-activations ``x W_gate`` that are
+    not positive (``relu`` zeroes them). Telemetry only: the plan and the
+    gate projection once more, outside the routine and its ``cond``. NaN
+    where the rows do not fit the bounded layout whole."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    tokens, w_gate = jax.lax.stop_gradient((held.tokens, held.experts[0]))
+    k = chosen.shape[1]
+    tiles, _, row_pair = _plan(chosen, held.first, w_gate.shape[0], held.bt,
+                               held.cap, held.load)
+    gate = mesh_lib.manual_call(
+        gmm_lib._gmm_padded, _rows(tokens, row_pair // k), w_gate, tiles,
+        in_specs=P(), out_specs=P())
+    # padding rows are zero rows and give zeros; the tiles past the last one
+    # in use are never written
+    live = jnp.arange(gate.shape[0]) // held.bt < tiles[2][0]
+    positive = jnp.sum((gate > 0) & live[:, None])
+    share = 1.0 - positive / jnp.maximum(
+        jnp.sum(held.load) * w_gate.shape[2], 1)
+    return jnp.where(held.whole > 0, share, jnp.nan)
 
 
 class SwiGLU(nn.Module):

@@ -267,6 +267,22 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # SmallThinker-21BA3B (models/smallthinker.py) whole, and one chip's
+    # share of it (a quarter of each layer's 64 experts and of the
+    # vocabulary, the first period of four layers): every layer an expert
+    # layer routed ahead of attention, one 8k sequence a chip per micro-step
+    "smallthinker_21b": dict(
+        model="smallthinker_21b", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=4, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "smallthinker_21b_share": dict(
+        model="smallthinker_21b_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

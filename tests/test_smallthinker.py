@@ -1,0 +1,515 @@
+"""The ``smallthinker`` family (``models/smallthinker.py``,
+``parallel/moe.TopKSoftmaxRouter`` and ``HeldExperts``, the gate of
+``ops/grouped_matmul.py``'s gated FFN as an argument): the model against the
+benchmark's plain reference, the router on planted near-ties, rotary positions
+and the window on the layers the layouts name and on no others, the shares
+adding up to the uncut layer, zero for a token with no held choice, collapsed
+routing through the bounded layout's parts, the hand-written backward against
+plain AD for both gates, the published entry and the chip's share of it, and
+the preset through the ``Trainer``. Float32 on the CPU at toy widths."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from chipbench import weights  # noqa: E402
+from chipbench.references import smallthinker_21b as reference  # noqa: E402
+from pytorch_distributed_training_example_tpu.core import (  # noqa: E402
+    mesh as mesh_lib, train_loop)
+from pytorch_distributed_training_example_tpu.core.trainer import Trainer  # noqa: E402
+from pytorch_distributed_training_example_tpu.models import (  # noqa: E402
+    registry, smallthinker)
+from pytorch_distributed_training_example_tpu.ops import (  # noqa: E402
+    grouped_matmul as gmm_lib)
+from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib  # noqa: E402
+from pytorch_distributed_training_example_tpu.utils.config import from_preset  # noqa: E402
+
+HIGHEST = jax.default_matmul_precision("highest")
+RULES = [["scale$", "const", 1.0], [".*", "normal", 0.02]]
+
+
+def _model_dict(module: smallthinker.SmallThinker) -> dict:
+    """The reference's ``model`` group for a program module."""
+    held, first = module.held_experts or (module.num_experts, 0)
+    return {
+        "hidden_size": module.d_model, "head_dim": module.head_dim,
+        "num_attention_heads": module.num_heads,
+        "num_key_value_heads": module.num_kv_heads,
+        "moe_ffn_hidden_size": module.expert_ffn_dim,
+        "moe_num_primary_experts": held, "held_experts_start": first,
+        "routed_experts": module.num_experts,
+        "moe_num_active_primary_experts": module.top_k,
+        "num_hidden_layers": module.num_layers,
+        "sliding_window_layout": list(module.window_layout),
+        "rope_layout": list(module.rope_layout),
+        "held_layers": list(range(module.num_layers)),
+        "sliding_window_size": module.window, "rope_theta": module.rope_theta,
+        "rms_norm_eps": module.epsilon, "vocab_size": module.vocab_size}
+
+
+def _seeded(module, S, seed=3, batch=2):
+    tokens = jax.random.randint(jax.random.key(seed), (batch, S + 1), 0,
+                                module.vocab_size)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), tokens[:, :-1]))
+    assert set(shapes) == {"params", "telemetry"}    # no buffer: no batch_stats
+    params = weights.make_like(shapes["params"], RULES, weights.seed_key(seed))
+    return params, {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+
+
+def _program_loss(module, batch):
+    task = train_loop.get_task("lm")
+    return lambda p: task.loss(
+        module.apply({"params": p}, batch["tokens"], train=True), batch)
+
+
+# -- the model against the plain reference ---------------------------------------
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["whole", "share"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_model_matches_the_plain_reference(held, remat):
+    """Loss and every leaf's gradient, in float32, at a sequence of three
+    windows. The tolerances are float32 rounding through four layers (the
+    Trinity test's, which the same kind of arithmetic met): 1e-5 on the loss,
+    2e-3 of an entry and 2e-4 of a leaf's largest entry on a gradient."""
+    module = smallthinker.smallthinker_tiny(remat=remat, held_experts=held)
+    params, batch = _seeded(module, 48)
+    model = _model_dict(module)
+    with HIGHEST:
+        loss, grads = jax.jit(jax.value_and_grad(
+            _program_loss(module, batch)))(params)
+        (want_loss, counts), want = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss_fn(p, batch, model), has_aux=True))(
+                weights.flatten(params))
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    grads = weights.flatten(grads)
+    assert set(grads) == set(want)
+    for path, g in grads.items():
+        scale = float(jnp.max(jnp.abs(want[path])))
+        assert scale > 0, path  # every leaf is alive at this init
+        np.testing.assert_allclose(g, want[path], rtol=2e-3,
+                                   atol=2e-4 * scale, err_msg=path)
+    assert float(jnp.sum(counts)) == 4 * 2 * 48 * module.top_k
+
+
+@pytest.mark.parametrize("left_out,change", [
+    ("rotary positions on the window layers", {"rope_layout": [0, 0, 0, 0]}),
+    ("the window", {"sliding_window_size": 10 ** 6}),
+    ("a position-free full layer (rotary there too)",
+     {"rope_layout": [1, 1, 1, 1]}),
+    ("a full first layer (a window there too)",
+     {"sliding_window_layout": [1, 1, 1, 1]}),
+    ("the router ahead of attention (another layer's router)",
+     "swap_routers"),
+    ("the softmax over the chosen (uniform weights)", "uniform")])
+def test_reference_sees_what_a_step_leaves_out(left_out, change, monkeypatch):
+    """Rotary positions and the window mask are on the layers the layouts
+    name and on no others, and the router is where the model says: the
+    reference with the piece moved or left out is farther from the program
+    than the tolerance of the test above, on the loss or on a gradient."""
+    module = smallthinker.smallthinker_tiny()
+    params, batch = _seeded(module, 48)
+    model, flat = _model_dict(module), weights.flatten(params)
+    if change == "swap_routers":
+        flat = dict(flat, **{
+            "block_0/moe_router/kernel": flat["block_1/moe_router/kernel"],
+            "block_1/moe_router/kernel": flat["block_0/moe_router/kernel"]})
+    elif change == "uniform":
+        real = reference.route
+        monkeypatch.setattr(reference, "route", lambda r, kernel, k: (
+            real(r, kernel, k)[0], jnp.full(r.shape[:-1] + (k,), 1.0 / k)))
+    else:
+        model = dict(model, **change)
+    with HIGHEST:
+        loss, grads = jax.jit(jax.value_and_grad(
+            _program_loss(module, batch)))(params)
+        (want_loss, _), want = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss_fn(p, batch, model), has_aux=True))(flat)
+    grads = weights.flatten(grads)
+    if change == "swap_routers":    # compare like with like
+        want = dict(want, **{
+            "block_0/moe_router/kernel": want["block_1/moe_router/kernel"],
+            "block_1/moe_router/kernel": want["block_0/moe_router/kernel"]})
+    worst = max(float(jnp.max(jnp.abs(g - want[path]))
+                      / jnp.max(jnp.abs(want[path])))
+                for path, g in grads.items())
+    assert abs(float(loss) - float(want_loss)) > 1e-4 * float(want_loss) \
+        or worst > 2e-2, (left_out, float(loss), float(want_loss), worst)
+
+
+# -- the router ------------------------------------------------------------------
+
+
+def test_router_on_planted_near_ties_is_the_references():
+    """The top-k of the logits and the softmax over the chosen, float32: on
+    inputs where two experts' logits differ by a few parts in 1e7 for every
+    token (a column of the kernel all but copied), so that the choice at the
+    boundary is a near-tie for a part of the tokens, the module chooses what
+    the reference chooses and weighs as it weighs; the weights are the
+    softmax over the chosen logits alone."""
+    T, d, E, k = 256, 64, 8, 3
+    x = jax.random.normal(jax.random.key(1), (2, T // 2, d))
+    kernel = 0.3 * jax.random.normal(jax.random.key(2), (d, E))
+    kernel = kernel.at[:, 5].set(kernel[:, 2] * (1 + 3e-7))
+    router = moe_lib.TopKSoftmaxRouter(num_experts=E, top_k=k)
+    with HIGHEST:
+        route = router.apply({"params": {"kernel": kernel}}, x)
+        chosen, weight = reference.route(x.reshape(T, d), kernel, k)
+        logits = np.asarray(x.reshape(T, d) @ kernel, np.float64)
+    np.testing.assert_array_equal(route.chosen, chosen)
+    np.testing.assert_allclose(route.weights, weight, rtol=0, atol=1e-7)
+    assert route.weights.dtype == jnp.float32
+    # near-ties at the boundary exist: the third and fourth largest logits of
+    # a token are the planted pair
+    order = np.sort(logits, -1)[:, ::-1]
+    gaps = order[:, k - 1] - order[:, k]
+    assert np.sum(gaps < 1e-5) >= T // 16, np.sum(gaps < 1e-5)
+    picked = np.take_along_axis(logits, np.asarray(chosen), -1)
+    want = np.exp(picked - picked.max(-1, keepdims=True))
+    np.testing.assert_allclose(route.weights,
+                               want / want.sum(-1, keepdims=True), atol=1e-6)
+    np.testing.assert_array_equal(
+        route.load, np.bincount(np.asarray(chosen).reshape(-1), minlength=E))
+
+
+# -- the held experts' layer -------------------------------------------------------
+
+
+def _held(held, **kw):
+    return moe_lib.HeldExperts(ffn_dim=32, held_experts=held, act="relu", **kw)
+
+
+def _layer_inputs(T=64, d=64, E=8, k=3, seed=5):
+    """``(r, y, route, params)``: the router's input and the experts' (two
+    different tensors, as in the model), the plan, all eight experts."""
+    keys = jax.random.split(jax.random.key(seed), 3)
+    r = jax.random.normal(keys[0], (2, T // 2, d))
+    y = jax.random.normal(keys[1], (2, T // 2, d))
+    kernel = 0.3 * jax.random.normal(keys[2], (d, E))
+    with HIGHEST:
+        route = moe_lib.route_softmax_chosen(r.reshape(T, d), kernel, k)
+    whole = _held(None)
+    params = weights.make_like(jax.eval_shape(
+        lambda: whole.init(jax.random.key(0), y, route)["params"]),
+        [[".*", "normal", 0.3]], weights.seed_key(seed))
+    return r, y, kernel, route, params
+
+
+def _part(params, first, held):
+    return {k: v[first:first + held] for k, v in params.items()}
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips hold two experts each of the eight: the parts that the
+    shares give are the uncut layer's output, which is the uncut reference's;
+    and each share's gradient of its own experts is the uncut layer's
+    gradient of them."""
+    r, y, kernel, route, params = _layer_inputs()
+    run = lambda layer, p: layer.apply({"params": p}, y, route)
+    flat = {"moe_router/kernel": kernel,
+            **{f"moe/{k}": v for k, v in params.items()}}
+    z = {"k": 3, "routed": 8, "first": 0, "held": 8}
+    with HIGHEST:
+        want = run(_held(None), params)
+        uncut, counts = reference.experts(r, y, flat, z, lambda a: a)
+        np.testing.assert_allclose(want, uncut, rtol=2e-5, atol=2e-4)  # float32 sums at |y| ~ 20
+        parts = [run(_held((2, s)), _part(params, s, 2)) for s in (0, 2, 4, 6)]
+        np.testing.assert_allclose(sum(parts), want, rtol=2e-5, atol=2e-4)
+        # the reference's own shares add up too
+        shares = [reference.experts(
+            r, y, {"moe_router/kernel": kernel,
+                   **{f"moe/{k}": v for k, v in _part(params, s, 2).items()}},
+            dict(z, first=s, held=2), lambda a: a)[0] for s in (0, 2, 4, 6)]
+        np.testing.assert_allclose(sum(shares), uncut, rtol=2e-5, atol=2e-4)
+        g = jax.grad(lambda p: jnp.sum(jnp.sin(run(_held(None), p))))(params)
+        others = sum(parts) - parts[1]
+        part = jax.grad(lambda p: jnp.sum(jnp.sin(
+            run(_held((2, 2)), p) + others)))(_part(params, 2, 2))
+    for name in ("w_gate", "w_up", "w_down"):
+        scale = float(jnp.max(jnp.abs(g[name])))   # float32 sums, as above
+        np.testing.assert_allclose(part[name], g[name][2:4],
+                                   atol=1e-5 * scale)
+    np.testing.assert_array_equal(counts, route.load)
+    assert float(jnp.max(jnp.abs(parts[3]))) > 0.1   # a share does something
+
+
+def test_a_token_with_no_held_choice_gets_exactly_zero():
+    """No shared expert: a token none of whose choices is held gets zero from
+    the layer, not a small number, in the program and in the reference; the
+    others get something."""
+    r, y, kernel, route, params = _layer_inputs()
+    out = _held((2, 0)).apply({"params": _part(params, 0, 2)}, y, route)
+    lands = np.asarray(jnp.any(route.chosen < 2, axis=-1))
+    assert 0.15 < 1 - lands.mean() < 0.6      # such tokens exist, and others
+    rows = np.asarray(out).reshape(-1, y.shape[-1])
+    np.testing.assert_array_equal(rows[~lands], 0.0)
+    assert np.all(np.abs(rows[lands]).max(-1) > 0)
+    z = {"k": 3, "routed": 8, "first": 0, "held": 2}
+    ref, _ = reference.experts(
+        r, y, {"moe_router/kernel": kernel,
+               **{f"moe/{k}": v for k, v in _part(params, 0, 2).items()}},
+        z, lambda a: a)
+    np.testing.assert_array_equal(
+        np.asarray(ref).reshape(rows.shape)[~lands], 0.0)
+
+
+def test_collapsed_routing_takes_the_parts_and_drops_nothing():
+    """Every token on the two held experts of eight (and a third elsewhere):
+    four times the rows a level router sends, past the whole layout's bound
+    (``chunks`` is 2 here, as at the published sizes), so the layer takes the
+    tokens in parts; the result and the gradients are the dense sum's."""
+    T, d, E, k = 64, 32, 8, 3
+    keys = jax.random.split(jax.random.key(2), 3)
+    y = jax.random.normal(keys[0], (1, T, d))
+    layer = moe_lib.HeldExperts(ffn_dim=16, held_experts=(2, 2), act="relu")
+    third = jax.random.randint(keys[1], (T, 1), 4, 8)
+    collapsed = jnp.concatenate([jnp.full((T, 1), 2), jnp.full((T, 1), 3),
+                                 third], -1).astype(jnp.int32)
+    spread = jnp.stack([jnp.arange(T) % 8, (jnp.arange(T) + 3) % 8,
+                        (jnp.arange(T) + 5) % 8], -1).astype(jnp.int32)
+    weight = jax.random.uniform(keys[2], (T, k), minval=0.2)
+    plan = lambda chosen: moe_lib.Route(
+        chosen, weight, jnp.bincount(chosen.reshape(-1), length=E))
+    params = weights.make_like(jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), y, plan(spread))["params"]),
+        [[".*", "normal", 0.3]], weights.seed_key(1))
+
+    def dense(p, chosen):
+        out = 0.0
+        for e in range(2):
+            mine = jnp.sum(jnp.where(chosen == 2 + e, weight, 0.0), -1)
+            h = jax.nn.relu(y[0] @ p["w_gate"][e]) * (y[0] @ p["w_up"][e])
+            out = out + mine[:, None] * (h @ p["w_down"][e])
+        return out[None]
+
+    with HIGHEST:
+        for chosen, whole in ((collapsed, 0.0), (spread, 1.0)):
+            run = lambda p: layer.apply({"params": p}, y, plan(chosen))
+            np.testing.assert_allclose(run(params), dense(params, chosen),
+                                       atol=2e-5)
+            got = jax.grad(lambda p: jnp.sum(jnp.sin(run(p))))(params)
+            want = jax.grad(lambda p: jnp.sum(jnp.sin(dense(p, chosen))))(
+                params)
+            for name in want:
+                np.testing.assert_allclose(got[name], want[name], atol=2e-5,
+                                           err_msg=name)
+            _, sown = layer.apply({"params": params}, y, plan(chosen),
+                                  mutable=["telemetry"])
+            sown = {k: float(v[0]) for k, v in sown["telemetry"].items()}
+            assert sown["moe_whole"] == whole
+            assert sown["moe_held_rows"] == float(jnp.sum(
+                (chosen >= 2) & (chosen < 4)))
+            if whole:     # the gate's zeros are counted on the whole layout
+                rows = jnp.concatenate([y[0][jnp.any(chosen == 2 + e, -1)]
+                                        @ params["w_gate"][e]
+                                        for e in range(2)])
+                assert sown["moe_gate_zero"] == pytest.approx(
+                    float(jnp.mean(rows <= 0)), abs=1e-6)
+                assert 0.3 < sown["moe_gate_zero"] < 0.7
+            else:
+                assert np.isnan(sown["moe_gate_zero"])
+    text = str(jax.make_jaxpr(lambda p: layer.apply(
+        {"params": p}, y, plan(spread)))(params))
+    assert "cond" in text     # whole where it fits, in parts where not
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("routing", ["level", "collapsed"])
+@pytest.mark.parametrize("act", sorted(gmm_lib.GATES))
+def test_bounded_backward_is_plain_ad_of_the_routine(act, routing, remat):
+    """The hand-written backward of ``_routed_bounded`` against plain AD of
+    ``_routed`` on the same inputs, for either gate, at the split the
+    published sizes give (a quarter of the experts held: ``chunks`` 2): the
+    whole layout's side strings the same products together from what the
+    forward kept (bit for bit), the parts' side sums the weights' gradients
+    part by part (to 1e-6 of the leaf's scale); with the block's remat around
+    it the forward rule is what runs again."""
+    T, d, f, E, k, held, first = 64, 32, 16, 8, 3, 2, 4
+    keys = jax.random.split(jax.random.key(3), 6)
+    tokens = jax.random.normal(keys[0], (T, d))
+    scores = jax.random.uniform(keys[1], (T, E))
+    if routing == "collapsed":
+        scores = scores.at[:, first:first + held].add(5.0)
+    _, chosen = jax.lax.top_k(scores, k)
+    weights_ = jax.random.uniform(keys[2], (T, k), minval=0.2)
+    experts = tuple(0.3 * jax.random.normal(key, shape) for key, shape in zip(
+        keys[3:], [(held, d, f), (held, d, f), (held, f, d)]))
+    counts = jnp.bincount(chosen.reshape(-1), length=E)[
+        first:first + held].astype(jnp.int32)
+    bt, chunks = 8, E // (2 * held)
+    assert chunks == 2
+    whole = bool(moe_lib._fits(counts, bt, moe_lib._bounded_tiles(
+        chosen, experts, bt, chunks)))
+    assert whole == (routing == "level")
+
+    def bounded(tokens, weights_, experts):
+        return moe_lib._routed_bounded(tokens, chosen, weights_, experts,
+                                       counts, first, bt, chunks, act)
+
+    def plain(tokens, weights_, experts):
+        return moe_lib._routed(tokens, chosen, weights_, experts, first, bt,
+                               act=act)
+
+    grads = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2)))(
+            tokens, weights_, experts)
+    with HIGHEST:
+        want_out, want = grads(plain)
+        got_out, got = grads(jax.checkpoint(bounded) if remat else bounded)
+    assert float(jnp.max(jnp.abs(want[0]))) > 1e-3
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if whole:
+            np.testing.assert_array_equal(g, w)
+        else:
+            np.testing.assert_allclose(
+                g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
+
+
+def test_the_gate_is_the_one_asked_for():
+    """``gated_ffn_padded`` with ``act`` against the written-out product, and
+    the hand-written backward against AD, for both gates on one tile
+    layout."""
+    T, d, f, E, bt = 32, 16, 8, 2, 8
+    keys = jax.random.split(jax.random.key(4), 5)
+    x = jax.random.normal(keys[0], (T, d))
+    w = [0.5 * jax.random.normal(key, shape) for key, shape in zip(
+        keys[1:4], [(E, d, f), (E, d, f), (E, f, d)])]
+    counts = jnp.array([T // 2, T // 2], jnp.int32)
+    tiles, src, _ = gmm_lib._padded_layout(
+        jnp.array([0, T // 2], jnp.int32), counts, T, E, bt, max_tiles=4)
+    x_pad = gmm_lib._pad_rows(x, src)
+    dy = jax.random.normal(keys[4], x_pad.shape)
+    seg = (jnp.arange(x_pad.shape[0]) // bt >= 2).astype(jnp.int32)
+    for act, fn in gmm_lib.GATES.items():
+        with HIGHEST:
+            got = gmm_lib.gated_ffn_padded(x_pad, *w, tiles, act)
+            want = jnp.einsum(
+                "tf,tfd->td", fn(jnp.einsum("td,tdf->tf", x_pad, w[0][seg]))
+                * jnp.einsum("td,tdf->tf", x_pad, w[1][seg]), w[2][seg])
+            np.testing.assert_allclose(got, want, atol=1e-5, err_msg=act)
+            _, gate, up = gmm_lib.gated_ffn_padded_kept(x_pad, *w, tiles, act)
+            by_hand = gmm_lib.gated_ffn_padded_bwd(x_pad, gate, up, *w, tiles,
+                                                   dy, act)
+            _, vjp = jax.vjp(lambda x, *w: gmm_lib.gated_ffn_padded(
+                x, *w, tiles, act), x_pad, *w)
+            for a, b in zip(by_hand, vjp(dy)):
+                np.testing.assert_array_equal(a, b)
+    silu = gmm_lib.gated_ffn_padded(x_pad, *w, tiles)       # the default
+    np.testing.assert_array_equal(
+        silu, gmm_lib.gated_ffn_padded(x_pad, *w, tiles, "silu"))
+    assert float(jnp.max(jnp.abs(silu - got))) > 1e-2        # relu differs
+
+
+# -- the published entry, its share, the preset --------------------------------------
+
+
+def test_published_entry_and_its_share():
+    whole = smallthinker.smallthinker_21b()
+    assert (whole.num_layers, whole.d_model, whole.num_experts, whole.top_k,
+            whole.expert_ffn_dim, whole.window, whole.vocab_size) == (
+                52, 2560, 64, 6, 768, 4096, 151936)
+    assert whole.window_layout == whole.rope_layout == (0, 1, 1, 1) * 13
+    assert smallthinker.num_params(whole) == 21_506_562_560
+    share = smallthinker.chip_share(whole)
+    assert (share.window_layout, share.rope_layout) == ((0, 1, 1, 1),) * 2
+    assert share.held_experts == (16, 0) and share.vocab_size == 37984
+    assert smallthinker.chip_share(whole, 3).held_experts == (16, 48)
+    # no width changes
+    for key in ("d_model", "num_heads", "num_kv_heads", "head_dim",
+                "expert_ffn_dim", "num_experts", "top_k", "window",
+                "rope_theta", "epsilon"):
+        assert getattr(share, key) == getattr(whole, key), key
+    assert smallthinker.num_params(share) == 656_529_920
+    shapes = jax.eval_shape(lambda: share.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32), train=False))
+    assert set(shapes) == {"params", "telemetry"}
+    leaves = jax.tree.leaves(shapes["params"])
+    assert sum(int(np.prod(s.shape)) for s in leaves) == 656_529_920
+    block = shapes["params"]["block_2"]
+    assert block["moe"]["w_gate"].shape == (16, 2560, 768)
+    assert block["moe_router"]["kernel"].shape == (2560, 64)
+    assert block["attn"]["query"]["kernel"].shape == (2560, 28, 128)
+    assert block["attn"]["key"]["kernel"].shape == (2560, 4, 128)
+    tiny = smallthinker.smallthinker_tiny()
+    shapes = jax.eval_shape(lambda: tiny.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32), train=False))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree.leaves(
+        shapes["params"])) == smallthinker.num_params(tiny)
+
+
+def test_forward_flops_agree_with_the_benchmarks_count():
+    for module, S in ((smallthinker.chip_share(
+            smallthinker.smallthinker_21b()), 8192),
+            (smallthinker.smallthinker_tiny(), 48),
+            (smallthinker.smallthinker_tiny(), 8)):
+        ours = S * smallthinker.forward_flops_per_token(module, S)
+        theirs = reference.forward_flops(_model_dict(module), {"seq_len": S})
+        assert ours == pytest.approx(theirs, rel=1e-12), S
+    assert theirs > 0
+    share = smallthinker.chip_share(smallthinker.smallthinker_21b())
+    assert 8192 * smallthinker.forward_flops_per_token(share, 8192) \
+        == pytest.approx(5.12e12, rel=1e-2)
+
+
+def test_preset_trains_through_the_trainer_with_named_regions(devices):
+    """The preset at toy size through ``Trainer`` (what ``main.py --preset``
+    builds): it steps, there is no ``batch_stats``, the telemetry carries the
+    expert layers' sows, and the step's text carries the scopes that the
+    benchmark's readers look for, the router's outside the experts'."""
+    cfg = from_preset("smallthinker_21b_share", model="smallthinker_tiny",
+                      seq_len=32, global_batch_size=8, precision="fp32",
+                      lr=3e-3, lr_schedule="constant", warmup_epochs=0.0,
+                      workers=0, steps_per_epoch=4, log_every=1000,
+                      checkpoint_dir=None, mesh_fsdp=4, mesh_data=2)
+    trainer = Trainer(cfg)
+    assert trainer.bundle.task == "lm" and cfg.remat
+    before = jax.device_get(trainer.state.params["block_1"]["moe"]["w_up"])
+    trainer.train_epoch(0)
+    assert int(trainer.state.step) == 4
+    assert not jax.tree.leaves(trainer.state.batch_stats)
+    after = jax.device_get(trainer.state.params["block_1"]["moe"]["w_up"])
+    assert np.abs(after - before).max() > 0
+    batch = {k: jax.ShapeDtypeStruct((8, 32), jnp.int32,
+                                     sharding=trainer.batch_sharding)
+             for k in ("tokens", "targets")}
+    with mesh_lib.use_mesh(trainer.mesh):
+        text = trainer.train_step.lower(trainer.state, batch).as_text(
+            debug_info=True)
+    for scope in ("embed", "attn", "mlp", "moe", "moe_router", "moe_dispatch",
+                  "moe_experts", "moe_combine", "norm", "head_loss",
+                  "optimizer"):
+        assert f"/{scope}/" in text, scope
+    assert "grouped_matmul" in text
+    assert "/mlp/moe/" in text and "/moe/moe_router" not in text
+    assert "/mlp/moe_router" not in text and "/attn/moe_router" not in text
+    assert "moe_shared" not in text
+
+
+def test_what_the_family_does_not_do_fails_loudly():
+    module = smallthinker.smallthinker_tiny()
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    variables = module.init(jax.random.key(0), tokens, train=False)
+    with pytest.raises(NotImplementedError, match="trains only"):
+        module.apply(variables, tokens, train=False, decode_ctx={})
+    with pytest.raises(ValueError, match="sequence-parallel"):
+        registry.create_model(
+            "smallthinker_tiny", num_classes=0, image_size=0, seq_len=8,
+            dtype=jnp.float32, param_dtype=jnp.float32,
+            logits_dtype=jnp.float32, remat=False, sp=True)
+    with pytest.raises(ValueError, match="held_experts"):
+        route = moe_lib.Route(jnp.zeros((4, 3), jnp.int32),
+                              jnp.ones((4, 3)), jnp.zeros((8,), jnp.int32))
+        _held((4, 6)).init(jax.random.key(0), jnp.zeros((1, 4, 8)), route)
+    with pytest.raises(ValueError, match="differ in length"):
+        smallthinker.smallthinker_tiny(rope_layout=(0, 1)).init(
+            jax.random.key(0), tokens, train=False)
