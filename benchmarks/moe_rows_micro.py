@@ -1,0 +1,196 @@
+#!/usr/bin/env python
+"""The held experts' moves of rows alone at a cell's shape: each move of
+``parallel/moe.py`` (``dispatch``, ``combine``, the two transposes) as its
+own jitted program on the chip, in the form the program asks XLA for and in
+the forms it does not, with the bytes the move cannot avoid over its device
+time. ``flash_micro.py`` / ``ssd_micro.py``'s sibling for the expert layers'
+token side; needs the chip; no cell runs it.
+
+Forms: ``program`` (the module's own: choice-major, a ``[T, d]`` slab a
+choice), ``one_gather`` (choice-major, one ``[k, T, d]`` gather),
+``token_major`` (PR 35's: the gathered rows viewed ``[T, k, d]``, the choice
+axis between the rows and the lanes; its ``combine_bwd`` makes ``d_weights``
+from a third ``[T*k, d]`` gather), and ``halves`` (the program's form over the
+source in two column halves: XLA copies a gather's source into VMEM where it
+fits, and SmallThinker's 136 MB ``y_pad`` does not; PERF.md section 6, PR 36).
+
+One JSON row per (shape, move, form): ``ms`` the device time of the whole
+call, ``gbps`` = ``bytes`` / ``ms`` with ``bytes`` the rows that exist read
+once in their dtype plus the result written once, ``ops`` the five longest
+operations (``ssd_micro.device_ms``), ``relayout`` the row arrays that a
+physical ``reshape`` of the compiled text makes, ``vmem`` those placed in VMEM
+(``S(1)``). A shape is ``T x d x k x E x held``; the defaults are the two
+expert cells' and the two with the widths swapped.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+
+import numpy as np
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path[:0] = [_HERE, os.path.dirname(_HERE)]
+
+SHAPES = ("8192x2560x6x64x16,8192x2048x8x128x16,"
+          "8192x2048x6x64x16,8192x2560x8x128x16")
+
+
+def forms(moe):
+    """{move: {form: function}} over ``(tokens, y_pad, d_out, d_pad, weights,
+    pair_row [k, T], row_pair [P])``; every function returns what the move
+    hands on."""
+    import jax.numpy as jnp
+
+    f32, rows = jnp.float32, moe._rows
+
+    def columns(move):
+        """``move`` over each half of its source's columns."""
+        def run(src, *rest):
+            half = src.shape[1] // 2
+            return jnp.concatenate([move(src[:, :half], *rest),
+                                    move(src[:, half:], *rest)], axis=1)
+        return run
+
+    def one_gather(x_pad, pair_row, weights=None):
+        got = rows(x_pad, pair_row).astype(f32)                    # [k, T, d]
+        if weights is not None:
+            got = got * weights.T[:, :, None]
+        return jnp.sum(got, axis=0)
+
+    def token_major(x_pad, pair_row, weights=None):
+        got = rows(x_pad, pair_row.T).astype(f32)                  # [T, k, d]
+        if weights is not None:
+            got = got * weights[..., None]
+        return jnp.sum(got, axis=1)
+
+    def token_major_combine_bwd(res, d_out):
+        y_pad, weights, pair_row, row_pair = res
+        k = weights.shape[1]
+        d_weights = jnp.sum(rows(y_pad, pair_row.T).astype(f32)
+                            * d_out[:, None, :], axis=-1)
+        d_pad = (rows(d_out.astype(y_pad.dtype), row_pair // k)
+                 * rows(weights.reshape(-1, 1), row_pair))
+        return d_pad.astype(y_pad.dtype), d_weights
+
+    sums = {"program": moe._choice_sum, "one_gather": one_gather,
+            "token_major": token_major}
+    k_of = lambda w: w.shape[1]
+    return {
+        "dispatch": {
+            "program": lambda a: rows(a.tokens, a.row_pair // k_of(a.weights)),
+            "halves": lambda a: columns(rows)(
+                a.tokens, a.row_pair // k_of(a.weights))},
+        "combine": {
+            **{name: (lambda a, s=s: s(a.y_pad, a.pair_row, a.weights))
+               for name, s in sums.items()},
+            "halves": lambda a: columns(moe._choice_sum)(
+                a.y_pad, a.pair_row, a.weights)},
+        "combine_bwd": {
+            "program": lambda a: moe._combine_bwd(
+                (a.y_pad, a.weights, a.pair_row, a.row_pair), a.d_out)[:2],
+            "token_major": lambda a: token_major_combine_bwd(
+                (a.y_pad, a.weights, a.pair_row, a.row_pair), a.d_out)},
+        "dispatch_bwd": {
+            **{name: (lambda a, s=s: s(a.d_pad, a.pair_row).astype(
+                a.d_pad.dtype)) for name, s in sums.items()},
+            "halves": lambda a: columns(moe._choice_sum)(
+                a.d_pad, a.pair_row).astype(a.d_pad.dtype)},
+    }
+
+
+def operands(moe, T, d, k, E, held, seed):
+    """A level router's plan for ``T`` tokens over the bounded layout the
+    cell takes, and rows of the program's dtypes."""
+    import collections
+
+    import jax
+    import jax.numpy as jnp
+
+    Args = collections.namedtuple(
+        "Args", "tokens y_pad d_out d_pad weights pair_row row_pair")
+    key = jax.random.split(jax.random.PRNGKey(seed), 6)
+    chosen = jax.lax.top_k(jax.random.uniform(key[0], (T, E)), k)[1]
+    cap = -(-(T // max(1, E // (2 * held))) * k // moe.EXPERT_TILE_ROWS) + held
+    _, pair_row, row_pair = jax.jit(
+        lambda c: moe._plan(c, 0, held, moe.EXPERT_TILE_ROWS, cap, None))(
+            chosen)
+    P = row_pair.shape[0]
+    bf = jnp.bfloat16
+    return Args(jax.random.normal(key[1], (T, d), bf),
+                jax.random.normal(key[2], (P, d), bf),
+                jax.random.normal(key[3], (T, d)),
+                jax.random.normal(key[4], (P, d), bf),
+                jax.nn.softmax(jax.random.normal(key[5], (T, k))),
+                pair_row, row_pair)
+
+
+def least_bytes(move, a):
+    """The rows that exist read once, the result written once."""
+    (T, d), P, k = a.tokens.shape, a.row_pair.shape[0], a.weights.shape[1]
+    live = int(np.sum(np.asarray(a.row_pair) < T * k))
+    return {"dispatch": live * d * 2 + P * d * 2,
+            "combine": live * d * 2 + T * d * 4,
+            "combine_bwd": live * d * (2 + 4) + P * d * 2 + T * k * 4,
+            "dispatch_bwd": live * d * 2 + T * d * 2}[move]
+
+
+def row_arrays(text, d, least):
+    """(shapes that a physical ``reshape`` makes, shapes placed in VMEM)
+    among the unfused instructions of a compiled text whose result is an
+    array of ``d`` or ``d / 2`` columns and ``least`` elements or more."""
+    unfused = re.sub(r"(?ms)^%?fused_computation[^\n]*\{$.*?^\}$", "", text)
+    relayout, vmem = set(), set()
+    for shape, dims, layout, op in re.findall(
+            r" = (\w+\[([\d,]+)\])(\{[^}]*\})? ([\w-]+)\(", unfused):
+        dims = [int(v) for v in dims.split(",")]
+        if dims[-1] in (d, d // 2) and np.prod(dims) >= least:
+            if op == "reshape":
+                relayout.add(shape)
+            if "S(1)" in layout:
+                vmem.add(shape)
+    return sorted(relayout), sorted(vmem)
+
+
+def main():
+    p = argparse.ArgumentParser()
+    p.add_argument("--shapes", default=SHAPES,
+                   help="comma-separated T x d x k x E x held")
+    p.add_argument("--iters", type=int, default=5)
+    p.add_argument("--seed", type=int, default=0)
+    args = p.parse_args()
+
+    import jax
+    from ssd_micro import device_ms
+
+    from pytorch_distributed_training_example_tpu.parallel import moe
+
+    if jax.default_backend() != "tpu":
+        sys.exit("moe_rows_micro.py times the moves on the chip; this is "
+                 + jax.default_backend())
+    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    for shape in args.shapes.split(","):
+        T, d, k, E, held = (int(v) for v in shape.split("x"))
+        a = operands(moe, T, d, k, E, held, args.seed)
+        for move, by_form in forms(moe).items():
+            for form, fn in by_form.items():
+                row = {"shape": shape, "P": a.row_pair.shape[0],
+                       "move": move, "form": form,
+                       "bytes": least_bytes(move, a)}
+                relayout, vmem = row_arrays(
+                    jax.jit(fn).lower(a).compile().as_text(), d, T * d // 2)
+                ms, _, top = device_ms(lambda *b: fn(type(a)(*b)), tuple(a),
+                                       args.iters)
+                row.update(
+                    ms=round(ms, 4), gbps=round(row["bytes"] / ms / 1e6, 1),
+                    ops={n: round(v, 4) for n, v in top.items()},
+                    relayout=relayout, vmem=vmem)
+                print(json.dumps(row), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -7,8 +7,9 @@ scatter > reduce > elementwise, first match wins; no name-guessing),
 (the ``moe_*`` named scopes), ``build_op_bytes`` (unique operand + result
 buffer bytes per executed op), ``collective_byte_census`` and ``collect_ops``
 (device time per XLA op out of a ``jax.profiler`` dump). Used by
-``serve_bench.py``, ``flash_micro.py``, ``ssd_micro.py``, ``graftlint.py``
-and ``tests/test_bench_regression.py``. The cells' per-region and
+``serve_bench.py``, ``flash_micro.py``, ``ssd_micro.py``,
+``moe_rows_micro.py``, ``graftlint.py`` and
+``tests/test_bench_regression.py``. The cells' per-region and
 per-kernel split is ``chipbench/run.py --trace 1``, not this file.
 """
 

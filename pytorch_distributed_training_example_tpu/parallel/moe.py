@@ -891,10 +891,22 @@ def _rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
+def _choice_sum(x_pad, pair_row, weights=None):
+    """``sum_c weights[t, c] * x_pad[pair_row[c, t]]`` as ``[T, d]`` float32 (a
+    choice with no padded row adds nothing; no ``weights``: ones), asked for
+    choice-major: a ``[T, d]`` slab of rows a choice, summed in the order c =
+    0..k-1. With the choice axis between rows and lanes XLA relays the gathered
+    rows out into ``[T, k, d]`` tiles first, wherever k is no multiple of 8."""
+    slabs = (_rows(x_pad, index).astype(jnp.float32) for index in pair_row)
+    if weights is not None:
+        slabs = (slab * w[:, None] for slab, w in zip(slabs, weights.T))
+    return functools.reduce(jnp.add, slabs)
+
+
 @jax.custom_vjp
 def _dispatch_rows(tokens, row_token, pair_row):
     """``tokens [T, d]`` into the experts' padded layout ``[P, d]``: padded
-    row p holds token ``row_token[p]`` (T = none: zeros). ``pair_row [T, k]``
+    row p holds token ``row_token[p]`` (T = none: zeros). ``pair_row [k, T]``
     is the inverse (the padded row of a token's c-th choice, P = none), so
     the transpose is a gather too and no scatter-add is ever lowered."""
     return _rows(tokens, row_token)
@@ -906,26 +918,19 @@ def _dispatch_fwd(tokens, row_token, pair_row):
 
 def _dispatch_bwd(res, d_pad):
     row_token, pair_row = res
-    d_tokens = jnp.sum(_rows(d_pad, pair_row), axis=1, dtype=jnp.float32)
-    return (d_tokens.astype(d_pad.dtype), _int_zeros(row_token),
-            _int_zeros(pair_row))
+    return (_choice_sum(d_pad, pair_row).astype(d_pad.dtype),
+            _int_zeros(row_token), _int_zeros(pair_row))
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _weighted(y_pad, weights, pair_row):
-    """``[T, k, d]`` float32: each choice's row of ``y_pad`` times its weight
-    (one fusion: the gathered rows are read in their own dtype)."""
-    return _rows(y_pad, pair_row).astype(jnp.float32) * weights[..., None]
-
-
 @jax.custom_vjp
 def _combine_rows(y_pad, weights, pair_row, row_pair):
-    """``out[t] = sum_c weights[t, c] * y_pad[pair_row[t, c]]`` in float32
-    (a choice with no padded row adds nothing); ``row_pair [P]`` is the
-    inverse (the flat (token, choice) of a padded row, T*k = none)."""
-    return jnp.sum(_weighted(y_pad, weights, pair_row), axis=1)
+    """``out[t] = sum_c weights[t, c] * y_pad[pair_row[c, t]]`` in float32;
+    ``row_pair [P]`` is the inverse (the flat (token, choice) of a padded
+    row, T*k = none)."""
+    return _choice_sum(y_pad, pair_row, weights)
 
 
 def _combine_fwd(y_pad, weights, pair_row, row_pair):
@@ -934,12 +939,16 @@ def _combine_fwd(y_pad, weights, pair_row, row_pair):
 
 
 def _combine_bwd(res, d_out):
+    """Both from one gather of ``d_out``'s float32 rows into the padded layout:
+    a pair's weight gets its padded row's ``<y_pad[p], d_out[t]>``, a scalar.
+    """
     y_pad, weights, pair_row, row_pair = res
     k = weights.shape[1]
-    d_weights = jnp.sum(_rows(y_pad, pair_row).astype(jnp.float32)
-                        * d_out[:, None, :], axis=-1)
-    d_pad = (_rows(d_out.astype(y_pad.dtype), row_pair // k)
+    d_rows = _rows(d_out, row_pair // k)                            # [P, d]
+    dw_pad = jnp.sum(y_pad.astype(jnp.float32) * d_rows, axis=-1)
+    d_pad = (d_rows.astype(y_pad.dtype)
              * _rows(weights.reshape(-1, 1), row_pair))
+    d_weights = _rows(dw_pad, pair_row).T                           # [T, k]
     return (d_pad.astype(y_pad.dtype), d_weights.astype(weights.dtype),
             _int_zeros(pair_row), _int_zeros(row_pair))
 
@@ -963,10 +972,10 @@ def _held_counts(key, held):
 
 
 def _plan(chosen, first, held, bt, max_tiles, counts):
-    """The integer plan ``(tiles, pair_row, row_pair)`` of the held experts'
-    padded layout for ``chosen [n, k]``: the (token, choice) pairs sorted by
-    held expert, the pairs that chose another chip's expert behind them all;
-    integers only. Both directions of every move of rows are gathers."""
+    """The integer plan ``(tiles, pair_row [k, n], row_pair [P])`` of the held
+    experts' padded layout for ``chosen [n, k]``: the (token, choice) pairs
+    sorted by held expert, the pairs that chose another chip's expert behind
+    them all; integers only. Both directions of every move are gathers."""
     from pytorch_distributed_training_example_tpu.ops import (
         grouped_matmul as gmm_lib)
 
@@ -981,7 +990,7 @@ def _plan(chosen, first, held, bt, max_tiles, counts):
     tiles, src, dst = gmm_lib._padded_layout(
         starts, counts, n * k, held, bt, max_tiles)
     row_pair = _rows(order, src) + (src >= n * k) * (n * k)         # [P]
-    pair_row = dst[rank].reshape(n, k)
+    pair_row = dst[rank].reshape(n, k).T                            # [k, n]
     return tiles, pair_row, row_pair
 
 

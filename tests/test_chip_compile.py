@@ -14,6 +14,8 @@ process with the persistent cache off; code that asks
 ``jax.default_backend()`` is steered by monkeypatch, not by a program option.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -333,10 +335,10 @@ def _held_experts_layer(one_chip):
             _sds((1, 8192, 2048), one_chip))
 
 
-def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
-    """The held experts' layer at the published widths: the gated grouped
-    FFN forward and backward (the transposed read of the weights in dx, the
-    4 MB accumulator of dw under the scoped VMEM)."""
+@functools.cache
+def _trinity_layer_text(one_chip):
+    """The compiled text of that layer's gradients (compiled once for the
+    tests that read it)."""
     layer, *args = _held_experts_layer(one_chip)
 
     def grads(params, stats, x):
@@ -344,7 +346,14 @@ def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
             {"params": p, "batch_stats": stats}, x, train=False).astype(
                 jnp.float32).sum(), argnums=(0, 1))(params, x)
 
-    text = _compiled_text(grads, *args)
+    return _compiled_text(grads, *args)
+
+
+def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
+    """The held experts' layer at the published widths: the gated grouped
+    FFN forward and backward (the transposed read of the weights in dx, the
+    4 MB accumulator of dw under the scoped VMEM)."""
+    text = _trinity_layer_text(one_chip)
     assert "grouped_matmul_dw" in text and "conditional" in text
     # the bounded layout: 144 tiles of 128 rows, not the worst case's 528
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
@@ -474,11 +483,12 @@ def test_window_flash_compiles_at_smallthinker_widths(one_chip):
     assert "flash_fwd_window" not in full
 
 
-def test_relu_held_experts_compile_at_published_widths(one_chip, as_tpu):
-    """The held experts' routine alone at SmallThinker's widths (16 held of
-    64, 6 a token, 8,192 tokens of 2560, experts of 768: column blocks of 256,
-    since 768 is no power of two), ReLU-gated, over a plan that a router made
-    elsewhere: forward and backward, the bounded layout at ``chunks`` 2."""
+@functools.cache
+def _smallthinker_routine_text(one_chip):
+    """The compiled text of the held experts' routine alone at SmallThinker's
+    widths (16 held of 64, 6 a token, 8,192 tokens of 2560, experts of 768),
+    ReLU-gated, over a plan that a router made elsewhere: forward and
+    backward (compiled once for the tests that read it)."""
     from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
 
     router = moe_lib.TopKSoftmaxRouter(num_experts=64, top_k=6)
@@ -503,14 +513,49 @@ def test_relu_held_experts_compile_at_published_widths(one_chip, as_tpu):
                 jnp.float32).sum()
         return jax.grad(total, argnums=(0, 1, 2))(kernel, params, y)
 
-    text = _compiled_text(grads, kernel, params,
+    return _compiled_text(grads, kernel, params,
                           _sds((1, 8192, 2560), one_chip, jnp.float32),
                           _sds((1, 8192, 2560), one_chip))
+
+
+def test_relu_held_experts_compile_at_published_widths(one_chip, as_tpu):
+    """That routine compiles (column blocks of 256, since 768 is no power of
+    two) in the bounded layout at ``chunks`` 2."""
+    text = _smallthinker_routine_text(one_chip)
     assert "grouped_matmul_dw" in text and "conditional" in text
     # the bounded layout: 208 tiles of 128 rows (half the tokens' worst
     # case), not the whole worst case's 400
     assert "bf16[26624,2560]" in text and "bf16[51200,2560]" not in text
     assert "bf16[26624,768]" in text
+
+
+@pytest.mark.parametrize("text_of,k,d,P", [
+    pytest.param(_trinity_layer_text, 8, 2048, 18432, id="trinity"),
+    pytest.param(_smallthinker_routine_text, 6, 2560, 26624,
+                 id="smallthinker")])
+def test_held_experts_moves_of_rows_are_choice_major(one_chip, as_tpu,
+                                                     text_of, k, d, P):
+    """The token-side moves at both expert cells' widths (8,192 tokens): no
+    row array is viewed ``[tokens, k, d]``, which at k = 6 is a physical
+    ``reshape`` that pads six rows to a tile's sublanes; and on the whole
+    layout's side the backward gathers ``k`` slabs of ``[8192, d]`` for the
+    dispatch's transpose, one float32 ``[P, d]`` for both of the combine's
+    cotangents and ``x_pad`` again: no ``[8192 * k, d]`` gather of ``y_pad``
+    for the weights' gradient, which is made in the padded layout."""
+    import re
+    from collections import Counter
+
+    text = text_of(one_chip)
+    assert not re.findall(r" = \w+\[\d+,%d,%d\]\S* reshape\(" % (k, d), text)
+    gathers = Counter(
+        (m.group(1), m.group(2).split("/")[-3]) for m in re.finditer(
+            r" = (\w+\[[\d,]+\])\S* fusion\([^\n]*kind=kCustom"
+            r"[^\n]*op_name=\"([^\"]*/gather)\"", text)
+        if "transpose(" in m.group(2) and "/while/" not in m.group(2)
+        and m.group(1).endswith(",%d]" % d))
+    assert gathers == {("bf16[8192,%d]" % d, "moe_dispatch"): k,
+                       ("f32[%d,%d]" % (P, d), "moe_combine"): 1,
+                       ("bf16[%d,%d]" % (P, d), "moe_dispatch"): 1}, gathers
 
 
 @pytest.mark.slow  # 50 s of the TPU compiler on every core, as Trinity's

@@ -375,6 +375,90 @@ def test_bounded_backward_is_plain_ad_of_the_routine(act, routing, remat):
                 g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
 
 
+@pytest.mark.parametrize("k", [6, 8])
+@pytest.mark.parametrize("routing", ["ragged", "absent", "collapsed"])
+def test_moves_of_rows_transpose_as_their_dense_sums(routing, k):
+    """The three token-side moves and their hand-written transposes against
+    ``jax.vjp`` of the same sums stated as one-hot float32 matrices (no
+    ``take`` in them), over the choice-major plan ``_plan`` hands out: with
+    every expert held (ragged tiles, no pair absent), with a quarter held and
+    every token's first choice on another chip (an absent pair in every
+    token), and with routing collapsed onto the held half, where ``_in_parts``
+    repeats the moves over parts of the tokens."""
+    T, d, f, E, bt = 48, 32, 16, 16, 8
+    held, first = {"ragged": (E, 0), "absent": (4, 4),
+                   "collapsed": (8, 4)}[routing]
+    keys = jax.random.split(jax.random.key(11), 9)
+    scores = jax.random.uniform(keys[0], (T, E))
+    if routing == "absent":
+        scores = scores.at[:, 0].add(5.0)
+    if routing == "collapsed":
+        scores = scores.at[:, first:first + held].add(5.0)
+    _, chosen = jax.lax.top_k(scores, k)
+    weights_ = jax.random.uniform(keys[1], (T, k), minval=0.2)
+    tokens, d_out = (jax.random.normal(key, (T, d)) for key in keys[2:4])
+
+    def agree(got, want):
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        strict=True):
+            scale = float(jnp.max(jnp.abs(w)))
+            assert scale > 1e-3
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6 * scale)
+
+    if routing == "collapsed":
+        experts = tuple(0.3 * jax.random.normal(key, shape) for key, shape in
+                        zip(keys[4:], [(held, d, f), (held, d, f),
+                                       (held, f, d)]))
+        cap = moe_lib._bounded_tiles(chosen, experts, bt, 2)
+        assert not bool(moe_lib._fits(moe_lib._held_counts(
+            moe_lib._held_keys(chosen, first, held), held), bt, cap))
+
+        def dense(tokens, weights_, experts):
+            out = 0.0
+            for e, (w_gate, w_up, w_down) in enumerate(zip(*experts)):
+                mine = jnp.sum(jnp.where(chosen == first + e, weights_, 0.0),
+                               -1)
+                h = jax.nn.relu(tokens @ w_gate) * (tokens @ w_up)
+                out = out + mine[:, None] * (h @ w_down)
+            return out
+
+        parts = lambda *a: moe_lib._in_parts(
+            a[0], chosen, *a[1:], first, bt, 2, cap, "relu")
+        with HIGHEST:
+            want_out, want = jax.vjp(dense, tokens, weights_, experts)
+            got_out, got = jax.vjp(parts, tokens, weights_, experts)
+            agree(got_out, want_out)
+            agree(got(d_out), want(d_out))
+        return
+
+    _, pair_row, row_pair = moe_lib._plan(chosen, first, held, bt, None, None)
+    P, row_token = row_pair.shape[0], row_pair // k
+    assert pair_row.shape == (k, T)
+    absent = np.asarray(pair_row == P)
+    if routing == "absent":
+        assert absent.any(axis=0).all() and not absent.all()
+    else:
+        counts = np.bincount(np.asarray(chosen).ravel(), minlength=E)
+        assert not absent.any() and (counts % bt).any()
+    y_pad, d_pad = (jax.random.normal(key, (P, d)) for key in keys[4:6])
+    into = jax.nn.one_hot(row_token, T)                  # [P, T]
+    back = jax.nn.one_hot(pair_row, P)                   # [k, T, P]
+    with HIGHEST:
+        want_pad, want = jax.vjp(lambda t: into @ t, tokens)
+        got_pad, got = jax.vjp(
+            lambda t: moe_lib._dispatch_rows(t, row_token, pair_row), tokens)
+        agree(got_pad, want_pad)
+        agree(got(d_pad), want(d_pad))
+        want_out, want = jax.vjp(
+            lambda y, w: jnp.einsum("tc,ctp,pd->td", w, back, y), y_pad,
+            weights_)
+        got_out, got = jax.vjp(
+            lambda y, w: moe_lib._combine_rows(y, w, pair_row, row_pair),
+            y_pad, weights_)
+        agree(got_out, want_out)
+        agree(got(d_out), want(d_out))
+
+
 def test_the_gate_is_the_one_asked_for():
     """``gated_ffn_padded`` with ``act`` against the written-out product, and
     the hand-written backward against AD, for both gates on one tile
