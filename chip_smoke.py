@@ -68,33 +68,28 @@ def check(cond, msg):
         raise AssertionError(msg)
 
 
-class _Compiles:
-    """Backend-compile seconds and persistent-cache hits/misses, per phase,
-    from jax's own monitoring events (no flag of the program needed)."""
+def _recorder():
+    from pytorch_distributed_training_example_tpu.utils import telemetry
 
-    def __init__(self):
-        import jax
+    return telemetry.recorder()
 
-        self.reset()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def reset(self):
-        self.compile_s, self.hits, self.misses = 0.0, 0, 0
+def _newest_record():
+    """The largest ``id`` in the program's span recorder: a phase's compile
+    records are those with a larger one."""
+    return max((r.id for r in _recorder().records()), default=0)
 
-    def _duration(self, event, seconds, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += seconds
 
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def row(self):
-        return {"compile_s": round(self.compile_s, 2),
-                "cache_hits": self.hits, "cache_misses": self.misses}
+def _compile_row(since):
+    """Backend seconds (XLA compiles and executables the persistent cache
+    served) and the cache's hits and misses of the records after ``since``,
+    from the recorder that hears jax's compile pipeline."""
+    found = [r for r in _recorder().records()
+             if r.kind == "compile" and r.id > since]
+    return {"compile_s": round(sum(r.seconds for r in found
+                                   if r.name in ("compile", "cache_load")), 2),
+            "cache_hits": sum(r.name == "cache_load" for r in found),
+            "cache_misses": sum(r.name == "cache_miss" for r in found)}
 
 
 class _LogTap(logging.Filter):
@@ -192,7 +187,7 @@ def _peak_bytes():
             for d in jax.devices()]
 
 
-def run_main(name, argv, ckpt_dir, compiles, *, expect_steps):
+def run_main(name, argv, ckpt_dir, *, expect_steps):
     """One ``main.main(argv)`` phase; returns (row, trainer)."""
     import main
     from pytorch_distributed_training_example_tpu.utils import (
@@ -201,7 +196,7 @@ def run_main(name, argv, ckpt_dir, compiles, *, expect_steps):
 
     tap, box = _LogTap(), []
     log.addFilter(tap)
-    compiles.reset()
+    since = _newest_record()
     t0 = time.perf_counter()
     try:
         with _capture_trainer(box):
@@ -219,7 +214,7 @@ def run_main(name, argv, ckpt_dir, compiles, *, expect_steps):
           f"{name}: non-finite loss in {losses}")
     gaps = [b["time"] - a["time"] for a, b in zip(rows[1:], rows[2:])]
     row = {"phase": name, "steps": [r["step"] for r in rows],
-           "losses": losses, "wall_s": round(wall, 2), **compiles.row(),
+           "losses": losses, "wall_s": round(wall, 2), **_compile_row(since),
            # host clock between consecutive per-step metric fetches (each a
            # blocking device_get): the loop's step time, input included
            "loop_step_s": statistics.median(gaps) if gaps else None,
@@ -248,14 +243,14 @@ def run_main(name, argv, ckpt_dir, compiles, *, expect_steps):
     return row, trainer
 
 
-def one_chip(compiles):
+def one_chip():
     from pytorch_distributed_training_example_tpu.core import (
         checkpoint as checkpoint_lib)
 
     gdir = os.path.join(OUT, "gpt2_124m")
     first, trainer = run_main(
         "gpt2_train", [*GPT2_ARGV, "--epochs", "1", "--steps-per-epoch", "6"],
-        gdir, compiles, expect_steps=6)
+        gdir, expect_steps=6)
     check(first["tpu_custom_calls"] > 0,
           "gpt2_train: no tpu_custom_call in the compiled step — the XLA "
           "attention fallback was taken")
@@ -268,7 +263,7 @@ def one_chip(compiles):
         "gpt2_resume",
         [*GPT2_ARGV, "--epochs", "2", "--steps-per-epoch", "6",
          "--resume", "auto"],
-        gdir, compiles, expect_steps=6)
+        gdir, expect_steps=6)
     check(second["resumed"] and "resumed from step 6" in second["resumed"],
           f"gpt2_resume: did not restore (log: {second['resumed']!r})")
     check(second["steps"][0] == 6,
@@ -282,7 +277,7 @@ def one_chip(compiles):
     run_main("resnet50_train",
              ["--config", "resnet50_imagenet", "--batch-size", "128",
               "--epochs", "1", "--steps-per-epoch", "3", "--log-every", "1"],
-             os.path.join(OUT, "resnet50"), compiles, expect_steps=3)
+             os.path.join(OUT, "resnet50"), expect_steps=3)
 
 
 def _param_bytes_per_device(params):
@@ -295,7 +290,7 @@ def _param_bytes_per_device(params):
     return per
 
 
-def four_chips(compiles):
+def four_chips():
     import jax
 
     from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
@@ -307,7 +302,7 @@ def four_chips(compiles):
 
     sharded_row, sharded = run_main(
         "gpt2_fsdp4", [*argv, "--mesh", "fsdp=4"],
-        os.path.join(OUT, "gpt2_fsdp4"), compiles, expect_steps=4)
+        os.path.join(OUT, "gpt2_fsdp4"), expect_steps=4)
     check(sharded_row["tpu_custom_calls"] > 0,
           "gpt2_fsdp4: the flash kernel is not in the compiled step")
 
@@ -330,14 +325,14 @@ def four_chips(compiles):
     ref_dir = os.path.join(OUT, "gpt2_one_device")
     cfg = main.config_from_args(main.build_parser().parse_args(
         [*argv, "--checkpoint-dir", ref_dir]))
-    compiles.reset()
+    since = _newest_record()
     ref = Trainer(cfg, mesh=mesh_lib.single_device_mesh(jax.devices()[0]))
     ref.train()
     ref_losses = [r["loss"] for r in _train_rows(ref_dir)][-4:]
     ref_bytes = _param_bytes_per_device(ref.state.params)
     check(len(ref_bytes) == 1, f"one-device run used {ref_bytes}")
     total = next(iter(ref_bytes.values()))
-    say(phase="gpt2_one_device", losses=ref_losses, **compiles.row(),
+    say(phase="gpt2_one_device", losses=ref_losses, **_compile_row(since),
         param_bytes=total)
 
     rel = [abs(a - b) / abs(b)
@@ -389,14 +384,13 @@ def main_(argv=None):
         say(phase="versions", jax=jax.__version__, jaxlib=jaxlib.__version__,
             libtpu=libtpu, device_kind=dev.device_kind,
             compile_cache=xcache.place_compile_cache())
-        compiles = _Compiles()
         if args.chips == 4:
-            four_chips(compiles)
+            four_chips()
         else:
             check(device["count"] == 1,
                   f"one-chip run found {device['count']} devices; use "
                   "--chips 4")
-            one_chip(compiles)
+            one_chip()
         ok = True
     except (Exception, SystemExit) as e:  # a failed phase fails the run
         import traceback

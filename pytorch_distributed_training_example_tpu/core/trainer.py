@@ -207,11 +207,15 @@ class Trainer:
         snapshot (last health row, goodput) when that is on."""
         snap = self.telemetry.snapshot() if self.telemetry is not None else {}
         return {**snap, "last_spans": self.recorder.tail(16, kind="span"),
-                "last_compiles": self.recorder.tail(8, kind="compile")}
+                "last_compiles": self.recorder.tail(
+                    8, kind="compile", names=telemetry_lib.BACKEND_RECORDS)}
 
     def _init_workload(self, cfg: Config, mesh=None):
-        self.mesh = mesh if mesh is not None else mesh_lib.build_mesh(
-            cfg.mesh_config(), elastic=cfg.elastic)
+        # ``init`` is split where it crosses a layer. The children have no
+        # goodput bucket: ``init`` accrues, they are the timeline's detail.
+        with self._span("build_mesh", bucket=None):
+            self.mesh = mesh if mesh is not None else mesh_lib.build_mesh(
+                cfg.mesh_config(), elastic=cfg.elastic)
         # Elastic resume: BEFORE anything batch-dependent is built, peek the
         # newest committed manifest for the geometry that wrote it; if the
         # world size changed, rescale this run's batch geometry under the
@@ -221,9 +225,24 @@ class Trainer:
         if cfg.elastic and cfg.resume:
             cfg = self._plan_elastic(cfg)
             self.cfg = cfg
-        self.bundle = build_model(cfg)
+        with self._span("build_model", bucket=None):
+            self.bundle = build_model(cfg)
+        with self._span("build_data", bucket=None):
+            self._build_data(cfg)
 
-        # data ------------------------------------------------------------
+        # optimizer / state / steps -----------------------------------------
+        program = build_step_program(cfg, self.mesh, self.steps_per_epoch,
+                                     self.bundle)
+        self.schedule = program.schedule
+        with self._span("init_state", bucket=None):
+            self.state = program.init_state()
+        self.train_step = program.train_step
+        self.eval_step = program.eval_step
+        self.batch_sharding = program.batch_sharding
+        self._init_run(cfg)
+
+    def _build_data(self, cfg: Config):
+        """Both datasets, the sampler and both loaders; the steps an epoch."""
         vocab = getattr(self.bundle.module, "vocab_size", 50257)
         data_kw = dict(image_size=cfg.image_size, seq_len=cfg.seq_len,
                        seed=cfg.seed, vocab_size=vocab)
@@ -285,16 +304,8 @@ class Trainer:
             # step-site ones: epoch * steps_per_epoch + batch.
             self._chaos.steps_per_epoch = self.steps_per_epoch
 
-        # optimizer / state / steps -----------------------------------------
-        program = build_step_program(cfg, self.mesh, self.steps_per_epoch,
-                                     self.bundle)
-        self.schedule = program.schedule
-        self.state = program.init_state()
-        self.train_step = program.train_step
-        self.eval_step = program.eval_step
-        self.batch_sharding = program.batch_sharding
-
-        # checkpointing ----------------------------------------------------
+    def _init_run(self, cfg: Config):
+        """Checkpointing and the restore, the profile and fault options."""
         self.checkpointer = (checkpoint_lib.Checkpointer(cfg.checkpoint_dir)
                              if cfg.checkpoint_dir else None)
         self.start_epoch = 0
@@ -781,14 +792,21 @@ class Trainer:
                         # First dispatch ever traces + compiles; block so the
                         # "compile" span covers it (dispatch is async — without
                         # the block the cost would leak into later step spans).
+                        # ``compile``'s self time is the host's (trace,
+                        # lower, compile or load); the child is the device's
+                        # first execution of the step.
                         with self._span("compile"):
                             metrics = self._first_dispatch(batch)
-                            jax.tree.map(lambda x: x.block_until_ready(),
-                                         metrics)
+                            with self._span("first_step_wait", bucket=None):
+                                jax.tree.map(lambda x: x.block_until_ready(),
+                                             metrics)
                         self._compiled = True
                         if tele is not None:
-                            # Time-to-first-step: wall from process start to
-                            # the first completed optimizer step, cold vs warm.
+                            # The first completed optimizer step, cold vs
+                            # warm. ``time_to_first_step_s`` counts from the
+                            # telemetry layer's start (``Telemetry`` adopts
+                            # the recorder above, in ``__init__``), and
+                            # ``process_to_first_step_s`` from the process's.
                             tele.mark_first_step(self._xcache_mode)
                     else:
                         # The enqueue. Productive time: goodput's "step".

@@ -149,14 +149,14 @@ class Span(NamedTuple):
     of the span that was open on the same thread when this one started."""
 
     kind: str            # "span" | "counter" | "compile"
-    name: str
+    name: str            # of a "compile" record: a key of COMPILE_RECORDS
     t0: int
     t1: int
     step: int | None
     id: int
     parent: int | None
     thread: str
-    value: Any = None    # a counter's reading; a compile event's function
+    value: Any = None    # a counter's reading; a compile record's function
 
     @property
     def seconds(self) -> float:
@@ -168,6 +168,48 @@ class Span(NamedTuple):
 RING_RECORDS = 1 << 16
 
 _SAME = object()
+
+#: ``jax.monitoring`` duration event -> the stage of the compile pipeline it
+#: times. ``SpanRecorder.compile_stage`` turns a stage into records.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+#: The stages that nest: a traced function traces the jitted functions it
+#: calls, and a lowering rule may trace and lower a function of its own.
+_NESTING_STAGES = ("trace", "lower")
+_NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
+                            if stage in _NESTING_STAGES)
+
+#: Every name a ``kind="compile"`` record can have. One jitted function
+#: leaves, in this order, ``trace`` (Python to jaxpr) and ``lower`` (jaxpr to
+#: StableHLO), of the outermost function only, and one backend record: ``compile``
+#: where XLA compiled it (with a ``cache_miss`` inside where the persistent
+#: cache then stored it), or ``cache_load`` where the persistent cache served
+#: the executable (the cache's key, then the ``cache_retrieval`` inside it:
+#: read, deserialize, load).
+COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
+                   "cache_retrieval", "cache_miss")
+#: The backend's share of them: what the watchdog's dump shows.
+BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
+
+
+def process_start_ns() -> int | None:
+    """This process's start as the operating system has it, on the
+    ``perf_counter_ns`` clock, or None where the system does not say. Linux
+    counts it in clock ticks (10 ms) since boot: its age by ``CLOCK_BOOTTIME``
+    is taken off the present stamp."""
+    try:
+        with open("/proc/self/stat", "rb") as fh:
+            # the fields after "pid (comm)": state is field 3, starttime 22
+            ticks = int(fh.read().rsplit(b")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter_ns() - int(age * 1e9) if age >= 0 else None
 
 
 class _OpenSpan:
@@ -242,10 +284,20 @@ class SpanRecorder:
     """
 
     def __init__(self, run_id: str = "", carry: dict | None = None,
-                 meta: dict | None = None, capacity: int = RING_RECORDS):
+                 meta: dict | None = None, capacity: int = RING_RECORDS,
+                 process_t0_ns: int | None = None):
         self._events: collections.deque = collections.deque(maxlen=capacity)
         self._local = threading.local()
         self._ids = itertools.count(1)
+        #: ``process``: from the operating system's start of this process to
+        #: this recorder's creation (interpreter, imports, the backend's
+        #: start, the caller's preamble). No goodput bucket; ``adopt`` keeps
+        #: it. None where the caller has no start time to give.
+        self._process: Span | None = None
+        if process_t0_ns is not None:
+            self._process = Span(
+                "span", "process", process_t0_ns, time.perf_counter_ns(),
+                None, next(self._ids), None, threading.current_thread().name)
         #: the global step the training loop is in: the default ``step`` of
         #: every span and event opened until the loop sets the next one
         self.step: int | None = None
@@ -270,6 +322,8 @@ class SpanRecorder:
         self._run_ids: list[str] = []
         self._attempt_ids: list[str] = []
         self._events.clear()
+        if self._process is not None:
+            self._events.append(self._process)
         self._totals: collections.defaultdict = collections.defaultdict(float)
         self._counts: collections.defaultdict = collections.defaultdict(int)
         # Cross-attempt carryover (elastic/preemption relaunch): ``carry`` is
@@ -282,11 +336,15 @@ class SpanRecorder:
         self._base_counts: dict[str, int] = {}
         self._base_wall = 0.0
         self.attempts = 1
-        # Time-to-first-step (r21 instant restart): wall from construction
-        # to the first completed optimizer step, tagged cold/warm by the
+        # Time-to-first-step (r21 instant restart): wall from this origin
+        # (the telemetry layer's start, inside ``Trainer.__init__``) to the
+        # first completed optimizer step, tagged cold/warm by the
         # executable-cache outcome. History carries across attempts so the
-        # warm-vs-cold comparison lives in ONE goodput.json.
+        # warm-vs-cold comparison lives in ONE goodput.json. Beside it, the
+        # same mark measured from the ``process`` span's start: what a
+        # launch costs, imports and the backend's start included.
         self._ttfs: float | None = None
+        self._process_ttfs: float | None = None
         self._ttfs_mode: str | None = None
         self._ttfs_history: list[dict] = []
         if carry:
@@ -345,23 +403,64 @@ class SpanRecorder:
             next(self._ids), None, threading.current_thread().name, value))
 
     def compile_event(self, name: str, seconds: float = 0.0,
-                      fun_name: str | None = None) -> None:
-        """A compilation (or a compilation-cache hit or miss) that just
-        ended, stamped with the step the loop is in."""
-        now = time.perf_counter_ns()
+                      fun_name: str | None = None,
+                      t1: int | None = None) -> None:
+        """One ``kind="compile"`` record that ended just now (or at ``t1``),
+        with the step the loop is in and the span open on this thread."""
+        if t1 is None:
+            t1 = time.perf_counter_ns()
+        stack = getattr(self._local, "stack", None)
         self._events.append(Span(
-            "compile", name, now - int(seconds * 1e9), now, self.step,
-            next(self._ids), None, threading.current_thread().name,
-            fun_name))
+            "compile", name, t1 - int(seconds * 1e9), t1, self.step,
+            next(self._ids), stack[-1] if stack else None,
+            threading.current_thread().name, fun_name))
+
+    def stage_started(self) -> None:
+        """jax began to trace or to lower a function on this thread
+        (``stage_started`` and the ``compile_stage`` of a stage that nests
+        come in nested pairs)."""
+        local = self._local
+        local.nesting = getattr(local, "nesting", 0) + 1
+
+    def compile_stage(self, stage: str, seconds: float,
+                      fun_name: str | None = None) -> None:
+        """A stage of jax's compile pipeline ended on this thread."""
+        local = self._local
+        if stage in _NESTING_STAGES:
+            # Every jitted function a traced function calls reports its own
+            # trace, inside its caller's interval (thousands for one train
+            # step), and so do the functions a lowering rule traces: only the
+            # outermost is a record, so that these records of a thread do not
+            # overlap and add up to wall time.
+            depth = getattr(local, "nesting", 0)
+            local.nesting = max(depth - 1, 0)
+            if depth > 1:
+                return
+        elif stage == "cache_retrieval":
+            # Comes inside the backend's interval and without the function's
+            # name: held until the backend event that follows it gives one.
+            local.retrieved = (time.perf_counter_ns(), seconds)
+            return
+        elif stage == "compile":
+            retrieved = getattr(local, "retrieved", None)
+            if retrieved is not None:
+                local.retrieved = None
+                self.compile_event("cache_retrieval", retrieved[1], fun_name,
+                                   t1=retrieved[0])
+                stage = "cache_load"
+        self.compile_event(stage, seconds, fun_name)
 
     def records(self) -> list[Span]:
         """A snapshot of the ring, oldest first."""
         return list(self._events)
 
-    def tail(self, n: int, kind: str | None = None) -> list[dict]:
-        """The newest ``n`` records (of ``kind``) as plain dicts, for the
-        watchdog's dump."""
-        picked = [r for r in self.records() if kind is None or r.kind == kind]
+    def tail(self, n: int, kind: str | None = None,
+             names: tuple | None = None) -> list[dict]:
+        """The newest ``n`` records (of ``kind``, called one of ``names``) as
+        plain dicts, for the watchdog's dump."""
+        picked = [r for r in self.records()
+                  if (kind is None or r.kind == kind)
+                  and (names is None or r.name in names)]
         return [{"kind": r.kind, "name": r.name, "step": r.step,
                  "ms": round(r.seconds * 1e3, 3), "thread": r.thread,
                  **({} if r.value is None else {"value": r.value})}
@@ -380,6 +479,9 @@ class SpanRecorder:
         if self._ttfs is not None:
             return
         self._ttfs = self.wall_s
+        if self._process is not None:
+            self._process_ttfs = (time.perf_counter_ns()
+                                  - self._process.t0) / 1e9
         self._ttfs_mode = str(mode)
         self._ttfs_history.append({"attempt": self.attempts,
                                    "ttfs_s": round(self._ttfs, 4),
@@ -445,6 +547,8 @@ class SpanRecorder:
         if self._ttfs is not None:
             out["time_to_first_step_s"] = round(self._ttfs, 4)
             out["ttfs_mode"] = self._ttfs_mode
+        if self._process_ttfs is not None:
+            out["process_to_first_step_s"] = round(self._process_ttfs, 4)
         if self._ttfs_history:
             out["ttfs_history"] = [dict(h) for h in self._ttfs_history]
         if "restart" in totals:
@@ -493,31 +597,40 @@ _process_lock = threading.Lock()
 
 
 def recorder() -> SpanRecorder:
-    """The process-wide :class:`SpanRecorder`, made on first use. From then
-    on it also hears jax's compile events (``jax.monitoring``), so the
-    timeline says which step a recompile fell into."""
+    """The process-wide :class:`SpanRecorder`, made on first use: its first
+    record is the ``process`` span. From then on it also hears jax's compile
+    pipeline (``jax.monitoring``), so the timeline says which span, and which
+    step, a trace, a lowering, a compile or a cache load fell into."""
     global _process_recorder
     if _process_recorder is None:
         with _process_lock:
             if _process_recorder is None:
-                rec = SpanRecorder()
+                _process_recorder = SpanRecorder(
+                    process_t0_ns=process_start_ns())
+                jax.monitoring.register_scalar_listener(_on_compile_start)
                 jax.monitoring.register_event_duration_secs_listener(
                     _on_compile_duration)
                 jax.monitoring.register_event_listener(_on_cache_event)
-                _process_recorder = rec
     return _process_recorder
 
 
+# The listeners: one comparison (or lookup) an event, nothing stored for an
+# event that is not of the compile pipeline.
+
+
+def _on_compile_start(event: str, value, **kw) -> None:
+    if event in _NESTING_EVENTS:
+        _process_recorder.stage_started()
+
+
 def _on_compile_duration(event: str, seconds: float, **kw) -> None:
-    if event == "/jax/core/compile/backend_compile_duration":
-        _process_recorder.compile_event("compile", seconds,
-                                        kw.get("fun_name"))
+    stage = _COMPILE_STAGES.get(event)
+    if stage is not None:
+        _process_recorder.compile_stage(stage, seconds, kw.get("fun_name"))
 
 
 def _on_cache_event(event: str, **kw) -> None:
-    if event == "/jax/compilation_cache/cache_hits":
-        _process_recorder.compile_event("cache_hit")
-    elif event == "/jax/compilation_cache/cache_misses":
+    if event == "/jax/compilation_cache/cache_misses":
         _process_recorder.compile_event("cache_miss")
 
 

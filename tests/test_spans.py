@@ -140,11 +140,142 @@ def test_compile_events_name_the_function_and_the_step(ring):
         return x * 3 + 1
 
     only_compiled_in_test_spans(np.arange(5.0))
+    # the backend's record: compiled, or served by the persistent cache
+    # where an earlier session left the program there
     events = [r for r in ring.records() if r.kind == "compile"
-              and r.name == "compile"]
+              and r.name in ("compile", "cache_load")]
     mine = [r for r in events if "only_compiled_in_test_spans" in (r.value or "")]
     assert len(mine) == 1 and mine[0].step == 41 and mine[0].t1 >= mine[0].t0
     assert ring.tail(4, kind="compile")[-1]["step"] == 41
+
+
+def test_process_span_starts_before_the_origin_and_survives_adopt():
+    """The process's recorder begins with the ``process`` span: from the
+    operating system's start of this process to the recorder's creation."""
+    t0 = telemetry.process_start_ns()
+    assert t0 is not None and t0 < telemetry.recorder()._start_ns
+    # the same start, read twice: the two clocks are read a few us apart
+    assert abs(telemetry.recorder()._process.t0 - t0) < 1_000_000
+    rec = telemetry.SpanRecorder(process_t0_ns=t0)
+    created = rec._start_ns
+    with rec.span("step"):
+        pass
+    rec.adopt("run")                   # what ``Telemetry`` does: a new origin
+    (process,) = rec.records()         # the ring emptied, ``process`` kept
+    assert (process.kind, process.name, process.parent) == (
+        "span", "process", None)
+    assert process.t0 == t0 < process.t1 <= created < rec._start_ns
+    assert rec.goodput()["counts"] == {}             # no goodput bucket
+    event = rec.trace_events()["traceEvents"][0]
+    assert event["name"] == "process" and event["ts"] < 0   # as ``restart``
+    rec.mark_first_step("cold")
+    g = rec.goodput()
+    assert g["process_to_first_step_s"] > g["time_to_first_step_s"] >= 0
+    # a system that gives no start time: no record, and no second key
+    bare = telemetry.SpanRecorder()
+    bare.mark_first_step("cold")
+    assert bare.records() == []
+    assert "process_to_first_step_s" not in bare.goodput()
+    assert "time_to_first_step_s" in bare.goodput()
+
+
+def _compile_records(ring, fun):
+    return [r for r in ring.records() if r.kind == "compile"
+            and fun in (r.value or "")]
+
+
+def test_one_trace_record_for_the_outermost_function_under_the_open_span(ring):
+    """A jitted function that calls jitted functions: jax reports a trace for
+    each, the inner ones inside the outer's interval. The ring has the
+    outermost only, then its ``lower`` and its backend record, each with the
+    open span as parent."""
+    heard = []
+    listener = lambda event, seconds, **kw: heard.append(
+        (event, kw.get("fun_name")))
+    jax.monitoring.register_event_duration_secs_listener(listener)
+
+    @jax.jit
+    def inner_in_test_spans(x):
+        return x * 2 + 1
+
+    @jax.jit
+    def outer_in_test_spans(x):
+        return inner_in_test_spans(x) + inner_in_test_spans(x + 1)
+
+    try:
+        with ring.span("init_state", bucket=None) as span:
+            outer_in_test_spans(np.arange(7.0))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    traced = [name for event, name in heard if event.endswith("trace_duration")]
+    assert "inner_in_test_spans" in traced and "outer_in_test_spans" in traced
+    assert _compile_records(ring, "inner_in_test_spans") == []
+    mine = _compile_records(ring, "outer_in_test_spans")
+    assert [r.name for r in mine][:2] == ["trace", "lower"]
+    assert mine[-1].name in ("compile", "cache_load")
+    assert sum(r.name in ("compile", "cache_load") for r in mine) == 1
+    assert all(r.parent == span.id for r in mine)
+    assert mine[0].value == "outer_in_test_spans"
+    # the stages follow each other: no instant is in two records
+    stages = [r for r in mine if r.name != "cache_retrieval" and r.t1 > r.t0]
+    assert all(a.t1 <= b.t0 + 1_000_000 for a, b in zip(stages, stages[1:]))
+    traces = sorted((r for r in ring.records() if r.name == "trace"),
+                    key=lambda r: r.t0)
+    assert all(a.t1 <= b.t0 for a, b in zip(traces, traces[1:]))
+
+
+def test_backend_record_says_compiled_or_served_by_the_cache(ring, tmp_path):
+    """First call: XLA compiles (``compile``, and a ``cache_miss`` where the
+    cache stored it). After the in-memory caches are dropped the persistent
+    cache serves the same program: ``cache_load`` with the ``cache_retrieval``
+    inside it, both with the function's name."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+
+    def cached_in_test_spans(x):
+        return (x * 5).sum() - 2
+
+    try:
+        jax.jit(cached_in_test_spans)(np.arange(9.0))
+        first = [r.name for r in ring.records() if r.kind == "compile"
+                 and (r.name == "cache_miss"
+                      or "cached_in_test_spans" in (r.value or ""))]
+        ring.clear()
+        jax.clear_caches()
+        jax.jit(cached_in_test_spans)(np.arange(9.0))
+        second = _compile_records(ring, "cached_in_test_spans")
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert first == ["trace", "lower", "cache_miss", "compile"]
+    assert [r.name for r in second] == ["trace", "lower", "cache_retrieval",
+                                        "cache_load"]
+    retrieval, load = second[2:]
+    assert load.t0 <= retrieval.t0 and retrieval.t1 <= load.t1
+    assert retrieval.value == load.value == "jit(cached_in_test_spans)"
+
+
+def test_the_watchdog_dump_shows_backend_records_not_traces(ring):
+    @jax.jit
+    def dumped_in_test_spans(x):
+        return x - 4
+
+    dumped_in_test_spans(np.arange(3.0))
+    names = [r.name for r in ring.records() if r.kind == "compile"]
+    assert "trace" in names and "lower" in names
+    shown = ring.tail(8, kind="compile", names=telemetry.BACKEND_RECORDS)
+    assert shown and {s["name"] for s in shown} <= set(
+        telemetry.BACKEND_RECORDS)
+    assert "dumped_in_test_spans" in shown[-1]["value"]
 
 
 def test_trace_events_keep_their_keys_with_counters_and_threads():
@@ -188,6 +319,49 @@ def test_telemetry_off_still_records_spans_and_writes_nothing(trained):
     assert {"init", "iteration", "input_wait", "loader_wait", "device_put",
             "make_batch", "compile", "dispatch", "metrics_fetch"} <= names
     assert files == []
+
+
+def test_init_is_split_at_its_layers_and_goodput_does_not_see_it(trained):
+    _, records, _ = trained
+    spans = {r.name: r for r in records if r.kind == "span"}
+    init = spans["init"]
+    children = [spans[n] for n in ("build_mesh", "build_model", "build_data",
+                                   "init_state")]
+    assert all(c.parent == init.id for c in children)
+    assert all(a.t1 <= b.t0 for a, b in zip(children, children[1:]))
+    assert init.t0 <= children[0].t0 and children[-1].t1 <= init.t1
+    # the state's init is traced, lowered and compiled (or loaded) under
+    # ``init_state``, by name
+    under = [r for r in records if r.kind == "compile"
+             and r.parent == spans["init_state"].id]
+    assert {"trace", "lower"} <= {r.name for r in under
+                                  if "init_fn" in (r.value or "")}
+    # the first execution of the step is a child of ``compile``
+    wait = spans["first_step_wait"]
+    assert wait.parent == spans["compile"].id and wait.step == 0
+    step = [r for r in records if r.kind == "compile"
+            and r.parent == spans["compile"].id
+            and "train_step" in (r.value or "")]
+    assert [r.name for r in step if r.name != "cache_retrieval"][:2] == [
+        "trace", "lower"]
+    assert sum(r.name == "trace" for r in step) == 1
+    assert all(r.t1 <= wait.t0 for r in step)
+    assert {r.name for r in records if r.kind == "compile"} <= set(
+        telemetry.COMPILE_RECORDS)
+    # goodput counts the buckets it counted: one init, one compile
+    counts = telemetry.recorder().goodput()["counts"]
+    assert not {"build_mesh", "build_model", "build_data", "init_state",
+                "first_step_wait", "process"} & set(counts)
+
+
+def test_an_iteration_that_compiles_nothing_leaves_no_compile_record(trained):
+    _, records, _ = trained
+    iterations = [r for r in records if r.kind == "span"
+                  and r.name == "iteration"]
+    last = iterations[3]
+    assert last.step == 3
+    inside = [r for r in records if last.t0 <= r.t0 and r.t1 <= last.t1]
+    assert inside and not [r for r in inside if r.kind == "compile"]
 
 
 def test_spans_of_one_iteration_share_its_step_and_hang_together(trained):
