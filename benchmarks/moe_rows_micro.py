@@ -6,13 +6,16 @@ the forms it does not, with the bytes the move cannot avoid over its device
 time. ``flash_micro.py`` / ``ssd_micro.py``'s sibling for the expert layers'
 token side; needs the chip; no cell runs it.
 
-Forms: ``program`` (the module's own: choice-major, a ``[T, d]`` slab a
-choice), ``one_gather`` (choice-major, one ``[k, T, d]`` gather),
-``token_major`` (PR 35's: the gathered rows viewed ``[T, k, d]``, the choice
-axis between the rows and the lanes; its ``combine_bwd`` makes ``d_weights``
-from a third ``[T*k, d]`` gather), and ``halves`` (the program's form over the
-source in two column halves: XLA copies a gather's source into VMEM where it
-fits, and SmallThinker's 136 MB ``y_pad`` does not; PERF.md section 6, PR 36).
+Forms: ``program`` (the module's own: choice-major, a ``[T, d / n]`` slab a
+choice and column part, ``n`` by the module's rule on the source's bytes: XLA
+copies a gather's source into VMEM where it fits, and SmallThinker's 136 MB
+``y_pad`` does not; PERF.md section 6, PRs 36 and 40), ``whole`` (the same
+with the source in one part whatever its bytes: PR 36's ``program``),
+``one_gather`` (choice-major, one ``[k, T, d]`` gather), ``token_major`` (PR
+35's: the gathered rows viewed ``[T, k, d]``, the choice axis between the rows
+and the lanes; its ``combine_bwd`` makes ``d_weights`` from a third ``[T*k,
+d]`` gather), and ``halves`` (``whole`` over each half of the columns with no
+order between the halves: what the rule's form was measured against).
 
 One JSON row per (shape, move, form): ``ms`` the device time of the whole
 call, ``gbps`` = ``bytes`` / ``ms`` with ``bytes`` the rows that exist read
@@ -21,12 +24,20 @@ operations (``ssd_micro.device_ms``), ``relayout`` the row arrays that a
 physical ``reshape`` of the compiled text makes, ``vmem`` those placed in VMEM
 (``S(1)``). A shape is ``T x d x k x E x held``; the defaults are the two
 expert cells' and the two with the widths swapped.
+
+``--sweep`` times the combine alone (8,192 tokens, 6 choices, a quarter of
+the pairs present, as SmallThinker's cell) over sources of ``P`` rows on both
+sides of the rule's edge at d = 2560 and 2048, ``whole`` and ``program``: a row
+each with the source's ``bytes``, ``parts`` (the rule's), ``vmem`` (did the
+compiled text place every gathered source in ``S(1)``) and ``ms``: the
+measurement that ``parallel/moe.GATHER_SOURCE_BYTES`` stands on.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import re
 import sys
@@ -77,8 +88,11 @@ def forms(moe):
                  * rows(weights.reshape(-1, 1), row_pair))
         return d_pad.astype(y_pad.dtype), d_weights
 
-    sums = {"program": moe._choice_sum, "one_gather": one_gather,
-            "token_major": token_major}
+    def whole(x_pad, pair_row, weights=None):
+        return moe._choice_sum_in(x_pad, pair_row, weights, 1)
+
+    sums = {"program": moe._choice_sum, "whole": whole,
+            "one_gather": one_gather, "token_major": token_major}
     k_of = lambda w: w.shape[1]
     return {
         "dispatch": {
@@ -88,7 +102,7 @@ def forms(moe):
         "combine": {
             **{name: (lambda a, s=s: s(a.y_pad, a.pair_row, a.weights))
                for name, s in sums.items()},
-            "halves": lambda a: columns(moe._choice_sum)(
+            "halves": lambda a: columns(whole)(
                 a.y_pad, a.pair_row, a.weights)},
         "combine_bwd": {
             "program": lambda a: moe._combine_bwd(
@@ -98,7 +112,7 @@ def forms(moe):
         "dispatch_bwd": {
             **{name: (lambda a, s=s: s(a.d_pad, a.pair_row).astype(
                 a.d_pad.dtype)) for name, s in sums.items()},
-            "halves": lambda a: columns(moe._choice_sum)(
+            "halves": lambda a: columns(whole)(
                 a.d_pad, a.pair_row).astype(a.d_pad.dtype)},
     }
 
@@ -156,10 +170,83 @@ def row_arrays(text, d, least):
     return sorted(relayout), sorted(vmem)
 
 
+def row_gathers(text, least=1 << 22):
+    """``[(scope, rows gathered, source)]`` of a compiled text's gathers of
+    ``least`` elements or more outside every ``while``: ``scope`` the
+    ``op_name`` of the fusion that holds the gather, ``source`` the gathered
+    array's shape and layout as that fusion's caller holds it (``S(1)`` in
+    the layout: placed in VMEM)."""
+    bodies = {m.group(1): body for body in text.split("\n\n")
+              if (m := re.match(r"(?:ENTRY )?(%[\w.-]+) \(", body.strip()))}
+    inside = {}                   # fused computation: [(rows, parameter)]
+    for name, body in bodies.items():
+        number = dict(re.findall(r"(%[\w.-]+) = \S+ parameter\((\d+)\)", body))
+        for rows, dims, source in re.findall(
+                r" = (\w+\[([\d,]+)\])\S* gather\((%[\w.-]+),", body):
+            if len(dims.split(",")) == 2 and source in number and \
+                    math.prod(map(int, dims.split(","))) >= least:
+                inside.setdefault(name, []).append((rows, int(number[source])))
+    found = []
+    for body in bodies.values():
+        held = dict(re.findall(
+            r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\]\S*) ", body, re.M))
+        for operands, callee, scope in re.findall(
+                r" fusion\(([^)]*)\), kind=\w+, calls=(%[\w.-]+), "
+                r"metadata=\{op_name=\"([^\"]*)\"", body):
+            if "/while/" not in scope:
+                operands = re.findall(r"%[\w.-]+", operands)
+                found += [(scope, rows, held.get(operands[index], "?"))
+                          for rows, index in inside.get(callee, [])]
+    return found
+
+
+#: ``--sweep``: {d: the sources' rows}, 72 MiB to 130 MiB; the edge is 112
+#: MiB (22,937.6 rows of 2560, 28,672 of 2048 in bf16).
+SWEEP = {2560: (14720, 18432, 20480, 21504, 22528, 22912, 23040, 23552, 24576,
+                26624),
+         2048: (18432, 24576, 26624, 28160, 28672, 28800, 29696, 30720,
+                33280)}
+
+
+def sweep(moe, device_ms, iters, seed, T=8192, k=6, present=0.25):
+    """One row a (d, P, form): the combine over a ``bf16[P, d]`` source."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    forms = {"whole": lambda *a: moe._choice_sum_in(*a, 1),
+             "program": moe._choice_sum}
+    for d, sizes in SWEEP.items():
+        for P in sizes:
+            pairs = np.full(k * T, P, np.int32)         # P: no padded row
+            live = rng.choice(k * T, int(present * k * T), replace=False)
+            pairs[live] = rng.permutation(P)[:live.size]
+            key = jax.random.split(jax.random.PRNGKey(seed), 2)
+            args = (jax.random.normal(key[0], (P, d), jnp.bfloat16),
+                    jnp.asarray(pairs.reshape(k, T)),
+                    jax.nn.softmax(jax.random.normal(key[1], (T, k))))
+            for form, fn in forms.items():
+                sources = [source for _, _, source in row_gathers(
+                    jax.jit(fn).lower(*args).compile().as_text())]
+                ms, _, top = device_ms(fn, args, iters)
+                print(json.dumps({
+                    "sweep": d, "P": P, "bytes": P * d * 2,
+                    "mib": round(P * d * 2 / 2**20, 2), "form": form,
+                    "parts": moe._source_parts(P, d, 2) if form == "program"
+                    else 1, "vmem": bool(sources) and all(
+                        "S(1)" in source for source in sources),
+                    "ms": round(ms, 4),
+                    "ops": {n: round(v, 4) for n, v in top.items()}}),
+                    flush=True)
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--shapes", default=SHAPES,
                    help="comma-separated T x d x k x E x held")
+    p.add_argument("--sweep", action="store_true",
+                   help="the combine over sources on both sides of the "
+                        "rule's edge, and nothing else")
     p.add_argument("--iters", type=int, default=5)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
@@ -173,6 +260,8 @@ def main():
         sys.exit("moe_rows_micro.py times the moves on the chip; this is "
                  + jax.default_backend())
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.sweep:
+        return sweep(moe, device_ms, args.iters, args.seed)
     for shape in args.shapes.split(","):
         T, d, k, E, held = (int(v) for v in shape.split("x"))
         a = operands(moe, T, d, k, E, held, args.seed)

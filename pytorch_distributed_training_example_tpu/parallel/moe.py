@@ -891,16 +891,56 @@ def _rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
+#: The most bytes of a row-gather's source that XLA copies into VMEM and
+#: gathers from there: a v5e's 128 MiB less the 16 MiB the compiler keeps for
+#: scoped use. Measured (``benchmarks/moe_rows_micro.py --sweep``; PERF.md
+#: section 6, PR 40): the combine over ``bf16[P, 2560]`` reads 0.89 ms at
+#: 111.9 MiB and, at 112.5 MiB, 2.70 ms whole (from HBM) and 1.20 in two parts;
+#: compiled for a described v5e, 22,936 rows are placed and 22,944 are not.
+GATHER_SOURCE_BYTES = 112 * 2**20
+
+
+def _source_parts(rows, d, itemsize):
+    """In how many column parts a ``[rows, d]`` source of row-gathers is asked
+    for: the fewest whose part is whole 128-lane columns of at most
+    ``GATHER_SOURCE_BYTES``; 1 where the whole source is, or no part would
+    be."""
+    for n in range(1, d // 128 + 1):
+        if d % (128 * n) == 0 and rows * (d // n) * itemsize \
+                <= GATHER_SOURCE_BYTES:
+            return n
+    return 1
+
+
 def _choice_sum(x_pad, pair_row, weights=None):
     """``sum_c weights[t, c] * x_pad[pair_row[c, t]]`` as ``[T, d]`` float32 (a
-    choice with no padded row adds nothing; no ``weights``: ones), asked for
-    choice-major: a ``[T, d]`` slab of rows a choice, summed in the order c =
-    0..k-1. With the choice axis between rows and lanes XLA relays the gathered
-    rows out into ``[T, k, d]`` tiles first, wherever k is no multiple of 8."""
-    slabs = (_rows(x_pad, index).astype(jnp.float32) for index in pair_row)
-    if weights is not None:
-        slabs = (slab * w[:, None] for slab, w in zip(slabs, weights.T))
-    return functools.reduce(jnp.add, slabs)
+    choice with no padded row adds nothing; no ``weights``: ones), in as many
+    column parts as ``_source_parts`` says of the source."""
+    return _choice_sum_in(x_pad, pair_row, weights, _source_parts(
+        *x_pad.shape, x_pad.dtype.itemsize))
+
+
+def _choice_sum_in(x_pad, pair_row, weights, n):
+    """``_choice_sum`` over ``n`` column parts of ``x_pad``, their ``[T, d /
+    n]`` sums joined along the columns: the same sum of the same terms in the
+    same order in every element, whatever ``n``. Asked for choice-major: a
+    ``[T, d / n]`` slab of rows a choice, summed in the order c = 0..k-1. With
+    the choice axis between rows and lanes XLA relays the gathered rows out
+    into ``[T, k, d]`` tiles first, wherever k is no multiple of 8. A part is
+    cut only once the part before it is summed (the barrier): XLA else makes
+    all the parts in one fusion, side by side in HBM."""
+    width, sums = x_pad.shape[1] // n, []
+    for lo in range(0, n * width, width):
+        if sums:
+            x_pad, sums[-1] = jax.lax.optimization_barrier((x_pad, sums[-1]))
+        slabs = (_rows(x_pad[:, lo:lo + width], index).astype(jnp.float32)
+                 for index in pair_row)
+        if weights is not None:
+            slabs = (slab * w[:, None] for slab, w in zip(slabs, weights.T))
+        sums.append(functools.reduce(jnp.add, slabs))
+    # (one part: a slice of every column and a join of one array trace to
+    # nothing, and the lowered text is what it was)
+    return jnp.concatenate(sums, axis=1)
 
 
 @jax.custom_vjp
@@ -1209,6 +1249,7 @@ class Held(NamedTuple):
     whole: jax.Array     # 1.0 where the rows went through the layout whole
     bt: int              # the layout's tile rows
     cap: int | None      # the bounded layout's tiles (None: unbounded)
+    parts: int           # the column parts its padded rows are gathered in
 
 
 def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act):
@@ -1254,7 +1295,10 @@ def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act):
         out = _routed_bounded(tokens, route.chosen, route.weights, experts,
                               held_load, first, bt, chunks, act)
         whole = _fits(held_load, bt, cap).astype(jnp.float32)
-    return out, Held(experts, tokens, first, held_load, whole, bt, cap)
+    tiles = -(-T * k // bt) + held      # ``_padded_layout``'s own bound
+    parts = _source_parts(bt * min(tiles, cap or tiles), d,
+                          tokens.dtype.itemsize)
+    return out, Held(experts, tokens, first, held_load, whole, bt, cap, parts)
 
 
 def _sow_telemetry(module, **values):
@@ -1301,8 +1345,9 @@ class SharedExpertMoE(nn.Module):
     (the rows that landed on held experts), ``moe_held_peak`` (the fullest
     held expert's rows over their mean), ``moe_bias_peak`` (largest |b|) and
     ``moe_whole`` (1.0 where the held rows fit the whole layout and the kept
-    residuals serve the backward, 0.0 where the tokens went in parts), each
-    with the enclosing block's name behind a dot.
+    residuals serve the backward, 0.0 where the tokens went in parts) and
+    ``moe_source_parts`` (the column parts the padded rows are gathered back
+    in: ``_source_parts``), each with the enclosing block's name behind a dot.
     """
 
     num_experts: int
@@ -1349,7 +1394,8 @@ class SharedExpertMoE(nn.Module):
         _sow_telemetry(self, moe_held_rows=jnp.sum(rows),
                        moe_held_peak=_held_peak(rows),
                        moe_bias_peak=jnp.max(jnp.abs(bias.value)),
-                       moe_whole=held.whole)
+                       moe_whole=held.whole,
+                       moe_source_parts=jnp.float32(held.parts))
         return out.reshape(B, S, d).astype(self.dtype)
 
 
@@ -1379,12 +1425,12 @@ class HeldExperts(nn.Module):
     buffer: a token none of whose choices is held gets exactly zero.
 
     Sows into ``telemetry`` as :class:`SharedExpertMoE` does:
-    ``moe_held_rows``, ``moe_held_peak``, ``moe_whole``, and
-    ``moe_gate_zero`` (the share of the held rows' gate activations that
-    ``relu`` zeroes: what a kernel that skipped them would have to gain
-    from; NaN where the rows did not fit the layout whole). The last costs
-    the plan and the gate projection once more, and is computed only in a
-    run that collects ``telemetry``."""
+    ``moe_held_rows``, ``moe_held_peak``, ``moe_whole``,
+    ``moe_source_parts``, and ``moe_gate_zero`` (the share of the held rows'
+    gate activations that ``relu`` zeroes: what a kernel that skipped them
+    would have to gain from; NaN where the rows did not fit the layout
+    whole). The last costs the plan and the gate projection once more, and is
+    computed only in a run that collects ``telemetry``."""
     ffn_dim: int
     held_experts: tuple | None = None   # (how many, starting where)
     act: str = "relu"                   # a key of ops.grouped_matmul.GATES
@@ -1397,7 +1443,8 @@ class HeldExperts(nn.Module):
                               self.ffn_dim, self.held_experts, self.act)
         rows = held.load.astype(jnp.float32)
         sown = dict(moe_held_rows=jnp.sum(rows),
-                    moe_held_peak=_held_peak(rows), moe_whole=held.whole)
+                    moe_held_peak=_held_peak(rows), moe_whole=held.whole,
+                    moe_source_parts=jnp.float32(held.parts))
         if self.is_mutable_collection("telemetry"):
             sown["moe_gate_zero"] = _gate_zero_share(route.chosen, held)
         _sow_telemetry(self, **sown)

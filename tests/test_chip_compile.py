@@ -15,6 +15,8 @@ process with the persistent cache off; code that asks
 """
 
 import functools
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 from pytorch_distributed_training_example_tpu.ops import (
     attention as attn, flash_attention as fa, fused_router, grouped_matmul)
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks"))
+import moe_rows_micro  # noqa: E402  (reads a compiled text's row gathers)
 
 BF16 = jnp.bfloat16
 
@@ -456,6 +462,14 @@ def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
     for name in ("flash_fwd_window", "flash_bwd_window_dq", "flash_fwd_online",
                  "grouped_matmul", "grouped_matmul_dw"):
         assert name in text, name
+    # three big moves a layer (the combine, the combine again in the block's
+    # remat, whose norm reads it, and the dispatch's transpose), eight slabs
+    # each, from one 75 MB source a move that VMEM holds
+    slabs = [source for _, rows, source in moe_rows_micro.row_gathers(text)
+             if rows == "bf16[8192,2048]"]
+    assert len(slabs) == 4 * 3 * 8, len(slabs)
+    assert all(source.startswith("bf16[18432,2048]{") and "S(1)" in source
+               for source in slabs), set(slabs)
 
 
 # -- SmallThinker's share (models/smallthinker.py): the window kernels at a
@@ -529,19 +543,24 @@ def test_relu_held_experts_compile_at_published_widths(one_chip, as_tpu):
     assert "bf16[26624,768]" in text
 
 
-@pytest.mark.parametrize("text_of,k,d,P", [
-    pytest.param(_trinity_layer_text, 8, 2048, 18432, id="trinity"),
-    pytest.param(_smallthinker_routine_text, 6, 2560, 26624,
+#: ``(compiled text, k, d, the padded rows P, the column parts of [P, d])``
+EXPERT_TEXTS = pytest.mark.parametrize("text_of,k,d,P,parts", [
+    pytest.param(_trinity_layer_text, 8, 2048, 18432, 1, id="trinity"),
+    pytest.param(_smallthinker_routine_text, 6, 2560, 26624, 2,
                  id="smallthinker")])
+
+
+@EXPERT_TEXTS
 def test_held_experts_moves_of_rows_are_choice_major(one_chip, as_tpu,
-                                                     text_of, k, d, P):
+                                                     text_of, k, d, P, parts):
     """The token-side moves at both expert cells' widths (8,192 tokens): no
     row array is viewed ``[tokens, k, d]``, which at k = 6 is a physical
     ``reshape`` that pads six rows to a tile's sublanes; and on the whole
-    layout's side the backward gathers ``k`` slabs of ``[8192, d]`` for the
-    dispatch's transpose, one float32 ``[P, d]`` for both of the combine's
-    cotangents and ``x_pad`` again: no ``[8192 * k, d]`` gather of ``y_pad``
-    for the weights' gradient, which is made in the padded layout."""
+    layout's side the backward gathers ``k`` slabs of ``[8192, d / parts]`` a
+    column part for the dispatch's transpose, one float32 ``[P, d]`` for both
+    of the combine's cotangents and ``x_pad`` again: no ``[8192 * k, d]``
+    gather of ``y_pad`` for the weights' gradient, which is made in the padded
+    layout."""
     import re
     from collections import Counter
 
@@ -552,10 +571,58 @@ def test_held_experts_moves_of_rows_are_choice_major(one_chip, as_tpu,
             r" = (\w+\[[\d,]+\])\S* fusion\([^\n]*kind=kCustom"
             r"[^\n]*op_name=\"([^\"]*/gather)\"", text)
         if "transpose(" in m.group(2) and "/while/" not in m.group(2)
-        and m.group(1).endswith(",%d]" % d))
-    assert gathers == {("bf16[8192,%d]" % d, "moe_dispatch"): k,
-                       ("f32[%d,%d]" % (P, d), "moe_combine"): 1,
-                       ("bf16[%d,%d]" % (P, d), "moe_dispatch"): 1}, gathers
+        and m.group(1).endswith((",%d]" % d, ",%d]" % (d // parts))))
+    assert gathers == {
+        ("bf16[8192,%d]" % (d // parts), "moe_dispatch"): k * parts,
+        ("f32[%d,%d]" % (P, d), "moe_combine"): 1,
+        ("bf16[%d,%d]" % (P, d), "moe_dispatch"): 1}, gathers
+
+
+@EXPERT_TEXTS
+def test_held_experts_gather_from_sources_vmem_can_hold(
+        one_chip, as_tpu, text_of, k, d, P, parts):
+    """The whole layout's side gathers its slabs of ``[8192, d / parts]`` from
+    sources of ``[P, d / parts]`` under ``GATHER_SOURCE_BYTES``: Trinity's
+    ``bf16[18432, 2048]`` (75 MB) stays one source a move, SmallThinker's
+    ``bf16[26624, 2560]`` (136 MB, over a v5e's VMEM) is read in two column
+    parts and whole by no gather. (These texts are the layer's gradients
+    alone, where nothing reads the forward's combine: the dispatch's
+    transpose is the move they hold; the steps' own texts are read by the slow
+    tests.)"""
+    from collections import Counter
+
+    slabs = Counter(
+        source.split("{")[0]
+        for _, rows, source in moe_rows_micro.row_gathers(text_of(one_chip))
+        if rows == "bf16[8192,%d]" % (d // parts))
+    assert slabs == {"bf16[%d,%d]" % (P, d // parts): k * parts}, slabs
+
+
+@pytest.mark.parametrize("d,P,parts", [(2048, 18432, 1), (2560, 26624, 2),
+                                       (2560, 22912, 1), (2560, 23040, 2)])
+def test_choice_sum_source_is_placed_in_vmem(one_chip, d, P, parts):
+    """The combine alone at each expert cell's padded rows and on each side of
+    the rule's edge (112 MiB: 22,936 rows of 2560 in bf16), its source made by
+    an operation as in the step: every slab is gathered from a source that the
+    compiler placed in VMEM (``S(1)``), which is what the column parts are
+    for; asked for whole, the 136 MB source is gathered from HBM."""
+    from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
+
+    args = (_sds((P, d), one_chip), _sds((P, d), one_chip),
+            _sds((6, 8192), one_chip, jnp.int32),
+            _sds((8192, 6), one_chip, jnp.float32))
+    sources = lambda fn: [
+        source for _, _, source in moe_rows_micro.row_gathers(
+            jax.jit(fn).lower(*args).compile().as_text())]
+    found = sources(lambda a, b, pair_row, w: moe_lib._choice_sum(
+        a + b, pair_row, w))
+    assert len(found) == 6 * parts, found
+    assert all(source.startswith("bf16[%d,%d]{" % (P, d // parts))
+               and "S(1)" in source for source in found), found
+    if parts > 1:
+        whole = sources(lambda a, b, pair_row, w: moe_lib._choice_sum_in(
+            a + b, pair_row, w, 1))
+        assert len(whole) == 6 and not any("S(1)" in s for s in whole), whole
 
 
 @pytest.mark.slow  # 50 s of the TPU compiler on every core, as Trinity's
@@ -572,11 +639,23 @@ def test_smallthinker_share_step_fits_the_chip(one_chip, as_tpu):
     assert mem.argument_size_in_bytes == pytest.approx(656_529_920 * 12,
                                                        rel=1e-3)
     assert held < 16.0e9, held
+    text = compiled.as_text()
     calls = Counter(m.group(1) for m in re.finditer(
-        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", compiled.as_text()))
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in ("flash_fwd_window", "flash_bwd_window_dq",
                  "flash_bwd_window_dkv"):
         assert calls[name] == 3, calls       # three window layers
     for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
         assert calls[name] == 1, calls       # one full layer
     assert calls["grouped_matmul"] and calls["grouped_matmul_dw"], calls
+    # two big moves a layer (the combine and the dispatch's transpose: nothing
+    # in the block reads the expert layer's output, so the remat's combine is
+    # dropped), six slabs a column part, each from a 68 MB part in VMEM; no
+    # gather reads the 136 MB ``[26624, 2560]`` whole
+    gathers = [(rows, source) for scope, rows, source in
+               moe_rows_micro.row_gathers(text) if "/moe/" in scope]
+    assert not [g for g in gathers if g[1].startswith("bf16[26624,2560]")]
+    slabs = [source for rows, source in gathers if rows == "bf16[8192,1280]"]
+    assert len(slabs) == 4 * 2 * 2 * 6, len(slabs)
+    assert all(source.startswith("bf16[26624,1280]{") and "S(1)" in source
+               for source in slabs), set(slabs)

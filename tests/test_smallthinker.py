@@ -262,7 +262,7 @@ def test_a_token_with_no_held_choice_gets_exactly_zero():
         np.asarray(ref).reshape(rows.shape)[~lands], 0.0)
 
 
-def test_collapsed_routing_takes_the_parts_and_drops_nothing():
+def test_collapsed_routing_takes_the_parts_and_drops_nothing(monkeypatch):
     """Every token on the two held experts of eight (and a third elsewhere):
     four times the rows a level router sends, past the whole layout's bound
     (``chunks`` is 2 here, as at the published sizes), so the layer takes the
@@ -306,6 +306,7 @@ def test_collapsed_routing_takes_the_parts_and_drops_nothing():
                                   mutable=["telemetry"])
             sown = {k: float(v[0]) for k, v in sown["telemetry"].items()}
             assert sown["moe_whole"] == whole
+            assert sown["moe_source_parts"] == 1.0     # kilobytes
             assert sown["moe_held_rows"] == float(jnp.sum(
                 (chosen >= 2) & (chosen < 4)))
             if whole:     # the gate's zeros are counted on the whole layout
@@ -320,6 +321,15 @@ def test_collapsed_routing_takes_the_parts_and_drops_nothing():
     text = str(jax.make_jaxpr(lambda p: layer.apply(
         {"params": p}, y, plan(spread)))(params))
     assert "cond" in text     # whole where it fits, in parts where not
+    # the counter says what the rule said, and the parts change no bit
+    for chosen in (collapsed, spread):
+        run = lambda: layer.apply({"params": params}, y, plan(chosen),
+                                  mutable=["telemetry"])
+        with monkeypatch.context() as patch:
+            patch.setattr(moe_lib, "_source_parts", lambda *shape: 2)
+            out, sown = run()
+        assert float(sown["telemetry"]["moe_source_parts"][0]) == 2.0
+        np.testing.assert_array_equal(out, run()[0])
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
@@ -375,16 +385,23 @@ def test_bounded_backward_is_plain_ad_of_the_routine(act, routing, remat):
                 g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
 
 
+@pytest.mark.parametrize("source_parts", [1, 2],
+                         ids=["whole_source", "two_parts"])
 @pytest.mark.parametrize("k", [6, 8])
 @pytest.mark.parametrize("routing", ["ragged", "absent", "collapsed"])
-def test_moves_of_rows_transpose_as_their_dense_sums(routing, k):
+def test_moves_of_rows_transpose_as_their_dense_sums(routing, k, source_parts,
+                                                     monkeypatch):
     """The three token-side moves and their hand-written transposes against
     ``jax.vjp`` of the same sums stated as one-hot float32 matrices (no
     ``take`` in them), over the choice-major plan ``_plan`` hands out: with
     every expert held (ragged tiles, no pair absent), with a quarter held and
     every token's first choice on another chip (an absent pair in every
     token), and with routing collapsed onto the held half, where ``_in_parts``
-    repeats the moves over parts of the tokens."""
+    repeats the moves over parts of the tokens; each with the padded rows
+    gathered back whole and in two column parts (as the rule asks of a source
+    wider than VMEM: kilobytes here, so the rule is stood in for)."""
+    monkeypatch.setattr(moe_lib, "_source_parts",
+                        lambda *shape: source_parts)
     T, d, f, E, bt = 48, 32, 16, 16, 8
     held, first = {"ragged": (E, 0), "absent": (4, 4),
                    "collapsed": (8, 4)}[routing]
@@ -457,6 +474,86 @@ def test_moves_of_rows_transpose_as_their_dense_sums(routing, k):
             y_pad, weights_)
         agree(got_out, want_out)
         agree(got(d_out), want(d_out))
+
+
+@pytest.mark.parametrize("rows,d,itemsize,parts", [
+    pytest.param(18432, 2048, 2, 1, id="trinity_y_pad_75MB"),
+    pytest.param(8192, 2560, 4, 1, id="float32_d_out_84MB"),
+    pytest.param(8192, 2560, 2, 1, id="tokens_42MB"),
+    pytest.param(22936, 2560, 2, 1, id="the_edge_112MiB"),
+    pytest.param(22944, 2560, 2, 2, id="a_tile_past_the_edge"),
+    pytest.param(26624, 2560, 2, 2, id="smallthinker_y_pad_136MB"),
+    pytest.param(53248, 2560, 2, 4, id="twice_that_no_third_of_2560"),
+    pytest.param(1 << 20, 200, 2, 1, id="no_128_lane_part"),
+    pytest.param(1 << 22, 256, 2, 1, id="no_part_small_enough")])
+def test_source_parts_rule(rows, d, itemsize, parts):
+    """The fewest column parts of whole 128-lane columns whose bytes XLA places
+    in VMEM, from the source's shape and dtype alone; 1 where no such part
+    exists."""
+    assert moe_lib._source_parts(rows, d, itemsize) == parts
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "plain"])
+def test_choice_sum_in_parts_is_the_whole_sum_to_the_bit(weighted, n):
+    """``_choice_sum_in`` over 2 and 4 column parts against one part, on a
+    ragged plan with an absent pair in every token: every element is the same
+    sum of the same terms in the same order, so not one bit differs, eagerly
+    and under ``jit`` (each against its own: a compiler may contract a
+    product and a sum, in every part alike)."""
+    T, d, E, k, bt = 48, 512, 16, 6, 8
+    keys = jax.random.split(jax.random.key(13), 3)
+    scores = jax.random.uniform(keys[0], (T, E)).at[:, 0].add(5.0)
+    _, chosen = jax.lax.top_k(scores, k)
+    _, pair_row, row_pair = moe_lib._plan(chosen, 4, 4, bt, None, None)
+    P = row_pair.shape[0]
+    absent = np.asarray(pair_row == P)
+    assert absent.any(axis=0).all() and not absent.all()
+    x_pad = jax.random.normal(keys[1], (P, d)).astype(jnp.bfloat16)
+    w = jax.random.uniform(keys[2], (T, k), minval=0.2) if weighted else None
+    for form in (moe_lib._choice_sum_in,
+                 jax.jit(moe_lib._choice_sum_in, static_argnums=3)):
+        whole = form(x_pad, pair_row, w, 1)
+        assert whole.shape == (T, d) and whole.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(whole))) > 1.0
+        np.testing.assert_array_equal(form(x_pad, pair_row, w, n), whole)
+    # the public form asks the rule: kilobytes, one part, no barrier
+    text = str(jax.make_jaxpr(moe_lib._choice_sum)(x_pad, pair_row, w))
+    assert "optimization_barrier" not in text and "concatenate" not in text
+    text = str(jax.make_jaxpr(
+        lambda *a: moe_lib._choice_sum_in(*a, n))(x_pad, pair_row, w))
+    assert text.count("optimization_barrier") == n - 1
+
+
+@pytest.mark.parametrize("preset,source,parts", [
+    ("smallthinker_21b_share", (26624, 2560, 2), 2),
+    ("trinity_mini_share", (18432, 2048, 2), 1)])
+def test_source_parts_at_the_cells_widths(preset, source, parts, monkeypatch):
+    """What each expert cell's step asks the rule (one sequence of 8,192 in
+    bf16, traced on shapes): SmallThinker's share ``bf16[26624, 2560]``, 136
+    MB, in 2 parts; Trinity's ``bf16[18432, 2048]``, 75 MB, in 1; four blocks
+    each sow ``moe_source_parts``, worked out from the rows that every source
+    ``_choice_sum`` sees has."""
+    from pytorch_distributed_training_example_tpu.core import (
+        trainer as trainer_lib)
+
+    asked, rule = {}, moe_lib._source_parts
+    monkeypatch.setattr(moe_lib, "_source_parts", lambda *shape: (
+        asked.setdefault(shape, rule(*shape))))
+    bundle = trainer_lib.build_model(from_preset(
+        preset, global_batch_size=1, seq_len=8192))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    shapes = jax.eval_shape(lambda t: bundle.module.init(
+        jax.random.key(0), t, train=False), tokens)
+    names = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_leaves_with_path(shapes["telemetry"])]
+    assert sum("moe_source_parts.block_" in name for name in names) == 4
+    asked.clear()
+    jax.eval_shape(lambda v, t: bundle.module.apply(
+        v, t, train=False, mutable=["telemetry"]), shapes, tokens)
+    # one [P, d] of bf16 for the counter and for every move of every block
+    assert asked == {source: parts}, asked
 
 
 def test_the_gate_is_the_one_asked_for():
