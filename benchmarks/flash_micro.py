@@ -288,7 +288,22 @@ def main():
             if stock_fa is not None:
                 impls.append(("stock_jax_pallas", stock, (qh, kh, vh), {}))
         for name, fn, (qi, ki, vi), tags in impls:
-            ms_f = timed(fn, qi, ki, vi)
+            def timed_or_refused(tag, fn_one):
+                """ms, or None with a row saying so where a sweep's block
+                pair does not compile."""
+                try:
+                    return timed(fn_one, qi, ki, vi)
+                except Exception as e:  # noqa: BLE001 - recorded, not hidden
+                    if not args.block_sweep:
+                        raise
+                    rows.append({"impl": name, "pass": tag, "B": B, "H": H,
+                                 "S": S, "D": D, **tags,
+                                 "refused": str(e).splitlines()[0][:160]})
+                    print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
+
+            ms_f = timed_or_refused("fwd", fn)
+            if ms_f is None:
+                continue
 
             def grad_step(qq, k, v, fn=fn):
                 # All three grads consumed: taking only dq lets XLA DCE the
@@ -299,10 +314,12 @@ def main():
                     argnums=(0, 1, 2))(qq, k, v)
                 return (dq + dk + dv).astype(qq.dtype)
 
-            ms_b = timed(grad_step, qi, ki, vi)
+            ms_b = timed_or_refused("fwd+bwd", grad_step)
 
             for tag, ms, bwd in (("fwd", ms_f, False),
                                  ("fwd+bwd", ms_b, True)):
+                if ms is None:
+                    continue
                 fl = attn_flops(B, H, S, D, bwd=bwd)
                 tf = fl / (ms / 1e3) / 1e12
                 rows.append({"impl": name, "pass": tag, "B": B, "H": H,

@@ -19,7 +19,11 @@ routed ahead of attention by a softmax over the chosen, under GQA that
 alternates a position-free full layer with window layers:
 ``smallthinker_21b`` at its published sizes, ``smallthinker_21b_share`` one
 chip's share of it, ``smallthinker_tiny`` for tests; training only,
-``dp``/``fsdp`` only).
+``dp``/``fsdp`` only) and the ``glm_moe_lite`` family (latent attention over
+a sigmoid-routed mixture with a shared expert, and a multi-token-prediction
+module whose loss rides the ``losses`` collection: ``glm47_flash`` at its
+published sizes, ``glm47_flash_share`` one chip's share of it,
+``glm_moe_lite_tiny`` for tests; training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -317,7 +321,8 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
 
 def _held_experts_family(name, make):
     """Registry builder for a family whose expert layers are told which
-    experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``):
+    experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``,
+    ``glm_moe_lite``):
     ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
     Granite hybrid: the expert layer has no exchange, and there is no
     tensor-parallel rule table."""
@@ -363,6 +368,17 @@ _REGISTRY["smallthinker_21b_share"] = _held_experts_family(
     "smallthinker", lambda m, **kw: m.chip_share(m.smallthinker_21b(**kw)))
 _REGISTRY["smallthinker_tiny"] = _held_experts_family(
     "smallthinker", lambda m, **kw: m.smallthinker_tiny(**kw))
+
+# The published GLM-4.7-Flash; one chip's share of it (an eighth of every
+# layer's routed experts and of the vocabulary, the leading dense layer, four
+# expert layers and the MTP layer: what the one-chip benchmark cell trains);
+# and a toy for the tests.
+_REGISTRY["glm47_flash"] = _held_experts_family(
+    "glm_moe_lite", lambda m, **kw: m.glm47_flash(**kw))
+_REGISTRY["glm47_flash_share"] = _held_experts_family(
+    "glm_moe_lite", lambda m, **kw: m.chip_share(m.glm47_flash(**kw)))
+_REGISTRY["glm_moe_lite_tiny"] = _held_experts_family(
+    "glm_moe_lite", lambda m, **kw: m.glm_moe_lite_tiny(**kw))
 
 
 @register("resnet_micro")

@@ -52,14 +52,60 @@ ONLINE_BLOCK_TABLE: dict[tuple[bool, int, int], tuple[int, int]] = {
     # peak (r4, a machine that is gone; records in git at 6739a2e) — the
     # default IS the tuned choice.
     (False, 4096, 128): (1024, 1024),
+    # D=256, S=8192 (GLM-4.7-Flash's expanded latent attention, B1 H20, bf16;
+    # PERF.md section 6, PR 41, the sweep's rows): forward 5.98 ms at
+    # 1024x1024 against 6.67 at (1024, 512), 7.04 at (512, 1024), 8.32 at
+    # 512x512: the default stays. Backward (the row's fwd+bwd less its fwd)
+    # 18.98 ms at (512, 1024) against 19.96 at (1024, 512), the rule's choice,
+    # and 20.79 at 512x512; (1024, 1024) does not compile.
+    (True, 8192, 256): (512, 1024),
 }
 
 
-def _online_blocks(bwd: bool, s: int, d: int, block_q: int, block_kv: int):
-    """Resolve the online kernels' block sizes through ONLINE_BLOCK_TABLE."""
+def _online_held(bwd: bool, block_q: int, block_kv: int, d: int,
+                 itemsize: int):
+    """Bytes of ``[block, D]`` rows an online kernel holds in VMEM at a grid
+    step: its operand and output blocks, which the pipeline double-buffers,
+    and its float32 accumulators. ``flash_fwd_online`` reads q, k, v, writes
+    o and accumulates o; ``flash_bwd_dq`` reads q, dO, k, v, writes dq and
+    accumulates dq; ``flash_bwd_dkv`` reads the same, writes dk and dv and
+    accumulates both (the backward's count is the fuller of its two). The
+    ``[block_q, block_kv]`` score tiles beside them do not grow with D or the
+    dtype and are not counted."""
+    if not bwd:
+        return d * (itemsize * 4 * (block_q + block_kv) + 4 * block_q)
+    dq = d * (itemsize * (6 * block_q + 4 * block_kv) + 4 * block_q)
+    dkv = d * (itemsize * (4 * block_q + 8 * block_kv) + 8 * block_kv)
+    return max(dq, dkv)
+
+
+#: The most the v5e compiler has taken of such rows beside a default score
+#: tile under its 16 MiB of scoped VMEM: the backward's at the defaults and
+#: D = 128 in float32 (7 MiB; tests/test_chip_compile.py compiles it). At
+#: D = 256 the defaults hold 8 MiB in the bf16 backward, which the compiler
+#: counts 36 KB over at B1 H20 S8192, and 9 MiB in the float32 forward, which
+#: it refuses too (PERF.md section 6, PR 41).
+ONLINE_HELD_MAX = _online_held(True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV, 128, 4)
+
+
+def _online_blocks(bwd: bool, s: int, d: int, block_q: int, block_kv: int,
+                   itemsize: int = 2):
+    """The online kernels' block sizes: the caller's where it chose them, a
+    row of ONLINE_BLOCK_TABLE where the shape was measured, else the
+    defaults, halved (the larger of the two, the kv block first) until the
+    rows the kernel holds fit ``ONLINE_HELD_MAX``. At D <= 128 that halves
+    nothing."""
     if (block_q, block_kv) != (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV):
         return block_q, block_kv
-    return ONLINE_BLOCK_TABLE.get((bwd, s, d), (block_q, block_kv))
+    if (bwd, s, d) in ONLINE_BLOCK_TABLE:
+        return ONLINE_BLOCK_TABLE[bwd, s, d]
+    while min(block_q, block_kv) > 128 and _online_held(
+            bwd, block_q, block_kv, d, itemsize) > ONLINE_HELD_MAX:
+        if block_kv >= block_q:
+            block_kv //= 2
+        else:
+            block_q //= 2
+    return block_q, block_kv
 
 
 def _fit_block(s: int, requested: int) -> int:
@@ -1463,7 +1509,8 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
                             if kv_len is not None else ""))
     if plan is not None:
         return _oneshot_fwd(q, k, v, causal=causal, plan=plan, kv_len=kv_len)
-    block_q, block_kv = _online_blocks(False, Sq, D, block_q, block_kv)
+    block_q, block_kv = _online_blocks(False, Sq, D, block_q, block_kv,
+                                       q.dtype.itemsize)
     return _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                       block_kv=block_kv)
 
@@ -1532,7 +1579,8 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
                                      plan=splan)
         else:
             block_q, block_kv = _online_blocks(True, q.shape[1], q.shape[3],
-                                               block_q, block_kv)
+                                               block_q, block_kv,
+                                               q.dtype.itemsize)
             dq, dk, dv = _flash_bwd(q, ke, ve, o, lse, g, causal=causal,
                                     block_q=block_q, block_kv=block_kv)
     return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)

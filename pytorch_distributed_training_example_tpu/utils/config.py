@@ -283,6 +283,23 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # GLM-4.7-Flash (models/glm_moe_lite.py) whole, and one chip's share of
+    # it (an eighth of each layer's 64 experts and of the vocabulary, the
+    # dense layer, four expert layers and the MTP layer): latent attention
+    # over sigmoid-routed experts and a second prediction depth, one 8k
+    # sequence a chip per micro-step
+    "glm47_flash": dict(
+        model="glm47_flash", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=8, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "glm47_flash_share": dict(
+        model="glm47_flash_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -95,6 +95,8 @@ def _grads(fn):
     (1, 4096, 8, 2, 128),      # largest S the streaming backward admits
     (1, 8192, 8, 2, 128),      # llama3_8b's S: refused there -> online bwd
     (1, 4096, 32, 8, 64),      # granite-4.0-h-micro's attention layer
+    (1, 8192, 20, 20, 256),    # GLM-4.7-Flash's expanded latent attention:
+    (1, 4096, 20, 20, 256),    # the backward's blocks follow the head width
 ])
 def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
     q = _sds((B, S, H, D), one_chip, dtype)
@@ -123,6 +125,28 @@ def test_flash_fwd_bwd_compiles(one_chip, B, S, H, Hkv, D, dtype):
         # The v5e compiler counts the streaming backward over its 16 MB of
         # scoped VMEM here; the planner must not admit it.
         assert fa._stream_bwd_plan(H, S, S, D) is None
+    if D == 256:
+        # no causal, one-shot or streaming plan at this width: the online
+        # three, at the blocks the rule gives (at the defaults the compiler
+        # counts flash_bwd_dkv 36 KB over its scoped VMEM at S=8192 in bf16,
+        # flash_bwd_dq 6 MB over in float32 at S=4096, and refuses the
+        # float32 forward at S=8192)
+        assert all(name in text for name in (
+            "flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"))
+        assert "flash_bwd_oneshot" not in text
+        item = jnp.dtype(dtype).itemsize
+        want = ([(1024, 1024), (1024, 512)] if dtype == BF16
+                else [(1024, 512), (512, 512)])
+        if S == 8192:   # the backward's row of the table, read on the chip
+            want[1] = (512, 1024)
+            assert fa.ONLINE_BLOCK_TABLE[True, 8192, 256] == want[1]
+        assert [fa._online_blocks(bwd, S, D, 1024, 1024, item)
+                for bwd in (False, True)] == want
+    else:
+        # the four accepted cells' widths: the plans are what they were
+        for bwd in (False, True):
+            assert fa._online_blocks(bwd, S, D, 1024, 1024,
+                                     jnp.dtype(dtype).itemsize) == (1024, 1024)
 
 
 def _entry_instructions(text):
@@ -324,14 +348,17 @@ def test_window_flash_compiles_at_published_widths(one_chip):
     assert "flash_fwd_online" in full and "flash_fwd_window" not in full
 
 
-def _held_experts_layer(one_chip):
-    """``(layer, params, batch_stats, x)``: 16 held experts of 128, 8 a
-    token, 8,192 tokens of 2048, experts of 1024, as shapes on the chip."""
+def _held_experts_layer(one_chip, num_experts=128, ffn_dim=1024, top_k=8,
+                        held=16, route_scale=2.826):
+    """``(layer, params, batch_stats, x)``: by default Trinity's 16 held
+    experts of 128, 8 a token, 8,192 tokens of 2048, experts of 1024, as
+    shapes on the chip."""
     from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
 
     layer = moe_lib.SharedExpertMoE(
-        num_experts=128, ffn_dim=1024, top_k=8, held_experts=(16, 0),
-        shared_ffn_dim=1024, route_scale=2.826, balance_coeff=0.001,
+        num_experts=num_experts, ffn_dim=ffn_dim, top_k=top_k,
+        held_experts=(held, 0), shared_ffn_dim=ffn_dim,
+        route_scale=route_scale, balance_coeff=0.001,
         dtype=BF16, param_dtype=jnp.float32)
     shapes = jax.eval_shape(lambda: layer.init(
         jax.random.key(0), jnp.zeros((1, 8192, 2048), BF16), train=False))
@@ -341,18 +368,20 @@ def _held_experts_layer(one_chip):
             _sds((1, 8192, 2048), one_chip))
 
 
-@functools.cache
-def _trinity_layer_text(one_chip):
-    """The compiled text of that layer's gradients (compiled once for the
-    tests that read it)."""
-    layer, *args = _held_experts_layer(one_chip)
-
+def _layer_grads_text(layer, *args):
+    """The compiled text of a held experts' layer's gradients."""
     def grads(params, stats, x):
         return jax.grad(lambda p, x: layer.apply(
             {"params": p, "batch_stats": stats}, x, train=False).astype(
                 jnp.float32).sum(), argnums=(0, 1))(params, x)
 
     return _compiled_text(grads, *args)
+
+
+@functools.cache
+def _trinity_layer_text(one_chip):
+    """Trinity's layer (compiled once for the tests that read it)."""
+    return _layer_grads_text(*_held_experts_layer(one_chip))
 
 
 def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
@@ -404,6 +433,19 @@ def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
     assert crossing and not any("f32[18432,1024]" in c for c in crossing)
     assert any("bf16[18432,1024]" in c for c in crossing)   # gate and up
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
+
+
+def test_held_experts_layer_compiles_at_glm_widths(one_chip, as_tpu):
+    """GLM-4.7-Flash's expert layer: 8 held experts of 64, 4 a token, 8,192
+    tokens of 2048, experts of 1536 (six column blocks of 256), a shared
+    expert of 1536; forward and backward, the bounded layout's ``cond``."""
+    text = _layer_grads_text(*_held_experts_layer(
+        one_chip, num_experts=64, ffn_dim=1536, top_k=4, held=8,
+        route_scale=1.8))
+    assert "grouped_matmul_dw" in text and "conditional" in text
+    # the bounded layout: 8,192 x 4 pairs, an eighth of them expected here;
+    # no layout of the worst case's 32,768 rows and more
+    assert "bf16[32768,2048]" not in text and "bf16[33792,2048]" not in text
 
 
 def _share_step(preset, one_chip):
@@ -659,3 +701,36 @@ def test_smallthinker_share_step_fits_the_chip(one_chip, as_tpu):
     assert len(slabs) == 4 * 2 * 2 * 6, len(slabs)
     assert all(source.startswith("bf16[26624,1280]{") and "S(1)" in source
                for source in slabs), set(slabs)
+
+
+# -- GLM-4.7-Flash's share (models/glm_moe_lite.py): latent attention at 20
+# -- heads of 256 in six blocks, a second depth's head, and the whole step
+
+
+@pytest.mark.slow  # 80 s of the TPU compiler on every core, as Trinity's
+def test_glm47_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``glm47_flash_share`` at 1 x 8192, bf16,
+    per-block remat, AdamW) compiles for a described v5e under the chip's
+    memory: 15.56 GB (PERF.md has the chip's own reading); six blocks' latent
+    attention is six calls of each online kernel (under the blocks' remat
+    each forward stays one call), and the five expert layers' grouped
+    matmuls."""
+    import re
+    from collections import Counter
+
+    compiled, mem, held = _share_step("glm47_flash_share", one_chip)
+    assert mem.argument_size_in_bytes == pytest.approx(706_518_528 * 12,
+                                                       rel=1e-3)
+    assert held < 16.0e9, held
+    text = compiled.as_text()
+    calls = Counter(m.group(1) for m in re.finditer(
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
+    for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert calls[name] == 6, calls    # dense, four expert, the module's
+    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 30, calls
+    assert not [name for name in calls if name.startswith("flash_")
+                and name not in ("flash_fwd_online", "flash_bwd_dq",
+                                 "flash_bwd_dkv")], calls
+    for scope in ("mla_q", "mla_kv", "mla_rope", "mla_out", "mtp_merge",
+                  "mtp/head_loss"):
+        assert f"/{scope}/" in text, scope
