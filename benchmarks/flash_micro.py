@@ -17,6 +17,15 @@ the causal kernels at the planner's plan or at each ``--plans`` entry.
 around the kernels, forward and backward in one program: the layout between
 the two is what it reads. Run from another checkout's root (``cd _parent &&
 python <this file>``) both modes import that checkout's package.
+
+``--kernels`` prints beside each online kernel's ms its ``flash_schedule``
+record: the schedule's static counts a head (grid steps, blocks computed,
+masked and fetched, sub-tiles left out, pairs computed over pairs the mask
+leaves). ``--schedule-parts`` times the causal schedule's three parts one at a
+time at the blocks dispatch picks (no step and no copy for a block above the
+diagonal; no mask inside a block below it; a crossed block in sub-tiles), each
+beside the whole rectangle under a mask everywhere ("parent") and all three
+together, and says whether each variant's results are "parent"'s bitwise.
 """
 
 from __future__ import annotations
@@ -71,12 +80,90 @@ def device_ms(fn, args, reps):
     return module_ns / reps / 1e6, took
 
 
+def schedules_since(mark):
+    """{kernel: its ``flash_schedule`` record's counts} of the calls traced
+    since the recorder held ``mark`` records."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+
+    return {r.value["kernel"]: r.value
+            for r in telemetry.recorder().records()[mark:]
+            if r.name == "flash_schedule"}
+
+
+#: ``--schedule-parts``: ``online_schedule``'s switches a variant. "parent" is
+#: the schedule before the causal one: every block of the rectangle a step and
+#: a copy, every computed block masked by position.
+SCHEDULE_PARTS = {
+    "parent": dict(walk=False, split=False, sub=0),
+    "walk": dict(split=False, sub=0),
+    "split": dict(walk=False, sub=0),
+    "sub": dict(walk=False, split=False),
+    "all": dict(),
+}
+
+
+def parts_rows(fa, shapes, reps, subs):
+    """One row per (shape, variant): the three online kernels' device ms
+    under that variant of the schedule, its counts, and whether its results
+    are the "parent" variant's bit for bit. ``subs``: further sub-tile sides
+    to time the whole schedule at."""
+    import jax
+    import jax.numpy as jnp
+
+    variants = dict(SCHEDULE_PARTS)
+    variants.update({"all_sub%d" % s: dict(sub=s) for s in subs})
+    rows = []
+    for (B, H, S, D) in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, g = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
+                      for key in ks)
+        fwd_blocks, bwd_blocks = (
+            fa._online_blocks(bwd, S, D, fa.DEFAULT_BLOCK_Q,
+                              fa.DEFAULT_BLOCK_KV) for bwd in (False, True))
+        o, lse = jax.jit(lambda q, k, v: fa._flash_fwd(
+            q, k, v, causal=True, block_q=fwd_blocks[0],
+            block_kv=fwd_blocks[1]))(q, k, v)
+        want = None
+        for name, parts in variants.items():
+            def both(q, k, v, o, lse, g, parts=parts):
+                return (*fa._flash_fwd(q, k, v, causal=True,
+                                       block_q=fwd_blocks[0],
+                                       block_kv=fwd_blocks[1], **parts),
+                        *fa._flash_bwd(q, k, v, o, lse, g, causal=True,
+                                       block_q=bwd_blocks[0],
+                                       block_kv=bwd_blocks[1], **parts))
+
+            row = {"parts": name, "B": B, "H": H, "S": S, "D": D}
+            try:
+                _, took = device_ms(both, (q, k, v, o, lse, g), reps)
+                got = jax.jit(both)(q, k, v, o, lse, g)
+            except Exception as e:  # a variant the compiler refuses
+                row["error"] = str(e).strip().splitlines()[-1][-300:]
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+                continue
+            want = got if want is None else want
+            row["kernels"] = {n: round(ms, 4) for n, ms in took.items()}
+            row["ms"] = round(sum(took.values()), 4)
+            row["bitwise"] = {n: bool(jnp.array_equal(a, b)) for n, a, b in
+                              zip(("o", "lse", "dq", "dk", "dv"), got, want)}
+            row["schedule"] = {
+                kernel: fa.online_schedule(kernel, True, S, S, *blocks,
+                                           **parts).record(D)
+                for kernel, blocks in zip(
+                    fa.ONLINE_KERNELS, (fwd_blocks, bwd_blocks, bwd_blocks))}
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    return rows
+
+
 def kernel_rows(fa, shapes, plans, reps):
     """One row per (shape, impl, direction): the flash kernels that ran and
     their device time. ``plans``: (G, T) pairs for the causal kernels; empty
     means the planner's own choice per direction."""
     import jax
     import jax.numpy as jnp
+    from pytorch_distributed_training_example_tpu.utils import telemetry
 
     blocks = (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_KV)
     rows = []
@@ -94,7 +181,8 @@ def kernel_rows(fa, shapes, plans, reps):
                               q, k, v, True, *blocks, impl, None)))
             cases.append((impl, "bwd", bwd_args,
                           lambda q, k, v, o, lse, g, impl=impl: fa._vjp_bwd(
-                              True, *blocks, impl, None, (q, k, v, o, lse), g)))
+                              True, *blocks, impl, None, None,
+                              (q, k, v, o, lse), g)))
         fwd_plan = fa._causal_plan(H, S, D)
         for bwd in (False, True):
             own = fa._causal_plan(H, S, D, bwd=bwd)
@@ -109,10 +197,14 @@ def kernel_rows(fa, shapes, plans, reps):
                                       plan=plan)))
         for impl, tag, args, fn in cases:
             row = {"impl": impl, "pass": tag, "B": B, "H": H, "S": S, "D": D}
+            mark = len(telemetry.recorder().records())
             try:
                 row["kernels"] = {n: round(ms, 4) for n, ms in
                                   device_ms(fn, args, reps)[1].items()}
                 row["ms"] = round(sum(row["kernels"].values()), 4)
+                schedule = schedules_since(mark)
+                if schedule:
+                    row["schedule"] = schedule
             except Exception as e:  # a plan the compiler refuses
                 row["error"] = str(e).strip().splitlines()[-1][-300:]
             rows.append(row)
@@ -178,6 +270,14 @@ def main():
     p.add_argument("--layer", action="store_true",
                    help="device time of one GPT-2 attention layer, the "
                         "projections around the kernels, forward + backward")
+    p.add_argument("--schedule-parts", action="store_true",
+                   help="device time of the three online kernels under each "
+                        "part of the causal schedule alone (see the "
+                        "docstring)")
+    p.add_argument("--subs", default="",
+                   help="with --schedule-parts: comma-separated sub-tile "
+                        "sides to time the whole schedule at, beside the "
+                        "rule's")
     p.add_argument("--plans", default="",
                    help="with --kernels: comma-separated G:T plans for the "
                         "causal kernels (default: the planner's choice)")
@@ -234,10 +334,13 @@ def main():
     if args.shapes:
         shapes = tuple(tuple(int(x) for x in s.split("x"))
                        for s in args.shapes.split(","))
-    if args.kernels or args.layer:
+    if args.kernels or args.layer or args.schedule_parts:
         plans = [tuple(int(x) for x in p.split(":"))
                  for p in args.plans.split(",") if p]
         rows = kernel_rows(fa, shapes, plans, args.iters) if args.kernels else []
+        if args.schedule_parts:
+            rows += parts_rows(fa, shapes, args.iters,
+                               [int(s) for s in args.subs.split(",") if s])
         if args.layer:
             rows += layer_rows(shapes, args.iters)
         if args.out:

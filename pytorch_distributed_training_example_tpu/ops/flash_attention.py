@@ -7,12 +7,14 @@ trailing dimension, so the online-softmax state (running max ``m``, denom
 ``l``, unnormalized accumulator) lives in VMEM scratch across kv steps and
 the output block is written once on the last step.
 
-Causal masking skips fully-masked kv blocks (predicated with ``pl.when``)
-and applies an elementwise mask only on the diagonal block.
+Under the causal mask the grid holds only the block pairs the mask leaves
+(a walked table of steps: a block above the diagonal is neither a step nor
+a copy), a block wholly below the diagonal takes a body without a mask, and
+a block the diagonal crosses is computed in sub-tiles (``online_schedule``).
 
 Backward is a Pallas dq/dkv kernel pair under ``custom_vjp`` (see
 ``_dq_kernel``/``_dkv_kernel`` below): recompute-based, using the
-saved forward LSE, with the same blockwise masking. Layout: [B, S, H, D] in;
+saved forward LSE, under the same schedule. Layout: [B, S, H, D] in;
 the online, one-shot and streaming kernels transpose to [B, H, S, D]
 internally, the causal pair blocks the [B, S, H*D] rows as they lie (see
 "Causal kernels" below).
@@ -20,6 +22,7 @@ internally, the causal pair blocks the [B, S, H*D] rows as they lie (see
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -52,12 +55,15 @@ ONLINE_BLOCK_TABLE: dict[tuple[bool, int, int], tuple[int, int]] = {
     # peak (r4, a machine that is gone; records in git at 6739a2e) — the
     # default IS the tuned choice.
     (False, 4096, 128): (1024, 1024),
-    # D=256, S=8192 (GLM-4.7-Flash's expanded latent attention, B1 H20, bf16;
-    # PERF.md section 6, PR 41, the sweep's rows): forward 5.98 ms at
-    # 1024x1024 against 6.67 at (1024, 512), 7.04 at (512, 1024), 8.32 at
-    # 512x512: the default stays. Backward (the row's fwd+bwd less its fwd)
-    # 18.98 ms at (512, 1024) against 19.96 at (1024, 512), the rule's choice,
-    # and 20.79 at 512x512; (1024, 1024) does not compile.
+    # D=256, S=8192 (GLM-4.7-Flash's expanded latent attention, B1 H20, bf16),
+    # read again under the causal schedule (PERF.md section 6, PR 42, the
+    # sweep's rows; PR 41's, of the whole rectangle, in brackets): forward
+    # 4.70 ms [5.98] at 1024x1024 against 5.56 [6.67] at (1024, 512), 5.02
+    # [7.04] at (512, 1024), 6.49 [8.32] at 512x512: the default stays.
+    # Backward (the row's fwd+bwd less its fwd) 14.97 ms [18.98] at
+    # (512, 1024) against 15.24 [19.96] at (1024, 512), the rule's choice, and
+    # 15.83 [20.79] at 512x512; (1024, 1024) does not compile. With the empty
+    # steps gone the two are 1.8% apart where they were 5%; the row stays.
     (True, 8192, 256): (512, 1024),
 }
 
@@ -138,56 +144,316 @@ def _mxu(x):
     return x.astype(jnp.float32) if x.dtype == jnp.float16 else x
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                sm_scale: float, causal: bool, block_q: int, block_kv: int):
-    qi = pl.program_id(2)
-    kvi = pl.program_id(3)
-    n_kv = pl.num_programs(3)
+# ---------------------------------------------------------------------------
+# The online kernels' schedule: which (q block, kv block) pairs a call visits,
+# fetches and masks. One pure function of (causal, Sq, Skv, block_q,
+# block_kv), read by the three kernels, by the recorder and by the tests.
+#
+# Row r sees column c iff c <= r, so a block pair at offset
+# d = qi * block_q - kvi * block_kv is one of three things:
+#   above the diagonal (d <= -block_q): nothing of it is visible. The grid
+#     does not hold it: its steps are the visited pairs alone, and (qi, kvi)
+#     come from a scalar-prefetched table, so it costs no step and no DMA.
+#   inside (d >= block_kv - 1): every pair is visible; the body has no mask.
+#   crossed by the diagonal: computed in stripes of ``sub`` rows (columns, in
+#     the dk/dv pass) against the keys (rows) the stripe can see: static
+#     slices, one unrolled body for each offset d a crossed block can have.
+#     Only the stripe's ``sub`` x ``sub`` square on the diagonal is masked,
+#     and a square wholly above it is not computed.
+# What is left out adds exact zeros in the whole-block form: a masked
+# probability is exp(NEG_INF - m) = 0.0, and the stripes split the axis the
+# kernel writes along and shorten the one it contracts over, so no float32
+# sum changes its order: on the chip all five results are the rectangle's bit
+# for bit (benchmarks/flash_micro.py --schedule-parts compares them;
+# tests/test_attention.py holds o, lse and dq so in interpret mode, and dk, dv
+# to a rounding where XLA's CPU dot adds a shorter contraction in another order).
+# ---------------------------------------------------------------------------
 
-    @pl.when(kvi == 0)
+ONLINE_KERNELS = ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv")
+#: Offsets a crossed block may have for it to be computed in sub-tiles (one
+#: at equal blocks, two at 512 x 1024), and stripes a block may be cut into:
+#: each offset is an unrolled body to compile, of a tile a stripe.
+SUB_OFFSETS_MAX = 2
+SUB_STRIPES_MAX = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class OnlineSchedule:
+    """One online kernel's schedule. ``steps``: the (qi, kvi) pairs of the
+    innermost grid dimension(s) in order. ``walk``: they are a table the grid
+    walks (else the whole rectangle). ``split``: blocks inside take the body
+    without a mask. ``sub``: the sub-tiles' side in crossed blocks (0: such a
+    block is computed whole under an elementwise mask)."""
+
+    kernel: str
+    causal: bool
+    sq: int
+    skv: int
+    block_q: int
+    block_kv: int
+    walk: bool
+    split: bool
+    sub: int
+    steps: tuple
+
+    @property
+    def by_kv(self):
+        """The dk/dv pass: kv blocks outer, a row of steps walks q blocks."""
+        return self.kernel == "flash_bwd_dkv"
+
+    def offset(self, qi, kvi):
+        return qi * self.block_q - kvi * self.block_kv
+
+    def crossed(self, d):
+        return -self.block_q < d < self.block_kv - 1
+
+    @property
+    def offsets(self):
+        """The offsets of the blocks the diagonal crosses, ascending."""
+        return tuple(sorted({self.offset(*s) for s in self.steps
+                             if self.crossed(self.offset(*s))}))
+
+    def tiles(self, d):
+        """``(tiles, skipped)`` of a crossed block at offset ``d``: the
+        computed ``(rows, cols, square)`` in the block's own coordinates,
+        ``square`` the corner in the tile of its ``sub``-square on the
+        diagonal (None: every pair is visible; "whole": the tile is the
+        block, masked by position), and the count of sub-tiles left out."""
+        bq, bkv, sub = self.block_q, self.block_kv, self.sub
+        if not sub:
+            return [((0, bq), (0, bkv), "whole")], 0
+        vary = int(self.by_kv)      # the stripes are rows (0) or columns (1)
+        tiles, skipped = [], 0
+        for lo in range(0, (bq, bkv)[vary], sub):
+            if self.by_kv:      # columns [lo, lo + sub): rows from lo - d on
+                first = min(max(0, lo - d), bq)
+                skipped += first // sub
+                tile = ((first, bq), (lo, lo + sub),
+                        (0, 0) if lo >= d else None)
+                seen = first < bq
+            else:               # rows [lo, lo + sub): keys below d + lo + sub
+                n = max(min(bkv, d + lo + sub), 0)
+                skipped += (bkv - n) // sub
+                tile = ((lo, lo + sub), (0, n),
+                        (0, n - sub) if d + lo < bkv else None)
+                seen = n > 0
+            if not seen:
+                continue
+            last = tiles[-1] if tiles else None
+            if (last and tile[2] is None and last[2] is None
+                    and last[1 - vary] == tile[1 - vary]):
+                # stripes that see the same keys (rows) whole are one tile
+                span = (last[vary][0], tile[vary][1])
+                tile = (tile[0], span, None) if vary else (span, tile[1], None)
+                tiles.pop()
+            tiles.append(tile)
+        return tiles, skipped
+
+    def counts(self):
+        """What the schedule does, a head: the blocks of the whole rectangle,
+        grid steps, blocks computed, blocks under a mask, sub-tiles left out
+        of crossed blocks, copies of the streamed operand (a step that names
+        the previous step's block copies nothing), and the (row, column)
+        pairs computed beside those the mask leaves."""
+        bq, bkv = self.block_q, self.block_kv
+        out = dict(rectangle=(self.sq // bq) * (self.skv // bkv),
+                   steps=len(self.steps), computed=0, masked=0,
+                   skipped_subtiles=0, fetched=0, pairs_computed=0)
+        before = None
+        for qi, kvi in self.steps:
+            streamed = qi if self.by_kv else kvi
+            out["fetched"] += streamed != before
+            before = streamed
+            d = self.offset(qi, kvi)
+            if not self.causal or d >= bkv - 1:
+                out["computed"] += 1
+                out["masked"] += self.causal and not self.split
+                out["pairs_computed"] += bq * bkv
+            elif self.crossed(d):
+                tiles, skipped = self.tiles(d)
+                out["computed"] += 1
+                out["masked"] += 1
+                out["skipped_subtiles"] += skipped
+                out["pairs_computed"] += sum(
+                    (r[1] - r[0]) * (c[1] - c[0]) for r, c, _ in tiles)
+        tri = min(self.sq, self.skv)    # rows that see fewer keys than all
+        out["pairs_needed"] = (
+            tri * (tri + 1) // 2 + (self.sq - tri) * self.skv if self.causal
+            else self.sq * self.skv)
+        return {k: int(v) for k, v in out.items()}
+
+    def record(self, d):
+        """The ``flash_schedule`` record's value at head width ``d``."""
+        return dict(kernel=self.kernel, Sq=self.sq, Skv=self.skv, D=d,
+                    block_q=self.block_q, block_kv=self.block_kv,
+                    causal=self.causal, sub=self.sub, **self.counts())
+
+
+def online_schedule(kernel, causal, sq, skv, block_q, block_kv, *, walk=True,
+                    split=True, sub=None):
+    """The schedule of ``kernel`` (one of ONLINE_KERNELS) over ``sq`` x ``skv``
+    in blocks that tile them. Not causal: the whole rectangle, nothing masked.
+    ``walk``, ``split`` and ``sub`` switch the schedule's three parts off one
+    at a time (benchmarks/flash_micro.py --schedule-parts times them so; the
+    program leaves them alone). Sub-tiles engage where ``sub`` leaves a
+    square to skip and a crossed block has at most SUB_OFFSETS_MAX offsets."""
+    assert kernel in ONLINE_KERNELS, kernel
+    n_q, n_kv = sq // block_q, skv // block_kv
+    by_kv = kernel == "flash_bwd_dkv"
+    if not causal:
+        walk, split, sub = False, False, 0
+    steps = ([(qi, kvi) for kvi in range(n_kv) for qi in range(n_q)] if by_kv
+             else [(qi, kvi) for qi in range(n_q) for kvi in range(n_kv)])
+    if walk:
+        rows = [[s for s in steps if s[by_kv] == row]
+                for row in range(n_kv if by_kv else n_q)]
+        # a row with nothing to see (keys past the last query row) keeps its
+        # last step: the init and finish there write the zeros
+        steps = [s for row in rows for s in
+                 [s for s in row if s[0] * block_q - s[1] * block_kv > -block_q]
+                 or row[-1:]]
+    if sub is None:
+        # half the larger block in whole 128-lane tiles, or the widest such
+        # that divides both blocks, if SUB_STRIPES_MAX of them span a block
+        # (640 = 5 x 128, S = 2560's fitted block, has none). Narrower
+        # sub-tiles skip more and read no faster (PERF.md section 6, PR 42):
+        # each is one more unrolled tile to trace, lower and compile.
+        cap = max(block_q, block_kv) // 2
+        sub = next((s for s in range(cap - cap % 128, 0, -128)
+                    if block_q % s == 0 == block_kv % s
+                    and max(block_q, block_kv) <= SUB_STRIPES_MAX * s), 0)
+    plan = OnlineSchedule(kernel, causal, sq, skv, block_q, block_kv, walk,
+                          split, 0, tuple(steps))
+    if (sub and sub < max(block_q, block_kv)
+            and block_q % sub == 0 == block_kv % sub
+            and len(plan.offsets) <= SUB_OFFSETS_MAX
+            and all(d % sub == 0 for d in plan.offsets)):
+        plan = dataclasses.replace(plan, sub=sub)
+    return plan
+
+
+def _say_schedules(kernels, causal, sq, skv, d, block_q, block_kv):
+    """One ``flash_schedule`` record a kernel of a traced call, under the span
+    that caused the trace: the schedule is static, so its counter is a
+    record."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+    for kernel in kernels:
+        plan = online_schedule(kernel, causal, sq, skv,
+                               _fit_block(sq, block_q), _fit_block(skv, block_kv))
+        telemetry.recorder().compile_event("flash_schedule", 0.0,
+                                           plan.record(d))
+
+
+def _online_where(refs, plan):
+    """``(qi, kvi, first, last, refs)`` of a grid step: the block pair, two
+    thunks that say whether the step opens or closes a row of its output
+    block, and the kernel's own refs (less the walked table's)."""
+    if plan.walk:
+        qi_ref, kvi_ref, *refs = refs
+        t, n = pl.program_id(2), pl.num_programs(2)
+        row_ref = kvi_ref if plan.by_kv else qi_ref
+        first = lambda: (t == 0) | (row_ref[jnp.maximum(t - 1, 0)]
+                                    != row_ref[t])
+        last = lambda: (t == n - 1) | (row_ref[jnp.minimum(t + 1, n - 1)]
+                                       != row_ref[t])
+        return qi_ref[t], kvi_ref[t], first, last, refs
+    row, inner = pl.program_id(2), pl.program_id(3)
+    n_inner = pl.num_programs(3)
+    qi, kvi = (inner, row) if plan.by_kv else (row, inner)
+    return qi, kvi, lambda: inner == 0, lambda: inner == n_inner - 1, refs
+
+
+def _online_bodies(plan, qi, kvi, tile):
+    """Run ``tile(rows, cols, square)`` for what the schedule computes of the
+    block (qi, kvi): under ``pl.when`` by the block's kind, a crossed block's
+    sub-tiles unrolled in the body of its offset."""
+    whole = lambda square: lambda: tile(None, None, square)
+    if not plan.causal:
+        return whole(None)()
+    d = plan.offset(qi, kvi)
+    inside = d >= plan.block_kv - 1
+    crossed = (d > -plan.block_q) & jnp.logical_not(inside)
+    pl.when(inside)(whole(None if plan.split else "whole"))
+    if not plan.sub:
+        return pl.when(crossed)(whole("whole"))
+    for at in plan.offsets:
+        @pl.when(d == at)
+        def _stripes(at=at):
+            for rows, cols, square in plan.tiles(at)[0]:
+                tile(rows, cols, square)
+
+
+def _rows(ref, span):
+    """The block a ref holds, or the rows ``span`` of it."""
+    return ref[0, 0] if span is None else ref[0, 0, span[0]:span[1], :]
+
+
+def _at(span):
+    return slice(None) if span is None else slice(*span)
+
+
+def _mask_tile(s, square, plan, qi, kvi):
+    """Causal mask of a score tile: nothing, the whole block by position, or
+    the ``sub``-square of the tile that lies on the diagonal."""
+    if square is None:
+        return s
+    if square == "whole":
+        q_pos = qi * plan.block_q + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        k_pos = kvi * plan.block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        return jnp.where(q_pos >= k_pos, s, NEG_INF)
+    sub, (r, c) = plan.sub, square
+    visible = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+               >= jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
+    diag = jnp.where(visible, s[r:r + sub, c:c + sub], NEG_INF)
+    if s.shape[1] > sub:            # a stripe of rows: the square comes last
+        return jnp.concatenate([s[:, :c], diag], axis=1)
+    if s.shape[0] > sub:            # a stripe of columns: it comes first
+        return jnp.concatenate([diag, s[sub:]], axis=0)
+    return diag
+
+
+def _fwd_kernel(*refs, sm_scale: float, plan: OnlineSchedule):
+    qi, kvi, first, last, refs = _online_where(refs, plan)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
+
+    @pl.when(first())
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Causal: kv block strictly above the diagonal contributes nothing.
-    run = True
-    if causal:
-        run = kvi * block_kv <= (qi + 1) * block_q - 1
-
-    @pl.when(run)
-    def _compute():
+    def tile(rows, cols, square):
         # MXU-native operands: dots take q/k/v in their stored dtype (bf16 in
         # training) with fp32 accumulation via preferred_element_type — the
         # FlashAttention-2 scheme. Upcasting operands to fp32 here measured
         # ~20 TF/s on v5e (fp32 MXU rate); bf16 operands run ~2-3x faster.
         # All softmax state (m, l, acc) stays fp32.
-        q = _mxu(q_ref[0, 0])                         # [bq, D]
-        k = _mxu(k_ref[0, 0])                         # [bkv, D]
-        v = _mxu(v_ref[0, 0])                         # [bkv, D]
+        q = _mxu(_rows(q_ref, rows))                  # [bq, D]
+        k = _mxu(_rows(k_ref, cols))                  # [bkv, D]
+        v = _mxu(_rows(v_ref, cols))                  # [bkv, D]
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bkv]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, logits.shape, 0)
-            k_pos = kvi * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, logits.shape, 1)
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        logits = _mask_tile(logits, square, plan, qi, kvi)
 
-        m_prev = m_ref[:, :1]                         # [bq, 1] (lane-bcast)
+        at = _at(rows)
+        m_prev = m_ref[at, :1]                        # [bq, 1] (lane-bcast)
         block_max = jnp.max(logits, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, block_max)
         p = jnp.exp(logits - m_new)                   # [bq, bkv]
         correction = jnp.exp(m_prev - m_new)          # [bq, 1]
-        l_new = l_ref[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
+        l_new = l_ref[at, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * correction + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[at] = acc_ref[at] * correction + pv
+        m_ref[at] = jnp.broadcast_to(m_new, (q.shape[0], m_ref.shape[1]))
+        l_ref[at] = jnp.broadcast_to(l_new, (q.shape[0], l_ref.shape[1]))
 
-    @pl.when(kvi == n_kv - 1)
+    _online_bodies(plan, qi, kvi, tile)
+
+    @pl.when(last())
     def _finish():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
@@ -196,8 +462,54 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                          + jnp.log(jnp.maximum(l_ref[:, :LSE_LANES], 1e-30)))
 
 
-def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int):
-    """Returns (out [B,S,H,D], lse [B,H,S]) with K/V already GQA-expanded."""
+def _online_grid(plan, B, H, *, in_blocks, out_blocks, scratch_shapes):
+    """``(keywords, table)`` of an online kernel's ``pallas_call`` under
+    ``plan``: the grid, the block specs and the compiler's parameters, and
+    the walked table's two operands to put first (none over the rectangle).
+    ``in_blocks`` and ``out_blocks``: a ``(rows, width, "q" | "kv")`` an
+    operand, blocked along the q or the kv blocks of a step."""
+    if plan.walk:
+        pick = {"q": lambda t, qi, kvi: qi[t], "kv": lambda t, qi, kvi: kvi[t]}
+        grid = (B, H, len(plan.steps))
+    elif plan.by_kv:
+        pick = {"q": lambda j, i: i, "kv": lambda j, i: j}
+        grid = (B, H, plan.skv // plan.block_kv, plan.sq // plan.block_q)
+    else:
+        pick = {"q": lambda i, j: i, "kv": lambda i, j: j}
+        grid = (B, H, plan.sq // plan.block_q, plan.skv // plan.block_kv)
+
+    def spec(block):
+        rows, width, axis = block
+        return pl.BlockSpec((1, 1, rows, width),
+                            lambda b, h, *g: (b, h, pick[axis](*g), 0))
+
+    specs = dict(
+        in_specs=[spec(b) for b in in_blocks],
+        out_specs=(spec(out_blocks) if isinstance(out_blocks[0], int)
+                   else tuple(spec(b) for b in out_blocks)),
+        scratch_shapes=scratch_shapes)
+    semantics = pltpu.CompilerParams(dimension_semantics=(
+        "parallel",) * (len(grid) - 1) + ("arbitrary",))
+    if not plan.walk:
+        return dict(grid=grid, compiler_params=semantics, **specs), ()
+    table = tuple(jnp.asarray([s[axis] for s in plan.steps], jnp.int32)
+                  for axis in (0, 1))
+    return dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=grid, **specs),
+        compiler_params=semantics), table
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "block_q", "block_kv", "walk", "split", "sub"))
+def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
+               walk=True, split=True, sub=None):
+    """Returns (out [B,S,H,D], lse [B,H,S]) with K/V already GQA-expanded.
+    ``walk``, ``split``, ``sub``: ``online_schedule``'s switches (the
+    micro-benchmark's).
+
+    Under ``jit``, as the causal pair is: a model's layers, and its later
+    traces, share one trace and one lowering of the unrolled bodies (traced
+    a layer, GLM-4.7-Flash's six attentions added 5 s to its first step)."""
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     # head-major layout for the kernel
@@ -207,130 +519,112 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int):
     block_q = _fit_block(Sq, block_q)
     block_kv = _fit_block(Skv, block_kv)
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, Skv, block_q, block_kv)
-    grid = (B, H, Sq // block_q, Skv // block_kv)
+    plan = online_schedule("flash_fwd_online", causal, Sq, Skv, block_q,
+                           block_kv, walk=walk, split=split, sub=sub)
 
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
-                          causal=causal, block_q=block_q, block_kv=block_kv),
-        name="flash_fwd_online",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, i, j: (b, h, j, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, LSE_LANES),
-                         lambda b, h, i, j: (b, h, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq, LSE_LANES), jnp.float32),
-        ),
+    call, table = _online_grid(
+        plan, B, H,
+        in_blocks=[(block_q, D, "q"), (block_kv, D, "kv"), (block_kv, D, "kv")],
+        out_blocks=[(block_q, D, "q"), (block_q, LSE_LANES, "q")],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # m
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
             pltpu.VMEM((block_q, D), jnp.float32),     # acc
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        ])
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, sm_scale=1.0 / math.sqrt(D), plan=plan),
+        name="flash_fwd_online",
+        out_shape=(
+            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, LSE_LANES), jnp.float32),
         ),
-    )(qt, kt, vt)
+        **call,
+    )(*table, qt, kt, vt)
     return jnp.transpose(out, (0, 2, 1, 3)), lse
 
 
 # ---------------------------------------------------------------------------
 # Backward kernels (FlashAttention-2 style): dq pass over kv blocks; dk/dv
-# pass over q blocks. Residuals: q,k,v,o + the forward logsumexp rows.
+# pass over q blocks. Residuals: q,k,v,o + the forward logsumexp rows. Both
+# walk the schedule above, the dk/dv pass with its stripes along the keys.
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, sm_scale, causal, block_q, block_kv):
-    qi = pl.program_id(2)
-    kvi = pl.program_id(3)
-    n_kv = pl.num_programs(3)
+def _online_probs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
+                  square, *, sm_scale, plan, qi, kvi):
+    """``(p, ds, q, k, do)`` of one tile: the probabilities from the saved
+    lse, and a thunk for dS in the operands' dtype (the dk/dv pass adds
+    P^T dO to its accumulator before it forms dS)."""
+    # Native-dtype matmul operands, fp32 accumulation (see _fwd_kernel).
+    q = _mxu(_rows(q_ref, rows))
+    k = _mxu(_rows(k_ref, cols))
+    v = _mxu(_rows(v_ref, cols))
+    do = _mxu(_rows(do_ref, rows))
+    lse = lse_ref[0, 0, _at(rows), :1]           # [bq, 1]
+    delta = delta_ref[0, 0, _at(rows), :1]       # [bq, 1]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    s = _mask_tile(s, square, plan, qi, kvi)
+    p = jnp.exp(s - lse)                         # [bq, bkv]
 
-    @pl.when(kvi == 0)
+    def ds():
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return (p * (dp - delta) * sm_scale).astype(k.dtype)
+
+    return p, ds, q, k, do
+
+
+def _dq_kernel(*refs, sm_scale, plan):
+    qi, kvi, first, last, refs = _online_where(refs, plan)
+    *operands, dq_ref, acc_ref = refs
+
+    @pl.when(first())
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = True
-    if causal:
-        run = kvi * block_kv <= (qi + 1) * block_q - 1
+    def tile(rows, cols, square):
+        _, ds, _, k, _ = _online_probs(*operands, rows, cols, square,
+                                       sm_scale=sm_scale, plan=plan, qi=qi,
+                                       kvi=kvi)
+        ds = ds()
+        acc_ref[_at(rows)] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        # Native-dtype matmul operands, fp32 accumulation (see _fwd_kernel).
-        q = _mxu(q_ref[0, 0])
-        k = _mxu(k_ref[0, 0])
-        v = _mxu(v_ref[0, 0])
-        do = _mxu(do_ref[0, 0])
-        lse = lse_ref[0, 0, :, :1]               # [bq, 1]
-        delta = delta_ref[0, 0, :, :1]           # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kvi * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                     # [bq, bkv]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        acc_ref[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
+    _online_bodies(plan, qi, kvi, tile)
 
-    @pl.when(kvi == n_kv - 1)
+    @pl.when(last())
     def _finish():
         dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *,
-                sm_scale, causal, block_q, block_kv):
-    kvi = pl.program_id(2)
-    qi = pl.program_id(3)
-    n_q = pl.num_programs(3)
+def _dkv_kernel(*refs, sm_scale, plan):
+    qi, kvi, first, last, refs = _online_where(refs, plan)
+    *operands, dk_ref, dv_ref, dk_acc, dv_acc = refs
 
-    @pl.when(qi == 0)
+    @pl.when(first())
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        run = (qi + 1) * block_q - 1 >= kvi * block_kv
-
-    @pl.when(run)
-    def _compute():
-        # Native-dtype matmul operands, fp32 accumulation (see _fwd_kernel).
-        q = _mxu(q_ref[0, 0])
-        k = _mxu(k_ref[0, 0])
-        v = _mxu(v_ref[0, 0])
-        do = _mxu(do_ref[0, 0])
-        lse = lse_ref[0, 0, :, :1]               # [bq, 1]
-        delta = delta_ref[0, 0, :, :1]           # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kvi * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                     # [bq, bkv]
+    def tile(rows, cols, square):
+        p, ds, q, _, do = _online_probs(*operands, rows, cols, square,
+                                        sm_scale=sm_scale, plan=plan, qi=qi,
+                                        kvi=kvi)
         # dV += P^T dO
-        dv_acc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        dv_acc[_at(cols)] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = ds()
         # dK += dS^T Q
-        dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        dk_acc[_at(cols)] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
+    _online_bodies(plan, qi, kvi, tile)
+
+    @pl.when(last())
     def _finish():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -345,8 +639,12 @@ def _delta_rows(g, o):
     return jnp.broadcast_to(delta[..., None], (*delta.shape, LSE_LANES))
 
 
-def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
-    """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse: [B,H,Sq]."""
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "block_q", "block_kv", "walk", "split", "sub"))
+def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, walk=True,
+               split=True, sub=None):
+    """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse: [B,H,Sq].
+    Under ``jit`` and with ``online_schedule``'s switches, as ``_flash_fwd``."""
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     block_q = _fit_block(Sq, block_q)
@@ -358,46 +656,37 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv):
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     dot = jnp.transpose(g, (0, 2, 1, 3))
+    qrows, krows = (block_q, D, "q"), (block_kv, D, "kv")
+    lrows = (block_q, LSE_LANES, "q")
+    in_blocks = [qrows, krows, krows, qrows, lrows, lrows]
+    plans = [online_schedule(name, causal, Sq, Skv, block_q, block_kv,
+                             walk=walk, split=split, sub=sub)
+             for name in ONLINE_KERNELS[1:]]
 
-    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec((1, 1, block_kv, D), lambda b, h, i, j: (b, h, j, 0))
-    lspec = pl.BlockSpec((1, 1, block_q, LSE_LANES),
-                         lambda b, h, i, j: (b, h, i, 0))
+    operands = (qt, kt, vt, dot, lse, delta)
 
+    call, table = _online_grid(
+        plans[0], B, H, in_blocks=in_blocks, out_blocks=qrows,
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)])
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv),
+        functools.partial(_dq_kernel, sm_scale=sm_scale, plan=plans[0]),
         name="flash_bwd_dq",
-        grid=(B, H, Sq // block_q, Skv // block_kv),
-        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
-        out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-    )(qt, kt, vt, dot, lse, delta)
+        **call,
+    )(*table, *operands)
 
     # dk/dv pass: kv blocks outer (parallel), q blocks inner (accumulated).
-    qspec2 = pl.BlockSpec((1, 1, block_q, D), lambda b, h, j, i: (b, h, i, 0))
-    kspec2 = pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j, i: (b, h, j, 0))
-    lspec2 = pl.BlockSpec((1, 1, block_q, LSE_LANES),
-                          lambda b, h, j, i: (b, h, i, 0))
+    call, table = _online_grid(
+        plans[1], B, H, in_blocks=in_blocks, out_blocks=(krows, krows),
+        scratch_shapes=[pltpu.VMEM((block_kv, D), jnp.float32),
+                        pltpu.VMEM((block_kv, D), jnp.float32)])
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv),
+        functools.partial(_dkv_kernel, sm_scale=sm_scale, plan=plans[1]),
         name="flash_bwd_dkv",
-        grid=(B, H, Skv // block_kv, Sq // block_q),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, lspec2, lspec2],
-        out_specs=(kspec2, kspec2),
         out_shape=(jax.ShapeDtypeStruct((B, H, Skv, D), k.dtype),
                    jax.ShapeDtypeStruct((B, H, Skv, D), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_kv, D), jnp.float32),
-                        pltpu.VMEM((block_kv, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-    )(qt, kt, vt, dot, lse, delta)
+        **call,
+    )(*table, *operands)
 
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     return tr(dq), tr(dk), tr(dv)
@@ -1470,8 +1759,9 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
       forward and 1.20 vs 1.94 backward a layer at B24·H12·S1024·D64).
     - Other causal forwards: the streaming online kernel (r4, on a machine
       that is gone: 0.72 vs 0.86 ms one-shot at S2048; 1.37 vs 1.99 at
-      S4096/D128). Its grid skips fully-masked kv blocks only where
-      S spans more than one 1024-block.
+      S4096/D128). Its grid walks only the blocks the mask leaves, and a
+      block the diagonal crosses is computed in sub-tiles, a single
+      1024-block too (``online_schedule``; PERF.md section 6, PR 42).
     - Other backwards: the one-shot chunked kernel whenever its plan fits
       VMEM; otherwise streaming (D=128) or online.
     - Non-causal forward: one-shot when a plan exists (no masked blocks
@@ -1511,6 +1801,8 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
         return _oneshot_fwd(q, k, v, causal=causal, plan=plan, kv_len=kv_len)
     block_q, block_kv = _online_blocks(False, Sq, D, block_q, block_kv,
                                        q.dtype.itemsize)
+    _say_schedules(ONLINE_KERNELS[:1], causal, Sq, k.shape[1], D, block_q,
+                   block_kv)
     return _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                       block_kv=block_kv)
 
@@ -1581,6 +1873,8 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
             block_q, block_kv = _online_blocks(True, q.shape[1], q.shape[3],
                                                block_q, block_kv,
                                                q.dtype.itemsize)
+            _say_schedules(ONLINE_KERNELS[1:], causal, q.shape[1],
+                           ke.shape[1], q.shape[3], block_q, block_kv)
             dq, dk, dv = _flash_bwd(q, ke, ve, o, lse, g, causal=causal,
                                     block_q=block_q, block_kv=block_kv)
     return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)

@@ -189,9 +189,12 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: where XLA compiled it (with a ``cache_miss`` inside where the persistent
 #: cache then stored it), or ``cache_load`` where the persistent cache served
 #: the executable (the cache's key, then the ``cache_retrieval`` inside it:
-#: read, deserialize, load).
+#: read, deserialize, load). ``flash_schedule`` is a record of what was traced,
+#: with no duration: an online flash kernel's static schedule (its ``value``
+#: a dict: kernel, S, D, blocks, counts; ops/flash_attention.py), once a
+#: traced call.
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
-                   "cache_retrieval", "cache_miss")
+                   "cache_retrieval", "cache_miss", "flash_schedule")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

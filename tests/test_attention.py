@@ -777,3 +777,338 @@ def test_padded_flash_eligibility_gates():
     # pure-shape logic (backend-independent pieces)
     assert A._round_up(197, A.PAD_MULTIPLE) == 256
     assert A._round_up(1024, A.PAD_MULTIPLE) == 1024
+
+
+# ---------------------------------------------------------------------------
+# The online kernels' causal schedule (ops/flash_attention.online_schedule):
+# the blocks above the diagonal are no step and no copy, the blocks below it
+# take no mask, the blocks it crosses are computed in sub-tiles.
+# ---------------------------------------------------------------------------
+
+import flash_online_parent as parent_kernels  # noqa: E402  (tests/: the oracle)
+
+# (S, block_q, block_kv) -> per kernel (fwd, dq, dkv) where they differ:
+# sub, steps, computed, masked, skipped_subtiles, fetched, pairs_computed
+SCHEDULE_CASES = {
+    # GLM-4.7-Flash's forward: 28 of the rectangle's 64 are never a step
+    (8192, 1024, 1024): dict(sub=512, rectangle=64, steps=36, computed=36,
+                             masked=8, skipped_subtiles=8, fetched=35,
+                             pairs_computed=34 << 20),
+    # its backward, ONLINE_BLOCK_TABLE's row: the wholly masked quarter
+    (8192, 512, 1024): dict(sub=512, rectangle=128, steps=72, computed=72,
+                            masked=16, skipped_subtiles=8,
+                            fetched=(70, 70, 72), pairs_computed=34 << 20),
+    (8192, 1024, 512): dict(sub=512, rectangle=128, steps=72, computed=72,
+                            masked=16, skipped_subtiles=8,
+                            fetched=(72, 72, 70), pairs_computed=34 << 20),
+    # Granite's one attention layer
+    (4096, 1024, 1024): dict(sub=512, rectangle=16, steps=10, computed=10,
+                             masked=4, skipped_subtiles=4, fetched=9,
+                             pairs_computed=9 << 20),
+    # 640 = 5 x 128 has no sub-tile of whole lanes within four stripes
+    (2560, 640, 640): dict(sub=0, rectangle=16, steps=10, computed=10,
+                           masked=4, skipped_subtiles=0, fetched=9,
+                           pairs_computed=10 * 640 * 640),
+    # one block: nothing to skip, the diagonal's sub-tiles alone
+    (1024, 1024, 1024): dict(sub=512, rectangle=1, steps=1, computed=1,
+                             masked=1, skipped_subtiles=1, fetched=1,
+                             pairs_computed=3 << 18),
+}
+
+
+def _coverage(plan):
+    """From the schedule's steps and tiles, over cells of 128 x 128 (or the
+    sub-tile, where that is narrower): how often each is computed, and
+    whether the schedule holds it visible (a tile without a mask says all of
+    it is; a masked square says its lower triangle is)."""
+    cell = min(128, plan.sub or 128, plan.block_q, plan.block_kv)
+    n_r, n_c = plan.sq // cell, plan.skv // cell
+    times = np.zeros((n_r, n_c), int)
+    whole = np.zeros((n_r, n_c), bool)      # every pair of the cell visible
+    some = np.zeros((n_r, n_c), bool)       # a pair of the cell visible
+    tri = np.tril(np.ones((n_r, n_c), bool))
+    for qi, kvi in plan.steps:
+        d = plan.offset(qi, kvi)
+        if plan.causal and d <= -plan.block_q:
+            continue
+        if not plan.causal or d >= plan.block_kv - 1:
+            tiles = [((0, plan.block_q), (0, plan.block_kv),
+                      None if plan.split or not plan.causal else "whole")]
+        else:
+            tiles = plan.tiles(d)[0]
+        for (r0, r1), (c0, c1), square in tiles:
+            rows = slice((qi * plan.block_q + r0) // cell,
+                         (qi * plan.block_q + r1) // cell)
+            cols = slice((kvi * plan.block_kv + c0) // cell,
+                         (kvi * plan.block_kv + c1) // cell)
+            times[rows, cols] += 1
+            if square is None:
+                whole[rows, cols] = some[rows, cols] = True
+                continue
+            some[rows, cols] |= tri[rows, cols]     # the mask is by position
+            if square != "whole":
+                # outside its square on the diagonal the tile has no mask
+                sr = rows.start + square[0] // cell
+                sc = cols.start + square[1] // cell
+                n = plan.sub // cell
+                held = np.ones((rows.stop - rows.start,
+                                cols.stop - cols.start), bool)
+                held[sr - rows.start:sr - rows.start + n,
+                     sc - cols.start:sc - cols.start + n] = False
+                whole[rows, cols] |= held
+                some[rows, cols] |= held
+                # the square itself is the canonical triangle: on the diagonal
+                assert sr == sc, (qi, kvi, square)
+    return times, whole, some
+
+
+@pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
+@pytest.mark.parametrize("case", list(SCHEDULE_CASES), ids=lambda c: "S%d-%dx%d" % c)
+def test_online_schedule_counts_and_coverage(case, kernel):
+    S, bq, bkv = case
+    want = dict(SCHEDULE_CASES[case])
+    at = F.ONLINE_KERNELS.index(kernel)
+    want = {k: v[at] if isinstance(v, tuple) else v for k, v in want.items()}
+    plan = F.online_schedule(kernel, True, S, S, bq, bkv)
+    got = dict(plan.counts(), sub=plan.sub)
+    assert {k: got[k] for k in want} == want
+    assert got["pairs_needed"] == S * (S + 1) // 2
+    assert plan.walk and got["steps"] == got["computed"]
+    times, whole, some = _coverage(plan)
+    lower = np.tril(np.ones(times.shape, bool))
+    # every pair the mask leaves is computed exactly once, none twice
+    assert times.max() == 1 and (times[lower] == 1).all()
+    assert (some == lower).all()
+    # and no pair is taken for visible that is not: a cell without a mask
+    # lies strictly below the diagonal
+    assert (whole <= np.tril(lower, -1)).all()
+
+
+@pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
+def test_online_schedule_not_causal_is_the_rectangle(kernel):
+    plan = F.online_schedule(kernel, False, 4096, 8192, 1024, 512)
+    assert (plan.walk, plan.split, plan.sub) == (False, False, 0)
+    assert plan.counts() == dict(
+        rectangle=64, steps=64, computed=64, masked=0, skipped_subtiles=0,
+        fetched=64, pairs_computed=4096 * 8192, pairs_needed=4096 * 8192)
+    times, whole, _ = _coverage(plan)
+    assert (times == 1).all() and whole.all()
+
+
+def test_online_schedule_keys_past_the_last_query_keep_a_step():
+    """Skv > Sq under the causal mask: the kv blocks no query row sees are
+    one empty step each in the dk/dv pass, whose init and finish write the
+    zeros; the other passes never visit them."""
+    plans = [F.online_schedule(k, True, 256, 512, 128, 128)
+             for k in F.ONLINE_KERNELS]
+    assert [p.counts()["steps"] for p in plans] == [3, 3, 5]
+    assert [p.counts()["computed"] for p in plans] == [3, 3, 3]
+    assert plans[2].steps[-2:] == ((1, 2), (1, 3))
+    q, k, v = _qkv(B=1, S=256, H=2, D=64)
+    k, v = (jnp.concatenate([x, x], axis=1) for x in (k, v))
+    g = jnp.ones_like(q)
+    with pltpu.force_tpu_interpret_mode():
+        o, lse = F._flash_fwd(q, k, v, causal=True, block_q=128, block_kv=128)
+        o0, _ = parent_kernels._flash_fwd(q, k, v, causal=True, block_q=128,
+                                          block_kv=128)
+        grads = F._flash_bwd(q, k, v, o, lse, g, causal=True, block_q=128,
+                             block_kv=128)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o0))
+    assert not np.asarray(grads[1][:, 256:]).any()
+    assert not np.asarray(grads[2][:, 256:]).any()
+
+
+ONLINE_PARITY = [
+    # S, block_q, block_kv, D, H, Hkv, causal
+    (512, 128, 256, 64, 4, 1, True),     # two offsets, rows in one stripe
+    (512, 128, 256, 128, 2, 2, True),
+    (512, 128, 256, 256, 4, 1, True),
+    (512, 256, 128, 64, 2, 2, True),     # a stripe of rows sees no key
+    (512, 256, 128, 128, 4, 1, True),
+    (512, 256, 128, 256, 2, 2, True),
+    (1024, 256, 256, 128, 4, 1, True),   # 4 x 4 blocks, sub-tile 128
+    (512, 512, 512, 64, 2, 2, True),     # one block: the sub-tiles alone
+    (768, 384, 384, 64, 2, 2, True),     # sub-tile 128 in three stripes
+    (1024, 512, 512, 64, 2, 2, True),    # sub-tile 256 in two
+    (512, 128, 256, 64, 4, 1, False),
+    (512, 256, 128, 128, 2, 2, False),
+    (512, 256, 256, 256, 4, 1, False),
+]
+
+
+@pytest.mark.parametrize("S,bq,bkv,D,H,Hkv,causal", ONLINE_PARITY)
+def test_online_schedule_parity_interpret(S, bq, bkv, D, H, Hkv, causal):
+    """Forward and all three gradients through ``flash_attention(...,
+    "online")`` against the XLA oracle, at blocks that reach every body:
+    the unmasked one, each offset's stripes, a skipped sub-tile."""
+    q, k, v = _qkv(B=1, S=S, H=H, Hkv=Hkv, D=D)
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+    ref = A.dot_product_attention(q, k, v, causal=causal)
+    g_ref = jax.grad(lambda *a: (A.dot_product_attention(*a, causal=causal)
+                                 * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    with pltpu.force_tpu_interpret_mode():
+        out = F.flash_attention(q, k, v, causal, bq, bkv, "online")
+        g_out = jax.grad(lambda *a: (F.flash_attention(
+            *a, causal, bq, bkv, "online") * w).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(g_ref, g_out):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def _both_online(S, bq, bkv, D=64, H=2, causal=True, dtype=jnp.float32,
+                 **parts):
+    """(o, lse, dq, dk, dv) of the scheduled kernels and of the kernels as
+    they stood before the schedule, on the same operands."""
+    r = np.random.RandomState(7)
+    q, k, v, g = (jnp.asarray(r.randn(1, S, H, D), dtype) for _ in range(4))
+    blocks = dict(causal=causal, block_q=bq, block_kv=bkv)
+    with pltpu.force_tpu_interpret_mode():
+        o0, l0 = parent_kernels._flash_fwd(q, k, v, **blocks)
+        o1, l1 = F._flash_fwd(q, k, v, **blocks, **parts)
+        g0 = parent_kernels._flash_bwd(q, k, v, o0, l0, g, **blocks)
+        g1 = F._flash_bwd(q, k, v, o0, l0, g, **blocks, **parts)
+    return (o1, l1, *g1), (o0, l0, *g0)
+
+
+@pytest.mark.parametrize("S,bq,bkv", [(512, 128, 256), (512, 256, 128),
+                                      (512, 256, 256), (768, 384, 384)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_online_schedule_is_the_rectangle_bitwise(S, bq, bkv, dtype):
+    """"Only exact zeros went": the scheduled kernels give the results of the
+    whole rectangle under a mask everywhere, bit for bit. The forward and dq
+    stripes cut the rows they write and drop a contraction's trailing zeros;
+    the dk/dv stripes cut the columns they write and drop its LEADING zeros,
+    which XLA's CPU dot (the interpreter's) does not add in the order of the
+    longer one: there dk and dv are held to a rounding of the sum, and the
+    test below holds the dropped terms to zero. On the chip
+    ``benchmarks/flash_micro.py --schedule-parts`` compares all five."""
+    got, want = _both_online(S, bq, bkv, dtype=dtype)
+    for name, a, b in zip(("o", "lse", "dq"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    plan = F.online_schedule("flash_bwd_dkv", True, S, S, bq, bkv)
+    assert plan.sub
+    whole_rows = all(rows[0] == 0 for d in plan.offsets
+                     for rows, _, _ in plan.tiles(d)[0])
+    for name, a, b in zip(("dk", "dv"), got[3:], want[3:]):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        if whole_rows:
+            np.testing.assert_array_equal(a, b, name)
+        else:
+            eps = float(jnp.finfo(dtype).eps)
+            np.testing.assert_allclose(a, b, rtol=2 * eps,
+                                       atol=eps * np.abs(b).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("parts", [
+    dict(walk=False, split=False, sub=0),       # the schedule before
+    dict(split=False, sub=0), dict(walk=False, sub=0),
+    dict(walk=False, split=False), dict(sub=0)],
+    ids=lambda p: "-".join("%s=%s" % kv for kv in p.items()))
+def test_online_schedule_parts_alone_bitwise(parts):
+    """Each part of the schedule switched on alone (what ``flash_micro.py
+    --schedule-parts`` times) gives the rectangle's results bit for bit; at
+    (128, 256) the dk/dv stripes keep every row, so dk and dv too."""
+    got, want = _both_online(512, 128, 256, **parts)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+def test_online_masked_terms_are_exact_zeros():
+    """What the sub-tiles leave out of a crossed block, the rectangle's
+    kernels add as exact zeros: a masked probability is exp(NEG_INF - m) =
+    0.0 whatever finite m, and dS = P * (dP - delta) * scale is 0.0 with it."""
+    r = np.random.RandomState(3)
+    q, k, v, do = (jnp.asarray(r.randn(256, 64) * 4, jnp.float32)
+                   for _ in range(4))
+    scale = 1 / 8
+    s = (q @ k.T) * scale
+    masked = jnp.where(np.tril(np.ones((256, 256), bool)), s, F.NEG_INF)
+    m = masked.max(axis=1, keepdims=True)
+    p_fwd = jnp.exp(masked - m)
+    lse = m + jnp.log(p_fwd.sum(axis=1, keepdims=True))
+    p = jnp.exp(masked - lse)
+    delta = jnp.sum(do * (p @ v), axis=1, keepdims=True)
+    ds = p * (do @ v.T - delta) * scale
+    above = ~np.tril(np.ones((256, 256), bool))
+    for name, x in (("forward p", p_fwd), ("backward p", p), ("dS", ds)):
+        assert not np.asarray(x)[above].any(), name
+    # a running max that another block has raised, or not yet: still 0.0
+    for m_prev in (-50.0, 0.0, 80.0):
+        assert float(jnp.exp(jnp.float32(F.NEG_INF) - m_prev)) == 0.0
+
+
+def _online_kernel_jaxprs(mod, causal):
+    def calls(q, k, v, g):
+        o, lse = mod._flash_fwd(q, k, v, causal=causal, block_q=1024,
+                                block_kv=512)
+        return o, mod._flash_bwd(q, k, v, o, lse, g, causal=causal,
+                                 block_q=512, block_kv=1024)
+    x = jax.ShapeDtypeStruct((1, 2048, 4, 128), jnp.bfloat16)
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grid = eqn.params["grid_mapping"]
+                found.append((
+                    eqn.params["name"], str(eqn.params["jaxpr"]), grid.grid,
+                    [(str(b.index_map_jaxpr), str(b.block_shape))
+                     for b in grid.block_mappings],
+                    str(eqn.params["compiler_params"])))
+            elif "jaxpr" in eqn.params:     # the launchers are jitted
+                walk(eqn.params["jaxpr"].jaxpr)
+
+    walk(jax.make_jaxpr(calls)(x, x, x, x).jaxpr)
+    return found
+
+
+def test_online_not_causal_lowers_to_the_kernels_before_the_schedule():
+    """``causal=False`` skips no block and masks none: the three calls' kernel
+    jaxprs, grids, index maps and compiler parameters are the earlier
+    kernels', text for text (what Mosaic is handed)."""
+    got = _online_kernel_jaxprs(F, False)
+    want = _online_kernel_jaxprs(parent_kernels, False)
+    assert [c[0] for c in got] == list(F.ONLINE_KERNELS)
+    assert got == want
+    # and the causal calls do differ: the walked grid is one dimension less
+    causal = _online_kernel_jaxprs(F, True)
+    assert [len(c[2]) for c in causal] == [3, 3, 3]
+    assert [c[0] for c in causal] == list(F.ONLINE_KERNELS)
+
+
+def test_flash_schedule_record_under_the_span_that_traced():
+    """One ``flash_schedule`` record a traced call and kernel, a child of the
+    span open on the tracing thread, with the counts the schedule test pins
+    at GLM-4.7-Flash's shape."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+
+    rec = telemetry.recorder()
+    mark = len(rec.records())
+    x = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16)
+    with rec.span("trace_here", bucket=None):
+        jax.eval_shape(jax.grad(
+            lambda q, k, v: F.flash_attention(q, k, v, True).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+    new = rec.records()[mark:]
+    span = next(r for r in new if r.kind == "span" and r.name == "trace_here")
+    said = [r for r in new if r.name == "flash_schedule"]
+    assert all(r.kind == "compile" and r.parent == span.id for r in said)
+    by_kernel = {r.value["kernel"]: r.value for r in said}
+    assert sorted(by_kernel) == sorted(F.ONLINE_KERNELS)
+    fwd = by_kernel["flash_fwd_online"]
+    assert (fwd["Sq"], fwd["D"], fwd["block_q"], fwd["block_kv"]) == (
+        8192, 256, 1024, 1024)
+    assert {k: fwd[k] for k in ("rectangle", "steps", "computed", "masked",
+                                "skipped_subtiles", "pairs_computed")} == {
+        "rectangle": 64, "steps": 36, "computed": 36, "masked": 8,
+        "skipped_subtiles": 8, "pairs_computed": 34 << 20}
+    for name in F.ONLINE_KERNELS[1:]:
+        bwd = by_kernel[name]
+        assert (bwd["block_q"], bwd["block_kv"]) == F.ONLINE_BLOCK_TABLE[
+            True, 8192, 256]
+        assert bwd["steps"] == bwd["computed"] < bwd["rectangle"]
+    assert "flash_schedule" in telemetry.COMPILE_RECORDS

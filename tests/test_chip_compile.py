@@ -734,3 +734,32 @@ def test_glm47_share_step_fits_the_chip(one_chip, as_tpu):
     for scope in ("mla_q", "mla_kv", "mla_rope", "mla_out", "mtp_merge",
                   "mtp/head_loss"):
         assert f"/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("B,S,H,D", [
+    (1, 8192, 20, 256),    # GLM-4.7-Flash's six attentions
+    (1, 8192, 32, 128),    # Trinity's full layer
+    (1, 4096, 32, 128),    # a 4 x 4 rectangle: 6 blocks never a step
+])
+def test_online_causal_schedule_compiles_at_the_cells_widths(one_chip, B, S,
+                                                             H, D):
+    """The three online kernels under the causal schedule (a walked table of
+    steps through scalar prefetch, an unmasked body, a body of sub-tiles for
+    each offset of a crossed block) compile for the v5e at the blocks dispatch
+    picks, and keep the names the benchmark's readers find them by."""
+    import re
+
+    x = _sds((B, S, H, D), one_chip)
+    text = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_KV, "online")),
+        x, x, x)
+    # "%transpose_jvp_flash_bwd_dq__.1 = bf16[...] custom-call(...)"
+    calls = re.findall(r"%(\w*flash_\w+?)_*(?:\.\d+)? = [^\n]*custom-call",
+                       text)
+    assert sorted(c[c.index("flash_"):] for c in calls) == sorted(
+        fa.ONLINE_KERNELS)
+    blocks = [fa._online_blocks(bwd, S, D, 1024, 1024) for bwd in (0, 1, 1)]
+    plans = [fa.online_schedule(name, True, S, S, *b)
+             for name, b in zip(fa.ONLINE_KERNELS, blocks)]
+    assert all(p.walk and p.split and p.sub == 512 for p in plans)
+    assert blocks[1] == ((512, 1024) if D == 256 else (1024, 1024))
