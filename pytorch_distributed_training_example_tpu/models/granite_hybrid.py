@@ -26,6 +26,7 @@ Training only: serving needs a recurrent-state cache beside the KV pages
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -44,11 +45,15 @@ PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
 
 class MambaMixer(nn.Module):
-    """Mamba-2 mixer with one B/C group: ``[z, xBC, dt] = W_in h``; a causal
+    """Mamba-2 mixer: ``[z, xBC, dt] = W_in h``; a causal
     depthwise conv and silu over ``xBC``; the SSD scan over ``[x, B, C] =
     split(xBC)`` with ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``;
-    ``RMSNorm(y * silu(z))`` over all inner channels (gate first, then the
-    norm); ``W_out``."""
+    ``RMSNorm(y * silu(z))`` (gate first, then the norm); ``W_out``.
+    ``groups`` B/C groups (``B``, ``C`` [S, groups, N], head ``h`` reading
+    group ``h // (H / groups)``), and the gated norm over each of the
+    ``groups`` runs of ``H P / groups`` inner channels by itself (one scale
+    vector over them all); at 1, Granite's, one B and C for every head and
+    the norm over all inner channels."""
     num_heads: int      # H
     head_dim: int       # P
     state_dim: int      # N
@@ -57,12 +62,13 @@ class MambaMixer(nn.Module):
     epsilon: float
     dtype: Any
     param_dtype: Any
+    groups: int = 1
 
     @nn.compact
     def __call__(self, h):
         b, S, d = h.shape
-        H, P, N = self.num_heads, self.head_dim, self.state_dim
-        inner, conv_dim = H * P, H * P + 2 * N
+        H, P, N, G = self.num_heads, self.head_dim, self.state_dim, self.groups
+        inner, conv_dim = H * P, H * P + 2 * G * N
         dense = lambda feat, name: nn.Dense(
             feat, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name=name)
@@ -74,7 +80,9 @@ class MambaMixer(nn.Module):
             bias = self.param("conv_bias", nn.initializers.zeros,
                               (conv_dim,), self.param_dtype)
             xBC = nn.silu(ssd_lib.causal_conv1d(xBC, kernel, bias))
-        x, B, C = jnp.split(xBC, [inner, inner + N], axis=-1)
+        x, B, C = jnp.split(xBC, [inner, inner + G * N], axis=-1)
+        if G > 1:
+            B, C = B.reshape(b, S, G, N), C.reshape(b, S, G, N)
         # Small tensors that steer the decay stay float32 under bf16 compute.
         dt_bias = self.param("dt_bias", nn.initializers.constant(-3.0), (H,),
                              jnp.float32)
@@ -93,9 +101,29 @@ class MambaMixer(nn.Module):
             # of two terms that all but cancel: one bf16 rounding of ``y`` (or
             # of its cotangent) in between puts it off by a percent.
             y = y.reshape(b, S, inner) * nn.silu(z.astype(jnp.float32))
-            y = RMSNorm(self.epsilon, self.dtype, self.param_dtype,
-                        name="norm")(y)
+            norm = RMSNorm if G == 1 else functools.partial(GroupRMSNorm, G)
+            y = norm(self.epsilon, self.dtype, self.param_dtype,
+                     name="norm")(y)
         return dense(d, "out_proj")(y)
+
+
+class GroupRMSNorm(nn.Module):
+    """``RMSNorm`` over each of ``groups`` equal runs of the last axis by
+    itself, with one scale vector over them all."""
+    groups: int
+    epsilon: float = 1e-5
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        x32 = x.astype(jnp.float32).reshape(*x.shape[:-1], self.groups, -1)
+        norm = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (norm.reshape(x.shape)
+                * scale.astype(jnp.float32)).astype(self.dtype)
 
 
 class GraniteAttention(nn.Module):

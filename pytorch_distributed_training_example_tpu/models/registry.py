@@ -23,7 +23,12 @@ chip's share of it, ``smallthinker_tiny`` for tests; training only,
 a sigmoid-routed mixture with a shared expert, and a multi-token-prediction
 module whose loss rides the ``losses`` collection: ``glm47_flash`` at its
 published sizes, ``glm47_flash_share`` one chip's share of it,
-``glm_moe_lite_tiny`` for tests; training only, ``dp``/``fsdp`` only).
+``glm_moe_lite_tiny`` for tests; training only, ``dp``/``fsdp`` only) and
+the ``nemotron_h`` family (every layer one mixer alone by a published pattern
+string: a Mamba-2 mixer with grouped B/C, an ungated squared-ReLU expert layer
+beside a shared expert, or position-free GQA: ``nemotron3_nano`` at its
+published sizes, ``nemotron3_nano_share`` one chip's share of it,
+``nemotron_h_tiny`` for tests; training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -322,7 +327,7 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
 def _held_experts_family(name, make):
     """Registry builder for a family whose expert layers are told which
     experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``,
-    ``glm_moe_lite``):
+    ``glm_moe_lite``, ``nemotron_h``):
     ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
     Granite hybrid: the expert layer has no exchange, and there is no
     tensor-parallel rule table."""
@@ -379,6 +384,18 @@ _REGISTRY["glm47_flash_share"] = _held_experts_family(
     "glm_moe_lite", lambda m, **kw: m.chip_share(m.glm47_flash(**kw)))
 _REGISTRY["glm_moe_lite_tiny"] = _held_experts_family(
     "glm_moe_lite", lambda m, **kw: m.glm_moe_lite_tiny(**kw))
+
+
+# The published Nemotron-3-Nano-30B-A3B; one chip's share of it (a sixteenth
+# of every expert layer's routed experts, an eighth of the vocabulary, the
+# published layers 0..8: what the one-chip benchmark cell trains); and a toy
+# for the tests.
+_REGISTRY["nemotron3_nano"] = _held_experts_family(
+    "nemotron_h", lambda m, **kw: m.nemotron3_nano(**kw))
+_REGISTRY["nemotron3_nano_share"] = _held_experts_family(
+    "nemotron_h", lambda m, **kw: m.chip_share(m.nemotron3_nano(**kw)))
+_REGISTRY["nemotron_h_tiny"] = _held_experts_family(
+    "nemotron_h", lambda m, **kw: m.nemotron_h_tiny(**kw))
 
 
 @register("resnet_micro")

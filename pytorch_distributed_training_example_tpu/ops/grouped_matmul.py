@@ -83,7 +83,21 @@ _BLOCK_BYTES = 4 * 1024 * 1024
 def _block_cols(n: int, depth: int = 1, itemsize: int = 4) -> int:
     """Largest nice power-of-two column block whose ``[depth, block]`` tile
     of ``itemsize`` bytes stays within ``_BLOCK_BYTES`` (a wider block reads
-    each row tile fewer times); odd widths get one block."""
+    each row tile fewer times); odd widths get one block.
+
+    A width over one lane tile that is no multiple of 256 (1856 = 14.5 tiles,
+    2688 = 21) has no such divisor that Mosaic takes (a block's last dimension
+    is whole 128-lane tiles or the whole array's): it gets blocks of whole
+    lane tiles, the fewest that fit the bytes and then the narrowest of those,
+    and where the width is no whole number of tiles the last block is
+    part-filled (the callers' grids are ``pl.cdiv``: Pallas reads what lies
+    past the array as padding and drops what is written there, and no
+    contraction here runs over a blocked dimension)."""
+    if n > 128 and n % 256:
+        tiles = -(-n // 128)
+        fit = [k for k in range(1, n // 128 + 1)
+               if depth * 128 * k * itemsize <= _BLOCK_BYTES] or [1]
+        return 128 * min(fit, key=lambda k: (-(-tiles // k), k))
     blocks = [bc for bc in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
               if n % bc == 0]
     for bc in blocks:
@@ -188,7 +202,7 @@ def _gmm_call(x_pad, w, tiles, bt: int, out_dtype, transposed: bool = False):
         name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(f // bf, Tp // bt),
+            grid=(pl.cdiv(f, bf), Tp // bt),
             in_specs=[
                 pl.BlockSpec((bt, d),
                              lambda jc, g, te, tf, nt: (_last_tile(g, nt), 0)),
@@ -239,7 +253,7 @@ def _gmm_dw_call(x_pad, g_pad, tiles, num_experts: int, bt: int):
             # tiles of one expert are visited consecutively (the padded
             # layout is segment-sorted), which is what makes the revisited
             # dw block a valid accumulator under sequential semantics.
-            grid=(f // bf, Tp // bt),
+            grid=(pl.cdiv(f, bf), Tp // bt),
             in_specs=[
                 pl.BlockSpec((bt, d),
                              lambda jc, g, te, tf, nt: (_last_tile(g, nt), 0)),
@@ -369,6 +383,56 @@ def gated_ffn_padded_bwd(x_pad, gate, up, w_gate, w_up, w_down, tiles, dy_pad,
     dx_gate, dw_gate, _ = _gmm_padded_bwd((x_pad, w_gate, tiles), dgate)
     dx_up, dw_up, _ = _gmm_padded_bwd((x_pad, w_up, tiles), dup)
     return dx_gate + dx_up, dw_gate, dw_up, dw_down
+
+
+#: An ungated expert's activation, by the name a caller gives as ``act``:
+#: the squared ReLU of the ``nemotron_h`` experts.
+ACTS = {"relu2": lambda v: jnp.square(jax.nn.relu(v))}
+
+
+def _activated(up, act="relu2"):
+    """``act(up)`` in float32, rounded once."""
+    return ACTS[act](up.astype(jnp.float32)).astype(up.dtype)
+
+
+def ungated_down_padded(up, w_down, tiles, act="relu2"):
+    """The activated up projection through the down projection:
+    ``ungated_ffn_padded_kept``'s result from its own ``up``."""
+    return _gmm_padded(_activated(up, act), w_down, tiles)
+
+
+def ungated_ffn_padded_kept(x_pad, w_up, w_down, tiles, act="relu2"):
+    """Ungated (two-matrix) grouped expert MLP over rows already in the
+    padded layout, ``act(x @ w_up[e]) @ w_down[e]`` a tile (``act`` a key of
+    ``ACTS``, static), with the projection it activated beside it: ``(y_pad,
+    up)`` in the compute dtype. With ``x_pad``, ``up`` is all that
+    ``ungated_ffn_padded_bwd`` reads of the forward. A padding row stays zero
+    through the activation (``act(0) = 0``)."""
+    up = _gmm_padded(x_pad, w_up, tiles)
+    return ungated_down_padded(up, w_down, tiles, act), up
+
+
+def ungated_ffn_padded_bwd(x_pad, up, w_up, w_down, tiles, dy_pad,
+                           act="relu2"):
+    """What differentiating ``ungated_ffn_padded_kept``'s ``y_pad`` gives for
+    ``dy_pad``, from the forward's own ``up``: ``(dx_pad, dw_up, dw_down)``,
+    as ``gated_ffn_padded_bwd`` for the gated form."""
+    h_pad, act_vjp = jax.vjp(functools.partial(_activated, act=act), up)
+    dh_pad, dw_down, _ = _gmm_padded_bwd((h_pad, w_down, tiles), dy_pad)
+    dx_pad, dw_up, _ = _gmm_padded_bwd((x_pad, w_up, tiles),
+                                       act_vjp(dh_pad)[0])
+    return dx_pad, dw_up, dw_down
+
+
+#: The expert forms by how many matrices an expert has: ``(kept, down, bwd)``.
+#: ``kept(x_pad, *experts, tiles, act=)`` gives ``(y_pad, *pre)``, ``pre`` the
+#: pre-activations a backward reads; ``down(*pre, w_down, tiles, act=)`` gives
+#: ``y_pad`` again from them; ``bwd(x_pad, *pre, *experts, tiles, dy_pad,
+#: act=)`` gives ``(dx_pad, *d_experts)``.
+FFN_FORMS = {
+    3: (gated_ffn_padded_kept, gated_down_padded, gated_ffn_padded_bwd),
+    2: (ungated_ffn_padded_kept, ungated_down_padded, ungated_ffn_padded_bwd),
+}
 
 
 def _gmm_impl(x, w, group_starts, group_counts):

@@ -2,7 +2,8 @@
 depthwise convolution that feeds it (Dao & Gu 2024, arXiv:2405.21060).
 
 Per head (``x_t`` in R^P, one scalar decay ``A < 0``, ``B_t``/``C_t`` in R^N
-shared by every head of the one group)::
+shared by the heads of a group: head ``h`` of ``H`` reads group ``h // (H /
+groups)``)::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t * x_t (outer) B_t,    S_{-1} = 0
     y_t = S_t C_t + D * x_t
@@ -15,11 +16,11 @@ the state carry are float32 whatever the compute dtype; every matmul takes
 operands in ``x.dtype`` and accumulates in float32; ``y`` comes back float32.
 
 Two bodies, one algorithm, chosen by :func:`_kernel_plan` from what the call
-shows (dtype, chunk, head and state widths):
+shows (dtype, chunk, head and state widths, groups):
 
 - a Pallas kernel pair under a ``custom_vjp`` (``ssd_fwd`` / ``ssd_bwd``): a
-  program holds one chunk of a group of heads, everything [chunk, chunk] per
-  head lives and dies in VMEM, and the grid walks a sequence's chunks in order
+  program holds one chunk of a run of heads (within one B/C group, or whole
+  groups), everything [chunk, chunk] per head lives and dies in VMEM, and the grid walks a sequence's chunks in order
   with the state in float32 scratch. The backward recomputes the tiles; its
   residuals are the inputs and the state each chunk started from. Compiled on
   ``tpu``, interpreted on ``cpu`` (the tests' route); under a mesh through
@@ -75,7 +76,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
     ``x`` [b, S, H, P] (compute dtype); ``dt`` [b, S, H] float32, already
     through its softplus; ``A`` [H] float32, negative; ``B``, ``C`` [b, S, N]
-    (one group); ``D`` [H] or None. Returns ``y`` [b, S, H, P] in float32, as
+    (one group) or [b, S, groups, N]; ``D`` [H] or None. Returns ``y`` [b, S, H, P] in float32, as
     accumulated (see :class:`models.granite_hybrid.MambaMixer` for why).
     A sequence that is no multiple of ``chunk`` is padded here: a padded step
     has ``dt = 0``, so it neither decays nor feeds the state.
@@ -86,6 +87,11 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     """
     b, S, H, P = x.shape
     cd = x.dtype
+    if B.ndim == 3:
+        B, C = B[:, :, None], C[:, :, None]
+    groups, N = B.shape[2:]
+    if H % groups:
+        raise ValueError(f"{groups} B/C groups do not divide {H} heads")
     Q = min(chunk, S)
     pad = -S % Q
     if pad:
@@ -93,7 +99,8 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         x, dt, B, C = widen(x), widen(dt), widen(B), widen(C)
     dt = dt.astype(F32)
     A = A.astype(F32)
-    plan = _kernel_plan(H, P, B.shape[-1], Q, cd)
+    plan = _kernel_plan(H, P, N, Q, cd, groups)
+    _say_plan(H, P, N, groups, Q, plan)
     if plan is None:
         y = _scan_xla(x, dt, A, B, C, D, Q)
     else:
@@ -107,45 +114,60 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                          precision=jax.lax.Precision.HIGHEST)
         y = _scan_kernels(
             x.reshape(b, S + pad, H * P), dt, cum.reshape(dt.shape),
-            B.astype(cd), C.astype(cd),
+            B.astype(cd).reshape(b, S + pad, groups * N),
+            C.astype(cd).reshape(b, S + pad, groups * N),
             jnp.zeros((H,), F32) if D is None else D.astype(F32),
-            (Q, P, plan)).reshape(x.shape)
+            (Q, P, plan, groups)).reshape(x.shape)
     return y[:, :S] if pad else y
 
 
+def _say_plan(H, P, N, groups, Q, plan):
+    """One ``ssd_plan`` record a traced call of :func:`ssd`, under the span
+    that caused the trace: whether the kernels engaged and at how many heads
+    a program is static, so its counter is a record."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+    telemetry.recorder().compile_event("ssd_plan", 0.0, {
+        "H": H, "P": P, "N": N, "groups": groups, "chunk": Q,
+        "heads_per_program": "xla" if plan is None else plan})
+
+
 def _scan_xla(x, dt, A, B, C, D, Q):
-    """The scan in plain ``jax.numpy`` on a sequence of whole chunks of ``Q``:
-    XLA differentiates it, and the [chunks, H, Q, Q] tiles pass through HBM."""
+    """The scan in plain ``jax.numpy`` on a sequence of whole chunks of ``Q``
+    (``B``, ``C`` [b, S, groups, N]; ``k`` below is a head of its group): XLA
+    differentiates it, and the [chunks, H, Q, Q] tiles pass through HBM."""
     b, S, H, P = x.shape
-    N = B.shape[-1]
+    G, N = B.shape[2:]
+    K = H // G
     cd = x.dtype
     nc = S // Q
-    xc = x.reshape(b, nc, Q, H, P)
-    Bc = B.reshape(b, nc, Q, N).astype(cd)
-    Cc = C.reshape(b, nc, Q, N).astype(cd)
+    by_group = lambda a: a.reshape(*a.shape[:-1], G, K)     # [.., H] -> [.., G, K]
+    xc = x.reshape(b, nc, Q, G, K, P)
+    Bc = B.reshape(b, nc, Q, G, N).astype(cd)
+    Cc = C.reshape(b, nc, Q, G, N).astype(cd)
     dtc = dt.reshape(b, nc, Q, H)
     # log-decay of each step and its running sum inside the chunk
     cum = jnp.cumsum(dtc * A, axis=2)                        # [b,c,Q,H]
-    xdt = (xc.astype(F32) * dtc[..., None]).astype(cd)       # dt * x
+    xdt = (xc.astype(F32) * by_group(dtc)[..., None]).astype(cd)   # dt * x
 
     # 1. inside a chunk: (L o C B^T) (dt x)
-    scores = jnp.einsum("bctn,bcsn->bcts", Cc, Bc,
-                        preferred_element_type=F32)          # [b,c,Q,Q]
+    scores = jnp.einsum("bctgn,bcsgn->bcgts", Cc, Bc,
+                        preferred_element_type=F32)          # [b,c,G,Q,Q]
     lower = jnp.tril(jnp.ones((Q, Q), bool))
     cumh = cum.transpose(0, 1, 3, 2)                         # [b,c,H,Q]
     seg = cumh[..., :, None] - cumh[..., None, :]            # [b,c,H,t,s]
     # masked before the exp: above the diagonal the difference is positive
     # and may overflow, and 0 * inf in the backward would be NaN
     L = jnp.exp(jnp.where(lower, seg, -jnp.inf))
-    M = (L * scores[:, :, None]).astype(cd)                  # [b,c,H,t,s]
-    y = jnp.einsum("bchts,bcshp->bcthp", M, xdt,
+    M = (L.reshape(b, nc, G, K, Q, Q)
+         * scores[:, :, :, None]).astype(cd)                 # [b,c,G,K,t,s]
+    y = jnp.einsum("bcgkts,bcsgkp->bctgkp", M, xdt,
                    preferred_element_type=F32)
 
     # 2. the state each chunk leaves: sum_s exp(cum_end - cum_s) dt x (x) B
-    to_end = jnp.exp(cum[:, :, -1:, :] - cum)                # [b,c,Q,H]
-    left = jnp.einsum("bcshp,bcsn->bchpn",
+    to_end = by_group(jnp.exp(cum[:, :, -1:, :] - cum))      # [b,c,Q,G,K]
+    left = jnp.einsum("bcsgkp,bcsgn->bcgkpn",
                       (xdt.astype(F32) * to_end[..., None]).astype(cd), Bc,
-                      preferred_element_type=F32)            # [b,c,H,P,N]
+                      preferred_element_type=F32)            # [b,c,G,K,P,N]
 
     # 3. the state each chunk starts from: the earlier chunks' states, decayed
     #    by every chunk in between (strictly lower triangular over chunks)
@@ -156,19 +178,20 @@ def _scan_xla(x, dt, A, B, C, D, Q):
         between = (run - total)[:, :, None, :] - run[:, None, :, :]  # [b,z,c,H]
         before = jnp.tril(jnp.ones((nc, nc), bool), -1)
         carry = jnp.exp(jnp.where(before[:, :, None], between, -jnp.inf))
-        start = jnp.einsum("bzch,bchpn->bzhpn", carry, left)  # float32
+        start = jnp.einsum("bzcgk,bcgkpn->bzgkpn", by_group(carry),
+                           left)                             # float32
         # 4. what the carried state adds: exp(cum_t) C_t S_start
-        y = y + jnp.einsum("bctn,bchpn->bcthp", Cc, start.astype(cd),
+        y = y + jnp.einsum("bctgn,bcgkpn->bctgkp", Cc, start.astype(cd),
                            preferred_element_type=F32) \
-            * jnp.exp(cum)[..., None]
+            * by_group(jnp.exp(cum))[..., None]
     if D is not None:
-        y = y + xc.astype(F32) * D.astype(F32)[:, None]
+        y = y + xc.astype(F32) * D.astype(F32).reshape(G, K)[..., None]
     return y.reshape(b, S, H, P)
 
 
 # ---------------------------------------------------------------------------
-# The Pallas kernel pair. A program is one (sequence, chunk, group of G
-# heads); the grid walks a sequence's chunks in order (the backward in
+# The Pallas kernel pair. A program is one (sequence, chunk, run of G heads:
+# a part of one B/C group, or whole groups); the grid walks a sequence's chunks in order (the backward in
 # reverse) with the state of every head, [H*P, N] float32, in VMEM scratch.
 # ``x``, ``y`` and their cotangents stay lane-dense [b, S, H*P]: a head is a
 # lane slice, and where P < 128 the 128 // P heads of one 128-lane block are
@@ -183,23 +206,27 @@ LANES = 128
 KERNEL_VMEM_BUDGET = 13 * 2 ** 20
 
 
-def _kernel_plan(H, P, N, Q, dtype):
+def _kernel_plan(H, P, N, Q, dtype, groups=1):
     """G, the heads a program of the kernels holds, or None where the
     kernels do not serve the shape and :func:`_scan_xla` runs instead.
 
     Admitted: bf16 or float32 operands (Mosaic refuses fp16 loads); a chunk
     and a state width on the 128-lane tiling (``L`` is [Q, Q], the state
     [*, N]); a head width that divides or is a multiple of 128 lanes, and a
-    head count made of whole lane blocks. G is the largest group (a multiple
-    of 8 heads, the row blocks' sublane tiling, or all of them) whose
+    head count made of whole lane blocks. G is the largest run of heads (a
+    multiple of 8 heads, the row blocks' sublane tiling, or all of them; a
+    part of one of the ``groups`` B/C groups or whole groups, so that a
+    program's B/C block is its own groups' and no other's) whose
     backward program fits the budget: its double-buffered blocks (x, dx, dy
-    in float32, the chunk's start state, B, C and their cotangents, the
-    columns padded to 128 lanes), every head's state cotangent in scratch,
-    the [Q, G*P] and [Q, Q] temporaries. At Granite's widths (H 64, P 64,
+    in float32, the chunk's start state, its groups' B, C and their
+    cotangents, the columns padded to 128 lanes), every head's state
+    cotangent in scratch, the [Q, G*P] and [Q, Q] temporaries. At Granite's widths (H 64, P 64,
     N 128, Q 256) that is 16 heads in bf16 and 8 in float32: the groups
     ``benchmarks/ssd_micro.py`` timed (8 was 8% slower than 16) and
     ``tests/test_chip_compile.py`` compiles. The model is on the safe side:
-    the compiler also admits 32 and 64, which were not timed unrolled.
+    the compiler also admits 32 and 64, which were not timed unrolled. At
+    Nemotron-3-Nano's (the same widths, 8 groups, Q 128) it is 32 heads, four
+    groups, in bf16 and 16, two groups, in float32.
     """
     if dtype not in (jnp.bfloat16, jnp.float32):
         return None
@@ -208,17 +235,39 @@ def _kernel_plan(H, P, N, Q, dtype):
     per_block = max(1, LANES // P)
     if H % per_block:
         return None
+    if H % groups:
+        return None
     item = jnp.dtype(dtype).itemsize
     for G in range(H, 0, -1):
         if H % G or G % per_block or (G % 8 and G != H):
             continue
+        held, shared_by = _group_span(H, G, groups)
+        if G * shared_by != held * (H // groups):
+            continue
         GP = G * P
         blocks = 2 * (2 * Q * GP * item + Q * GP * 4 + GP * N * 4
-                      + 2 * Q * N * (item + 4) + 4 * Q * LANES * 4)
+                      + 2 * Q * held * N * (item + 4) + 4 * Q * LANES * 4)
         temps = 2 * Q * GP * 4 + 8 * Q * Q * 4
         if blocks + H * P * N * 4 + temps <= KERNEL_VMEM_BUDGET:
             return G
     return None
+
+
+def _group_span(H, G, groups):
+    """``(held, shared_by)`` for programs of ``G`` heads: the B/C groups a
+    program holds, and the programs that share one group (one of the two is
+    1 in a plan that :func:`_kernel_plan` admits)."""
+    per_group = H // groups
+    return max(1, G // per_group), max(1, per_group // G)
+
+
+def _group_cols(ref, q, N):
+    """Group ``q``'s [Q, N] of a program's [1, Q, held * N] block of B or C
+    (or of their cotangents): the lanes to index ``ref[0]`` with. ``q`` is
+    traced where a program holds several groups."""
+    if ref.shape[2] == N:
+        return slice(None)
+    return pl.ds(pl.multiple_of(q * N, N), N)
 
 
 def _by_head(parts, width, axis):
@@ -287,14 +336,16 @@ def _ssd_fwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
     """One chunk of G heads. ``x_ref`` [1, Q, G*P]; ``dt_ref``, ``ac_ref``
     [1, 1, Q, G] (dt and the running log-decay, a head a lane: columns);
     ``ar_ref`` [1, 1, G, Q] (the same log-decay, a head a row); ``b_ref``,
-    ``c_ref`` [1, Q, N]; ``d_ref`` [1, G*P]. Out: ``y_ref`` [1, Q, G*P]
+    ``c_ref`` [1, Q, held * N], the B/C groups of the program's heads side by
+    side; ``d_ref`` [1, G*P]. Out: ``y_ref`` [1, Q, G*P]
     float32 and ``s0_ref`` [1, 1, G*P, N], the state the chunk started from
     (the backward's residual). ``state`` [H/G, G*P, N] carries every head's
     state from chunk to chunk. One 128-lane block of heads at a time, in a
     ``fori_loop`` that Pallas unrolls: a Python loop over the 8 blocks traced
     the body 8 times, 7 s of every start-up on the chip's host, and a loop
     left rolled was 0.32 ms a layer against 0.26 (the backward 0.95 against
-    0.67). The [Q, Q] tile is walked in [128, 128] tiles, at and under the
+    0.67). Where the program holds several B/C groups, a loop of the same
+    kind over them around it: ``C B^T`` is a group's. The [Q, Q] tile is walked in [128, 128] tiles, at and under the
     diagonal ones only (in one piece the backward took 10% longer)."""
     c, g = pl.program_id(1), pl.program_id(2)
     Q, GP = x_ref.shape[1], x_ref.shape[2]
@@ -308,11 +359,21 @@ def _ssd_fwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
         state[g] = jnp.zeros(state.shape[1:], F32)
 
     s0_ref[0, 0] = state[g]
-    Bm, Cm = b_ref[0], c_ref[0]
-    scores = _nt_dot(Cm, Bm)                                  # [Q, Q]
     a_all, dt_all = ac_ref[0, 0], dt_ref[0, 0]                # [Q, G]
+    N = state.shape[2]
+    held = b_ref.shape[2] // N
+    per_group = GP // W // held       # lane blocks of a group's heads
 
-    def block(j, _):
+    def group(q, _):
+        at = _group_cols(b_ref, q, N)
+        Bm, Cm = b_ref[0, :, at], c_ref[0, :, at]
+        scores = _nt_dot(Cm, Bm)                              # [Q, Q]
+        return jax.lax.fori_loop(
+            0, per_group,
+            lambda i, _: block(Bm, Cm, scores, q * per_group + i, _),
+            0, unroll=True)
+
+    def block(Bm, Cm, scores, j, _):
         lanes = pl.ds(pl.multiple_of(j * W, W), W)
         heads = [j * per_block + i for i in range(per_block)]
         xf = x_ref[0, :, lanes].astype(F32)                   # [Q, W]
@@ -344,13 +405,16 @@ def _ssd_fwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
         state[g, lanes, :] = s0 * keep + _tn_dot(xe, Bm)
         return 0
 
-    jax.lax.fori_loop(0, GP // W, block, 0, unroll=True)
+    if held > 1:
+        jax.lax.fori_loop(0, held, group, 0, unroll=True)
+    else:
+        group(0, 0)
 
 
 def _ssd_bwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
                     s0_ref, dy_ref,
                     dx_ref, ddt_ref, da_ref, dar_ref, db_ref, dc_ref, dd_ref,
-                    dstate, dscores, dxdt_t, *, P):
+                    dstate, dscores, dxdt_t, *, P, shared_by):
     """The forward's program, backwards: chunks arrive last first and
     ``dstate`` [H/G, G*P, N] carries the cotangent of the state a chunk
     leaves. Recomputes ``L`` and ``M`` from the log-decays and ``C B^T``, in
@@ -358,10 +422,11 @@ def _ssd_bwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
     [1, 1, Q, G] (of dt as it scales x); of the running log-decay,
     ``da_ref`` [1, 1, Q, G] less ``dar_ref`` [1, 1, G, Q] (what a token
     gathers as t, a column, and what it loses as s, a row); ``db_ref``,
-    ``dc_ref`` [1, Q, N] float32, summed over the heads of a program and
-    over the programs of a chunk; ``dd_ref`` [1, 1, 1, G*P], the chunk's sum
+    ``dc_ref`` [1, Q, held * N] float32, a group's summed over its heads in the
+    program and over the ``shared_by`` programs of a chunk that share the
+    group (they follow one another); ``dd_ref`` [1, 1, 1, G*P], the chunk's sum
     over tokens of dy * x. Scratch: ``dscores`` [Q, Q], the cotangent of
-    ``C B^T`` summed over the program's heads; ``dxdt_t`` [W, Q], a lane
+    ``C B^T`` summed over the heads of one group; ``dxdt_t`` [W, Q], a lane
     block's ``(M^T dy)^T`` (transposing dy for the MXU is a quarter of
     transposing M)."""
     c, g = pl.program_id(1), pl.program_id(2)
@@ -375,16 +440,49 @@ def _ssd_bwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
     def _():
         dstate[g] = jnp.zeros(dstate.shape[1:], F32)
 
-    Bm, Cm = b_ref[0], c_ref[0]
-    scores = _nt_dot(Cm, Bm)                                  # [Q, Q]
     last = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
     a_all, dt_all = ac_ref[0, 0], dt_ref[0, 0]                # [Q, G]
-    dscores[:] = jnp.zeros((Q, Q), F32)
     at = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
     own = [(at >= i * P) & (at < (i + 1) * P) for i in range(per_block)]
     mine = lambda i, v: jnp.where(own[i], v, 0) if per_block > 1 else v
+    N = dstate.shape[2]
+    held = b_ref.shape[2] // N
+    per_group = GP // W // held       # lane blocks of a group's heads
 
-    def block(j, sums):
+    def group(q, _):
+        at = _group_cols(b_ref, q, N)
+        Bm, Cm = b_ref[0, :, at], c_ref[0, :, at]
+        scores = _nt_dot(Cm, Bm)                              # [Q, Q]
+        dscores[:] = jnp.zeros((Q, Q), F32)
+        zero = jnp.zeros(Bm.shape, F32)
+        db, dc = jax.lax.fori_loop(
+            0, per_group,
+            lambda i, sums: block(Bm, Cm, scores, q * per_group + i, sums),
+            (zero, zero), unroll=True)
+        # 6. C B^T is every head's of the group: its cotangent was summed
+        #    over them above
+        dsc = dscores[:].astype(cd)
+        dc += jnp.dot(dsc, Bm, preferred_element_type=F32)
+        db += _tn_dot(dsc, Cm)
+        if shared_by == 1:      # the group's heads are all this program's
+            db_ref[0, :, at] = db
+            dc_ref[0, :, at] = dc
+            return 0
+        # the first of the programs that share the group, and the others
+        nth = g % shared_by
+
+        @pl.when(nth == 0)
+        def _():
+            db_ref[0] = db
+            dc_ref[0] = dc
+
+        @pl.when(nth > 0)
+        def _():
+            db_ref[0] += db
+            dc_ref[0] += dc
+        return 0
+
+    def block(Bm, Cm, scores, j, sums):
         db, dc = sums
         lanes = pl.ds(pl.multiple_of(j * W, W), W)
         heads = [j * per_block + i for i in range(per_block)]
@@ -475,23 +573,10 @@ def _ssd_bwd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, d_ref,
                         jnp.sum(mine(i, of_dt), axis=1, keepdims=True))
         return db, dc
 
-    zero = jnp.zeros(Bm.shape, F32)
-    db, dc = jax.lax.fori_loop(0, GP // W, block, (zero, zero),
-                               unroll=True)
-    # 6. C B^T is every head's: its cotangent was summed over them above
-    dsc = dscores[:].astype(cd)
-    dc += jnp.dot(dsc, Bm, preferred_element_type=F32)
-    db += _tn_dot(dsc, Cm)
-
-    @pl.when(g == 0)
-    def _():
-        db_ref[0] = db
-        dc_ref[0] = dc
-
-    @pl.when(g > 0)
-    def _():
-        db_ref[0] += db
-        dc_ref[0] += dc
+    if held > 1:
+        jax.lax.fori_loop(0, held, group, 0, unroll=True)
+    else:
+        group(0, 0)
 
 
 def _columns(a, G):
@@ -506,15 +591,19 @@ def _rows(a, Q):
     return a.reshape(b, S // Q, Q, H).transpose(0, 1, 3, 2)
 
 
-def _specs(Q, N, G, P, order):
-    """Block specs for the grid (sequence, chunk, head group), by what a
-    block holds; ``order`` maps the grid's chunk index to the chunk."""
+def _specs(Q, N, G, P, order, held, shared_by):
+    """Block specs for the grid (sequence, chunk, run of heads), by what a
+    block holds; ``order`` maps the grid's chunk index to the chunk. A
+    program's B/C block is its ``held`` groups', or the one that it shares
+    with the ``shared_by - 1`` programs beside it (of one group, every
+    program shares the one block)."""
     GP = G * P
     return dict(
         tokens=pl.BlockSpec((1, Q, GP), lambda i, c, g: (i, order(c), g)),
         cols=pl.BlockSpec((1, 1, Q, G), lambda i, c, g: (i, g, order(c), 0)),
         rows=pl.BlockSpec((1, 1, G, Q), lambda i, c, g: (i, order(c), g, 0)),
-        shared=pl.BlockSpec((1, Q, N), lambda i, c, g: (i, order(c), 0)),
+        shared=pl.BlockSpec((1, Q, held * N), lambda i, c, g: (
+            i, order(c), g // shared_by)),
         state=pl.BlockSpec((1, 1, GP, N), lambda i, c, g: (i, order(c), g, 0)),
         per_lane=pl.BlockSpec((1, GP), lambda i, c, g: (0, g)),
         chunk_sum=pl.BlockSpec((1, 1, 1, GP),
@@ -524,7 +613,7 @@ def _specs(Q, N, G, P, order):
 def _operands(x, dt, cum, B, C, D, plan):
     """Both kernels' first seven operands and their specs' names: dt and the
     log-decay as columns, the log-decay as rows, D a lane a channel."""
-    Q, P, G = plan
+    Q, P, G, _ = plan
     return ((x, _columns(dt, G), _columns(cum, G), _rows(cum, Q), B, C,
              jnp.repeat(D, P)[None]),
             ("tokens", "cols", "cols", "rows", "shared", "shared", "per_lane"))
@@ -540,11 +629,11 @@ def _fwd_call(x, dt, cum, B, C, D, *, plan):
     """``y`` [b, S, H*P] float32 and the states the chunks started from,
     [b, S/Q, H*P, N] float32. Under ``jit`` so that a model's layers, and the
     recomputation in its backward, share one trace and one lowering."""
-    Q, P, G = plan
+    Q, P, G, groups = plan
     b, S, HP = x.shape
-    H, N, nc = HP // P, B.shape[-1], S // Q
+    H, N, nc = HP // P, B.shape[-1] // groups, S // Q
     operands, names = _operands(x, dt, cum, B, C, D, plan)
-    spec = _specs(Q, N, G, P, lambda c: c)
+    spec = _specs(Q, N, G, P, lambda c: c, *_group_span(H, G, groups))
     return pl.pallas_call(
         functools.partial(_ssd_fwd_kernel, P=P),
         name="ssd_fwd",
@@ -563,15 +652,16 @@ def _fwd_call(x, dt, cum, B, C, D, *, plan):
 def _bwd_call(x, dt, cum, B, C, D, states, dy, *, plan):
     """Cotangents of x, dt, cum, B, C (the last two float32) and, per
     sequence and chunk, the sum over tokens of dy * x [b, S/Q, 1, H*P]."""
-    Q, P, G = plan
+    Q, P, G, groups = plan
     b, S, HP = x.shape
-    H, N, nc = HP // P, B.shape[-1], S // Q
+    H, N, nc = HP // P, B.shape[-1] // groups, S // Q
     operands, names = _operands(x, dt, cum, B, C, D, plan)
-    spec = _specs(Q, N, G, P, lambda c: nc - 1 - c)
+    held, shared_by = _group_span(H, G, groups)
+    spec = _specs(Q, N, G, P, lambda c: nc - 1 - c, held, shared_by)
     columns = jax.ShapeDtypeStruct((b, H // G, S, G), F32)
-    shared = jax.ShapeDtypeStruct((b, S, N), F32)
+    shared = jax.ShapeDtypeStruct(B.shape, F32)
     dx, ddt, da, dar, db, dc, dd = pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, P=P),
+        functools.partial(_ssd_bwd_kernel, P=P, shared_by=shared_by),
         name="ssd_bwd",
         grid=(b, nc, H // G),
         in_specs=[spec[n] for n in names + ("state", "tokens")],
@@ -618,8 +708,8 @@ def _per_device(fn, *args, n_out):
 def _scan_kernels(x, dt, cum, B, C, D, plan):
     """The scan through the kernels. ``x`` [b, S, H*P]; ``dt``, ``cum``
     [b, S, H] float32 (``cum`` the log-decay's running sum inside each
-    chunk); ``B``, ``C`` [b, S, N] in ``x.dtype``; ``D`` [H] float32;
-    ``plan`` (Q, P, G). Returns ``y`` [b, S, H*P] float32."""
+    chunk); ``B``, ``C`` [b, S, groups * N] in ``x.dtype``; ``D`` [H] float32;
+    ``plan`` (Q, P, G, groups). Returns ``y`` [b, S, H*P] float32."""
     return _scan_fwd(x, dt, cum, B, C, D, plan)[0]
 
 

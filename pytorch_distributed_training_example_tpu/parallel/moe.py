@@ -1039,12 +1039,15 @@ def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
     """The held experts' part of the layer's sum for these tokens, ``[n, d]``
     float32, and what a backward reads of this forward beside its inputs:
     ``experts = (w_gate, w_up, w_down)`` are the experts ``first`` onwards
-    (``act`` their gate's function, a key of ``grouped_matmul.GATES``),
+    (``act`` their gate's function, a key of ``grouped_matmul.GATES``), or
+    ``(w_up, w_down)`` ungated ones (``act`` a key of ``grouped_matmul.ACTS``;
+    ``grouped_matmul.FFN_FORMS`` has both forms' routines),
     ``chosen [n, k]`` indexes all the router's experts, the padded layout
     takes at most ``max_tiles`` tiles of ``bt`` rows, and ``counts`` are the
     held experts' rows where the caller has them (the router counts all the
     tokens). Kept: the integer plan ``(tiles, pair_row, row_pair)`` and the
-    two projections ``(gate, up)`` of the padded rows in the compute dtype;
+    two projections ``(gate, up)`` of the padded rows in the compute dtype
+    (``(up,)`` alone of an ungated expert);
     ``x_pad`` is one gather of rows from the plan and ``y_pad`` one grouped
     matmul from ``gate`` and ``up``, and whatever is kept here lives from the
     forward to the backward in the step XLA schedules (151 MB more a layer
@@ -1058,12 +1061,12 @@ def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
                                           counts)
         x_pad = _dispatch_rows(tokens, row_pair // k, pair_row)
     with jax.named_scope("moe_experts"):
-        y_pad, gate, up = mesh_lib.manual_call(
-            functools.partial(gmm_lib.gated_ffn_padded_kept, act=act),
+        y_pad, *pre = mesh_lib.manual_call(
+            functools.partial(gmm_lib.FFN_FORMS[len(experts)][0], act=act),
             x_pad, *experts, tiles, in_specs=P(), out_specs=P())
     with jax.named_scope("moe_combine"):
         out = _combine_rows(y_pad, weights, pair_row, row_pair)
-    return out, ((tiles, pair_row, row_pair), (gate, up))
+    return out, ((tiles, pair_row, row_pair), tuple(pre))
 
 
 def _routed(tokens, chosen, weights, experts, first, bt, max_tiles=None,
@@ -1082,21 +1085,22 @@ def _routed_kept_bwd(kept, tokens, weights, experts, d_out, act="silu"):
     from pytorch_distributed_training_example_tpu.ops import (
         grouped_matmul as gmm_lib)
 
-    (tiles, pair_row, row_pair), (gate, up) = kept
+    (tiles, pair_row, row_pair), pre = kept
+    _, down, bwd = gmm_lib.FFN_FORMS[len(experts)]
     with jax.named_scope("moe_dispatch"):
         row_token = row_pair // weights.shape[1]
         x_pad = _rows(tokens, row_token)
     with jax.named_scope("moe_experts"):
         y_pad = mesh_lib.manual_call(
-            functools.partial(gmm_lib.gated_down_padded, act=act), gate, up,
-            experts[2], tiles, in_specs=P(), out_specs=P())
+            functools.partial(down, act=act), *pre, experts[-1], tiles,
+            in_specs=P(), out_specs=P())
     with jax.named_scope("moe_combine"):
         dy_pad, d_weights = _combine_bwd(
             (y_pad, weights, pair_row, row_pair), d_out)[:2]
     with jax.named_scope("moe_experts"):
         dx_pad, *d_experts = mesh_lib.manual_call(
-            functools.partial(gmm_lib.gated_ffn_padded_bwd, act=act), x_pad,
-            gate, up, *experts, tiles, dy_pad, in_specs=P(), out_specs=P())
+            functools.partial(bwd, act=act), x_pad, *pre, *experts, tiles,
+            dy_pad, in_specs=P(), out_specs=P())
     with jax.named_scope("moe_dispatch"):
         d_tokens = _dispatch_bwd((row_token, pair_row), dx_pad)[0]
     return d_tokens, d_weights, tuple(d_experts)
@@ -1242,7 +1246,8 @@ def route_softmax_chosen(tokens, kernel, k) -> Route:
 
 class Held(NamedTuple):
     """What ``_held_sum`` made on its way, for its callers' telemetry."""
-    experts: tuple       # (w_gate, w_up, w_down) in the compute dtype
+    experts: tuple       # (w_gate, w_up, w_down) in the compute dtype, or
+                         # (w_up, w_down) of ungated experts
     tokens: jax.Array    # [T, d] in the compute dtype
     first: int           # the first held expert
     load: jax.Array      # [held] int32: the held experts' rows
@@ -1252,9 +1257,12 @@ class Held(NamedTuple):
     parts: int           # the column parts its padded rows are gathered in
 
 
-def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act):
+def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act,
+              gated=True):
     """The held experts' routine inside ``module`` (which gets the stacked
-    ``w_gate``, ``w_up``, ``w_down``): the part of ``sum_c weights[t, c] *
+    ``w_gate``, ``w_up``, ``w_down``; ``w_up`` and ``w_down`` alone where the
+    experts are not ``gated``: ``act(x W_up) W_down``, ``act`` then a key of
+    ``grouped_matmul.ACTS``): the part of ``sum_c weights[t, c] *
     Expert_chosen[t, c](tokens[t])`` that the experts ``held_experts = (how
     many, starting where)`` give, ``[T, d]`` float32, dropless whatever the
     imbalance; and a :class:`Held`.
@@ -1276,9 +1284,8 @@ def _held_sum(module, tokens, route: Route, ffn_dim, held_experts, act):
     stacked = lambda name, shape: module.param(
         name, nn.initializers.lecun_normal(), (held, *shape),
         module.param_dtype).astype(module.dtype)
-    experts = (stacked("w_gate", (d, ffn_dim)),
-               stacked("w_up", (d, ffn_dim)),
-               stacked("w_down", (ffn_dim, d)))
+    experts = ((stacked("w_gate", (d, ffn_dim)),) if gated else ()) + (
+        stacked("w_up", (d, ffn_dim)), stacked("w_down", (ffn_dim, d)))
     bt = min(EXPERT_TILE_ROWS, gmm_lib._block_rows(T * k, held))
     held_load = route.load[first:first + held].astype(jnp.int32)
 
@@ -1325,7 +1332,9 @@ class SharedExpertMoE(nn.Module):
     the ``batch_stats`` collection: no gradient, no optimizer state); the
     weights are ``route_scale * s_i / (sum of the chosen s + 1e-20)``: the
     bias chooses and nothing more (``route_sigmoid_bias``). ``y = Shared(x)
-    + sum_i w_i Expert_i(x)`` with SwiGLU experts. After a training step ``b
+    + sum_i w_i Expert_i(x)`` with SwiGLU experts, or where ``gated`` is
+    false with two-matrix squared-ReLU ones, ``relu(x W_up)^2 W_down``, the
+    shared expert likewise (the ``nemotron_h`` models). After a training step ``b
     += d - mean(d)``, ``d = balance_coeff * sign(mean(c) - c)``, ``c`` the
     tokens of this call that chose each expert (all ``num_experts``, this
     chip's tokens).
@@ -1347,7 +1356,10 @@ class SharedExpertMoE(nn.Module):
     ``moe_whole`` (1.0 where the held rows fit the whole layout and the kept
     residuals serve the backward, 0.0 where the tokens went in parts) and
     ``moe_source_parts`` (the column parts the padded rows are gathered back
-    in: ``_source_parts``), each with the enclosing block's name behind a dot.
+    in: ``_source_parts``), each with the enclosing block's name behind a dot;
+    an ungated layer also ``moe_gate_zero`` (the share of the held rows' ``up``
+    pre-activations that the squared ReLU zeroes: ``_gate_zero_share``), in a
+    run that collects ``telemetry``.
     """
 
     num_experts: int
@@ -1357,6 +1369,7 @@ class SharedExpertMoE(nn.Module):
     shared_ffn_dim: int = 0
     route_scale: float = 1.0
     balance_coeff: float = 0.0
+    gated: bool = True                  # SwiGLU experts; False: squared ReLU
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -1383,19 +1396,24 @@ class SharedExpertMoE(nn.Module):
                 bias.value = bias.value + delta - jnp.mean(delta)
 
         out, held = _held_sum(self, tokens, route, self.ffn_dim,
-                              self.held_experts, "silu")
+                              self.held_experts,
+                              "silu" if self.gated else "relu2", self.gated)
         if self.shared_ffn_dim:
             with jax.named_scope("moe_shared"):
-                out = out + SwiGLU(self.shared_ffn_dim, self.dtype,
+                shared = SwiGLU if self.gated else SquaredReLU
+                out = out + shared(self.shared_ffn_dim, self.dtype,
                                    self.param_dtype, name="shared")(
                     held.tokens).astype(jnp.float32)
 
         rows = held.load.astype(jnp.float32)
-        _sow_telemetry(self, moe_held_rows=jnp.sum(rows),
-                       moe_held_peak=_held_peak(rows),
-                       moe_bias_peak=jnp.max(jnp.abs(bias.value)),
-                       moe_whole=held.whole,
-                       moe_source_parts=jnp.float32(held.parts))
+        sown = dict(moe_held_rows=jnp.sum(rows),
+                    moe_held_peak=_held_peak(rows),
+                    moe_bias_peak=jnp.max(jnp.abs(bias.value)),
+                    moe_whole=held.whole,
+                    moe_source_parts=jnp.float32(held.parts))
+        if not self.gated and self.is_mutable_collection("telemetry"):
+            sown["moe_gate_zero"] = _gate_zero_share(route.chosen, held)
+        _sow_telemetry(self, **sown)
         return out.reshape(B, S, d).astype(self.dtype)
 
 
@@ -1453,7 +1471,9 @@ class HeldExperts(nn.Module):
 
 def _gate_zero_share(chosen, held: Held):
     """The share of the held rows' gate pre-activations ``x W_gate`` that are
-    not positive (``relu`` zeroes them). Telemetry only: the plan and the
+    not positive (``relu`` zeroes them); of an ungated layer, whose first
+    matrix is ``W_up``, the share of ``x W_up`` that its squared ReLU zeroes.
+    Telemetry only: the plan and the
     gate projection once more, outside the routine and its ``cond``. NaN
     where the rows do not fit the bounded layout whole."""
     from pytorch_distributed_training_example_tpu.ops import (
@@ -1491,6 +1511,26 @@ class SwiGLU(nn.Module):
         return dense(h.shape[-1], "down")(
             nn.silu(dense(self.ffn_dim, "gate")(h))
             * dense(self.ffn_dim, "up")(h))
+
+
+class SquaredReLU(nn.Module):
+    """``down(relu(up(h))^2)`` without biases: the ungated two-matrix MLP of
+    the ``nemotron_h`` models' shared expert, activated as the routed ones
+    are (``ops.grouped_matmul._activated``: in float32, rounded once)."""
+    ffn_dim: int
+    dtype: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        dense = lambda feat, name: nn.Dense(
+            feat, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name)
+        from pytorch_distributed_training_example_tpu.ops import (
+            grouped_matmul as gmm_lib)
+
+        return dense(h.shape[-1], "down")(
+            gmm_lib._activated(dense(self.ffn_dim, "up")(h)))
 
 
 #: Expert-parallel rules: stacked expert weights shard on the 'expert' axis

@@ -300,6 +300,23 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # Nemotron-3-Nano-30B-A3B (models/nemotron_h.py) whole, and one chip's
+    # share of it (a sixteenth of each expert layer's 128 experts, an eighth
+    # of the vocabulary, the published layers 0..8): every layer one mixer
+    # alone (Mamba-2 with 8 B/C groups, ungated experts, position-free GQA),
+    # one 8k sequence a chip per micro-step
+    "nemotron3_nano": dict(
+        model="nemotron3_nano", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=16, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "nemotron3_nano_share": dict(
+        model="nemotron3_nano_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -192,9 +192,12 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: read, deserialize, load). ``flash_schedule`` is a record of what was traced,
 #: with no duration: an online flash kernel's static schedule (its ``value``
 #: a dict: kernel, S, D, blocks, counts; ops/flash_attention.py), once a
-#: traced call.
+#: traced call; ``ssd_plan`` likewise, once a traced call of ``ops.ssd.ssd``
+#: (``value``: H, P, N, groups, chunk and the heads a program of the kernels
+#: holds, or ``"xla"`` where the shape took the ``jax.numpy`` scan).
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
-                   "cache_retrieval", "cache_miss", "flash_schedule")
+                   "cache_retrieval", "cache_miss", "flash_schedule",
+                   "ssd_plan")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

@@ -34,17 +34,23 @@ xcache.place_compile_cache(min_compile_secs=0.5)
 #: added to its table from here, which is outside the benchmark's paths.
 APPENDED_LATER = {
     "test_granite_cells.py::test_manifest_gained_one_cell_and_three_metrics":
-        {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360"},
+        {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
+         "nemotron3_nano.b1.s8192.v16384"},
     "test_trinity_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
-        {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360"},
+        {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
+         "nemotron3_nano.b1.s8192.v16384"},
     "test_smallthinker_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
-        {"glm47_flash.b1.s8192.v19360"},
+        {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384"},
     # PR 39's eight start-up entries were the tail until PR 41 appended
     "test_setup_readers.py::"
     "test_the_manifest_gained_eight_entries_that_move_setup_s":
-        {"glm47_flash.b1.s8192.v19360"},
+        {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384"},
+    # PR 41's six entries were the tail until PR 43 appended
+    "test_glm47_cells.py::"
+    "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
+        {"nemotron3_nano.b1.s8192.v16384"},
 }
 
 

@@ -763,3 +763,155 @@ def test_online_causal_schedule_compiles_at_the_cells_widths(one_chip, B, S,
              for name, b in zip(fa.ONLINE_KERNELS, blocks)]
     assert all(p.walk and p.split and p.sub == 512 for p in plans)
     assert blocks[1] == ((512, 1024) if D == 256 else (1024, 1024))
+
+
+# -- Nemotron-3-Nano's share (models/nemotron_h.py): the scan's kernels at 8
+# -- B/C groups and chunk 128, grouped matmuls at an expert width of 14.5 lane
+# -- tiles, ungated experts behind the sigmoid router, GQA 32/2, the whole step
+
+
+@pytest.mark.parametrize("dtype,heads", [(BF16, 32), (jnp.float32, 16)],
+                         ids=["bf16", "fp32"])
+def test_ssd_kernels_compile_at_nemotron_widths(one_chip, as_tpu, dtype,
+                                                heads):
+    """H64 P64 N128 with 8 B/C groups at chunk 128 over one 8,192 sequence:
+    the kernel pair serves it, forward and all six cotangents, at a program of
+    four whole groups in bf16 and of two in float32; the [64, 64, 128, 128]
+    float32 tiles of the ``jax.numpy`` scan are nowhere."""
+    from pytorch_distributed_training_example_tpu.ops import ssd
+
+    S, H, Pd, N, G = 8192, 64, 64, 128, 8
+    assert ssd._kernel_plan(H, Pd, N, 128, dtype, G) == heads
+    f32 = jnp.float32
+    args = (_sds((1, S, H, Pd), one_chip, dtype), _sds((1, S, H), one_chip, f32),
+            _sds((H,), one_chip, f32), _sds((1, S, G, N), one_chip, dtype),
+            _sds((1, S, G, N), one_chip, dtype), _sds((H,), one_chip, f32))
+    total = lambda *a: ssd.ssd(*a, chunk=128).astype(f32).sum()
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert "64,128,128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def test_grouped_ffn_compiles_at_a_width_off_the_lane_tiling(one_chip,
+                                                             as_tpu):
+    """d 2688 (21 lane tiles) and f 1856 (14.5): every one of the six grouped
+    products lowers, in column blocks of whole lane tiles whose last one is
+    part-filled (this call failed in Mosaic's block check before PR 43)."""
+    T, E, d, ffn = 3072, 8, 2688, 1856
+    assert grouped_matmul._block_cols(ffn, d, 2) == 640    # 3 blocks: 5 + 5 + 4.5
+    assert grouped_matmul._block_cols(ffn, d, 4) == 384
+    assert grouped_matmul._block_cols(d, ffn, 2) == 896    # 3 x 7 tiles
+    seg = _sds((E,), one_chip, jnp.int32)
+
+    def grads(x, w_up, w_down, starts, counts):
+        return _grads(lambda x, wu, wd: grouped_matmul.grouped_ffn(
+            x, wu, wd, starts, counts))(x, w_up, w_down)
+
+    text = _compiled_text(grads, _sds((T, d), one_chip),
+                          _sds((E, d, ffn), one_chip),
+                          _sds((E, ffn, d), one_chip), seg, seg)
+    assert "grouped_matmul_dw" in text
+
+
+def _nemotron_layer(one_chip):
+    """Nemotron-3-Nano's expert layer as shapes on the chip: 8 held of 128,
+    6 a token, 8,192 tokens of 2688, ungated experts of 1856 beside a shared
+    one of 3712."""
+    from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
+
+    layer = moe_lib.SharedExpertMoE(
+        num_experts=128, ffn_dim=1856, top_k=6, held_experts=(8, 0),
+        shared_ffn_dim=3712, route_scale=2.5, balance_coeff=0.001,
+        gated=False, dtype=BF16, param_dtype=jnp.float32)
+    x = jnp.zeros((1, 8192, 2688), BF16)
+    shapes = jax.eval_shape(lambda: layer.init(jax.random.key(0), x,
+                                               train=False))
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _sds(s.shape, one_chip, s.dtype), tree)
+    return (layer, on_chip(shapes["params"]), on_chip(shapes["batch_stats"]),
+            _sds(x.shape, one_chip))
+
+
+def test_ungated_held_experts_run_the_routed_forward_twice(one_chip, as_tpu):
+    """The ungated layer under the block's remat, with a consumer that needs
+    its output: the leaves keep the published shapes (no padded copy of a
+    weight), and on the whole layout's side of the ``cond``s the step holds
+    the forward's two grouped matmuls, the recomputation's two, and of the
+    backward the down projection again, two ``dx`` and two ``dw``: the routed
+    forward runs twice a step, not three times; ``up`` crosses the ``cond``
+    in the compute dtype."""
+    import re
+    from collections import Counter
+
+    layer, params, stats, x = _nemotron_layer(one_chip)
+    assert params["w_up"].shape == (8, 2688, 1856)
+    assert params["w_down"].shape == (8, 1856, 2688)
+    assert "w_gate" not in params and set(params["shared"]) == {"up", "down"}
+
+    def grads(params, stats, x):
+        block = jax.checkpoint(
+            lambda p, x: jnp.sin(layer.apply(
+                {"params": p, "batch_stats": stats}, x, train=False).astype(
+                    jnp.float32)),
+            prevent_cse=False,
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return jax.grad(lambda p, x: block(p, x).sum(), argnums=(0, 1))(
+            params, x)
+
+    text = _compiled_text(grads, params, stats, x)
+    found = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"%(grouped_matmul(?:_dw)?)[.\d]* = [^\n]*tpu_custom_call"
+        r"[^\n]*op_name=\"([^\"]*)\"", text) if "/while/" not in m.group(2)]
+    calls = Counter(name for name, _ in found)
+    # (2 + 2 + 3; XLA may fold the recomputation into the forward it equals)
+    assert 0 < calls["grouped_matmul"] <= 7, calls
+    assert calls["grouped_matmul_dw"] == 2, calls
+    backward = Counter(name for name, scope in found if "transpose(" in scope
+                       and "rematted_computation" not in scope)
+    assert backward == {"grouped_matmul": 3, "grouped_matmul_dw": 2}, backward
+    # the bounded layout: 8,192 x 6 pairs in a 16th of... 8 parts: 56 tiles
+    crossing = re.findall(r" = (\(.*?\)) conditional\(", text)
+    assert crossing and any("bf16[7168,1856]" in c for c in crossing)
+    assert not any("f32[7168,1856]" in c for c in crossing)
+    assert "[8,2688,1920]" not in text and "[8,2688,2048]" not in text
+    for scope in ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+                  "moe_shared"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_flash_compiles_at_nemotron_widths(one_chip):
+    """B1 S8192 H32/2 D128, causal, no window: the three online kernels, the
+    keys' and values' gradients folded back from 32 heads to 2 outside them."""
+    q = _sds((1, 8192, 32, 128), one_chip)
+    kv = _sds((1, 8192, 2, 128), one_chip)
+    text = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True)), q, kv, kv)
+    for name in fa.ONLINE_KERNELS:
+        assert name in text, name
+    assert "flash_fwd_window" not in text
+
+
+@pytest.mark.slow  # the TPU compiler on every core for minutes, as Trinity's
+def test_nemotron_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``nemotron3_nano_share`` at 1 x 8192, bf16,
+    per-block remat, AdamW) compiles for a described v5e under the chip's
+    memory, with the scan's kernels four times each way, the online flash
+    kernels once and the four expert layers' grouped matmuls."""
+    import re
+    from collections import Counter
+
+    compiled, mem, held = _share_step("nemotron3_nano_share", one_chip)
+    assert mem.argument_size_in_bytes == pytest.approx(666_962_944 * 12,
+                                                       rel=1e-3)
+    assert held < 16.0e9, held
+    text = compiled.as_text()
+    calls = Counter(m.group(1) for m in re.finditer(
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
+    assert calls["ssd_bwd"] == 4 and calls["ssd_fwd"] >= 4, calls
+    for name in fa.ONLINE_KERNELS:
+        assert calls[name] == 1, calls
+    # four layers' two matrices, on both sides of the bounded layout's cond
+    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 4 * 2 * 2
