@@ -12,6 +12,14 @@ cumulative sums and layouts around them, for ``grad`` the forward too),
 prints, on N seeds and for both paths, every cotangent's distance from a float32 scan at
 matmul precision ``highest`` (relative error, and the norm gap the chip
 benchmark's ``grad_leaf`` is made of).
+
+``--stages`` times the mixer's two elementwise stages alone instead
+(``ops/ssd.conv_silu``, ``ops/ssd.gate_norm``), each at both cells' shapes,
+the kernels beside the ``jax.numpy`` forms: ``fwd`` is the call, ``bwd`` every
+cotangent from a given one (for the ``jax.numpy`` form whatever XLA runs for
+that, its forward's share included); ``floor_ms`` is the bytes the stage must
+move at the chip's HBM peak and ``floor_pct`` that over ``ms``. ``--tiles``
+tries other [rows x cols] tiles than the planner's.
 """
 
 from __future__ import annotations
@@ -25,6 +33,17 @@ from unittest import mock
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [_HERE, os.path.dirname(_HERE)]
+
+
+#: What a kernel of ``ops/ssd.py`` is called in a trace.
+KERNELS = ("ssd_", "conv_silu_", "gate_norm_")
+#: The chip benchmark's peaks by ``device_kind``, with their source. A device
+#: that is not there is an error.
+PEAKS = os.path.join(os.path.dirname(_HERE), "chipbench", "peaks.json")
+#: The stages at the two cells' shapes: S, the conv's channels, the inner
+#: channels, the norm's groups.
+STAGE_SHAPES = {"nemotron3_nano": (8192, 6144, 4096, 8),
+                "granite4_h_micro": (4096, 4352, 4096, 1)}
 
 
 def inputs(b, S, H, P, N, seed=0):
@@ -65,7 +84,7 @@ def device_ms(fn, args, reps):
     kernels, others = {}, {}
     for event, (ns, _) in ops.items():
         name = event.split(" = ", 1)[0].strip().lstrip("%")
-        into = kernels if name.startswith("ssd_") else others
+        into = kernels if name.startswith(KERNELS) else others
         name = name.split(".")[0] if into is kernels else name
         into[name] = into.get(name, 0.0) + ns / reps / 1e6
     top = dict(sorted(others.items(), key=lambda kv: -kv[1])[:5])
@@ -111,6 +130,105 @@ def check(fns, args, w, seed):
         print(json.dumps(row), flush=True)
 
 
+def stage_errors(fns, exact, args, ct):
+    """One row an impl: the result's and every cotangent's distance from
+    ``exact`` (the ``jax.numpy`` form on the same operands held in float32,
+    float32 out), relative to the exact one's norm."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+
+    def both(fn, operands, ct):
+        out, back = jax.vjp(fn, *operands)
+        return (out,) + back(ct.astype(out.dtype))
+
+    want = jax.jit(lambda *a: both(exact, a, ct.astype(f32)))(
+        *(a.astype(f32) for a in args))
+    norm = lambda a: float(jnp.linalg.norm(a.astype(f32).ravel()))
+    for impl, fn in fns.items():
+        got = jax.jit(lambda *a: both(fn, a, ct))(*args)
+        yield impl, [norm(g.astype(f32) - t) / norm(t)
+                     for g, t in zip(got, want)]
+
+
+def stages(ssd, iters, tiles, seed, check=0):
+    """One row a (stage, cell's shape, impl, pass); with ``check``, one more
+    an impl: ``rel_err`` of the result and of each cotangent."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, bf = jnp.float32, jnp.bfloat16
+    with open(PEAKS) as fh:
+        peak = json.load(fh)["kinds"][jax.devices()[0].device_kind][
+            "hbm_bytes_per_s"]
+    conv_body = lambda x, w, c: jax.nn.silu(ssd.causal_conv1d(x, w, c))
+    gate_body = lambda groups, dtype: lambda y, z, g: ssd.group_rms_norm(
+        y * jax.nn.silu(z.astype(f32)), g, groups, 1e-5, dtype)
+    for cell, (S, conv_dim, inner, groups) in STAGE_SHAPES.items():
+        k = jax.random.split(jax.random.PRNGKey(seed), 8)
+        normal = lambda i, C, dtype: jax.random.normal(k[i], (1, S, C), dtype)
+        # channels, bytes an element (fwd, bwd), operands, the cotangent,
+        # the jax.numpy form, the same with float32 out, the stage as
+        # planned, and at a given tile
+        cases = {
+            "conv_silu": (
+                conv_dim, (2 * 2, 3 * 2),
+                (normal(0, conv_dim, bf),
+                 0.5 * jax.random.normal(k[1], (4, conv_dim)),
+                 0.1 * jax.random.normal(k[2], (conv_dim,))),
+                normal(3, conv_dim, bf),
+                conv_body, conv_body, ssd.conv_silu,
+                lambda plan: lambda x, w, c: ssd._conv_silu_kernels(
+                    x, x, w, c, (plan, 0))),
+            "gate_norm": (
+                inner, (4 + 2 + 2, 4 + 2 + 2 + 4 + 2),
+                (normal(4, inner, f32), normal(5, inner, bf),
+                 1.0 + 0.1 * jax.random.normal(k[6], (inner,))),
+                normal(7, inner, bf),
+                gate_body(groups, bf), gate_body(groups, f32),
+                lambda *a, groups=groups: ssd.gate_norm(
+                    *a, groups=groups, epsilon=1e-5, dtype=bf),
+                lambda plan: lambda *a: ssd._gate_norm_kernels(
+                    *a, (plan, groups, 1e-5, jnp.dtype(bf)))),
+        }
+        for stage, (C, per_elem, args, ct, xla, exact, planned,
+                    at) in cases.items():
+            fns = {"xla": xla, "kernels": planned}
+            if check:
+                for impl, errors in stage_errors(fns, exact, args, ct):
+                    print(json.dumps({
+                        "stage": stage, "cell": cell, "impl": impl,
+                        "check": "against float32 operands",
+                        "rel_err": dict(zip(("out", "d0", "d1", "d2"),
+                                            errors))}), flush=True)
+            # a tile's columns divide the channels and hold whole groups
+            width = C // groups if stage == "gate_norm" else 128
+            fns.update({"kernels:%dx%d" % t: at(t) for t in tiles
+                        if C % t[1] == 0 and t[1] % width == 0})
+            for impl, fn in fns.items():
+                for tag, nbytes, run, operands in (
+                        ("fwd", per_elem[0], fn, args),
+                        ("bwd", per_elem[1],
+                         lambda *a, fn=fn: jax.vjp(fn, *a[:-1])[1](a[-1]),
+                         args + (ct,))):
+                    floor = S * C * nbytes / peak * 1e3
+                    row = {"stage": stage, "cell": cell, "shape": [S, C],
+                           "groups": groups, "impl": impl, "pass": tag,
+                           "floor_ms": round(floor, 4)}
+                    try:
+                        ms, kernels, others = device_ms(run, operands, iters)
+                        rounded = lambda d: {n: round(v, 4)
+                                             for n, v in d.items()}
+                        row.update(ms=round(ms, 4),
+                                   floor_pct=round(100 * floor / ms, 2),
+                                   kernels=rounded(kernels),
+                                   others=rounded(others))
+                    except Exception as e:  # a tile the compiler refuses
+                        row["error"] = str(e).strip().splitlines()[-1][-300:]
+                    print(json.dumps(row), flush=True)
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--shape", default="1x4096x64x64x128",
@@ -125,6 +243,12 @@ def main():
                    help="compare the cotangents with float32 at highest on "
                         "this many seeds")
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--stages", action="store_true",
+                   help="time conv_silu and gate_norm alone at both cells' "
+                        "shapes instead of the scan")
+    p.add_argument("--tiles", default="",
+                   help="with --stages: comma-separated ROWSxCOLS tiles to "
+                        "try besides the planner's")
     args = p.parse_args()
 
     import jax
@@ -135,6 +259,12 @@ def main():
     if jax.default_backend() != "tpu":
         sys.exit("ssd_micro.py times kernels on the chip; this is "
                  + jax.default_backend())
+    if args.stages:
+        print(json.dumps({"stages": STAGE_SHAPES,
+                          "device": jax.devices()[0].device_kind}), flush=True)
+        tiles = [tuple(int(v) for v in t.split("x"))
+                 for t in args.tiles.split(",") if t]
+        return stages(ssd, args.iters, tiles, args.seed, args.check)
     b, S, H, P, N = (int(v) for v in args.shape.split("x"))
     groups = [int(g) for g in args.groups.split(",") if g]
     operands, w = inputs(b, S, H, P, N, args.seed)

@@ -26,7 +26,6 @@ Training only: serving needs a recurrent-state cache beside the KV pages
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any
 
@@ -79,7 +78,8 @@ class MambaMixer(nn.Module):
                                 (self.conv_width, conv_dim), self.param_dtype)
             bias = self.param("conv_bias", nn.initializers.zeros,
                               (conv_dim,), self.param_dtype)
-            xBC = nn.silu(ssd_lib.causal_conv1d(xBC, kernel, bias))
+            xBC = ssd_lib.conv_silu(xBC, kernel, bias, source=zxbcdt,
+                                    offset=inner)
         x, B, C = jnp.split(xBC, [inner, inner + G * N], axis=-1)
         if G > 1:
             B, C = B.reshape(b, S, G, N), C.reshape(b, S, G, N)
@@ -100,30 +100,30 @@ class MambaMixer(nn.Module):
             # blind to its input's scale, so ``D``'s gradient is what is left
             # of two terms that all but cancel: one bf16 rounding of ``y`` (or
             # of its cotangent) in between puts it off by a percent.
-            y = y.reshape(b, S, inner) * nn.silu(z.astype(jnp.float32))
-            norm = RMSNorm if G == 1 else functools.partial(GroupRMSNorm, G)
-            y = norm(self.epsilon, self.dtype, self.param_dtype,
-                     name="norm")(y)
+            y = GroupRMSNorm(G, self.epsilon, self.dtype, self.param_dtype,
+                             name="norm")(y.reshape(b, S, inner), gate=z)
         return dense(d, "out_proj")(y)
 
 
 class GroupRMSNorm(nn.Module):
     """``RMSNorm`` over each of ``groups`` equal runs of the last axis by
-    itself, with one scale vector over them all."""
+    itself, with one scale vector over them all; with ``gate``, of ``x *
+    silu(gate)`` (``x`` float32, and float32 throughout:
+    ``ops/ssd.gate_norm``)."""
     groups: int
     epsilon: float = 1e-5
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, gate=None):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            self.param_dtype)
-        x32 = x.astype(jnp.float32).reshape(*x.shape[:-1], self.groups, -1)
-        norm = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
-        return (norm.reshape(x.shape)
-                * scale.astype(jnp.float32)).astype(self.dtype)
+        if gate is not None:
+            return ssd_lib.gate_norm(x, gate, scale, groups=self.groups,
+                                     epsilon=self.epsilon, dtype=self.dtype)
+        return ssd_lib.group_rms_norm(x, scale, self.groups, self.epsilon,
+                                      self.dtype)
 
 
 class GraniteAttention(nn.Module):

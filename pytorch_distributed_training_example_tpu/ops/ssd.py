@@ -1,5 +1,7 @@
-"""State-space duality (SSD) scan of Mamba-2, chunked, and the causal
-depthwise convolution that feeds it (Dao & Gu 2024, arXiv:2405.21060).
+"""State-space duality (SSD) scan of Mamba-2, chunked, with the mixer's two
+elementwise stages around it: the causal depthwise convolution with its silu
+that feeds it (:func:`conv_silu`) and the gate with its group norm that
+follows it (:func:`gate_norm`) (Dao & Gu 2024, arXiv:2405.21060).
 
 Per head (``x_t`` in R^P, one scalar decay ``A < 0``, ``B_t``/``C_t`` in R^N
 shared by the heads of a group: head ``h`` of ``H`` reads group ``h // (H /
@@ -29,6 +31,12 @@ shows (dtype, chunk, head and state widths, groups):
   as a [chunks x chunks] lower-triangular product per head, no sequential
   loop): every shape the plan refuses, and what the kernels are tested
   against. Its [chunks, H, chunk, chunk] tiles pass through HBM.
+
+The two stages are chosen the same way (:func:`_stage_plan`): a row-tiled
+kernel pair under a ``custom_vjp`` each (``conv_silu_fwd`` / ``conv_silu_bwd``,
+``gate_norm_fwd`` / ``gate_norm_bwd``) that reads its operands from HBM once
+and writes its results once, or the ``jax.numpy`` bodies
+(:func:`causal_conv1d`, :func:`group_rms_norm`).
 
 No packed documents (no state or mask resets) and no recurrent-state cache
 for serving: one document a sequence.
@@ -121,12 +129,18 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y[:, :S] if pad else y
 
 
-def _say_plan(H, P, N, groups, Q, plan):
-    """One ``ssd_plan`` record a traced call of :func:`ssd`, under the span
-    that caused the trace: whether the kernels engaged and at how many heads
-    a program is static, so its counter is a record."""
+def _say(name, value):
+    """One record of no duration under the span that caused the trace (a name
+    of ``telemetry.COMPILE_RECORDS``): a program is static, so the counter of
+    whether its kernels engaged is a record a traced call."""
     from pytorch_distributed_training_example_tpu.utils import telemetry
-    telemetry.recorder().compile_event("ssd_plan", 0.0, {
+    telemetry.recorder().compile_event(name, 0.0, value)
+
+
+def _say_plan(H, P, N, groups, Q, plan):
+    """One ``ssd_plan`` record a traced call of :func:`ssd`: whether the
+    kernels engaged and at how many heads a program."""
+    _say("ssd_plan", {
         "H": H, "P": P, "N": N, "groups": groups, "chunk": Q,
         "heads_per_program": "xla" if plan is None else plan})
 
@@ -683,10 +697,11 @@ def _bwd_call(x, dt, cum, B, C, D, states, dy, *, plan):
 
 
 def _per_device(fn, *args, n_out):
-    """``fn`` over arrays whose first axis is the batch (``D`` [H] apart),
-    per device of the ambient mesh with the batch sharded: GSPMD cannot
-    partition a Mosaic call (``core/mesh.manual_call``). A batch the
-    data-parallel axes do not divide is replicated."""
+    """``fn`` over [b, S, ..] arrays whose first axis is the batch (a
+    parameter, of one or two axes, is every device's whole), per device of
+    the ambient mesh with the batch sharded: GSPMD cannot partition a Mosaic
+    call (``core/mesh.manual_call``). A batch the data-parallel axes do not
+    divide is replicated. ``n_out`` results, or with None the one array."""
     from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 
     mesh = mesh_lib.current_mesh()
@@ -698,10 +713,11 @@ def _per_device(fn, *args, n_out):
         if not axes or args[0].shape[0] % ways:
             axes = None
     spec = lambda a: (PartitionSpec(axes, *([None] * (a.ndim - 1)))
-                      if a.ndim > 1 else PartitionSpec())
+                      if a.ndim > 2 else PartitionSpec())
+    out = PartitionSpec(axes)
     return mesh_lib.manual_call(
         fn, *args, mesh=mesh, in_specs=tuple(spec(a) for a in args),
-        out_specs=tuple(PartitionSpec(axes) for _ in range(n_out)))
+        out_specs=out if n_out is None else (out,) * n_out)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -728,3 +744,497 @@ def _scan_bwd(plan, res, dy):
 
 
 _scan_kernels.defvjp(_scan_fwd, _scan_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The mixer's two elementwise stages, each one pass over HBM a direction:
+# :func:`conv_silu` (the causal conv with its bias and silu) and
+# :func:`gate_norm` (the gate with its group norm). A program is one
+# (sequence, column block, row tile); the grid walks a column block's row
+# tiles in order (the conv's backward in reverse), so that the conv's history
+# and every parameter's gradient are carried in VMEM from tile to tile. Both
+# are chosen by :func:`_stage_plan` the way the scan's pair is, from dtype and
+# widths, and fall back to the ``jax.numpy`` bodies (:func:`causal_conv1d`
+# with ``silu``; :func:`group_rms_norm` of the gated value).
+# ---------------------------------------------------------------------------
+
+#: Rows of history a conv tile carries in VMEM: one float32 sublane tile,
+#: which is also what holds the taps with the bias, and their gradients.
+HALO = 8
+#: Rows the conv's kernels work at a time: a strip of a tile whose float32
+#: values stay in registers from the load to the store (a sublane tile of
+#: bf16). Whole tiles at a time, every elementwise step went through VMEM.
+STRIP = 16
+#: Elements of a program's [rows, cols] tile, and the widest column block of
+#: the conv, and of the norm where a group is narrower (a block of the norm is
+#: whole groups). ``benchmarks/ssd_micro.py --stages --tiles`` timed others
+#: of as many elements: the norm's within 2% of these, the conv's up to 5%
+#: slower ([128, 1024], [512, 256]), and at half of them 28% (forward).
+STAGE_TILE = 128 * 1024
+STAGE_COLS = 512
+
+
+def _stage_plan(stage, S, C, groups, dtype, K=1):
+    """``(rows, cols)`` of a program's tile of ``stage`` (``conv_silu`` or
+    ``gate_norm``) over ``[S, C]``, or None where the ``jax.numpy`` body runs.
+
+    Admitted: bf16 or float32 (Mosaic refuses fp16 loads); channels on the
+    128-lane tiling, for the norm every group's run of them; a conv of at most
+    7 taps (its history is one 8-row tile, and its taps' and bias's gradients
+    one); a sequence of at least one tile's rows. The conv's columns are the
+    most lane tiles up to 512 lanes that divide C; the norm's are whole
+    groups, as many as 512 lanes hold or one where a group is wider. The rows
+    follow from :data:`STAGE_TILE` elements a tile, in whole strips (which are
+    bf16's sublane tiles): [256, 512] for both stages at
+    Nemotron-3-Nano's widths (6144 channels; eight groups of 512), [512, 256]
+    and [32, 4096] at Granite's (4352; one group)."""
+    if dtype not in (jnp.bfloat16, jnp.float32):
+        return None
+    if C % groups or (C // groups) % LANES:
+        return None
+    if stage == "conv_silu":
+        if not 1 <= K <= HALO - 1:
+            return None
+        cols = next(c for c in range(STAGE_COLS, 0, -LANES) if C % c == 0)
+    else:
+        width = C // groups
+        held = max((n for n in range(1, groups + 1)
+                    if groups % n == 0 and n * width <= STAGE_COLS), default=1)
+        cols = held * width
+    rows = max(STRIP, STAGE_TILE // cols // STRIP * STRIP)
+    return (rows, cols) if S >= rows else None
+
+
+def _say_stage_plan(stage, S, C, groups, plan):
+    """One ``mixer_plan`` record a traced call of a stage: the tile, or
+    ``"xla"``."""
+    _say("mixer_plan", {
+        "stage": stage, "rows": S, "channels": C, "groups": groups,
+        "tile": "xla" if plan is None else list(plan)})
+
+
+def _silu_and_slope(v, rounded=False):
+    """``silu(v)`` and its derivative, from one sigmoid. ``rounded``: the
+    operands and the results are bf16, and the sigmoid is taken as a ``tanh``,
+    one transcendental and no division: 1e-5 off on the chip where the
+    exponential's is 1e-7, under bf16's 4e-3 (a parameter's gradient, summed
+    in float32, comes out 6e-6 off where the ``jax.numpy`` body's is 4.5e-6),
+    for an eighth of the conv's kernels' time (``benchmarks/ssd_micro.py
+    --stages --check 1``)."""
+    s = 0.5 * jnp.tanh(0.5 * v) + 0.5 if rounded else jax.nn.sigmoid(v)
+    return v * s, s * (1.0 + v * (1.0 - s))
+
+
+def _valid_rows(first, rows, S):
+    """[rows, 1] mask of the rows from ``first`` on that the sequence has."""
+    at = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    return first + at < S
+
+
+def _strip(at):
+    """The STRIP rows from ``at``, a traced multiple of STRIP."""
+    return pl.ds(pl.multiple_of(at, STRIP), STRIP)
+
+
+def _over_strips(rows, body, carry, reverse=False):
+    """``body(at, carry)`` for each strip of a tile in turn, in a loop left
+    rolled: a strip is hundreds of vector operations, and unrolled the
+    sixteen or thirty-two copies of it cost every start-up seconds of tracing
+    and lowering (5 to 10 s of ``setup_s`` on the chip's host)."""
+    last = rows // STRIP - 1
+    return jax.lax.fori_loop(
+        0, last + 1,
+        lambda i, carry: body((last - i if reverse else i) * STRIP, carry),
+        carry)
+
+
+def _rows_back(strip, before, shift):
+    """``strip`` [STRIP, cols] ``shift`` rows back, its first rows from the
+    end of ``before`` [HALO, cols]: a sublane rotation of the two stacked
+    (Mosaic loads no strip at a traced offset off the sublane tiling)."""
+    if not shift:
+        return strip
+    return pltpu.roll(jnp.concatenate([before, strip], axis=0), shift,
+                      0)[HALO:]
+
+
+def _rows_on(strip, after, shift):
+    """``strip`` ``shift`` rows on, its last rows from the start of
+    ``after`` [HALO, cols]."""
+    if not shift:
+        return strip
+    return pltpu.roll(jnp.concatenate([strip, after], axis=0),
+                      STRIP + HALO - shift, 0)[:STRIP]
+
+
+def _pre_activation(x, before, taps):
+    """``bias + sum_k taps[k] x_{t-(K-1)+k}`` for a strip ``x`` with the HALO
+    rows ``before`` it; ``taps``: the K taps and the bias, [1, cols] each.
+    Returns it and the K shifted strips, tap by tap."""
+    K = len(taps) - 1
+    reads = [_rows_back(x, before, K - 1 - k) for k in range(K)]
+    pre = taps[K]
+    for read, tap in zip(reads, taps):
+        pre = pre + read * tap
+    return pre, reads
+
+
+def _conv_silu_fwd_kernel(x_ref, taps_ref, o_ref, history, *, K):
+    """``x_ref``, ``o_ref`` [1, rows, cols]; ``taps_ref`` [8, cols] float32,
+    rows 0..K-1 the taps and row K the bias; ``history`` [HALO, cols] float32
+    scratch: the last rows of the tile before, zero at a sequence's start. A
+    strip hands its own last rows to the next as the loop's carry."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        history[:] = jnp.zeros(history.shape, F32)
+
+    taps = [taps_ref[k:k + 1, :] for k in range(K + 1)]
+
+    def strip(at, before):
+        x = x_ref[0, _strip(at), :].astype(F32)
+        pre, _ = _pre_activation(x, before, taps)
+        o_ref[0, _strip(at), :] = _silu_and_slope(
+            pre, o_ref.dtype == jnp.bfloat16)[0].astype(o_ref.dtype)
+        return x[STRIP - HALO:]
+
+    history[:] = _over_strips(x_ref.shape[1], strip, history[:])
+
+
+def _conv_silu_bwd_kernel(x_ref, before_ref, dy_ref, taps_ref,
+                          dx_ref, dtaps_ref, after, *, K, S):
+    """The row tiles last first, and a tile's strips last first.
+    ``before_ref`` [1, h, cols]: the sublane tile of ``x`` that ends where the
+    tile starts (the pre-activation is recomputed from ``x``; inside a tile
+    the rows before a strip are read from ``x_ref`` again). ``after`` [HALO,
+    cols] float32 scratch: the first rows of the pre-activation's cotangent
+    of the tile that follows, zero at a sequence's end; a strip hands its own
+    first rows to the one before it as the loop's carry. ``dtaps_ref`` [1, 8,
+    cols] float32 gathers the taps' (rows 0..K-1) and the bias's (row K)
+    gradients over a column block's tiles."""
+    r = pl.program_id(2)
+    tile = pl.num_programs(2) - 1 - r
+    rows, cols = x_ref.shape[1:]
+
+    @pl.when(r == 0)
+    def _():
+        after[:] = jnp.zeros(after.shape, F32)
+        dtaps_ref[0] = jnp.zeros(dtaps_ref.shape[1:], F32)
+
+    ahead = before_ref[0].astype(F32)
+    ahead = jnp.where(tile == 0, 0.0, ahead[ahead.shape[0] - HALO:])
+    taps = [taps_ref[k:k + 1, :] for k in range(K + 1)]
+    fold = lambda v: sum(v[i:i + HALO] for i in range(0, STRIP, HALO))
+
+    def load(ref, at):
+        v = ref[0, _strip(at), :].astype(F32)
+        if S % rows:   # the last tile's rows past the sequence hold anything
+            v = jnp.where(_valid_rows(tile * rows + at, STRIP, S), v, 0.0)
+        return v
+
+    def strip(at, carry):
+        following, sums = carry
+        x, dy = load(x_ref, at), load(dy_ref, at)
+        before = jnp.where(at == 0, ahead, load(
+            x_ref, jnp.maximum(at - STRIP, 0))[STRIP - HALO:])
+        pre, reads = _pre_activation(x, before, taps)
+        dpre = dy * _silu_and_slope(pre, dx_ref.dtype == jnp.bfloat16)[1]
+        # dx_t = sum_k taps[k] dpre_{t+(K-1)-k}
+        dx_ref[0, _strip(at), :] = sum(
+            _rows_on(dpre, following, K - 1 - k) * taps[k]
+            for k in range(K)).astype(dx_ref.dtype)
+        return dpre[:HALO], [
+            acc + fold(dpre * read) for acc, read in zip(sums, reads)] + [
+            sums[K] + fold(dpre)]
+
+    # a parameter's sum over the tile, a sublane a partial sum until the end
+    following, sums = _over_strips(
+        rows, strip, (after[:], [jnp.zeros((HALO, cols), F32)] * (K + 1)),
+        reverse=True)
+    after[:] = following
+    for k, acc in enumerate(sums):
+        dtaps_ref[0, k:k + 1, :] += jnp.sum(acc, axis=0, keepdims=True)
+
+
+def _tiles_in_order(*sliced):
+    """The stages' grid: (sequence, column block, row tile), the tiles in
+    order. ``sliced``, an operand each where any: whether XLA may fuse what
+    makes it into the call's reads. The norm is handed ``z``, a lane slice of
+    ``zxbcdt``: fused, the kernel reads it where it lies and no copy of the
+    slice is made. (Not the conv's operands: with a loop in the kernel the
+    chip's compiler fails on a fused operand's staging buffer, so the conv
+    reads its slice of ``zxbcdt`` through its own index maps.)"""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        allow_input_fusion=list(sliced) or None)
+
+
+def _taps_block(kernel, bias):
+    """[8, C] float32: the taps, then the bias."""
+    K, C = kernel.shape
+    return jnp.concatenate([kernel.astype(F32), bias.astype(F32)[None],
+                            jnp.zeros((HALO - K - 1, C), F32)])
+
+
+def _conv_tiles(plan, offset, order):
+    """Block specs of the conv's kernels over the grid (sequence, column
+    block, row tile): a [rows, cols] tile of the source, whose lanes from
+    ``offset`` on are the conv's channels, and of an array of the channels
+    alone; ``order`` maps the grid's row index to the tile."""
+    rows, cols = plan
+    skip = offset // cols
+    return (pl.BlockSpec((1, rows, cols),
+                         lambda i, j, r: (i, order(r), skip + j)),
+            pl.BlockSpec((1, rows, cols), lambda i, j, r: (i, order(r), j)))
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "offset"))
+def _conv_silu_fwd_call(source, kernel, bias, *, plan, offset):
+    rows, cols = plan
+    b, S, _ = source.shape
+    K, C = kernel.shape
+    read, written = _conv_tiles(plan, offset, lambda r: r)
+    return pl.pallas_call(
+        functools.partial(_conv_silu_fwd_kernel, K=K),
+        name="conv_silu_fwd",
+        grid=(b, C // cols, pl.cdiv(S, rows)),
+        in_specs=[read, pl.BlockSpec((HALO, cols), lambda i, j, r: (0, j))],
+        out_specs=written,
+        out_shape=jax.ShapeDtypeStruct((b, S, C), source.dtype),
+        scratch_shapes=[pltpu.VMEM((HALO, cols), F32)],
+        compiler_params=_tiles_in_order(),
+        interpret=not backend.on_tpu(),
+    )(source, _taps_block(kernel, bias))
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "offset"))
+def _conv_silu_bwd_call(source, kernel, bias, dy, *, plan, offset):
+    """``dx`` and, a sequence, [8, C] float32: the taps' and the bias's
+    gradients."""
+    rows, cols = plan
+    b, S, _ = source.shape
+    K, C = kernel.shape
+    nr = pl.cdiv(S, rows)
+    h = HALO * 4 // source.dtype.itemsize     # the source's sublane tile
+    read, written = _conv_tiles(plan, offset, lambda r: nr - 1 - r)
+    before = pl.BlockSpec((1, h, cols), lambda i, j, r: (
+        i, jnp.maximum((nr - 1 - r) * (rows // h) - 1, 0),
+        offset // cols + j))
+    return pl.pallas_call(
+        functools.partial(_conv_silu_bwd_kernel, K=K, S=S),
+        name="conv_silu_bwd",
+        grid=(b, C // cols, nr),
+        in_specs=[read, before, written,
+                  pl.BlockSpec((HALO, cols), lambda i, j, r: (0, j))],
+        out_specs=(written, pl.BlockSpec((1, HALO, cols),
+                                         lambda i, j, r: (i, 0, j))),
+        out_shape=(jax.ShapeDtypeStruct((b, S, C), source.dtype),
+                   jax.ShapeDtypeStruct((b, HALO, C), F32)),
+        scratch_shapes=[pltpu.VMEM((HALO, cols), F32)],
+        compiler_params=_tiles_in_order(),
+        interpret=not backend.on_tpu(),
+    )(source, source, dy, _taps_block(kernel, bias))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _conv_silu_kernels(x, source, kernel, bias, how):
+    """``how``: (plan, offset), a program's tile and the lane of ``source``
+    where ``x`` starts. ``x`` [b, S, C] is never read: it is there for its
+    cotangent, and the kernels read its values out of ``source`` where they
+    lie, so that a slice of a wider array is not made for their sake."""
+    return _conv_silu_fwd(x, source, kernel, bias, how)[0]
+
+
+def _conv_silu_fwd(x, source, kernel, bias, how):
+    plan, offset = how
+    out = _per_device(
+        functools.partial(_conv_silu_fwd_call, plan=plan, offset=offset),
+        source, kernel, bias, n_out=None)
+    return out, (source, kernel, bias)
+
+
+def _conv_silu_bwd(how, res, dy):
+    plan, offset = how
+    kernel, bias = res[1:]
+    K = kernel.shape[0]
+    dx, dtaps = _per_device(
+        functools.partial(_conv_silu_bwd_call, plan=plan, offset=offset),
+        *res, dy, n_out=2)
+    dtaps = dtaps.sum(0)
+    return (dx, None, dtaps[:K].astype(kernel.dtype),
+            dtaps[K].astype(bias.dtype))
+
+
+_conv_silu_kernels.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(x: jax.Array, kernel: jax.Array, bias: jax.Array, *,
+              source: jax.Array | None = None, offset: int = 0) -> jax.Array:
+    """``silu(causal_conv1d(x, kernel, bias))``: ``x`` [b, S, C] read once and
+    the result written once, each way, where :func:`_stage_plan` admits the
+    shape; the ``jax.numpy`` body where not. Float32 inside, ``x.dtype`` out;
+    the backward recomputes the pre-activation (its residual is what it
+    reads) and sums the taps' and the bias's gradients in float32.
+
+    ``source``, where given, is an array [b, S, W] whose lanes ``offset :
+    offset + C`` are ``x`` (the mixer's ``zxbcdt``): where ``offset`` falls on
+    a column block's edge the kernels read ``x`` out of it, and the slice
+    that ``x`` is is never made."""
+    b, S, C = x.shape
+    plan = _stage_plan("conv_silu", S, C, 1, x.dtype, kernel.shape[0])
+    _say_stage_plan("conv_silu", S, C, 1, plan)
+    if plan is None:
+        return jax.nn.silu(causal_conv1d(x, kernel, bias))
+    if (source is None or source.dtype != x.dtype or offset % plan[1]
+            or source.shape[:2] != x.shape[:2]):
+        source, offset = x, 0
+    return _conv_silu_kernels(x, source, kernel, bias, (plan, offset))
+
+
+def group_rms_norm(x: jax.Array, scale: jax.Array, groups: int,
+                   epsilon: float, dtype) -> jax.Array:
+    """``RMSNorm`` in float32 over each of ``groups`` equal runs of the last
+    axis by itself, times ``scale`` (one vector over them all), as ``dtype``."""
+    x32 = x.astype(F32).reshape(*x.shape[:-1], groups, -1)
+    norm = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
+    return (norm.reshape(x.shape) * scale.astype(F32)).astype(dtype)
+
+
+def _gated_groups(y_ref, z_ref, width):
+    """``(lanes, y, z)`` in float32 for each group, a run of ``width`` lanes,
+    of a program's tile."""
+    for lo in range(0, y_ref.shape[2], width):
+        lanes = slice(lo, lo + width)
+        yield lanes, y_ref[0, :, lanes], z_ref[0, :, lanes].astype(F32)
+
+
+def _gate_norm_fwd_kernel(y_ref, z_ref, scale_ref, o_ref, *, width, epsilon):
+    """``y_ref`` [1, rows, cols] float32, ``z_ref`` the same in the compute
+    dtype, ``scale_ref`` [1, cols] float32; a group is ``width`` lanes."""
+    for lanes, y, z in _gated_groups(y_ref, z_ref, width):
+        g = y * _silu_and_slope(z)[0]
+        r = jax.lax.rsqrt(jnp.mean(g * g, axis=1, keepdims=True) + epsilon)
+        o_ref[0, :, lanes] = (g * r * scale_ref[:, lanes]).astype(o_ref.dtype)
+
+
+def _gate_norm_bwd_kernel(y_ref, z_ref, scale_ref, do_ref,
+                          dy_ref, dz_ref, dscale_ref, *, width, epsilon, S):
+    """Recomputes the gate and the statistics. With ``n`` the normed value
+    and ``dn = do * scale``: ``dg = r (dn - n mean(dn n))``, ``dy = dg
+    silu(z)`` in float32, ``dz = dg y silu'(z)``; ``dscale_ref`` [1, 1, cols]
+    float32 gathers ``do * n`` over a column block's tiles."""
+    tile = pl.program_id(2)
+    rows = y_ref.shape[1]
+
+    @pl.when(tile == 0)
+    def _():
+        dscale_ref[0] = jnp.zeros(dscale_ref.shape[1:], F32)
+
+    valid = _valid_rows(tile * rows, rows, S) if S % rows else None
+    for lanes, y, z in _gated_groups(y_ref, z_ref, width):
+        do = do_ref[0, :, lanes].astype(F32)
+        if valid is not None:   # rows past the sequence hold anything
+            y, z, do = (jnp.where(valid, v, 0.0) for v in (y, z, do))
+        gate, slope = _silu_and_slope(z)
+        g = y * gate
+        r = jax.lax.rsqrt(jnp.mean(g * g, axis=1, keepdims=True) + epsilon)
+        n = g * r
+        dscale_ref[0, :, lanes] += jnp.sum(do * n, axis=0, keepdims=True)
+        dn = do * scale_ref[:, lanes]
+        dg = r * (dn - n * jnp.mean(dn * n, axis=1, keepdims=True))
+        dy_ref[0, :, lanes] = dg * gate
+        dz_ref[0, :, lanes] = (dg * y * slope).astype(dz_ref.dtype)
+
+
+def _gate_norm_specs(plan):
+    rows, cols = plan
+    return (pl.BlockSpec((1, rows, cols), lambda i, j, r: (i, r, j)),
+            pl.BlockSpec((1, cols), lambda i, j, r: (0, j)))
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "groups", "epsilon",
+                                             "dtype"))
+def _gate_norm_fwd_call(y, z, scale, *, plan, groups, epsilon, dtype):
+    rows, cols = plan
+    b, S, C = y.shape
+    tile, lane = _gate_norm_specs(plan)
+    return pl.pallas_call(
+        functools.partial(_gate_norm_fwd_kernel, width=C // groups,
+                          epsilon=epsilon),
+        name="gate_norm_fwd",
+        grid=(b, C // cols, pl.cdiv(S, rows)),
+        in_specs=[tile, tile, lane],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(y.shape, dtype),
+        compiler_params=_tiles_in_order(False, True, False),
+        interpret=not backend.on_tpu(),
+    )(y, z, scale.astype(F32)[None])
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "groups", "epsilon"))
+def _gate_norm_bwd_call(y, z, scale, do, *, plan, groups, epsilon):
+    """``dy`` float32, ``dz`` in ``z.dtype`` and, a sequence, the scale's
+    gradient [1, C] float32."""
+    rows, cols = plan
+    b, S, C = y.shape
+    tile, lane = _gate_norm_specs(plan)
+    return pl.pallas_call(
+        functools.partial(_gate_norm_bwd_kernel, width=C // groups,
+                          epsilon=epsilon, S=S),
+        name="gate_norm_bwd",
+        grid=(b, C // cols, pl.cdiv(S, rows)),
+        in_specs=[tile, tile, lane, tile],
+        out_specs=(tile, tile, pl.BlockSpec((1, 1, cols),
+                                            lambda i, j, r: (i, 0, j))),
+        out_shape=(jax.ShapeDtypeStruct(y.shape, F32),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct((b, 1, C), F32)),
+        compiler_params=_tiles_in_order(False, True, False, False),
+        interpret=not backend.on_tpu(),
+    )(y, z, scale.astype(F32)[None], do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gate_norm_kernels(y, z, scale, how):
+    """``how``: (plan, groups, epsilon, dtype)."""
+    return _gate_norm_fwd(y, z, scale, how)[0]
+
+
+def _gate_norm_fwd(y, z, scale, how):
+    plan, groups, epsilon, dtype = how
+    out = _per_device(
+        functools.partial(_gate_norm_fwd_call, plan=plan, groups=groups,
+                          epsilon=epsilon, dtype=dtype),
+        y, z, scale, n_out=None)
+    return out, (y, z, scale)
+
+
+def _gate_norm_bwd(how, res, do):
+    plan, groups, epsilon, _ = how
+    dy, dz, dscale = _per_device(
+        functools.partial(_gate_norm_bwd_call, plan=plan, groups=groups,
+                          epsilon=epsilon), *res, do, n_out=3)
+    return dy, dz, dscale.sum((0, 1)).astype(res[2].dtype)
+
+
+_gate_norm_kernels.defvjp(_gate_norm_fwd, _gate_norm_bwd)
+
+
+def gate_norm(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
+              epsilon: float, dtype) -> jax.Array:
+    """``group_rms_norm(y * silu(z))``: ``y`` [b, S, C] float32 (the scan's,
+    as accumulated), ``z`` [b, S, C] in the compute dtype, ``scale`` [C];
+    ``dtype`` out. Float32 from ``y`` through the gate into the norm and back
+    (``dy`` comes back float32, the scale's gradient is summed in float32);
+    one pass each way where :func:`_stage_plan` admits the shape, the
+    ``jax.numpy`` body where not."""
+    b, S, C = y.shape
+    plan = _stage_plan("gate_norm", S, C, groups, z.dtype)
+    if y.dtype != F32 or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        plan = None
+    _say_stage_plan("gate_norm", S, C, groups, plan)
+    if plan is None:
+        return group_rms_norm(y * jax.nn.silu(z.astype(F32)), scale, groups,
+                              epsilon, dtype)
+    return _gate_norm_kernels(y, z, scale,
+                              (plan, groups, float(epsilon), jnp.dtype(dtype)))

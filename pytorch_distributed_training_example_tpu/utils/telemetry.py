@@ -194,10 +194,13 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: a dict: kernel, S, D, blocks, counts; ops/flash_attention.py), once a
 #: traced call; ``ssd_plan`` likewise, once a traced call of ``ops.ssd.ssd``
 #: (``value``: H, P, N, groups, chunk and the heads a program of the kernels
-#: holds, or ``"xla"`` where the shape took the ``jax.numpy`` scan).
+#: holds, or ``"xla"`` where the shape took the ``jax.numpy`` scan);
+#: ``mixer_plan`` likewise, once a traced call of ``ops.ssd.conv_silu`` or
+#: ``ops.ssd.gate_norm`` (``value``: stage, rows, channels, groups and the
+#: [rows, cols] tile of a program, or ``"xla"``).
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
                    "cache_retrieval", "cache_miss", "flash_schedule",
-                   "ssd_plan")
+                   "ssd_plan", "mixer_plan")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

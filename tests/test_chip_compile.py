@@ -327,6 +327,65 @@ def test_ssd_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
     assert "ssd_fwd" in text and "ssd_bwd" in text and "all-reduce" in text
 
 
+def _stage_calls(S, conv_dim, inner, groups, sharding, b=1):
+    """``{stage: (function, its operands)}`` of the mixer's two elementwise
+    stages at a cell's widths in bf16 (the scan's ``y`` float32)."""
+    from pytorch_distributed_training_example_tpu.ops import ssd
+
+    f32 = jnp.float32
+    rep = sharding if b == 1 else NamedSharding(sharding.mesh, P())
+    return ssd, {
+        "conv_silu": (ssd.conv_silu, (
+            _sds((b, S, conv_dim), sharding), _sds((4, conv_dim), rep, f32),
+            _sds((conv_dim,), rep, f32))),
+        "gate_norm": (
+            lambda *a: ssd.gate_norm(*a, groups=groups, epsilon=1e-5,
+                                     dtype=BF16),
+            (_sds((b, S, inner), sharding, f32), _sds((b, S, inner), sharding),
+             _sds((inner,), rep, f32)))}
+
+
+@pytest.mark.parametrize("S,conv_dim,inner,groups", [
+    (8192, 6144, 4096, 8),     # Nemotron-3-Nano's mixer at one 8,192 sequence
+    (4096, 4352, 4096, 1),     # granite-4.0-h-micro's at one of 4,096
+], ids=["nemotron", "granite"])
+def test_mixer_stage_kernels_compile_at_the_cells_widths(
+        one_chip, as_tpu, S, conv_dim, inner, groups):
+    """``conv_silu`` (K 4) and ``gate_norm``, forward and every cotangent, in
+    bf16: the plan admits both, each kernel is in its program by name inside
+    the scoped VMEM, and nothing the size of the operands is left in HBM
+    beside them (no float32 copy of ``xBC``, no padded cotangent)."""
+    ssd, calls = _stage_calls(S, conv_dim, inner, groups, one_chip)
+    assert ssd._stage_plan("conv_silu", S, conv_dim, 1, BF16, 4)
+    assert ssd._stage_plan("gate_norm", S, inner, groups, BF16)
+    for stage, (fn, args) in calls.items():
+        forward = jax.jit(fn).lower(*args).compile()
+        assert stage + "_fwd" in forward.as_text()
+        assert forward.memory_analysis().temp_size_in_bytes < 2 ** 20
+        total = lambda *a: fn(*a).astype(jnp.float32).sum()
+        backward = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+            *args).compile()
+        assert stage + "_bwd" in backward.as_text()
+        # the cotangent handed in (ones, as the sum's) and the small sums
+        assert backward.memory_analysis().temp_size_in_bytes < (
+            S * max(conv_dim, inner) * 2 * 1.1)
+
+
+def test_mixer_stages_under_four_device_mesh_compile(topo, one_chip, as_tpu):
+    """Four sequences over four chips: both stages go through
+    mesh_lib.manual_call with the batch sharded, and the parameters'
+    cotangents are summed across the chips outside the kernels."""
+    mesh = mesh_lib.build_mesh({"fsdp": 4}, devices=topo.devices)
+    batch = NamedSharding(mesh, P(("data", "fsdp")))
+    _, calls = _stage_calls(8192, 6144, 4096, 8, batch, b=4)
+    for stage, (fn, args) in calls.items():
+        total = lambda *a: fn(*a).astype(jnp.float32).sum()
+        with mesh_lib.use_mesh(mesh):
+            text = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+                *args).compile().as_text()
+        assert stage + "_bwd" in text and "all-reduce" in text, stage
+
+
 # -- Trinity-Mini's share (models/afmoe.py): the window kernels, the held
 # -- experts' grouped matmuls, and the whole step at the benchmark's size
 
@@ -898,8 +957,9 @@ def test_flash_compiles_at_nemotron_widths(one_chip):
 def test_nemotron_share_step_fits_the_chip(one_chip, as_tpu):
     """The benchmark cell's step (``nemotron3_nano_share`` at 1 x 8192, bf16,
     per-block remat, AdamW) compiles for a described v5e under the chip's
-    memory, with the scan's kernels four times each way, the online flash
-    kernels once and the four expert layers' grouped matmuls."""
+    memory, with the scan's kernels and the mixer's two stages' four times
+    each way, the online flash kernels once and the four expert layers'
+    grouped matmuls."""
     import re
     from collections import Counter
 
@@ -911,6 +971,12 @@ def test_nemotron_share_step_fits_the_chip(one_chip, as_tpu):
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     assert calls["ssd_bwd"] == 4 and calls["ssd_fwd"] >= 4, calls
+    # the mixers' elementwise stages as kernels (a call sits in the fusion
+    # that holds the lane slice it reads), and with them no float32 copy of
+    # xBC nor its padded cotangents: 7.36 GB of temporaries before them
+    for stage in ("conv_silu", "gate_norm"):
+        assert calls[stage + "_bwd"] == 4 and calls[stage + "_fwd"] >= 4, calls
+    assert mem.temp_size_in_bytes < 6.5e9, mem.temp_size_in_bytes
     for name in fa.ONLINE_KERNELS:
         assert calls[name] == 1, calls
     # four layers' two matrices, on both sides of the bounded layout's cond

@@ -102,6 +102,162 @@ def test_causal_conv_matches_convolve_per_channel():
                                        atol=1e-5)
 
 
+# -- the conv with its silu as one kernel pair (interpreted here) ----------------
+
+
+def _conv_silu_body(x, kernel, bias):
+    return jax.nn.silu(ssd_lib.causal_conv1d(x, kernel, bias))
+
+
+def _conv_inputs(S, C, dtype, K=4, b=2):
+    k = jax.random.split(jax.random.key(2), 4)
+    return ((jax.random.normal(k[0], (b, S, C)).astype(dtype),
+             0.5 * jax.random.normal(k[1], (K, C)),
+             0.1 * jax.random.normal(k[2], (C,))),
+            jax.random.normal(k[3], (b, S, C)))
+
+
+def _value_and_cotangents(fn, args, w):
+    return jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+        argnums=tuple(range(len(args))))(*args)
+
+
+def _close(got, want, tol, what):
+    for i, (g, w) in enumerate(zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want))):
+        assert g.shape == w.shape and g.dtype == w.dtype, (what, i)
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        gap, scale = float(jnp.max(jnp.abs(g - w))), float(jnp.max(jnp.abs(w)))
+        assert gap <= tol * scale, (what, i, gap, scale)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of [32, 128] for the conv and of 32 rows of whole groups (at most
+    256 lanes, or one group) for the norm: the interpreter's size."""
+    monkeypatch.setattr(ssd_lib, "STAGE_TILE", 32 * 128)
+    monkeypatch.setattr(ssd_lib, "STAGE_COLS", 128)
+
+
+@pytest.mark.parametrize("S", [64, 80], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["fp32", "bf16"])
+def test_conv_silu_kernels_are_the_jax_numpy_body(small_tiles, dtype, tol, S):
+    """Values, ``dx``, ``d kernel`` and ``d bias`` of the kernel pair at two
+    sequences, two column blocks and two or three row tiles (the last one
+    part-filled where S is 80) against ``silu(causal_conv1d)`` differentiated
+    by XLA. bf16: the body rounds the conv before its silu and the kernel does
+    not, so they differ by a rounding."""
+    args, w = _conv_inputs(S, 256, dtype)
+    assert ssd_lib._stage_plan("conv_silu", S, 256, 1, dtype, 4) == (32, 128)
+    got = _value_and_cotangents(ssd_lib.conv_silu, args, w)
+    want = _value_and_cotangents(_conv_silu_body, args, w)
+    _close(got, want, tol, "conv_silu")
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["fp32", "bf16"])
+def test_conv_silu_halo_rows_by_themselves(small_tiles, dtype, tol):
+    """The first K-1 rows of a tile read the rows before it and the last K-1
+    feed the tile that follows: those rows of the result and of ``dx`` alone,
+    at both tile borders of a 96-row sequence."""
+    args, w = _conv_inputs(96, 128, dtype)
+    out = ssd_lib.conv_silu(*args)
+    dx = jax.grad(lambda x: jnp.sum(ssd_lib.conv_silu(x, *args[1:]) * w))(
+        args[0])
+    want = _conv_silu_body(*args)
+    want_dx = jax.grad(lambda x: jnp.sum(_conv_silu_body(x, *args[1:]) * w))(
+        args[0])
+    for border in (32, 64):
+        _close(out[:, border:border + 3], want[:, border:border + 3], tol,
+               ("first rows", border))
+        _close(dx[:, border - 3:border], want_dx[:, border - 3:border], tol,
+               ("last rows", border))
+
+
+def test_conv_silu_carries_nothing_from_one_sequence_into_the_next(
+        small_tiles):
+    """A batch of two is each sequence by itself, forward and backward: the
+    history and the cotangent a tile hands on are zeroed at a sequence's
+    start and end."""
+    (x, kernel, bias), w = _conv_inputs(64, 128, jnp.float32)
+    both = _value_and_cotangents(ssd_lib.conv_silu, (x, kernel, bias), w)
+    for i in range(2):
+        alone = _value_and_cotangents(
+            ssd_lib.conv_silu, (x[i:i + 1], kernel, bias), w[i:i + 1])
+        np.testing.assert_array_equal(both[1][0][i], alone[1][0][0])
+    # and the parameters' gradients are the two sequences' summed
+    one, two = (_value_and_cotangents(
+        ssd_lib.conv_silu, (x[i:i + 1], kernel, bias), w[i:i + 1])[1]
+        for i in range(2))
+    _close(both[1][1:], jax.tree.map(jnp.add, one[1:], two[1:]), 1e-6, "sum")
+
+
+@pytest.mark.parametrize("offset", [128, 64], ids=["block_edge", "off_it"])
+def test_conv_silu_reads_its_slice_out_of_the_wider_array(small_tiles,
+                                                          offset):
+    """Handed the array whose lanes ``offset : offset + C`` are ``x``, the
+    kernels read them there (an offset on a column block's edge; off it they
+    read ``x``), and the wider array's cotangent is the slice's, through the
+    slice alone: values and every gradient are the body's."""
+    (x, kernel, bias), w = _conv_inputs(64, 256, jnp.float32)
+    k = jax.random.split(jax.random.key(7), 2)
+    wide = jnp.concatenate([jax.random.normal(k[0], (2, 64, offset)), x,
+                            jax.random.normal(k[1], (2, 64, 64))], -1)
+    cut = lambda a: a[..., offset:offset + 256]
+    got = _value_and_cotangents(
+        lambda a, *rest: ssd_lib.conv_silu(cut(a), *rest, source=a,
+                                           offset=offset),
+        (wide, kernel, bias), w)
+    want = _value_and_cotangents(lambda a, *rest: _conv_silu_body(cut(a), *rest),
+                                 (wide, kernel, bias), w)
+    _close(got, want, 1e-5, "source")
+    # on a block's edge nothing reads the slice: the call's operand is wide
+    read = _pallas_operands(lambda a: ssd_lib.conv_silu(
+        cut(a), kernel, bias, source=a, offset=offset), wide)
+    assert [shapes[0] for shapes in read] == [
+        wide.shape if offset == 128 else x.shape]
+
+
+def _pallas_operands(fn, *args):
+    """The operands' shapes of every ``pallas_call`` in ``fn``'s jaxpr."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append([v.aval.shape for v in eqn.invars])
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) else [
+                        param]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("S,C,dtype,K,why", [
+    (64, 256, jnp.float16, 4, "fp16"),
+    (64, 192, jnp.float32, 4, "channels off the lane tiling"),
+    (16, 256, jnp.float32, 4, "a sequence shorter than a tile"),
+    (64, 256, jnp.float32, 8, "more taps than the history holds"),
+], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None)
+def test_conv_silu_plan_refuses_and_the_body_runs(small_tiles, S, C, dtype, K,
+                                                  why):
+    assert ssd_lib._stage_plan("conv_silu", S, C, 1, dtype, K) is None, why
+    args, w = _conv_inputs(S, C, dtype, K)
+    got = _value_and_cotangents(ssd_lib.conv_silu, args, w)
+    want = _value_and_cotangents(_conv_silu_body, args, w)
+    for g, t in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(g, t)
+    assert "pallas_call" not in str(jax.make_jaxpr(ssd_lib.conv_silu)(*args))
+
+
 # -- the model against the plain reference -------------------------------------
 
 #: the reference reads the published config's keys; these are the toy's

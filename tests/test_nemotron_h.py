@@ -203,6 +203,114 @@ def test_gated_norm_norms_each_group_by_itself():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
+# -- the gate with its group norm as one kernel pair (interpreted here) ----------------
+
+
+def _gate_norm_body(groups, dtype):
+    return lambda y, z, scale: ssd_lib.group_rms_norm(
+        y * jax.nn.silu(z.astype(jnp.float32)), scale, groups, 1e-5, dtype)
+
+
+def _gate_inputs(S, C, dtype, b=2):
+    k = jax.random.split(jax.random.key(4), 4)
+    return ((3.0 * jax.random.normal(k[0], (b, S, C)),
+             jax.random.normal(k[1], (b, S, C)).astype(dtype),
+             1.0 + 0.1 * jax.random.normal(k[2], (C,))),
+            jax.random.normal(k[3], (b, S, C)))
+
+
+def _cotangents(fn, args, w):
+    return jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+        argnums=(0, 1, 2))(*args)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 32 rows of whole groups: 256 lanes, or one group."""
+    monkeypatch.setattr(ssd_lib, "STAGE_TILE", 32 * 256)
+    monkeypatch.setattr(ssd_lib, "STAGE_COLS", 256)
+
+
+@pytest.mark.parametrize("S", [64, 80], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("groups,C,cols", [(1, 512, 512), (2, 256, 256),
+                                           (8, 1024, 256)],
+                         ids=["g1", "g2", "g8"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["fp32", "bf16"])
+def test_gate_norm_kernels_are_the_jax_numpy_body(small_tiles, dtype, tol,
+                                                  groups, C, cols, S):
+    """Values, ``dy`` (float32), ``dz`` and ``d scale`` of the kernel pair at
+    two sequences and two or three row tiles (the last one part-filled where
+    S is 80), a program holding one group wider than a block, one block of two
+    groups, and two groups of eight, against the gated ``group_rms_norm``
+    differentiated by XLA."""
+    args, w = _gate_inputs(S, C, dtype)
+    assert ssd_lib._stage_plan("gate_norm", S, C, groups, dtype) == (
+        32 * 256 // cols, cols)
+    run = lambda *a: ssd_lib.gate_norm(*a, groups=groups, epsilon=1e-5,
+                                       dtype=dtype)
+    got = _cotangents(run, args, w)
+    want = _cotangents(_gate_norm_body(groups, dtype), args, w)
+    assert got[1][0].dtype == jnp.float32 and got[1][1].dtype == dtype
+    for i, (g, t) in enumerate(zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want))):
+        assert g.shape == t.shape and g.dtype == t.dtype, i
+    _all_close(jax.tree.map(lambda a: a.astype(jnp.float32), got),
+               jax.tree.map(lambda a: a.astype(jnp.float32), want), tol,
+               "gate_norm")
+
+
+@pytest.mark.parametrize("C,groups,dtype,why", [
+    (256, 2, jnp.float16, "fp16"),
+    (192, 1, jnp.float32, "channels off the lane tiling"),
+    (384, 6, jnp.float32, "a group off the lane tiling"),
+], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None)
+def test_gate_norm_plan_refuses_and_the_body_runs(small_tiles, C, groups,
+                                                  dtype, why):
+    assert ssd_lib._stage_plan("gate_norm", 64, C, groups, dtype) is None, why
+    args, w = _gate_inputs(64, C, dtype)
+    run = lambda *a: ssd_lib.gate_norm(*a, groups=groups, epsilon=1e-5,
+                                       dtype=dtype)
+    got = _cotangents(run, args, w)
+    want = _cotangents(_gate_norm_body(groups, dtype), args, w)
+    for g, t in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(g, t)
+    assert "pallas_call" not in str(jax.make_jaxpr(run)(*args))
+
+
+def test_mixer_plan_record_under_the_span_that_traced():
+    """One ``mixer_plan`` record a traced call of a stage, a child of the span
+    open on the tracing thread: the tile, or ``xla``."""
+    rec = telemetry.recorder()
+    mark = len(rec.records())
+    shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(s, dtype)
+    bf16 = jnp.bfloat16
+    norm = lambda groups: lambda *a: ssd_lib.gate_norm(
+        *a, groups=groups, epsilon=1e-5, dtype=bf16)
+    with rec.span("trace_here", bucket=None):
+        jax.eval_shape(ssd_lib.conv_silu, shape(1, 8192, 6144, dtype=bf16),
+                       shape(4, 6144), shape(6144))
+        jax.eval_shape(norm(8), shape(1, 8192, 4096),
+                       shape(1, 8192, 4096, dtype=bf16), shape(4096))
+        jax.eval_shape(norm(2), shape(1, 8192, 192),
+                       shape(1, 8192, 192, dtype=bf16), shape(192))
+    new = rec.records()[mark:]
+    span = next(r for r in new if r.kind == "span" and r.name == "trace_here")
+    said = [r for r in new if r.name == "mixer_plan"]
+    assert [r.kind for r in said] == ["compile"] * 3
+    assert all(r.parent == span.id and r.seconds == 0 for r in said)
+    assert said[0].value == {
+        "stage": "conv_silu", "rows": 8192, "channels": 6144, "groups": 1,
+        "tile": list(ssd_lib._stage_plan("conv_silu", 8192, 6144, 1, bf16, 4))}
+    assert said[1].value == {
+        "stage": "gate_norm", "rows": 8192, "channels": 4096, "groups": 8,
+        "tile": list(ssd_lib._stage_plan("gate_norm", 8192, 4096, 8, bf16))}
+    assert said[2].value["tile"] == "xla"
+    assert "mixer_plan" in telemetry.COMPILE_RECORDS
+
+
 # -- the ungated experts -------------------------------------------------------------
 
 

@@ -461,8 +461,10 @@ def test_every_pallas_call_has_a_name(file, call):
 def test_pallas_names_are_one_per_kernel():
     names = [k.value.value for p in _pallas_sites() for k in p.values[1].keywords
              if k.arg == "name"]
-    assert len(names) == 17 and len(set(names)) == 17
+    assert len(names) == 21 and len(set(names)) == 21
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
+    assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
+        "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd"}
     assert {n for n in names if n.startswith("flash_fwd")} == {
         "flash_fwd_online", "flash_fwd_oneshot", "flash_fwd_causal",
         "flash_fwd_window"}
