@@ -26,6 +26,14 @@ time at the blocks dispatch picks (no step and no copy for a block above the
 diagonal; no mask inside a block below it; a crossed block in sub-tiles), each
 beside the whole rectangle under a mask everywhere ("parent") and all three
 together, and says whether each variant's results are "parent"'s bitwise.
+
+``--window W`` puts the three modes under a sliding window (row r sees the W
+keys up to its own): ``--kernels`` times what dispatch gives the window call
+(from a checkout that still has window kernels of its own, theirs),
+``--block-sweep`` the online kernels by name at each pair of ``--blocks`` in
+both directions, ``--schedule-parts`` the window's schedule a part at a time.
+Each row carries ms a call, ps a computed (row, key) pair and the share of the
+FLOP floor (2 products forward, 5 backward, over the pairs the mask leaves).
 """
 
 from __future__ import annotations
@@ -45,6 +53,27 @@ def attn_flops(B, H, S, D, causal=True, bwd=False):
     if causal:
         f /= 2
     return f * (3.5 if bwd else 1.0)
+
+
+def window_rates(row, took, B, H, S, D, window, schedule, peak_tflops):
+    """Into ``row``, a direction: ps a computed pair (where the kernel's
+    schedule says how many it computes; dq computes dkv's again) and the share
+    of the FLOP floor, the least time of the direction's products (2 forward,
+    5 backward) over the pairs the window leaves."""
+    needed = (window * S - window * (window - 1) // 2) * B * H
+    row["ps_per_pair"], row["floor_share"] = {}, {}
+    for tag, products, counted in (("fwd", 2, "flash_fwd_window"),
+                                   ("bwd", 5, "flash_bwd_window_dkv")):
+        ms = sum(t for n, t in took.items() if ("_fwd_" in n) == (tag == "fwd"))
+        if not ms:
+            continue
+        floor_ms = products * 2 * D * needed / (peak_tflops * 1e12) * 1e3
+        row["floor_share"][tag] = round(floor_ms / ms, 4)
+        said = (schedule or {}).get(counted)
+        if said:
+            row["ps_per_pair"][tag] = round(
+                ms * 1e9 / (said["pairs_computed"] * B * H), 3)
+    return row
 
 
 def device_ms(fn, args, reps):
@@ -102,11 +131,11 @@ SCHEDULE_PARTS = {
 }
 
 
-def parts_rows(fa, shapes, reps, subs):
+def parts_rows(fa, shapes, reps, subs, window=None, peak_tflops=197.0):
     """One row per (shape, variant): the three online kernels' device ms
     under that variant of the schedule, its counts, and whether its results
     are the "parent" variant's bit for bit. ``subs``: further sub-tile sides
-    to time the whole schedule at."""
+    to time the whole schedule at. ``window``: the schedule's second edge."""
     import jax
     import jax.numpy as jnp
 
@@ -117,14 +146,18 @@ def parts_rows(fa, shapes, reps, subs):
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q, k, v, g = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
                       for key in ks)
+        edge = {} if window is None else {"window": window}
         fwd_blocks, bwd_blocks = (
             fa._online_blocks(bwd, S, D, fa.DEFAULT_BLOCK_Q,
-                              fa.DEFAULT_BLOCK_KV) for bwd in (False, True))
+                              fa.DEFAULT_BLOCK_KV, **edge)
+            for bwd in (False, True))
         o, lse = jax.jit(lambda q, k, v: fa._flash_fwd(
             q, k, v, causal=True, block_q=fwd_blocks[0],
-            block_kv=fwd_blocks[1]))(q, k, v)
+            block_kv=fwd_blocks[1], **edge))(q, k, v)
         want = None
         for name, parts in variants.items():
+            parts = dict(parts, **edge)
+
             def both(q, k, v, o, lse, g, parts=parts):
                 return (*fa._flash_fwd(q, k, v, causal=True,
                                        block_q=fwd_blocks[0],
@@ -133,7 +166,7 @@ def parts_rows(fa, shapes, reps, subs):
                                        block_q=bwd_blocks[0],
                                        block_kv=bwd_blocks[1], **parts))
 
-            row = {"parts": name, "B": B, "H": H, "S": S, "D": D}
+            row = {"parts": name, "B": B, "H": H, "S": S, "D": D, **edge}
             try:
                 _, took = device_ms(both, (q, k, v, o, lse, g), reps)
                 got = jax.jit(both)(q, k, v, o, lse, g)
@@ -152,15 +185,67 @@ def parts_rows(fa, shapes, reps, subs):
                                            **parts).record(D)
                 for kernel, blocks in zip(
                     fa.ONLINE_KERNELS, (fwd_blocks, bwd_blocks, bwd_blocks))}
+            if window is not None:
+                window_rates(row, took, B, H, S, D, window, {
+                    s["kernel"]: s for s in row["schedule"].values()},
+                    peak_tflops)
             rows.append(row)
             print(json.dumps(row), flush=True)
     return rows
 
 
-def kernel_rows(fa, shapes, plans, reps):
+def window_sweep_rows(fa, shapes, reps, window, blocks, peak_tflops):
+    """One row per (shape, direction, block_q, block_kv): the online kernels
+    under the window at that pair of blocks, by name in a device trace."""
+    import jax
+    import jax.numpy as jnp
+
+    rows = []
+    for (B, H, S, D) in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, g = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
+                      for key in ks)
+        o, lse = fa._fwd_dispatch(q, k, v, True, fa.DEFAULT_BLOCK_Q,
+                                  fa.DEFAULT_BLOCK_KV, "auto", None, window)
+        for bq in blocks:
+            for bkv in blocks:
+                call = dict(causal=True, block_q=bq, block_kv=bkv,
+                            window=window)
+                for tag, fn, args, kernels in (
+                        ("fwd", lambda *a, call=call: fa._flash_fwd(*a, **call),
+                         (q, k, v), fa.ONLINE_KERNELS[:1]),
+                        ("bwd", lambda *a, call=call: fa._flash_bwd(*a, **call),
+                         (q, k, v, o, lse, g), fa.ONLINE_KERNELS[1:])):
+                    row = {"impl": "online", "pass": tag, "B": B, "H": H,
+                           "S": S, "D": D, "window": window, "block_q": bq,
+                           "block_kv": bkv}
+                    try:
+                        took = device_ms(fn, args, reps)[1]
+                    except Exception as e:  # a pair the compiler refuses
+                        row["error"] = str(e).strip().splitlines()[-1][-300:]
+                    else:
+                        schedule = {}
+                        for kernel in kernels:
+                            plan = fa.online_schedule(kernel, True, S, S, bq,
+                                                      bkv, window=window)
+                            schedule[plan.name] = plan.record(D)
+                        row["kernels"] = {n: round(ms, 4)
+                                          for n, ms in took.items()}
+                        row["ms"] = round(sum(took.values()), 4)
+                        row["sub"] = [s["sub"] for s in schedule.values()]
+                        row["steps"] = [s["steps"] for s in schedule.values()]
+                        window_rates(row, took, B, H, S, D, window, schedule,
+                                     peak_tflops)
+                    rows.append(row)
+                    print(json.dumps(row), flush=True)
+    return rows
+
+
+def kernel_rows(fa, shapes, plans, reps, window=None, peak_tflops=197.0):
     """One row per (shape, impl, direction): the flash kernels that ran and
     their device time. ``plans``: (G, T) pairs for the causal kernels; empty
-    means the planner's own choice per direction."""
+    means the planner's own choice per direction. ``window``: the window
+    call alone, as dispatch serves it."""
     import jax
     import jax.numpy as jnp
     from pytorch_distributed_training_example_tpu.utils import telemetry
@@ -172,20 +257,20 @@ def kernel_rows(fa, shapes, plans, reps):
         q, k, v, g = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
                       for key in ks)
         o, lse = jax.jit(lambda q, k, v: fa._fwd_dispatch(
-            q, k, v, True, *blocks, "online", None))(q, k, v)
+            q, k, v, True, *blocks, "online", None, window))(q, k, v)
         fwd_args, bwd_args = (q, k, v), (q, k, v, o, lse, g)
         cases = []
-        for impl in ("auto", "online", "oneshot"):
+        for impl in ("auto",) if window else ("auto", "online", "oneshot"):
             cases.append((impl, "fwd", fwd_args,
                           lambda q, k, v, impl=impl: fa._fwd_dispatch(
-                              q, k, v, True, *blocks, impl, None)))
+                              q, k, v, True, *blocks, impl, None, window)))
             cases.append((impl, "bwd", bwd_args,
                           lambda q, k, v, o, lse, g, impl=impl: fa._vjp_bwd(
-                              True, *blocks, impl, None, None,
+                              True, *blocks, impl, None, window,
                               (q, k, v, o, lse), g)))
         fwd_plan = fa._causal_plan(H, S, D)
         for bwd in (False, True):
-            own = fa._causal_plan(H, S, D, bwd=bwd)
+            own = None if window else fa._causal_plan(H, S, D, bwd=bwd)
             for plan in plans or ([own] if own else []):
                 if bwd:  # the causal backward reads the causal forward's lse
                     args = (q, k, v, *fa._causal_fwd(
@@ -199,12 +284,16 @@ def kernel_rows(fa, shapes, plans, reps):
             row = {"impl": impl, "pass": tag, "B": B, "H": H, "S": S, "D": D}
             mark = len(telemetry.recorder().records())
             try:
-                row["kernels"] = {n: round(ms, 4) for n, ms in
-                                  device_ms(fn, args, reps)[1].items()}
+                took = device_ms(fn, args, reps)[1]
+                row["kernels"] = {n: round(ms, 4) for n, ms in took.items()}
                 row["ms"] = round(sum(row["kernels"].values()), 4)
                 schedule = schedules_since(mark)
                 if schedule:
                     row["schedule"] = schedule
+                if window:
+                    row["window"] = window
+                    window_rates(row, took, B, H, S, D, window, schedule,
+                                 peak_tflops)
             except Exception as e:  # a plan the compiler refuses
                 row["error"] = str(e).strip().splitlines()[-1][-300:]
             rows.append(row)
@@ -278,6 +367,10 @@ def main():
                    help="with --schedule-parts: comma-separated sub-tile "
                         "sides to time the whole schedule at, beside the "
                         "rule's")
+    p.add_argument("--window", type=int, default=None,
+                   help="with --kernels, --block-sweep or --schedule-parts: "
+                        "the sliding window the calls are under (see the "
+                        "docstring)")
     p.add_argument("--plans", default="",
                    help="with --kernels: comma-separated G:T plans for the "
                         "causal kernels (default: the planner's choice)")
@@ -334,13 +427,20 @@ def main():
     if args.shapes:
         shapes = tuple(tuple(int(x) for x in s.split("x"))
                        for s in args.shapes.split(","))
-    if args.kernels or args.layer or args.schedule_parts:
+    if (args.kernels or args.layer or args.schedule_parts
+            or (args.block_sweep and args.window)):
         plans = [tuple(int(x) for x in p.split(":"))
                  for p in args.plans.split(",") if p]
-        rows = kernel_rows(fa, shapes, plans, args.iters) if args.kernels else []
+        rows = (kernel_rows(fa, shapes, plans, args.iters, args.window,
+                            args.peak_tflops) if args.kernels else [])
+        if args.block_sweep:
+            rows += window_sweep_rows(
+                fa, shapes, args.iters, args.window,
+                [int(x) for x in args.blocks.split(",")], args.peak_tflops)
         if args.schedule_parts:
             rows += parts_rows(fa, shapes, args.iters,
-                               [int(s) for s in args.subs.split(",") if s])
+                               [int(s) for s in args.subs.split(",") if s],
+                               args.window, args.peak_tflops)
         if args.layer:
             rows += layer_rows(shapes, args.iters)
         if args.out:

@@ -502,7 +502,8 @@ def attention(
     (see :func:`ring_attention` ``ring_impl``).
 
     ``window`` (causal only): row i sees the keys [i - window + 1, i]. The
-    flash path has window kernels and the XLA path a mask; the context-
+    flash path's online kernels take it as their schedule's second edge
+    and the XLA path as a mask; the context-
     parallel schedules and the padded one-shot path have neither.
     """
     from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
@@ -573,8 +574,9 @@ def attention(
 
 def _window_attention(q, k, v, *, causal, impl, ctx, mesh, batch_axes,
                       window):
-    """``attention`` with a window: the window kernels where the flash path
-    is eligible (or asked for), else the masked XLA reference."""
+    """``attention`` with a window: the flash kernels under the window's
+    schedule where the flash path is eligible (or asked for), else the masked
+    XLA reference."""
     if impl not in ("auto", "flash", "xla") or ctx > 1:
         raise ValueError(
             f"window attention has no context-parallel schedule (impl="

@@ -11,6 +11,11 @@ Under the causal mask the grid holds only the block pairs the mask leaves
 (a walked table of steps: a block above the diagonal is neither a step nor
 a copy), a block wholly below the diagonal takes a body without a mask, and
 a block the diagonal crosses is computed in sub-tiles (``online_schedule``).
+A sliding window (row i sees the keys [i - window + 1, i]) is that mask's
+second edge and nothing else: the same three kernels under a schedule that
+also leaves out the blocks wholly behind the window and computes the blocks
+its far edge crosses in sub-tiles; their calls carry the names
+``flash_fwd_window``, ``flash_bwd_window_dq``, ``flash_bwd_window_dkv``.
 
 Backward is a Pallas dq/dkv kernel pair under ``custom_vjp`` (see
 ``_dq_kernel``/``_dkv_kernel`` below): recompute-based, using the
@@ -44,13 +49,14 @@ DEFAULT_BLOCK_KV = 1024
 LSE_LANES = 8  # lse stored [B,H,S,8]: minor dims satisfy Mosaic tiling
 
 # Measured per-shape block overrides for the ONLINE kernels, keyed
-# (bwd, S, D) -> (block_q, block_kv). Consulted only when the caller left
+# (bwd, S, D) -> (block_q, block_kv), or (bwd, S, D, window) for a call under
+# a sliding window. Consulted only when the caller left
 # block_q/block_kv at the module defaults (an explicit caller choice always
 # wins), so it is a tuning table, not an API change. Entries are added ONLY
 # from on-chip sweeps (``benchmarks/flash_micro.py --block-sweep`` emits the
 # grid); the r3 LM sweep that picked the 1024x1024 default ran at D=64 —
 # D=128 long-S shapes get their own rows here as they are measured.
-ONLINE_BLOCK_TABLE: dict[tuple[bool, int, int], tuple[int, int]] = {
+ONLINE_BLOCK_TABLE: dict[tuple[int, ...], tuple[int, int]] = {
     # D=128, S=4096 fwd: default 1024x1024 measured 1.371 ms = 0.509 of MXU
     # peak (r4, a machine that is gone; records in git at 6739a2e) — the
     # default IS the tuned choice.
@@ -95,16 +101,17 @@ ONLINE_HELD_MAX = _online_held(True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV, 128, 4)
 
 
 def _online_blocks(bwd: bool, s: int, d: int, block_q: int, block_kv: int,
-                   itemsize: int = 2):
+                   itemsize: int = 2, window: int | None = None):
     """The online kernels' block sizes: the caller's where it chose them, a
-    row of ONLINE_BLOCK_TABLE where the shape was measured, else the
-    defaults, halved (the larger of the two, the kv block first) until the
-    rows the kernel holds fit ``ONLINE_HELD_MAX``. At D <= 128 that halves
-    nothing."""
+    row of ONLINE_BLOCK_TABLE where the shape (with its window, where the
+    call has one) was measured, else the defaults, halved (the larger of the
+    two, the kv block first) until the rows the kernel holds fit
+    ``ONLINE_HELD_MAX``. At D <= 128 that halves nothing."""
     if (block_q, block_kv) != (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV):
         return block_q, block_kv
-    if (bwd, s, d) in ONLINE_BLOCK_TABLE:
-        return ONLINE_BLOCK_TABLE[bwd, s, d]
+    shape = (bwd, s, d) if window is None else (bwd, s, d, window)
+    if shape in ONLINE_BLOCK_TABLE:
+        return ONLINE_BLOCK_TABLE[shape]
     while min(block_q, block_kv) > 128 and _online_held(
             bwd, block_q, block_kv, d, itemsize) > ONLINE_HELD_MAX:
         if block_kv >= block_q:
@@ -147,19 +154,26 @@ def _mxu(x):
 # ---------------------------------------------------------------------------
 # The online kernels' schedule: which (q block, kv block) pairs a call visits,
 # fetches and masks. One pure function of (causal, Sq, Skv, block_q,
-# block_kv), read by the three kernels, by the recorder and by the tests.
+# block_kv, window), read by the three kernels, by the recorder and by the
+# tests.
 #
-# Row r sees column c iff c <= r, so a block pair at offset
-# d = qi * block_q - kvi * block_kv is one of three things:
-#   above the diagonal (d <= -block_q): nothing of it is visible. The grid
-#     does not hold it: its steps are the visited pairs alone, and (qi, kvi)
-#     come from a scalar-prefetched table, so it costs no step and no DMA.
-#   inside (d >= block_kv - 1): every pair is visible; the body has no mask.
-#   crossed by the diagonal: computed in stripes of ``sub`` rows (columns, in
-#     the dk/dv pass) against the keys (rows) the stripe can see: static
-#     slices, one unrolled body for each offset d a crossed block can have.
-#     Only the stripe's ``sub`` x ``sub`` square on the diagonal is masked,
-#     and a square wholly above it is not computed.
+# Row r sees column c iff 0 <= r - c (< window, where the call has one), so a
+# block pair at offset d = qi * block_q - kvi * block_kv is one of three
+# things:
+#   outside: above the diagonal (d <= -block_q) or wholly ``window`` or more
+#     behind it (d >= window + block_kv - 1): nothing of it is visible. The
+#     grid does not hold it: its steps are the visited pairs alone, and
+#     (qi, kvi) come from a scalar-prefetched table, so it costs no step and
+#     no DMA.
+#   inside (block_kv - 1 <= d <= window - block_q): every pair is visible;
+#     the body has no mask.
+#   crossed by the diagonal or by the window's far edge (by both, where the
+#     window is narrower than a block): computed in stripes of ``sub`` rows
+#     (columns, in the dk/dv pass) against the keys (rows) the stripe can
+#     see: static slices, one unrolled body for each offset d a crossed block
+#     can have. Only the stripe's ``sub`` x ``sub`` squares an edge passes
+#     through are masked (the diagonal's keeps row >= column, the far edge's,
+#     its mirror, row < column), and a square wholly outside is not computed.
 # What is left out adds exact zeros in the whole-block form: a masked
 # probability is exp(NEG_INF - m) = 0.0, and the stripes split the axis the
 # kernel writes along and shorten the one it contracts over, so no float32
@@ -170,9 +184,13 @@ def _mxu(x):
 # ---------------------------------------------------------------------------
 
 ONLINE_KERNELS = ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv")
+#: The names the same three kernels' calls carry under a window.
+WINDOW_KERNELS = ("flash_fwd_window", "flash_bwd_window_dq",
+                  "flash_bwd_window_dkv")
 #: Offsets a crossed block may have for it to be computed in sub-tiles (one
-#: at equal blocks, two at 512 x 1024), and stripes a block may be cut into:
-#: each offset is an unrolled body to compile, of a tile a stripe.
+#: at equal blocks, two at 512 x 1024 or at equal blocks under a window that
+#: is whole blocks), and stripes a block may be cut into: each offset is an
+#: unrolled body to compile, of a tile a stripe.
 SUB_OFFSETS_MAX = 2
 SUB_STRIPES_MAX = 4
 
@@ -183,7 +201,8 @@ class OnlineSchedule:
     innermost grid dimension(s) in order. ``walk``: they are a table the grid
     walks (else the whole rectangle). ``split``: blocks inside take the body
     without a mask. ``sub``: the sub-tiles' side in crossed blocks (0: such a
-    block is computed whole under an elementwise mask)."""
+    block is computed whole under an elementwise mask). ``window``: row r
+    sees the ``window`` keys up to its own (None: all of them)."""
 
     kernel: str
     causal: bool
@@ -195,58 +214,81 @@ class OnlineSchedule:
     split: bool
     sub: int
     steps: tuple
+    window: int | None = None
 
     @property
     def by_kv(self):
         """The dk/dv pass: kv blocks outer, a row of steps walks q blocks."""
         return self.kernel == "flash_bwd_dkv"
 
+    @property
+    def name(self):
+        """The name the kernel's ``pallas_call`` carries."""
+        if self.window is None:
+            return self.kernel
+        return WINDOW_KERNELS[ONLINE_KERNELS.index(self.kernel)]
+
     def offset(self, qi, kvi):
         return qi * self.block_q - kvi * self.block_kv
 
-    def crossed(self, d):
-        return -self.block_q < d < self.block_kv - 1
+    def kind(self, d):
+        """``(inside, crossed)`` of a block at offset ``d``, static or traced:
+        every pair of it is visible; an edge of the mask passes through it."""
+        inside = d >= self.block_kv - 1
+        if self.window is not None:
+            inside &= d <= self.window - self.block_q
+        crossed = (d > -self.block_q) & (
+            jnp.logical_not(inside) if isinstance(inside, jax.Array)
+            else not inside)
+        if self.window is not None:
+            crossed &= d < self.window + self.block_kv - 1
+        return inside, crossed
 
     @property
     def offsets(self):
-        """The offsets of the blocks the diagonal crosses, ascending."""
+        """The offsets of the blocks an edge crosses, ascending."""
         return tuple(sorted({self.offset(*s) for s in self.steps
-                             if self.crossed(self.offset(*s))}))
+                             if self.kind(self.offset(*s))[1]}))
 
     def tiles(self, d):
         """``(tiles, skipped)`` of a crossed block at offset ``d``: the
-        computed ``(rows, cols, square)`` in the block's own coordinates,
-        ``square`` the corner in the tile of its ``sub``-square on the
-        diagonal (None: every pair is visible; "whole": the tile is the
+        computed ``(rows, cols, squares)`` in the block's own coordinates,
+        ``squares`` the corners in the tile of its ``sub``-squares on the
+        diagonal and on the window's far edge, ``(near, far)``, either None
+        (None for both: every pair is visible; "whole": the tile is the
         block, masked by position), and the count of sub-tiles left out."""
         bq, bkv, sub = self.block_q, self.block_kv, self.sub
         if not sub:
             return [((0, bq), (0, bkv), "whole")], 0
         vary = int(self.by_kv)      # the stripes are rows (0) or columns (1)
+        size = (bq, bkv)[1 - vary]  # of the block along the other axis
+        # how far behind its own diagonal a stripe sees (without a window:
+        # farther than a block reaches)
+        reach = bq + bkv if self.window is None else self.window
         tiles, skipped = [], 0
         for lo in range(0, (bq, bkv)[vary], sub):
-            if self.by_kv:      # columns [lo, lo + sub): rows from lo - d on
-                first = min(max(0, lo - d), bq)
-                skipped += first // sub
-                tile = ((first, bq), (lo, lo + sub),
-                        (0, 0) if lo >= d else None)
-                seen = first < bq
-            else:               # rows [lo, lo + sub): keys below d + lo + sub
-                n = max(min(bkv, d + lo + sub), 0)
-                skipped += (bkv - n) // sub
-                tile = ((lo, lo + sub), (0, n),
-                        (0, n - sub) if d + lo < bkv else None)
-                seen = n > 0
-            if not seen:
+            # where, along the other axis, the stripe's squares on the two
+            # edges begin: it sees from the one to the other
+            near = lo - d if vary else lo + d
+            far = near + reach if vary else near - reach
+            first = min(max(min(near, far), 0), size)
+            end = min(max(max(near, far) + sub, first), size)
+            skipped += (size - (end - first)) // sub
+            if end == first:
                 continue
+            squares = tuple(
+                ((at - first, 0) if vary else (0, at - first))
+                if first <= at < end else None for at in (near, far))
+            tile = (((first, end), (lo, lo + sub)) if vary
+                    else ((lo, lo + sub), (first, end)))
             last = tiles[-1] if tiles else None
-            if (last and tile[2] is None and last[2] is None
+            if (last and not any(squares) and last[2] is None
                     and last[1 - vary] == tile[1 - vary]):
                 # stripes that see the same keys (rows) whole are one tile
                 span = (last[vary][0], tile[vary][1])
-                tile = (tile[0], span, None) if vary else (span, tile[1], None)
+                tile = (tile[0], span) if vary else (span, tile[1])
                 tiles.pop()
-            tiles.append(tile)
+            tiles.append((*tile, squares if any(squares) else None))
         return tiles, skipped
 
     def counts(self):
@@ -265,11 +307,12 @@ class OnlineSchedule:
             out["fetched"] += streamed != before
             before = streamed
             d = self.offset(qi, kvi)
-            if not self.causal or d >= bkv - 1:
+            inside, crossed = self.kind(d)
+            if not self.causal or inside:
                 out["computed"] += 1
                 out["masked"] += self.causal and not self.split
                 out["pairs_computed"] += bq * bkv
-            elif self.crossed(d):
+            elif crossed:
                 tiles, skipped = self.tiles(d)
                 out["computed"] += 1
                 out["masked"] += 1
@@ -277,27 +320,34 @@ class OnlineSchedule:
                 out["pairs_computed"] += sum(
                     (r[1] - r[0]) * (c[1] - c[0]) for r, c, _ in tiles)
         tri = min(self.sq, self.skv)    # rows that see fewer keys than all
-        out["pairs_needed"] = (
-            tri * (tri + 1) // 2 + (self.sq - tri) * self.skv if self.causal
-            else self.sq * self.skv)
+        needed = (tri * (tri + 1) // 2 + (self.sq - tri) * self.skv
+                  if self.causal else self.sq * self.skv)
+        if self.window is not None:     # sq == skv: a row sees min(r + 1, W)
+            w = self.window
+            needed = w * self.sq - w * (w - 1) // 2
+        out["pairs_needed"] = needed
         return {k: int(v) for k, v in out.items()}
 
     def record(self, d):
         """The ``flash_schedule`` record's value at head width ``d``."""
-        return dict(kernel=self.kernel, Sq=self.sq, Skv=self.skv, D=d,
+        return dict(kernel=self.name, Sq=self.sq, Skv=self.skv, D=d,
                     block_q=self.block_q, block_kv=self.block_kv,
-                    causal=self.causal, sub=self.sub, **self.counts())
+                    causal=self.causal, window=self.window, sub=self.sub,
+                    **self.counts())
 
 
-def online_schedule(kernel, causal, sq, skv, block_q, block_kv, *, walk=True,
-                    split=True, sub=None):
+def online_schedule(kernel, causal, sq, skv, block_q, block_kv, *, window=None,
+                    walk=True, split=True, sub=None):
     """The schedule of ``kernel`` (one of ONLINE_KERNELS) over ``sq`` x ``skv``
     in blocks that tile them. Not causal: the whole rectangle, nothing masked.
-    ``walk``, ``split`` and ``sub`` switch the schedule's three parts off one
-    at a time (benchmarks/flash_micro.py --schedule-parts times them so; the
-    program leaves them alone). Sub-tiles engage where ``sub`` leaves a
-    square to skip and a crossed block has at most SUB_OFFSETS_MAX offsets."""
+    ``window`` (causal self-attention, shorter than the sequence): the mask's
+    second edge. ``walk``, ``split`` and ``sub`` switch the schedule's three
+    parts off one at a time (benchmarks/flash_micro.py --schedule-parts times
+    them so; the program leaves them alone). Sub-tiles engage where ``sub``
+    leaves a square to skip, both edges pass through corners of ``sub``-squares
+    and a crossed block has at most SUB_OFFSETS_MAX offsets."""
     assert kernel in ONLINE_KERNELS, kernel
+    assert window is None or (causal and sq == skv and 0 < window < skv), window
     n_q, n_kv = sq // block_q, skv // block_kv
     by_kv = kernel == "flash_bwd_dkv"
     if not causal:
@@ -305,13 +355,17 @@ def online_schedule(kernel, causal, sq, skv, block_q, block_kv, *, walk=True,
     steps = ([(qi, kvi) for kvi in range(n_kv) for qi in range(n_q)] if by_kv
              else [(qi, kvi) for qi in range(n_q) for kvi in range(n_kv)])
     if walk:
+        def seen(qi, kvi):      # not above the diagonal, not behind the window
+            d = qi * block_q - kvi * block_kv
+            return d > -block_q and (window is None
+                                     or d < window + block_kv - 1)
+
         rows = [[s for s in steps if s[by_kv] == row]
                 for row in range(n_kv if by_kv else n_q)]
         # a row with nothing to see (keys past the last query row) keeps its
         # last step: the init and finish there write the zeros
-        steps = [s for row in rows for s in
-                 [s for s in row if s[0] * block_q - s[1] * block_kv > -block_q]
-                 or row[-1:]]
+        steps = [s for row in rows
+                 for s in [s for s in row if seen(*s)] or row[-1:]]
     if sub is None:
         # half the larger block in whole 128-lane tiles, or the widest such
         # that divides both blocks, if SUB_STRIPES_MAX of them span a block
@@ -323,23 +377,26 @@ def online_schedule(kernel, causal, sq, skv, block_q, block_kv, *, walk=True,
                     if block_q % s == 0 == block_kv % s
                     and max(block_q, block_kv) <= SUB_STRIPES_MAX * s), 0)
     plan = OnlineSchedule(kernel, causal, sq, skv, block_q, block_kv, walk,
-                          split, 0, tuple(steps))
+                          split, 0, tuple(steps), window)
     if (sub and sub < max(block_q, block_kv)
             and block_q % sub == 0 == block_kv % sub
             and len(plan.offsets) <= SUB_OFFSETS_MAX
-            and all(d % sub == 0 for d in plan.offsets)):
+            and all(d % sub == 0 for d in plan.offsets)
+            and (window is None or window % sub == 0)):
         plan = dataclasses.replace(plan, sub=sub)
     return plan
 
 
-def _say_schedules(kernels, causal, sq, skv, d, block_q, block_kv):
+def _say_schedules(kernels, causal, sq, skv, d, block_q, block_kv,
+                   window=None):
     """One ``flash_schedule`` record a kernel of a traced call, under the span
     that caused the trace: the schedule is static, so its counter is a
     record."""
     from pytorch_distributed_training_example_tpu.utils import telemetry
     for kernel in kernels:
         plan = online_schedule(kernel, causal, sq, skv,
-                               _fit_block(sq, block_q), _fit_block(skv, block_kv))
+                               _fit_block(sq, block_q), _fit_block(skv, block_kv),
+                               window=window)
         telemetry.recorder().compile_event("flash_schedule", 0.0,
                                            plan.record(d))
 
@@ -364,23 +421,22 @@ def _online_where(refs, plan):
 
 
 def _online_bodies(plan, qi, kvi, tile):
-    """Run ``tile(rows, cols, square)`` for what the schedule computes of the
+    """Run ``tile(rows, cols, squares)`` for what the schedule computes of the
     block (qi, kvi): under ``pl.when`` by the block's kind, a crossed block's
     sub-tiles unrolled in the body of its offset."""
-    whole = lambda square: lambda: tile(None, None, square)
+    whole = lambda squares: lambda: tile(None, None, squares)
     if not plan.causal:
         return whole(None)()
     d = plan.offset(qi, kvi)
-    inside = d >= plan.block_kv - 1
-    crossed = (d > -plan.block_q) & jnp.logical_not(inside)
+    inside, crossed = plan.kind(d)
     pl.when(inside)(whole(None if plan.split else "whole"))
     if not plan.sub:
         return pl.when(crossed)(whole("whole"))
     for at in plan.offsets:
         @pl.when(d == at)
         def _stripes(at=at):
-            for rows, cols, square in plan.tiles(at)[0]:
-                tile(rows, cols, square)
+            for rows, cols, squares in plan.tiles(at)[0]:
+                tile(rows, cols, squares)
 
 
 def _rows(ref, span):
@@ -392,26 +448,44 @@ def _at(span):
     return slice(None) if span is None else slice(*span)
 
 
-def _mask_tile(s, square, plan, qi, kvi):
-    """Causal mask of a score tile: nothing, the whole block by position, or
-    the ``sub``-square of the tile that lies on the diagonal."""
-    if square is None:
+def _mask_tile(s, squares, plan, qi, kvi):
+    """The mask of a score tile: nothing, the whole block by position, or the
+    ``sub``-squares of the tile that lie on the diagonal and on the window's
+    far edge."""
+    if squares is None:
         return s
-    if square == "whole":
+    if squares == "whole":
         q_pos = qi * plan.block_q + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0)
         k_pos = kvi * plan.block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
-        return jnp.where(q_pos >= k_pos, s, NEG_INF)
-    sub, (r, c) = plan.sub, square
-    visible = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
-               >= jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
-    diag = jnp.where(visible, s[r:r + sub, c:c + sub], NEG_INF)
-    if s.shape[1] > sub:            # a stripe of rows: the square comes last
-        return jnp.concatenate([s[:, :c], diag], axis=1)
-    if s.shape[0] > sub:            # a stripe of columns: it comes first
-        return jnp.concatenate([diag, s[sub:]], axis=0)
-    return diag
+        visible = q_pos >= k_pos
+        if plan.window is not None:
+            visible &= q_pos - k_pos < plan.window
+        return jnp.where(visible, s, NEG_INF)
+    sub = plan.sub
+    row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    # the tile is a stripe along ``axis``: its masked squares in their order
+    # (the far edge's keeps the diagonal's mirror), and between them the
+    # tile as it is
+    axis = int(s.shape[0] == sub)
+    masked = sorted(
+        ((corner[axis], jnp.where(
+            row >= col if edge == 0 else row < col,
+            s[corner[0]:corner[0] + sub, corner[1]:corner[1] + sub], NEG_INF))
+         for edge, corner in enumerate(squares) if corner),
+        key=lambda square: square[0])
+    cut = lambda lo, hi: s[:, lo:hi] if axis else s[lo:hi]
+    parts, at = [], 0
+    for lo, square in masked:
+        if lo > at:
+            parts.append(cut(at, lo))
+        parts.append(square)
+        at = lo + sub
+    if at < s.shape[axis]:
+        parts.append(cut(at, s.shape[axis]))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
 
 
 def _fwd_kernel(*refs, sm_scale: float, plan: OnlineSchedule):
@@ -424,7 +498,7 @@ def _fwd_kernel(*refs, sm_scale: float, plan: OnlineSchedule):
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def tile(rows, cols, square):
+    def tile(rows, cols, squares):
         # MXU-native operands: dots take q/k/v in their stored dtype (bf16 in
         # training) with fp32 accumulation via preferred_element_type — the
         # FlashAttention-2 scheme. Upcasting operands to fp32 here measured
@@ -436,7 +510,7 @@ def _fwd_kernel(*refs, sm_scale: float, plan: OnlineSchedule):
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bkv]
-        logits = _mask_tile(logits, square, plan, qi, kvi)
+        logits = _mask_tile(logits, squares, plan, qi, kvi)
 
         at = _at(rows)
         m_prev = m_ref[at, :1]                        # [bq, 1] (lane-bcast)
@@ -500,10 +574,11 @@ def _online_grid(plan, B, H, *, in_blocks, out_blocks, scratch_shapes):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_kv", "walk", "split", "sub"))
+    "causal", "block_q", "block_kv", "window", "walk", "split", "sub"))
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
-               walk=True, split=True, sub=None):
-    """Returns (out [B,S,H,D], lse [B,H,S]) with K/V already GQA-expanded.
+               window=None, walk=True, split=True, sub=None):
+    """Returns (out [B,S,H,D], lse [B,H,S,LSE_LANES]) with K/V already
+    GQA-expanded. ``window``: the mask's second edge (``_window_of``'s).
     ``walk``, ``split``, ``sub``: ``online_schedule``'s switches (the
     micro-benchmark's).
 
@@ -520,7 +595,8 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
     block_kv = _fit_block(Skv, block_kv)
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, Skv, block_q, block_kv)
     plan = online_schedule("flash_fwd_online", causal, Sq, Skv, block_q,
-                           block_kv, walk=walk, split=split, sub=sub)
+                           block_kv, window=window, walk=walk, split=split,
+                           sub=sub)
 
     call, table = _online_grid(
         plan, B, H,
@@ -533,7 +609,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
         ])
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=1.0 / math.sqrt(D), plan=plan),
-        name="flash_fwd_online",
+        name=plan.name,
         out_shape=(
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, LSE_LANES), jnp.float32),
@@ -551,7 +627,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
 
 def _online_probs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
-                  square, *, sm_scale, plan, qi, kvi):
+                  squares, *, sm_scale, plan, qi, kvi):
     """``(p, ds, q, k, do)`` of one tile: the probabilities from the saved
     lse, and a thunk for dS in the operands' dtype (the dk/dv pass adds
     P^T dO to its accumulator before it forms dS)."""
@@ -564,7 +640,7 @@ def _online_probs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
     delta = delta_ref[0, 0, _at(rows), :1]       # [bq, 1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
-    s = _mask_tile(s, square, plan, qi, kvi)
+    s = _mask_tile(s, squares, plan, qi, kvi)
     p = jnp.exp(s - lse)                         # [bq, bkv]
 
     def ds():
@@ -583,8 +659,8 @@ def _dq_kernel(*refs, sm_scale, plan):
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def tile(rows, cols, square):
-        _, ds, _, k, _ = _online_probs(*operands, rows, cols, square,
+    def tile(rows, cols, squares):
+        _, ds, _, k, _ = _online_probs(*operands, rows, cols, squares,
                                        sm_scale=sm_scale, plan=plan, qi=qi,
                                        kvi=kvi)
         ds = ds()
@@ -608,8 +684,8 @@ def _dkv_kernel(*refs, sm_scale, plan):
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def tile(rows, cols, square):
-        p, ds, q, _, do = _online_probs(*operands, rows, cols, square,
+    def tile(rows, cols, squares):
+        p, ds, q, _, do = _online_probs(*operands, rows, cols, squares,
                                         sm_scale=sm_scale, plan=plan, qi=qi,
                                         kvi=kvi)
         # dV += P^T dO
@@ -640,11 +716,12 @@ def _delta_rows(g, o):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_kv", "walk", "split", "sub"))
-def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, walk=True,
-               split=True, sub=None):
-    """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse: [B,H,Sq].
-    Under ``jit`` and with ``online_schedule``'s switches, as ``_flash_fwd``."""
+    "causal", "block_q", "block_kv", "window", "walk", "split", "sub"))
+def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, window=None,
+               walk=True, split=True, sub=None):
+    """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse:
+    [B,H,Sq,LSE_LANES]. Under ``jit``, with the window and
+    ``online_schedule``'s switches, as ``_flash_fwd``."""
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     block_q = _fit_block(Sq, block_q)
@@ -660,7 +737,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, walk=True,
     lrows = (block_q, LSE_LANES, "q")
     in_blocks = [qrows, krows, krows, qrows, lrows, lrows]
     plans = [online_schedule(name, causal, Sq, Skv, block_q, block_kv,
-                             walk=walk, split=split, sub=sub)
+                             window=window, walk=walk, split=split, sub=sub)
              for name in ONLINE_KERNELS[1:]]
 
     operands = (qt, kt, vt, dot, lse, delta)
@@ -670,7 +747,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, walk=True,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)])
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, plan=plans[0]),
-        name="flash_bwd_dq",
+        name=plans[0].name,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         **call,
     )(*table, *operands)
@@ -682,252 +759,13 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, walk=True,
                         pltpu.VMEM((block_kv, D), jnp.float32)])
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, plan=plans[1]),
-        name="flash_bwd_dkv",
+        name=plans[1].name,
         out_shape=(jax.ShapeDtypeStruct((B, H, Skv, D), k.dtype),
                    jax.ShapeDtypeStruct((B, H, Skv, D), v.dtype)),
         **call,
     )(*table, *operands)
 
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-    return tr(dq), tr(dk), tr(dv)
-
-
-# ---------------------------------------------------------------------------
-# Window kernels: causal attention in which row i sees the keys
-# [i - window + 1, i] (``sliding_attention`` layers). The online kernels'
-# scheme with one difference that carries the rest: the innermost grid
-# dimension walks only the ``nb + 1`` kv blocks (q blocks, in the dk/dv pass)
-# that a block's window can reach, through index maps offset by the outer
-# block's own index, so a block outside the window costs neither a grid step
-# nor a DMA. Whether a visited block needs the elementwise mask depends on
-# its distance from the diagonal alone, which is the inner index: the blocks
-# strictly inside the window take the unmasked body.
-# ---------------------------------------------------------------------------
-
-# q and kv block of the window kernels: at window 2048 a q block visits five
-# kv blocks (2,560 keys for the 2,048 a row sees); 1024 would visit 3,072.
-WINDOW_BLOCK = 512
-
-
-def _window_blocks(S: int, window: int, block_q: int, block_kv: int):
-    """``(block, nb)``: one block size for q and kv, and how many kv blocks
-    before the diagonal one a q block's window can reach."""
-    block = _fit_block(S, min(block_q, block_kv, WINDOW_BLOCK))
-    return block, min(-(-(window - 1) // block), S // block - 1)
-
-
-def _window_mask(s, dist, block, window):
-    """Mask the score tile of a (q block, kv block) pair ``dist`` blocks
-    apart: row r sees column c iff 0 <= dist*block + r - c < window."""
-    gap = dist * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where((gap >= 0) & (gap < window), s, NEG_INF)
-
-
-def _window_inside(dist, block, window):
-    """True where every pair of the tile is visible: below the diagonal block
-    and with its farthest pair (last row, first column) inside the window."""
-    return (dist > 0) & ((dist + 1) * block - 1 < window)
-
-
-def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                       acc_ref, *, sm_scale, window, block, nb):
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
-    dist = nb - j                       # q block index less kv block index
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def step(masked):
-        q, k, v = _mxu(q_ref[0, 0]), _mxu(k_ref[0, 0]), _mxu(v_ref[0, 0])
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if masked:
-            logits = _window_mask(logits, dist, block, window)
-        # A row may see no key of the window's first block: its running max
-        # stays NEG_INF and p reads 1 there; the first real max that follows
-        # (the diagonal block holds the row's own key) multiplies that by
-        # exp(NEG_INF - m) = 0.
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :1] * correction + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * correction + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    inside = _window_inside(dist, block, window)
-    seen = qi - dist >= 0               # the kv block exists
-    pl.when(seen & inside)(lambda: step(False))
-    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
-
-    @pl.when(j == nb)
-    def _finish():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, :LSE_LANES]
-                         + jnp.log(jnp.maximum(l_ref[:, :LSE_LANES], 1e-30)))
-
-
-def _window_fwd(q, k, v, *, window, block_q, block_kv):
-    """(out [B,S,H,D], lse [B,H,S,LSE_LANES]); K/V already GQA-expanded."""
-    B, S, H, D = q.shape
-    block, nb = _window_blocks(S, window, block_q, block_kv)
-    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-    qspec = pl.BlockSpec((1, 1, block, D), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec(
-        (1, 1, block, D),
-        lambda b, h, i, j: (b, h, jnp.maximum(i - nb + j, 0), 0))
-    out, lse = pl.pallas_call(
-        functools.partial(_window_fwd_kernel, sm_scale=1.0 / math.sqrt(D),
-                          window=window, block=block, nb=nb),
-        name="flash_fwd_window",
-        grid=(B, H, S // block, nb + 1),
-        in_specs=[qspec, kspec, kspec],
-        out_specs=(qspec, pl.BlockSpec((1, 1, block, LSE_LANES),
-                                       lambda b, h, i, j: (b, h, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, LSE_LANES), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((block, 128), jnp.float32),   # m
-                        pltpu.VMEM((block, 128), jnp.float32),   # l
-                        pltpu.VMEM((block, D), jnp.float32)],    # acc
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-    )(tr(q), tr(k), tr(v))
-    return tr(out), lse
-
-
-def _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dist, *,
-                  masked, sm_scale, window, block):
-    """``(p, ds)`` of one tile, as the online backward kernels compute them."""
-    q, k = _mxu(q_ref[0, 0]), _mxu(k_ref[0, 0])
-    v, do = _mxu(v_ref[0, 0]), _mxu(do_ref[0, 0])
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    if masked:
-        s = _window_mask(s, dist, block, window)
-    p = jnp.exp(s - lse_ref[0, 0, :, :1])
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta_ref[0, 0, :, :1]) * sm_scale).astype(q.dtype)
-    return p, ds
-
-
-def _window_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, acc_ref, *, nb, **tile):
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
-    dist = nb - j
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def step(masked):
-        _, ds = _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, dist, masked=masked, **tile)
-        acc_ref[:] += jax.lax.dot_general(
-            ds, _mxu(k_ref[0, 0]), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    inside = _window_inside(dist, tile["block"], tile["window"])
-    seen = qi - dist >= 0
-    pl.when(seen & inside)(lambda: step(False))
-    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
-
-    @pl.when(j == nb)
-    def _finish():
-        dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
-
-
-def _window_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, nb, n_q, **tile):
-    kvi = pl.program_id(2)
-    dist = pl.program_id(3)             # q block index less kv block index
-
-    @pl.when(dist == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    def step(masked):
-        p, ds = _window_probs(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, dist, masked=masked, **tile)
-        do = _mxu(do_ref[0, 0])
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, _mxu(q_ref[0, 0]), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    inside = _window_inside(dist, tile["block"], tile["window"])
-    seen = kvi + dist < n_q             # the q block exists
-    pl.when(seen & inside)(lambda: step(False))
-    pl.when(seen & jnp.logical_not(inside))(lambda: step(True))
-
-    @pl.when(dist == nb)
-    def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _window_bwd(q, k, v, o, lse, g, *, window, block_q, block_kv):
-    """q,k,v,o,g: [B,S,H,D] (kv GQA-expanded); lse: [B,H,S,LSE_LANES]."""
-    B, S, H, D = q.shape
-    block, nb = _window_blocks(S, window, block_q, block_kv)
-    n_q = S // block
-    tile = dict(sm_scale=1.0 / math.sqrt(D), window=window, block=block)
-    delta = _delta_rows(g, o)
-    tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-    operands = (tr(q), tr(k), tr(v), tr(g), lse, delta)
-    semantics = pltpu.CompilerParams(dimension_semantics=(
-        "parallel", "parallel", "parallel", "arbitrary"))
-
-    def specs(q_block, kv_block):
-        rows = lambda width, at: pl.BlockSpec(
-            (1, 1, block, width), lambda b, h, i, j: (b, h, at(i, j), 0))
-        return (rows(D, q_block), rows(D, kv_block),
-                rows(LSE_LANES, q_block))
-
-    qspec, kspec, lspec = specs(lambda i, j: i,
-                                lambda i, j: jnp.maximum(i - nb + j, 0))
-    dq = pl.pallas_call(
-        functools.partial(_window_dq_kernel, nb=nb, **tile),
-        name="flash_bwd_window_dq",
-        grid=(B, H, n_q, nb + 1),
-        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
-        compiler_params=semantics,
-    )(*operands)
-
-    # dk/dv pass: kv blocks outer, the nb + 1 q blocks that see each inner
-    qspec, kspec, lspec = specs(lambda i, j: jnp.minimum(i + j, n_q - 1),
-                                lambda i, j: i)
-    dk, dv = pl.pallas_call(
-        functools.partial(_window_dkv_kernel, nb=nb, n_q=n_q, **tile),
-        name="flash_bwd_window_dkv",
-        grid=(B, H, n_q, nb + 1),
-        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
-        out_specs=(kspec, kspec),
-        out_shape=(jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, D), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
-                        pltpu.VMEM((block, D), jnp.float32)],
-        compiler_params=semantics,
-    )(*operands)
     return tr(dq), tr(dk), tr(dv)
 
 
@@ -1728,8 +1566,9 @@ def flash_attention(q, k, v, causal: bool = False,
     256); one-shot kernels only.
 
     ``window`` (static): row i sees the keys [i - window + 1, i] only
-    (causal self-attention; the window kernels, whatever ``impl``). A window
-    that covers the sequence is plain causal attention and dispatches as such.
+    (causal self-attention; the online kernels under the window's schedule,
+    whatever ``impl``). A window that covers the sequence is plain causal
+    attention and dispatches as such.
     """
     k = attn_lib._repeat_kv(k, q.shape[2])
     v = attn_lib._repeat_kv(v, q.shape[2])
@@ -1753,6 +1592,10 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
                   window=None):
     """Auto dispatch is per direction, each from measurements on the chip:
 
+    - A window shorter than the sequence: the online kernels, both
+      directions, under a schedule with the window as its second edge (1024
+      x 1024 blocks with 512 sub-tiles on both edges at the published S8192
+      D128 windows of 2048 and 4096; PERF.md section 6, PR 45).
     - Causal self-attention (Sq == Skv, no kv_len) at a shape in
       ``CAUSAL_MEASURED``: the causal kernels, both directions where
       ``_causal_plan`` has one (PERF.md section 6, PR 31: 0.71 vs 1.05 ms
@@ -1777,8 +1620,7 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
     B, Sq, H, D = q.shape
     window = _window_of(window, causal, kv_len, Sq, k.shape[1])
     if window is not None:
-        return _window_fwd(q, k, v, window=window, block_q=block_q,
-                           block_kv=block_kv)
+        return _online_fwd(q, k, v, True, block_q, block_kv, window)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
@@ -1799,12 +1641,30 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
                             if kv_len is not None else ""))
     if plan is not None:
         return _oneshot_fwd(q, k, v, causal=causal, plan=plan, kv_len=kv_len)
+    return _online_fwd(q, k, v, causal, block_q, block_kv)
+
+
+def _online_fwd(q, k, v, causal, block_q, block_kv, window=None):
+    """The online forward at the blocks ``_online_blocks`` gives the call,
+    its schedule said to the recorder."""
+    _, Sq, _, D = q.shape
     block_q, block_kv = _online_blocks(False, Sq, D, block_q, block_kv,
-                                       q.dtype.itemsize)
+                                       q.dtype.itemsize, window)
     _say_schedules(ONLINE_KERNELS[:1], causal, Sq, k.shape[1], D, block_q,
-                   block_kv)
+                   block_kv, window)
     return _flash_fwd(q, k, v, causal=causal, block_q=block_q,
-                      block_kv=block_kv)
+                      block_kv=block_kv, window=window)
+
+
+def _online_bwd(q, k, v, o, lse, g, causal, block_q, block_kv, window=None):
+    """The online backward, as ``_online_fwd``."""
+    _, Sq, _, D = q.shape
+    block_q, block_kv = _online_blocks(True, Sq, D, block_q, block_kv,
+                                       q.dtype.itemsize, window)
+    _say_schedules(ONLINE_KERNELS[1:], causal, Sq, k.shape[1], D, block_q,
+                   block_kv, window)
+    return _flash_bwd(q, k, v, o, lse, g, causal=causal, block_q=block_q,
+                      block_kv=block_kv, window=window)
 
 
 def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len, window):
@@ -1831,8 +1691,8 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
     ve = attn_lib._repeat_kv(v, H)
     window = _window_of(window, causal, kv_len, q.shape[1], k.shape[1])
     if window is not None:
-        dq, dk, dv = _window_bwd(q, ke, ve, o, lse, g, window=window,
-                                 block_q=block_q, block_kv=block_kv)
+        dq, dk, dv = _online_bwd(q, ke, ve, o, lse, g, True, block_q, block_kv,
+                                 window)
         return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
@@ -1870,13 +1730,8 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
             dq, dk, dv = _stream_bwd(q, ke, ve, o, lse, g, causal=causal,
                                      plan=splan)
         else:
-            block_q, block_kv = _online_blocks(True, q.shape[1], q.shape[3],
-                                               block_q, block_kv,
-                                               q.dtype.itemsize)
-            _say_schedules(ONLINE_KERNELS[1:], causal, q.shape[1],
-                           ke.shape[1], q.shape[3], block_q, block_kv)
-            dq, dk, dv = _flash_bwd(q, ke, ve, o, lse, g, causal=causal,
-                                    block_q=block_q, block_kv=block_kv)
+            dq, dk, dv = _online_bwd(q, ke, ve, o, lse, g, causal, block_q,
+                                     block_kv)
     return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)
 
 

@@ -72,19 +72,44 @@ def test_attention_window_matches_the_written_out_mask(window):
             np.testing.assert_allclose(a, b, atol=2e-5)
 
 
-@pytest.mark.parametrize("S,window,block", [
-    (256, 100, 64),    # no multiple of the block: both edge blocks masked
-    (256, 128, 64),    # whole blocks: the blocks inside take the unmasked body
-    (512, 129, 128),   # one key past a block's edge
-    (256, 1, 64),      # a row sees itself alone
-    (256, 64, 64),     # the window is one block
-    (256, 300, 64)])   # covers the sequence: plain causal, the online kernels
-def test_window_kernels_match_the_reference_interpret(S, window, block):
-    """The window kernels in interpret mode against ``dot_product_attention``,
-    forward and gradients, grouped queries."""
+@pytest.fixture
+def sub_offsets_max(monkeypatch):
+    """Set the cap on a crossed block's offsets for one test: the jitted
+    launchers read it as they trace, so their traces go with it."""
+    def set_cap(cap):
+        monkeypatch.setattr(flash_lib, "SUB_OFFSETS_MAX", cap)
+        flash_lib._flash_fwd.clear_cache()
+        flash_lib._flash_bwd.clear_cache()
+    yield set_cap
+    flash_lib._flash_fwd.clear_cache()
+    flash_lib._flash_bwd.clear_cache()
+
+
+@pytest.mark.parametrize("S,window,block_q,block_kv,cap,sub", [
+    (256, 100, 64, 64, 2, 0),      # no multiple of the block: both edge blocks masked
+    (256, 128, 64, 64, 2, 0),      # whole blocks: the blocks inside take the unmasked body
+    (512, 129, 128, 128, 2, 0),    # one key past a block's edge
+    (256, 1, 64, 64, 2, 0),        # a row sees itself alone
+    (256, 64, 64, 64, 2, 0),       # the window is one block
+    (256, 300, 64, 64, 2, 0),      # covers the sequence: plain causal, no window
+    (1024, 512, 256, 256, 2, 128),     # sub-tiles on both edges, a block inside between
+    (1024, 256, 256, 256, 2, 128),     # the far edge in the diagonal block's neighbour
+    (1024, 128, 256, 256, 2, 128),     # narrower than a block: both edges in one block
+    (1024, 512, 256, 512, 2, 0),       # unequal blocks: four crossed offsets, whole blocks
+    (1024, 512, 256, 512, 4, 256),     # the same in sub-tiles, under a cap that admits them
+    (1024, 512, 512, 256, 4, 256)])
+def test_window_kernels_match_the_reference_interpret(sub_offsets_max, S, window,
+                                                      block_q, block_kv, cap, sub):
+    """The online kernels under a window in interpret mode against
+    ``dot_product_attention``, forward and gradients, grouped queries."""
+    sub_offsets_max(cap)
+    if window < S:
+        assert [flash_lib.online_schedule(k, True, S, S, block_q, block_kv,
+                                          window=window).sub
+                for k in flash_lib.ONLINE_KERNELS] == [sub] * 3
     q, k, v, g = _qkv(S)
     flash = lambda q, k, v: flash_lib.flash_attention(
-        q, k, v, True, block, block, "auto", None, window)
+        q, k, v, True, block_q, block_kv, "auto", None, window)
     plain = lambda q, k, v: attn_lib.dot_product_attention(
         q, k, v, causal=True, window=window)
     total = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * g)
@@ -94,6 +119,37 @@ def test_window_kernels_match_the_reference_interpret(S, window, block):
     np.testing.assert_allclose(out, plain(q, k, v), atol=2e-6)
     for a, b in zip(grads, jax.grad(total(plain), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+@pytest.mark.parametrize("S,window,block,dtype", [
+    (1024, 512, 256, jnp.float32), (1024, 256, 256, jnp.float32),
+    (1024, 128, 256, jnp.float32), (1536, 768, 384, jnp.float32),
+    (1024, 512, 256, jnp.bfloat16), (1024, 128, 256, jnp.bfloat16)],
+    ids=lambda v: getattr(v, "__name__", str(v)))
+def test_window_sub_tiles_are_the_masked_blocks_bitwise(S, window, block, dtype):
+    """As for the diagonal (tests/test_attention.py): the sub-tiled form of
+    the blocks both edges cross gives what the same blocks give computed whole
+    under the positional mask, bit for bit in o, lse and dq; dk and dv, whose
+    stripes drop a contraction's leading zeros, to a rounding of the sum on
+    XLA's CPU dot (``benchmarks/flash_micro.py --schedule-parts --window``
+    compares all five on the chip)."""
+    r = np.random.RandomState(7)
+    q, k, v, g = (jnp.asarray(r.randn(1, S, 2, 64), dtype) for _ in range(4))
+    call = dict(causal=True, block_q=block, block_kv=block, window=window)
+    assert flash_lib.online_schedule("flash_fwd_online", True, S, S, block,
+                                     block, window=window).sub == 128
+    with pltpu.force_tpu_interpret_mode():
+        o0, l0 = flash_lib._flash_fwd(q, k, v, **call, sub=0)
+        o1, l1 = flash_lib._flash_fwd(q, k, v, **call)
+        g0 = flash_lib._flash_bwd(q, k, v, o0, l0, g, **call, sub=0)
+        g1 = flash_lib._flash_bwd(q, k, v, o0, l0, g, **call)
+    for name, a, b in zip(("o", "lse", "dq"), (o1, l1, g1[0]), (o0, l0, g0[0])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    eps = float(jnp.finfo(dtype).eps)
+    for name, a, b in zip(("dk", "dv"), g1[1:], g0[1:]):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        np.testing.assert_allclose(a, b, rtol=2 * eps,
+                                   atol=eps * np.abs(b).max(), err_msg=name)
 
 
 def test_window_kernels_carry_their_own_names():

@@ -1,5 +1,8 @@
 """Ring/Ulysses/flash attention vs the XLA oracle (SURVEY.md §4.2, §7(c))."""
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -848,8 +851,10 @@ def _coverage(plan):
             some[rows, cols] |= tri[rows, cols]     # the mask is by position
             if square != "whole":
                 # outside its square on the diagonal the tile has no mask
-                sr = rows.start + square[0] // cell
-                sc = cols.start + square[1] // cell
+                (r, c), far = square
+                assert far is None
+                sr = rows.start + r // cell
+                sc = cols.start + c // cell
                 n = plan.sub // cell
                 held = np.ones((rows.stop - rows.start,
                                 cols.stop - cols.start), bool)
@@ -884,6 +889,168 @@ def test_online_schedule_counts_and_coverage(case, kernel):
     assert (whole <= np.tril(lower, -1)).all()
 
 
+def _pair_coverage(plan):
+    """The schedule read pair by pair: how often each (row, key) is computed,
+    which pairs its masks leave visible, the computed tiles in the sequence's
+    own coordinates as ``(rows, cols, squares)``, and the same for the masked
+    ``sub``-squares."""
+    times = np.zeros((plan.sq, plan.skv), np.int8)
+    seen = np.zeros((plan.sq, plan.skv), bool)
+    gap = np.arange(plan.sq)[:, None] - np.arange(plan.skv)[None, :]
+    by_position = (gap >= 0) & (gap < (plan.window or plan.sq + plan.skv))
+    tiles, masked = [], []
+    for qi, kvi in plan.steps:
+        inside, crossed = plan.kind(plan.offset(qi, kvi))
+        if not plan.causal or inside:
+            block = [((0, plan.block_q), (0, plan.block_kv),
+                      None if plan.split or not plan.causal else "whole")]
+        elif crossed:
+            block = plan.tiles(plan.offset(qi, kvi))[0]
+        else:
+            continue        # a step of the rectangle that computes nothing
+        for (r0, r1), (c0, c1), squares in block:
+            r0, r1 = qi * plan.block_q + r0, qi * plan.block_q + r1
+            c0, c1 = kvi * plan.block_kv + c0, kvi * plan.block_kv + c1
+            tiles.append(((r0, r1), (c0, c1), squares))
+            times[r0:r1, c0:c1] += 1
+            if squares == "whole":
+                seen[r0:r1, c0:c1] = by_position[r0:r1, c0:c1]
+                continue
+            seen[r0:r1, c0:c1] = True
+            low = np.tril(np.ones((plan.sub, plan.sub), bool))
+            for corner, keeps in zip(squares or (), (low, ~low)):
+                if corner:
+                    r, c = r0 + corner[0], c0 + corner[1]
+                    seen[r:r + plan.sub, c:c + plan.sub] = keeps
+                    masked.append((r, c))
+    return times, seen, by_position, tiles, masked
+
+
+# (S, window, block_q, block_kv, SUB_OFFSETS_MAX): what the interpret tests of
+# tests/test_afmoe.py run, and the geometry's corners
+WINDOW_SCHEDULES = [
+    (256, 100, 64, 64, 2),      # no multiple of the block: three offsets, whole
+    (512, 129, 128, 128, 2),    # one key past a block's edge
+    (256, 1, 64, 64, 2),        # a row sees itself alone
+    (256, 64, 64, 64, 2),       # the window is one block
+    (1024, 512, 256, 256, 2),   # sub-tiles of 128 on both edges
+    (1024, 256, 256, 256, 2),   # the far edge lies in the diagonal's neighbour
+    (1024, 128, 256, 256, 2),   # narrower than a block: one block, both edges
+    (2048, 1024, 512, 512, 2),  # sub-tiles of 256
+    (1024, 384, 256, 256, 2),   # whole sub-tiles, no whole blocks: three offsets
+    (1024, 1, 256, 256, 2),     # one offset, and no corner of a sub-tile
+    (1024, 512, 256, 512, 2),   # unequal blocks: four offsets, whole
+    (1024, 512, 512, 256, 2),
+    (1024, 512, 256, 512, 4),   # the same under a cap that admits them
+    (1024, 512, 512, 256, 4),
+    (1536, 768, 384, 384, 2),   # three stripes a block
+    (512, None, 128, 256, 2),   # no window: the one edge, as it was
+    (1024, None, 256, 256, 2),
+]
+
+
+@pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
+@pytest.mark.parametrize("S,window,bq,bkv,cap", WINDOW_SCHEDULES, ids=lambda v: str(v))
+def test_online_schedule_with_a_window_covers_the_mask(monkeypatch, S, window,
+                                                       bq, bkv, cap, kernel):
+    """Every visible pair lies in exactly one computed tile, no computed tile
+    lies wholly outside the mask, and a ``sub``-square is under a mask exactly
+    where an edge passes through it."""
+    monkeypatch.setattr(F, "SUB_OFFSETS_MAX", cap)
+    plan = F.online_schedule(kernel, True, S, S, bq, bkv, window=window)
+    times, seen, visible, tiles, masked = _pair_coverage(plan)
+    assert times.max() == 1 and (times[visible] == 1).all()
+    assert (seen == visible).all()
+    for (r0, r1), (c0, c1), _ in tiles:
+        assert visible[r0:r1, c0:c1].any(), ((r0, r1), (c0, c1))
+    counts = plan.counts()
+    assert counts["pairs_computed"] == int(times.sum())
+    assert counts["pairs_needed"] == int(visible.sum())
+    assert counts["steps"] == counts["computed"] == len(
+        {(r[0] // bq, c[0] // bkv) for r, c, _ in tiles})
+    if not plan.sub:
+        assert all(squares == "whole" or squares is None
+                   for _, _, squares in tiles)
+        return
+    sub = plan.sub
+    crossed_by_an_edge = {
+        (r, c) for (r0, r1), (c0, c1), _ in tiles
+        for r in range(r0, r1, sub) for c in range(c0, c1, sub)
+        if not visible[r:r + sub, c:c + sub].all()}
+    assert sorted(masked) == sorted(crossed_by_an_edge)
+
+
+@pytest.mark.parametrize("S,window,bq,bkv,sub,offsets", [
+    (1024, 512, 256, 256, 128, (0, 512)), (1024, 128, 256, 256, 128, (0, 256)),
+    (2048, 1024, 512, 512, 256, (0, 1024)), (1024, 1, 256, 256, 0, (0,)),
+    (1024, 384, 256, 256, 0, (0, 256, 512)), (256, 100, 64, 64, 0, (0, 64, 128)),
+    (1024, 512, 256, 512, 0, (0, 256, 512, 768)),
+    (8192, 2048, 512, 1024, 0, (0, 512, 2048, 2560))])
+def test_online_schedule_sub_tiles_engage_over_both_edges(S, window, bq, bkv,
+                                                          sub, offsets):
+    """PR 42's rule read over both edges: ``sub`` divides both blocks, the
+    window and every crossed offset, of which there are at most two."""
+    for kernel in F.ONLINE_KERNELS:
+        plan = F.online_schedule(kernel, True, S, S, bq, bkv, window=window)
+        assert (plan.sub, plan.offsets) == (sub, offsets), kernel
+        assert plan.name == F.WINDOW_KERNELS[F.ONLINE_KERNELS.index(kernel)]
+
+
+@pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
+@pytest.mark.parametrize("window,steps,computed,needed", [
+    (2048, 21, 18_350_080, 14_681_088),     # Trinity-Mini: 1 + 2 + 6 x 3
+    (4096, 30, 28_311_552, 25_167_872)],    # SmallThinker: 1 + 2 + 3 + 4 + 4 x 5
+    ids=["trinity_mini", "smallthinker_21b"])
+def test_online_schedule_counts_at_the_published_windows(window, steps,
+                                                         computed, needed, kernel):
+    """B1 S8192 D128 at the blocks dispatch picks (1024 x 1024): three steps a
+    1024 rows where the 512-blocks took ten (five where they took eighteen),
+    the pairs those computed, and ``W*S - W*(W-1)/2`` needed."""
+    bwd = kernel != "flash_fwd_online"
+    blocks = F._online_blocks(bwd, 8192, 128, F.DEFAULT_BLOCK_Q,
+                              F.DEFAULT_BLOCK_KV, 2, window)
+    assert blocks == (1024, 1024)
+    plan = F.online_schedule(kernel, True, 8192, 8192, *blocks, window=window)
+    assert (plan.sub, plan.offsets) == (512, (0, window))
+    counts = plan.counts()
+    assert (counts["steps"], counts["pairs_computed"],
+            counts["pairs_needed"]) == (steps, computed, needed)
+    assert needed == window * 8192 - window * (window - 1) // 2
+    record = plan.record(128)
+    assert (record["kernel"], record["window"]) == (plan.name, window)
+    assert record["fetched"] == steps - 1 and record["rectangle"] == 64
+
+
+with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                       "online_schedule_parent.json")) as _f:
+    PARENT_SCHEDULES = json.load(_f)
+
+
+@pytest.mark.parametrize("golden", PARENT_SCHEDULES,
+                         ids=lambda g: "%s-%s" % (g["cell"], g["kernel"]))
+def test_online_schedule_without_a_window_is_the_parents(golden):
+    """With ``window=None`` the schedule of each online call the cells make
+    (GLM's, Granite's, Nemotron's, Trinity's and SmallThinker's full layer)
+    is what it was before the window became its second edge, field for field
+    (tests/fixtures/online_schedule_parent.json, taken from commit 456c3a6)."""
+    S, D, kernel = golden["S"], golden["D"], golden["kernel"]
+    blocks = F._online_blocks(kernel != "flash_fwd_online", S, D,
+                              F.DEFAULT_BLOCK_Q, F.DEFAULT_BLOCK_KV, 2)
+    plan = F.online_schedule(kernel, True, S, S, *blocks)
+    assert plan.window is None and plan.name == kernel
+    got = dict(block_q=plan.block_q, block_kv=plan.block_kv, walk=plan.walk,
+               split=plan.split, sub=plan.sub,
+               steps=[list(s) for s in plan.steps],
+               offsets=list(plan.offsets), counts=plan.counts())
+    assert got == {k: golden[k] for k in got}
+    for d in plan.offsets:
+        tiles, skipped = plan.tiles(d)
+        assert all(squares[1] is None for _, _, squares in tiles if squares)
+        # the parent named a tile's one square by its corner
+        assert [[list(r), list(c), list(squares[0]) if squares else None]
+                for r, c, squares in tiles] + [skipped] == golden["tiles"][str(d)]
+
+
 @pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
 def test_online_schedule_not_causal_is_the_rectangle(kernel):
     plan = F.online_schedule(kernel, False, 4096, 8192, 1024, 512)
@@ -916,6 +1083,18 @@ def test_online_schedule_keys_past_the_last_query_keep_a_step():
     np.testing.assert_array_equal(np.asarray(o), np.asarray(o0))
     assert not np.asarray(grads[1][:, 256:]).any()
     assert not np.asarray(grads[2][:, 256:]).any()
+
+
+@pytest.mark.parametrize("kernel", F.ONLINE_KERNELS)
+def test_online_schedule_rows_past_the_last_key_see_every_block(kernel):
+    """Sq > Skv under the causal mask and no window: nothing lies behind a
+    row, however far below the diagonal its block is."""
+    plan = F.online_schedule(kernel, True, 1024, 256, 128, 128)
+    counts = plan.counts()
+    assert (counts["steps"], counts["computed"], counts["masked"]) == (15, 15, 2)
+    times, seen, visible, _, _ = _pair_coverage(plan)
+    assert times.max() == 1 and (times[visible] == 1).all()
+    assert (seen == visible).all()
 
 
 ONLINE_PARITY = [
@@ -1112,3 +1291,32 @@ def test_flash_schedule_record_under_the_span_that_traced():
             True, 8192, 256]
         assert bwd["steps"] == bwd["computed"] < bwd["rectangle"]
     assert "flash_schedule" in telemetry.COMPILE_RECORDS
+
+
+@pytest.mark.parametrize("H,window,steps,computed,needed", [
+    (32, 2048, 21, 18_350_080, 14_681_088),
+    (28, 4096, 30, 28_311_552, 25_167_872)],
+    ids=["trinity_mini", "smallthinker_21b"])
+def test_flash_schedule_record_of_a_window_call(H, window, steps, computed,
+                                                needed):
+    """A traced window call at the published shapes says one record a kernel,
+    under the names the calls carry, with the window and the counts under it:
+    the run's own word that the 1024-blocks and both edges' sub-tiles engaged."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+
+    rec = telemetry.recorder()
+    mark = len(rec.records())
+    q = jax.ShapeDtypeStruct((1, 8192, H, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: F.flash_attention(q, k, v, True, window=window).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    said = {r.value["kernel"]: r.value for r in rec.records()[mark:]
+            if r.name == "flash_schedule"}
+    assert sorted(said) == sorted(F.WINDOW_KERNELS)
+    for value in said.values():
+        assert {k: value[k] for k in (
+            "window", "block_q", "block_kv", "sub", "steps", "pairs_computed",
+            "pairs_needed")} == dict(
+            window=window, block_q=1024, block_kv=1024, sub=512, steps=steps,
+            pairs_computed=computed, pairs_needed=needed)

@@ -84,6 +84,24 @@ def _grads(fn):
     return jax.grad(total, argnums=(0, 1, 2))
 
 
+def _pallas_calls(fn, *args):
+    """{name: (grid, scalar-prefetch operands)} of the kernels ``fn`` calls,
+    the jitted launchers' among them."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grid = eqn.params["grid_mapping"]
+                found[eqn.params["name"]] = (grid.grid, grid.num_index_operands)
+            for value in eqn.params.values():
+                if hasattr(value, "jaxpr"):
+                    walk(value.jaxpr)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
 @pytest.mark.parametrize("dtype", [BF16, jnp.float32],
                          ids=["bf16", "fp32"])  # --precision bf16 / fp32
 @pytest.mark.parametrize("B,S,H,Hkv,D", [
@@ -391,17 +409,21 @@ def test_mixer_stages_under_four_device_mesh_compile(topo, one_chip, as_tpu):
 
 
 def test_window_flash_compiles_at_published_widths(one_chip):
-    """B1 H32/4 S8192 D128 with the published window of 2048: the three
-    window kernels and none of the others; the same call without a window
-    keeps the kernels it had."""
+    """B1 H32/4 S8192 D128 with the published window of 2048: the online
+    kernels under the window's schedule and their window names, a walked
+    table of 21 steps a head (1 + 2 + 6 x 3 blocks of 1024 x 1024), and none
+    of the others; the same call without a window keeps the kernels it had."""
     q = _sds((1, 8192, 32, 128), one_chip)
     kv = _sds((1, 8192, 4, 128), one_chip)
-    windowed = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
-        q, k, v, True, window=2048)), q, kv, kv)
-    for name in ("flash_fwd_window", "flash_bwd_window_dq",
-                 "flash_bwd_window_dkv"):
+    call = _grads(lambda q, k, v: fa.flash_attention(q, k, v, True,
+                                                     window=2048))
+    windowed = _compiled_text(call, q, kv, kv)
+    for name in fa.WINDOW_KERNELS:
         assert name in windowed, name
     assert "flash_fwd_online" not in windowed
+    # the grid is the walked table (its two scalar-prefetch operands first)
+    assert _pallas_calls(call, q, kv, kv) == dict.fromkeys(
+        fa.WINDOW_KERNELS, ((1, 32, 21), 2))
     full = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
         q, k, v, True)), q, kv, kv)
     assert "flash_fwd_online" in full and "flash_fwd_window" not in full
@@ -580,17 +602,20 @@ def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
 
 def test_window_flash_compiles_at_smallthinker_widths(one_chip):
     """B1 H28/4 S8192 D128 (seven query heads a KV head: not a power of two)
-    with the published window of 4096 (eight 512-blocks): the three window
-    kernels; the same call without a window, the model's position-free full
-    layer, takes the online forward and the dq / dkv backward."""
+    with the published window of 4096 (four 1024-blocks): the three kernels
+    under their window names, a walked table of 30 steps a head (1 + 2 + 3 +
+    4 + 4 x 5); the same call without a window, the model's position-free
+    full layer, takes the online forward and the dq / dkv backward."""
     q = _sds((1, 8192, 28, 128), one_chip)
     kv = _sds((1, 8192, 4, 128), one_chip)
-    windowed = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
-        q, k, v, True, window=4096)), q, kv, kv)
-    for name in ("flash_fwd_window", "flash_bwd_window_dq",
-                 "flash_bwd_window_dkv"):
+    call = _grads(lambda q, k, v: fa.flash_attention(q, k, v, True,
+                                                     window=4096))
+    windowed = _compiled_text(call, q, kv, kv)
+    for name in fa.WINDOW_KERNELS:
         assert name in windowed, name
     assert "flash_fwd_online" not in windowed
+    assert _pallas_calls(call, q, kv, kv) == dict.fromkeys(
+        fa.WINDOW_KERNELS, ((1, 28, 30), 2))
     full = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
         q, k, v, True)), q, kv, kv)
     for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):

@@ -120,6 +120,9 @@ def test_inline_loader_and_prefetch_record_their_spans(ring, devices):
 
     sharding = NamedSharding(Mesh(np.array(devices), ("data",)), P("data"))
     ring.step = 7
+    # the process's totals outlive ``clear()``: whatever an earlier test of
+    # this worker left in them, these spans add nothing
+    counts = ring.goodput()["counts"]
     got = list(prefetch.device_prefetch(
         loader_lib.DataLoader(Rows(), 8, num_workers=0), sharding))
     assert len(got) == 3
@@ -129,7 +132,7 @@ def test_inline_loader_and_prefetch_record_their_spans(ring, devices):
     assert names.count("loader_wait") == 4      # the last finds the end
     assert all(r.step == 7 for r in ring.records()
                if r.name in ("loader_wait", "device_put"))
-    assert ring.goodput()["counts"] == {}       # detail, not goodput
+    assert ring.goodput()["counts"] == counts   # detail, not goodput
 
 
 def test_compile_events_name_the_function_and_the_step(ring):
@@ -450,17 +453,35 @@ def _pallas_sites():
     return sites
 
 
+def _site_names(call):
+    """The names a ``pallas_call`` site gives its kernel: its literal, or, at
+    the online flash kernels' three sites, the schedule's (``plan.name``: the
+    kernel's own, or its window name under a schedule with a window)."""
+    from pytorch_distributed_training_example_tpu.ops import flash_attention
+
+    named = [k.value for k in call.keywords if k.arg == "name"]
+    if len(named) != 1:
+        return None
+    if isinstance(named[0], ast.Constant):
+        return [named[0].value]
+    if isinstance(named[0], ast.Attribute) and named[0].attr == "name":
+        return [*flash_attention.ONLINE_KERNELS, *flash_attention.WINDOW_KERNELS]
+    return None
+
+
 @pytest.mark.parametrize("file,call", _pallas_sites())
 def test_every_pallas_call_has_a_name(file, call):
-    named = [k.value for k in call.keywords if k.arg == "name"]
-    assert len(named) == 1, f"{file}:{call.lineno} pl.pallas_call has no name="
-    assert isinstance(named[0], ast.Constant) and isinstance(
-        named[0].value, str) and named[0].value.isidentifier()
+    names = _site_names(call)
+    assert names, f"{file}:{call.lineno} pl.pallas_call has no name="
+    assert all(isinstance(n, str) and n.isidentifier() for n in names)
+    assert len(names) == 1 or file == "flash_attention.py"
 
 
 def test_pallas_names_are_one_per_kernel():
-    names = [k.value.value for p in _pallas_sites() for k in p.values[1].keywords
-             if k.arg == "name"]
+    sites = [_site_names(p.values[1]) for p in _pallas_sites()]
+    by_schedule = [s for s in sites if len(s) > 1]
+    assert len(by_schedule) == 3        # the online forward, dq and dkv
+    names = [s[0] for s in sites if len(s) == 1] + by_schedule[0]
     assert len(names) == 21 and len(set(names)) == 21
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
     assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
