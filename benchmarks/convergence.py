@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Convergence artifact (VERDICT r3 missing #1; hardened in r5 per r4 weak #4).
+"""Convergence artifact.
 
 The reference's implicit acceptance test is "ResNet converges to known
 accuracy" (SURVEY.md §4.4). Real CIFAR/ImageNet files and network access
@@ -36,8 +36,7 @@ it without cheating. The gate is two-sided:
 - final eval loss >= floor - eps: a loss BELOW the floor on i.i.d.
   uniform data is impossible except through target leakage — a broken
   causal mask (attention peeking at position t+1) or shifted-target
-  misalignment. This is the cheap, always-on canary for exactly the bug
-  class the r17 EP dispatch reshuffles tokens around.
+  misalignment. This is the cheap, always-on canary for that bug class.
 
     python benchmarks/convergence.py --out CONVERGENCE.json
     python benchmarks/convergence.py --task lm --out CONVERGENCE_LM.json
@@ -57,9 +56,8 @@ import time
 
 
 def run_lm(args):
-    """LM entropy-floor leg: train ``--model`` (llama_tiny default; pass
-    llama_moe_tiny + --moe-* mains for the MoE path) on the uniform
-    synthetic token stream and gate the final eval loss against
+    """LM entropy-floor leg: train ``--model`` (llama_tiny default) on the
+    uniform synthetic token stream and gate the final eval loss against
     ``ln(vocab_size)``."""
     import jax
 
@@ -73,9 +71,6 @@ def run_lm(args):
         steps_per_epoch=args.steps_per_epoch, lr=args.lr,
         warmup_epochs=0.0, optimizer="adamw", weight_decay=0.0,
         precision="fp32", workers=0, evaluate=True, eval_every_epochs=1,
-        moe_dispatch_impl=args.moe_dispatch,
-        moe_capacity_factor=1.0 if args.moe_dispatch == "dropless" else 1.25,
-        moe_ep_dispatch=args.moe_ep_dispatch,
         checkpoint_dir=tempfile.mkdtemp(prefix="conv_lm_ck_"))
     t = Trainer(cfg)
     vocab = getattr(t.bundle.module, "vocab_size", None)
@@ -110,8 +105,6 @@ def run_lm(args):
         "steps_per_epoch": args.steps_per_epoch,
         "epochs": args.epochs,
         "lr": args.lr,
-        "moe_dispatch_impl": args.moe_dispatch,
-        "moe_ep_dispatch": args.moe_ep_dispatch,
         "devices": jax.device_count(),
         "backend": jax.default_backend(),
         "final_loss": final_loss,
@@ -147,12 +140,6 @@ def main(argv=None):
     p.add_argument("--floor-eps", type=float, default=1e-3,
                    help="--task lm: loss below floor - eps fails (target "
                         "leakage; fp sum tolerance only)")
-    p.add_argument("--moe-dispatch", default="gather",
-                   choices=["sort", "gather", "einsum", "dropless"],
-                   help="--task lm with an MoE model")
-    p.add_argument("--moe-ep-dispatch", default="replicated",
-                   choices=["replicated", "a2a", "a2a_overlap"],
-                   help="--task lm with an MoE model (dropless only)")
     p.add_argument("--out", default=None)
     p.add_argument("--tpu", action="store_true",
                    help="run on the default backend instead of CPU fakes")

@@ -35,11 +35,10 @@ inspects its optimized HLO / StableHLO:
          context>1 mesh the census also counts sequence-dim constraints
          (zero seq anchors at such a mesh is an error).
   GL105  unattributable point-to-point collectives: every ``all-to-all``
-         (sanctioned scopes: ``moe_*`` for the EP dropless transport,
-         ``attn_ulysses_a2a`` for Ulysses) and every
+         (sanctioned scope: ``attn_ulysses_a2a`` for Ulysses) and every
          ``collective-permute`` (``attn_ring_ppermute`` for the ring
-         K/V rotation, ``pp_stage_shift`` for the GPipe hop, ``moe_*``
-         for the EP ppermute fallback) in the compiled step must carry
+         K/V rotation, ``pp_stage_shift`` for the GPipe hop) in the
+         compiled step must carry
          a sanctioned named-scope tag in its op_name metadata — an
          untagged collective evades the comms census (``--aot-bytes``)
          and the per-region profile rollups.
@@ -51,8 +50,8 @@ this module for CI.
 
 Usage:
   python benchmarks/graftlint.py                 # AST layer, gate vs baseline
-  python benchmarks/graftlint.py --ir llama_moe_tiny
-  python benchmarks/graftlint.py --all           # AST + IR (llama_moe_tiny)
+  python benchmarks/graftlint.py --ir afmoe_tiny
+  python benchmarks/graftlint.py --all           # AST + IR (afmoe_tiny)
   python benchmarks/graftlint.py --json          # machine-readable findings
 """
 from __future__ import annotations
@@ -75,30 +74,25 @@ ERROR = "error"
 INFO = "info"
 
 # Region tags used by the AOT byte gate; the IR layer keys GL102/GL104 on
-# the same vocabulary so findings line up with check_regression --aot-bytes.
-# moe_experts_gmm is the dropless grouped-matmul kernel's inner scope
-# (ops/grouped_matmul.py) — listed before moe_experts so a standalone
-# occurrence classifies; nested occurrences resolve to the outer tag.
-MOE_TAG_RE = re.compile(
-    r"\bmoe_(router|dispatch|experts_gmm|experts|combine|aux)\b")
+# the same vocabulary so findings line up with check_regression --aot-bytes:
+# the expert layer's scopes (parallel/moe.py).
+MOE_TAG_RE = re.compile(r"\bmoe_(router|dispatch|experts|combine|shared)\b")
 
-# Scopes sanctioned to issue all-to-all (GL105): the MoE EP transport
-# regions and the Ulysses head<->sequence reshard (ops/attention.py). The
-# moe_* alternatives mirror MOE_TAG_RE; cotangent a2as keep the forward
-# scope path inside transpose(...), so backward ops match too.
-A2A_SCOPE_RE = re.compile(
-    r"\b(?:moe_(?:router|dispatch|experts_gmm|experts|combine|aux)"
-    r"|attn_ulysses_a2a)\b")
+# Scope sanctioned to issue all-to-all (GL105): the Ulysses head<->sequence
+# reshard (ops/attention.py). Cotangent a2as keep the forward scope path
+# inside transpose(...), so backward ops match too. The expert layer has no
+# exchange across chips (ROADMAP B4 (1)): one written under a ``moe_*``
+# scope is a finding until it is sanctioned here.
+A2A_SCOPE_RE = re.compile(r"\battn_ulysses_a2a\b")
 
 # Scopes sanctioned to issue collective-permute (GL105, r22): the ring /
 # zigzag K-V rotation and output un-permute (``attn_ring_ppermute``,
-# ops/attention.py), the GPipe stage hop (``pp_stage_shift``,
-# parallel/pipeline.py), and the moe_* EP ppermute fallback transport.
-# ``attn_ring_allgather`` (the ring's dense fallback) rides along so an
-# attention-site gather stays census-attributable too.
+# ops/attention.py) and the GPipe stage hop (``pp_stage_shift``,
+# parallel/pipeline.py). ``attn_ring_allgather`` (the ring's dense
+# fallback) rides along so an attention-site gather stays
+# census-attributable too.
 CPERM_SCOPE_RE = re.compile(
-    r"\b(?:moe_(?:router|dispatch|experts_gmm|experts|combine|aux)"
-    r"|attn_ring_ppermute|attn_ring_allgather|pp_stage_shift)\b")
+    r"\b(?:attn_ring_ppermute|attn_ring_allgather|pp_stage_shift)\b")
 
 
 def _norm(s: str) -> str:
@@ -1094,14 +1088,13 @@ _CPERM_LINE_RE = re.compile(
 def _ir_a2a_scope(hlo, label) -> list[Finding]:
     """GL105: point-to-point collectives outside sanctioned named scopes.
 
-    The comms census (profile_step.collective_byte_census) and the
-    PROFILE_MOE region rollups attribute traffic by named-scope tag; a
+    The comms census (profile_step.collective_byte_census) and the region
+    rollups attribute traffic by named-scope tag; a
     collective issued outside a sanctioned scope lands in ``non_moe``
     where the --aot-bytes golden never gates it. Two opcodes are policed:
-    ``all-to-all`` (sanctioned: ``moe_*`` EP transport,
-    ``attn_ulysses_a2a``) and, since the ring/pipeline axes (r22),
-    ``collective-permute`` (sanctioned: ``attn_ring_ppermute``,
-    ``pp_stage_shift``, ``moe_*`` ppermute fallback). All-gather is NOT
+    ``all-to-all`` (sanctioned: ``attn_ulysses_a2a``) and, since the
+    ring/pipeline axes (r22), ``collective-permute`` (sanctioned:
+    ``attn_ring_ppermute``, ``pp_stage_shift``). All-gather is NOT
     policed — GSPMD's FSDP weight gathers are legitimately everywhere —
     but the ring's dense fallback tags its gathers ``attn_ring_allgather``
     so they classify. -done halves are skipped (same instruction, counted
@@ -1110,7 +1103,7 @@ def _ir_a2a_scope(hlo, label) -> list[Finding]:
     out: list[Finding] = []
     seen: set[str] = set()
     policed = (("all-to-all", _A2A_LINE_RE, A2A_SCOPE_RE,
-                "jax.named_scope('moe_dispatch'/'attn_ulysses_a2a')"),
+                "jax.named_scope('attn_ulysses_a2a')"),
                ("collective-permute", _CPERM_LINE_RE, CPERM_SCOPE_RE,
                 "jax.named_scope('attn_ring_ppermute'/'pp_stage_shift')"))
     for line in hlo.splitlines():
@@ -1181,7 +1174,7 @@ def lint_lowered(
 
 
 def run_ir(
-    model: str = "llama_moe_tiny",
+    model: str = "afmoe_tiny",
     *,
     per_chip_batch: int = 2,
     seq_len: int = 64,
@@ -1313,7 +1306,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description="repo-specific two-layer linter")
     p.add_argument("--root", default=REPO_ROOT, help="tree to lint (AST layer)")
     p.add_argument("--ir", metavar="MODEL", default=None, help="run IR rules on MODEL")
-    p.add_argument("--all", action="store_true", help="AST + IR on llama_moe_tiny")
+    p.add_argument("--all", action="store_true", help="AST + IR on afmoe_tiny")
     p.add_argument("--no-ast", action="store_true", help="skip the AST layer")
     p.add_argument("--json", action="store_true", help="machine-readable output")
     p.add_argument("--baseline", default=DEFAULT_BASELINE)
@@ -1328,7 +1321,7 @@ def main(argv=None) -> int:
     findings: list[Finding] = []
     if not args.no_ast:
         findings += run_ast(os.path.abspath(args.root))
-    ir_model = args.ir or ("llama_moe_tiny" if args.all else None)
+    ir_model = args.ir or ("afmoe_tiny" if args.all else None)
     if ir_model:
         findings += run_ir(
             ir_model,

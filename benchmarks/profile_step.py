@@ -243,7 +243,7 @@ def build_op_moe_weights(hlo_text: str, tag_re: re.Pattern | None = None):
 # On the real target the kernel is ONE custom call whose HBM traffic is
 # its operands + results; the loop interior is pure CPU-lowering artifact
 # (r14: it charged ~103 GB of phantom traffic to moe_experts for the
-# dropless grouped matmul at the llama_moe bench shape). Interior ops
+# dropless grouped matmul at a bench shape that is gone). Interior ops
 # carry the kernel's named scope followed by the loop path in op_name
 # ("...moe_experts_gmm/while/body/..."); the while instruction itself
 # (scope path ends at .../while) is KEPT — its carried tuple is the
@@ -315,7 +315,7 @@ def build_op_bytes(hlo_text: str):
       buffer lookup, because operands appear as bare ``%name`` references.
 
     Unlike XLA's cost-model "bytes accessed" (which double-counts fused
-    interior uses and can exceed physical bandwidth — VERDICT r3 weak #3)
+    interior uses and can exceed physical bandwidth)
     this approximates the DMA traffic the scheduled program issues. It is
     still a model: a tiled conv may re-read inputs (undercount) and a
     consumer whose producer stayed VMEM-resident is overcounted; the
@@ -392,7 +392,7 @@ _COLLECTIVE_RE = re.compile(
 
 def collective_byte_census(hlo_text: str):
     """Per-opcode / per-region byte census of the collectives in a compiled
-    module — the chipless EP comms model (PROFILE_MOE.md r17).
+    module: a chipless comms model.
 
     Every collective instruction is charged its HBM result-buffer bytes
     (``_shape_bytes`` over the printed result shape, alternate-memory

@@ -15,9 +15,9 @@ TPU pod, so this doubles as the end-to-end CI leg. Two measured phases:
 ``--aot`` emits the chipless byte/FLOP model of the decode step instead:
 ``jit(...).lower(abstract).compile()`` read by profile_step.py's HLO
 readers, with per-region HBM bytes attributed by the serve_*
-named-scope tags (serve_cache / serve_attn / serve_mlp / serve_moe /
-serve_head) and gated in CI by ``check_regression.py --aot-bytes``
-against the ``aot_regions`` golden (key
+named-scope tags (serve_cache / serve_attn / serve_mlp / serve_head) and
+gated in CI by ``check_regression.py --aot-bytes`` against the
+``aot_regions`` golden (key
 ``<model>_decode b<bucket> s<max_len> -``).
 
 ``--spec-decode ngram|draft`` (r19) runs saturation a second time with
@@ -48,7 +48,7 @@ if REPO_ROOT not in sys.path:
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 #: Named-scope tags the decode forward emits (models/llama.py decode path).
-SERVE_TAG_RE = re.compile(r"\bserve_(embed|cache|attn|mlp|moe|head)\b")
+SERVE_TAG_RE = re.compile(r"\bserve_(embed|cache|attn|mlp|head)\b")
 
 
 def _say(msg: str) -> None:

@@ -69,44 +69,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "parallel ppermute; ring_allgather = all-gather-KV "
                         "fallback), Ulysses all-to-all, or plain XLA")
     p.add_argument("--seq-len", type=int, default=None)
-    p.add_argument("--moe-top-k", type=int, default=None, dest="moe_top_k",
-                   help="experts routed per token (llama_moe family)")
-    p.add_argument("--moe-capacity-factor", type=float, default=None,
-                   dest="moe_capacity_factor",
-                   help="expert capacity = cf * T * top_k / E (tokens beyond "
-                        "it are dropped, Switch-style)")
-    p.add_argument("--moe-dispatch", default=None, dest="moe_dispatch_impl",
-                   choices=["sort", "gather", "einsum", "dropless"],
-                   help="MoE token-dispatch formulation (parallel/moe.py): "
-                        "sort (argsort+segment), gather (slot table), "
-                        "einsum (one-hot masks, GSPMD oracle), or dropless "
-                        "(ragged Pallas grouped matmul — no capacity "
-                        "factor, no dropped tokens)")
-    p.add_argument("--moe-combine", default=None, dest="moe_combine_dtype",
-                   choices=["fp32", "bf16"],
-                   help="combine-einsum precision (bf16 halves combine "
-                        "bandwidth; router softmax/top-k always fp32)")
-    p.add_argument("--moe-router-dtype", default=None, dest="moe_router_dtype",
-                   choices=["fp32", "bf16"],
-                   help="router logits-matmul precision (fp32 = ST-MoE "
-                        "exact default; bf16 keeps fp32 accumulation and "
-                        "fp32 softmax/top-k)")
-    p.add_argument("--moe-router-impl", default=None, dest="moe_router_impl",
-                   choices=["reference", "fused"],
-                   help="router softmax/top-k/gates: reference XLA chain "
-                        "(default) or the fused single-pass Pallas kernel "
-                        "(ops/fused_router.py)")
-    p.add_argument("--moe-ep-dispatch", default=None, dest="moe_ep_dispatch",
-                   choices=["replicated", "a2a", "a2a_overlap"],
-                   help="dropless expert-parallel transport: replicated "
-                        "(every device runs all experts), a2a (all-to-all "
-                        "token shards to local expert weights), or "
-                        "a2a_overlap (chunked a2a double-buffered against "
-                        "the grouped matmul)")
-    p.add_argument("--moe-ep-overlap-chunks", type=int, default=None,
-                   dest="moe_ep_overlap_chunks",
-                   help="a2a_overlap double-buffer windows over the token "
-                        "dim (>= 2 overlaps; the last window may be torn)")
     p.add_argument("--dropout", type=float, default=None,
                    help="model dropout rate (families that support it)")
     p.add_argument("--tensorboard-dir", type=str, default=None,

@@ -289,7 +289,7 @@ def manual_call(fn, *args, in_specs, out_specs, mesh: Mesh | None = None):
     GSPMD path goes through here: mesh axes the specs leave unmentioned
     replicate, so ``P()`` specs mean "every device runs the whole kernel".
     Without a multi-device mesh, or when already inside a manual
-    region (ring attention, pipeline stages, the EP shard_map), ``fn`` is
+    region (ring attention, pipeline stages), ``fn`` is
     called directly.
     """
     mesh = mesh or current_mesh()

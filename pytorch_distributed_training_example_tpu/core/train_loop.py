@@ -176,8 +176,8 @@ def make_train_step(task, grad_accum: int = 1, health: bool = False) -> Callable
 
     ``health=True`` adds the telemetry health pack to the metrics dict:
     update/param norms, finite flags (utils/telemetry.health_pack) and any
-    scalars the model sows under the ``"telemetry"`` collection (MoE
-    router-load entropy / drop fraction). All on-device; the scalars ride
+    scalars the model sows under the ``"telemetry"`` collection (the expert
+    layers' held rows and layouts). All on-device; the scalars ride
     the same device_get the loss already takes, so there is no extra host
     sync — only the small fused reductions inside the step. Downstream the
     fetched row feeds the anomaly guard AND the fleet layer: the
@@ -192,8 +192,9 @@ def make_train_step(task, grad_accum: int = 1, health: bool = False) -> Callable
     def compute_grads(state: TrainState, batch: dict, step_rng, batch_stats):
         def loss_fn(params):
             variables = {"params": params}
-            # "losses" collects model-internal auxiliary terms (MoE load
-            # balancing); "batch_stats" is BatchNorm's running stats;
+            # "losses" collects model-internal auxiliary terms (GLM's
+            # multi-token-prediction loss); "batch_stats" is BatchNorm's
+            # running stats;
             # "telemetry" (health runs only) collects model diagnostics —
             # sow() is a no-op when the collection isn't mutable.
             mutable = ["losses"]

@@ -61,9 +61,7 @@ TRACED_KNOBS = (
     "global_batch_size", "grad_accum_steps", "precision", "remat",
     "remat_policy", "strategy", "attn_impl", "dropout", "label_smoothing",
     "grad_clip", "optimizer", "weight_decay", "momentum", "telemetry",
-    "moe_top_k", "moe_capacity_factor", "moe_dispatch_impl",
-    "moe_combine_dtype", "moe_router_dtype", "moe_router_impl",
-    "moe_ep_dispatch", "moe_ep_overlap_chunks", "pp_microbatches",
+    "pp_microbatches",
 )
 
 

@@ -175,15 +175,6 @@ class LlamaBlock(nn.Module):
     dtype: Any
     param_dtype: Any
     attn_impl: str = "auto"
-    num_experts: int = 0     # >0 replaces the SwiGLU MLP with an MoE block (EP)
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_dispatch_impl: str = "gather"  # sort|gather|einsum|dropless (parallel/moe.py)
-    moe_combine_dtype: Any = None      # None -> fp32 combine (exact)
-    moe_router_dtype: Any = None       # None -> fp32 logits matmul (exact)
-    moe_router_impl: str = "reference"  # reference | fused (ops/fused_router)
-    moe_ep_dispatch: str = "replicated"  # replicated|a2a|a2a_overlap (dropless)
-    moe_ep_overlap_chunks: int = 2      # a2a_overlap double-buffer windows
     sp: bool = False
 
     @nn.compact
@@ -196,37 +187,10 @@ class LlamaBlock(nn.Module):
                                                             decode_ctx)
         x = mesh_lib.constrain(x, _seq_rule("residual", self.sp))
         h = rn("mlp_norm")(x)
-        if self.num_experts > 0:
-            from pytorch_distributed_training_example_tpu.parallel.moe import MoEBlock
-
-            # Serving decode reuses the training MoE block at batch-decode
-            # shapes (T = B*S tokens). ``decode=True`` forces the dropless
-            # route: capacity-dropped dispatch is non-causal (a token's k>1
-            # choice competes for capacity with LATER tokens' k=0 choices),
-            # so only per-token-independent dropless routing has an exact
-            # incremental equivalent. Params are identical across dispatch
-            # impls, so any trained checkpoint serves through this path.
-            scope = (jax.named_scope("serve_moe") if decode_ctx is not None
-                     else contextlib.nullcontext())
-            with scope:
-                h = MoEBlock(self.num_experts, self.ffn_dim,
-                             top_k=self.moe_top_k,
-                             capacity_factor=self.moe_capacity_factor,
-                             dispatch_impl=self.moe_dispatch_impl,
-                             combine_dtype=self.moe_combine_dtype,
-                             router_dtype=self.moe_router_dtype,
-                             router_impl=self.moe_router_impl,
-                             ep_dispatch=self.moe_ep_dispatch,
-                             ep_overlap_chunks=self.moe_ep_overlap_chunks,
-                             dtype=self.dtype,
-                             param_dtype=self.param_dtype,
-                             name="moe")(h, train,
-                                         decode=decode_ctx is not None)
-        else:
-            scope = (jax.named_scope("serve_mlp") if decode_ctx is not None
-                     else contextlib.nullcontext())
-            with scope:
-                h = swiglu_mlp(h, self.ffn_dim, self.dtype, self.param_dtype)
+        scope = (jax.named_scope("serve_mlp") if decode_ctx is not None
+                 else contextlib.nullcontext())
+        with scope:
+            h = swiglu_mlp(h, self.ffn_dim, self.dtype, self.param_dtype)
         x = x + h
         return mesh_lib.constrain(x, _seq_rule("residual", self.sp))
 
@@ -267,15 +231,6 @@ class Llama(nn.Module):
     remat_policy: str = "nothing"  # key into REMAT_POLICIES
     scan_layers: bool = False
     attn_impl: str = "auto"
-    num_experts: int = 0
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_dispatch_impl: str = "gather"
-    moe_combine_dtype: Any = None
-    moe_router_dtype: Any = None
-    moe_router_impl: str = "reference"
-    moe_ep_dispatch: str = "replicated"
-    moe_ep_overlap_chunks: int = 2
     sp: bool = False
     logits_dtype: Any = jnp.float32  # storage dtype; loss upcasts per-element
 
@@ -312,14 +267,7 @@ class Llama(nn.Module):
             head_dim=self.head_dim, ffn_dim=self.ffn_dim,
             rope_theta=self.rope_theta, dtype=self.dtype,
             param_dtype=self.param_dtype, attn_impl=self.attn_impl,
-            num_experts=self.num_experts, moe_top_k=self.moe_top_k,
-            moe_capacity_factor=self.moe_capacity_factor,
-            moe_dispatch_impl=self.moe_dispatch_impl,
-            moe_combine_dtype=self.moe_combine_dtype,
-            moe_router_dtype=self.moe_router_dtype,
-            moe_router_impl=self.moe_router_impl,
-            moe_ep_dispatch=self.moe_ep_dispatch,
-            moe_ep_overlap_chunks=self.moe_ep_overlap_chunks, sp=self.sp)
+            sp=self.sp)
         if self.scan_layers:
             # One stacked block scanned over a leading 'layers' dim: constant
             # trace/compile cost regardless of depth. The body wrapper adapts
@@ -406,9 +354,6 @@ TP_RULES = (
     (r"down/kernel", P("model", None)),
     (r"embed/embedding", P(None, "model")),
     (r"lm_head/kernel", P(None, "model")),
-    # MoE variant: experts sharded on the expert axis (EP), router replicated.
-    (r"moe/experts/w_(up|down)", P("expert", None, "model")),
-    (r"moe/router/kernel", P()),
 )
 
 
@@ -443,52 +388,10 @@ def llama_tiny(**kw) -> Llama:
     return Llama(**kw)
 
 
-def llama_moe_tiny(**kw) -> Llama:
-    """Test-scale MoE Llama (8 experts, top-2 routing)."""
-    kw.setdefault("num_experts", 8)
-    return llama_tiny(**kw)
-
-
-def llama_moe_520m(**kw) -> Llama:
-    """One-chip MoE Llama: the llama_400m trunk (d=1024, GQA 4:1, RoPE) at
-    12 layers with 8-expert top-2 MoE FFNs of ffn_dim 2048 — ~520M total /
-    ~220M active params. Sized so AdamW optimizer state (12 B/param f32) + bf16
-    compute copies + activations fit ONE v5e's 16 GB HBM: the 400m
-    backbone with 8 experts (1.18 B total) measured RESOURCE_EXHAUSTED
-    at any batch, with or without remat — expert stacks multiply FFN
-    params 8x, and optimizer memory, not activations, is the binding
-    constraint on a single chip (EP sharding divides it on real pods)."""
-    kw.setdefault("num_experts", 8)
-    kw.setdefault("num_layers", 12)
-    kw.setdefault("ffn_dim", 2048)
-    return llama_400m(**kw)
-
-
 def num_params(cfg: Llama) -> int:
     d, L, V = cfg.d_model, cfg.num_layers, cfg.vocab_size
     hd = cfg.head_dim
     attn = d * cfg.num_heads * hd + 2 * d * cfg.num_kv_heads * hd \
         + cfg.num_heads * hd * d
-    if cfg.num_experts:
-        # MoE block: E stacked (w_up, w_down) expert FFNs + fp32 router
-        mlp = cfg.num_experts * 2 * d * cfg.ffn_dim + d * cfg.num_experts
-    else:
-        mlp = 3 * d * cfg.ffn_dim
+    mlp = 3 * d * cfg.ffn_dim
     return V * d + L * (attn + mlp + 2 * d) + d + d * V
-
-
-def num_params_active(cfg: Llama, top_k: int | None = None) -> int:
-    """Parameters touched per token — the honest FLOPs basis for MoE MFU
-    (6*N_active, PaLM-style): only the routed experts' FFN weights count,
-    everything else as in the dense model. ``top_k`` defaults to the
-    routing the model actually executes (``cfg.moe_top_k``) so the MFU
-    basis can't drift from the config (ADVICE r5)."""
-    if not cfg.num_experts:
-        return num_params(cfg)
-    if top_k is None:
-        top_k = cfg.moe_top_k
-    top_k = min(top_k, cfg.num_experts)
-    total = num_params(cfg)
-    per_expert = 2 * cfg.d_model * cfg.ffn_dim
-    inactive = (cfg.num_experts - top_k) * per_expert * cfg.num_layers
-    return total - inactive

@@ -6,7 +6,7 @@ the framework needs: which task head to use, an input template for sharded
 init, a forward-FLOPs estimate for MFU accounting, and per-family tensor-
 parallel rule tables.
 
-Families: ResNet (torchvision depths), ViT, GPT-2, Llama (dense and MoE) and
+Families: ResNet (torchvision depths), ViT, GPT-2, Llama and
 the Granite 4.0-H hybrid (Mamba-2 state-space mixers among GQA attention:
 ``granite4_h_micro`` at its published sizes, ``granite4_h_micro_share`` one
 chip's share of it, ``granite_hybrid_tiny`` for tests; training only,
@@ -82,17 +82,17 @@ def create_model(name: str, *, num_classes: int = 1000, image_size: int = 224,
                  seq_len: int = 1024, dtype=jnp.bfloat16,
                  param_dtype=jnp.float32, logits_dtype=jnp.float32,
                  **options) -> ModelBundle:
-    """``options`` (``_OPTIONS`` and the expert models' ``_MOE_OPTIONS``) go
-    whole to the family's builder, which names the ones it takes."""
+    """``options`` (``_OPTIONS``) go whole to the family's builder, which
+    names the ones it takes."""
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; have {list_models()}")
     builder = _REGISTRY[name]
     named = inspect.signature(builder).parameters
     for opt, value in options.items():
-        if opt not in _OPTIONS and opt not in _MOE_OPTIONS:
+        if opt not in _OPTIONS:
             raise TypeError(
                 f"create_model() got an unexpected option {opt!r}; have "
-                f"{sorted({**_OPTIONS, **_MOE_OPTIONS})}")
+                f"{sorted(_OPTIONS)}")
         if (opt in _NEVER_DROPPED and opt not in named
                 and value != _OPTIONS[opt]):
             raise ValueError(
@@ -103,51 +103,13 @@ def create_model(name: str, *, num_classes: int = 1000, image_size: int = 224,
                    logits_dtype=logits_dtype, **{**_OPTIONS, **options})
 
 
-_MOE_DTYPES = {"fp32": None, "bf16": jnp.bfloat16}
-#: The expert layers' options (``parallel/moe.MoEBlock``): the value that
-#: asks for nothing, and the values a spelt-out one may take.
-_MOE_OPTIONS = {
-    "moe_capacity_factor": (1.25, None),
-    "moe_top_k": (2, None),
-    "moe_dispatch_impl": ("gather", ("sort", "gather", "einsum", "dropless")),
-    "moe_combine_dtype": ("fp32", tuple(sorted(_MOE_DTYPES))),
-    "moe_router_dtype": ("fp32", tuple(sorted(_MOE_DTYPES))),
-    "moe_router_impl": ("reference", ("reference", "fused")),
-    "moe_ep_dispatch": ("replicated", ("replicated", "a2a", "a2a_overlap")),
-    "moe_ep_overlap_chunks": (2, None),
-}
-
-
-def _moe_kwargs(**moe):
-    """An expert builder's options, checked, as ``Llama``'s fields: the two
-    dtype spellings become dtypes (None = fp32, exact)."""
-    moe = {k: moe.get(k, default) for k, (default, _) in _MOE_OPTIONS.items()}
-    for key, (_, have) in _MOE_OPTIONS.items():
-        if have is not None and moe[key] not in have:
-            raise ValueError(f"unknown {key} {moe[key]!r}; have {list(have)}")
-    ep, chunks = moe["moe_ep_dispatch"], int(moe["moe_ep_overlap_chunks"])
-    if ep != "replicated" and moe["moe_dispatch_impl"] != "dropless":
-        raise ValueError(
-            f"moe_ep_dispatch={ep!r} requires moe_dispatch_impl='dropless' "
-            f"(got {moe['moe_dispatch_impl']!r}); the capacity-dropped impls "
-            "shard through GSPMD alone")
-    if chunks < 1:
-        raise ValueError(f"moe_ep_overlap_chunks must be >= 1 (got {chunks})")
-    return {**moe, "moe_ep_overlap_chunks": chunks,
-            "moe_combine_dtype": _MOE_DTYPES[moe["moe_combine_dtype"]],
-            "moe_router_dtype": _MOE_DTYPES[moe["moe_router_dtype"]]}
-
-
 @register("vit_b16")
 def _vit_b16(*, num_classes, image_size, dtype, param_dtype, remat,
              attn_impl="auto", dropout=0.0, **_):
     from pytorch_distributed_training_example_tpu.models import vit
 
     # dropout defaults to 0.0 for parity with the reference model factory
-    # (torchvision vit_b_16: dropout=0.0, attention_dropout=0.0). r4 profile
-    # found dropout=0.1 was costing ~25% of the ViT step: the threefry mask
-    # bits get rematerialized inside the weight-grad matmul fusions
-    # (PROFILE_VIT.md).
+    # (torchvision vit_b_16: dropout=0.0, attention_dropout=0.0).
     module = vit.vit_b16(num_classes=num_classes, dtype=dtype,
                          param_dtype=param_dtype, remat=remat, dropout=dropout,
                          attn_impl=attn_impl)
@@ -250,40 +212,6 @@ def _llama_tiny(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
                               max_seq_len=max(seq_len, 256), sp=sp,
                               attn_impl=attn_impl, logits_dtype=logits_dtype)
     return _lm_bundle(module, llama.TP_RULES, seq_len, llama.num_params)
-
-
-@register("llama_moe_tiny")
-def _llama_moe_tiny(*, seq_len, dtype, param_dtype, remat,
-                    remat_policy="nothing", sp=False, attn_impl="auto",
-                    logits_dtype, **options):
-    from pytorch_distributed_training_example_tpu.models import llama
-
-    module = llama.llama_moe_tiny(dtype=dtype, param_dtype=param_dtype,
-                                  remat=remat, remat_policy=remat_policy,
-                                  max_seq_len=max(seq_len, 256),
-                                  sp=sp, attn_impl=attn_impl,
-                                  logits_dtype=logits_dtype,
-                                  **_moe_kwargs(**options))
-    # MFU basis = ACTIVE params (top-2 experts), not the full expert stack
-    return _lm_bundle(module, llama.TP_RULES, seq_len,
-                      llama.num_params_active)
-
-
-@register("llama_moe")
-def _llama_moe(*, seq_len, dtype, param_dtype, remat, remat_policy="nothing",
-               sp=False, attn_impl="auto", logits_dtype, **options):
-    """Llama trunk with 8 experts, top-2, ~520M parameters in all: sized so
-    that AdamW's state fits one v5e (``llama.llama_moe_520m``)."""
-    from pytorch_distributed_training_example_tpu.models import llama
-
-    module = llama.llama_moe_520m(dtype=dtype, param_dtype=param_dtype,
-                                  remat=remat, remat_policy=remat_policy,
-                                  max_seq_len=max(seq_len, 2048),
-                                  sp=sp, attn_impl=attn_impl,
-                                  logits_dtype=logits_dtype,
-                                  **_moe_kwargs(**options))
-    return _lm_bundle(module, llama.TP_RULES, seq_len,
-                      llama.num_params_active)
 
 
 def _granite_hybrid(make):

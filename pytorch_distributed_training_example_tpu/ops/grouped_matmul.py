@@ -2,15 +2,16 @@
 
 ``gmm(x [Tk, d], w [E, d, f], group_starts [E], group_counts [E]) -> [Tk, f]``
 computes ``out[r] = x[r] @ w[e]`` for every row ``r`` of expert ``e``'s
-contiguous segment ``[starts[e], starts[e] + counts[e])`` of the sorted
-token layout that ``routing_stats()``'s stable argsort already produces
-(parallel/moe.py). This is the MegaBlocks reformulation of the expert
-FFN: no ``[E, C, d]`` capacity buffer is ever materialized and no token
-is dropped — the kernel tiles the token dimension and a scalar-prefetched
-per-tile expert index steers each tile's ``[d, bf]`` weight block straight
-out of the stacked ``[E, d, f]`` weights (the BlockSpec index_map reads
-the prefetched tile->expert table, so weight traffic is one block per
-tile, reused across a segment's consecutive tiles).
+contiguous segment ``[starts[e], starts[e] + counts[e])`` of a token
+layout sorted by expert (``parallel/moe._plan`` makes the padded form of it
+directly and calls ``_gmm_padded`` and the FFN forms). This is the
+MegaBlocks reformulation of the expert FFN: no ``[E, C, d]`` capacity
+buffer is ever materialized and no token is dropped — the kernel tiles
+the token dimension and a scalar-prefetched per-tile expert index steers
+each tile's ``[d, bf]`` weight block straight out of the stacked
+``[E, d, f]`` weights (the BlockSpec index_map reads the prefetched
+tile->expert table, so weight traffic is one block per tile, reused across
+a segment's consecutive tiles).
 
 Raggedness is handled by a tile-aligned relayout with STATIC shapes:
 each expert's segment is padded up to a whole number of ``bt``-row tiles
@@ -18,8 +19,7 @@ each expert's segment is padded up to a whole number of ``bt``-row tiles
 weight block is visited and zero-initialized). The padded row count is
 bounded by ``ceil(Tk/bt)*bt + E*bt`` independent of any capacity factor,
 so the relayout is two O(Tk·d) gathers (in, out) against int32 index
-vectors built from the segment offsets — the same compact-index
-machinery the sort dispatch uses, never an ``[E, C]`` slot table.
+vectors built from the segment offsets, never an ``[E, C]`` slot table.
 
 Backward is a ``custom_vjp``:
 
@@ -38,9 +38,10 @@ On the ``cpu`` platform both kernels run in interpret mode (numerically the
 same program), so CPU tests and dryruns validate the real kernel bodies
 (``ops/backend.py``). fp32 accumulation everywhere (``preferred_element_type``); outputs are
 cast to the input dtype, gradients to the primal dtypes. Tile sizes are
-powers of two down to 8 rows — Mosaic-friendly at bench shapes; lane-dim
-(128) padding of small test shapes is interpret-mode territory and part
-of the chip A/B, not correctness (PROFILE_MOE.md r14 hooks).
+powers of two down to 8 rows — Mosaic-friendly at the cells' shapes (on the
+chip the kernels are read by name under the ``per_layer`` metric
+``moe_experts_ms``); lane-dim (128) padding of small test shapes is
+interpret-mode territory, not correctness.
 """
 
 from __future__ import annotations
@@ -61,8 +62,8 @@ def _block_rows(n_rows: int, num_experts: int) -> int:
     worst-case padding ``E * bt`` (every expert rounds up at most one
     partial tile): the tile is capped so padding stays within ~1/8 of the
     real rows. Tiny test shapes bottom out at 8-row tiles (mostly-padding
-    layouts are interpret-mode territory); the llama_moe bench shape
-    (kT=16384, E=8) gets 256-row tiles — 12.5% worst-case padding instead
+    layouts are interpret-mode territory); kT=16384 rows over E=8
+    get 256-row tiles — 12.5% worst-case padding instead
     of the 25% a 512-row tile costs, at twice the grid length. 512 stays
     the hard ceiling (MXU-friendly multiples of 128 beyond that buy no
     reuse: the weight block is already resident across a segment's tiles).
@@ -451,9 +452,9 @@ def gmm(x, w, group_starts, group_counts):
     ``out[r] = x[r] @ w[e]`` for rows ``r`` in segment
     ``[group_starts[e], group_starts[e] + group_counts[e])``; segments must
     tile ``[0, Tk)`` in expert order (``group_starts`` = exclusive cumsum of
-    ``group_counts``, ``sum == Tk``) — exactly what ``routing_stats()``
-    hands out. fp32 accumulation, output in ``x.dtype``. Differentiable in
-    ``x`` and ``w``; the integer segment offsets get float0 cotangents.
+    ``group_counts``, ``sum == Tk``). fp32 accumulation, output in
+    ``x.dtype``. Differentiable in ``x`` and ``w``; the integer segment
+    offsets get float0 cotangents.
     """
     out, _ = _gmm_impl(x, w, group_starts, group_counts)
     return out

@@ -69,8 +69,7 @@ class PipelinedLlama:
         block = llama_lib.LlamaBlock(
             num_heads=m.num_heads, num_kv_heads=m.num_kv_heads,
             head_dim=m.head_dim, ffn_dim=m.ffn_dim, rope_theta=m.rope_theta,
-            dtype=m.dtype, param_dtype=m.param_dtype, attn_impl="xla",
-            num_experts=m.num_experts)
+            dtype=m.dtype, param_dtype=m.param_dtype, attn_impl="xla")
         if m.remat:
             block_apply = jax.checkpoint(
                 lambda p, x: block.apply({"params": p}, x, train),

@@ -47,16 +47,6 @@ class Config:
     # dots_no_batch | attn_out — see models.llama.REMAT_POLICIES
     remat_policy: str = "nothing"
     grad_accum_steps: int = 1  # microbatches per optimizer step (in-step scan)
-    # MoE routing/dispatch (llama_moe family; parallel/moe.py)
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_dispatch_impl: str = "gather"  # sort | gather | einsum | dropless
-    moe_combine_dtype: str = "fp32"  # fp32 (exact) | bf16 (combine-BW A/B)
-    moe_router_dtype: str = "fp32"  # fp32 (ST-MoE exact) | bf16 (matmul A/B)
-    moe_router_impl: str = "reference"  # reference | fused (Pallas kernel)
-    # dropless EP transport: replicated weights | sharded a2a | a2a+gmm overlap
-    moe_ep_dispatch: str = "replicated"  # replicated | a2a | a2a_overlap
-    moe_ep_overlap_chunks: int = 2  # a2a_overlap double-buffer windows
     pp_microbatches: int = 8  # GPipe microbatches (strategy "pp")
     # parallelism (mesh axis sizes; -1 absorbs remaining devices)
     strategy: str = "dp"  # dp | fsdp | fsdp_tp (model-provided tables)
@@ -195,13 +185,10 @@ class Config:
     def model_options(self) -> dict[str, Any]:
         """The fields that shape the model, as ``registry.create_model``'s
         options: the one place that says which they are. A family's builder
-        names the ones it takes; every ``moe_*`` field goes along by its
-        prefix, so a new one needs a field and a flag here and a name there."""
-        moe = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
-               if f.name.startswith("moe_")}
+        names the ones it takes."""
         return dict(remat=self.remat, remat_policy=self.remat_policy,
                     sp=self.strategy.endswith("_sp"),
-                    attn_impl=self.attn_impl, dropout=self.dropout, **moe)
+                    attn_impl=self.attn_impl, dropout=self.dropout)
 
     def replace(self, **kw) -> "Config":
         return dataclasses.replace(self, **kw)

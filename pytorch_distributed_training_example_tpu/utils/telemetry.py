@@ -5,8 +5,8 @@ interpretable):
 
 1. **Health pack** (device side): ``health_pack`` computes global grad/update/
    param norms and finite flags INSIDE the compiled train step, and
-   ``collect_sowed`` folds model-internal diagnostics (MoE router-load
-   entropy, drop fraction — sowed under the ``"telemetry"`` collection) into
+   ``collect_sowed`` folds model-internal diagnostics (the expert layers'
+   held rows and layouts, sowed under the ``"telemetry"`` collection) into
    the same metrics dict. Everything rides the existing ``log_every``
    device_get: zero extra host syncs at the default cadence.
 
@@ -112,14 +112,18 @@ def collect_sowed(tele_vars) -> dict[str, jax.Array]:
     """Fold a flax ``"telemetry"`` sow collection into named mean scalars.
 
     Sow appends one entry per call site per layer (tuples; a leading scan
-    dim when layers are scanned) — group leaves by their final name and
-    average, so ``router_load_entropy`` is the mean over all MoE layers.
+    dim when layers are scanned): leaves are grouped by their final name and
+    averaged.
 
-    Since the r8 router round both MoE sows (``moe_drop_fraction``,
-    ``router_load_entropy``) derive from the SAME compact [E] routing
-    counts the dispatch uses (``parallel/moe.py routing_stats``) — they
-    are exact token counts, not a second mask-based estimate, and cost no
-    extra [T, E] materialization in the step.
+    The expert layers (``parallel/moe.py``) sow four scalars each, named with
+    the enclosing block behind a dot, so a layer keeps its own reading:
+    ``moe_held_rows.<block>`` (the (token, choice) pairs that landed on the
+    experts this chip holds), ``moe_held_peak.<block>`` (the fullest held
+    expert's rows over their mean), ``moe_whole.<block>`` (1.0 where the held
+    rows went through the bounded layout whole) and
+    ``moe_source_parts.<block>`` (the column parts the padded rows are
+    gathered back in). All four come from the router's own ``[E]`` counts,
+    which the routine needs anyway.
     """
     out: dict[str, list] = {}
     flat = jax.tree_util.tree_flatten_with_path(tele_vars)[0]

@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 from pytorch_distributed_training_example_tpu.ops import (
-    attention as attn, flash_attention as fa, fused_router, grouped_matmul)
+    attention as attn, flash_attention as fa, grouped_matmul)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
@@ -312,12 +312,6 @@ def test_grouped_ffn_fwd_bwd_compiles(one_chip, as_tpu):
 
     _compiled_text(grads, _sds((T, d), one_chip), _sds((E, d, ffn), one_chip),
                    _sds((E, ffn, d), one_chip), seg, seg)
-
-
-@pytest.mark.parametrize("E,k", [(8, 2), (64, 8)])
-def test_fused_router_compiles(one_chip, as_tpu, E, k):
-    _compiled_text(lambda logits: fused_router.fused_router(logits, k),
-                   _sds((8192, E), one_chip, jnp.float32))
 
 
 def test_flash_under_four_device_mesh_compiles(topo, one_chip, as_tpu):

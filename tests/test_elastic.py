@@ -450,9 +450,18 @@ def _write_repeat_kill_script(tmp_path):
         "os.makedirs(ckdir, exist_ok=True)\n"
         "rank = int(os.environ.get('PROCESS_ID', '0'))\n"
         "world = int(os.environ.get('NUM_PROCESSES', '1'))\n"
+        "path = os.path.join(ckdir, 'dead_hosts.jsonl')\n"
         "if rank == 0:\n"
-        "    with open(os.path.join(ckdir, 'dead_hosts.jsonl'), 'a') as fh:\n"
+        "    with open(path, 'a') as fh:\n"
         "        fh.write(json.dumps({'host': 1, 'world': world}) + '\\n')\n"
+        "else:\n"
+        "    # the launcher tears the gang down at the first exit: die only\n"
+        "    # once rank 0's record of this (the first) attempt is on disk\n"
+        "    import time\n"
+        "    deadline = time.time() + 30\n"
+        "    while time.time() < deadline and not (\n"
+        "            os.path.exists(path) and os.path.getsize(path)):\n"
+        "        time.sleep(0.02)\n"
         "os._exit(76)\n")
     return script
 

@@ -460,10 +460,10 @@ def test_ir_sharding_seq_census(tiny):
         errs[0].message)
 
 
-@pytest.mark.parametrize("scope", [None, "moe_dispatch", "attn_ulysses_a2a"])
+@pytest.mark.parametrize("scope", [None, "attn_ulysses_a2a"])
 def test_ir_a2a_scope_rule(tiny, scope, compiled_afresh):
-    """GL105: an untagged all-to-all is an error; the MoE EP transport and
-    Ulysses scopes are sanctioned (their bytes are census-attributable)."""
+    """GL105: an untagged all-to-all is an error; the Ulysses scope is
+    sanctioned (its bytes are census-attributable)."""
     import numpy as np
     from jax.sharding import Mesh
 

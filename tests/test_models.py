@@ -26,9 +26,8 @@ def test_resnet_forward_shapes(name, size, classes):
 
 
 def test_vit_dropout_plumbed_and_defaults_off():
-    """Reference parity: torchvision vit_b_16 defaults to dropout=0.0; the
-    r3 registry hardcoded 0.1 and paid ~25% of the step for it
-    (PROFILE_VIT.md). The rate must flow from create_model to the module."""
+    """Reference parity: torchvision vit_b_16 defaults to dropout=0.0. The
+    rate must flow from create_model to the module."""
     off = registry.create_model("vit_b16", num_classes=10)
     assert off.module.dropout == 0.0
     on = registry.create_model("vit_b16", num_classes=10, dropout=0.1)
@@ -47,51 +46,34 @@ def test_dropout_rejected_for_families_without_it():
     assert on.module.dropout == 0.1
 
 
-@pytest.mark.parametrize("option,given,on_module", [
-    ("moe_top_k", 1, 1),
-    ("moe_capacity_factor", 2.0, 2.0),
-    ("moe_dispatch_impl", "sort", "sort"),
-    ("moe_combine_dtype", "bf16", jnp.bfloat16),
-    ("moe_router_dtype", "bf16", jnp.bfloat16),
-    ("moe_router_impl", "fused", "fused"),
-    ("moe_ep_dispatch", "a2a", "a2a"),
-    ("moe_ep_overlap_chunks", 3, 3),
-])
-def test_create_model_forwards_each_option(option, given, on_module):
-    """``create_model`` names no family's option: what a caller gives the
-    expert model is what its module holds (the dtype spellings as dtypes)."""
-    more = ({"moe_dispatch_impl": "dropless"}  # the sharded transports need it
-            if option == "moe_ep_dispatch" else {})
-    default = registry.create_model("llama_moe_tiny", seq_len=32).module
-    module = registry.create_model("llama_moe_tiny", seq_len=32,
-                                   **{option: given}, **more).module
-    assert getattr(module, option) == on_module
-    assert getattr(default, option) != on_module
-    # a dense model of the family is handed the same option and ignores it
-    registry.create_model("llama_tiny", seq_len=32, **{option: given}, **more)
-
-
 @pytest.mark.parametrize("name,options,error,match", [
-    ("llama_moe_tiny", {"moe_dispach_impl": "sort"}, TypeError, "moe_dispach"),
     ("llama_tiny", {"dropout": 0.1}, ValueError, "does not implement dropout"),
     ("gpt2_tiny", {"remat_policy": "dots"}, ValueError,
      "does not implement remat_policy"),
-    ("llama_moe_tiny", {"moe_dispatch_impl": "nope"}, ValueError,
-     "unknown moe_dispatch_impl 'nope'"),
     ("resnet18", {"dropout": 0.0, "remat_policy": "nothing", "sp": True,
-                  "attn_impl": "flash", "moe_top_k": 1}, None, None),
+                  "attn_impl": "flash"}, None, None),
 ])
 def test_create_model_refuses_what_it_refused(name, options, error, match):
     """The registry's edge is what it was when ``create_model`` spelt every
-    option out: a misspelt keyword, a dropout or remat policy given to a
-    family without one and an unknown ``moe_*`` value fail loudly; the
-    value that asks for nothing, and the options another family takes, pass
-    (the Trainer hands every model the whole of ``Config.model_options``)."""
+    option out: a dropout or remat policy given to a family without one
+    fails loudly; the value that asks for nothing, and the options another
+    family takes, pass (the Trainer hands every model the whole of
+    ``Config.model_options``)."""
     if error is None:
         registry.create_model(name, seq_len=32, **options)
         return
     with pytest.raises(error, match=match):
         registry.create_model(name, seq_len=32, **options)
+
+
+@pytest.mark.parametrize("name", registry.list_models())
+def test_no_moe_option_reaches_any_model(name):
+    """The expert layer has no options: ``moe_dispatch_impl`` (an option of
+    the expert layer that is gone, which every family once accepted and all
+    but two ignored) is refused by name for every registered model, as any
+    keyword outside ``_OPTIONS`` is."""
+    with pytest.raises(TypeError, match="unexpected option 'moe_dispatch_impl'"):
+        registry.create_model(name, seq_len=32, moe_dispatch_impl="sort")
 
 
 @pytest.mark.parametrize("name,expected_m", [
@@ -107,32 +89,6 @@ def test_param_counts_extended_zoo(name, expected_m):
                                    jnp.zeros((1, 224, 224, 3)), train=False))
     n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(variables["params"]))
     assert abs(n / 1e6 - expected_m) / expected_m < 0.01, n
-
-
-def test_llama_moe_param_accounting():
-    """The MoE zoo entry's closed-form totals match real init, and the MFU
-    basis counts only ACTIVE (top-2) experts — an 8-expert MoE must not
-    claim the full expert stack as compute."""
-    from pytorch_distributed_training_example_tpu.models import llama
-
-    bundle = registry.create_model("llama_moe", seq_len=64)
-    variables = jax.eval_shape(
-        lambda: bundle.module.init(jax.random.PRNGKey(0),
-                                   jnp.zeros((1, 64), jnp.int32)))
-    n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(variables["params"]))
-    cfg = bundle.module
-    assert n == llama.num_params(cfg)
-    # Independent structural check: count the REAL expert-stack leaves
-    # (params under .../moe/experts) from the initialized tree; active =
-    # trunk + top_k/E of the expert stack must match the closed form.
-    flat = jax.tree_util.tree_flatten_with_path(variables["params"])[0]
-    expert = sum(
-        int(np.prod(leaf.shape)) for path, leaf in flat
-        if any(getattr(p, "key", None) == "experts" for p in path))
-    assert expert > 0.5 * n  # the stack dominates an 8-expert MoE
-    want_active = (n - expert) + expert * 2 // cfg.num_experts
-    assert llama.num_params_active(cfg) == want_active, (
-        llama.num_params_active(cfg), want_active)
 
 
 def test_param_count_resnet18():

@@ -4,9 +4,8 @@ A speculative engine may only change WHEN tokens are computed (K drafts
 scored in one batched verify forward), never WHICH tokens come out: greedy
 output with speculation on must be bit-identical to the unsped engine —
 across page-boundary crossings, eviction/recompute, prefix-cache hits and
-the prefill/decode disaggregation handoff. The same bar applies to the two
-decode paths this PR opens: MoE blocks served via forced-dropless routing
-and scan_layers checkpoints served with a stacked cache carry must match
+the prefill/decode disaggregation handoff. The same bar applies to
+scan_layers checkpoints served with a stacked cache carry: they must match
 the training forward's greedy argmax.
 """
 
@@ -275,34 +274,6 @@ def test_spec_rollback_returns_overshoot_pages(devices):
     # Every request retired; every page (minus the reserved scratch page)
     # must be back in the pool — rollback may not leak overshoot pages.
     assert eng.pool.num_free == spec.num_pages - kv_cache.RESERVED_PAGES
-
-
-# ---------------------------------------------------------------------------
-# MoE decode: forced-dropless serving == dropless training forward
-# ---------------------------------------------------------------------------
-
-
-def test_moe_decode_parity_with_dropless_training_forward(devices):
-    module, params = _model("llama_moe_tiny")
-    spec = engine_lib.spec_for_module(module, num_pages=64, page_size=8)
-    eng, _, out = _run_engine(module, params, spec, n_req=3, seed=2)
-    # Decode forces dropless routing whatever the checkpoint trained with
-    # (capacity-dropped dispatch is non-causal), so the oracle is the same
-    # weights applied through the dropless training path.
-    oracle = module.copy(moe_dispatch_impl="dropless")
-    for r in eng.completed:
-        ref = _jit_greedy(oracle, params, r.prompt, len(r.generated))
-        assert r.generated == ref, r.request_id
-
-
-def test_moe_spec_decode_token_identity(devices):
-    module, params = _model("llama_moe_tiny")
-    spec = engine_lib.spec_for_module(module, num_pages=64, page_size=8)
-    _, _, base = _run_engine(module, params, spec, n_req=4, seed=1)
-    eng, _, sped = _run_engine(module, params, spec, spec_decode_="ngram",
-                               n_req=4, seed=1)
-    assert sped == base
-    assert eng.stats["spec_steps"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -556,6 +556,72 @@ def test_source_parts_at_the_cells_widths(preset, source, parts, monkeypatch):
     assert asked == {source: parts}, asked
 
 
+def _numpy_plan(chosen, first, held, bt, G):
+    """The held experts' padded layout written out: for each held expert in
+    turn its (token, choice) pairs in flat order, padded to whole tiles of
+    ``bt`` rows (an expert with no pair keeps one tile), in ``G`` tiles;
+    ``(counts, tile_expert, row_pair, pair_row)`` with ``n*k`` for a padded
+    row that holds no pair and ``G*bt`` for a pair that has no row."""
+    n, k = chosen.shape
+    flat = chosen.reshape(-1)
+    counts, tile_expert, row_pair = [], [], []
+    pair_row = np.full(n * k, G * bt, np.int64)
+    for e in range(held):
+        pairs = np.flatnonzero(flat == first + e)
+        rows = max(-(-len(pairs) // bt), 1) * bt
+        pair_row[pairs] = len(row_pair) + np.arange(len(pairs))
+        row_pair += list(pairs) + [n * k] * (rows - len(pairs))
+        tile_expert += [e] * (rows // bt)
+        counts.append(len(pairs))
+    return (np.array(counts), np.array(tile_expert), np.array(row_pair),
+            pair_row.reshape(n, k).T)
+
+
+@pytest.mark.parametrize("routing", ["level", "collapsed", "absent"])
+@pytest.mark.parametrize("preset", [
+    "trinity_mini_share", "smallthinker_21b_share", "glm47_flash_share",
+    "nemotron3_nano_share"])
+def test_plan_at_the_cells_sizes_is_the_written_out_layout(preset, routing):
+    """``_held_counts`` and ``_plan`` against a numpy layout at each expert
+    cell's ``(experts, k, held, tile rows)`` and 8,192 tokens: level routing
+    in the bounded layout the cell runs (it fits), every token on held
+    experts in the unbounded one (the bounded one's parts each plan their own
+    rows), and no token on them (one empty tile an expert)."""
+    module = registry.create_model(preset, seq_len=8192).module
+    E, k, (held, first), n = (module.num_experts, module.top_k,
+                              module.held_experts, 8192)
+    scores = np.random.RandomState(5).rand(n, E)
+    if routing == "collapsed":
+        scores[:, first:first + held] += 1.0
+    elif routing == "absent":
+        scores[:, first:first + held] -= 1.0
+    chosen = np.argsort(-scores, axis=-1)[:, :k].astype(np.int32)
+    bt = min(moe_lib.EXPERT_TILE_ROWS, gmm_lib._block_rows(n * k, held))
+    assert bt == 128
+    cap = None
+    if routing != "collapsed":
+        cap = -(-(n // (E // (2 * held))) * k // bt) + held
+    G = min(-(-n * k // bt) + held, cap or n * k)
+    counts, tile_expert, row_pair, pair_row = _numpy_plan(
+        chosen, first, held, bt, G)
+    used = len(tile_expert)
+    assert used <= G and (counts.sum() == 0) == (routing == "absent")
+
+    got_counts = moe_lib._held_counts(
+        moe_lib._held_keys(jnp.asarray(chosen), first, held), held)
+    np.testing.assert_array_equal(got_counts, counts)
+    assert bool(moe_lib._fits(got_counts, bt, G))
+    tiles, got_pair_row, got_row_pair = jax.jit(
+        moe_lib._plan, static_argnums=(1, 2, 3, 4))(
+            jnp.asarray(chosen), first, held, bt, cap, None)
+    assert int(tiles[2][0]) == used and got_row_pair.shape == (G * bt,)
+    np.testing.assert_array_equal(tiles[0][:used], tile_expert)
+    np.testing.assert_array_equal(
+        tiles[1][:used], np.diff(tile_expert, prepend=-1) > 0)
+    np.testing.assert_array_equal(got_row_pair[:used * bt], row_pair)
+    np.testing.assert_array_equal(got_pair_row, pair_row)
+
+
 def test_the_gate_is_the_one_asked_for():
     """``gated_ffn_padded`` with ``act`` against the written-out product, and
     the hand-written backward against AD, for both gates on one tile

@@ -482,7 +482,7 @@ def test_pallas_names_are_one_per_kernel():
     by_schedule = [s for s in sites if len(s) > 1]
     assert len(by_schedule) == 3        # the online forward, dq and dkv
     names = [s[0] for s in sites if len(s) == 1] + by_schedule[0]
-    assert len(names) == 21 and len(set(names)) == 21
+    assert len(names) == 20 and len(set(names)) == 20
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
     assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
         "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd"}

@@ -90,73 +90,149 @@ def test_health_pack_matches_reference_norms(devices):
     assert m["grads_finite_all"] == 1.0
 
 
-def test_train_step_moe_telemetry_with_grad_accum(devices):
-    """MoE router scalars survive the grad-accum scan carry and land in the
-    metrics dict alongside the health pack."""
-    mesh = mesh_lib.single_device_mesh()
-    bundle = registry.create_model(
-        "llama_moe_tiny", seq_len=16, dtype=jnp.float32,
-        param_dtype=jnp.float32, moe_capacity_factor=1.0, moe_top_k=2,
-        moe_dispatch_impl="gather")
+EXPERT_SOWS = ("moe_held_rows", "moe_held_peak", "moe_whole",
+               "moe_source_parts")
+
+
+def _grad_accum_step(name, shapes_only=False):
+    """``(module, state, step)``: the train step of model ``name`` over two
+    microbatches with the health pack on, on one device; the state as shapes
+    alone (nothing initialised) where ``shapes_only``."""
+    bundle = registry.create_model(name, seq_len=16, dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
     tx, _ = optim.build_optimizer(Config(lr=0.01, warmup_epochs=0.0),
                                   steps_per_epoch=10)
-    rules = sharding_lib.strategy_rules("fsdp", bundle.rules)
-    state = train_loop.create_train_state(
-        bundle.module, tx, bundle.input_template, mesh, rules, seed=0)
-    step = jax.jit(train_loop.make_train_step(
-        train_loop.get_task("lm"), grad_accum=2, health=True),
-        donate_argnums=0)
+    make = lambda: train_loop.create_train_state(
+        bundle.module, tx, bundle.input_template,
+        mesh_lib.single_device_mesh(),
+        sharding_lib.strategy_rules("fsdp", bundle.rules), seed=0)
+    state = jax.eval_shape(make) if shapes_only else make()
+    return bundle.module, state, train_loop.make_train_step(
+        train_loop.get_task("lm"), grad_accum=2, health=True)
+
+
+@pytest.mark.parametrize("name", ["afmoe_tiny", "smallthinker_tiny",
+                                  "glm_moe_lite_tiny", "nemotron_h_tiny"])
+def test_train_step_carries_the_expert_sows_through_grad_accum(devices, name):
+    """The expert layers' four sows survive the grad-accum scan carry and land
+    in the metrics dict beside the health pack, one float32 scalar a sow and
+    an expert layer (traced on shapes: the carry's structure is what a model
+    can break)."""
+    module, state, step = _grad_accum_step(name, shapes_only=True)
+    tokens = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    with mesh_lib.use_mesh(mesh_lib.single_device_mesh()):
+        _, metrics = jax.eval_shape(
+            step, state, {"tokens": tokens, "targets": tokens})
+    for key in ("update_norm", "param_norm", "loss_finite",
+                "grads_finite_all"):
+        assert key in metrics, (key, sorted(metrics))
+    layers = {k.split(".", 1)[1] for k in metrics if k.startswith("moe_")}
+    assert layers, sorted(metrics)
+    for layer in layers:
+        for sow in EXPERT_SOWS:
+            leaf = metrics[f"{sow}.{layer}"]
+            assert (leaf.shape, leaf.dtype) == ((), jnp.float32), (sow, leaf)
+
+
+def test_grad_accum_means_the_expert_sows_over_the_microbatches(devices):
+    """Each of the four in the step's metrics is the mean over the two
+    microbatches of what the model sows for that microbatch alone (the
+    buffers of ``batch_stats`` chained from the first to the second, as the
+    step chains them)."""
+    module, state, step = _grad_accum_step("nemotron_h_tiny")
+    batch = _lm_batch(4, 16, vocab=module.vocab_size)
+
+    @jax.jit
+    def sown(stats, tokens):
+        _, new = state.apply_fn(
+            {"params": state.params, "batch_stats": stats}, tokens,
+            train=True, mutable=["telemetry", "losses", "batch_stats"])
+        return (telemetry_lib.collect_sowed(new["telemetry"]),
+                new["batch_stats"])
+
+    mesh = mesh_lib.single_device_mesh()
     with mesh_lib.use_mesh(mesh):
-        b = prefetch.shard_batch(_lm_batch(4, 16),
-                                 mesh_lib.batch_sharding(mesh))
-        state, metrics = step(state, b)
+        first, stats = sown(state.batch_stats, batch["tokens"][:2])
+        second, _ = sown(stats, batch["tokens"][2:])
+        _, metrics = jax.jit(step)(state, prefetch.shard_batch(
+            batch, mesh_lib.batch_sharding(mesh)))
     m = {k: float(v) for k, v in jax.device_get(metrics).items()}
-    for key in ("router_load_entropy", "moe_drop_fraction", "update_norm",
-                "param_norm", "loss_finite", "grads_finite_all"):
-        assert key in m and np.isfinite(m[key]), (key, m)
-    assert 0.0 <= m["router_load_entropy"] <= 1.0 + 1e-6
-    assert 0.0 <= m["moe_drop_fraction"] <= 1.0
+    keys = [k for k in first if k.split(".")[0] in EXPERT_SOWS]
+    assert len(keys) == 2 * len(EXPERT_SOWS), sorted(first)   # "ME*EM"
+    for key in keys:
+        want = (float(first[key]) + float(second[key])) / 2
+        assert np.isclose(m[key], want, rtol=1e-6), (key, m[key], want)
+    assert all(m[k] > 0 for k in keys if k.startswith("moe_held_rows")), m
 
 
-@pytest.mark.parametrize("impl", ["sort", "gather", "einsum"])
-def test_moe_router_scalars_match_numpy(devices, impl):
-    """router_load_entropy / moe_drop_fraction from the sow collection equal
-    a from-scratch numpy recomputation of the routing math — identically
-    across all three dispatch implementations."""
-    E, k, cf = 4, 2, 0.5  # cf=0.5 forces real capacity drops
-    B, S, d = 2, 8, 16
-    T = B * S
-    capacity = max(int(cf * T * k / E), 1)
-    moe = moe_lib.MoEBlock(num_experts=E, ffn_dim=32, top_k=k,
-                           capacity_factor=cf, dispatch_impl=impl,
-                           dtype=jnp.float32, param_dtype=jnp.float32)
-    rng = np.random.RandomState(0)
-    x = rng.randn(B, S, d).astype(np.float32)
-    variables = moe.init(jax.random.PRNGKey(0), jnp.asarray(x))
-    _, new_vars = moe.apply({"params": variables["params"]}, jnp.asarray(x),
-                            mutable=["losses", "telemetry"])
-    tele = {kk: float(v) for kk, v in
-            telemetry_lib.collect_sowed(new_vars["telemetry"]).items()}
+def _expert_layer_sows(router, routing, E=16, k=2, held=(2, 6), T=64, d=32):
+    """One expert layer under ``router`` with tokens and router weights
+    planted so that the routing is ``level`` (no expert favoured),
+    ``collapsed`` (every token on the held experts) or ``absent`` (no token on
+    them): the sown scalars, and the router's choices ``[T, k]`` recomputed in
+    numpy from the same float32 tokens and router weights."""
+    rng = np.random.RandomState(3)
+    x = rng.randn(1, T, d).astype(np.float32)
+    x[..., 0] = 1.0                         # the feature a planted row reads
+    kernel = (0.3 * rng.randn(d, E)).astype(np.float32)
+    n, first = held
+    if routing == "collapsed":
+        kernel[0, first:first + n] += 30.0
+    elif routing == "absent":
+        kernel[0, first:first + n] -= 30.0
+    scores = x[0].astype(np.float64) @ kernel.astype(np.float64)
+    if router == "sigmoid_bias":
+        layer = moe_lib.SharedExpertMoE(num_experts=E, ffn_dim=16, top_k=k,
+                                        held_experts=held)
+        variables = layer.init(jax.random.PRNGKey(0), jnp.asarray(x),
+                               train=False)
+        params = {**variables["params"], "router": jnp.asarray(kernel)}
+        _, new = layer.apply(
+            {"params": params, "batch_stats": variables["batch_stats"]},
+            jnp.asarray(x), train=False, mutable=["telemetry"])
+        scores = 1.0 / (1.0 + np.exp(-scores))      # the bias is zero
+    else:
+        top = moe_lib.TopKSoftmaxRouter(num_experts=E, top_k=k)
+        route = top.apply({"params": {"kernel": jnp.asarray(kernel)}},
+                          jnp.asarray(x))
+        layer = moe_lib.HeldExperts(ffn_dim=16, held_experts=held)
+        variables = layer.init(jax.random.PRNGKey(0), jnp.asarray(x), route)
+        _, new = layer.apply(variables, jnp.asarray(x), route,
+                             mutable=["telemetry"])
+    order = np.argsort(-scores, axis=-1, kind="stable")
+    ranked = np.take_along_axis(scores, order, axis=-1)
+    assert np.min(ranked[:, k - 1] - ranked[:, k]) > 1e-6    # no near tie
+    sows = {name: float(np.asarray(value[0]))
+            for name, value in new["telemetry"].items()}
+    return sows, order[:, :k]
 
-    # numpy reference: router softmax -> top-k -> load entropy; priority-
-    # order capacity cumsum -> drop fraction.
-    W = np.asarray(variables["params"]["router"]["kernel"], np.float32)
-    logits = x.reshape(T, d) @ W
-    z = logits - logits.max(-1, keepdims=True)
-    probs = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
-    expert_idx = np.argsort(-probs, axis=-1, kind="stable")[:, :k]  # [T, k]
-    onehot = np.eye(E, dtype=np.float32)[expert_idx]                # [T, k, E]
-    load = onehot.mean((0, 1))
-    ref_entropy = float(-np.sum(load * np.log(load + 1e-9)) / np.log(E))
-    flat = onehot.transpose(1, 0, 2).reshape(k * T, E)
-    pos_in_expert = np.cumsum(flat, axis=0) - flat
-    pos = (pos_in_expert.reshape(k, T, E).transpose(1, 0, 2) * onehot).sum(-1)
-    within_cap = pos < capacity
-    ref_drop = float(1.0 - within_cap.mean())
 
-    assert np.isclose(tele["router_load_entropy"], ref_entropy, atol=1e-5)
-    assert np.isclose(tele["moe_drop_fraction"], ref_drop, atol=1e-6)
-    assert ref_drop > 0.0  # the capacity factor actually bit
+@pytest.mark.parametrize("routing", ["level", "collapsed", "absent"])
+@pytest.mark.parametrize("router", ["sigmoid_bias", "softmax_chosen"])
+def test_expert_sows_match_a_numpy_count_of_the_choices(router, routing):
+    """``moe_held_rows`` / ``moe_held_peak`` / ``moe_whole`` from the sow
+    collection equal a from-scratch numpy count of the router's choices:
+    the pairs that fell on each held expert, the fullest over their mean, and
+    whether their tiles fit the bounded layout (a ``chunks``-th part of the
+    tokens, every choice held here), under both routers."""
+    from pytorch_distributed_training_example_tpu.ops import (
+        grouped_matmul as gmm_lib)
+
+    E, k, (n, first), T = 16, 2, (2, 6), 64
+    sows, chosen = _expert_layer_sows(router, routing, E, k, (n, first), T)
+    rows = np.array([(chosen == first + e).sum() for e in range(n)], float)
+    bt = min(moe_lib.EXPERT_TILE_ROWS, gmm_lib._block_rows(T * k, n))
+    chunks = E // (2 * n)
+    cap = -(-(T // chunks) * k // bt) + n
+    tiles = np.maximum(np.ceil(rows / bt), 1).sum()
+    want = {"moe_held_rows": rows.sum(),
+            "moe_held_peak": rows.max() / max(rows.mean(), 1.0),
+            "moe_whole": float(tiles <= cap)}
+    for name, value in want.items():
+        assert np.isclose(sows[name], value, rtol=1e-6), (name, sows, want)
+    assert want["moe_whole"] == (routing != "collapsed")
+    assert (rows.sum() == 0) == (routing == "absent")
+    assert routing != "collapsed" or rows.sum() == T * k
 
 
 # ---------------------------------------------------------------------------

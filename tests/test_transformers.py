@@ -85,29 +85,15 @@ def test_context_parallel_train_step(devices):
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)
 
 
-def test_composed_3d_mesh_train_step(devices):
-    """The composed mesh: dp x ep x seq on one MoE model, one train step
-    program — parity vs the single-device oracle (ROADMAP item 4)."""
-    # Dropless dispatch: capacity-dropped routing is discontinuous at the
-    # capacity boundary, so reduction-order noise across meshes can flip a
-    # drop and break parity — a property of capacity factors, not of the
-    # composed mesh.
-    mesh = mesh_lib.build_mesh({"data": 2, "expert": 2, "seq": 2})
-    ref_params, ref_m = _run("llama_moe_tiny", mesh_lib.single_device_mesh(),
-                             "dp", moe_dispatch_impl="dropless")
-    par_params, par_m = _run("llama_moe_tiny", mesh, "fsdp_tp",
-                             moe_dispatch_impl="dropless")
-    assert np.isclose(ref_m["loss"], par_m["loss"], rtol=1e-3), (ref_m, par_m)
-    for a, b in zip(jax.tree.leaves(ref_params), jax.tree.leaves(par_params)):
-        np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)
-
-
-def test_composed_seq_tp_train_step(devices):
-    """dp x seq x tp on the dense model: ring attention over 'context'
-    composed with Megatron column/row splits over 'model'."""
+@pytest.mark.parametrize("model_name", ["gpt2_tiny", "llama_tiny"])
+def test_composed_seq_tp_train_step(devices, model_name):
+    """The composed mesh, dp x seq x tp on a dense model, one train step
+    program: ring attention over 'context' composed with Megatron column/row
+    splits over 'model' (under GQA and RoPE for ``llama_tiny``) against the
+    single-device oracle."""
     mesh = mesh_lib.build_mesh({"data": 2, "seq": 2, "model": 2})
-    ref_params, ref_m = _run("gpt2_tiny", mesh_lib.single_device_mesh(), "dp")
-    par_params, par_m = _run("gpt2_tiny", mesh, "fsdp_tp")
+    ref_params, ref_m = _run(model_name, mesh_lib.single_device_mesh(), "dp")
+    par_params, par_m = _run(model_name, mesh, "fsdp_tp")
     assert np.isclose(ref_m["loss"], par_m["loss"], rtol=1e-3), (ref_m, par_m)
     for a, b in zip(jax.tree.leaves(ref_params), jax.tree.leaves(par_params)):
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)

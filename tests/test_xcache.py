@@ -82,6 +82,52 @@ def test_fingerprint_key_stable_and_knob_sensitive():
         mesh=mesh, config=cfg, example_args=(x,))) == key
 
 
+def _another(value):
+    """A value of ``value``'s type that is not ``value``."""
+    if isinstance(value, bool):
+        return not value
+    if isinstance(value, (int, float)):
+        return value + 1
+    return f"{value}_other"
+
+
+@pytest.mark.parametrize("knob", xcache.TRACED_KNOBS)
+def test_each_traced_knob_alone_moves_the_key(knob):
+    """Every entry of ``TRACED_KNOBS`` is a ``Config`` field, and changing
+    that field alone changes ``cache_key``: an entry that named no field
+    would key nothing, in silence (``fingerprint`` skips what the config
+    lacks)."""
+    from pytorch_distributed_training_example_tpu.utils.config import Config
+
+    base = Config()
+    changed = base.replace(**{knob: _another(getattr(base, knob))})
+    key = lambda cfg: xcache.cache_key(
+        xcache.fingerprint(mesh=_mesh(), config=cfg))
+    assert key(changed) != key(base)
+    assert key(base.replace()) == key(base)
+
+
+def test_every_field_model_options_reads_is_a_traced_knob():
+    """``Config.model_options()`` says which fields shape the model; each of
+    them reaches tracing, so each must be in ``TRACED_KNOBS`` (by hand, until
+    the tuple is derived: ROADMAP C5). And what it hands out is the
+    registry's whole set of options, no more."""
+    from pytorch_distributed_training_example_tpu.models import registry
+    from pytorch_distributed_training_example_tpu.utils.config import Config
+
+    cfg, read = Config(), set()
+
+    class Reads:
+        def __getattr__(self, name):
+            read.add(name)
+            return getattr(cfg, name)
+
+    options = Config.model_options(Reads())
+    assert read and read <= set(xcache.TRACED_KNOBS), (
+        read - set(xcache.TRACED_KNOBS))
+    assert set(options) == set(registry._OPTIONS)
+
+
 def test_save_load_roundtrip_executes_warm(tmp_path, caplog):
     x = jnp.arange(4, dtype=jnp.float32)
     compiled = jax.jit(lambda v: v * 2.0 + 1.0).lower(x).compile()
