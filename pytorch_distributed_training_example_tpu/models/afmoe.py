@@ -60,7 +60,10 @@ PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
 class GatedAttention(nn.Module):
     """Causal GQA with per-head q/k norms and a sigmoid output gate;
     ``window`` None is a ``full_attention`` layer (no positional term),
-    otherwise rotary positions and the window mask."""
+    otherwise rotary positions and the window mask. The ``lfm2_moe`` models
+    run the same q/k norms and rotary positions with neither a gate nor a
+    window: ``gated`` False drops ``W_g`` and the sigmoid, ``rotary`` says
+    where the positions go (None: where there is a window)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -70,6 +73,8 @@ class GatedAttention(nn.Module):
     dtype: Any
     param_dtype: Any
     attn_impl: str = "auto"
+    gated: bool = True
+    rotary: bool | None = None
 
     @nn.compact
     def __call__(self, h):
@@ -81,8 +86,11 @@ class GatedAttention(nn.Module):
         q = norm("q_norm")(heads(self.num_heads, "query"))
         k = norm("k_norm")(heads(self.num_kv_heads, "key"))
         v = heads(self.num_kv_heads, "value")
-        gate = heads(self.num_heads, "gate")
-        if self.window is not None:
+        gate = heads(self.num_heads, "gate") if self.gated else None
+        rotary = self.rotary
+        if rotary is None:
+            rotary = self.window is not None
+        if rotary:
             positions = jnp.arange(h.shape[1])[None, :]
             q = llama.rope(q, positions, self.rope_theta)
             k = llama.rope(k, positions, self.rope_theta)
@@ -91,8 +99,9 @@ class GatedAttention(nn.Module):
         v = mesh_lib.constrain(v, llama._seq_rule("qkv"))
         out = attn_lib.attention(q, k, v, causal=True, impl=self.attn_impl,
                                  window=self.window)
-        out = (out.astype(jnp.float32)
-               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
+        if self.gated:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(self.dtype)
         return nn.DenseGeneral(h.shape[-1], axis=(-2, -1), use_bias=False,
                                dtype=self.dtype, param_dtype=self.param_dtype,
                                name="out")(out)

@@ -28,7 +28,12 @@ the ``nemotron_h`` family (every layer one mixer alone by a published pattern
 string: a Mamba-2 mixer with grouped B/C, an ungated squared-ReLU expert layer
 beside a shared expert, or position-free GQA: ``nemotron3_nano`` at its
 published sizes, ``nemotron3_nano_share`` one chip's share of it,
-``nemotron_h_tiny`` for tests; training only, ``dp``/``fsdp`` only).
+``nemotron_h_tiny`` for tests; training only, ``dp``/``fsdp`` only) and the
+``lfm2_moe`` family (a doubly gated three-tap convolution in three layers of
+four beside q/k-normed rotary GQA, over a sigmoid-and-bias routed mixture
+with no shared expert and a tied embedding: ``lfm2_8b_a1b`` at its published
+sizes, ``lfm2_8b_a1b_share`` one chip's share of it, ``lfm2_moe_tiny`` for
+tests; training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -255,7 +260,7 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
 def _held_experts_family(name, make):
     """Registry builder for a family whose expert layers are told which
     experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``,
-    ``glm_moe_lite``, ``nemotron_h``):
+    ``glm_moe_lite``, ``nemotron_h``, ``lfm2_moe``):
     ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
     Granite hybrid: the expert layer has no exchange, and there is no
     tensor-parallel rule table."""
@@ -324,6 +329,18 @@ _REGISTRY["nemotron3_nano_share"] = _held_experts_family(
     "nemotron_h", lambda m, **kw: m.chip_share(m.nemotron3_nano(**kw)))
 _REGISTRY["nemotron_h_tiny"] = _held_experts_family(
     "nemotron_h", lambda m, **kw: m.nemotron_h_tiny(**kw))
+
+
+# The published LFM2-8B-A1B; one chip's share of it (a quarter of every
+# expert layer's routed experts and of the tied vocabulary, the published
+# layers 1..7: what the one-chip benchmark cell trains); and a toy for the
+# tests.
+_REGISTRY["lfm2_8b_a1b"] = _held_experts_family(
+    "lfm2_moe", lambda m, **kw: m.lfm2_8b_a1b(**kw))
+_REGISTRY["lfm2_8b_a1b_share"] = _held_experts_family(
+    "lfm2_moe", lambda m, **kw: m.chip_share(m.lfm2_8b_a1b(**kw)))
+_REGISTRY["lfm2_moe_tiny"] = _held_experts_family(
+    "lfm2_moe", lambda m, **kw: m.lfm2_moe_tiny(**kw))
 
 
 @register("resnet_micro")

@@ -38,6 +38,10 @@ kernel pair under a ``custom_vjp`` each (``conv_silu_fwd`` / ``conv_silu_bwd``,
 and writes its results once, or the ``jax.numpy`` bodies
 (:func:`causal_conv1d`, :func:`group_rms_norm`).
 
+:func:`gated_conv` is another family's mixer on the same conv: the doubly
+gated short convolution of the ``lfm2_moe`` models, ``C * conv(B * x)`` with
+no activation, as ``jax.numpy`` alone.
+
 No packed documents (no state or mask resets) and no recurrent-state cache
 for serving: one document a sequence.
 """
@@ -75,6 +79,20 @@ def causal_conv1d(x: jax.Array, kernel: jax.Array,
     if bias is not None:
         y = y + bias.astype(F32)
     return y.astype(x.dtype)
+
+
+def gated_conv(bcx: jax.Array, kernel: jax.Array) -> jax.Array:
+    """The doubly gated short convolution of the ``lfm2_moe`` models: ``y = C
+    * conv(B * x)``, the conv :func:`causal_conv1d`'s (depthwise, causal, zero
+    history, no bias), with no activation anywhere.
+
+    ``bcx`` [b, S, 3 C]: the chunks ``B``, ``C``, ``x`` in that order, as the
+    one input projection leaves them; ``kernel`` [K, C]. Float32 inside,
+    ``bcx.dtype`` out [b, S, C]. Elementwise ``jax.numpy`` that XLA fuses and
+    differentiates: no kernel of its own yet.
+    """
+    B, C, x = jnp.split(bcx.astype(F32), 3, axis=-1)
+    return (C * causal_conv1d(B * x, kernel)).astype(bcx.dtype)
 
 
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,

@@ -2,7 +2,7 @@
 
 Two halves that a block calls where its model says: *a router* (scores to the
 chosen experts, their weights and every expert's count: ``route_sigmoid_bias``
-for the ``afmoe``, ``glm_moe_lite`` and ``nemotron_h`` families,
+for the ``afmoe``, ``glm_moe_lite``, ``nemotron_h`` and ``lfm2_moe`` families,
 :class:`TopKSoftmaxRouter` for ``smallthinker``) and *the held experts'
 routine* (``_held_sum`` over ``(tokens, chosen, weights, counts)``:
 ``_routed`` / ``_routed_bounded`` through ``ops/grouped_matmul.py``'s FFN,
@@ -372,16 +372,18 @@ def _load(chosen, num_experts):
     return mesh_lib.constrain(load, P(None))
 
 
-def route_sigmoid_bias(tokens, kernel, bias, k, route_scale) -> Route:
+def route_sigmoid_bias(tokens, kernel, bias, k, route_scale,
+                       norm_eps=1e-20) -> Route:
     """``s = sigmoid(tokens W_r)``; the ``k`` largest of ``s + bias`` are
-    chosen; weights ``route_scale * s_i / (sum of the chosen s + 1e-20)``:
+    chosen; weights ``route_scale * s_i / (sum of the chosen s + norm_eps)``:
     the bias chooses and nothing more (torchtitan's router as the ``afmoe``
-    models configure it)."""
+    models configure it; ``norm_eps`` is a family's constant, 1e-20 there
+    and 1e-6 in the ``lfm2_moe`` models' released router)."""
     scores = jax.nn.sigmoid(_scores(tokens, kernel))
     _, chosen = jax.lax.top_k(scores + bias, k)                     # [T, k]
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = route_scale * picked / (
-        jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+        jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
     return Route(chosen, weights, _load(chosen, kernel.shape[1]))
 
 
@@ -482,8 +484,8 @@ class SharedExpertMoE(nn.Module):
     ``s = sigmoid(x W_r)`` over all ``num_experts``, in float32; the ``top_k``
     largest of ``s + b`` are chosen (``b``, ``expert_bias``, is a buffer in
     the ``batch_stats`` collection: no gradient, no optimizer state); the
-    weights are ``route_scale * s_i / (sum of the chosen s + 1e-20)``: the
-    bias chooses and nothing more (``route_sigmoid_bias``). ``y = Shared(x)
+    weights are ``route_scale * s_i / (sum of the chosen s + route_norm_eps)``:
+    the bias chooses and nothing more (``route_sigmoid_bias``). ``y = Shared(x)
     + sum_i w_i Expert_i(x)`` with SwiGLU experts, or where ``gated`` is
     false with two-matrix squared-ReLU ones, ``relu(x W_up)^2 W_down``, the
     shared expert likewise (the ``nemotron_h`` models). After a training step ``b
@@ -521,6 +523,7 @@ class SharedExpertMoE(nn.Module):
     shared_ffn_dim: int = 0
     route_scale: float = 1.0
     balance_coeff: float = 0.0
+    route_norm_eps: float = 1e-20       # the family's constant (its model file)
     gated: bool = True                  # SwiGLU experts; False: squared ReLU
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -540,7 +543,7 @@ class SharedExpertMoE(nn.Module):
             kernel = self.param("router", nn.initializers.lecun_normal(),
                                 (d, E), jnp.float32)
             route = route_sigmoid_bias(tokens, kernel, bias.value, self.top_k,
-                                       self.route_scale)
+                                       self.route_scale, self.route_norm_eps)
             if train and not self.is_initializing() \
                     and self.is_mutable_collection("batch_stats"):
                 mean = jnp.mean(route.load.astype(jnp.float32))

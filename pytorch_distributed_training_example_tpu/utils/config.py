@@ -304,6 +304,23 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # LFM2-8B-A1B (models/lfm2_moe.py) whole, and one chip's share of it (a
+    # quarter of each expert layer's 32 experts and of the tied vocabulary,
+    # the published layers 1..7): a doubly gated three-tap convolution in five
+    # layers of seven beside q/k-normed rotary GQA at head 64, one 8k sequence
+    # a chip per micro-step
+    "lfm2_8b_a1b": dict(
+        model="lfm2_8b_a1b", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=4, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "lfm2_8b_a1b_share": dict(
+        model="lfm2_8b_a1b_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

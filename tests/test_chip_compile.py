@@ -1000,3 +1000,56 @@ def test_nemotron_share_step_fits_the_chip(one_chip, as_tpu):
         assert calls[name] == 1, calls
     # four layers' two matrices, on both sides of the bounded layout's cond
     assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 4 * 2 * 2
+
+
+# -- LFM2-8B-A1B's share (models/lfm2_moe.py): causal GQA 32/8 at a head of 64
+# -- over 8,192 rotated positions, the gated conv as XLA's fusions, the step
+
+
+def test_flash_compiles_at_lfm2_widths(one_chip):
+    """B1 S8192 H32/8 D64, causal, no window: no causal, one-shot or
+    streaming plan reaches S=8192 at this width, so the three online kernels
+    at 1024 x 1024 blocks both ways, under the causal schedule (a walked
+    table, sub-tiles on the crossed blocks)."""
+    q = _sds((1, 8192, 32, 64), one_chip)
+    kv = _sds((1, 8192, 8, 64), one_chip)
+    text = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True)), q, kv, kv)
+    for name in fa.ONLINE_KERNELS:
+        assert name in text, name
+    for other in ("flash_fwd_window", "flash_fwd_causal", "flash_bwd_causal",
+                  "flash_bwd_oneshot"):
+        assert other not in text, other
+    assert [fa._online_blocks(bwd, 8192, 64, 1024, 1024, 2)
+            for bwd in (False, True)] == [(1024, 1024), (1024, 1024)]
+    plans = [fa.online_schedule(name, True, 8192, 8192, 1024, 1024)
+             for name in fa.ONLINE_KERNELS]
+    assert all(p.walk and p.split for p in plans)
+
+
+@pytest.mark.slow  # the TPU compiler on every core for minutes, as Trinity's
+def test_lfm2_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``lfm2_8b_a1b_share`` at 1 x 8192, bf16,
+    per-block remat, AdamW) compiles for a described v5e under the chip's
+    memory: seven layers (five gated convs, two attentions, six expert FFNs);
+    the online flash kernels twice each, the six expert layers' grouped
+    matmuls, and no Pallas call under ``conv_gate``: the gated conv is XLA's
+    own fusions in this PR."""
+    import re
+    from collections import Counter
+
+    compiled, mem, held = _share_step("lfm2_8b_a1b_share", one_chip)
+    assert mem.argument_size_in_bytes == pytest.approx(711_389_440 * 12,
+                                                       rel=1e-3)
+    assert held < 16.0e9, held
+    text = compiled.as_text()
+    calls = Counter(m.group(1) for m in re.finditer(
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
+    for name in fa.ONLINE_KERNELS:
+        assert calls[name] == 2, calls
+    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 6 * 3 * 2
+    assert not [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "conv_gate" in line]
+    for scope in ("short_conv", "conv_gate", "in_proj", "out_proj",
+                  "moe_router", "moe_experts"):
+        assert f"/{scope}/" in text, scope
