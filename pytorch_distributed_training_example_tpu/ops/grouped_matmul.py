@@ -34,14 +34,29 @@ Backward is a ``custom_vjp``:
   accumulates into it before the block index moves on — segment-wise
   accumulation with no atomics and no ``[E, Tk]`` masks.
 
-On the ``cpu`` platform both kernels run in interpret mode (numerically the
+The gated expert FFN (``gated_ffn_padded``: SwiGLU and ReGLU experts) has six
+kernels of its own over the same layout, grid and prefetched tables, so that
+a row tile meets each expert matrix once and the gate's elementwise work runs
+on the tiles in use only: ``gated_ffn_up`` (gate and up in one pass
+over the row tile, both weight blocks side by side in VMEM),
+``gated_ffn_down`` (the down projection from ``gate`` and ``up``, ``h`` made in
+VMEM), and in the backward ``gated_ffn_dh`` (``dy w_down^T`` leaves as ``dgate``
+and ``dup``), ``gated_ffn_dx`` (both halves of ``dx`` in one float32 sum),
+``gated_ffn_dw_up`` (``dw_gate`` and ``dw_up`` from one read of the ``x`` tile)
+and ``gated_ffn_dw_down``; the weight gradients leave their kernels in the
+weights' dtype. The ungated form (``ungated_ffn_padded_kept``) is two
+``grouped_matmul`` calls with XLA's activation between them. Every traced
+call of a kernel says what it was given as a ``gmm_plan`` record
+(``_say_plan``).
+
+On the ``cpu`` platform the kernels run in interpret mode (numerically the
 same program), so CPU tests and dryruns validate the real kernel bodies
 (``ops/backend.py``). fp32 accumulation everywhere (``preferred_element_type``); outputs are
 cast to the input dtype, gradients to the primal dtypes. Tile sizes are
 powers of two down to 8 rows — Mosaic-friendly at the cells' shapes (on the
-chip the kernels are read by name under the ``per_layer`` metric
-``moe_experts_ms``); lane-dim (128) padding of small test shapes is
-interpret-mode territory, not correctness.
+chip the kernels are read by their scope, ``moe_experts``, under the
+``per_layer`` metrics of the expert cells); lane-dim (128) padding of small
+test shapes is interpret-mode territory, not correctness.
 """
 
 from __future__ import annotations
@@ -77,34 +92,46 @@ def _block_rows(n_rows: int, num_experts: int) -> int:
 
 
 #: Largest weight (or weight-gradient) block a kernel keeps in VMEM; Pallas
-#: double-buffers it, and the row tiles ride beside it under the 16 MB scope.
+#: double-buffers it, and the row tiles ride beside it. A kernel that keeps
+#: two side by side (the gated FFN's gate and up) gives each this much and
+#: asks for the scoped VMEM that takes (``_vmem``).
 _BLOCK_BYTES = 4 * 1024 * 1024
+
+#: The scoped VMEM a kernel gets without asking (a v5e's default), and what a
+#: kernel's float32 tiles between its blocks are given beside the blocks.
+_SCOPED_VMEM = 16 * 1024 * 1024
+_TILE_TEMPS = 6
 
 
 def _block_cols(n: int, depth: int = 1, itemsize: int = 4) -> int:
-    """Largest nice power-of-two column block whose ``[depth, block]`` tile
-    of ``itemsize`` bytes stays within ``_BLOCK_BYTES`` (a wider block reads
-    each row tile fewer times); odd widths get one block.
+    """The column block of a width ``n`` beside ``depth`` rows of ``itemsize``
+    bytes: the fewest blocks of whole 128-lane tiles whose ``[depth, block]``
+    tile stays within ``_BLOCK_BYTES`` (a wider block reads each row tile
+    fewer times), and the narrowest of those. A width of whole lane tiles
+    that fits is one block; a width within one lane tile is one block.
 
-    A width over one lane tile that is no multiple of 256 (1856 = 14.5 tiles,
-    2688 = 21) has no such divisor that Mosaic takes (a block's last dimension
-    is whole 128-lane tiles or the whole array's): it gets blocks of whole
-    lane tiles, the fewest that fit the bytes and then the narrowest of those,
-    and where the width is no whole number of tiles the last block is
-    part-filled (the callers' grids are ``pl.cdiv``: Pallas reads what lies
-    past the array as padding and drops what is written there, and no
-    contraction here runs over a blocked dimension)."""
-    if n > 128 and n % 256:
-        tiles = -(-n // 128)
-        fit = [k for k in range(1, n // 128 + 1)
-               if depth * 128 * k * itemsize <= _BLOCK_BYTES] or [1]
-        return 128 * min(fit, key=lambda k: (-(-tiles // k), k))
-    blocks = [bc for bc in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
-              if n % bc == 0]
-    for bc in blocks:
-        if depth * bc * itemsize <= _BLOCK_BYTES:
-            return bc
-    return blocks[-1] if blocks else n
+    Where the width is no whole number of lane tiles (1856 = 14.5 tiles) a
+    block is never the whole width, and the last block is part-filled (the
+    callers' grids are ``pl.cdiv``: Pallas reads what lies past the array as
+    padding and drops what is written there, and no contraction here runs
+    over a blocked dimension)."""
+    if n <= 128:
+        return n
+    tiles = -(-n // 128)
+    most = max(1, min(n // 128, _BLOCK_BYTES // (depth * 128 * itemsize)))
+    blocks = -(-tiles // most)
+    return 128 * -(-tiles // blocks)
+
+
+def _say_plan(kernel, form, rows, bt, d, f, n, block, vmem):
+    """One ``gmm_plan`` record a traced call of a kernel, under the span that
+    caused the trace (a name of ``telemetry.COMPILE_RECORDS``): the blocks
+    are static, so what was chosen is a record. ``n`` is the width that is
+    cut in ``block``s, ``vmem`` the bytes of the blocks the kernel holds."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+    telemetry.recorder().compile_event("gmm_plan", 0.0, {
+        "kernel": kernel, "form": form, "rows": rows, "d": d, "f": f,
+        "bt": bt, "block": block, "blocks": -(-n // block), "vmem": vmem})
 
 
 def num_tiles(group_counts, bt: int):
@@ -192,6 +219,10 @@ def _gmm_call(x_pad, w, tiles, bt: int, out_dtype, transposed: bool = False):
     Tp, d = x_pad.shape
     f = w.shape[1] if transposed else w.shape[2]
     bf = _block_cols(f, d, w.dtype.itemsize)
+    _say_plan("grouped_matmul", "plain", Tp, bt, d, f, f, bf, _vmem([
+        ("rows", x_pad.shape, x_pad.dtype), ("cols", (Tp, f), out_dtype),
+        ("w_rows" if transposed else "w_cols", w.shape, w.dtype)], (), bt,
+        bf)[0])
     if transposed:
         w_spec = pl.BlockSpec((1, bf, d), lambda jc, g, te, tf, nt: (
             te[_last_tile(g, nt)], jc, 0))
@@ -245,6 +276,9 @@ def _gmm_dw_call(x_pad, g_pad, tiles, num_experts: int, bt: int):
     Tp, d = x_pad.shape
     f = g_pad.shape[1]
     bf = _block_cols(f, d, 4)
+    _say_plan("grouped_matmul_dw", "plain", Tp, bt, d, f, f, bf, _vmem([
+        ("rows", x_pad.shape, x_pad.dtype), ("cols", g_pad.shape, g_pad.dtype),
+        ("w_cols", (num_experts, d, f), jnp.float32)], (), bt, bf)[0])
     return pl.pallas_call(
         _gmm_dw_kernel,
         name="grouped_matmul_dw",
@@ -345,20 +379,261 @@ def _gated(gate, up, act="silu"):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
+# The gated FFN's own kernels. A row tile meets each expert matrix once: the
+# gate's elementwise work rides in the kernels on either side of it, on the
+# tiles in use only, and no ``[P, f]`` or ``[P, d]`` array is written that
+# only the next kernel reads. Every kernel has the plain kernels' grid,
+# ``(blocks of the cut width, row tiles)`` with the tiles inside, and the
+# four kinds of block below.
+
+
+def _dot(a, b, contract):
+    """``a . b`` over ``a``'s and ``b``'s dimensions ``contract``, float32."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _live(nt_ref):
+    """Is this grid step's tile one in use (``_gmm_kernel``'s rule)?"""
+    return pl.program_id(1) < nt_ref[0]
+
+
+def _up_kernel(te_ref, tf_ref, nt_ref, x_ref, wg_ref, wu_ref, gate_ref,
+               up_ref):
+    """``gate = x w_gate`` and ``up = x w_up`` of a row tile and a column
+    block of both matrices, each rounded to the compute dtype as a
+    ``grouped_matmul`` call rounds it."""
+    @pl.when(_live(nt_ref))
+    def _tile():
+        x = x_ref[...]
+        gate_ref[...] = _dot(x, wg_ref[0], (1, 0)).astype(gate_ref.dtype)
+        up_ref[...] = _dot(x, wu_ref[0], (1, 0)).astype(up_ref.dtype)
+
+
+def _down_kernel(te_ref, tf_ref, nt_ref, gate_ref, up_ref, w_ref, y_ref, *,
+                 act):
+    """``y = (act(gate) * up) w_down``: ``h`` is made in VMEM."""
+    @pl.when(_live(nt_ref))
+    def _tile():
+        h = _gated(gate_ref[...], up_ref[...], act)
+        y_ref[...] = _dot(h, w_ref[0], (1, 0)).astype(y_ref.dtype)
+
+
+def _dh_kernel(te_ref, tf_ref, nt_ref, dy_ref, w_ref, gate_ref, up_ref,
+               dgate_ref, dup_ref, *, act):
+    """``dh = dy w_down^T`` (the weight read as it lies) leaves as ``dgate``
+    and ``dup``: the gate's transpose as AD makes it for ``act``, on the
+    float32 tile."""
+    @pl.when(_live(nt_ref))
+    def _tile():
+        dh = _dot(dy_ref[...], w_ref[0], (1, 1))
+        _, transpose = jax.vjp(
+            lambda gate, up: GATES[act](gate) * up,
+            gate_ref[...].astype(jnp.float32),
+            up_ref[...].astype(jnp.float32))
+        dgate, dup = transpose(dh)
+        dgate_ref[...] = dgate.astype(dgate_ref.dtype)
+        dup_ref[...] = dup.astype(dup_ref.dtype)
+
+
+def _dx_kernel(te_ref, tf_ref, nt_ref, dgate_ref, dup_ref, wg_ref, wu_ref,
+               dx_ref):
+    """``dx = dgate w_gate^T + dup w_up^T``: one float32 sum, rounded once."""
+    @pl.when(_live(nt_ref))
+    def _tile():
+        dx_ref[...] = (_dot(dgate_ref[...], wg_ref[0], (1, 1))
+                       + _dot(dup_ref[...], wu_ref[0], (1, 1))
+                       ).astype(dx_ref.dtype)
+
+
+def _accumulate(tf_ref, nt_ref, products, acc_refs, dw_refs):
+    """``acc += lhs^T rhs`` for each ``(lhs, rhs)`` that ``products()`` reads
+    of a row tile, segment-wise as ``_gmm_dw_kernel``: an accumulator is
+    zeroed on an expert's first tile, and on its last the weight gradient's
+    block leaves in the weights' dtype (the float32 sum rounded once, in
+    VMEM)."""
+    g = pl.program_id(1)
+    live = _live(nt_ref)
+
+    @pl.when(live & (tf_ref[g] == 1))
+    def _init():
+        for acc_ref in acc_refs:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _tile():
+        for (lhs, rhs), acc_ref in zip(products(), acc_refs):
+            acc_ref[...] += _dot(lhs, rhs, (0, 0))
+
+    following = jnp.minimum(g + 1, pl.num_programs(1) - 1)
+
+    @pl.when(live & ((g + 1 == nt_ref[0]) | (tf_ref[following] == 1)))
+    def _leave():
+        for acc_ref, dw_ref in zip(acc_refs, dw_refs):
+            dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
+
+
+def _dw_up_kernel(te_ref, tf_ref, nt_ref, x_ref, dgate_ref, dup_ref,
+                  dwg_ref, dwu_ref, accg_ref, accu_ref):
+    """``dw_gate`` and ``dw_up`` from one read of the ``x`` tile."""
+    def products():
+        x = x_ref[...]
+        return (x, dgate_ref[...]), (x, dup_ref[...])
+
+    _accumulate(tf_ref, nt_ref, products, (accg_ref, accu_ref),
+                (dwg_ref, dwu_ref))
+
+
+def _dw_down_kernel(te_ref, tf_ref, nt_ref, gate_ref, up_ref, dy_ref,
+                    dw_ref, acc_ref, *, act):
+    """``dw_down = h^T dy`` with ``h`` made in VMEM, a block of its columns
+    (``dw_down``'s rows) a grid step: each ``h`` is made once."""
+    _accumulate(
+        tf_ref, nt_ref,
+        lambda: [(_gated(gate_ref[...], up_ref[...], act), dy_ref[...])],
+        (acc_ref,), (dw_ref,))
+
+
+#: The gated FFN's kernels by the names their calls carry.
+GATED_KERNELS = {
+    "gated_ffn_up": _up_kernel, "gated_ffn_down": _down_kernel,
+    "gated_ffn_dh": _dh_kernel, "gated_ffn_dx": _dx_kernel,
+    "gated_ffn_dw_up": _dw_up_kernel, "gated_ffn_dw_down": _dw_down_kernel}
+
+
+def _block(kind, shape, bt, bc):
+    """The block of an operand of ``shape`` and where a grid step ``(jc, g)``
+    finds it: ``rows`` a row tile whole, ``cols`` a row tile's column block
+    ``jc``, ``w_cols`` the tile's expert's ``[depth, bc]`` columns ``jc``,
+    ``w_rows`` its ``[bc, depth]`` rows ``jc`` (a weight read transposed, as
+    it lies)."""
+    if kind == "rows":
+        return (bt, shape[1]), lambda jc, g, te, tf, nt: (_last_tile(g, nt), 0)
+    if kind == "cols":
+        return (bt, bc), lambda jc, g, te, tf, nt: (_last_tile(g, nt), jc)
+    if kind == "w_cols":
+        return (1, shape[1], bc), lambda jc, g, te, tf, nt: (
+            te[_last_tile(g, nt)], 0, jc)
+    return (1, bc, shape[2]), lambda jc, g, te, tf, nt: (
+        te[_last_tile(g, nt)], jc, 0)
+
+
+def _vmem(blocks, scratch, bt, bc):
+    """``(bytes the kernel's blocks take, the scoped VMEM to ask for)``:
+    ``blocks`` are ``(kind, shape, dtype)`` of the operands and results
+    (double-buffered), ``scratch`` the accumulators' ``(shape, dtype)``; the
+    ask is None where the default scope holds them with ``_TILE_TEMPS``
+    float32 tiles beside them."""
+    size = lambda shape, dtype: int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+    held = sum(2 * size(_block(kind, shape, bt, bc)[0], dtype)
+               for kind, shape, dtype in blocks)
+    held += sum(size(shape, dtype) for shape, dtype in scratch)
+    ask = held + _TILE_TEMPS * bt * max(bc, 128) * 4
+    return held, None if ask <= _SCOPED_VMEM else ask
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "name", "act", "kinds", "outs", "n", "bc", "scratch", "limit",
+    "interpret"))
+def _run(tiles, *operands, name, act, kinds, outs, n, bc, scratch, limit,
+         interpret):
+    """The ``pallas_call`` of the gated kernel ``name`` (``act``: the gate's
+    function, for the kernels that apply it). Under ``jit`` so that a model's
+    layers, and the recomputation in its backward, share one trace."""
+    bt = operands[0].shape[0] // tiles[0].shape[0]
+    spec = lambda kind, shape: pl.BlockSpec(*_block(kind, shape, bt, bc))
+    kernel = GATED_KERNELS[name]
+    return pl.pallas_call(
+        kernel if act is None else functools.partial(kernel, act=act),
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            # as the plain kernels': the row tiles inside, in segment order
+            grid=(pl.cdiv(n, bc), operands[0].shape[0] // bt),
+            in_specs=[spec(kind, a.shape) for kind, a in zip(kinds, operands)],
+            out_specs=tuple(spec(kind, shape) for kind, shape, _ in outs),
+            scratch_shapes=[pltpu.VMEM(shape, dtype)
+                            for shape, dtype in scratch]),
+        out_shape=tuple(jax.ShapeDtypeStruct(shape, dtype)
+                        for _, shape, dtype in outs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=limit),
+        interpret=interpret,
+    )(*tiles, *operands)
+
+
+def _gated_call(name, tiles, operands, kinds, outs, *, d, f, cut, depth,
+                itemsize, act=None, accumulators=()):
+    """The kernel ``name`` of the gated FFN over the padded layout:
+    ``operands`` of ``kinds`` (``_block``'s) to ``outs`` (``(kind, shape,
+    dtype)``), the width ``cut`` in column blocks beside ``depth`` rows of
+    ``itemsize`` bytes; ``accumulators`` are the float32 ``[rows, cols]``
+    scratch of a weight gradient, ``None`` for the cut width. Says its
+    ``gmm_plan``."""
+    rows = operands[0].shape[0]
+    bt = rows // tiles[0].shape[0]
+    bc = _block_cols(cut, depth, itemsize)
+    scratch = tuple((tuple(bc if s is None else s for s in shape),
+                     jnp.dtype(jnp.float32)) for shape in accumulators)
+    outs = tuple((kind, shape, jnp.dtype(dtype)) for kind, shape, dtype in outs)
+    held, limit = _vmem(
+        [(kind, a.shape, a.dtype) for kind, a in zip(kinds, operands)]
+        + list(outs), scratch, bt, bc)
+    _say_plan(name, "gated", rows, bt, d, f, cut, bc, held)
+    return _run(tiles, *operands, name=name, act=act,
+                kinds=tuple(kinds), outs=outs, n=cut, bc=bc, scratch=scratch,
+                limit=limit, interpret=not backend.on_tpu())
+
+
+def _gated_up(x_pad, w_gate, w_up, tiles):
+    """``(gate, up)`` of the padded rows: one kernel, each row tile read once
+    a column block of the two matrices."""
+    (rows, d), f = x_pad.shape, w_gate.shape[2]
+    return _gated_call(
+        "gated_ffn_up", tiles, (x_pad, w_gate, w_up),
+        ("rows", "w_cols", "w_cols"),
+        (("cols", (rows, f), x_pad.dtype),) * 2, d=d, f=f, cut=f, depth=d,
+        itemsize=w_gate.dtype.itemsize)
+
+
 def gated_down_padded(gate, up, w_down, tiles, act="silu"):
     """The gated product of the two projections through the down
     projection: ``gated_ffn_padded``'s result from its own ``gate`` and
-    ``up``."""
-    return _gmm_padded(_gated(gate, up, act), w_down, tiles)
+    ``up``, in one kernel."""
+    (rows, f), d = gate.shape, w_down.shape[2]
+    return _gated_call(
+        "gated_ffn_down", tiles, (gate, up, w_down),
+        ("rows", "rows", "w_cols"), (("cols", (rows, d), gate.dtype),), d=d,
+        f=f, cut=d, depth=f, itemsize=w_down.dtype.itemsize, act=act)[0]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down, tiles, act="silu"):
     """``gated_ffn_padded`` with the two projections it gated beside it:
     ``(y_pad, gate, up)``, all in the compute dtype. With ``x_pad`` they are
-    all that ``gated_ffn_padded_bwd`` reads of the forward."""
-    gate = _gmm_padded(x_pad, w_gate, tiles)
-    up = _gmm_padded(x_pad, w_up, tiles)
+    all that ``gated_ffn_padded_bwd`` reads of the forward; no ``h`` is
+    written, and a caller that reads no ``y_pad`` runs no down projection.
+    Its derivative is ``gated_ffn_padded_bwd`` on its own ``gate`` and ``up``,
+    under plain AD as in the expert layer's own rule."""
+    gate, up = _gated_up(x_pad, w_gate, w_up, tiles)
     return gated_down_padded(gate, up, w_down, tiles, act), gate, up
+
+
+def _gated_ffn_fwd(x_pad, w_gate, w_up, w_down, tiles, act):
+    y_pad, gate, up = gated_ffn_padded_kept(x_pad, w_gate, w_up, w_down,
+                                            tiles, act)
+    return (y_pad, gate, up), (x_pad, gate, up, w_gate, w_up, w_down, tiles)
+
+
+def _gated_ffn_bwd(act, res, cotangents):
+    *res, tiles = res
+    dy_pad, d_gate, d_up = cotangents
+    return (*gated_ffn_padded_bwd(*res, tiles, dy_pad, act, d_gate, d_up),
+            _float0(tiles))
+
+
+gated_ffn_padded_kept.defvjp(_gated_ffn_fwd, _gated_ffn_bwd)
 
 
 def gated_ffn_padded(x_pad, w_gate, w_up, w_down, tiles, act="silu"):
@@ -373,17 +648,40 @@ def gated_ffn_padded(x_pad, w_gate, w_up, w_down, tiles, act="silu"):
 
 
 def gated_ffn_padded_bwd(x_pad, gate, up, w_gate, w_up, w_down, tiles, dy_pad,
-                         act="silu"):
+                         act="silu", d_gate=None, d_up=None):
     """What differentiating ``gated_ffn_padded`` gives for ``dy_pad``, from
     the forward's own ``gate`` and ``up``: ``(dx_pad, dw_gate, dw_up,
-    dw_down)``, each product as ``_gmm_padded``'s rule makes it and the
-    gate's derivative as AD makes it for ``act`` (ReLU's is 0 at 0)."""
-    h_pad, gated_vjp = jax.vjp(functools.partial(_gated, act=act), gate, up)
-    dh_pad, dw_down, _ = _gmm_padded_bwd((h_pad, w_down, tiles), dy_pad)
-    dgate, dup = gated_vjp(dh_pad)
-    dx_gate, dw_gate, _ = _gmm_padded_bwd((x_pad, w_gate, tiles), dgate)
-    dx_up, dw_up, _ = _gmm_padded_bwd((x_pad, w_up, tiles), dup)
-    return dx_gate + dx_up, dw_gate, dw_up, dw_down
+    dw_down)`` in four kernels. The gate's derivative is as AD makes it for
+    ``act`` (ReLU's is 0 at 0), applied to ``dh`` before it is rounded; the
+    two halves of ``dx`` are summed in float32; each weight gradient is the
+    float32 sum over its expert's rows, rounded once. ``d_gate`` and ``d_up``
+    are what a caller that differentiates ``gated_ffn_padded_kept``'s
+    ``gate`` and ``up`` themselves adds to theirs (AD's zeros where nobody
+    does, which XLA folds away)."""
+    (rows, d), f = x_pad.shape, gate.shape[1]
+    dtype, wide = x_pad.dtype, w_gate.dtype
+    sizes = dict(d=d, f=f)
+    dgate, dup = _gated_call(
+        "gated_ffn_dh", tiles, (dy_pad, w_down, gate, up),
+        ("rows", "w_rows", "cols", "cols"),
+        (("cols", (rows, f), dtype),) * 2, cut=f, depth=d,
+        itemsize=w_down.dtype.itemsize, act=act, **sizes)
+    if d_gate is not None:
+        dgate, dup = dgate + d_gate, dup + d_up
+    dx_pad, = _gated_call(
+        "gated_ffn_dx", tiles, (dgate, dup, w_gate, w_up),
+        ("rows", "rows", "w_rows", "w_rows"), (("cols", (rows, d), dtype),),
+        cut=d, depth=f, itemsize=wide.itemsize, **sizes)
+    dw_gate, dw_up = _gated_call(
+        "gated_ffn_dw_up", tiles, (x_pad, dgate, dup),
+        ("rows", "cols", "cols"), (("w_cols", w_gate.shape, wide),) * 2,
+        cut=f, depth=d, itemsize=4, accumulators=((d, None),) * 2, **sizes)
+    dw_down, = _gated_call(
+        "gated_ffn_dw_down", tiles, (gate, up, dy_pad),
+        ("cols", "cols", "rows"), (("w_rows", w_down.shape, w_down.dtype),),
+        cut=f, depth=d, itemsize=4, accumulators=((None, d),), act=act,
+        **sizes)
+    return dx_pad, dw_gate, dw_up, dw_down
 
 
 #: An ungated expert's activation, by the name a caller gives as ``act``:

@@ -201,10 +201,13 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: holds, or ``"xla"`` where the shape took the ``jax.numpy`` scan);
 #: ``mixer_plan`` likewise, once a traced call of ``ops.ssd.conv_silu`` or
 #: ``ops.ssd.gate_norm`` (``value``: stage, rows, channels, groups and the
-#: [rows, cols] tile of a program, or ``"xla"``).
+#: [rows, cols] tile of a program, or ``"xla"``); ``gmm_plan`` likewise, once
+#: a traced call of a kernel of ``ops.grouped_matmul`` (``value``: kernel,
+#: form (``gated`` / ``plain``), rows, d, f, bt, the column block, how many
+#: blocks, and the bytes of VMEM the kernel's blocks take).
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
                    "cache_retrieval", "cache_miss", "flash_schedule",
-                   "ssd_plan", "mixer_plan")
+                   "ssd_plan", "mixer_plan", "gmm_plan")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

@@ -314,6 +314,72 @@ def test_grouped_ffn_fwd_bwd_compiles(one_chip, as_tpu):
                    _sds((E, ffn, d), one_chip), seg, seg)
 
 
+#: The gated FFN's kernels a layer, by side of the bounded layout's ``cond``:
+#: a forward is ``gated_ffn_up`` and the down projection from its ``gate`` and
+#: ``up`` (``gated_ffn_down``); the backward runs the down projection again
+#: from them on the whole layout's side alone (the parts' side differentiates a
+#: checkpointed part, whose combine is inside it).
+_GATED_BACKWARD = ("gated_ffn_dh", "gated_ffn_dx", "gated_ffn_dw_up",
+                   "gated_ffn_dw_down")
+
+
+def _expert_kernel_calls(text):
+    """``[(kernel, op_name)]`` of a compiled text's expert kernels."""
+    import re
+
+    return [(m.group(1), m.group(2)) for m in re.finditer(
+        r"%((?:gated_ffn|grouped_matmul)[a-z_]*)[.\d]* = [^\n]*tpu_custom_call"
+        r"[^\n]*op_name=\"([^\"]*)\"", text)]
+
+
+@pytest.mark.parametrize("rows,d,f,held,act,two_blocks", [
+    pytest.param(18432, 2048, 1024, 16, "silu", (1024, 2048), id="trinity"),
+    pytest.param(26624, 2560, 768, 16, "relu", (768, 2560), id="smallthinker"),
+    pytest.param(9216, 2048, 1536, 8, "silu", (768, 1024), id="glm"),
+    pytest.param(17408, 2048, 1792, 8, "silu", (896, 1024), id="lfm2")])
+def test_gated_ffn_kernels_compile_at_published_widths(
+        one_chip, as_tpu, rows, d, f, held, act, two_blocks):
+    """The gated FFN's six kernels at the four gated cells' widths and
+    bounded layouts (tiles of 128 rows): the forward, the down projection
+    again from ``gate`` and ``up``, and the backward's four, each under the scoped
+    VMEM it asks for. The kernels that hold two weight blocks side by side
+    (``gated_ffn_up``, ``gated_ffn_dx``: ``two_blocks`` are their column
+    blocks) and ``gated_ffn_dw_up``'s two accumulators take more than the
+    default scope and say so in their ``gmm_plan``."""
+    from pytorch_distributed_training_example_tpu.utils import telemetry
+
+    tiles = tuple(_sds((n,), one_chip, jnp.int32)
+                  for n in (rows // 128, rows // 128, 1))
+    x, w_in, w_out = (_sds((rows, d), one_chip), _sds((held, d, f), one_chip),
+                      _sds((held, f, d), one_chip))
+
+    def every(x, w_gate, w_up, w_down, tiles, dy):
+        y, gate, up = grouped_matmul.gated_ffn_padded_kept(
+            x, w_gate, w_up, w_down, tiles, act)
+        return (y,
+                grouped_matmul.gated_down_padded(gate, up, w_down, tiles, act),
+                grouped_matmul.gated_ffn_padded_bwd(
+                    x, gate, up, w_gate, w_up, w_down, tiles, dy, act))
+
+    mark = len(telemetry.recorder().records())
+    text = _compiled_text(every, x, w_in, w_in, w_out, tiles, x)
+    for name in ("gated_ffn_up", "gated_ffn_down", *_GATED_BACKWARD):
+        assert f"%{name}" in text, name
+    assert "%grouped_matmul" not in text
+    plans = {r.value["kernel"]: r.value
+             for r in telemetry.recorder().records()[mark:]
+             if r.name == "gmm_plan" and r.value["form"] == "gated"}
+    assert (plans["gated_ffn_up"]["block"],
+            plans["gated_ffn_dx"]["block"]) == two_blocks
+    over = {name for name, plan in plans.items()
+            if plan["vmem"] > grouped_matmul._SCOPED_VMEM}
+    assert {"gated_ffn_dw_up"} <= over <= {
+        "gated_ffn_up", "gated_ffn_dx", "gated_ffn_dw_up"}, plans
+    assert {"gated_ffn_up", "gated_ffn_dx"} <= over or f > 1024, plans
+    assert all(plan["vmem"] < 2 * grouped_matmul._SCOPED_VMEM
+               for plan in plans.values()), plans
+
+
 def test_flash_under_four_device_mesh_compiles(topo, one_chip, as_tpu):
     """Batch sharded over four chips: GSPMD cannot partition a Mosaic
     kernel, so a bare call raises "wrap the call in a shard_map" — the
@@ -461,10 +527,11 @@ def _trinity_layer_text(one_chip):
 
 def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
     """The held experts' layer at the published widths: the gated grouped
-    FFN forward and backward (the transposed read of the weights in dx, the
-    4 MB accumulator of dw under the scoped VMEM)."""
+    FFN's kernels forward and backward (the transposed read of the weights in
+    dh and dx, the two 4 MB accumulators of dw_gate and dw_up under the
+    scoped VMEM their kernel asks for)."""
     text = _trinity_layer_text(one_chip)
-    assert "grouped_matmul_dw" in text and "conditional" in text
+    assert "gated_ffn_dw_up" in text and "conditional" in text
     # the bounded layout: 144 tiles of 128 rows, not the worst case's 528
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
 
@@ -473,10 +540,15 @@ def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
     """The same layer under the block's remat (``nothing_saveable``), with a
     consumer inside it that needs the layer's output as ``post_ffn_norm``
     does: on the whole layout's side of the ``cond``s (the parts' side lies
-    under a ``while``) the step holds the forward's three grouped matmuls,
-    the recomputation's three, the backward's three ``dx`` and the down
-    projection again for the combine's weights, and three ``dw``; what
-    crosses a ``cond`` is in the compute dtype."""
+    under a ``while``) the gradients hold the recomputation's forward (one
+    ``gated_ffn_up`` that writes ``gate`` and ``up`` and the down projection
+    from them; the first forward's value nobody asks for here) and the
+    backward's five kernels: the down projection again for the combine's
+    weights from ``gate`` and ``up``, ``dh`` as ``dgate`` and ``dup``, one
+    ``dx``, ``dw_gate`` with ``dw_up``, ``dw_down``. The parts' side runs the
+    same kernels (a part's forward, its recomputation and the transposes; its
+    combine lies inside the part, so no down projection runs a third time);
+    what crosses a ``cond`` is in the compute dtype."""
     import re
     from collections import Counter
 
@@ -493,31 +565,37 @@ def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
             params, x)
 
     text = _compiled_text(grads, *args)
-    found = [(m.group(1), m.group(2)) for m in re.finditer(
-        r"%(grouped_matmul(?:_dw)?)[.\d]* = [^\n]*tpu_custom_call"
-        r"[^\n]*op_name=\"([^\"]*)\"", text) if "/while/" not in m.group(2)]
-    calls = Counter(name for name, _ in found)
-    assert 0 < calls["grouped_matmul"] <= 10, calls
-    assert calls["grouped_matmul_dw"] == 3, calls
-    # the backward's own: the down projection again and three dx (six where
-    # it differentiated the branch as a whole, the forward inside it)
-    backward = Counter(name for name, scope in found if "transpose(" in scope
+    found = _expert_kernel_calls(text)
+    whole = [(name, scope) for name, scope in found if "/while/" not in scope]
+    assert Counter(name for name, _ in whole) == {
+        "gated_ffn_up": 1, "gated_ffn_down": 2,
+        **dict.fromkeys(_GATED_BACKWARD, 1)}, whole
+    # the backward's own: no routed forward again (two more were it to
+    # differentiate the branch as a whole, the forward inside it)
+    backward = Counter(name for name, scope in whole if "transpose(" in scope
                        and "rematted_computation" not in scope)
-    assert backward == {"grouped_matmul": 4, "grouped_matmul_dw": 3}, backward
+    assert backward == {"gated_ffn_down": 1,
+                        **dict.fromkeys(_GATED_BACKWARD, 1)}, backward
+    parts = Counter(name for name, scope in found if "/while/" in scope)
+    assert parts == {"gated_ffn_up": 2, "gated_ffn_down": 2,
+                     **dict.fromkeys(_GATED_BACKWARD, 1)}, parts
     crossing = re.findall(r" = (\(.*?\)) conditional\(", text)
     assert crossing and not any("f32[18432,1024]" in c for c in crossing)
     assert any("bf16[18432,1024]" in c for c in crossing)   # gate and up
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
+    # no XLA pass over the whole layout is left between the kernels: the
+    # float32 views of gate and up that the gate's fusions made are gone
+    assert "f32[18432,1024]" not in text
 
 
 def test_held_experts_layer_compiles_at_glm_widths(one_chip, as_tpu):
     """GLM-4.7-Flash's expert layer: 8 held experts of 64, 4 a token, 8,192
-    tokens of 2048, experts of 1536 (six column blocks of 256), a shared
+    tokens of 2048, experts of 1536 (two column blocks of 768), a shared
     expert of 1536; forward and backward, the bounded layout's ``cond``."""
     text = _layer_grads_text(*_held_experts_layer(
         one_chip, num_experts=64, ffn_dim=1536, top_k=4, held=8,
         route_scale=1.8))
-    assert "grouped_matmul_dw" in text and "conditional" in text
+    assert "gated_ffn_dw_up" in text and "conditional" in text
     # the bounded layout: 8,192 x 4 pairs, an eighth of them expected here;
     # no layout of the worst case's 32,768 rows and more
     assert "bf16[32768,2048]" not in text and "bf16[33792,2048]" not in text
@@ -577,8 +655,9 @@ def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
     assert held < 16.0e9, held
     text = compiled.as_text()
     for name in ("flash_fwd_window", "flash_bwd_window_dq", "flash_fwd_online",
-                 "grouped_matmul", "grouped_matmul_dw"):
+                 "gated_ffn_up", "gated_ffn_down", *_GATED_BACKWARD):
         assert name in text, name
+    assert "grouped_matmul" not in text
     # three big moves a layer (the combine, the combine again in the block's
     # remat, whose norm reads it, and the dispatch's transpose), eight slabs
     # each, from one 75 MB source a move that VMEM holds
@@ -653,10 +732,10 @@ def _smallthinker_routine_text(one_chip):
 
 
 def test_relu_held_experts_compile_at_published_widths(one_chip, as_tpu):
-    """That routine compiles (column blocks of 256, since 768 is no power of
-    two) in the bounded layout at ``chunks`` 2."""
+    """That routine compiles (768 columns in one block: six lane tiles that
+    fit) in the bounded layout at ``chunks`` 2."""
     text = _smallthinker_routine_text(one_chip)
-    assert "grouped_matmul_dw" in text and "conditional" in text
+    assert "gated_ffn_dw_up" in text and "conditional" in text
     # the bounded layout: 208 tiles of 128 rows (half the tokens' worst
     # case), not the whole worst case's 400
     assert "bf16[26624,2560]" in text and "bf16[51200,2560]" not in text
@@ -767,7 +846,16 @@ def test_smallthinker_share_step_fits_the_chip(one_chip, as_tpu):
         assert calls[name] == 3, calls       # three window layers
     for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
         assert calls[name] == 1, calls       # one full layer
-    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"], calls
+    # four layers, both sides of the ``cond``: four forwards a layer (each
+    # side's own and its recomputation, all of them the rule's forward that
+    # keeps ``gate`` and ``up``; nothing in the block reads the expert
+    # layer's output, so the whole side's recomputation runs no down
+    # projection), the down projection again in the whole side's backward,
+    # the four transposes on both
+    assert calls["gated_ffn_up"] == 4 * 4, calls
+    assert calls["gated_ffn_down"] == 4 * 4, calls
+    assert all(calls[name] == 4 * 2 for name in _GATED_BACKWARD), calls
+    assert not calls["grouped_matmul"] + calls["grouped_matmul_dw"], calls
     # two big moves a layer (the combine and the dispatch's transpose: nothing
     # in the block reads the expert layer's output, so the remat's combine is
     # dropped), six slabs a column part, each from a 68 MB part in VMEM; no
@@ -805,7 +893,10 @@ def test_glm47_share_step_fits_the_chip(one_chip, as_tpu):
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
         assert calls[name] == 6, calls    # dense, four expert, the module's
-    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 30, calls
+    assert calls["gated_ffn_up"] == 5 * 4, calls      # as SmallThinker's
+    assert calls["gated_ffn_down"] == 5 * 4, calls
+    assert all(calls[name] == 5 * 2 for name in _GATED_BACKWARD), calls
+    assert not calls["grouped_matmul"] + calls["grouped_matmul_dw"], calls
     assert not [name for name in calls if name.startswith("flash_")
                 and name not in ("flash_fwd_online", "flash_bwd_dq",
                                  "flash_bwd_dkv")], calls
@@ -1047,7 +1138,10 @@ def test_lfm2_share_step_fits_the_chip(one_chip, as_tpu):
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in fa.ONLINE_KERNELS:
         assert calls[name] == 2, calls
-    assert calls["grouped_matmul"] and calls["grouped_matmul_dw"] == 6 * 3 * 2
+    assert calls["gated_ffn_up"] == 6 * 4, calls      # as SmallThinker's
+    assert calls["gated_ffn_down"] == 6 * 4, calls
+    assert all(calls[name] == 6 * 2 for name in _GATED_BACKWARD), calls
+    assert not calls["grouped_matmul"] + calls["grouped_matmul_dw"], calls
     assert not [line for line in text.splitlines()
                 if "tpu_custom_call" in line and "conv_gate" in line]
     for scope in ("short_conv", "conv_gate", "in_proj", "out_proj",

@@ -165,6 +165,61 @@ def test_ssd_plan_record_under_the_span_that_traced():
     assert "ssd_plan" in telemetry.COMPILE_RECORDS
 
 
+def test_gmm_plan_record_under_the_span_that_traced():
+    """One ``gmm_plan`` record a traced call of each kernel of
+    ``ops/grouped_matmul.py``, a child of the span open on the tracing
+    thread: the gated form's kernels at LFM2's widths (the forward that
+    keeps ``gate`` and ``up``, then the backward's four), and the plain
+    kernels of an ungated layer at Nemotron's."""
+    rec = telemetry.recorder()
+    mark = len(rec.records())
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    shape = lambda *s, dtype=bf16: jax.ShapeDtypeStruct(s, dtype)
+    tiles = lambda rows: (shape(rows // 128, dtype=i32),
+                          shape(rows // 128, dtype=i32), shape(1, dtype=i32))
+
+    def gated(x, wg, wu, wd, tiles, dy):
+        _, gate, up = gmm_lib.gated_ffn_padded_kept(x, wg, wu, wd, tiles)
+        return gmm_lib.gated_ffn_padded_bwd(x, gate, up, wg, wu, wd, tiles, dy)
+
+    def ungated(x, wu, wd, tiles, dy):
+        _, up = gmm_lib.ungated_ffn_padded_kept(x, wu, wd, tiles)
+        return gmm_lib.ungated_ffn_padded_bwd(x, up, wu, wd, tiles, dy)
+
+    with rec.span("trace_here", bucket=None):
+        jax.eval_shape(gated, shape(17408, 2048), shape(8, 2048, 1792),
+                       shape(8, 2048, 1792), shape(8, 1792, 2048),
+                       tiles(17408), shape(17408, 2048))
+        jax.eval_shape(ungated, shape(7168, 2688), shape(8, 2688, 1856),
+                       shape(8, 1856, 2688), tiles(7168), shape(7168, 2688))
+    new = rec.records()[mark:]
+    span = next(r for r in new if r.kind == "span" and r.name == "trace_here")
+    said = [r for r in new if r.name == "gmm_plan"]
+    assert all(r.kind == "compile" and r.parent == span.id and r.seconds == 0
+               for r in said)
+    brief = [(r.value["kernel"], r.value["form"], r.value["block"],
+              r.value["blocks"]) for r in said]
+    assert brief == [
+        ("gated_ffn_up", "gated", 896, 2), ("gated_ffn_down", "gated", 1024, 2),
+        ("gated_ffn_dh", "gated", 896, 2), ("gated_ffn_dx", "gated", 1024, 2),
+        ("gated_ffn_dw_up", "gated", 512, 4),
+        ("gated_ffn_dw_down", "gated", 512, 4),
+        ("grouped_matmul", "plain", 640, 3), ("grouped_matmul", "plain", 896, 3),
+        ("grouped_matmul", "plain", 640, 3), ("grouped_matmul_dw", "plain", 512, 6),
+        ("grouped_matmul", "plain", 896, 3), ("grouped_matmul_dw", "plain", 384, 5),
+    ], brief
+    assert said[0].value == {
+        "kernel": "gated_ffn_up", "form": "gated", "rows": 17408, "d": 2048,
+        "f": 1792, "bt": 128, "block": 896, "blocks": 2,
+        # two weight blocks of 3.5 MiB, the x tile and two [128, 896]
+        # results, each double-buffered
+        "vmem": 2 * (2 * 2048 * 896 + 128 * 2048 + 2 * 128 * 896) * 2}
+    # two accumulators side by side take more than the default scope: the
+    # kernel asks for that and its tiles
+    assert said[4].value["vmem"] > gmm_lib._SCOPED_VMEM
+    assert "gmm_plan" in telemetry.COMPILE_RECORDS
+
+
 def test_mixer_at_one_group_is_granites():
     """``groups`` 1: the parameter paths and shapes of the mixer that Granite
     builds, and its traced program, are what they were without the field."""

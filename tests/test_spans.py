@@ -457,7 +457,8 @@ def _site_names(call):
     """The names a ``pallas_call`` site gives its kernel: its literal, or, at
     the online flash kernels' three sites, the schedule's (``plan.name``: the
     kernel's own, or its window name under a schedule with a window)."""
-    from pytorch_distributed_training_example_tpu.ops import flash_attention
+    from pytorch_distributed_training_example_tpu.ops import (
+        flash_attention, grouped_matmul)
 
     named = [k.value for k in call.keywords if k.arg == "name"]
     if len(named) != 1:
@@ -466,6 +467,8 @@ def _site_names(call):
         return [named[0].value]
     if isinstance(named[0], ast.Attribute) and named[0].attr == "name":
         return [*flash_attention.ONLINE_KERNELS, *flash_attention.WINDOW_KERNELS]
+    if isinstance(named[0], ast.Name) and named[0].id == "name":
+        return list(grouped_matmul.GATED_KERNELS)   # the gated FFN's launcher
     return None
 
 
@@ -474,15 +477,22 @@ def test_every_pallas_call_has_a_name(file, call):
     names = _site_names(call)
     assert names, f"{file}:{call.lineno} pl.pallas_call has no name="
     assert all(isinstance(n, str) and n.isidentifier() for n in names)
-    assert len(names) == 1 or file == "flash_attention.py"
+    assert len(names) == 1 or file in ("flash_attention.py",
+                                       "grouped_matmul.py")
 
 
 def test_pallas_names_are_one_per_kernel():
     sites = [_site_names(p.values[1]) for p in _pallas_sites()]
-    by_schedule = [s for s in sites if len(s) > 1]
-    assert len(by_schedule) == 3        # the online forward, dq and dkv
-    names = [s[0] for s in sites if len(s) == 1] + by_schedule[0]
-    assert len(names) == 20 and len(set(names)) == 20
+    several = [s for s in sites if len(s) > 1]
+    # the online forward, dq and dkv by their schedule; the gated FFN's six
+    # kernels through one launcher
+    assert len(several) == 4 and several[0] == several[1] == several[2]
+    names = [s[0] for s in sites if len(s) == 1] + several[0] + several[3]
+    assert len(names) == 26 and len(set(names)) == 26
+    assert {n for n in names if n.startswith(("gated_ffn", "grouped_"))} == {
+        "grouped_matmul", "grouped_matmul_dw", "gated_ffn_up",
+        "gated_ffn_down", "gated_ffn_dh", "gated_ffn_dx", "gated_ffn_dw_up",
+        "gated_ffn_dw_down"}
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
     assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
         "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd"}
