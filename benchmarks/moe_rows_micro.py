@@ -31,6 +31,17 @@ sides of the rule's edge at d = 2560 and 2048, ``whole`` and ``program``: a row
 each with the source's ``bytes``, ``parts`` (the rule's), ``vmem`` (did the
 compiled text place every gathered source in ``S(1)``) and ``ms``: the
 measurement that ``parallel/moe.GATHER_SOURCE_BYTES`` stands on.
+
+``--index-ops`` times nothing and needs no chip: it compiles the gradients of
+one expert layer of each cell's shape (``LAYERS``; under a block's remat with a
+consumer of the layer's output, as a model's block holds it) for the chip, or
+in the sandbox for a described v5e, and prints ``index_ops`` of the compiled
+text grouped: every ``gather``, ``scatter`` and ``sort`` by inner scope, with
+how many places it indexes, the elements a place and whether it carries a
+scope at all. These are counts of a compiled text, not times: they say which
+operations index a scalar at a time (``each`` 1) and which no reader of a
+trace by scope will count (``scope`` null); what each costs is for a traced
+run of the cell to say.
 """
 
 from __future__ import annotations
@@ -170,14 +181,19 @@ def row_arrays(text, d, least):
     return sorted(relayout), sorted(vmem)
 
 
+def _computations(text):
+    """{name: body} of a compiled text's computations."""
+    return {m.group(1): body for body in text.split("\n\n")
+            if (m := re.match(r"(?:ENTRY )?(%[\w.-]+) \(", body.strip()))}
+
+
 def row_gathers(text, least=1 << 22):
     """``[(scope, rows gathered, source)]`` of a compiled text's gathers of
     ``least`` elements or more outside every ``while``: ``scope`` the
     ``op_name`` of the fusion that holds the gather, ``source`` the gathered
     array's shape and layout as that fusion's caller holds it (``S(1)`` in
     the layout: placed in VMEM)."""
-    bodies = {m.group(1): body for body in text.split("\n\n")
-              if (m := re.match(r"(?:ENTRY )?(%[\w.-]+) \(", body.strip()))}
+    bodies = _computations(text)
     inside = {}                   # fused computation: [(rows, parameter)]
     for name, body in bodies.items():
         number = dict(re.findall(r"(%[\w.-]+) = \S+ parameter\((\d+)\)", body))
@@ -198,6 +214,161 @@ def row_gathers(text, least=1 << 22):
                 found += [(scope, rows, held.get(operands[index], "?"))
                           for rows, index in inside.get(callee, [])]
     return found
+
+
+#: The expert layer's inner scopes, as the benchmark's ``row: "moe"`` line
+#: splits its time.
+INNER = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+         "moe_shared")
+
+
+def index_ops(text):
+    """Every ``gather``, ``scatter`` and ``sort`` of a compiled text, a dict
+    each: ``op``; ``result`` (a sort's first array); ``indices``, how many
+    places it looks up, writes to or orders (a gather's slices, a scatter's
+    updates, the elements along a sort's dimension times its rows); ``each``,
+    the elements a place (1: a scalar at a time); ``arrays`` (a sort's
+    operands, else 1); ``scope``, the first of ``INNER`` in its ``op_name``,
+    (the ``op_name`` of the fusion that holds it, where one does), else
+    ``"other"``, else ``None`` where neither the fusion nor the instruction
+    carries an ``op_name`` at all (no reader of a trace by scope counts it);
+    ``loop``: under a ``while``. Counts of a compiled
+    text, not times: what one costs is for a traced run to say (about 8 ns a
+    scalar index on a v5e, 14 ns a row from VMEM; PERF.md section 6)."""
+    bodies = _computations(text)
+    name_of = lambda line: (re.findall(r'op_name="([^"]*)"', line) or [None])[0]
+    callers = {}                  # fused computation: its caller's op_name
+    for body in bodies.values():
+        for line in body.splitlines():
+            if (m := re.search(r" fusion\(.*calls=(%[\w.-]+)", line)):
+                callers[m.group(1)] = name_of(line)
+    dims_of = lambda shape: [int(v) for v in re.findall(r"\d+", shape.split(
+        "[")[1].split("]")[0])]
+    found = []
+    for name, body in bodies.items():
+        held = dict(re.findall(
+            r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])", body, re.M))
+        for line in body.splitlines():
+            m = re.search(r" = \(?(\w+\[[\d,]*\])[^=]*? (gather|scatter|sort)"
+                          r"\(([^)]*)\)", line)
+            if not m:
+                continue
+            result, op, operands = m.groups()
+            dims, arrays = dims_of(result), 1
+            if op == "gather":
+                window = [int(v) for v in re.search(
+                    r"offset_dims=\{([\d,]*)\}", line).group(1).split(",") if v]
+                indices = math.prod(
+                    v for axis, v in enumerate(dims) if axis not in window)
+                each = math.prod(dims) // indices
+            elif op == "scatter":
+                places, updates = (dims_of(held[name]) for name in re.findall(
+                    r"%[\w.-]+", operands)[1:3])
+                vector = int(re.search(r"index_vector_dim=(\d+)", line).group(1))
+                indices = math.prod(
+                    v for axis, v in enumerate(places) if axis != vector)
+                each = math.prod(updates) // indices
+            else:
+                indices, each, arrays = math.prod(dims), 1, operands.count("%")
+            # a fused instruction counts where its fusion does: a trace has
+            # the fusion's time under the fusion's ``op_name``
+            scope = callers.get(name) or name_of(line)
+            found.append(dict(
+                op=op, result=result, indices=indices, each=each,
+                arrays=arrays, scope=None if scope is None else next(
+                    (part for part in scope.split("/") if part in INNER),
+                    "other"),
+                loop=bool(scope) and "/while/" in scope))
+    return found
+
+
+#: ``--index-ops``: an expert layer of each expert cell's shape at 8,192
+#: tokens: ``(d, k, E, held, the experts' width, the shared expert's, gated,
+#: the router's scale; None: the softmax router ahead of ``HeldExperts``)``.
+LAYERS = {
+    "trinity_mini": (2048, 8, 128, 16, 1024, 1024, True, 2.826),
+    "smallthinker_21b": (2560, 6, 64, 16, 768, 0, True, None),
+    "glm47_flash": (2048, 4, 64, 8, 1536, 1536, True, 1.8),
+    "nemotron3_nano": (2688, 6, 128, 8, 1856, 3712, False, 2.5),
+    "lfm2_8b_a1b": (2048, 4, 32, 8, 1792, 0, True, 1.0),
+}
+
+
+def layer_grads(moe, cell, sharding, T=8192):
+    """``(fn, args)``: the gradients of ``LAYERS[cell]``'s layer in its
+    parameters and input, under ``jax.checkpoint(nothing_saveable)`` with a
+    consumer of the layer's output inside it (what a block's closing norm
+    is), over shapes placed by ``sharding``."""
+    import jax
+    import jax.numpy as jnp
+
+    d, k, E, held, ffn, shared, gated, scale = LAYERS[cell]
+    bf = jnp.bfloat16
+    x = jnp.zeros((1, T, d), bf)
+    if scale is None:
+        router = moe.TopKSoftmaxRouter(num_experts=E, top_k=k)
+        experts = moe.HeldExperts(ffn_dim=ffn, held_experts=(held, 0),
+                                  act="relu", dtype=bf)
+
+        def init():
+            kernel = router.init(jax.random.key(0), x.astype(jnp.float32))
+            route = router.apply(kernel, x.astype(jnp.float32))
+            return {"router": kernel,
+                    "experts": experts.init(jax.random.key(0), x, route)}
+
+        def apply(variables, x):
+            with jax.named_scope("block"), jax.named_scope("moe_router"):
+                route = router.apply(variables["router"],
+                                     x.astype(jnp.float32))
+            return experts.apply(variables["experts"], x, route)
+    else:
+        layer = moe.SharedExpertMoE(
+            num_experts=E, ffn_dim=ffn, top_k=k, held_experts=(held, 0),
+            shared_ffn_dim=shared, route_scale=scale, balance_coeff=0.001,
+            gated=gated, dtype=bf)
+        init = lambda: layer.init(jax.random.key(0), x, train=False)
+        apply = lambda variables, x: layer.apply(variables, x, train=False)
+
+    def grads(variables, x):
+        block = jax.checkpoint(
+            lambda v, x: jnp.sin(apply(v, x).astype(jnp.float32)),
+            prevent_cse=False,
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return jax.grad(lambda v, x: block(v, x).sum(), argnums=(0, 1))(
+            variables, x)
+
+    placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    return grads, placed((jax.eval_shape(init), x))
+
+
+def print_index_ops(moe, cells):
+    """One JSON row a (cell, op, result, scope, ...) with how many such
+    instructions the layer's compiled text holds."""
+    import collections
+
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    if jax.default_backend() == "tpu":
+        device = jax.devices()[0]
+    else:       # compile for a described chip; the program asks the backend
+        from jax.experimental import topologies
+
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0]
+        jax.config.update("jax_enable_compilation_cache", False)
+        jax.default_backend = lambda: "tpu"
+    print(json.dumps({"device": device.device_kind, "counts": True,
+                      "times": False}), flush=True)
+    for cell in cells:
+        fn, args = layer_grads(moe, cell, SingleDeviceSharding(device))
+        found = collections.Counter(
+            tuple(op.items())
+            for op in index_ops(jax.jit(fn).lower(*args).compile().as_text()))
+        for op, n in sorted(found.items(), key=lambda kv: str(kv[0])):
+            print(json.dumps({"cell": cell, **dict(op), "n": n}), flush=True)
 
 
 #: ``--sweep``: {d: the sources' rows}, 72 MiB to 130 MiB; the edge is 112
@@ -247,14 +418,22 @@ def main():
     p.add_argument("--sweep", action="store_true",
                    help="the combine over sources on both sides of the "
                         "rule's edge, and nothing else")
+    p.add_argument("--index-ops", action="store_true",
+                   help="no timing, no chip needed: the gathers, scatters and "
+                        "sorts of a layer of each of --cells, from its "
+                        "compiled text")
+    p.add_argument("--cells", default=",".join(LAYERS))
     p.add_argument("--iters", type=int, default=5)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
 
     import jax
-    from ssd_micro import device_ms
 
     from pytorch_distributed_training_example_tpu.parallel import moe
+
+    if args.index_ops:
+        return print_index_ops(moe, args.cells.split(","))
+    from ssd_micro import device_ms
 
     if jax.default_backend() != "tpu":
         sys.exit("moe_rows_micro.py times the moves on the chip; this is "

@@ -159,8 +159,31 @@ def _held_keys(chosen, first, held):
     return jnp.where((local >= 0) & (local < held), local, held)
 
 
+def _chose(index, size):
+    """``[..., size]`` booleans: does ``index[...]`` name this one of ``size``?
+
+    How an index over a small axis (an expert of E) is applied in this file: by
+    comparison, in a dense pass that XLA fuses with what selects or counts by
+    it, and never by a gather or a scatter of one scalar an element, which
+    costs a v5e 7 to 10 ns an element whatever the table's size (0.5 ms for
+    the 65,536 pairs of 8,192 tokens; PERF.md sections 5 and 6, PR 49), where
+    the ``size`` compares an element cost an eighth of that at E = 128 (a
+    layer's picks, their transpose and its counts: 0.28 ms against 2.18) and
+    less at the presets' smaller E. With experts in the thousands the dense
+    pass would cost what the gather does; nothing here branches on it."""
+    return index[..., None] == jnp.arange(size, dtype=index.dtype)
+
+
+def _count(index, size):
+    """How many of ``index`` name each of ``size``, ``[size]`` int32
+    (``bincount``'s integers; an index of ``size`` or more is counted
+    nowhere)."""
+    return jnp.sum(_chose(index, size), axis=tuple(range(index.ndim)),
+                   dtype=jnp.int32)
+
+
 def _held_counts(key, held):
-    return jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    return _count(key, held)
 
 
 def _plan(chosen, first, held, bt, max_tiles, counts):
@@ -175,15 +198,17 @@ def _plan(chosen, first, held, bt, max_tiles, counts):
     key = _held_keys(chosen, first, held)                           # [n*k]
     pairs = jnp.arange(n * k, dtype=jnp.int32)
     _, order = jax.lax.sort((key, pairs), num_keys=1)
-    _, rank = jax.lax.sort((order, pairs), num_keys=1)
     if counts is None:
         counts = _held_counts(key, held)
     starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
     tiles, src, dst = gmm_lib._padded_layout(
         starts, counts, n * k, held, bt, max_tiles)
     row_pair = _rows(order, src) + (src >= n * k) * (n * k)         # [P]
-    pair_row = dst[rank].reshape(n, k).T                            # [k, n]
-    return tiles, pair_row, row_pair
+    # ``dst`` is by sorted place; the sort that undoes ``order`` carries it
+    # back to the pairs: ``dst[rank]`` with no ``rank`` and no gather (its keys
+    # are distinct, and a stable sort would carry an iota more to part ties)
+    _, pair_row = jax.lax.sort((order, dst), num_keys=1, is_stable=False)
+    return tiles, pair_row.reshape(n, k).T, row_pair                # [k, n]
 
 
 def _routed_kept(tokens, chosen, weights, experts, first, bt, max_tiles=None,
@@ -368,8 +393,18 @@ def _scores(tokens, kernel):
 
 
 def _load(chosen, num_experts):
-    load = jnp.bincount(chosen.reshape(-1), length=num_experts)    # [E]
-    return mesh_lib.constrain(load, P(None))
+    return mesh_lib.constrain(_count(chosen, num_experts), P(None))  # [E]
+
+
+def _pick(scores, chosen):
+    """``take_along_axis(scores [T, E], chosen [T, k])`` by selection: one
+    term of each sum over E is not zero, so the sum is that term to the bit
+    (a ``-0.0`` comes out ``0.0``); its transpose is a select and a sum over
+    the choices (a token's choices are distinct, so an element gets one term
+    at most: exact too), where the gather's is a scatter of ``T * k`` scalars
+    into a zero ``[T, E]``."""
+    return jnp.sum(jnp.where(_chose(chosen, scores.shape[1]),
+                             scores[:, None, :], 0.0), axis=-1)
 
 
 def route_sigmoid_bias(tokens, kernel, bias, k, route_scale,
@@ -381,7 +416,7 @@ def route_sigmoid_bias(tokens, kernel, bias, k, route_scale,
     and 1e-6 in the ``lfm2_moe`` models' released router)."""
     scores = jax.nn.sigmoid(_scores(tokens, kernel))
     _, chosen = jax.lax.top_k(scores + bias, k)                     # [T, k]
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    picked = _pick(scores, chosen)                                  # [T, k]
     weights = route_scale * picked / (
         jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
     return Route(chosen, weights, _load(chosen, kernel.shape[1]))
@@ -392,8 +427,10 @@ def route_softmax_chosen(tokens, kernel, k) -> Route:
     softmax over the chosen logits alone, then divided by their sum (the
     identity but for rounding; the published ``norm_topk_prob``). All
     float32 (the ``smallthinker`` models' primary router)."""
-    top, chosen = jax.lax.top_k(_scores(tokens, kernel), k)         # [T, k]
-    weights = jax.nn.softmax(top, axis=-1)
+    logits = _scores(tokens, kernel)
+    _, chosen = jax.lax.top_k(logits, k)                            # [T, k]
+    # ``top_k``'s own values are these, and their transpose a scatter
+    weights = jax.nn.softmax(_pick(logits, chosen), axis=-1)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return Route(chosen, weights, _load(chosen, kernel.shape[1]))
 

@@ -26,7 +26,8 @@ from pytorch_distributed_training_example_tpu.core.trainer import Trainer  # noq
 from pytorch_distributed_training_example_tpu.models import (  # noqa: E402
     afmoe, registry)
 from pytorch_distributed_training_example_tpu.ops import (  # noqa: E402
-    attention as attn_lib, flash_attention as flash_lib)
+    attention as attn_lib, flash_attention as flash_lib,
+    grouped_matmul as gmm_lib)
 from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib  # noqa: E402
 from pytorch_distributed_training_example_tpu.utils.config import from_preset  # noqa: E402
 
@@ -489,6 +490,132 @@ def test_bounded_backward_is_plain_ad_of_the_routine(routing, remat):
         else:
             np.testing.assert_allclose(
                 g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
+
+
+# -- the router's and the plan's indexing (PR 49): dense passes over [T, k, E]
+# -- and a sort's payload, against the forms they replaced (a gather, a
+# -- scatter-add and a gather of one scalar a pair), kept here as the yardstick
+
+#: ``(k, E, held)`` of the five expert cells: Trinity, SmallThinker, GLM,
+#: Nemotron, LFM2
+CELLS = pytest.mark.parametrize("k,E,held", [
+    (8, 128, 16), (6, 64, 16), (4, 64, 8), (6, 128, 8), (4, 32, 8)])
+ROUTINGS = pytest.mark.parametrize("routing", ["random", "collapsed", "ties"])
+
+
+def _router_inputs(routing, E, T=256, d=32, seed=7):
+    """``(tokens, kernel, bias)``: a random router; one whose bias puts every
+    token on the first held expert; one with exact ties in ``scores + bias``
+    (every second column of the kernel a copy of the one before it, one bias
+    for all, and a sixteenth of the tokens zero: all their scores 0.5)."""
+    keys = jax.random.split(jax.random.key(seed), 3)
+    tokens = jax.random.normal(keys[0], (T, d))
+    kernel = 0.3 * jax.random.normal(keys[1], (d, E))
+    bias = 0.05 * jax.random.normal(keys[2], (E,))
+    if routing == "collapsed":
+        bias = bias.at[0].add(5.0)
+    elif routing == "ties":
+        kernel = kernel.at[:, 1::2].set(kernel[:, 0::2])
+        bias = jnp.full((E,), 0.01)
+        tokens = tokens.at[::16].set(0.0)
+    return tokens, kernel, bias
+
+
+def _gathered_route_sigmoid_bias(tokens, kernel, bias, k, route_scale,
+                                 norm_eps=1e-20):
+    """``route_sigmoid_bias`` as it was before PR 49."""
+    scores = jax.nn.sigmoid(moe_lib._scores(tokens, kernel))
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = route_scale * picked / (
+        jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
+    return moe_lib.Route(chosen, weights, jnp.bincount(
+        chosen.reshape(-1), length=kernel.shape[1]))
+
+
+def _gathered_plan(chosen, first, held, bt, max_tiles, counts):
+    """``_plan`` as it was before PR 49: a second sort for every pair's rank,
+    a gather of ``dst`` by it, ``bincount`` for the counts."""
+    n, k = chosen.shape
+    key = moe_lib._held_keys(chosen, first, held)
+    pairs = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = jax.lax.sort((key, pairs), num_keys=1)
+    _, rank = jax.lax.sort((order, pairs), num_keys=1)
+    if counts is None:
+        counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+    tiles, src, dst = gmm_lib._padded_layout(
+        starts, counts, n * k, held, bt, max_tiles)
+    row_pair = moe_lib._rows(order, src) + (src >= n * k) * (n * k)
+    return tiles, dst[rank].reshape(n, k).T, row_pair
+
+
+@ROUTINGS
+@CELLS
+def test_sigmoid_router_selects_and_counts_what_it_gathered(k, E, held,
+                                                            routing):
+    """``chosen``, ``load`` and the picked scores are the gather's and the
+    ``bincount``'s exactly, the weights with them, op by op and under one
+    ``jit``; the gradients to the tokens and the router's kernel, whose
+    transpose was a scatter of a scalar a pair, are within 1e-6 of theirs."""
+    tokens, kernel, bias = _router_inputs(routing, E)
+    with HIGHEST:
+        want = _gathered_route_sigmoid_bias(tokens, kernel, bias, k, 2.826)
+        got = moe_lib.route_sigmoid_bias(tokens, kernel, bias, k, 2.826)
+        scores = jax.nn.sigmoid(moe_lib._scores(tokens, kernel))
+        picked = jnp.take_along_axis(scores, want.chosen, axis=-1)
+        for pick in (moe_lib._pick, jax.jit(moe_lib._pick)):
+            np.testing.assert_array_equal(pick(scores, want.chosen), picked)
+        jitted = jax.jit(moe_lib.route_sigmoid_bias, static_argnums=(3, 4))(
+            tokens, kernel, bias, k, 2.826)
+    for route in (got, jitted):
+        np.testing.assert_array_equal(route.chosen, want.chosen)
+        np.testing.assert_array_equal(route.load, want.load)
+        assert route.load.dtype == want.load.dtype == jnp.int32
+    np.testing.assert_array_equal(got.weights, want.weights)
+    np.testing.assert_allclose(jitted.weights, want.weights, rtol=3e-7)
+    assert int(want.load.sum()) == tokens.shape[0] * k
+    if routing == "collapsed":
+        assert int(want.load[0]) == tokens.shape[0]
+    if routing == "ties":       # ties at the boundary of the choice exist
+        ranked = np.sort(np.asarray(scores + bias), -1)[:, ::-1]
+        assert np.sum(ranked[:, k - 1] == ranked[:, k]) >= tokens.shape[0] // 16
+
+    mix = jax.random.normal(jax.random.key(9), (tokens.shape[0], k))
+    grads = lambda route: jax.grad(lambda t, w: jnp.sum(jnp.sin(
+        route(t, w, bias, k, 2.826).weights * mix)), (0, 1))(tokens, kernel)
+    with HIGHEST:
+        want_g = grads(_gathered_route_sigmoid_bias)
+        got_g = grads(moe_lib.route_sigmoid_bias)
+    for g, w in zip(got_g, want_g):
+        assert float(jnp.max(jnp.abs(w))) > 1e-4
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))))
+
+
+@ROUTINGS
+@CELLS
+def test_plan_carries_the_padded_rows_through_its_second_sort(k, E, held,
+                                                              routing):
+    """``tiles``, ``pair_row`` and ``row_pair`` are exactly what the plan made
+    with a rank a pair and ``dst[rank]``, with the router's counts and with
+    its own, in the bounded layout (the unbounded one where routing
+    collapses) at the cell's ``(k, E, held)``."""
+    tokens, kernel, bias = _router_inputs(routing, E)
+    with HIGHEST:
+        route = moe_lib.route_sigmoid_bias(tokens, kernel, bias, k, 1.0)
+    bt, first = 8, 0
+    cap = None if routing == "collapsed" else (
+        -(-(tokens.shape[0] // (E // (2 * held))) * k // bt) + held)
+    plan = lambda fn, counts: jax.jit(fn, static_argnums=(1, 2, 3, 4))(
+        route.chosen, first, held, bt, cap, counts)
+    for counts in (None, route.load[first:first + held].astype(jnp.int32)):
+        want, got = plan(_gathered_plan, counts), plan(moe_lib._plan, counts)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+    held_pairs = int(np.sum(np.asarray(got[1]) < got[2].shape[0]))
+    assert held_pairs == int(route.load[:held].sum()) > 0
 
 
 def test_bias_moves_as_the_rule_says_and_no_optimizer_sees_it():

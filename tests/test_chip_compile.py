@@ -536,10 +536,21 @@ def test_held_experts_layer_compiles_at_published_widths(one_chip, as_tpu):
     assert "bf16[18432,2048]" in text and "bf16[67584,2048]" not in text
 
 
-def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
-    """The same layer under the block's remat (``nothing_saveable``), with a
+@functools.cache
+def _trinity_remat_text(one_chip):
+    """Trinity's layer under the block's remat (``nothing_saveable``), with a
     consumer inside it that needs the layer's output as ``post_ffn_norm``
-    does: on the whole layout's side of the ``cond``s (the parts' side lies
+    does (``moe_rows_micro.layer_grads``, what ``--index-ops`` compiles): the
+    gradients' compiled text (compiled once for the tests that read it)."""
+    from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
+
+    grads, args = moe_rows_micro.layer_grads(moe_lib, "trinity_mini", one_chip)
+    return _compiled_text(grads, *args)
+
+
+def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
+    """The same layer under the block's remat (``_trinity_remat_text``): on
+    the whole layout's side of the ``cond``s (the parts' side lies
     under a ``while``) the gradients hold the recomputation's forward (one
     ``gated_ffn_up`` that writes ``gate`` and ``up`` and the down projection
     from them; the first forward's value nobody asks for here) and the
@@ -552,19 +563,7 @@ def test_held_experts_backward_runs_no_routed_forward_again(one_chip, as_tpu):
     import re
     from collections import Counter
 
-    layer, *args = _held_experts_layer(one_chip)
-
-    def grads(params, stats, x):
-        block = jax.checkpoint(
-            lambda p, x: jnp.sin(layer.apply(
-                {"params": p, "batch_stats": stats}, x, train=False).astype(
-                    jnp.float32)),
-            prevent_cse=False,
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return jax.grad(lambda p, x: block(p, x).sum(), argnums=(0, 1))(
-            params, x)
-
-    text = _compiled_text(grads, *args)
+    text = _trinity_remat_text(one_chip)
     found = _expert_kernel_calls(text)
     whole = [(name, scope) for name, scope in found if "/while/" not in scope]
     assert Counter(name for name, _ in whole) == {
@@ -654,6 +653,7 @@ def test_trinity_share_step_fits_the_chip(one_chip, as_tpu):
                                                        rel=1e-3)
     assert held < 16.0e9, held
     text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 128, 8) == []
     for name in ("flash_fwd_window", "flash_bwd_window_dq", "flash_fwd_online",
                  "gated_ffn_up", "gated_ffn_down", *_GATED_BACKWARD):
         assert name in text, name
@@ -700,7 +700,8 @@ def test_window_flash_compiles_at_smallthinker_widths(one_chip):
 def _smallthinker_routine_text(one_chip):
     """The compiled text of the held experts' routine alone at SmallThinker's
     widths (16 held of 64, 6 a token, 8,192 tokens of 2560, experts of 768),
-    ReLU-gated, over a plan that a router made elsewhere: forward and
+    ReLU-gated, over a plan that the model's router made elsewhere (under
+    the scope its name gives it in the model, ``moe_router``): forward and
     backward (compiled once for the tests that read it)."""
     from pytorch_distributed_training_example_tpu.parallel import moe as moe_lib
 
@@ -721,7 +722,10 @@ def _smallthinker_routine_text(one_chip):
 
     def grads(kernel, params, r, y):
         def total(kernel, params, y):
-            plan = router.apply({"params": kernel}, r)
+            # (the outermost scope's name is wrapped by the transforms'
+            # own: ``jvp(block)/moe_router``, as under a model's name)
+            with jax.named_scope("block"), jax.named_scope("moe_router"):
+                plan = router.apply({"params": kernel}, r)
             return layer.apply({"params": params}, y, plan).astype(
                 jnp.float32).sum()
         return jax.grad(total, argnums=(0, 1, 2))(kernel, params, y)
@@ -797,6 +801,87 @@ def test_held_experts_gather_from_sources_vmem_can_hold(
     assert slabs == {"bf16[%d,%d]" % (P, d // parts): k * parts}, slabs
 
 
+def _scalar_index_ops(text, T, E, k):
+    """What the router and the plan must not hold of a compiled text's
+    ``moe_rows_micro.index_ops`` (PR 49): a gather or a scatter under
+    ``moe_router`` (the picks' ``take_along_axis``, the loads' ``bincount``), a
+    scatter of ``T * E`` elements under any scope or none (the picks'
+    transpose, which carried none), a gather of ``s32[n * k]`` under
+    ``moe_dispatch`` (``dst[rank]``: a part of the tokens plans ``T / chunks``
+    of them under a ``while``, so any length is held against)."""
+    return [op for op in moe_rows_micro.index_ops(text) if (
+        op["scope"] == "moe_router" and op["op"] != "sort"
+        or op["op"] == "scatter" and op["result"].endswith("[%d]" % (T * E))
+        or op["op"] == "gather" and op["scope"] == "moe_dispatch"
+        and op["result"] in {"s32[%d]" % (T * k // chunks)
+                             for chunks in (1, 2, 4, 8)})]
+
+
+@pytest.mark.parametrize("text_of,E,k", [
+    pytest.param(_trinity_remat_text, 128, 8, id="trinity"),
+    pytest.param(_smallthinker_routine_text, 64, 6, id="smallthinker")])
+def test_router_and_plan_index_nothing_a_scalar_at_a_time(one_chip, as_tpu,
+                                                         text_of, E, k):
+    """Trinity's layer under the block's remat (the sigmoid-and-bias router)
+    and SmallThinker's router with its routine (the softmax one): the router's
+    scope holds its ``top_k``'s sort and no gather and no scatter, no scatter
+    of ``T * E`` scalars is left under any scope or none, and a plan is two
+    sorts of the ``T * k`` pairs (the second carries each pair's padded row as
+    its payload, two arrays as the first) with no gather of ``s32[T * k]``
+    behind them. What is left a scalar at a time are the plans' ``row_pair``
+    and the combine's transposes, over tables with no small axis."""
+    from collections import Counter
+
+    text = text_of(one_chip)
+    assert _scalar_index_ops(text, 8192, E, k) == []
+    ops = Counter((op["scope"], op["op"], op["result"], op["arrays"])
+                  for op in moe_rows_micro.index_ops(text) if not op["loop"]
+                  and (op["op"] == "sort" or op["scope"] == "moe_router"))
+    assert ops == {
+        ("moe_router", "sort", "f32[8192,%d]" % E, 2): 1,
+        ("moe_dispatch", "sort", "s32[%d]" % (8192 * k), 2): 2}, ops
+    # in the parts (the ``while``) too: a part's plan of its own rows counts
+    # them by comparison, with no scatter-add into ``held + 1`` bins
+    assert not [op for op in moe_rows_micro.index_ops(text)
+                if op["op"] == "scatter"]
+
+
+def test_index_ops_reads_a_text_with_the_forms_this_file_left():
+    """``moe_rows_micro.index_ops`` on lines of the kinds a compiled text
+    holds: a scalar gather inside a fusion that carries the scope, a scatter
+    whose fusion carries none, a sort under a ``while``."""
+    text = """%fused_computation.1 (p0: f32[8192,128], p1: s32[65536,2]) -> f32[65536] {
+  %p0 = f32[8192,128]{1,0} parameter(0)
+  %p1 = s32[65536,2]{1,0} parameter(1)
+  ROOT %gather.1 = f32[65536]{0:T(1024)} gather(%p0, %p1), offset_dims={}, collapsed_slice_dims={0,1}, start_index_map={0,1}, index_vector_dim=1, slice_sizes={1,1}
+}
+
+%fused_computation.2 (p0: f32[1048576], p1: s32[8192,8,1], p2: f32[8192,8]) -> f32[1048576] {
+  %p0 = f32[1048576]{0} parameter(0)
+  %p1 = s32[8192,8,1]{2,1,0} parameter(1)
+  %p2 = f32[8192,8]{1,0} parameter(2)
+  ROOT %scatter.6 = f32[1048576]{0:T(1024)S(1)} scatter(%p0, %p1, %p2), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=2, to_apply=%region_58.158
+}
+
+ENTRY %main (a: f32[8192,128], b: s32[65536,2]) -> f32[65536] {
+  %a = f32[8192,128]{1,0} parameter(0)
+  %fusion.51 = f32[65536]{0:T(1024)S(1)} fusion(%a, %b), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(f)/Block/moe/moe_router/jit(take_along_axis)/gather" stack_frame_id=4}
+  %fusion.52 = f32[1048576]{0:T(1024)} fusion(%z, %i, %u), kind=kCustom, calls=%fused_computation.2
+  %sort.153 = (s32[16384]{0:T(1024)}, s32[16384]{0:T(1024)S(1)}) sort(%keys, %iota.263), dimensions={0}, is_stable=true, to_apply=%region_33.93, metadata={op_name="jit(f)/Block/moe/cond/branch_0_fun/while/body/closed_call/moe_dispatch/sort" stack_frame_id=17}
+  %gather.9 = bf16[8192,2048]{1,0:T(8,128)(2,1)} gather(%x_pad, %rows), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,2048}, metadata={op_name="jit(f)/Block/mlp/take" stack_frame_id=9}
+}
+"""
+    assert moe_rows_micro.index_ops(text) == [
+        dict(op="gather", result="f32[65536]", indices=65536, each=1,
+             arrays=1, scope="moe_router", loop=False),
+        dict(op="scatter", result="f32[1048576]", indices=65536, each=1,
+             arrays=1, scope=None, loop=False),
+        dict(op="sort", result="s32[16384]", indices=16384, each=1, arrays=2,
+             scope="moe_dispatch", loop=True),
+        dict(op="gather", result="bf16[8192,2048]", indices=8192, each=2048,
+             arrays=1, scope="other", loop=False)]
+
+
 @pytest.mark.parametrize("d,P,parts", [(2048, 18432, 1), (2560, 26624, 2),
                                        (2560, 22912, 1), (2560, 23040, 2)])
 def test_choice_sum_source_is_placed_in_vmem(one_chip, d, P, parts):
@@ -839,6 +924,7 @@ def test_smallthinker_share_step_fits_the_chip(one_chip, as_tpu):
                                                        rel=1e-3)
     assert held < 16.0e9, held
     text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 64, 6) == []
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in ("flash_fwd_window", "flash_bwd_window_dq",
@@ -889,6 +975,7 @@ def test_glm47_share_step_fits_the_chip(one_chip, as_tpu):
                                                        rel=1e-3)
     assert held < 16.0e9, held
     text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 64, 4) == []
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
@@ -1078,6 +1165,7 @@ def test_nemotron_share_step_fits_the_chip(one_chip, as_tpu):
                                                        rel=1e-3)
     assert held < 16.0e9, held
     text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 128, 6) == []
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     assert calls["ssd_bwd"] == 4 and calls["ssd_fwd"] >= 4, calls
@@ -1134,6 +1222,7 @@ def test_lfm2_share_step_fits_the_chip(one_chip, as_tpu):
                                                        rel=1e-3)
     assert held < 16.0e9, held
     text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 32, 4) == []
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
     for name in fa.ONLINE_KERNELS:

@@ -181,6 +181,96 @@ def test_router_on_planted_near_ties_is_the_references():
         route.load, np.bincount(np.asarray(chosen).reshape(-1), minlength=E))
 
 
+#: ``(k, E, held)`` of the five expert cells: Trinity, SmallThinker, GLM,
+#: Nemotron, LFM2
+CELLS = pytest.mark.parametrize("k,E,held", [
+    (8, 128, 16), (6, 64, 16), (4, 64, 8), (6, 128, 8), (4, 32, 8)])
+
+
+def _router_inputs(routing, E, T=256, d=32, seed=7):
+    """``(tokens, kernel)``: a random router; one whose logits put every token
+    on expert 0; one with exact ties (every second column of the kernel a copy
+    of the one before it, a sixteenth of the tokens zero: all logits 0)."""
+    keys = jax.random.split(jax.random.key(seed), 2)
+    tokens = jax.random.normal(keys[0], (T, d))
+    kernel = 0.3 * jax.random.normal(keys[1], (d, E))
+    if routing == "collapsed":
+        tokens = tokens.at[:, 0].set(9.0)
+        kernel = kernel.at[0].set(0.0).at[0, 0].set(9.0)
+    elif routing == "ties":
+        kernel = kernel.at[:, 1::2].set(kernel[:, 0::2])
+        tokens = tokens.at[::16].set(0.0)
+    return tokens, kernel
+
+
+@pytest.mark.parametrize("routing", ["random", "collapsed", "ties"])
+@CELLS
+def test_softmax_router_counts_what_bincount_counted(k, E, held, routing):
+    """The router's ``load`` is ``bincount``'s integers (a one-hot summed over
+    tokens and choices since PR 49, where a scatter-add of a scalar a pair
+    was), ``chosen`` and the weights what they were, op by op and under one
+    ``jit``; the kernel's and the tokens' gradients within 1e-6."""
+    tokens, kernel = _router_inputs(routing, E)
+
+    def counted(tokens, kernel, k):          # the router before PR 49
+        top, chosen = jax.lax.top_k(moe_lib._scores(tokens, kernel), k)
+        weights = jax.nn.softmax(top, axis=-1)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return moe_lib.Route(chosen, weights, jnp.bincount(
+            chosen.reshape(-1), length=kernel.shape[1]))
+
+    with HIGHEST:
+        want = counted(tokens, kernel, k)
+        got = moe_lib.route_softmax_chosen(tokens, kernel, k)
+        jitted = jax.jit(moe_lib.route_softmax_chosen, static_argnums=2)(
+            tokens, kernel, k)
+    for route in (got, jitted):
+        np.testing.assert_array_equal(route.chosen, want.chosen)
+        np.testing.assert_array_equal(route.load, want.load)
+        assert route.load.dtype == want.load.dtype == jnp.int32
+    np.testing.assert_array_equal(got.weights, want.weights)
+    np.testing.assert_allclose(jitted.weights, want.weights, rtol=3e-7)
+    assert int(want.load.sum()) == tokens.shape[0] * k
+    if routing == "collapsed":
+        assert int(want.load[0]) == tokens.shape[0]
+    if routing == "ties":       # ties at the boundary of the choice exist
+        with HIGHEST:
+            ranked = np.sort(np.asarray(moe_lib._scores(tokens, kernel)),
+                             -1)[:, ::-1]
+        assert np.sum(ranked[:, k - 1] == ranked[:, k]) >= tokens.shape[0] // 16
+
+    mix = jax.random.normal(jax.random.key(9), (tokens.shape[0], k))
+    grads = lambda route: jax.grad(lambda t, w: jnp.sum(jnp.sin(
+        route(t, w, k).weights * mix)), (0, 1))(tokens, kernel)
+    with HIGHEST:
+        want_g, got_g = grads(counted), grads(moe_lib.route_softmax_chosen)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(w))) + 1e-30)
+
+
+@pytest.mark.parametrize("first", [0, 8])
+@CELLS
+def test_held_counts_of_a_part_are_bincounts(k, E, held, first):
+    """``_held_counts`` (what a part of the bounded layout's second way counts
+    of its own rows, ``_plan`` without the router's counts) over keys that
+    hold ``held`` for another chip's expert: ``bincount``'s integers less its
+    last bin, for a level router's part and for one that sends every token to
+    the held experts."""
+    scores = np.random.RandomState(3).rand(128, E)
+    for lift in (0.0, 1.0):
+        scores[:, first:first + held] += lift
+        chosen = jnp.asarray(np.argsort(-scores, -1)[:, :k].astype(np.int32))
+        key = moe_lib._held_keys(chosen, first, held)
+        want = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        for count in (moe_lib._held_counts, jax.jit(moe_lib._held_counts,
+                                                    static_argnums=1)):
+            got = count(key, held)
+            np.testing.assert_array_equal(got, want)
+            assert got.dtype == jnp.int32 and got.shape == (held,)
+    assert int(want.sum()) == 128 * k          # collapsed: every pair is held
+
+
 # -- the held experts' layer -------------------------------------------------------
 
 
