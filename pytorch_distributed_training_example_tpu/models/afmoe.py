@@ -63,7 +63,9 @@ class GatedAttention(nn.Module):
     otherwise rotary positions and the window mask. The ``lfm2_moe`` models
     run the same q/k norms and rotary positions with neither a gate nor a
     window: ``gated`` False drops ``W_g`` and the sigmoid, ``rotary`` says
-    where the positions go (None: where there is a window)."""
+    where the positions go (None: where there is a window). The ``qwen3_next``
+    models rotate the first ``rotary_dim`` columns of a head alone
+    (rotate-half within them; None: the whole head)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -75,6 +77,7 @@ class GatedAttention(nn.Module):
     attn_impl: str = "auto"
     gated: bool = True
     rotary: bool | None = None
+    rotary_dim: int | None = None
 
     @nn.compact
     def __call__(self, h):
@@ -92,8 +95,16 @@ class GatedAttention(nn.Module):
             rotary = self.window is not None
         if rotary:
             positions = jnp.arange(h.shape[1])[None, :]
-            q = llama.rope(q, positions, self.rope_theta)
-            k = llama.rope(k, positions, self.rope_theta)
+            cut = self.rotary_dim
+
+            def turn(x):
+                if cut is None:
+                    return llama.rope(x, positions, self.rope_theta)
+                return jnp.concatenate(
+                    [llama.rope(x[..., :cut], positions, self.rope_theta),
+                     x[..., cut:]], axis=-1)
+
+            q, k = turn(q), turn(k)
         q = mesh_lib.constrain(q, llama._seq_rule("qkv"))
         k = mesh_lib.constrain(k, llama._seq_rule("qkv"))
         v = mesh_lib.constrain(v, llama._seq_rule("qkv"))

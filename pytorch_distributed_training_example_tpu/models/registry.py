@@ -33,7 +33,12 @@ published sizes, ``nemotron3_nano_share`` one chip's share of it,
 four beside q/k-normed rotary GQA, over a sigmoid-and-bias routed mixture
 with no shared expert and a tied embedding: ``lfm2_8b_a1b`` at its published
 sizes, ``lfm2_8b_a1b_share`` one chip's share of it, ``lfm2_moe_tiny`` for
-tests; training only, ``dp``/``fsdp`` only).
+tests; training only, ``dp``/``fsdp`` only) and the ``qwen3_next`` family (a
+gated delta rule in three layers of four beside gated GQA with a quarter of
+the head rotary, every layer over a softmax-routed mixture of 512 experts
+with a sigmoid-gated shared expert: ``qwen3_next_80b`` at its published
+sizes, ``qwen3_next_80b_share`` one chip's share of it, ``qwen3_next_tiny``
+for tests; training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -260,7 +265,7 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
 def _held_experts_family(name, make):
     """Registry builder for a family whose expert layers are told which
     experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``,
-    ``glm_moe_lite``, ``nemotron_h``, ``lfm2_moe``):
+    ``glm_moe_lite``, ``nemotron_h``, ``lfm2_moe``, ``qwen3_next``):
     ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
     Granite hybrid: the expert layer has no exchange, and there is no
     tensor-parallel rule table."""
@@ -341,6 +346,18 @@ _REGISTRY["lfm2_8b_a1b_share"] = _held_experts_family(
     "lfm2_moe", lambda m, **kw: m.chip_share(m.lfm2_8b_a1b(**kw)))
 _REGISTRY["lfm2_moe_tiny"] = _held_experts_family(
     "lfm2_moe", lambda m, **kw: m.lfm2_moe_tiny(**kw))
+
+
+# The published Qwen3-Next-80B-A3B; one chip's share of it (a sixteenth of
+# every layer's routed experts, an eighth of the vocabulary, the published
+# layers 0..3: what the one-chip benchmark cell trains); and a toy for the
+# tests.
+_REGISTRY["qwen3_next_80b"] = _held_experts_family(
+    "qwen3_next", lambda m, **kw: m.qwen3_next_80b(**kw))
+_REGISTRY["qwen3_next_80b_share"] = _held_experts_family(
+    "qwen3_next", lambda m, **kw: m.chip_share(m.qwen3_next_80b(**kw)))
+_REGISTRY["qwen3_next_tiny"] = _held_experts_family(
+    "qwen3_next", lambda m, **kw: m.qwen3_next_tiny(**kw))
 
 
 @register("resnet_micro")

@@ -42,6 +42,10 @@ and writes its results once, or the ``jax.numpy`` bodies
 gated short convolution of the ``lfm2_moe`` models, ``C * conv(B * x)`` with
 no activation, as ``jax.numpy`` alone.
 
+:func:`norm_gate` is the ``qwen3_next`` models' order of the output stage,
+``group_rms_norm(y) * silu(z)`` (norm first, gate after), as ``jax.numpy``
+alone; their delta rule is ``ops/gated_delta.py``.
+
 No packed documents (no state or mask resets) and no recurrent-state cache
 for serving: one document a sequence.
 """
@@ -1116,6 +1120,19 @@ def group_rms_norm(x: jax.Array, scale: jax.Array, groups: int,
     norm = x32 * jax.lax.rsqrt(
         jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + epsilon)
     return (norm.reshape(x.shape) * scale.astype(F32)).astype(dtype)
+
+
+def norm_gate(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
+              epsilon: float, dtype) -> jax.Array:
+    """``group_rms_norm(y) * silu(z)``: the other order of the two steps that
+    :func:`gate_norm` takes (Mamba-2 gates and then norms; the ``qwen3_next``
+    models' delta-rule mixer norms a head and gates after). ``y`` [b, S, C]
+    float32 as accumulated, ``z`` [b, S, C] in the compute dtype, ``scale``
+    one vector of ``C / groups`` for every group; float32 throughout,
+    ``dtype`` out. ``jax.numpy`` that XLA fuses and differentiates: the
+    kernel pair is :func:`gate_norm`'s order alone."""
+    normed = group_rms_norm(y, jnp.tile(scale, groups), groups, epsilon, F32)
+    return (normed * jax.nn.silu(z.astype(F32))).astype(dtype)
 
 
 def _gated_groups(y_ref, z_ref, width):

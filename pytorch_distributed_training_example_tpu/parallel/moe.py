@@ -3,12 +3,14 @@
 Two halves that a block calls where its model says: *a router* (scores to the
 chosen experts, their weights and every expert's count: ``route_sigmoid_bias``
 for the ``afmoe``, ``glm_moe_lite``, ``nemotron_h`` and ``lfm2_moe`` families,
-:class:`TopKSoftmaxRouter` for ``smallthinker``) and *the held experts'
-routine* (``_held_sum`` over ``(tokens, chosen, weights, counts)``:
+:class:`TopKSoftmaxRouter` for ``smallthinker`` and ``qwen3_next``) and *the
+held experts' routine* (``_held_sum`` over ``(tokens, chosen, weights, counts)``:
 ``_routed`` / ``_routed_bounded`` through ``ops/grouped_matmul.py``'s FFN,
 with the telemetry). :class:`SharedExpertMoE` is both in one module with a
 shared expert beside them; :class:`HeldExperts` is the routine alone, for a
-block that routes on another tensor than the experts read.
+block that routes on another tensor than the experts read (``smallthinker``)
+or gates its shared expert (``qwen3_next``: ``sigmoid(x w_sg)``, one scalar a
+token, in the block).
 
 The layer routes over all the experts and computes the part of the sum that
 the held ones give: one chip's share. Dropless, whatever the imbalance; what
@@ -636,11 +638,13 @@ class HeldExperts(nn.Module):
 
     Sows into ``telemetry`` as :class:`SharedExpertMoE` does:
     ``moe_held_rows``, ``moe_held_peak``, ``moe_whole``,
-    ``moe_source_parts``, and ``moe_gate_zero`` (the share of the held rows'
-    gate activations that ``relu`` zeroes: what a kernel that skipped them
-    would have to gain from; NaN where the rows did not fit the layout
-    whole). The last costs the plan and the gate projection once more, and is
-    computed only in a run that collects ``telemetry``."""
+    ``moe_source_parts``, and under a ``relu`` gate ``moe_gate_zero`` (the
+    share of the held rows' gate activations that ``relu`` zeroes: what a
+    kernel that skipped them would have to gain from; NaN where the rows did
+    not fit the layout whole; a ``silu`` gate zeroes nothing, and the
+    ``qwen3_next`` models' layers sow no such key). The last costs the plan
+    and the gate projection once more, and is computed only in a run that
+    collects ``telemetry``."""
     ffn_dim: int
     held_experts: tuple | None = None   # (how many, starting where)
     act: str = "relu"                   # a key of ops.grouped_matmul.GATES
@@ -655,7 +659,7 @@ class HeldExperts(nn.Module):
         sown = dict(moe_held_rows=jnp.sum(rows),
                     moe_held_peak=_held_peak(rows), moe_whole=held.whole,
                     moe_source_parts=jnp.float32(held.parts))
-        if self.is_mutable_collection("telemetry"):
+        if self.act == "relu" and self.is_mutable_collection("telemetry"):
             sown["moe_gate_zero"] = _gate_zero_share(route.chosen, held)
         _sow_telemetry(self, **sown)
         return out.reshape(x.shape).astype(self.dtype)

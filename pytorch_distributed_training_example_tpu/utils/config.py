@@ -321,6 +321,18 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # One chip's share of Qwen3-Next-80B-A3B (models/qwen3_next.py): a
+    # sixteenth of each layer's 512 experts, an eighth of the vocabulary, the
+    # published layers 0..3: three gated delta rules (a 128 x 128 state a
+    # head, 128 chunks of 64) to one layer of gated GQA 16/2 at head 256, one
+    # 8k sequence a chip per micro-step. (The whole model is 80 B parameters:
+    # no preset of this repo's one-host recipes holds it.)
+    "qwen3_next_80b_share": dict(
+        model="qwen3_next_80b_share", dataset="lm", seq_len=8192, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -204,10 +204,13 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: [rows, cols] tile of a program, or ``"xla"``); ``gmm_plan`` likewise, once
 #: a traced call of a kernel of ``ops.grouped_matmul`` (``value``: kernel,
 #: form (``gated`` / ``plain``), rows, d, f, bt, the column block, how many
-#: blocks, and the bytes of VMEM the kernel's blocks take).
+#: blocks, and the bytes of VMEM the kernel's blocks take);
+#: ``delta_rule_plan`` likewise, once a traced call of
+#: ``ops.gated_delta.gated_delta_rule`` (``value``: key and value heads and
+#: their widths, the chunk, the chunks, and ``"xla"`` for the scan's body).
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
                    "cache_retrieval", "cache_miss", "flash_schedule",
-                   "ssd_plan", "mixer_plan", "gmm_plan")
+                   "ssd_plan", "mixer_plan", "gmm_plan", "delta_rule_plan")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

@@ -35,28 +35,37 @@ xcache.place_compile_cache(min_compile_secs=0.5)
 APPENDED_LATER = {
     "test_granite_cells.py::test_manifest_gained_one_cell_and_three_metrics":
         {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
-         "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384"},
+         "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
+         "qwen3_next_80b.b1.s8192.v18992"},
     "test_trinity_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
         {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
-         "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384"},
+         "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
+         "qwen3_next_80b.b1.s8192.v18992"},
     "test_smallthinker_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
         {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384",
-         "lfm2_8b_a1b.b1.s8192.v16384"},
+         "lfm2_8b_a1b.b1.s8192.v16384",
+         "qwen3_next_80b.b1.s8192.v18992"},
     # PR 39's eight start-up entries were the tail until PR 41 appended
     "test_setup_readers.py::"
     "test_the_manifest_gained_eight_entries_that_move_setup_s":
         {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384",
-         "lfm2_8b_a1b.b1.s8192.v16384"},
+         "lfm2_8b_a1b.b1.s8192.v16384",
+         "qwen3_next_80b.b1.s8192.v18992"},
     # PR 41's six entries were the tail until PR 43 appended
     "test_glm47_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
-        {"nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384"},
+        {"nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
+         "qwen3_next_80b.b1.s8192.v18992"},
     # PR 43's eight entries were the tail until PR 47 appended
     "test_nemotron_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics":
-        {"lfm2_8b_a1b.b1.s8192.v16384"},
+        {"lfm2_8b_a1b.b1.s8192.v16384", "qwen3_next_80b.b1.s8192.v18992"},
+    # PR 47's eight entries were the tail until PR 50 appended
+    "test_lfm2_cells.py::"
+    "test_manifest_gained_one_configuration_one_cell_and_eight_metrics":
+        {"qwen3_next_80b.b1.s8192.v18992"},
 }
 
 
@@ -77,6 +86,8 @@ TAIL_READERS = (
     "test_glm47_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics",
     "test_nemotron_cells.py::"
+    "test_manifest_gained_one_configuration_one_cell_and_eight_metrics",
+    "test_lfm2_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics",
 )
 

@@ -1236,3 +1236,83 @@ def test_lfm2_share_step_fits_the_chip(one_chip, as_tpu):
     for scope in ("short_conv", "conv_gate", "in_proj", "out_proj",
                   "moe_router", "moe_experts"):
         assert f"/{scope}/" in text, scope
+
+
+# -- Qwen3-Next-80B-A3B's share (models/qwen3_next.py): the chunked gated delta
+# -- rule as XLA's scan, causal GQA 16/2 at a head of 256 over 8,192 positions
+# -- of which a quarter of the head is rotated, the step
+
+
+def test_flash_compiles_at_qwen3_next_widths(one_chip):
+    """B1 S8192 H16/2 D256, causal, no window: eight query heads a key head at
+    GLM's head width; no causal, one-shot or streaming plan reaches S=8192 at
+    this width, so the three online kernels under the causal schedule."""
+    q = _sds((1, 8192, 16, 256), one_chip)
+    kv = _sds((1, 8192, 2, 256), one_chip)
+    text = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True)), q, kv, kv)
+    for name in fa.ONLINE_KERNELS:
+        assert name in text, name
+    for other in ("flash_fwd_window", "flash_fwd_causal", "flash_bwd_causal",
+                  "flash_bwd_oneshot"):
+        assert other not in text, other
+    plans = [fa.online_schedule(name, True, 8192, 8192, *fa._online_blocks(
+        name != "flash_fwd_online", 8192, 256, 1024, 1024, 2))
+        for name in fa.ONLINE_KERNELS]
+    assert all(p.walk and p.split for p in plans)
+
+
+def test_gated_delta_rule_compiles_at_published_widths(one_chip):
+    """The chunked rule and its transpose at 1 x 8192, 16 key heads to 32
+    value heads of 128, chunk 64, bf16: XLA's own program (no Pallas call),
+    the 128 chunks under ``while`` loops and not unrolled, the chunk's solve
+    without a triangular-solve call."""
+    from pytorch_distributed_training_example_tpu.ops import gated_delta
+
+    qk = _sds((1, 8192, 16, 128), one_chip)
+    v = _sds((1, 8192, 32, 128), one_chip)
+    gates = _sds((1, 8192, 32), one_chip, jnp.float32)
+    text = jax.jit(jax.grad(
+        lambda *a: gated_delta.gated_delta_rule(*a).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(qk, qk, v, gates,
+                                        gates).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert 2 <= text.count(" while(") <= 4, text.count(" while(")
+    assert "triangular-solve" not in text
+    assert "f32[128,1,16,2,128,128]" in text       # the states a chunk starts from
+
+
+@pytest.mark.slow  # two minutes of the TPU compiler on every core, as Trinity's
+def test_qwen3_next_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``qwen3_next_80b_share`` at 1 x 8192, bf16,
+    per-block remat, AdamW) compiles for a described v5e under the chip's
+    memory: 15.23 GB, with the rule checkpointed inside its block (16.95
+    without: its chunk tensors then live beside the expert layer's); four
+    layers: three conv kernels each way, the online flash kernels once each,
+    the four expert layers' gated-FFN kernels, no Pallas call under
+    ``delta_rule``, and nothing in the router or the plan at E = 512, k = 10
+    that indexes a scalar at a time."""
+    import re
+    from collections import Counter
+
+    compiled, mem, held = _share_step("qwen3_next_80b_share", one_chip)
+    assert mem.argument_size_in_bytes == pytest.approx(625_667_136 * 12,
+                                                       rel=1e-3)
+    assert held < 16.0e9, held
+    text = compiled.as_text()
+    assert _scalar_index_ops(text, 8192, 512, 10) == []
+    calls = Counter(m.group(1) for m in re.finditer(
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
+    for name in fa.ONLINE_KERNELS:
+        assert calls[name] == 1, calls
+    assert calls["conv_silu_fwd"] == calls["conv_silu_bwd"] == 3, calls
+    assert calls["gated_ffn_up"] == 4 * 4, calls      # as SmallThinker's
+    assert calls["gated_ffn_down"] == 4 * 4, calls
+    assert all(calls[name] == 4 * 2 for name in _GATED_BACKWARD), calls
+    assert not calls["grouped_matmul"] + calls["grouped_matmul_dw"], calls
+    assert not [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "delta_rule" in line]
+    for scope in ("gated_delta_net", "delta_rule", "conv_silu", "gate_norm",
+                  "in_proj", "out_proj", "moe_router", "moe_experts",
+                  "moe_shared"):
+        assert f"/{scope}/" in text, scope

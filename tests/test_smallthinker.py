@@ -670,7 +670,7 @@ def _numpy_plan(chosen, first, held, bt, G):
 @pytest.mark.parametrize("routing", ["level", "collapsed", "absent"])
 @pytest.mark.parametrize("preset", [
     "trinity_mini_share", "smallthinker_21b_share", "glm47_flash_share",
-    "nemotron3_nano_share", "lfm2_8b_a1b_share"])
+    "nemotron3_nano_share", "lfm2_8b_a1b_share", "qwen3_next_80b_share"])
 def test_plan_at_the_cells_sizes_is_the_written_out_layout(preset, routing):
     """``_held_counts`` and ``_plan`` against a numpy layout at each expert
     cell's ``(experts, k, held, tile rows)`` and 8,192 tokens: level routing
