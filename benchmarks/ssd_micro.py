@@ -63,9 +63,10 @@ def inputs(b, S, H, P, N, seed=0):
     return args, jax.random.normal(k[6], (b, S, H, P))
 
 
-def device_ms(fn, args, reps):
-    """(device ms of one run of ``jit(fn)``, {ssd_* kernel: ms a run}, the
-    five longest other operations as {name: ms a run})."""
+def device_ms(fn, args, reps, kernels_named=KERNELS):
+    """(device ms of one run of ``jit(fn)``, {kernel: ms a run} for the
+    operations whose names start with ``kernels_named``, the five longest
+    other operations as {name: ms a run})."""
     import jax
 
     from profile_step import collect_ops
@@ -84,7 +85,7 @@ def device_ms(fn, args, reps):
     kernels, others = {}, {}
     for event, (ns, _) in ops.items():
         name = event.split(" = ", 1)[0].strip().lstrip("%")
-        into = kernels if name.startswith(KERNELS) else others
+        into = kernels if name.startswith(kernels_named) else others
         name = name.split(".")[0] if into is kernels else name
         into[name] = into.get(name, 0.0) + ns / reps / 1e6
     top = dict(sorted(others.items(), key=lambda kv: -kv[1])[:5])

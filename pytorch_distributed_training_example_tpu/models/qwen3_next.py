@@ -66,7 +66,6 @@ module: the published ``config.json`` has no key for one.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any
 
@@ -135,14 +134,14 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
             g = -jnp.exp(A_log) * jax.nn.softplus(
                 ba[..., Hv:].astype(f32) + dt_bias)
-            # checkpointed once more inside the block's remat: the rule's
-            # chunk tensors (1.7 GB a layer at the published widths) then
-            # live only while its own transpose runs, not beside the expert
-            # layer's residuals (the share's step: 15.23 GB so, 16.95 without)
-            o = jax.checkpoint(functools.partial(
-                gated_delta.gated_delta_rule, chunk=self.chunk))(
-                    q.astype(self.dtype), k.astype(self.dtype),
-                    v.reshape(b, S, Hv, Dv), g, beta)
+            # one call, no flag: the rule picks its body from the shapes (the
+            # kernel pair at the published widths, whose residuals beside the
+            # block's remat are its inputs and the chunks' start states; its
+            # own checkpoint only around the XLA body, whose chunk tensors
+            # would else live beside the expert layer's residuals)
+            o = gated_delta.gated_delta_rule(
+                q.astype(self.dtype), k.astype(self.dtype),
+                v.reshape(b, S, Hv, Dv), g, beta, chunk=self.chunk)
         moe_lib._sow_telemetry(self, gdn_decay=jnp.mean(jnp.exp(g)))
         with jax.named_scope("gate_norm"):
             scale = self.param("norm_scale", nn.initializers.ones, (Dv,),

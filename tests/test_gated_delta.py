@@ -4,7 +4,8 @@ with every gradient (bf16 and float32, sequences that are no multiple of the
 chunk, chunks of 16 and 64, a padded step), the chunk's triangular solve and
 its hand-written transpose, the rule's plan record, ``ops/ssd.norm_gate``
 against its two lines, and ``afmoe.GatedAttention``'s rotary slice. Float32 on
-the CPU at toy widths."""
+the CPU at toy widths (the XLA body); the Pallas kernel pair interpreted at
+lane-wide heads against the same loop, recurrence and body."""
 
 import jax
 import jax.numpy as jnp
@@ -165,18 +166,23 @@ def test_unit_lower_inverse_is_the_inverse_and_its_gradient():
                                atol=1e-5 * float(jnp.abs(want).max()))
 
 
-def test_delta_rule_plan_record_under_the_span_that_traced():
+@pytest.mark.parametrize("width,body", [
+    (128, {"body": "kernel", "key_heads_per_program": 8}),
+    (8, {"body": "xla"})], ids=["published", "narrow"])
+def test_delta_rule_plan_record_under_the_span_that_traced(width, body):
     """One ``delta_rule_plan`` record a traced call, a child of the span open
-    on the tracing thread: what the call was given."""
+    on the tracing thread: what the call was given and which body runs it: the
+    kernels with eight key heads a program at the published widths, XLA's
+    scan at heads that are no whole lane tile."""
     rec = telemetry.recorder()
     mark = len(rec.records())
     shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(s, dtype)
     bf16 = jnp.bfloat16
     with rec.span("trace_here", bucket=None):
         jax.eval_shape(gated_delta.gated_delta_rule,
-                       shape(1, 8192, 16, 128, dtype=bf16),
-                       shape(1, 8192, 16, 128, dtype=bf16),
-                       shape(1, 8192, 32, 128, dtype=bf16),
+                       shape(1, 8192, 16, width, dtype=bf16),
+                       shape(1, 8192, 16, width, dtype=bf16),
+                       shape(1, 8192, 32, width, dtype=bf16),
                        shape(1, 8192, 32), shape(1, 8192, 32))
     new = rec.records()[mark:]
     span = next(r for r in new if r.kind == "span" and r.name == "trace_here")
@@ -184,9 +190,171 @@ def test_delta_rule_plan_record_under_the_span_that_traced():
     assert [r.kind for r in said] == ["compile"]
     assert said[0].parent == span.id and said[0].seconds == 0
     assert said[0].value == {"key_heads": 16, "value_heads": 32,
-                             "key_dim": 128, "value_dim": 128, "chunk": 64,
-                             "chunks": 128, "body": "xla"}
+                             "key_dim": width, "value_dim": width,
+                             "chunk": 64, "chunks": 128, **body}
     assert "delta_rule_plan" in telemetry.COMPILE_RECORDS
+
+
+# -- the Pallas kernel pair, interpreted -----------------------------------------
+
+
+@pytest.fixture
+def uncached():
+    """The persistent compile cache off for a test of the interpreted kernels:
+    their CPU executables are tens of megabytes each, and serialising one for
+    the cache under six workers once took a worker down."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _xla_body(*args, chunk=64):
+    """The rule with the plan refused: today's ``jax.numpy`` body on the same
+    inputs."""
+    from unittest import mock
+    with mock.patch.object(gated_delta, "_kernel_plan", lambda *a: None):
+        return gated_delta.gated_delta_rule(*args, chunk=chunk)
+
+
+@pytest.mark.parametrize("S,Hk,R", [(128, 1, 2), (150, 2, 2), (256, 1, 4)],
+                         ids=lambda v: str(v))
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-6),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["fp32", "bf16"])
+def test_kernels_are_the_recurrence_with_every_gradient(uncached, dtype, tol,
+                                                        S, Hk, R):
+    """The kernel pair at heads of 128 (two to four chunks, one sequence that
+    is no multiple of the chunk, one and two pairs of value heads a key head),
+    at the tolerances the XLA body is held to: ``o`` against the float64 loop,
+    the five gradients against plain AD of the recurrence; and both against
+    the XLA body on the same inputs."""
+    args = _rule_inputs(S, b=1, Hk=Hk, Hv=Hk * R, Dk=128, Dv=128, dtype=dtype)
+    assert gated_delta._kernel_plan(Hk, Hk * R, 128, 128, 64, dtype) == Hk
+    w = jax.random.normal(jax.random.key(9), (1, S, Hk * R, 128))
+    rule = jax.jit(gated_delta.gated_delta_rule)
+    body = jax.jit(_xla_body)
+    loss = lambda rule: lambda *a: jnp.sum(rule(*a) * w)
+    every = (0, 1, 2, 3, 4)
+    with HIGHEST:
+        o = rule(*args)
+        assert o.dtype == jnp.float32 and o.shape == w.shape
+        want = _loop(*args)
+        scale = float(np.abs(want).max())
+        np.testing.assert_allclose(o, want, rtol=0, atol=tol * scale)
+        np.testing.assert_allclose(o, body(*args), rtol=0, atol=tol * scale)
+        got = jax.grad(loss(rule), argnums=every)(*args)
+        ref = jax.grad(loss(_recurrence), argnums=every)(*args)
+        other = jax.grad(loss(body), argnums=every)(*args)
+    for name, a, r, x in zip(("q", "k", "v", "g", "beta"), got, ref, other):
+        assert a.dtype == r.dtype, name
+        a, r, x = (np.asarray(y.astype(jnp.float32)) for y in (a, r, x))
+        top = float(np.abs(r).max())
+        np.testing.assert_allclose(a, r, rtol=0, atol=4 * tol * top,
+                                   err_msg=name)
+        np.testing.assert_allclose(a, x, rtol=0, atol=4 * tol * top,
+                                   err_msg=name + " against the XLA body")
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["fp32", "bf16"])
+def test_kernel_keeps_the_state_each_chunk_started_from(uncached, dtype, tol):
+    """The forward's second output, the backward's residual: the state before
+    chunk ``c`` is the recurrence's after ``64 c`` tokens (zero before the
+    first), float32 whatever the operands."""
+    S, Hk, Hv = 192, 1, 2
+    q, k, v, g, beta = _rule_inputs(S, b=1, Hk=Hk, Hv=Hv, Dk=128, Dv=128,
+                                    dtype=dtype)
+    gamma = jnp.cumsum(g.reshape(1, 3, 64, Hv), axis=2).reshape(g.shape)
+    _, states = gated_delta._fwd_call(
+        q.reshape(1, S, -1), k.reshape(1, S, -1), v.reshape(1, S, -1), gamma,
+        beta, plan=(64, 128, 128, 2, 1))
+    assert states.dtype == jnp.float32 and states.shape == (1, 3, Hv, 128,
+                                                            128)
+    f64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)
+    q, k, v, g, beta = map(f64, (q, k, v, g, beta))
+    state = np.zeros((Hv, 128, 128))
+    for t in range(128):
+        if t % 64 == 0:
+            np.testing.assert_allclose(
+                states[0, t // 64], state, rtol=0,
+                atol=tol * max(np.abs(state).max(), 1e-3))
+        for h in range(Hv):
+            state[h] *= np.exp(g[0, t, h])
+            read = state[h].T @ k[0, t, 0]
+            state[h] += np.outer(k[0, t, 0], beta[0, t, h] * (v[0, t, h]
+                                                              - read))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_in_kernel_solve_is_unit_lower_inverse(uncached, seed):
+    """The kernels' solve for two pairs at once (``_diagonals``,
+    ``_substitute``, ``_from_diagonals``: the diagonal blocks of 16 by forward
+    substitution in diagonal form, the levels at 16 and 32 by halves) against
+    ``_unit_lower_inverse`` on the same ``A``, for both heads of each pair: a
+    kernel of its own around it, interpreted."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Q, pairs = 64, 2
+    key = jax.random.split(jax.random.key(seed), 3)
+    k = jax.random.normal(key[0], (pairs, Q, 128))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = jnp.cumsum(-0.3 * jax.nn.softplus(
+        jax.random.normal(key[1], (pairs, 2, Q))), axis=2)
+    beta = jax.nn.sigmoid(jax.random.normal(key[2], (pairs, 2, Q)))
+
+    def kernel(k_ref, g_ref, b_ref, out_ref, a_ref, diag):
+        rows = lambda ref: [ref[p].reshape(1, 2 * Q) for p in range(pairs)]
+        found = gated_delta._diagonals([k_ref[p] for p in range(pairs)],
+                                       rows(g_ref), rows(b_ref))
+        for p in range(pairs):
+            diag[p] = found[p]
+        diag[...] = gated_delta._substitute(diag[...])
+        row, s, first = gated_delta._pair_grid(Q)
+        for p in range(pairs):
+            col = lambda ref: jnp.where(first, ref[p, 0:1, :].T,
+                                        ref[p, 1:2, :].T)
+            kk = jnp.dot(k_ref[p], k_ref[p].T)
+            D = jnp.exp(jnp.where(row >= s, col(g_ref) - rows(g_ref)[p],
+                                  -jnp.inf))
+            a_ref[p] = jnp.where(row > s, jnp.concatenate([kk, kk], 1) * D
+                                 * col(b_ref), 0.0)
+        for p, inv in enumerate(gated_delta._from_diagonals(
+                [diag[p] for p in range(pairs)],
+                [a_ref[p] for p in range(pairs)])):
+            out_ref[p] = inv
+
+    pair = jax.ShapeDtypeStruct((pairs, Q, 2 * Q), jnp.float32)
+    with HIGHEST:
+        inv, A = pl.pallas_call(
+            kernel, out_shape=(pair, pair), interpret=True,
+            scratch_shapes=[pltpu.VMEM((pairs, gated_delta.BASE, 2 * Q),
+                                       jnp.float32)])(k, g, beta)
+        heads = lambda a: jnp.stack([a[..., :Q], a[..., Q:]])
+        want = gated_delta._unit_lower_inverse(heads(A))
+    assert float(jnp.abs(A).max()) > 0.05
+    np.testing.assert_allclose(heads(inv), want, rtol=0, atol=2e-6)
+    assert not np.asarray(jnp.triu(heads(inv), 1)).any()
+
+
+def test_kernel_plan_admits_what_the_kernels_are_written_for():
+    """Shapes decide the body, nothing else: bf16 or float32, a chunk of 64,
+    an even number of value heads a key head, head widths of whole lane
+    tiles; G the most key heads whose backward program fits VMEM."""
+    plan = gated_delta._kernel_plan
+    assert plan(16, 32, 128, 128, 64, jnp.bfloat16) == 8
+    assert plan(16, 32, 128, 128, 64, jnp.float32) == 8
+    assert plan(2, 4, 128, 256, 64, jnp.bfloat16) == 2
+    assert plan(16, 32, 128, 128, 64, jnp.float16) is None
+    assert plan(16, 32, 128, 128, 32, jnp.bfloat16) is None     # the chunk
+    assert plan(16, 16, 128, 128, 64, jnp.bfloat16) is None     # no pair
+    assert plan(16, 32, 64, 128, 64, jnp.bfloat16) is None      # half a tile
+    assert plan(2, 4, 8, 8, 64, jnp.float32) is None            # the tests'
 
 
 # -- the norm-then-gate stage, the rotary slice ----------------------------------

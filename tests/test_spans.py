@@ -488,12 +488,14 @@ def test_pallas_names_are_one_per_kernel():
     # kernels through one launcher
     assert len(several) == 4 and several[0] == several[1] == several[2]
     names = [s[0] for s in sites if len(s) == 1] + several[0] + several[3]
-    assert len(names) == 26 and len(set(names)) == 26
+    assert len(names) == 28 and len(set(names)) == 28
     assert {n for n in names if n.startswith(("gated_ffn", "grouped_"))} == {
         "grouped_matmul", "grouped_matmul_dw", "gated_ffn_up",
         "gated_ffn_down", "gated_ffn_dh", "gated_ffn_dx", "gated_ffn_dw_up",
         "gated_ffn_dw_down"}
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
+    assert {n for n in names if n.startswith("delta_rule")} == {
+        "delta_rule_fwd", "delta_rule_bwd"}
     assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
         "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd"}
     assert {n for n in names if n.startswith("flash_fwd")} == {
