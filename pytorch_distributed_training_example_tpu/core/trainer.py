@@ -814,7 +814,7 @@ class Trainer:
                             self.state, metrics = self.train_step(self.state,
                                                                   batch)
                     if self.profile_range and gstep + 1 == self.profile_range[1]:
-                        profiled = metrics
+                        profiled = (metrics, batch)
                     tput.update(cfg.global_batch_size)
                     is_log = ((i + 1) % cfg.log_every == 0
                               or i + 1 == self.steps_per_epoch)
@@ -832,7 +832,7 @@ class Trainer:
                             tripped = tele.observe(gstep, {"epoch": epoch, **m})
                             if tripped and cfg.anomaly_action == "rollback":
                                 if profiled is not None:
-                                    self._stop_profile(profiled)
+                                    self._stop_profile(*profiled)
                                 it.close()
                                 i = self._anomaly_rollback(epoch, i)
                                 it = self._make_step_iter(epoch, i)
@@ -879,7 +879,7 @@ class Trainer:
                         self._publish(gstep, epoch, m, dt)
                 if profiled is not None:
                     # after the step marker has closed, so the trace holds it
-                    self._stop_profile(profiled)
+                    self._stop_profile(*profiled)
                 if tele is not None:
                     # Feed the fleet layer every step from the spans just
                     # closed: flight-recorder ring, buffered step rows, live
@@ -897,11 +897,41 @@ class Trainer:
                     self._graceful_shutdown(epoch, i + 1)
                 i += 1
 
-    def _stop_profile(self, metrics):
-        """End the ``--profile-steps`` trace once its last step has run."""
-        jax.tree.map(lambda x: x.block_until_ready(), metrics)
-        jax.profiler.stop_trace()
-        log.info("profile written to %s", self.cfg.profile_dir)
+    def _stop_profile(self, metrics, batch):
+        """End the ``--profile-steps`` trace once its last step has run, and
+        leave the step's map beside it (utils/stepmap.py): which part of the
+        program and which pass each ``fusion.1234`` of the trace is."""
+        with self._span("stop_profile", bucket=None):
+            jax.tree.map(lambda x: x.block_until_ready(), metrics)
+            jax.profiler.stop_trace()
+            log.info("profile written to %s", self.cfg.profile_dir)
+            try:
+                self._write_step_map(batch)
+            except Exception as e:  # noqa: BLE001 - a diagnostic, never fatal
+                log.warning("no step map beside the profile (%s: %s)",
+                            type(e).__name__, e)
+
+    def _write_step_map(self, batch):
+        """The step that just ran, compiled once more for the state and the
+        batch it ran on (an AOT executable has its text already; the
+        persistent cache serves the jitted one), read into ``step_map.json`` in the profile's
+        directory, a line of the log and one ``step_map`` record."""
+        if not distributed.is_main_process():
+            return
+        from pytorch_distributed_training_example_tpu.utils import stepmap
+
+        step = self.train_step
+        if not hasattr(step, "as_text"):
+            step = step.lower(self.state, batch).compile()
+        os.makedirs(self.cfg.profile_dir, exist_ok=True)
+        path = os.path.join(self.cfg.profile_dir, "step_map.json")
+        summary = stepmap.write(step.as_text(), path)
+        self.recorder.compile_event("step_map", 0.0, summary)
+        log.info("step map written to %s: kernel calls by pass %s; "
+                 "instructions by pass %s; %d compiler clones, %d mixed "
+                 "fusions", path, json.dumps(summary["kernel_calls"]),
+                 json.dumps(summary["instructions"]),
+                 summary["compiler_clones"], summary["mixed_fusions"])
 
     def _first_dispatch(self, batch):
         """Run the first step, consulting the persistent executable cache.

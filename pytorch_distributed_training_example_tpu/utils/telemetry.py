@@ -208,9 +208,14 @@ _NESTING_EVENTS = frozenset(e for e, stage in _COMPILE_STAGES.items()
 #: ``delta_rule_plan`` likewise, once a traced call of
 #: ``ops.gated_delta.gated_delta_rule`` (``value``: key and value heads and
 #: their widths, the chunk, the chunks, and ``"xla"`` for the scan's body).
+#: ``step_map`` is a record of what was compiled, with no duration: once a
+#: ``--profile-steps`` run, when its trace has stopped (``value``:
+#: ``utils/stepmap.summary`` of the step's text: instructions and kernel
+#: calls by pass, the compiler's clones, the mixed fusions).
 COMPILE_RECORDS = ("trace", "lower", "compile", "cache_load",
                    "cache_retrieval", "cache_miss", "flash_schedule",
-                   "ssd_plan", "mixer_plan", "gmm_plan", "delta_rule_plan")
+                   "ssd_plan", "mixer_plan", "gmm_plan", "delta_rule_plan",
+                   "step_map")
 #: The backend's share of them: what the watchdog's dump shows.
 BACKEND_RECORDS = ("compile", "cache_load", "cache_miss")
 

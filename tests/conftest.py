@@ -70,12 +70,17 @@ APPENDED_LATER = {
 
 
 #: Per-layer entries appended without a ``workloads`` list (every cell reports
-#: them) are not hidden by that table, which hides by cell. The tests that read
+#: them), or with a list of every cell, are not hidden by that table, which
+#: hides by cell. The tests that read
 #: the manifest's tail are shown the manifest less these, by name.
+STEP_FIVE = ("step_fwd_ms", "step_bwd_ms", "step_recompute_ms",
+             "scope_mixed_pct", "scope_coverage_pct")
 APPENDED_FOR_EVERY_CELL = {
     "setup_preinit_s", "setup_init_s", "setup_between_s",
     "setup_first_step_s", "setup_warmup_s", "setup_trace_lower_s",
     "setup_cache_load_s", "setup_xla_compile_s",
+    # PR 52's five list the eight cells of their day, so no cell hides them
+    *STEP_FIVE,
 }
 TAIL_READERS = (
     "test_granite_cells.py::test_manifest_gained_one_cell_and_three_metrics",
@@ -89,7 +94,17 @@ TAIL_READERS = (
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics",
     "test_lfm2_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics",
+    # PR 50's nine entries were the tail until PR 52 appended
+    "test_qwen3_next_cells.py::"
+    "test_manifest_gained_one_configuration_one_cell_and_nine_metrics",
 )
+#: a tail reader whose own entries are in that table is shown the manifest less
+#: the others (PR 39's eight were every cell's tail until PR 52 appended;
+#: ``context`` reads the manifest under the harness's own ``ROOT``)
+TAIL_READERS_OF_THAT_TABLE = {
+    "test_setup_readers.py::"
+    "test_the_manifest_gained_eight_entries_that_move_setup_s": STEP_FIVE,
+}
 
 
 @pytest.fixture(autouse=True)
@@ -98,19 +113,26 @@ def _manifest_less_the_entries_of_every_cell(request, monkeypatch, tmp_path):
     conftest's autouse fixtures come first), which reads its module's ``ROOT``
     when called: both that and the test module's are pointed at a directory
     that holds the filtered manifest."""
-    if not request.node.nodeid.endswith(TAIL_READERS):
+    hidden = own = next(
+        (names for test, names in TAIL_READERS_OF_THAT_TABLE.items()
+         if request.node.nodeid.endswith(test)), None)
+    if hidden is None and request.node.nodeid.endswith(TAIL_READERS):
+        hidden = APPENDED_FOR_EVERY_CELL
+    if hidden is None:
         return
     import json
 
     with open(os.path.join(request.module.ROOT, "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
     manifest["per_layer"] = [m for m in manifest["per_layer"]
-                             if m["name"] not in APPENDED_FOR_EVERY_CELL]
+                             if m["name"] not in hidden]
     shown = tmp_path / "manifest_less_every_cell"
     shown.mkdir()
     with open(shown / "BENCHMARK.json", "w") as fh:
         json.dump(manifest, fh)
     monkeypatch.setattr(request.module, "ROOT", str(shown))
+    if own is not None:
+        monkeypatch.setattr(request.module.run_lib, "ROOT", str(shown))
     for plugin in request.config.pluginmanager.get_plugins():
         if isinstance(getattr(plugin, "APPENDED_SINCE", None), dict):
             monkeypatch.setattr(plugin, "ROOT", str(shown))

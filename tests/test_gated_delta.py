@@ -205,6 +205,11 @@ def uncached():
     the cache under six workers once took a worker down."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    # A worker that ran ``test_attention.py`` before this file holds that
+    # file's interpreted kernels, and the next one's compile then aborts the
+    # process (PR 52: two whole runs of three lost a worker here): let go of
+    # every executable the process keeps before compiling these.
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     yield
