@@ -14,7 +14,8 @@ matmul precision ``highest`` (relative error, and the norm gap the chip
 benchmark's ``grad_leaf`` is made of).
 
 ``--stages`` times the mixer's two elementwise stages alone instead
-(``ops/ssd.conv_silu``, ``ops/ssd.gate_norm``), each at both cells' shapes,
+(``ops/ssd.conv_silu``, and ``ops/ssd.gate_norm`` or, at the delta-rule cell's
+shape, its other order ``ops/ssd.norm_gate``), each at the three cells' shapes,
 the kernels beside the ``jax.numpy`` forms: ``fwd`` is the call, ``bwd`` every
 cotangent from a given one (for the ``jax.numpy`` form whatever XLA runs for
 that, its forward's share included); ``floor_ms`` is the bytes the stage must
@@ -36,14 +37,16 @@ sys.path[:0] = [_HERE, os.path.dirname(_HERE)]
 
 
 #: What a kernel of ``ops/ssd.py`` is called in a trace.
-KERNELS = ("ssd_", "conv_silu_", "gate_norm_")
+KERNELS = ("ssd_", "conv_silu_", "gate_norm_", "norm_gate_")
 #: The chip benchmark's peaks by ``device_kind``, with their source. A device
 #: that is not there is an error.
 PEAKS = os.path.join(os.path.dirname(_HERE), "chipbench", "peaks.json")
-#: The stages at the two cells' shapes: S, the conv's channels, the inner
-#: channels, the norm's groups.
-STAGE_SHAPES = {"nemotron3_nano": (8192, 6144, 4096, 8),
-                "granite4_h_micro": (4096, 4352, 4096, 1)}
+#: The stages at the cells' shapes: S, the conv's channels, the inner
+#: channels, the norm's groups, and which order of gate and norm the mixer
+#: takes (Qwen3-Next's delta rule: 32 value heads of 128, one scale for all).
+STAGE_SHAPES = {"nemotron3_nano": (8192, 6144, 4096, 8, "gate_norm"),
+                "granite4_h_micro": (4096, 4352, 4096, 1, "gate_norm"),
+                "qwen3_next_80b": (8192, 8192, 4096, 32, "norm_gate")}
 
 
 def inputs(b, S, H, P, N, seed=0):
@@ -164,9 +167,17 @@ def stages(ssd, iters, tiles, seed, check=0):
         peak = json.load(fh)["kinds"][jax.devices()[0].device_kind][
             "hbm_bytes_per_s"]
     conv_body = lambda x, w, c: jax.nn.silu(ssd.causal_conv1d(x, w, c))
-    gate_body = lambda groups, dtype: lambda y, z, g: ssd.group_rms_norm(
-        y * jax.nn.silu(z.astype(f32)), g, groups, 1e-5, dtype)
-    for cell, (S, conv_dim, inner, groups) in STAGE_SHAPES.items():
+    norm_bodies = {
+        "gate_norm": lambda groups, dtype: lambda y, z, g: ssd.group_rms_norm(
+            y * jax.nn.silu(z.astype(f32)), g, groups, 1e-5, dtype),
+        "norm_gate": lambda groups, dtype: lambda y, z, g: (
+            ssd.group_rms_norm(y, jnp.tile(g, groups), groups, 1e-5, f32)
+            * jax.nn.silu(z.astype(f32))).astype(dtype)}
+    for cell, (S, conv_dim, inner, groups, order) in STAGE_SHAPES.items():
+        gate_body = norm_bodies[order]
+        # norm_gate's scale is one vector that every group shares
+        shared = order == "norm_gate"
+        wide = lambda g, n=groups if shared else 1: jnp.tile(g, n)
         k = jax.random.split(jax.random.PRNGKey(seed), 8)
         normal = lambda i, C, dtype: jax.random.normal(k[i], (1, S, C), dtype)
         # channels, bytes an element (fwd, bwd), operands, the cotangent,
@@ -182,16 +193,18 @@ def stages(ssd, iters, tiles, seed, check=0):
                 conv_body, conv_body, ssd.conv_silu,
                 lambda plan: lambda x, w, c: ssd._conv_silu_kernels(
                     x, x, w, c, (plan, 0))),
-            "gate_norm": (
+            order: (
                 inner, (4 + 2 + 2, 4 + 2 + 2 + 4 + 2),
                 (normal(4, inner, f32), normal(5, inner, bf),
-                 1.0 + 0.1 * jax.random.normal(k[6], (inner,))),
+                 1.0 + 0.1 * jax.random.normal(
+                     k[6], (inner // groups if shared else inner,))),
                 normal(7, inner, bf),
                 gate_body(groups, bf), gate_body(groups, f32),
-                lambda *a, groups=groups: ssd.gate_norm(
+                lambda *a, groups=groups, order=order: getattr(ssd, order)(
                     *a, groups=groups, epsilon=1e-5, dtype=bf),
-                lambda plan: lambda *a: ssd._gate_norm_kernels(
-                    *a, (plan, groups, 1e-5, jnp.dtype(bf)))),
+                lambda plan: lambda y, z, g: ssd._gate_norm_kernels(
+                    y, z, None, wide(g),
+                    (order, plan, 0, groups, 1e-5, jnp.dtype(bf)))),
         }
         for stage, (C, per_elem, args, ct, xla, exact, planned,
                     at) in cases.items():
@@ -204,7 +217,7 @@ def stages(ssd, iters, tiles, seed, check=0):
                         "rel_err": dict(zip(("out", "d0", "d1", "d2"),
                                             errors))}), flush=True)
             # a tile's columns divide the channels and hold whole groups
-            width = C // groups if stage == "gate_norm" else 128
+            width = 128 if stage == "conv_silu" else C // groups
             fns.update({"kernels:%dx%d" % t: at(t) for t in tiles
                         if C % t[1] == 0 and t[1] % width == 0})
             for impl, fn in fns.items():
@@ -245,8 +258,8 @@ def main():
                         "this many seeds")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--stages", action="store_true",
-                   help="time conv_silu and gate_norm alone at both cells' "
-                        "shapes instead of the scan")
+                   help="time conv_silu and gate_norm (norm_gate) alone at "
+                        "the cells' shapes instead of the scan")
     p.add_argument("--tiles", default="",
                    help="with --stages: comma-separated ROWSxCOLS tiles to "
                         "try besides the planner's")

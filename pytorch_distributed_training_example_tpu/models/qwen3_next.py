@@ -25,8 +25,9 @@ config does not state is the Gated Delta Networks paper's and the released
   norms (``q`` by ``sqrt`` of its width too), a key head serving
   ``value heads / key heads`` value heads in a row; the gated delta rule
   (``ops/gated_delta.py``); ``y = RMS(o; w) * silu(z)`` per head, norm first
-  and gate after (``ops/ssd.norm_gate``; one ``w`` for all heads); ``W_out
-  y``.
+  and gate after (``ops/ssd.norm_gate``: the kernel pair ``norm_gate_fwd`` /
+  ``norm_gate_bwd`` on the ``[b, S, Hv Dv]`` the rule's kernels write, ``z``
+  read out of ``qkvz``; one ``w`` for all heads); ``W_out y``.
 - ``full_attention``: :class:`models.afmoe.GatedAttention` with rotary
   positions over the first ``partial_rotary_factor * head_dim`` columns of a
   head (``rope_theta``): ``num_attention_heads`` query and
@@ -146,9 +147,12 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gate_norm"):
             scale = self.param("norm_scale", nn.initializers.ones, (Dv,),
                                self.param_dtype)
+            # o and z where they lie: the rule's kernels write [b, S, Hv Dv]
+            # (the two reshapes cancel), and z is qkvz's last V lanes
             y = ssd_lib.norm_gate(o.reshape(b, S, V), qkvz[..., 2 * K + V:],
                                   scale, groups=Hv, epsilon=self.epsilon,
-                                  dtype=self.dtype)
+                                  dtype=self.dtype, source=qkvz,
+                                  offset=2 * K + V)
         return dense(d, "out_proj")(y)
 
 

@@ -43,8 +43,12 @@ gated short convolution of the ``lfm2_moe`` models, ``C * conv(B * x)`` with
 no activation, as ``jax.numpy`` alone.
 
 :func:`norm_gate` is the ``qwen3_next`` models' order of the output stage,
-``group_rms_norm(y) * silu(z)`` (norm first, gate after), as ``jax.numpy``
-alone; their delta rule is ``ops/gated_delta.py``.
+``group_rms_norm(y) * silu(z)`` (norm first, gate after): the same row-tiled
+stage, kernels, specs and ``custom_vjp`` as :func:`gate_norm` with the order a
+static argument that the public function binds (its calls are named
+``norm_gate_fwd`` / ``norm_gate_bwd``), on the ``[b, S, Hv Dv]`` layout that
+the delta rule's kernels (``ops/gated_delta.py``) write, a head a run of
+lanes.
 
 No packed documents (no state or mask resets) and no recurrent-state cache
 for serving: one document a sequence.
@@ -771,7 +775,8 @@ _scan_kernels.defvjp(_scan_fwd, _scan_bwd)
 # ---------------------------------------------------------------------------
 # The mixer's two elementwise stages, each one pass over HBM a direction:
 # :func:`conv_silu` (the causal conv with its bias and silu) and
-# :func:`gate_norm` (the gate with its group norm). A program is one
+# :func:`gate_norm` (the gate with its group norm; :func:`norm_gate` is the
+# same stage with the norm first). A program is one
 # (sequence, column block, row tile); the grid walks a column block's row
 # tiles in order (the conv's backward in reverse), so that the conv's history
 # and every parameter's gradient are carried in VMEM from tile to tile. Both
@@ -797,8 +802,9 @@ STAGE_COLS = 512
 
 
 def _stage_plan(stage, S, C, groups, dtype, K=1):
-    """``(rows, cols)`` of a program's tile of ``stage`` (``conv_silu`` or
-    ``gate_norm``) over ``[S, C]``, or None where the ``jax.numpy`` body runs.
+    """``(rows, cols)`` of a program's tile of ``stage`` (``conv_silu``, or
+    ``gate_norm`` / ``norm_gate``: one tile for both orders) over ``[S, C]``,
+    or None where the ``jax.numpy`` body runs.
 
     Admitted: bf16 or float32 (Mosaic refuses fp16 loads); channels on the
     128-lane tiling, for the norm every group's run of them; a conv of at most
@@ -809,7 +815,8 @@ def _stage_plan(stage, S, C, groups, dtype, K=1):
     follow from :data:`STAGE_TILE` elements a tile, in whole strips (which are
     bf16's sublane tiles): [256, 512] for both stages at
     Nemotron-3-Nano's widths (6144 channels; eight groups of 512), [512, 256]
-    and [32, 4096] at Granite's (4352; one group)."""
+    and [32, 4096] at Granite's (4352; one group), [256, 512] at Qwen3-Next's
+    delta rule (8192 conv channels; 32 heads of 128, four a block)."""
     if dtype not in (jnp.bfloat16, jnp.float32):
         return None
     if C % groups or (C // groups) % LANES:
@@ -982,9 +989,11 @@ def _tiles_in_order(*sliced):
     order. ``sliced``, an operand each where any: whether XLA may fuse what
     makes it into the call's reads. The norm is handed ``z``, a lane slice of
     ``zxbcdt``: fused, the kernel reads it where it lies and no copy of the
-    slice is made. (Not the conv's operands: with a loop in the kernel the
-    chip's compiler fails on a fused operand's staging buffer, so the conv
-    reads its slice of ``zxbcdt`` through its own index maps.)"""
+    slice is made (``norm_gate`` is handed ``qkvz`` itself and the lane where
+    ``z`` starts, and reads it through its index map as the conv does). (Not
+    the conv's operands: with a loop in the kernel the chip's compiler fails
+    on a fused operand's staging buffer, so the conv reads its slice of
+    ``zxbcdt`` through its own index maps.)"""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         allow_input_fusion=list(sliced) or None)
@@ -1122,19 +1131,6 @@ def group_rms_norm(x: jax.Array, scale: jax.Array, groups: int,
     return (norm.reshape(x.shape) * scale.astype(F32)).astype(dtype)
 
 
-def norm_gate(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
-              epsilon: float, dtype) -> jax.Array:
-    """``group_rms_norm(y) * silu(z)``: the other order of the two steps that
-    :func:`gate_norm` takes (Mamba-2 gates and then norms; the ``qwen3_next``
-    models' delta-rule mixer norms a head and gates after). ``y`` [b, S, C]
-    float32 as accumulated, ``z`` [b, S, C] in the compute dtype, ``scale``
-    one vector of ``C / groups`` for every group; float32 throughout,
-    ``dtype`` out. ``jax.numpy`` that XLA fuses and differentiates: the
-    kernel pair is :func:`gate_norm`'s order alone."""
-    normed = group_rms_norm(y, jnp.tile(scale, groups), groups, epsilon, F32)
-    return (normed * jax.nn.silu(z.astype(F32))).astype(dtype)
-
-
 def _gated_groups(y_ref, z_ref, width):
     """``(lanes, y, z)`` in float32 for each group, a run of ``width`` lanes,
     of a program's tile."""
@@ -1143,21 +1139,31 @@ def _gated_groups(y_ref, z_ref, width):
         yield lanes, y_ref[0, :, lanes], z_ref[0, :, lanes].astype(F32)
 
 
-def _gate_norm_fwd_kernel(y_ref, z_ref, scale_ref, o_ref, *, width, epsilon):
+def _gate_norm_fwd_kernel(y_ref, z_ref, scale_ref, o_ref, *, width, epsilon,
+                          norm_first):
     """``y_ref`` [1, rows, cols] float32, ``z_ref`` the same in the compute
-    dtype, ``scale_ref`` [1, cols] float32; a group is ``width`` lanes."""
+    dtype, ``scale_ref`` [1, cols] float32; a group is ``width`` lanes.
+    ``norm_first``: the statistics are ``y``'s and the gate comes after
+    (:func:`norm_gate`), else the gated value's (:func:`gate_norm`)."""
     for lanes, y, z in _gated_groups(y_ref, z_ref, width):
-        g = y * _silu_and_slope(z)[0]
+        gate = _silu_and_slope(z)[0]
+        g = y if norm_first else y * gate
         r = jax.lax.rsqrt(jnp.mean(g * g, axis=1, keepdims=True) + epsilon)
-        o_ref[0, :, lanes] = (g * r * scale_ref[:, lanes]).astype(o_ref.dtype)
+        n = g * r * scale_ref[:, lanes]
+        o_ref[0, :, lanes] = (n * gate if norm_first else n).astype(
+            o_ref.dtype)
 
 
 def _gate_norm_bwd_kernel(y_ref, z_ref, scale_ref, do_ref,
-                          dy_ref, dz_ref, dscale_ref, *, width, epsilon, S):
+                          dy_ref, dz_ref, dscale_ref, *, width, epsilon, S,
+                          norm_first):
     """Recomputes the gate and the statistics. With ``n`` the normed value
     and ``dn = do * scale``: ``dg = r (dn - n mean(dn n))``, ``dy = dg
     silu(z)`` in float32, ``dz = dg y silu'(z)``; ``dscale_ref`` [1, 1, cols]
-    float32 gathers ``do * n`` over a column block's tiles."""
+    float32 gathers ``do * n`` over a column block's tiles. ``norm_first``:
+    ``n = y r`` and the gate multiplies what leaves the norm, so ``do`` meets
+    the norm as ``do silu(z)``: ``dy = dg`` and ``dz = do n scale
+    silu'(z)``."""
     tile = pl.program_id(2)
     rows = y_ref.shape[1]
 
@@ -1171,88 +1177,132 @@ def _gate_norm_bwd_kernel(y_ref, z_ref, scale_ref, do_ref,
         if valid is not None:   # rows past the sequence hold anything
             y, z, do = (jnp.where(valid, v, 0.0) for v in (y, z, do))
         gate, slope = _silu_and_slope(z)
-        g = y * gate
+        g = y if norm_first else y * gate
         r = jax.lax.rsqrt(jnp.mean(g * g, axis=1, keepdims=True) + epsilon)
         n = g * r
-        dscale_ref[0, :, lanes] += jnp.sum(do * n, axis=0, keepdims=True)
-        dn = do * scale_ref[:, lanes]
+        met = do * gate if norm_first else do   # the cotangent of n * scale
+        dscale_ref[0, :, lanes] += jnp.sum(met * n, axis=0, keepdims=True)
+        dn = met * scale_ref[:, lanes]
         dg = r * (dn - n * jnp.mean(dn * n, axis=1, keepdims=True))
-        dy_ref[0, :, lanes] = dg * gate
-        dz_ref[0, :, lanes] = (dg * y * slope).astype(dz_ref.dtype)
+        if norm_first:
+            dy_ref[0, :, lanes] = dg
+            dz_ref[0, :, lanes] = (do * n * scale_ref[:, lanes]
+                                   * slope).astype(dz_ref.dtype)
+        else:
+            dy_ref[0, :, lanes] = dg * gate
+            dz_ref[0, :, lanes] = (dg * y * slope).astype(dz_ref.dtype)
 
 
-def _gate_norm_specs(plan):
-    rows, cols = plan
-    return (pl.BlockSpec((1, rows, cols), lambda i, j, r: (i, r, j)),
-            pl.BlockSpec((1, cols), lambda i, j, r: (0, j)))
+def _gate_norm_specs(plan, offset):
+    """A [rows, cols] tile of an array of the channels alone, the scale's
+    lanes of a column block, and the tile of ``z`` in an array whose lanes
+    from ``offset`` on are ``z`` (the conv's way of reading its source)."""
+    gate, tile = _conv_tiles(plan, offset, lambda r: r)
+    return (tile, pl.BlockSpec((1, plan[1]), lambda i, j, r: (0, j)),
+            gate if offset else tile)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "groups", "epsilon",
-                                             "dtype"))
-def _gate_norm_fwd_call(y, z, scale, *, plan, groups, epsilon, dtype):
+@functools.partial(jax.jit, static_argnames=("stage", "plan", "offset",
+                                             "groups", "epsilon", "dtype"))
+def _gate_norm_fwd_call(y, z, scale, *, stage, plan, offset, groups, epsilon,
+                        dtype):
+    """``z``: the gate's values [b, S, C], or a wider array that holds them
+    from lane ``offset`` on (read there by block: nothing to fuse)."""
     rows, cols = plan
     b, S, C = y.shape
-    tile, lane = _gate_norm_specs(plan)
+    tile, lane, gate = _gate_norm_specs(plan, offset)
     return pl.pallas_call(
         functools.partial(_gate_norm_fwd_kernel, width=C // groups,
-                          epsilon=epsilon),
-        name="gate_norm_fwd",
+                          epsilon=epsilon, norm_first=stage == "norm_gate"),
+        name=stage + "_fwd",
         grid=(b, C // cols, pl.cdiv(S, rows)),
-        in_specs=[tile, tile, lane],
+        in_specs=[tile, gate, lane],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(y.shape, dtype),
-        compiler_params=_tiles_in_order(False, True, False),
+        compiler_params=_tiles_in_order(False, z.shape == y.shape, False),
         interpret=not backend.on_tpu(),
     )(y, z, scale.astype(F32)[None])
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "groups", "epsilon"))
-def _gate_norm_bwd_call(y, z, scale, do, *, plan, groups, epsilon):
-    """``dy`` float32, ``dz`` in ``z.dtype`` and, a sequence, the scale's
-    gradient [1, C] float32."""
+@functools.partial(jax.jit, static_argnames=("stage", "plan", "offset",
+                                             "groups", "epsilon"))
+def _gate_norm_bwd_call(y, z, scale, do, *, stage, plan, offset, groups,
+                        epsilon):
+    """``dy`` float32, ``dz`` [b, S, C] in ``z.dtype`` and, a sequence, the
+    scale's gradient [1, C] float32."""
     rows, cols = plan
     b, S, C = y.shape
-    tile, lane = _gate_norm_specs(plan)
+    tile, lane, gate = _gate_norm_specs(plan, offset)
     return pl.pallas_call(
         functools.partial(_gate_norm_bwd_kernel, width=C // groups,
-                          epsilon=epsilon, S=S),
-        name="gate_norm_bwd",
+                          epsilon=epsilon, S=S,
+                          norm_first=stage == "norm_gate"),
+        name=stage + "_bwd",
         grid=(b, C // cols, pl.cdiv(S, rows)),
-        in_specs=[tile, tile, lane, tile],
+        in_specs=[tile, gate, lane, tile],
         out_specs=(tile, tile, pl.BlockSpec((1, 1, cols),
                                             lambda i, j, r: (i, 0, j))),
         out_shape=(jax.ShapeDtypeStruct(y.shape, F32),
-                   jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct(y.shape, z.dtype),
                    jax.ShapeDtypeStruct((b, 1, C), F32)),
-        compiler_params=_tiles_in_order(False, True, False, False),
+        compiler_params=_tiles_in_order(False, z.shape == y.shape, False,
+                                        False),
         interpret=not backend.on_tpu(),
     )(y, z, scale.astype(F32)[None], do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gate_norm_kernels(y, z, scale, how):
-    """``how``: (plan, groups, epsilon, dtype)."""
-    return _gate_norm_fwd(y, z, scale, how)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gate_norm_kernels(y, z, source, scale, how):
+    """``how``: (stage, plan, offset, groups, epsilon, dtype); the stage's
+    name is the order. ``source`` is None, or an array that holds ``z`` from
+    lane ``offset`` on: the kernels then read ``z`` out of it where it lies,
+    and ``z`` itself is there for its cotangent alone, as the conv's ``x``
+    is."""
+    return _gate_norm_fwd(y, z, source, scale, how)[0]
 
 
-def _gate_norm_fwd(y, z, scale, how):
-    plan, groups, epsilon, dtype = how
+def _gate_norm_fwd(y, z, source, scale, how):
+    stage, plan, offset, groups, epsilon, dtype = how
+    res = (y, z if source is None else source, scale)
     out = _per_device(
-        functools.partial(_gate_norm_fwd_call, plan=plan, groups=groups,
-                          epsilon=epsilon, dtype=dtype),
-        y, z, scale, n_out=None)
-    return out, (y, z, scale)
+        functools.partial(_gate_norm_fwd_call, stage=stage, plan=plan,
+                          offset=offset, groups=groups, epsilon=epsilon,
+                          dtype=dtype), *res, n_out=None)
+    return out, res
 
 
 def _gate_norm_bwd(how, res, do):
-    plan, groups, epsilon, _ = how
+    stage, plan, offset, groups, epsilon, _ = how
     dy, dz, dscale = _per_device(
-        functools.partial(_gate_norm_bwd_call, plan=plan, groups=groups,
-                          epsilon=epsilon), *res, do, n_out=3)
-    return dy, dz, dscale.sum((0, 1)).astype(res[2].dtype)
+        functools.partial(_gate_norm_bwd_call, stage=stage, plan=plan,
+                          offset=offset, groups=groups, epsilon=epsilon),
+        *res, do, n_out=3)
+    return dy, dz, None, dscale.sum((0, 1)).astype(res[2].dtype)
 
 
 _gate_norm_kernels.defvjp(_gate_norm_fwd, _gate_norm_bwd)
+
+
+def _gated_norm(stage, y, z, scale, groups, epsilon, dtype, source=None,
+                offset=0):
+    """The gate and the group norm in the order that ``stage`` names
+    (``gate_norm`` or ``norm_gate``) through the kernel pair, or None where
+    :func:`_stage_plan` refuses the shape and the caller's ``jax.numpy`` body
+    runs. ``scale`` [C]."""
+    b, S, C = y.shape
+    plan = _stage_plan(stage, S, C, groups, z.dtype)
+    if y.dtype != F32 or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        plan = None
+    _say_stage_plan(stage, S, C, groups, plan)
+    if plan is None:
+        return None
+    if source is not None and (source.dtype != z.dtype or offset % plan[1]
+                               or source.shape[:2] != z.shape[:2]):
+        source = None
+    return _gate_norm_kernels(
+        y, z, source, scale,
+        (stage, plan, 0 if source is None else offset, groups,
+         float(epsilon), jnp.dtype(dtype)))
 
 
 def gate_norm(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
@@ -1263,13 +1313,35 @@ def gate_norm(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
     (``dy`` comes back float32, the scale's gradient is summed in float32);
     one pass each way where :func:`_stage_plan` admits the shape, the
     ``jax.numpy`` body where not."""
-    b, S, C = y.shape
-    plan = _stage_plan("gate_norm", S, C, groups, z.dtype)
-    if y.dtype != F32 or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
-        plan = None
-    _say_stage_plan("gate_norm", S, C, groups, plan)
-    if plan is None:
-        return group_rms_norm(y * jax.nn.silu(z.astype(F32)), scale, groups,
-                              epsilon, dtype)
-    return _gate_norm_kernels(y, z, scale,
-                              (plan, groups, float(epsilon), jnp.dtype(dtype)))
+    out = _gated_norm("gate_norm", y, z, scale, groups, epsilon, dtype)
+    if out is None:
+        out = group_rms_norm(y * jax.nn.silu(z.astype(F32)), scale, groups,
+                             epsilon, dtype)
+    return out
+
+
+def norm_gate(y: jax.Array, z: jax.Array, scale: jax.Array, *, groups: int,
+              epsilon: float, dtype, source: jax.Array | None = None,
+              offset: int = 0) -> jax.Array:
+    """``group_rms_norm(y) * silu(z)``: the other order of the two steps that
+    :func:`gate_norm` takes (Mamba-2 gates and then norms; the ``qwen3_next``
+    models' delta-rule mixer norms a head and gates after). ``y`` [b, S, C]
+    float32 as accumulated, ``z`` [b, S, C] in the compute dtype, ``scale``
+    one vector of ``C / groups`` for every group; float32 throughout with the
+    exact sigmoid, ``dtype`` out. :func:`gate_norm`'s kernel pair in this
+    order (``norm_gate_fwd`` / ``norm_gate_bwd``: ``dy`` float32, ``dz`` in
+    ``z``'s dtype, the scale's gradient summed in float32 over rows and
+    groups) where :func:`_stage_plan` admits the shape, the ``jax.numpy`` body
+    where not.
+
+    ``source``, where given, is an array [b, S, W] whose lanes ``offset :
+    offset + C`` are ``z`` (the mixer's ``qkvz``): where ``offset`` falls on a
+    column block's edge the kernels read ``z`` out of it, and the slice that
+    ``z`` is is never made, as for :func:`conv_silu`."""
+    wide = jnp.tile(scale, groups)
+    out = _gated_norm("norm_gate", y, z, wide, groups, epsilon, dtype, source,
+                      offset)
+    if out is None:
+        normed = group_rms_norm(y, wide, groups, epsilon, F32)
+        out = (normed * jax.nn.silu(z.astype(F32))).astype(dtype)
+    return out

@@ -449,6 +449,88 @@ def test_mixer_stage_kernels_compile_at_the_cells_widths(
             S * max(conv_dim, inner) * 2 * 1.1)
 
 
+def _delta_net_args(one_chip, S=8192, K=2048, V=4096):
+    """Shapes of the delta-rule mixer's output stage at the published widths:
+    the rule's float32 ``o`` [1, S, 32 x 128], ``qkvz`` [1, S, 12288] whose
+    last 4,096 lanes are ``z`` (lane 8192 on), one scale of 128."""
+    return (_sds((1, S, V), one_chip, jnp.float32),
+            _sds((1, S, 2 * K + 2 * V), one_chip),
+            _sds((V // 32,), one_chip, jnp.float32))
+
+
+def test_norm_gate_kernels_compile_at_qwen3_next_widths(one_chip, as_tpu):
+    """``gate_norm``'s pair in the other order at S 8192, 32 heads of 128,
+    ``z`` read at lane 8192 of ``qkvz``'s 12,288: a tile of [256, 512] (four
+    heads a block, sixteen blocks in), each kernel in its program by its own
+    name and no ``gate_norm_*`` beside it, the source itself the call's
+    operand (no ``bf16[1,8192,4096]`` slice made of it), and nothing the size
+    of the operands left in HBM beside them."""
+    from pytorch_distributed_training_example_tpu.ops import ssd
+
+    assert ssd._stage_plan("norm_gate", 8192, 4096, 32, BF16) == (256, 512)
+    fn = lambda o, qkvz, scale: ssd.norm_gate(
+        o, qkvz[..., 8192:], scale, groups=32, epsilon=1e-6, dtype=BF16,
+        source=qkvz, offset=8192)
+    args = _delta_net_args(one_chip)
+    forward = jax.jit(fn).lower(*args).compile()
+    text = forward.as_text()
+    assert "%norm_gate_fwd" in text and "%gate_norm_fwd" not in text
+    assert " slice(" not in text
+    assert forward.memory_analysis().temp_size_in_bytes < 2 ** 20
+    total = lambda *a: fn(*a).astype(jnp.float32).sum()
+    backward = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+        *args).compile()
+    text = backward.as_text()
+    assert "%norm_gate_bwd" in text and "%gate_norm_bwd" not in text
+    assert not [line for line in text.splitlines()
+                if " slice(" in line and "bf16[1,8192,4096]" in line]
+    # the cotangent handed in (ones, as the sum's), dz before it is padded
+    # into qkvz's cotangent, and the small sums
+    assert backward.memory_analysis().temp_size_in_bytes < (
+        2 * 8192 * 4096 * 2 * 1.1)
+
+
+def test_delta_rule_output_meets_its_norm_without_a_change_of_layout(
+        one_chip, as_tpu):
+    """One delta-rule mixer's rule and output stage, forward and backward, as
+    ``models/qwen3_next.GatedDeltaNet`` chains them: ``o`` goes from
+    ``delta_rule_fwd`` to ``norm_gate_fwd`` and ``do`` from ``norm_gate_bwd``
+    to ``delta_rule_bwd`` as ``f32[1,8192,4096]``, and no float32 ``copy`` of
+    a ``[..., 32, 128]`` shape (8 heads x 128 lanes a tile where the kernels'
+    layout is 8 tokens x 128 lanes: 134 MB re-laid out each way, which the
+    ``jax.numpy`` norm's reshape cost a layer) is left in the program."""
+    import re
+
+    from pytorch_distributed_training_example_tpu.ops import gated_delta, ssd
+
+    def layer(q, k, v, g, beta, qkvz, scale):
+        o = gated_delta.gated_delta_rule(q, k, v, g, beta)
+        b, S = o.shape[:2]
+        y = ssd.norm_gate(o.reshape(b, S, -1), qkvz[..., 8192:], scale,
+                          groups=32, epsilon=1e-6, dtype=BF16, source=qkvz,
+                          offset=8192)
+        return y.astype(jnp.float32).sum()
+
+    qk = _sds((1, 8192, 16, 128), one_chip)
+    v = _sds((1, 8192, 32, 128), one_chip)
+    gates = _sds((1, 8192, 32), one_chip, jnp.float32)
+    _, qkvz, scale = _delta_net_args(one_chip)
+    text = _compiled_text(jax.value_and_grad(layer, argnums=tuple(range(7))),
+                          qk, qk, v, gates, gates, qkvz, scale)
+    calls = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    assert sorted(calls) == ["delta_rule_bwd", "delta_rule_fwd",
+                             "norm_gate_bwd", "norm_gate_fwd"], calls
+    relaid = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= f32\[[\d,]*32,128\]\S* copy\(", line)]
+    assert not relaid, relaid
+    # what the kernels hand each other is the lane-dense array itself
+    for name in ("norm_gate_fwd", "norm_gate_bwd", "delta_rule_bwd"):
+        line = next(l for l in text.splitlines()
+                    if re.search(rf"%{name}[.\d]* = ", l))
+        assert "f32[1,8192,4096]{2,1,0}" in line.split(
+            "operand_layout_constraints=")[1], name
+
+
 def test_mixer_stages_under_four_device_mesh_compile(topo, one_chip, as_tpu):
     """Four sequences over four chips: both stages go through
     mesh_lib.manual_call with the batch sharded, and the parameters'
@@ -1306,22 +1388,24 @@ def test_gated_delta_rule_xla_body_compiles_at_narrow_heads(one_chip):
 def test_qwen3_next_share_step_fits_the_chip(one_chip, as_tpu):
     """The benchmark cell's step (``qwen3_next_80b_share`` at 1 x 8192, bf16,
     per-block remat, AdamW) compiles for a described v5e under the chip's
-    memory: 15.10 GB, at or under the 15.23 it took while the rule was XLA's
-    scan under a checkpoint of its own; four layers: three conv kernels each
-    way and the delta rule's pair as often (a block's recomputed forward is
-    merged with the step's own where the inputs are the same values, as the
-    conv's is), every Pallas call under ``delta_rule`` one of the pair and no
-    ``while`` left there, the online flash kernels once each, the four expert
-    layers' gated-FFN kernels, and nothing in the router or the plan at
-    E = 512, k = 10 that indexes a scalar at a time."""
+    memory: 14.29 GB, under the 15.10 it took while the head norm and the gate
+    were ``jax.numpy`` on a 4-D view; four layers: three conv kernels each
+    way, the delta rule's pair and the ``norm_gate`` pair as often (a block's
+    recomputed forward is merged with the step's own where the inputs are the
+    same values, as the conv's is) with no float32 ``copy`` of a ``[..., 32,
+    128]`` shape left between them, every Pallas call under ``delta_rule`` one
+    of the pair and no ``while`` left there, the online flash kernels once
+    each, the four expert layers' gated-FFN kernels, and nothing in the router
+    or the plan at E = 512, k = 10 that indexes a scalar at a time."""
     import re
     from collections import Counter
 
     compiled, mem, held = _share_step("qwen3_next_80b_share", one_chip)
     assert mem.argument_size_in_bytes == pytest.approx(625_667_136 * 12,
                                                        rel=1e-3)
-    assert held <= 15.23e9, held
+    assert held <= 14.4e9, held
     text = compiled.as_text()
+    assert not re.findall(r"= f32\[[\d,]*32,128\]\S* copy\(", text)
     assert _scalar_index_ops(text, 8192, 512, 10) == []
     calls = Counter(m.group(1) for m in re.finditer(
         r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
@@ -1333,6 +1417,7 @@ def test_qwen3_next_share_step_fits_the_chip(one_chip, as_tpu):
     assert all(calls[name] == 4 * 2 for name in _GATED_BACKWARD), calls
     assert not calls["grouped_matmul"] + calls["grouped_matmul_dw"], calls
     assert calls["delta_rule_fwd"] == calls["delta_rule_bwd"] == 3, calls
+    assert calls["norm_gate_fwd"] == calls["norm_gate_bwd"] == 3, calls
     under_rule = [line for line in text.splitlines() if "/delta_rule/" in line]
     assert sum("tpu_custom_call" in line for line in under_rule) == 6
     assert not [line for line in under_rule if " while(" in line]

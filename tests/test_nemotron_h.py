@@ -266,11 +266,21 @@ def _gate_norm_body(groups, dtype):
         y * jax.nn.silu(z.astype(jnp.float32)), scale, groups, 1e-5, dtype)
 
 
-def _gate_inputs(S, C, dtype, b=2):
+def _norm_gate_body(groups, dtype):
+    """The other order (``ops/ssd.norm_gate``): a head's norm with the one
+    scale all heads share, the gate after."""
+    return lambda y, z, scale: (ssd_lib.group_rms_norm(
+        y, jnp.tile(scale, groups), groups, 1e-5, jnp.float32)
+        * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+
+
+def _gate_inputs(S, C, dtype, b=2, scale=None):
+    """``((y, z, scale), the result's cotangent)``; ``scale``: its width
+    where it is not one value a channel."""
     k = jax.random.split(jax.random.key(4), 4)
     return ((3.0 * jax.random.normal(k[0], (b, S, C)),
              jax.random.normal(k[1], (b, S, C)).astype(dtype),
-             1.0 + 0.1 * jax.random.normal(k[2], (C,))),
+             1.0 + 0.1 * jax.random.normal(k[2], (scale or C,))),
             jax.random.normal(k[3], (b, S, C)))
 
 
@@ -287,6 +297,24 @@ def small_tiles(monkeypatch):
     monkeypatch.setattr(ssd_lib, "STAGE_COLS", 256)
 
 
+def _stage(order, groups, dtype):
+    """``(the stage in that order, its jax.numpy body, how many groups share
+    one run of the scale)``."""
+    run = lambda *a: getattr(ssd_lib, order)(*a, groups=groups, epsilon=1e-5,
+                                             dtype=dtype)
+    if order == "gate_norm":
+        return run, _gate_norm_body(groups, dtype), 1
+    return run, _norm_gate_body(groups, dtype), groups
+
+
+def _same_leaves(got, want, tol, what):
+    for i, (g, t) in enumerate(zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want))):
+        assert g.shape == t.shape and g.dtype == t.dtype, (what, i)
+    _all_close(jax.tree.map(lambda a: a.astype(jnp.float32), got),
+               jax.tree.map(lambda a: a.astype(jnp.float32), want), tol, what)
+
+
 @pytest.mark.parametrize("S", [64, 80], ids=["tiles", "ragged"])
 @pytest.mark.parametrize("groups,C,cols", [(1, 512, 512), (2, 256, 256),
                                            (8, 1024, 256)],
@@ -294,27 +322,58 @@ def small_tiles(monkeypatch):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 1e-2)],
                          ids=["fp32", "bf16"])
-def test_gate_norm_kernels_are_the_jax_numpy_body(small_tiles, dtype, tol,
-                                                  groups, C, cols, S):
-    """Values, ``dy`` (float32), ``dz`` and ``d scale`` of the kernel pair at
+@pytest.mark.parametrize("order", ["gate_norm", "norm_gate"])
+def test_gate_norm_kernels_are_the_jax_numpy_body(small_tiles, order, dtype,
+                                                  tol, groups, C, cols, S):
+    """Values, ``dy`` (float32), ``dz`` and ``d scale`` of the kernel pair, in
+    both orders (``norm_gate``'s scale one vector that every group shares), at
     two sequences and two or three row tiles (the last one part-filled where
     S is 80), a program holding one group wider than a block, one block of two
-    groups, and two groups of eight, against the gated ``group_rms_norm``
-    differentiated by XLA."""
-    args, w = _gate_inputs(S, C, dtype)
-    assert ssd_lib._stage_plan("gate_norm", S, C, groups, dtype) == (
+    groups, and two groups of eight, against the ``jax.numpy`` body of that
+    order differentiated by XLA."""
+    run, body, sharing = _stage(order, groups, dtype)
+    args, w = _gate_inputs(S, C, dtype, scale=C // sharing)
+    assert ssd_lib._stage_plan(order, S, C, groups, dtype) == (
         32 * 256 // cols, cols)
-    run = lambda *a: ssd_lib.gate_norm(*a, groups=groups, epsilon=1e-5,
-                                       dtype=dtype)
+    assert f"name={order}_fwd" in str(jax.make_jaxpr(run)(*args))
     got = _cotangents(run, args, w)
-    want = _cotangents(_gate_norm_body(groups, dtype), args, w)
+    want = _cotangents(body, args, w)
     assert got[1][0].dtype == jnp.float32 and got[1][1].dtype == dtype
-    for i, (g, t) in enumerate(zip(jax.tree.leaves(got),
-                                   jax.tree.leaves(want))):
-        assert g.shape == t.shape and g.dtype == t.dtype, i
-    _all_close(jax.tree.map(lambda a: a.astype(jnp.float32), got),
-               jax.tree.map(lambda a: a.astype(jnp.float32), want), tol,
-               "gate_norm")
+    _same_leaves(got, want, tol, order)
+
+
+@pytest.mark.parametrize("S", [64, 80], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("offset,read_in_place", [(512, True), (384, False)],
+                         ids=["block_edge", "off_edge"])
+def test_norm_gate_reads_its_gate_out_of_a_wider_source(
+        small_tiles, offset, read_in_place, dtype, tol, S):
+    """``z`` as lanes ``offset : offset + C`` of a wider array (the delta-rule
+    mixer's ``qkvz``): on a column block's edge the kernels' operand is the
+    source itself and no slice of it reaches them; off it they take ``z`` as
+    an array of its own. Either way the result and every cotangent, the
+    source's (zero outside ``z``'s lanes) among them, are the body's."""
+    groups, C = 4, 512
+    (y, _, scale), w = _gate_inputs(S, C, dtype, scale=C // groups)
+    source = jax.random.normal(jax.random.key(5),
+                               (2, S, offset + C + 128)).astype(dtype)
+    cut = lambda src: src[..., offset:offset + C]
+    run = lambda y, src, scale: ssd_lib.norm_gate(
+        y, cut(src), scale, groups=groups, epsilon=1e-5, dtype=dtype,
+        source=src, offset=offset)
+    body = _norm_gate_body(groups, dtype)
+    call = next(e for e in jax.make_jaxpr(run)(y, source, scale).eqns
+                if e.primitive.name == "custom_vjp_call")
+    shapes = [v.aval.shape for v in call.invars]
+    assert (source.shape in shapes[2:]) == read_in_place, shapes
+    got = _cotangents(run, (y, source, scale), w)
+    want = _cotangents(lambda y, src, scale: body(y, cut(src), scale),
+                       (y, source, scale), w)
+    assert got[1][1].shape == source.shape and got[1][1].dtype == dtype
+    assert not np.asarray(got[1][1][..., :offset], np.float32).any()
+    _same_leaves(got, want, tol, "norm_gate from a source")
 
 
 @pytest.mark.parametrize("C,groups,dtype,why", [
@@ -322,17 +381,28 @@ def test_gate_norm_kernels_are_the_jax_numpy_body(small_tiles, dtype, tol,
     (192, 1, jnp.float32, "channels off the lane tiling"),
     (384, 6, jnp.float32, "a group off the lane tiling"),
 ], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None)
-def test_gate_norm_plan_refuses_and_the_body_runs(small_tiles, C, groups,
-                                                  dtype, why):
-    assert ssd_lib._stage_plan("gate_norm", 64, C, groups, dtype) is None, why
-    args, w = _gate_inputs(64, C, dtype)
-    run = lambda *a: ssd_lib.gate_norm(*a, groups=groups, epsilon=1e-5,
-                                       dtype=dtype)
+@pytest.mark.parametrize("order", ["gate_norm", "norm_gate"])
+def test_gate_norm_plan_refuses_and_the_body_runs(small_tiles, order, C,
+                                                  groups, dtype, why):
+    assert ssd_lib._stage_plan(order, 64, C, groups, dtype) is None, why
+    run, body, sharing = _stage(order, groups, dtype)
+    args, w = _gate_inputs(64, C, dtype, scale=C // sharing)
     got = _cotangents(run, args, w)
-    want = _cotangents(_gate_norm_body(groups, dtype), args, w)
+    want = _cotangents(body, args, w)
     for g, t in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(g, t)
     assert "pallas_call" not in str(jax.make_jaxpr(run)(*args))
+
+
+def test_norm_gate_shorter_than_a_tile_runs_the_body(small_tiles):
+    """A sequence of fewer rows than a tile's (32 here) is refused whatever
+    the widths, and a source then changes nothing."""
+    assert ssd_lib._stage_plan("norm_gate", 24, 512, 4, jnp.float32) is None
+    (y, z, scale), _ = _gate_inputs(24, 512, jnp.float32, scale=128)
+    got = ssd_lib.norm_gate(y, z, scale, groups=4, epsilon=1e-5,
+                            dtype=jnp.float32, source=z, offset=0)
+    np.testing.assert_array_equal(
+        got, _norm_gate_body(4, jnp.float32)(y, z, scale))
 
 
 def test_mixer_plan_record_under_the_span_that_traced():
@@ -344,6 +414,11 @@ def test_mixer_plan_record_under_the_span_that_traced():
     bf16 = jnp.bfloat16
     norm = lambda groups: lambda *a: ssd_lib.gate_norm(
         *a, groups=groups, epsilon=1e-5, dtype=bf16)
+    # the delta-rule mixer's call at the published widths: 32 heads of 128,
+    # z the last 4,096 lanes of qkvz's 12,288
+    heads = lambda y, qkvz, scale: ssd_lib.norm_gate(
+        y, qkvz[..., 8192:], scale, groups=32, epsilon=1e-6, dtype=bf16,
+        source=qkvz, offset=8192)
     with rec.span("trace_here", bucket=None):
         jax.eval_shape(ssd_lib.conv_silu, shape(1, 8192, 6144, dtype=bf16),
                        shape(4, 6144), shape(6144))
@@ -351,9 +426,19 @@ def test_mixer_plan_record_under_the_span_that_traced():
                        shape(1, 8192, 4096, dtype=bf16), shape(4096))
         jax.eval_shape(norm(2), shape(1, 8192, 192),
                        shape(1, 8192, 192, dtype=bf16), shape(192))
+        jax.eval_shape(heads, shape(1, 8192, 4096),
+                       shape(1, 8192, 12288, dtype=bf16), shape(128))
+        jax.eval_shape(heads, shape(1, 8192, 4096),
+                       shape(1, 8192, 12288, dtype=jnp.float16), shape(128))
     new = rec.records()[mark:]
     span = next(r for r in new if r.kind == "span" and r.name == "trace_here")
     said = [r for r in new if r.name == "mixer_plan"]
+    assert said.pop().value == {
+        "stage": "norm_gate", "rows": 8192, "channels": 4096, "groups": 32,
+        "tile": "xla"}
+    assert said.pop().value == {
+        "stage": "norm_gate", "rows": 8192, "channels": 4096, "groups": 32,
+        "tile": [256, 512]}
     assert [r.kind for r in said] == ["compile"] * 3
     assert all(r.parent == span.id and r.seconds == 0 for r in said)
     assert said[0].value == {

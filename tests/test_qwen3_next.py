@@ -198,7 +198,8 @@ def test_reference_sees_what_a_step_leaves_out(piece, monkeypatch):
         module = module.clone(rotary_dim=module.head_dim)
     elif piece == "the norm before the gate":
         monkeypatch.setattr(
-            ssd_lib, "norm_gate", lambda y, z, scale, *, groups, **kw:
+            ssd_lib, "norm_gate",
+            lambda y, z, scale, *, groups, source, offset, **kw:
             ssd_lib.gate_norm(y, z, jnp.tile(scale, groups), groups=groups,
                               **kw))
     elif piece == "a value head's key":

@@ -456,7 +456,8 @@ def _pallas_sites():
 def _site_names(call):
     """The names a ``pallas_call`` site gives its kernel: its literal, or, at
     the online flash kernels' three sites, the schedule's (``plan.name``: the
-    kernel's own, or its window name under a schedule with a window)."""
+    kernel's own, or its window name under a schedule with a window), or, at
+    the gate-and-norm stage's two, the order's (``stage + "_fwd"``)."""
     from pytorch_distributed_training_example_tpu.ops import (
         flash_attention, grouped_matmul)
 
@@ -469,6 +470,12 @@ def _site_names(call):
         return [*flash_attention.ONLINE_KERNELS, *flash_attention.WINDOW_KERNELS]
     if isinstance(named[0], ast.Name) and named[0].id == "name":
         return list(grouped_matmul.GATED_KERNELS)   # the gated FFN's launcher
+    if (isinstance(named[0], ast.BinOp) and isinstance(named[0].op, ast.Add)
+            and isinstance(named[0].left, ast.Name)
+            and named[0].left.id == "stage"
+            and isinstance(named[0].right, ast.Constant)):
+        return [order + named[0].right.value
+                for order in ("gate_norm", "norm_gate")]
     return None
 
 
@@ -478,17 +485,18 @@ def test_every_pallas_call_has_a_name(file, call):
     assert names, f"{file}:{call.lineno} pl.pallas_call has no name="
     assert all(isinstance(n, str) and n.isidentifier() for n in names)
     assert len(names) == 1 or file in ("flash_attention.py",
-                                       "grouped_matmul.py")
+                                       "grouped_matmul.py", "ssd.py")
 
 
 def test_pallas_names_are_one_per_kernel():
     sites = [_site_names(p.values[1]) for p in _pallas_sites()]
     several = [s for s in sites if len(s) > 1]
     # the online forward, dq and dkv by their schedule; the gated FFN's six
-    # kernels through one launcher
-    assert len(several) == 4 and several[0] == several[1] == several[2]
-    names = [s[0] for s in sites if len(s) == 1] + several[0] + several[3]
-    assert len(names) == 28 and len(set(names)) == 28
+    # kernels through one launcher; the gate and the group norm in two orders
+    # through one launcher each way
+    assert len(several) == 6 and several[0] == several[1] == several[2]
+    names = [s[0] for s in sites if len(s) == 1] + sum(several[2:], [])
+    assert len(names) == 30 and len(set(names)) == 30
     assert {n for n in names if n.startswith(("gated_ffn", "grouped_"))} == {
         "grouped_matmul", "grouped_matmul_dw", "gated_ffn_up",
         "gated_ffn_down", "gated_ffn_dh", "gated_ffn_dx", "gated_ffn_dw_up",
@@ -496,8 +504,10 @@ def test_pallas_names_are_one_per_kernel():
     assert {n for n in names if n.startswith("ssd_")} == {"ssd_fwd", "ssd_bwd"}
     assert {n for n in names if n.startswith("delta_rule")} == {
         "delta_rule_fwd", "delta_rule_bwd"}
-    assert {n for n in names if n.startswith(("conv_silu", "gate_norm"))} == {
-        "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd"}
+    assert {n for n in names if n.startswith(
+            ("conv_silu", "gate_norm", "norm_gate"))} == {
+        "conv_silu_fwd", "conv_silu_bwd", "gate_norm_fwd", "gate_norm_bwd",
+        "norm_gate_fwd", "norm_gate_bwd"}
     assert {n for n in names if n.startswith("flash_fwd")} == {
         "flash_fwd_online", "flash_fwd_oneshot", "flash_fwd_causal",
         "flash_fwd_window"}
