@@ -76,7 +76,13 @@ from pytorch_distributed_training_example_tpu.utils import metrics as metrics_li
 
 
 class LatentAttention(nn.Module):
-    """Causal multi-head latent attention in its expanded form."""
+    """Causal multi-head latent attention in its expanded form. The value
+    width may differ from the query/key width (``ops/attention.attention``
+    sends such a call to the online flash kernels or the XLA path).
+    ``rope_inv_freq``: the rotary columns' inverse frequencies where they are
+    not ``rope_theta``'s own powers; ``softmax_scale``: the scores' factor
+    where it is not ``1 / sqrt(nope_dim + rope_dim)`` (a YaRN-scaled model
+    brings both)."""
     num_heads: int
     q_rank: int
     kv_rank: int
@@ -88,6 +94,8 @@ class LatentAttention(nn.Module):
     dtype: Any
     param_dtype: Any
     attn_impl: str = "auto"
+    rope_inv_freq: tuple | None = None
+    softmax_scale: float | None = None
 
     @nn.compact
     def __call__(self, h):
@@ -96,16 +104,8 @@ class LatentAttention(nn.Module):
         norm = lambda name: RMSNorm(self.epsilon, self.dtype,
                                     self.param_dtype, name=name)
         H, nope, S = self.num_heads, self.nope_dim, h.shape[1]
-        impl = self.attn_impl
-        if self.v_dim != nope + self.rope_dim:
-            # ROADMAP B5: no kernel whose value width differs from its
-            # query/key width
-            if impl not in ("auto", "xla"):
-                raise NotImplementedError(
-                    f"attn_impl={impl!r} needs v_head_dim == qk_nope_head_dim"
-                    f" + qk_rope_head_dim ({self.v_dim} != {nope} + "
-                    f"{self.rope_dim}): ops/flash_attention.py has one width")
-            impl = "xla"
+        rope = functools.partial(llama.rope, theta=self.rope_theta,
+                                 inv_freq=self.rope_inv_freq)
         with jax.named_scope("mla"):
             with jax.named_scope("mla_q"):
                 c_q = norm("q_norm")(nn.Dense(self.q_rank, name="q_a",
@@ -120,11 +120,10 @@ class LatentAttention(nn.Module):
                                      **kinds)(c_kv)
             with jax.named_scope("mla_rope"):
                 positions = jnp.arange(S)[None, :]
-                k_r = llama.rope(down[..., None, self.kv_rank:], positions,
-                                 self.rope_theta)          # [B, S, 1, rope]
+                k_r = rope(down[..., None, self.kv_rank:],
+                           positions)                      # [B, S, 1, rope]
                 q = jnp.concatenate(
-                    [q[..., :nope], llama.rope(q[..., nope:], positions,
-                                               self.rope_theta)], axis=-1)
+                    [q[..., :nope], rope(q[..., nope:], positions)], axis=-1)
                 k = jnp.concatenate(
                     [kv[..., :nope], jnp.broadcast_to(
                         k_r, (*kv.shape[:-1], self.rope_dim))], axis=-1)
@@ -134,7 +133,9 @@ class LatentAttention(nn.Module):
             q = mesh_lib.constrain(q, llama._seq_rule("qkv"))
             k = mesh_lib.constrain(k, llama._seq_rule("qkv"))
             v = mesh_lib.constrain(v, llama._seq_rule("qkv"))
-            out = attn_lib.attention(q, k, v, causal=True, impl=impl)
+            out = attn_lib.attention(q, k, v, causal=True,
+                                     impl=self.attn_impl,
+                                     scale=self.softmax_scale)
             with jax.named_scope("mla_out"):
                 return nn.DenseGeneral(h.shape[-1], axis=(-2, -1), name="out",
                                        **kinds)(out)

@@ -54,10 +54,16 @@ class RMSNorm(nn.Module):
         return (norm * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings on [B, S, H, D] (rotate half, fp32 trig)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None) -> jax.Array:
+    """Rotary embeddings on [B, S, H, D] (rotate half, fp32 trig).
+    ``inv_freq``: the ``D / 2`` inverse frequencies where they are not
+    ``theta``'s own powers (a scaled rope's, e.g. YaRN's blend)."""
     d_half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B?,S,d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     cos = cos[:, :, None, :]  # broadcast over heads

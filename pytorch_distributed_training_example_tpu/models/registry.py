@@ -38,7 +38,14 @@ gated delta rule in three layers of four beside gated GQA with a quarter of
 the head rotary, every layer over a softmax-routed mixture of 512 experts
 with a sigmoid-gated shared expert: ``qwen3_next_80b`` at its published
 sizes, ``qwen3_next_80b_share`` one chip's share of it, ``qwen3_next_tiny``
-for tests; training only, ``dp``/``fsdp`` only).
+for tests; training only, ``dp``/``fsdp`` only) and the ``xing4`` family
+(``model_type`` ``xing4_0``: the ``glm_moe_lite`` family's latent attention,
+with a value width under its query/key width and YaRN positions, and its
+expert layer, on a residual path four streams wide that
+manifold-constrained hyper-connections read, mix and write: ``xing4_29b`` at
+its published sizes without the prediction layer, which the family does not
+build, ``xing4_29b_share`` one chip's share of it, ``xing4_tiny`` for tests;
+training only, ``dp``/``fsdp`` only).
 """
 
 from __future__ import annotations
@@ -265,7 +272,8 @@ _REGISTRY["granite_hybrid_tiny"] = _granite_hybrid(
 def _held_experts_family(name, make):
     """Registry builder for a family whose expert layers are told which
     experts they hold (``models/<name>.py``: ``afmoe``, ``smallthinker``,
-    ``glm_moe_lite``, ``nemotron_h``, ``lfm2_moe``, ``qwen3_next``):
+    ``glm_moe_lite``, ``nemotron_h``, ``lfm2_moe``, ``qwen3_next``,
+    ``xing4``):
     ``make(module, **kw)`` returns the model. ``dp``/``fsdp`` only, as the
     Granite hybrid: the expert layer has no exchange, and there is no
     tensor-parallel rule table."""
@@ -358,6 +366,19 @@ _REGISTRY["qwen3_next_80b_share"] = _held_experts_family(
     "qwen3_next", lambda m, **kw: m.chip_share(m.qwen3_next_80b(**kw)))
 _REGISTRY["qwen3_next_tiny"] = _held_experts_family(
     "qwen3_next", lambda m, **kw: m.qwen3_next_tiny(**kw))
+
+
+# The published Xing4.0-29B-A4B (its 40 layers: the prediction layer behind
+# them is not built, ``models/xing4.py`` says why); one chip's share of it (an
+# eighth of every layer's routed experts and of the vocabulary, one leading
+# dense layer and four expert layers: what the one-chip benchmark cell
+# trains); and a toy for the tests.
+_REGISTRY["xing4_29b"] = _held_experts_family(
+    "xing4", lambda m, **kw: m.xing4_29b(mtp_layers=0, **kw))
+_REGISTRY["xing4_29b_share"] = _held_experts_family(
+    "xing4", lambda m, **kw: m.chip_share(m.xing4_29b(**kw)))
+_REGISTRY["xing4_tiny"] = _held_experts_family(
+    "xing4", lambda m, **kw: m.xing4_tiny(**kw))
 
 
 @register("resnet_micro")

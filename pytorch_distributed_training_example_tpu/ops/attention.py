@@ -62,8 +62,11 @@ def dot_product_attention(
     bias: jax.Array | None = None,
     q_offset: int | jax.Array = 0,
     window: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Reference attention in pure XLA; fp32 softmax, inputs' dtype out.
+    ``v`` may be of another width than ``q`` and ``k``; ``scale`` is the
+    scores' factor, ``1 / sqrt(D)`` of the query/key width where None.
 
     ``q_offset`` positions the query block within the global sequence for
     causal masking (used by the ring schedule where K/V blocks come from
@@ -78,7 +81,7 @@ def dot_product_attention(
     v = _repeat_kv(v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
-    logits = logits * (1.0 / math.sqrt(depth))
+    logits = logits * (scale or 1.0 / math.sqrt(depth))
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     if causal:
@@ -489,6 +492,7 @@ def attention(
     q, k, v, *, causal=False, impl: str = "auto",
     mesh: Mesh | None = None, context_axis: str = "context",
     batch_axes=("data", "fsdp"), window: int | None = None,
+    scale: float | None = None,
 ):
     """Dispatcher used by the models.
 
@@ -505,15 +509,20 @@ def attention(
     flash path's online kernels take it as their schedule's second edge
     and the XLA path as a mask; the context-
     parallel schedules and the padded one-shot path have neither.
+
+    ``v`` of another width than ``q`` and ``k``, or a ``scale`` other than ``1
+    / sqrt(D)``: the flash path's online kernels or the XLA path, as under
+    a window; the others hold one width and one factor.
     """
     from pytorch_distributed_training_example_tpu.core import mesh as mesh_lib
 
     mesh = mesh or mesh_lib.current_mesh()
     ctx = mesh.shape.get(context_axis, 1) if mesh is not None else 1
-    if window is not None:
-        return _window_attention(q, k, v, causal=causal, impl=impl, ctx=ctx,
+    if (window is not None or scale is not None
+            or v.shape[-1] != q.shape[-1]):
+        return _online_attention(q, k, v, causal=causal, impl=impl, ctx=ctx,
                                  mesh=mesh, batch_axes=batch_axes,
-                                 window=window)
+                                 window=window, scale=scale)
     if impl == "auto":
         if ctx > 1:
             impl = "ring_zigzag" if causal else "ring"
@@ -572,25 +581,32 @@ def attention(
     return dot_product_attention(q, k, v, causal=causal)
 
 
-def _window_attention(q, k, v, *, causal, impl, ctx, mesh, batch_axes,
-                      window):
-    """``attention`` with a window: the flash kernels under the window's
-    schedule where the flash path is eligible (or asked for), else the masked
-    XLA reference."""
+def _online_attention(q, k, v, *, causal, impl, ctx, mesh, batch_axes,
+                      window, scale):
+    """``attention`` with a window, a value width that differs from the
+    query/key width, or the caller's scale: what the flash kernels' online
+    family alone takes (the window as its schedule's second edge, each
+    operand blocked at its own width, the scale as its factor), where the
+    flash path is eligible (or asked for), else the masked XLA reference."""
     if impl not in ("auto", "flash", "xla") or ctx > 1:
         raise ValueError(
-            f"window attention has no context-parallel schedule (impl="
-            f"{impl!r}, context axis {ctx}); use impl auto, flash or xla")
-    if impl != "xla" and _flash_eligible(q, k, explicit=impl == "flash"):
+            f"window={window}, widths {q.shape[-1]} / {v.shape[-1]}, scale="
+            f"{scale}: no context-parallel schedule takes a window, two "
+            f"widths or a scale (impl={impl!r}, context axis {ctx}); use "
+            f"impl auto, flash or xla")
+    if impl != "xla" and _flash_eligible(q, k, explicit=impl == "flash", v=v):
         from pytorch_distributed_training_example_tpu.ops import flash_attention
 
         return _per_device_flash(
-            functools.partial(flash_attention.flash_attention, window=window),
+            functools.partial(flash_attention.flash_attention, window=window,
+                              scale=scale),
             q, k, v, causal=causal, mesh=mesh, batch_axes=batch_axes)
     if impl == "flash" and backend.on_tpu():
         raise ValueError(f"attn_impl='flash' not eligible for shape "
-                         f"q={q.shape} k={k.shape} with a window")
-    return dot_product_attention(q, k, v, causal=causal, window=window)
+                         f"q={q.shape} k={k.shape} v={v.shape} (window="
+                         f"{window})")
+    return dot_product_attention(q, k, v, causal=causal, window=window,
+                                 scale=scale)
 
 
 def _per_device_flash(fn, q, k, v, *, causal, mesh, batch_axes):
@@ -678,15 +694,20 @@ def _padded_flash_eligible(q, k, multiple: int = PAD_MULTIPLE,
             is not None)
 
 
-def _flash_eligible(q, k, explicit: bool = False) -> bool:
+def _flash_eligible(q, k, explicit: bool = False, v=None) -> bool:
     """Whether the Pallas kernel can (explicit) / should (auto) run.
 
     ``auto`` additionally requires seq >= 1024 — below that the XLA fusion
     is already fast and kernel launch overhead dominates; an explicit
-    ``impl='flash'`` only needs the kernel's hard shape constraints.
+    ``impl='flash'`` only needs the kernel's hard shape constraints. ``v``
+    where its width may differ (the online kernels' call): 192, latent
+    attention's 128 + 64, is a query/key width there.
     """
     on_tpu = backend.on_tpu()
     seq_ok = q.shape[1] % 512 == 0 and k.shape[1] % 512 == 0
     if not explicit:
         seq_ok = seq_ok and q.shape[1] >= 1024
+    if v is not None and v.shape[-1] != q.shape[-1]:
+        return (on_tpu and seq_ok and q.shape[-1] in (64, 128, 192, 256)
+                and v.shape[-1] in (64, 128, 256))
     return on_tpu and seq_ok and q.shape[-1] in (64, 128, 256)

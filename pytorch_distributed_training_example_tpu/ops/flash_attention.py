@@ -50,7 +50,8 @@ LSE_LANES = 8  # lse stored [B,H,S,8]: minor dims satisfy Mosaic tiling
 
 # Measured per-shape block overrides for the ONLINE kernels, keyed
 # (bwd, S, D) -> (block_q, block_kv), or (bwd, S, D, window) for a call under
-# a sliding window. Consulted only when the caller left
+# a sliding window; D is the head's width, or (query/key width, value width)
+# where the two differ. Consulted only when the caller left
 # block_q/block_kv at the module defaults (an explicit caller choice always
 # wins), so it is a tuning table, not an API change. Entries are added ONLY
 # from on-chip sweeps (``benchmarks/flash_micro.py --block-sweep`` emits the
@@ -74,20 +75,30 @@ ONLINE_BLOCK_TABLE: dict[tuple[int, ...], tuple[int, int]] = {
 }
 
 
-def _online_held(bwd: bool, block_q: int, block_kv: int, d: int,
-                 itemsize: int):
+def _widths(d):
+    """``(query/key width, value width)`` of ``d``: one width for both, or
+    the pair."""
+    return (d, d) if isinstance(d, int) else tuple(d)
+
+
+def _online_held(bwd: bool, block_q: int, block_kv: int, d, itemsize: int):
     """Bytes of ``[block, D]`` rows an online kernel holds in VMEM at a grid
     step: its operand and output blocks, which the pipeline double-buffers,
     and its float32 accumulators. ``flash_fwd_online`` reads q, k, v, writes
     o and accumulates o; ``flash_bwd_dq`` reads q, dO, k, v, writes dq and
     accumulates dq; ``flash_bwd_dkv`` reads the same, writes dk and dv and
-    accumulates both (the backward's count is the fuller of its two). The
-    ``[block_q, block_kv]`` score tiles beside them do not grow with D or the
-    dtype and are not counted."""
+    accumulates both (the backward's count is the fuller of its two). q, k,
+    dq and dk are of the query/key width, v, o, dO and dv of the value width
+    (``d``: one width, or the pair). The ``[block_q, block_kv]`` score tiles
+    beside them do not grow with D or the dtype and are not counted."""
+    qk, v = _widths(d)
     if not bwd:
-        return d * (itemsize * 4 * (block_q + block_kv) + 4 * block_q)
-    dq = d * (itemsize * (6 * block_q + 4 * block_kv) + 4 * block_q)
-    dkv = d * (itemsize * (4 * block_q + 8 * block_kv) + 8 * block_kv)
+        return (itemsize * 2 * (block_q + block_kv) * (qk + v)
+                + 4 * block_q * v)
+    dq = (itemsize * 2 * (block_q * (2 * qk + v) + block_kv * (qk + v))
+          + 4 * block_q * qk)
+    dkv = (itemsize * 2 * (block_q + 2 * block_kv) * (qk + v)
+           + 4 * block_kv * (qk + v))
     return max(dq, dkv)
 
 
@@ -100,7 +111,7 @@ def _online_held(bwd: bool, block_q: int, block_kv: int, d: int,
 ONLINE_HELD_MAX = _online_held(True, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV, 128, 4)
 
 
-def _online_blocks(bwd: bool, s: int, d: int, block_q: int, block_kv: int,
+def _online_blocks(bwd: bool, s: int, d, block_q: int, block_kv: int,
                    itemsize: int = 2, window: int | None = None):
     """The online kernels' block sizes: the caller's where it chose them, a
     row of ONLINE_BLOCK_TABLE where the shape (with its window, where the
@@ -329,8 +340,11 @@ class OnlineSchedule:
         return {k: int(v) for k, v in out.items()}
 
     def record(self, d):
-        """The ``flash_schedule`` record's value at head width ``d``."""
-        return dict(kernel=self.name, Sq=self.sq, Skv=self.skv, D=d,
+        """The ``flash_schedule`` record's value at head width ``d`` (where
+        the value width differs, beside it as ``Dv``)."""
+        qk, v = _widths(d)
+        return dict(kernel=self.name, Sq=self.sq, Skv=self.skv, D=qk,
+                    **({"Dv": v} if v != qk else {}),
                     block_q=self.block_q, block_kv=self.block_kv,
                     causal=self.causal, window=self.window, sub=self.sub,
                     **self.counts())
@@ -574,19 +588,22 @@ def _online_grid(plan, B, H, *, in_blocks, out_blocks, scratch_shapes):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_kv", "window", "walk", "split", "sub"))
+    "causal", "block_q", "block_kv", "window", "walk", "split", "sub",
+    "scale"))
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
-               window=None, walk=True, split=True, sub=None):
-    """Returns (out [B,S,H,D], lse [B,H,S,LSE_LANES]) with K/V already
-    GQA-expanded. ``window``: the mask's second edge (``_window_of``'s).
-    ``walk``, ``split``, ``sub``: ``online_schedule``'s switches (the
-    micro-benchmark's).
+               window=None, walk=True, split=True, sub=None, scale=None):
+    """Returns (out [B,S,H,Dv], lse [B,H,S,LSE_LANES]) with K/V already
+    GQA-expanded; q and k ``D`` wide, v and the result ``Dv`` (each operand's
+    blocks are of its own width: nothing is padded to the other's).
+    ``window``: the mask's second edge (``_window_of``'s). ``scale``: the
+    scores' factor, ``1 / sqrt(D)`` where None. ``walk``, ``split``, ``sub``:
+    ``online_schedule``'s switches (the micro-benchmark's).
 
     Under ``jit``, as the causal pair is: a model's layers, and its later
     traces, share one trace and one lowering of the unrolled bodies (traced
     a layer, GLM-4.7-Flash's six attentions added 5 s to its first step)."""
     B, Sq, H, D = q.shape
-    Skv = k.shape[1]
+    Skv, Dv = k.shape[1], v.shape[3]
     # head-major layout for the kernel
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
@@ -600,18 +617,19 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_kv: int,
 
     call, table = _online_grid(
         plan, B, H,
-        in_blocks=[(block_q, D, "q"), (block_kv, D, "kv"), (block_kv, D, "kv")],
-        out_blocks=[(block_q, D, "q"), (block_q, LSE_LANES, "q")],
+        in_blocks=[(block_q, D, "q"), (block_kv, D, "kv"), (block_kv, Dv, "kv")],
+        out_blocks=[(block_q, Dv, "q"), (block_q, LSE_LANES, "q")],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # m
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
-            pltpu.VMEM((block_q, D), jnp.float32),     # acc
+            pltpu.VMEM((block_q, Dv), jnp.float32),    # acc
         ])
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=1.0 / math.sqrt(D), plan=plan),
+        functools.partial(_fwd_kernel, sm_scale=scale or 1.0 / math.sqrt(D),
+                          plan=plan),
         name=plan.name,
         out_shape=(
-            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, LSE_LANES), jnp.float32),
         ),
         **call,
@@ -716,26 +734,28 @@ def _delta_rows(g, o):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_kv", "window", "walk", "split", "sub"))
+    "causal", "block_q", "block_kv", "window", "walk", "split", "sub",
+    "scale"))
 def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, window=None,
-               walk=True, split=True, sub=None):
-    """q,k,v,o,g: [B,S,H,D] (kv already GQA-expanded); lse:
-    [B,H,Sq,LSE_LANES]. Under ``jit``, with the window and
+               walk=True, split=True, sub=None, scale=None):
+    """q,k: [B,S,H,D], v,o,g: [B,S,H,Dv] (kv already GQA-expanded); lse:
+    [B,H,Sq,LSE_LANES]. Under ``jit``, with the window, the scale and
     ``online_schedule``'s switches, as ``_flash_fwd``."""
     B, Sq, H, D = q.shape
-    Skv = k.shape[1]
+    Skv, Dv = k.shape[1], v.shape[3]
     block_q = _fit_block(Sq, block_q)
     block_kv = _fit_block(Skv, block_kv)
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, Skv, block_q, block_kv)
-    sm_scale = 1.0 / math.sqrt(D)
+    sm_scale = scale or 1.0 / math.sqrt(D)
     delta = _delta_rows(g, o)
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     dot = jnp.transpose(g, (0, 2, 1, 3))
     qrows, krows = (block_q, D, "q"), (block_kv, D, "kv")
+    vrows, orows = (block_kv, Dv, "kv"), (block_q, Dv, "q")
     lrows = (block_q, LSE_LANES, "q")
-    in_blocks = [qrows, krows, krows, qrows, lrows, lrows]
+    in_blocks = [qrows, krows, vrows, orows, lrows, lrows]
     plans = [online_schedule(name, causal, Sq, Skv, block_q, block_kv,
                              window=window, walk=walk, split=split, sub=sub)
              for name in ONLINE_KERNELS[1:]]
@@ -754,14 +774,14 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal, block_q, block_kv, window=None,
 
     # dk/dv pass: kv blocks outer (parallel), q blocks inner (accumulated).
     call, table = _online_grid(
-        plans[1], B, H, in_blocks=in_blocks, out_blocks=(krows, krows),
+        plans[1], B, H, in_blocks=in_blocks, out_blocks=(krows, vrows),
         scratch_shapes=[pltpu.VMEM((block_kv, D), jnp.float32),
-                        pltpu.VMEM((block_kv, D), jnp.float32)])
+                        pltpu.VMEM((block_kv, Dv), jnp.float32)])
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, plan=plans[1]),
         name=plans[1].name,
         out_shape=(jax.ShapeDtypeStruct((B, H, Skv, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Skv, D), v.dtype)),
+                   jax.ShapeDtypeStruct((B, H, Skv, Dv), v.dtype)),
         **call,
     )(*table, *operands)
 
@@ -1544,13 +1564,14 @@ def _stream_bwd(q, k, v, o, lse, g, *, causal, plan):
     return tr(dq), tr(dk), tr(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_kv: int = DEFAULT_BLOCK_KV,
                     impl: str = "auto",
                     kv_len: int | None = None,
-                    window: int | None = None):
+                    window: int | None = None,
+                    scale: float | None = None):
     """Flash attention with the XLA oracle's exact semantics.
 
     [B, S, H, D] layout; fp32 softmax; GQA via fewer KV heads. Forward and
@@ -1569,11 +1590,17 @@ def flash_attention(q, k, v, causal: bool = False,
     (causal self-attention; the online kernels under the window's schedule,
     whatever ``impl``). A window that covers the sequence is plain causal
     attention and dispatches as such.
+
+    ``v`` may be of another width than ``q`` and ``k`` (latent attention's
+    192 / 128), and ``scale`` (static) another factor on the scores than ``1 /
+    sqrt(D)``: either makes the call the online kernels', which block each
+    operand at its own width; the one-shot, causal and streaming kernels
+    hold one ``D`` and one factor and refuse such a call.
     """
     k = attn_lib._repeat_kv(k, q.shape[2])
     v = attn_lib._repeat_kv(v, q.shape[2])
     out, _ = _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
-                           window)
+                           window, scale)
     return out
 
 
@@ -1588,10 +1615,27 @@ def _window_of(window, causal, kv_len, Sq, Skv):
     return window if window < Skv else None
 
 
+def _online_only(q, v, scale, impl, kv_len):
+    """Whether the call is one only the online kernels compute: a value width
+    that differs from the query/key width, or the caller's own scale. Raises
+    where the caller pinned it to kernels that cannot."""
+    if v.shape[-1] == q.shape[-1] and scale is None:
+        return False
+    if impl == "oneshot" or kv_len is not None:
+        raise ValueError(
+            f"impl={impl!r}, kv_len={kv_len}: the one-shot, causal and "
+            f"streaming kernels take one head width and 1 / sqrt(D) (query/"
+            f"key width {q.shape[-1]}, value width {v.shape[-1]}, scale "
+            f"{scale}); the online kernels serve such a call")
+    return True
+
+
 def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
-                  window=None):
+                  window=None, scale=None):
     """Auto dispatch is per direction, each from measurements on the chip:
 
+    - Unequal widths or a scale of the caller's: the online kernels, both
+      directions (``_online_only``).
     - A window shorter than the sequence: the online kernels, both
       directions, under a schedule with the window as its second edge (1024
       x 1024 blocks with 512 sub-tiles on both edges at the published S8192
@@ -1620,7 +1664,9 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
     B, Sq, H, D = q.shape
     window = _window_of(window, causal, kv_len, Sq, k.shape[1])
     if window is not None:
-        return _online_fwd(q, k, v, True, block_q, block_kv, window)
+        return _online_fwd(q, k, v, True, block_q, block_kv, window, scale)
+    if _online_only(q, v, scale, impl, kv_len):
+        return _online_fwd(q, k, v, causal, block_q, block_kv, scale=scale)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
                          "impl='online' cannot serve it")
@@ -1644,35 +1690,44 @@ def _fwd_dispatch(q, k, v, causal, block_q, block_kv, impl, kv_len,
     return _online_fwd(q, k, v, causal, block_q, block_kv)
 
 
-def _online_fwd(q, k, v, causal, block_q, block_kv, window=None):
+def _width_of(q, v):
+    """The call's head width as the block plan keys it: one number, or the
+    pair where the value width differs."""
+    return q.shape[3] if v.shape[3] == q.shape[3] else (q.shape[3], v.shape[3])
+
+
+def _online_fwd(q, k, v, causal, block_q, block_kv, window=None, scale=None):
     """The online forward at the blocks ``_online_blocks`` gives the call,
     its schedule said to the recorder."""
-    _, Sq, _, D = q.shape
+    Sq, D = q.shape[1], _width_of(q, v)
     block_q, block_kv = _online_blocks(False, Sq, D, block_q, block_kv,
                                        q.dtype.itemsize, window)
     _say_schedules(ONLINE_KERNELS[:1], causal, Sq, k.shape[1], D, block_q,
                    block_kv, window)
     return _flash_fwd(q, k, v, causal=causal, block_q=block_q,
-                      block_kv=block_kv, window=window)
+                      block_kv=block_kv, window=window, scale=scale)
 
 
-def _online_bwd(q, k, v, o, lse, g, causal, block_q, block_kv, window=None):
+def _online_bwd(q, k, v, o, lse, g, causal, block_q, block_kv, window=None,
+                scale=None):
     """The online backward, as ``_online_fwd``."""
-    _, Sq, _, D = q.shape
+    Sq, D = q.shape[1], _width_of(q, v)
     block_q, block_kv = _online_blocks(True, Sq, D, block_q, block_kv,
                                        q.dtype.itemsize, window)
     _say_schedules(ONLINE_KERNELS[1:], causal, Sq, k.shape[1], D, block_q,
                    block_kv, window)
     return _flash_bwd(q, k, v, o, lse, g, causal=causal, block_q=block_q,
-                      block_kv=block_kv, window=window)
+                      block_kv=block_kv, window=window, scale=scale)
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len, window):
+def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len, window,
+             scale=None):
     ke = attn_lib._repeat_kv(k, q.shape[2])
     ve = attn_lib._repeat_kv(v, q.shape[2])
     out, lse = _fwd_dispatch(q, ke, ve, causal, block_q, block_kv, impl,
-                             kv_len, window)
-    if _window_of(window, causal, kv_len, q.shape[1], k.shape[1]) is not None:
+                             kv_len, window, scale)
+    if (_window_of(window, causal, kv_len, q.shape[1], k.shape[1]) is not None
+            or _online_only(q, v, scale, impl, kv_len)):
         return out, (q, k, v, out, lse)
     fwd_plan, bwd_plan = (
         _auto_causal_plan(impl, causal, kv_len, q.shape[1], k.shape[1],
@@ -1684,15 +1739,16 @@ def _vjp_fwd(q, k, v, causal, block_q, block_kv, impl, kv_len, window):
     return out, (q, k, v, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
+def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g,
+             scale=None):
     q, k, v, o, lse = res
     H, Hkv = q.shape[2], k.shape[2]
     ke = attn_lib._repeat_kv(k, H)
     ve = attn_lib._repeat_kv(v, H)
     window = _window_of(window, causal, kv_len, q.shape[1], k.shape[1])
-    if window is not None:
-        dq, dk, dv = _online_bwd(q, ke, ve, o, lse, g, True, block_q, block_kv,
-                                 window)
+    if window is not None or _online_only(q, v, scale, impl, kv_len):
+        dq, dk, dv = _online_bwd(q, ke, ve, o, lse, g, causal, block_q,
+                                 block_kv, window, scale)
         return (dq,) + _fold_kv_heads(dk, dv, H, Hkv)
     if kv_len is not None and impl == "online":
         raise ValueError("kv_len masking requires the one-shot kernels; "
@@ -1738,13 +1794,21 @@ def _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g):
 def _fold_kv_heads(dk, dv, H, Hkv):
     """GQA: fold the repeated-head grads back onto the shared KV heads."""
     if Hkv != H:
-        B, S, _, D = dk.shape
-        dk = dk.reshape(B, S, Hkv, H // Hkv, D).sum(3)
-        dv = dv.reshape(B, S, Hkv, H // Hkv, D).sum(3)
+        fold = lambda d: d.reshape(*d.shape[:2], Hkv, H // Hkv,
+                                   d.shape[3]).sum(3)
+        dk, dv = fold(dk), fold(dv)
     return dk, dv
 
 
-flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
+def _vjp_bwd_scaled(causal, block_q, block_kv, impl, kv_len, window, scale,
+                    res, g):
+    """``_vjp_bwd`` as ``custom_vjp`` calls it: the static arguments in
+    ``flash_attention``'s order, the scale the last of them."""
+    return _vjp_bwd(causal, block_q, block_kv, impl, kv_len, window, res, g,
+                    scale=scale)
+
+
+flash_attention.defvjp(_vjp_fwd, _vjp_bwd_scaled)
 
 
 # ---------------------------------------------------------------------------

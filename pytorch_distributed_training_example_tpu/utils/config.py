@@ -333,6 +333,25 @@ PRESETS: dict[str, dict[str, Any]] = {
         weight_decay=0.1, optimizer="adamw", precision="bf16",
         strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
     ),
+    # Xing4.0-29B-A4B (models/xing4.py) whole (its 40 layers, 29.5 B
+    # parameters: a recipe for a pod, no one-host mesh holds it), and one
+    # chip's share of it (an eighth of each layer's 64 experts and of the
+    # vocabulary, one dense layer and four expert layers): latent attention
+    # at 192 / 128 and sigmoid-routed experts on four residual streams mixed
+    # by hyper-connections, one 2k sequence a chip per micro-step (the
+    # float32 [B, S, 4, 3584] stream quarters the tokens that fit)
+    "xing4_29b": dict(
+        model="xing4_29b", dataset="lm", seq_len=2048, epochs=1,
+        global_batch_size=8, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
+    "xing4_29b_share": dict(
+        model="xing4_29b_share", dataset="lm", seq_len=2048, epochs=1,
+        global_batch_size=1, lr=3e-4, warmup_epochs=0.01,
+        weight_decay=0.1, optimizer="adamw", precision="bf16",
+        strategy="fsdp", mesh_data=1, mesh_fsdp=-1, remat=True, grad_clip=1.0,
+    ),
 }
 
 

@@ -56,6 +56,9 @@ SCOPES = (
     # module ``mtp_block``)
     "mla", "mla_q", "mla_kv", "mla_rope", "mla_out",
     "mtp", "mtp_merge", "mtp_block",
+    # the hyper-connections of a residual path several streams wide
+    # (models/xing4.py): ``hc`` around each, its parts inside
+    "hc", "hc_maps", "hc_sinkhorn", "hc_read", "hc_write",
     # the health reductions of --telemetry (utils/telemetry.py)
     "telemetry_health",
     # the collectives (ops/ring_attention.py, ops/ulysses.py,

@@ -36,36 +36,45 @@ APPENDED_LATER = {
     "test_granite_cells.py::test_manifest_gained_one_cell_and_three_metrics":
         {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
          "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
-         "qwen3_next_80b.b1.s8192.v18992"},
+         "qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
     "test_trinity_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
         {"smallthinker_21b.b1.s8192.v37984", "glm47_flash.b1.s8192.v19360",
          "nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
-         "qwen3_next_80b.b1.s8192.v18992"},
+         "qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
     "test_smallthinker_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
         {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384",
          "lfm2_8b_a1b.b1.s8192.v16384",
-         "qwen3_next_80b.b1.s8192.v18992"},
+         "qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
     # PR 39's eight start-up entries were the tail until PR 41 appended
     "test_setup_readers.py::"
     "test_the_manifest_gained_eight_entries_that_move_setup_s":
         {"glm47_flash.b1.s8192.v19360", "nemotron3_nano.b1.s8192.v16384",
          "lfm2_8b_a1b.b1.s8192.v16384",
-         "qwen3_next_80b.b1.s8192.v18992"},
+         "qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
     # PR 41's six entries were the tail until PR 43 appended
     "test_glm47_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_six_metrics":
         {"nemotron3_nano.b1.s8192.v16384", "lfm2_8b_a1b.b1.s8192.v16384",
-         "qwen3_next_80b.b1.s8192.v18992"},
+         "qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
     # PR 43's eight entries were the tail until PR 47 appended
     "test_nemotron_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics":
-        {"lfm2_8b_a1b.b1.s8192.v16384", "qwen3_next_80b.b1.s8192.v18992"},
+        {"lfm2_8b_a1b.b1.s8192.v16384", "qwen3_next_80b.b1.s8192.v18992",
+         "xing4_29b.b1.s2048.v16384"},
     # PR 47's eight entries were the tail until PR 50 appended
     "test_lfm2_cells.py::"
     "test_manifest_gained_one_configuration_one_cell_and_eight_metrics":
-        {"qwen3_next_80b.b1.s8192.v18992"},
+        {"qwen3_next_80b.b1.s8192.v18992", "xing4_29b.b1.s2048.v16384"},
+    # PR 50's nine entries were the last cell's until PR 54 appended
+    "test_qwen3_next_cells.py::"
+    "test_manifest_gained_one_configuration_one_cell_and_nine_metrics":
+        {"xing4_29b.b1.s2048.v16384"},
+    # PR 52's five entries list the eight cells of their day
+    "test_pass_readers.py::"
+    "test_the_manifest_gained_five_entries_for_the_eight_cells":
+        {"xing4_29b.b1.s2048.v16384"},
 }
 
 
@@ -136,6 +145,18 @@ def _manifest_less_the_entries_of_every_cell(request, monkeypatch, tmp_path):
     for plugin in request.config.pluginmanager.get_plugins():
         if isinstance(getattr(plugin, "APPENDED_SINCE", None), dict):
             monkeypatch.setattr(plugin, "ROOT", str(shown))
+
+
+@pytest.fixture(autouse=True)
+def _the_harness_beside_a_shown_manifest(request, tmp_path):
+    """PR 52's tail reader also looks for the harness's files under the
+    ``ROOT`` that ``tests/chipbench/conftest.py`` points at the shown
+    manifest's directory (this one's ``tmp_path``): they are linked there."""
+    if request.node.nodeid.endswith(
+            "test_pass_readers.py::"
+            "test_the_manifest_gained_five_entries_for_the_eight_cells"):
+        os.symlink(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench"), tmp_path / "chipbench")
 
 
 def pytest_collection_modifyitems(config, items):

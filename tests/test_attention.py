@@ -1137,6 +1137,102 @@ def test_online_schedule_parity_interpret(S, bq, bkv, D, H, Hkv, causal):
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("qk,dv,Hkv,scale", [
+    (192, 128, 2, None),        # latent attention's 128 + 64 over 128
+    (192, 128, 1, 0.14468),     # with YaRN's factor on the scores, one KV head
+    (64, 128, 2, None),         # a value width over the query/key width
+])
+def test_online_kernels_at_unequal_widths_match_the_oracle_interpret(
+        qk, dv, Hkv, scale):
+    """q and k ``qk`` wide, v ``dv`` wide: forward and all three gradients,
+    causal, through ``flash_attention`` under ``auto`` (which sends such a call
+    to the online kernels: no other family holds two widths) against the XLA
+    oracle, at blocks that reach the unmasked body and the sub-tiles."""
+    r = np.random.RandomState(3)
+    q = jnp.asarray(r.randn(1, 512, 2, qk), jnp.float32)
+    k = jnp.asarray(r.randn(1, 512, Hkv, qk), jnp.float32)
+    v = jnp.asarray(r.randn(1, 512, Hkv, dv), jnp.float32)
+    w = jnp.asarray(r.randn(1, 512, 2, dv), jnp.float32)
+    oracle = lambda *a: A.dot_product_attention(*a, causal=True, scale=scale)
+    flash = lambda *a: F.flash_attention(*a, True, 256, 256, "auto", None,
+                                         None, scale)
+    ref = oracle(q, k, v)
+    g_ref = jax.grad(lambda *a: (oracle(*a) * w).sum(),
+                     argnums=(0, 1, 2))(q, k, v)
+    with pltpu.force_tpu_interpret_mode():
+        out = flash(q, k, v)
+        g_out = jax.grad(lambda *a: (flash(*a) * w).sum(),
+                         argnums=(0, 1, 2))(q, k, v)
+    assert out.shape == (1, 512, 2, dv)
+    assert [g.shape for g in g_out] == [q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(g_ref, g_out):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_unequal_widths_go_to_the_online_kernels_and_nowhere_else(monkeypatch):
+    """Dispatch: a value width that differs, or a scale of the caller's, is
+    the online kernels' in both directions whatever ``auto`` would pick at
+    equal widths; the families that hold one width refuse it by name; the
+    block plan and the recorder key the pair."""
+    calls = _stub_flash_kernels(monkeypatch)
+    q = jnp.zeros((1, 1024, 12, 64), jnp.bfloat16)     # auto: the causal pair
+    v = jnp.zeros((1, 1024, 12, 128), jnp.bfloat16)
+    F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None)
+    assert calls == ["_causal_fwd"]
+    del calls[:]
+    F._fwd_dispatch(q, q, v, True, 1024, 1024, "auto", None)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (q, q, v, "o", "l"), v)
+    F._fwd_dispatch(q, q, q, True, 1024, 1024, "auto", None, None, 0.2)
+    F._vjp_bwd(True, 1024, 1024, "auto", None, None, (q, q, q, "o", "l"), q,
+               scale=0.2)
+    assert calls == ["_flash_fwd", "_flash_bwd"] * 2, calls
+    for impl, kv_len in (("oneshot", None), ("auto", 197)):
+        with pytest.raises(ValueError, match="one head width"):
+            F._fwd_dispatch(q, q, v, False, 1024, 1024, impl, kv_len)
+    with pytest.raises(ValueError, match="context-parallel"):
+        A.attention(q, q, v, causal=True, impl="ring")
+    # the plan at the pair: the parent's formulas at equal widths, the pair's
+    # own rows where they differ
+    for bwd in (False, True):
+        for d in (64, 128, 256):
+            assert F._online_held(bwd, 512, 1024, (d, d), 2) == \
+                F._online_held(bwd, 512, 1024, d, 2)
+    assert F._online_held(False, 512, 1024, 256, 2) == 256 * (
+        2 * 4 * (512 + 1024) + 4 * 512)
+    assert F._online_held(True, 512, 1024, 256, 2) == max(
+        256 * (2 * (6 * 512 + 4 * 1024) + 4 * 512),
+        256 * (2 * (4 * 512 + 8 * 1024) + 8 * 1024))
+    assert F._online_held(False, 1024, 1024, (192, 128), 2) == (
+        2 * 2 * 2048 * 320 + 4 * 1024 * 128)
+    monkeypatch.setitem(F.ONLINE_BLOCK_TABLE, (True, 2048, (192, 128)),
+                        (512, 1024))
+    assert F._online_blocks(True, 2048, (192, 128), 1024, 1024, 2) == \
+        (512, 1024)
+    assert F._online_blocks(True, 2048, 192, 1024, 1024, 2) == (1024, 1024)
+    plan = F.online_schedule("flash_bwd_dq", True, 2048, 2048, 1024, 1024)
+    assert (plan.record((192, 128))["D"], plan.record((192, 128))["Dv"],
+            "Dv" in plan.record(128)) == (192, 128, False)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_equal_widths_are_the_parents_kernels_bitwise(dtype):
+    """At one width nothing changed: the kernels that now block q, k and v at
+    their own widths, with the scale left to them or given as ``1 / sqrt(D)``,
+    give the results of the kernels as they stood (``flash_online_parent``)
+    bit for bit: o, lse, dq in interpret mode, dk and dv where the
+    interpreter's dot adds in the kernel's order."""
+    for scale in (None, 1.0 / np.sqrt(64.0)):
+        new, old = _both_online(512, 256, 256, dtype=dtype, walk=False,
+                                split=False, sub=0, scale=scale)
+        for got, want in zip(new, old):
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+
+
 def _both_online(S, bq, bkv, D=64, H=2, causal=True, dtype=jnp.float32,
                  **parts):
     """(o, lse, dq, dk, dv) of the scheduled kernels and of the kernels as

@@ -572,7 +572,7 @@ def test_window_flash_compiles_at_published_widths(one_chip):
 
 
 def _held_experts_layer(one_chip, num_experts=128, ffn_dim=1024, top_k=8,
-                        held=16, route_scale=2.826):
+                        held=16, route_scale=2.826, d=2048, tokens=8192):
     """``(layer, params, batch_stats, x)``: by default Trinity's 16 held
     experts of 128, 8 a token, 8,192 tokens of 2048, experts of 1024, as
     shapes on the chip."""
@@ -584,11 +584,11 @@ def _held_experts_layer(one_chip, num_experts=128, ffn_dim=1024, top_k=8,
         route_scale=route_scale, balance_coeff=0.001,
         dtype=BF16, param_dtype=jnp.float32)
     shapes = jax.eval_shape(lambda: layer.init(
-        jax.random.key(0), jnp.zeros((1, 8192, 2048), BF16), train=False))
+        jax.random.key(0), jnp.zeros((1, tokens, d), BF16), train=False))
     on_chip = lambda tree: jax.tree.map(
         lambda s: _sds(s.shape, one_chip, s.dtype), tree)
     return (layer, on_chip(shapes["params"]), on_chip(shapes["batch_stats"]),
-            _sds((1, 8192, 2048), one_chip))
+            _sds((1, tokens, d), one_chip))
 
 
 def _layer_grads_text(layer, *args):
@@ -682,8 +682,8 @@ def test_held_experts_layer_compiles_at_glm_widths(one_chip, as_tpu):
     assert "bf16[32768,2048]" not in text and "bf16[33792,2048]" not in text
 
 
-def _share_step(preset, one_chip):
-    """``(compiled step, held bytes)`` of ``preset`` at 1 x 8192 (bf16,
+def _share_step(preset, one_chip, seq_len=8192):
+    """``(compiled step, held bytes)`` of ``preset`` at 1 x ``seq_len`` (bf16,
     per-block remat, AdamW, flash attention) for a described v5e chip."""
     from pytorch_distributed_training_example_tpu.core import (
         train_loop, trainer as trainer_lib)
@@ -693,7 +693,7 @@ def _share_step(preset, one_chip):
         from_preset)
 
     cfg = from_preset(preset, global_batch_size=1,
-                      seq_len=8192, lr_schedule="constant", warmup_epochs=0.0,
+                      seq_len=seq_len, lr_schedule="constant", warmup_epochs=0.0,
                       attn_impl="flash")
     mesh = mesh_lib.build_mesh(dict(data=1, fsdp=1),
                                devices=[one_chip._device])
@@ -713,7 +713,7 @@ def _share_step(preset, one_chip):
     state = jax.tree.map(lambda s, sh: _sds(s.shape, sh, s.dtype), shape,
                          shardings)
     rows = NamedSharding(mesh, P(("data", "fsdp")))
-    batch = {k: _sds((1, 8192), rows, jnp.int32)
+    batch = {k: _sds((1, seq_len), rows, jnp.int32)
              for k in ("tokens", "targets")}
     with mesh_lib.use_mesh(mesh):
         compiled = program.train_step.lower(state, batch).compile()
@@ -1424,4 +1424,87 @@ def test_qwen3_next_share_step_fits_the_chip(one_chip, as_tpu):
     for scope in ("gated_delta_net", "delta_rule", "conv_silu", "gate_norm",
                   "in_proj", "out_proj", "moe_router", "moe_experts",
                   "moe_shared"):
+        assert f"/{scope}/" in text, scope
+
+
+# -- Xing4.0's share (models/xing4.py): the online kernels at a value width
+# -- under the query/key width, grouped matmuls at 3584 x 1024, the whole step
+# -- with its four-wide float32 stream
+
+
+@pytest.mark.parametrize("B,S,H,qk,v", [
+    (1, 2048, 32, 192, 128),   # the Xing4.0 cell's five attentions
+    (1, 1024, 4, 64, 128),     # a value width over the query/key width
+])
+def test_online_kernels_compile_at_unequal_widths(one_chip, B, S, H, qk, v):
+    """q and k ``qk`` wide, v and the result ``v`` wide, with a softmax scale
+    of the caller's: the three online kernels under the causal schedule, each
+    operand blocked at its own width (no operand of the call is padded to the
+    other's: the step has no ``pad``, and the calls' results have the widths
+    their cotangents came with), at the blocks the plan gives the pair."""
+    import re
+
+    wide = _sds((B, S, H, qk), one_chip)
+    narrow = _sds((B, S, H, v), one_chip)
+    text = _compiled_text(_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, True, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_KV, "auto", None,
+        None, 0.1447)), wide, wide, narrow)
+    calls = re.findall(r"%(\w*flash_\w+?)_*(?:\.\d+)? = ([^\n]*)custom-call"
+                       r"\(([^\n]*?)\), custom_call_target", text)
+    assert sorted(c[0][c[0].index("flash_"):] for c in calls) == sorted(
+        fa.ONLINE_KERNELS)
+    # o and dv at the value width, dq and dk at the query/key width, and no
+    # operand widened on its way into a call
+    results = sorted(int(w) for _, result, _ in calls for w in re.findall(
+        rf"bf16\[{B},{H},{S},(\d+)\]", result))
+    assert results == sorted([v, qk, qk, v]), calls
+    assert " pad(" not in text
+    assert [fa._online_blocks(bwd, S, (qk, v), 1024, 1024, 2)
+            for bwd in (False, True)] == [(1024, 1024)] * 2
+    assert fa._online_held(True, 1024, 1024, (192, 128), 2) == \
+        2 * 2 * 3 * 1024 * 320 + 4 * 1024 * 320 <= fa.ONLINE_HELD_MAX
+    with pytest.raises(ValueError, match="one head width"):
+        fa.flash_attention(wide, wide, narrow, True, 1024, 1024, "oneshot")
+
+
+def test_grouped_ffn_compiles_at_xing4_widths(one_chip, as_tpu):
+    """Xing4.0's expert layer: 8 held experts of 64, 4 a token, 2,048 tokens
+    of 3584 (28 lane tiles), experts of 1024, a shared expert of 1024;
+    forward and backward through the gated kernels, the bounded layout's
+    ``cond``. Neither width had run."""
+    text = _layer_grads_text(*_held_experts_layer(
+        one_chip, num_experts=64, ffn_dim=1024, top_k=4, held=8,
+        route_scale=2.0, d=3584, tokens=2048))
+    assert "gated_ffn_dw_up" in text and "conditional" in text
+    calls = _expert_kernel_calls(text)
+    assert {"gated_ffn_up", "gated_ffn_down", *_GATED_BACKWARD} <= {
+        name for name, _ in calls}
+
+
+def test_xing4_share_step_fits_the_chip(one_chip, as_tpu):
+    """The benchmark cell's step (``xing4_29b_share`` at 1 x 2048, bf16,
+    per-block remat, AdamW) compiles for a described v5e under the chip's
+    memory (PERF.md has the chip's own reading): five blocks' latent
+    attention is five calls of each online kernel at 192 / 128, the four
+    expert layers' gated kernels, and every hyper-connection's scopes."""
+    import re
+    from collections import Counter
+
+    compiled, mem, held = _share_step("xing4_29b_share", one_chip,
+                                      seq_len=2048)
+    assert mem.argument_size_in_bytes == pytest.approx(759_346_190 * 12,
+                                                       rel=1e-3)
+    assert 12.2e9 < held < 15.6e9, held
+    text = compiled.as_text()
+    assert _scalar_index_ops(text, 2048, 64, 4) == []
+    calls = Counter(m.group(1) for m in re.finditer(
+        r"%([a-z_]+)[.\d]* = [^\n]*tpu_custom_call", text))
+    for name in ("flash_fwd_online", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert calls[name] == 5, calls
+    assert calls["gated_ffn_up"] and calls["gated_ffn_down"], calls
+    assert not [name for name in calls if name.startswith("flash_")
+                and name not in fa.ONLINE_KERNELS], calls
+    assert "bf16[1,32,2048,192]" in text and "bf16[1,32,2048,128]" in text
+    for scope in ("hc_maps", "hc_sinkhorn", "hc_read", "hc_write", "mla_q",
+                  "mla_rope", "moe_router"):
         assert f"/{scope}/" in text, scope

@@ -380,17 +380,18 @@ def test_rope_key_depends_on_position_and_nope_does_not():
 
 
 def test_unequal_widths_take_the_xla_path_and_flash_refuses():
-    """ROADMAP B5: no kernel whose value width differs from its query/key
-    width."""
+    """ROADMAP B5 (1), done: a value width that differs from the query/key
+    width is the dispatcher's business (the online kernels on the chip, the
+    XLA path here), and ``attn_impl="flash"`` no longer refuses it."""
     module = glm_moe_lite.glm_moe_lite_tiny(v_dim=8)
     params, stats, batch = _seeded(module, 16)
     out = module.apply({"params": params, "batch_stats": stats},
                        batch["tokens"], train=False)
     assert out.shape == (2, 16, 96) and bool(jnp.isfinite(out).all())
-    with pytest.raises(NotImplementedError, match="v_head_dim"):
-        module.clone(attn_impl="flash").apply(
-            {"params": params, "batch_stats": stats}, batch["tokens"],
-            train=False)
+    forced = module.clone(attn_impl="flash").apply(
+        {"params": params, "batch_stats": stats}, batch["tokens"],
+        train=False)
+    np.testing.assert_allclose(forced, out, atol=1e-6)
 
 
 # -- the online kernels' block plan --------------------------------------------------

@@ -113,7 +113,8 @@ def _grad_accum_step(name, shapes_only=False):
 
 @pytest.mark.parametrize("name", ["afmoe_tiny", "smallthinker_tiny",
                                   "glm_moe_lite_tiny", "nemotron_h_tiny",
-                                  "lfm2_moe_tiny", "qwen3_next_tiny"])
+                                  "lfm2_moe_tiny", "qwen3_next_tiny",
+                                  "xing4_tiny"])
 def test_train_step_carries_the_expert_sows_through_grad_accum(devices, name):
     """The expert layers' four sows survive the grad-accum scan carry and land
     in the metrics dict beside the health pack, one float32 scalar a sow and
